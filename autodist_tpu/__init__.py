@@ -12,27 +12,13 @@ __version__ = "0.1.0"
 
 import jax as _jax
 
-# version gate (reference enforces TF in [1.15, 2.2], __init__.py:35-43)
-_MIN_JAX = (0, 4, 30)
-_ver = tuple(int(x) for x in _jax.__version__.split(".")[:3])
-if _ver < _MIN_JAX:
-    raise RuntimeError("autodist_tpu requires jax >= %s, found %s"
-                       % (".".join(map(str, _MIN_JAX)), _jax.__version__))
-
-if not hasattr(_jax, "shard_map"):
-    # graceful degradation on older JAX: releases before jax 0.6 ship
-    # shard_map under jax.experimental with ``check_vma`` spelled
-    # ``check_rep``. Alias the modern spelling so the framework (and user
-    # code written against it) runs unchanged instead of dying with
-    # AttributeError at the first step compile.
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def _shard_map_compat(f, *args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _legacy_shard_map(f, *args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
+# version gate (reference enforces TF in [1.15, 2.2], __init__.py:35-43):
+# one installation is supported — the jax minor this tree is built and
+# run against (jax.shard_map, pallas tpu.CompilerParams, jit._cache_size)
+_JAX_MINOR = (0, 9)
+if tuple(int(x) for x in _jax.__version__.split(".")[:2]) < _JAX_MINOR:
+    raise RuntimeError("autodist_tpu requires jax >= %d.%d, found %s"
+                       % (_JAX_MINOR + (_jax.__version__,)))
 
 from autodist_tpu import const  # noqa: E402
 from autodist_tpu import patch as _patch  # noqa: E402

@@ -186,6 +186,17 @@ class AutoDist:
 
     # ------------------------------------------------------------- build path
 
+    def _check_live_device(self):
+        """A spec about to execute on a live TPU must describe THAT chip:
+        its HBM budget gates the plan (ADT501, the Runner budget) and its
+        peak prices it. Device-less planning (CPU meshes, dry-runs) keeps
+        the spec's documented default."""
+        import jax
+        dev = (jax.devices(self._backend) if self._backend
+               else jax.devices())[0]
+        if dev.platform == "tpu":
+            self._resource_spec.require_live_kind(dev.device_kind)
+
     def _verify_strategy(self, strategy: Strategy, item: ModelItem,
                          sentinel_policy=None):
         """Static verification BEFORE kernel transformation
@@ -219,8 +230,10 @@ class AutoDist:
                 strategy, item, self._resource_spec)["diagnostics"]
         except Exception as e:  # noqa: BLE001 — the memory gate is
             # best-effort: a model the cost heuristics cannot trace must
-            # not fail an otherwise-verifiable build
-            logging.debug("plan-level memory gate skipped: %s", e)
+            # not fail an otherwise-verifiable build — but a skipped gate
+            # is said out loud, never silently waved through
+            logging.warning("plan-level memory gate (ADT501) skipped: %s",
+                            e)
         errors = [d for d in diags if d.severity >= Severity.ERROR]
         for d in diags:
             log = (logging.warning if d.severity >= Severity.WARNING
@@ -325,6 +338,7 @@ class AutoDist:
         instance is used as-is — health guards are then compiled INTO
         the step program (docs/sentinel.md)."""
         from autodist_tpu.runtime.sentinel import resolve_policy
+        self._check_live_device()
         policy = resolve_policy(sentinel)
         item = ModelItem(loss_fn=loss_fn, optimizer=optimizer, params=params,
                          example_batch=example_batch, has_aux=has_aux,
@@ -558,6 +572,7 @@ class AutoDist:
         policy degrades to host-side loss monitoring here (the opaque
         step hides its gradients — ADT420)."""
         from autodist_tpu.runtime.sentinel import resolve_policy
+        self._check_live_device()
         policy = resolve_policy(sentinel)
         item = ModelItem(step_fn=step_fn, params=state,
                          example_batch=example_batch).prepare()

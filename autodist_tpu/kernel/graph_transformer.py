@@ -68,9 +68,8 @@ class ForwardProgram:
     def __call__(self, state, ps_vals, batch):
         return self.fn(state, ps_vals, batch)
 
-    def _cache_size(self) -> Optional[int]:
-        cache_size = getattr(self.fn, "_cache_size", None)
-        return cache_size() if callable(cache_size) else None
+    def _cache_size(self) -> int:
+        return self.fn._cache_size()
 
 
 class DistributedStep:

@@ -105,12 +105,12 @@ def make_train_setup(config: Optional[BertConfig] = None, seq_len: int = 128,
     ``attention``: "xla" (fused XLA attention), "flash" (the pallas kernel
     with the padding ``attention_mask`` as segment ids,
     ``ops/flash_attention.py``), or "auto" (default): XLA below 8192
-    tokens, flash at or above. Measured on the v5e chip (BENCHMARKS.md):
-    for masked bidirectional attention XLA is FASTER at every length that
-    fits (~1.8x at 512-4096), but it materializes the [S, S] logits and
-    fails to compile by seq 8192 at bert-base geometry — the flash
-    kernel's O(S) memory is what extends BERT past that wall, so "auto"
-    switches exactly where XLA stops being an option.
+    tokens, flash at or above. XLA attention materializes the [S, S]
+    logits and stops fitting around seq 8192 at bert-base geometry; the
+    flash kernel's O(S) memory is what extends BERT past that wall, so
+    "auto" switches where XLA stops being an option. Which side is
+    faster below the wall is not measured on today's code (the records
+    that said XLA, ~1.8x at 512-4096, were deleted in PR 21).
     """
     cfg = config or BertConfig.base()
     if attention == "auto":
@@ -125,8 +125,7 @@ def make_train_setup(config: Optional[BertConfig] = None, seq_len: int = 128,
     rng = jax.random.PRNGKey(seed)
     ids0 = jnp.zeros((1, seq_len), jnp.int32)
     # jitted init: ONE device dispatch for the whole parameter tree
-    # (eager flax init issues one RPC per initializer — minutes over a
-    # high-latency host<->device link)
+    # (eager flax init issues one dispatch per initializer)
     variables = jax.jit(model.init)(rng, ids0, ids0,
                                     jnp.ones((1, seq_len), jnp.int32))
 

@@ -163,10 +163,10 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
                      batch_size: int = 32, seed: int = 0,
                      attention: str = "auto", lean_head="auto"):
     """``attention``: "auto" (XLA softmax attention below seq 8192, the
-    pallas flash kernel at/above it on TPU — the measured crossover:
-    XLA is ~20% faster at seq 256 but falls over the [S, S] logits HBM
-    wall at 8192, where flash is 4.4x and O(seq) memory), "flash"
-    (force the kernel; interpreted off-TPU), or "default" (XLA always).
+    pallas flash kernel at/above it on TPU, where XLA's [S, S] logits
+    stop fitting in HBM and the kernel's O(seq) memory keeps running),
+    "flash" (force the kernel; interpreted on the CPU backend), or
+    "default" (XLA always).
 
     ``lean_head``: True routes the loss through the chunked cross-entropy
     (``ops.xent.chunked_softmax_xent``) — the [tokens, vocab] fp32 logits
@@ -184,11 +184,10 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         raise ValueError("seq_len %d exceeds config.max_seq_len %d"
                          % (seq_len, cfg.max_seq_len))
     attn_fn = None
-    # "auto" matches the measured crossover (BENCHMARKS.md, same policy
-    # as models/bert.py): XLA's fused softmax attention is FASTER below
-    # seq 8192 (order-alternated on-chip pairs at lm1b seq 256 read
-    # ~290 vs ~244 seq/s) and only falls over the [S, S] logits HBM wall
-    # at/above it, where the flash kernel's O(S) memory keeps running.
+    # "auto" (same policy as models/bert.py) switches where XLA's
+    # [S, S] logits stop fitting; which side is faster below that is not
+    # measured on today's code (the pre-PR-21 records that said XLA,
+    # ~290 vs ~244 seq/s at seq 256, were deleted).
     if attention == "flash" or (attention == "auto"
                                 and jax.default_backend() == "tpu"
                                 and seq_len >= 8192):

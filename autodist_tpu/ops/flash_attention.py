@@ -30,8 +30,8 @@ Design (standard FlashAttention-2 tiling, arXiv 2307.08691):
   instead yields a uniform average for such rows, so don't rely on
   empty-row values across paths.
 
-On non-TPU backends the same kernels run under ``interpret=True`` so unit
-tests exercise the identical code path on CPU (tests/test_flash_attention.py
+On the CPU backend the same kernels run under ``interpret=True`` so unit
+tests exercise the identical code path (tests/test_flash_attention.py
 checks fwd+grad against ``ops.attention.reference_attention``).
 
 Layout matches the rest of the model zoo: [batch, seq, heads, head_dim];
@@ -45,17 +45,22 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in jax 0.6; accept both so
-# the kernels compile across the supported version range
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp() NaN-free on masked rows
 _LANES = 128     # last-dim tile width; m/l scratch are lane-replicated
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled (Mosaic) on TPU, interpreted on the CPU test backend —
+    and nothing else: a machine that came up on some other backend must
+    not quietly run the kernel interpreted."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        "pallas flash attention compiles for tpu and interprets on cpu; "
+        "the active jax backend is %r" % (backend,))
 
 
 def _pick_block(seq: int, want: int) -> int:
@@ -201,7 +206,7 @@ def _fwd(q, k, v, segs, causal, block_q, block_k):
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
                         pltpu.VMEM((bq, _LANES), jnp.float32),
                         pltpu.VMEM((bq, _LANES), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*operands)
@@ -307,7 +312,7 @@ def _bwd(causal, block_q, block_k, res, do):
     delta = jnp.einsum("bhsd,bhsd->bhs", do.astype(jnp.float32),
                        out.astype(jnp.float32))[..., None]
 
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
     q_spec_i = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0),
@@ -428,6 +433,11 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
         q_seg = kv_seg = jnp.asarray(segment_ids, jnp.int32)
     if not _tileable(q, k, block_q, block_k):
         from autodist_tpu.ops.attention import reference_attention
+        from autodist_tpu.utils import logging
+        logging.warning(
+            "flash_attention: q %s / k %s cannot be tiled (blocks %d/%d, "
+            "8-row minimum) — running the XLA reference path instead of "
+            "the kernel", tuple(q.shape), tuple(k.shape), block_q, block_k)
         mask = None
         if causal:
             rows = jnp.arange(q.shape[1])[:, None]

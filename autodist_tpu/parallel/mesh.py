@@ -65,8 +65,7 @@ def host_to_mesh(mesh: Mesh, value, pspec) -> jax.Array:
     On a single-process mesh, already-device-resident values take the
     ``device_put`` path: XLA reshards on device (a no-op when the sharding
     already matches). ``np.asarray`` on a jax.Array would DOWNLOAD it to
-    host and re-upload — invisible over PCIe, but a 220 MB parameter tree
-    over a slow host<->device link pays minutes for nothing. Multi-process
+    host and re-upload for nothing. Multi-process
     meshes stay on the callback path: ``device_put`` cannot retarget a
     committed process-local array onto a mesh this process only partly
     owns, and for uncommitted arrays it inserts per-leaf cross-host

@@ -10,6 +10,7 @@ Device naming follows the reference's ``ip:TYPE:index`` convention
 (reference ``autodist/resource_spec.py:218-277``), with ``TPU`` as the
 accelerator type, e.g. ``10.0.0.1:TPU:0``.
 """
+import dataclasses
 import os
 from enum import Enum
 from typing import Dict, List, Optional
@@ -23,20 +24,62 @@ from autodist_tpu.utils import logging
 DEFAULT_NETWORK_BANDWIDTH_GBPS = 1
 # Default ICI link bandwidth per direction for a v4-like slice, bytes/sec.
 DEFAULT_ICI_BANDWIDTH_GBPS = 400
-# Per-chip HBM capacity by generation, bytes (public figures); "cpu" is
-# host-RAM order for the CPU-mesh development path. The single source of
-# truth for every memory budget in the system — the cost model's
-# feasibility gate and the ADT5xx static HBM analyzer both read it
-# through ResourceSpec.chip_hbm_bytes().
-CHIP_HBM_BYTES = {
-    "v2": 8e9,
-    "v3": 16e9,
-    "v4": 32e9,
-    "v5e": 16e9,
-    "v5p": 95e9,
-    "v6e": 32e9,
-    "cpu": 64e9,
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipSpec:
+    """One row of the chip table: what one JAX device of that generation
+    offers, and where the figures come from."""
+    hbm_bytes: float        # HBM capacity per device
+    peak_bf16_flops: float  # peak dense bf16 FLOP/s per device
+    source: str
+
+
+# THE chip table — the single source of every memory budget (cost-model
+# feasibility gate, ADT5xx static HBM analyzer, Runner budget) and every
+# peak (cost-model compute term, bench/chip_smoke plausibility checks),
+# keyed by generation. A device that is not here is an error, not a
+# default (``chip_kind_of``). v2/v3 rows are per TensorCore: JAX exposes
+# each of those chips' two cores as its own device.
+CHIP_TABLE = {
+    "v2": ChipSpec(8e9, 22.5e12, "Cloud TPU docs 'TPU v2': 16 GiB and "
+                   "45 TFLOP/s per chip, two cores per chip"),
+    "v3": ChipSpec(16e9, 61.5e12, "Cloud TPU docs 'TPU v3': 32 GiB and "
+                   "123 TFLOP/s per chip, two cores per chip"),
+    "v4": ChipSpec(32e9, 275e12, "Cloud TPU docs 'TPU v4'"),
+    "v5e": ChipSpec(16e9, 197e12, "Cloud TPU docs 'TPU v5e'"),
+    "v5p": ChipSpec(95e9, 459e12, "Cloud TPU docs 'TPU v5p'"),
+    "v6e": ChipSpec(32e9, 918e12, "Cloud TPU docs 'TPU v6e'"),
+    # the CPU development mesh: host-RAM order and a few-core FLOP rate,
+    # assumed — a planning prior so chipless specs rank, never a figure
+    # reported about a device
+    "cpu": ChipSpec(64e9, 5e10, "assumed (CPU development mesh)"),
 }
+CHIP_HBM_BYTES = {k: c.hbm_bytes for k, c in CHIP_TABLE.items()}
+
+# ``jax.Device.device_kind`` of a live TPU -> chip table key
+_DEVICE_KIND_TO_CHIP = {
+    "tpu v2": "v2", "tpu v3": "v3", "tpu v4": "v4",
+    "tpu v5 lite": "v5e", "tpu v5e": "v5e",
+    "tpu v5": "v5p", "tpu v5p": "v5p",
+    "tpu v6 lite": "v6e", "tpu v6e": "v6e",
+}
+
+
+def chip_kind_of(device_kind: str) -> str:
+    """Chip-table key for a live device's ``device_kind`` ("TPU v5 lite"
+    -> "v5e"). An unknown kind raises: budgeting HBM or pricing FLOPs
+    for a device the program did not identify is how a 16 GB chip ends
+    up planned as a 32 GB one."""
+    kind = _DEVICE_KIND_TO_CHIP.get(str(device_kind).strip().lower())
+    if kind is None:
+        raise ValueError(
+            "attached device kind %r is not in the chip table "
+            "(resource_spec.CHIP_TABLE knows %s) — declare it in an "
+            "explicit resource spec: `slice.type` (the generation whose "
+            "peak applies) and `slice.hbm_gib` (its per-device HBM)"
+            % (device_kind, sorted(set(_DEVICE_KIND_TO_CHIP.values()))))
+    return kind
 
 
 class DeviceType(Enum):
@@ -390,13 +433,19 @@ class ResourceSpec:
 
     @classmethod
     def from_local(cls) -> "ResourceSpec":
-        """Build a single-node spec from the local JAX runtime's devices."""
+        """Build a single-node spec from the local JAX runtime's devices.
+        Accelerators are identified, not assumed: the first device's
+        ``device_kind`` is recorded as ``slice.type`` (a kind the chip
+        table does not know raises — see :func:`chip_kind_of`)."""
         import jax
-        n = len(jax.local_devices())
-        kind = jax.local_devices()[0].platform.upper() if n else "CPU"
+        devs = jax.local_devices()
+        n = len(devs)
+        on_cpu = not n or devs[0].platform == "cpu"
         d = {"nodes": [{"address": "127.0.0.1", "chief": True,
-                        "tpus": n if kind != "CPU" else 0,
-                        "cpus": list(range(n if kind == "CPU" else 1))}]}
+                        "tpus": 0 if on_cpu else n,
+                        "cpus": list(range(n if on_cpu else 1))}]}
+        if not on_cpu:
+            d["slice"] = {"type": chip_kind_of(devs[0].device_kind)}
         return cls.from_dict(d)
 
     def _from_dict(self, d: dict):
@@ -507,13 +556,40 @@ class ResourceSpec:
 
     def chip_kind(self) -> str:
         """Chip generation of this cluster ("v4", "v5e", ..., or "cpu"),
-        from ``slice.type`` in the yaml; TPU clusters with no declared
-        type default to v4, chipless specs to the CPU development path."""
+        from ``slice.type`` in the yaml. Device-less PLANNING specs with
+        no declared type default to v4 (chipless ones to the CPU
+        development path); a spec that executes on a live TPU never gets
+        that default — :meth:`require_live_kind` refuses it."""
         kind = str(self._slice_info.get("type", "")).lower()
-        for k in sorted(CHIP_HBM_BYTES, key=len, reverse=True):
+        # accelerator-type spellings ("v5litepod-4") name the same chips
+        kind = kind.replace("v5lite", "v5e").replace("v6lite", "v6e")
+        for k in sorted(CHIP_TABLE, key=len, reverse=True):
             if k != "cpu" and k in kind:
                 return k
         return "v4" if self.num_tpus else "cpu"
+
+    def require_live_kind(self, device_kind: str) -> None:
+        """Refuse to run on a live TPU whose kind disagrees with the one
+        this spec declares (or defaulted to): the HBM budget and the
+        peak would describe another chip than the one executing. A live
+        kind the chip table does not know is accepted only under an
+        explicitly declared ``slice.type``."""
+        declared = self.chip_kind()
+        try:
+            live = chip_kind_of(device_kind)
+        except ValueError:
+            if "type" in self._slice_info:
+                return
+            raise
+        if live != declared:
+            raise ValueError(
+                "resource spec describes %s chips%s but the attached "
+                "device is %r (%s) — set `slice.type: %s` in the spec "
+                "(and `slice.hbm_gib` for a partial-HBM reservation), or "
+                "build the spec with ResourceSpec.from_local()"
+                % (declared, "" if "type" in self._slice_info
+                   else " (the default of a spec with no `slice.type`)",
+                   device_kind, live, live))
 
     def chip_hbm_bytes(self) -> float:
         """Per-chip HBM capacity in bytes — the memory budget one device's
@@ -524,7 +600,7 @@ class ResourceSpec:
         override = self._slice_info.get("hbm_gib")
         if override is not None:
             return float(override) * (1 << 30)
-        return CHIP_HBM_BYTES[self.chip_kind()]
+        return CHIP_TABLE[self.chip_kind()].hbm_bytes
 
     def node_tpu_count(self, address: str) -> int:
         return len(self._nodes[address].tpu_indices)

@@ -1044,8 +1044,8 @@ class Runner:
         isolates trace+compile; ``steady_*`` percentiles describe the
         post-compile regime over a recent window; ``goodput`` is the
         fraction of total stepping wall time the job would have needed at
-        steady median speed — compile time, host stalls, and throttle
-        windows all show up as lost goodput.
+        steady median speed — compile time and host stalls show up as
+        lost goodput.
 
         Fused accounting: wall-time samples are PER DISPATCH, so both
         counts are reported — ``supersteps`` (dispatches: what the timing

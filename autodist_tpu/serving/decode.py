@@ -339,14 +339,9 @@ class DecodeEngine:
             self._caches_after_warmup = self._jit_cache_sizes()
         return self
 
-    def _jit_cache_sizes(self) -> Optional[int]:
-        sizes = []
-        for prog in (self._decode_prog.fn, self._insert_prog):
-            cs = getattr(prog, "_cache_size", None)
-            sizes.append(cs() if callable(cs) else None)
-        if any(s is None for s in sizes):
-            return None
-        return sum(sizes)
+    def _jit_cache_sizes(self) -> int:
+        return (self._decode_prog._cache_size()
+                + self._insert_prog._cache_size())
 
     def recompiles_after_warmup(self) -> int:
         """Compiled-specialization growth since :meth:`warmup` across
@@ -354,8 +349,7 @@ class DecodeEngine:
         the zero-recompile continuous-batching contract."""
         n = self._prefill.recompiles_after_warmup()
         if self._caches_after_warmup is not None:
-            now = self._jit_cache_sizes()
-            n += max(0, (now or 0) - self._caches_after_warmup)
+            n += max(0, self._jit_cache_sizes() - self._caches_after_warmup)
         return n
 
     # --------------------------------------------------------- submit

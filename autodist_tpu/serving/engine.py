@@ -244,28 +244,18 @@ class InferenceEngine:
             with tel.span("serve.warmup", "serve", bucket=b):
                 self.run_batch([self._example_request] * b)
         self._warmed = True
-        self._cache_size_after_warmup = self._jit_cache_size()
-        if self._cache_size_after_warmup is None:
-            logging.warning(
-                "serving: jit cache size is not introspectable on this jax "
-                "version — the zero-recompile contract cannot be verified "
-                "(recompiles_after_warmup() will report 0)")
-        tel.counter_add("serve.compiles",
-                        self._cache_size_after_warmup or len(self.buckets))
+        self._cache_size_after_warmup = self._program._cache_size()
+        tel.counter_add("serve.compiles", self._cache_size_after_warmup)
         return self
-
-    def _jit_cache_size(self) -> Optional[int]:
-        cache_size = getattr(self._program, "_cache_size", None)
-        return cache_size() if callable(cache_size) else None
 
     def recompiles_after_warmup(self) -> int:
         """Compiled-specialization count growth since :meth:`warmup` —
-        the zero-recompile serving contract (0 in steady state). Falls
-        back to 0 when the jit cache size is not introspectable."""
+        the zero-recompile serving contract (0 in steady state; 0 before
+        any warmup, when there is no baseline to grow from)."""
         if self._cache_size_after_warmup is None:
             return 0
-        now = self._jit_cache_size()
-        return max(0, (now or 0) - self._cache_size_after_warmup)
+        return max(0, self._program._cache_size()
+                   - self._cache_size_after_warmup)
 
     def run_batch(self, requests) -> Tuple[dict, int]:
         """Execute one request group: pad to the nearest bucket, dispatch

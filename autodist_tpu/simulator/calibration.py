@@ -9,7 +9,7 @@ launch latency) carries a multiplicative scale factor, and ``fit`` finds
 the scales that best explain a handful of measured (strategy, seconds)
 pairs. The analytic structure stays — calibration corrects the constants
 (achieved MXU efficiency, effective link bandwidths, real launch
-overheads) that no closed form gets right on every chip/tunnel/host.
+overheads) that no closed form gets right on every chip and host.
 
 Scales persist as JSON so one measured session calibrates future
 ``AutoStrategy`` decisions on the same hardware

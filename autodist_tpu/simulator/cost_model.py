@@ -16,20 +16,11 @@ import dataclasses
 import math
 from typing import Dict, Optional
 
+from autodist_tpu.resource_spec import CHIP_TABLE
 from autodist_tpu.strategy.base import (AllReduceSynchronizer, PSSynchronizer,
                                         Strategy, ZeroShardedSynchronizer)
 from autodist_tpu.utils import logging
 
-# Peak dense bf16 FLOP/s per chip by generation (public figures).
-CHIP_PEAK_FLOPS = {
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "cpu": 5e10,
-}
-# HBM per chip now lives in resource_spec.py (the ResourceSpec owns the
-# cluster's memory budget; re-exported here for back-compat)
-from autodist_tpu.resource_spec import CHIP_HBM_BYTES  # noqa: E402,F401
 # extra compute for gradient rematerialization: "full" re-runs the whole
 # forward in the backward (fwd+bwd ~3x fwd -> ~4x), "dots" recomputes
 # only the cheap non-contraction work (~3.5x)
@@ -224,7 +215,7 @@ class CostModel:
         self._static_profiles: Dict[Optional[str], StaticCollectiveProfile] = {}
         if static_profile is not None:
             self._static_profiles[None] = static_profile
-        self._chip = chip_kind or self._guess_chip()
+        self._chip = chip_kind or resource_spec.chip_kind()
         self._eff = mxu_efficiency
         self._flops = flops_per_step
         if hbm_capacity_bytes is not None:
@@ -232,7 +223,7 @@ class CostModel:
         elif chip_kind is not None:
             # an explicit chip override prices that generation's memory
             # even when the spec describes another
-            self._hbm_capacity = CHIP_HBM_BYTES[chip_kind]
+            self._hbm_capacity = CHIP_TABLE[chip_kind].hbm_bytes
         else:
             self._hbm_capacity = resource_spec.chip_hbm_bytes()
         self._act_cache = None
@@ -302,9 +293,6 @@ class CostModel:
         from autodist_tpu.analysis import verify as _verify
         return _verify(strategy, self._item, self._spec)
 
-    def _guess_chip(self) -> str:
-        kind = self._spec.chip_kind()
-        return kind if kind in CHIP_PEAK_FLOPS else "v4"
 
     # ---------------------------------------------------------------- pieces
 
@@ -354,7 +342,7 @@ class CostModel:
         return self._flops
 
     def compute_time(self, num_devices: int) -> float:
-        peak = CHIP_PEAK_FLOPS[self._chip] * self._eff
+        peak = CHIP_TABLE[self._chip].peak_bf16_flops * self._eff
         return self.flops_per_step() / max(num_devices, 1) / peak
 
     # shape-only ops fuse away in XLA and hold no residual of their own

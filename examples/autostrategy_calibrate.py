@@ -1,7 +1,7 @@
 """Calibrate AutoStrategy's cost model from measured runs, then reuse it.
 
 The analytic cost model ranks candidate strategies from closed-form
-constants; real hardware disagrees (throttled chips, slow host links).
+constants; real hardware disagrees (achieved MXU efficiency, host links).
 This example measures a few strategies for real, fits the model's term
 scales to those measurements (``Simulator.calibrate`` — the reference's
 AutoSync measured-runs idea, ``autodist/simulator/dataset/README.md``,

@@ -18,6 +18,7 @@ import optax
 
 import autodist_tpu as adt
 from autodist_tpu.models import bert
+from autodist_tpu.utils.compile_cache import enable_compile_cache
 from examples.benchmark.utils.logs import BenchmarkLogger, ExamplesPerSecondHook
 from examples.benchmark.imagenet import make_builder
 
@@ -34,6 +35,7 @@ def main():
     p.add_argument("--steps", type=int, default=100)
     p.add_argument("--resource_spec", default=None)
     args = p.parse_args()
+    enable_compile_cache()
 
     ad = adt.AutoDist(resource_spec_file=args.resource_spec,
                       strategy_builder=make_builder(args.autodist_strategy, 256))

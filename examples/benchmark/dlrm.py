@@ -20,6 +20,7 @@ import optax
 import autodist_tpu as adt
 from autodist_tpu import strategy
 from autodist_tpu.models import dlrm
+from autodist_tpu.utils.compile_cache import enable_compile_cache
 from examples.benchmark.utils.logs import BenchmarkLogger, ExamplesPerSecondHook
 from examples.benchmark.imagenet import make_builder
 
@@ -34,6 +35,7 @@ def main():
     p.add_argument("--embed_dim", type=int, default=64)
     p.add_argument("--resource_spec", default=None)
     args = p.parse_args()
+    enable_compile_cache()
 
     builder = (strategy.AutoStrategy()
                if args.autodist_strategy == "AutoStrategy"

@@ -23,6 +23,7 @@ import optax
 import autodist_tpu as adt
 from autodist_tpu import strategy as S
 from autodist_tpu import models
+from autodist_tpu.utils.compile_cache import enable_compile_cache
 from examples.benchmark.utils.logs import BenchmarkLogger, ExamplesPerSecondHook
 
 # per-model chunk sizes, as tuned in the reference (imagenet.py:150-158:
@@ -85,6 +86,7 @@ def main():
                         "prefetcher instead of a fixed device-resident "
                         "batch (measures the full input path)")
     args = p.parse_args()
+    enable_compile_cache()
 
     chunk = CHUNK_SIZES.get(args.model, 512)
     ad = adt.AutoDist(resource_spec_file=args.resource_spec,

@@ -24,6 +24,7 @@ import optax
 
 import autodist_tpu as adt
 from autodist_tpu.models import ncf
+from autodist_tpu.utils.compile_cache import enable_compile_cache
 from examples.benchmark.utils.logs import BenchmarkLogger, ExamplesPerSecondHook
 from examples.benchmark.imagenet import make_builder
 
@@ -119,6 +120,7 @@ def main():
     p.add_argument("--neg_per_pos", type=int, default=4)
     p.add_argument("--eval_negatives", type=int, default=99)
     args = p.parse_args()
+    enable_compile_cache()
 
     builder = make_builder(args.autodist_strategy, 512)
     if args.data:

@@ -21,6 +21,7 @@ import optax
 import autodist_tpu as adt
 from autodist_tpu import strategy as S
 from autodist_tpu.models import lm
+from autodist_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -41,6 +42,7 @@ def main():
                         "through the continuous-batching DecodeEngine "
                         "(serving/decode.py)")
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = {"tiny": lm.LMConfig.tiny, "default": lm.LMConfig,
            # byte-level vocab for raw-text corpora (--data), small dims
