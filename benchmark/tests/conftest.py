@@ -1,0 +1,11 @@
+"""Run by hand, never by the repo's tier-1 run (which collects ``tests/``):
+
+    JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests -q
+"""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
