@@ -103,6 +103,7 @@ class DevicePrefetcher:
         self._it = iter(iterable)
         self._queue = collections.deque()
         self._exhausted = False
+        self._placed = 0  # queue items placed so far (the span's item=)
         # observable data-loss accounting (stack mode's dropped tails)
         self.dropped_batches = 0
         self.dropped_examples = 0
@@ -161,8 +162,9 @@ class DevicePrefetcher:
                 return
             # placement is async: this enqueues the transfer and returns
             with tel.span("prefetch.place", "prefetch",
-                          stack=self.stack_k):
+                          stack=self.stack_k, item=self._placed):
                 self._queue.append(self._place(host_batch))
+            self._placed += 1
         # occupancy AFTER filling: 0 here means the consumer is about to
         # stall on the host side — the starvation signal
         tel.gauge_set("prefetch.queue_depth", len(self._queue))

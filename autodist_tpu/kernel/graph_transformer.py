@@ -35,6 +35,7 @@ from autodist_tpu.kernel.synchronization.synchronizer import Synchronizer
 from autodist_tpu.parallel import collectives
 from autodist_tpu.parallel import ps as ps_lib
 from autodist_tpu.strategy.base import Strategy
+from autodist_tpu.telemetry import scopes as sc
 from autodist_tpu.telemetry import spans as tel
 from autodist_tpu.train_state import TrainState
 from autodist_tpu.utils import logging
@@ -362,7 +363,7 @@ class DistributedStep:
         return fused
 
     def run_multi(self, state: TrainState, stacked_batch,
-                  donate: bool = True):
+                  donate: bool = True, step: Optional[int] = None):
         """Run one superstep (k = the stacked batch's leading dim) and
         manage the PS carry: pull once before the first superstep, keep
         values/opt device-resident across supersteps, write back only at
@@ -379,7 +380,7 @@ class DistributedStep:
             raise ValueError(
                 "stacked batch has mismatched leading (microstep) dims %s"
                 % sorted(lead))
-        with tel.span("dstep.dispatch", "dstep", fused=True):
+        with tel.span("dstep.dispatch", "dstep", fused=True, step=step):
             ps_vals, ps_opt = self._ensure_fused_ps_carry()
             new_state, new_vals, new_opt, metrics = fn(
                 state, ps_vals, ps_opt, stacked_batch)
@@ -407,12 +408,14 @@ class DistributedStep:
             del self._ps_pipe_obj
         self._flush_fused_ps()
 
-    def __call__(self, state: TrainState, batch, donate: bool = True):
+    def __call__(self, state: TrainState, batch, donate: bool = True,
+                 step: Optional[int] = None):
         """Run one step. ``donate=True`` (default) consumes ``state``'s
         buffers — callers holding their own reference to the input state must
-        pass ``donate=False``."""
+        pass ``donate=False``. ``step`` (the Runner's microstep index) only
+        labels the dispatch span."""
         fn = self._step_fn if donate else self._step_fn_nodonate
-        with tel.span("dstep.dispatch", "dstep", fused=False):
+        with tel.span("dstep.dispatch", "dstep", fused=False, step=step):
             ps_vals = self.pull_ps()
             new_state, ps_grads, metrics = fn(state, ps_vals, batch)
             # sentinel-guarded programs ship the verdict in the metrics;
@@ -1418,8 +1421,14 @@ class GraphTransformer:
         else:
             loss_fn_cd = item.loss_fn
 
-        grad_fn = jax.value_and_grad(remat_wrap(loss_fn_cd),
-                                     has_aux=item.has_aux)
+        # the loss scope sits OUTSIDE remat and INSIDE the differentiated
+        # function: JAX then names forward ops jvp(loss)/..., backward
+        # ops transpose(jvp(loss))/... and recomputed ones
+        # .../rematted_computation/...
+        under_loss_scope = sc.scoped(sc.LOSS)
+
+        grad_fn = jax.value_and_grad(
+            under_loss_scope(remat_wrap(loss_fn_cd)), has_aux=item.has_aux)
         if sparse_wire:
             def loss_with_taps(full_params, taps, batch):
                 with embedding_lib.capture(taps) as cap:
@@ -1427,7 +1436,8 @@ class GraphTransformer:
                 loss, aux = (out if item.has_aux else (out, None))
                 return loss, (aux, cap.ids)
             sparse_grad_fn = jax.value_and_grad(
-                remat_wrap(loss_with_taps), argnums=(0, 1), has_aux=True)
+                under_loss_scope(remat_wrap(loss_with_taps)),
+                argnums=(0, 1), has_aux=True)
         optimizer = item.optimizer
         has_aux = item.has_aux
         axis = self._axis
@@ -1584,14 +1594,21 @@ class GraphTransformer:
                     out[n], tuple(info.shape), np.dtype(info.dtype))
             return out
 
+        def _full_params(state: TrainState, ps_vals):
+            """The prologue of every compiled program: stored leaves
+            gathered into the full layout, host-resident PS values
+            (pulled + replicated) filled into the holes, so the user's
+            function sees the full original params tree."""
+            with sc.scope(sc.PARAMS):
+                ps_vals = _ps_dewire(ps_vals)
+                gathered = _tree_map_layouts(
+                    lambda leaf, lay: lay.gather_full(leaf), state.params,
+                    layout_tree)
+                return (ps_lib.fill_holes(gathered, ps_vals)
+                        if ps_names else gathered)
+
         def local_step(state: TrainState, ps_vals, batch):
-            ps_vals = _ps_dewire(ps_vals)
-            gathered = _tree_map_layouts(
-                lambda leaf, lay: lay.gather_full(leaf), state.params, layout_tree)
-            # host-resident PS values arrive pulled + replicated; fill the
-            # holes so the user's loss sees the full original params tree
-            full_params = (ps_lib.fill_holes(gathered, ps_vals)
-                           if ps_names else gathered)
+            full_params = _full_params(state, ps_vals)
             if sparse_wire:
                 taps = embedding_lib.make_taps(sparse_specs)
                 (loss, (aux, ids_seen)), (grads, tap_grads) = sparse_grad_fn(
@@ -1610,215 +1627,220 @@ class GraphTransformer:
                 # all-reduced verdict judges) the same poisoned value
                 g = fi.apply_grad_faults(grad_plan, state.step, g)
 
-            # sparse wire: per-var (ids, values) pairs, all-gathered across
-            # the mesh — batch-shaped payload instead of vocab-shaped
-            sparse_pairs = {}
-            for n in sorted(sparse_wire):
-                flat_ids, flat_vals = embedding_lib.flatten_pairs(
-                    ids_seen.get(n, []), tap_grads.get(n, []))
-                if N > 1:
-                    flat_ids, flat_vals = embedding_lib.gather_pairs(
-                        flat_ids, flat_vals, all_axes)
-                sparse_pairs[n] = (flat_ids, flat_vals / N)
+            with sc.scope(sc.GRAD_SYNC):
+                # sparse wire: per-var (ids, values) pairs, all-gathered across
+                # the mesh — batch-shaped payload instead of vocab-shaped
+                sparse_pairs = {}
+                for n in sorted(sparse_wire):
+                    flat_ids, flat_vals = embedding_lib.flatten_pairs(
+                        ids_seen.get(n, []), tap_grads.get(n, []))
+                    if N > 1:
+                        flat_ids, flat_vals = embedding_lib.gather_pairs(
+                            flat_ids, flat_vals, all_axes)
+                    sparse_pairs[n] = (flat_ids, flat_vals / N)
 
-            # PS gradients exit the device: mean-reduced, replicated, pushed
-            # to the host store by the caller (the reference's grad push to
-            # the PS accumulator, ps_synchronizer.py:556-633); sparse PS
-            # vars ship the (ids, values) pair itself — the store
-            # scatter-adds into each owner shard's index range
-            ps_grads = {}
-            for n in sorted(ps_names):
-                if n in sparse_pairs:
-                    ps_grads[n] = sparse_pairs[n]
-                elif N == 1:
-                    ps_grads[n] = g[n]
-                else:
-                    ps_grads[n] = jax.lax.psum(g[n], all_axes) / N
-                if n in ps_quant:
-                    # quantize ON DEVICE: the D2H transfer (the PS push
-                    # wire) carries int8 + scales; the store dequantizes
-                    # at its boundary before the optimizer apply
-                    ps_grads[n] = collectives.quant_wire(ps_grads[n])
-
-            sync_state = dict(state.sync_state) if isinstance(state.sync_state, dict) else {}
-            new_bucket_state = dict(sync_state.get("bucket", {}))
-            new_var_state = dict(sync_state.get("var", {}))
-            synced: Dict[str, Any] = {}
-            psum = lambda x: jax.lax.psum(x, all_axes)  # noqa: E731
-
-            if N == 1:
-                # single replica: gradients are already global; collectives
-                # would only insert degenerate all-reduces that block fusion
-                # (compressor states pass through unchanged)
-                synced = {n: (jnp.zeros_like(v) if n in frozen_names else v)
-                          for n, v in g.items()
-                          if n not in ps_names and n not in sparse_wire}
-
-            # model-parallel vars: mean over the complement axes only; the /N
-            # (total devices) normalization is exact — shard_map AD transposes
-            # the forward psum/all_to_all into a sum over the model axes, and
-            # that inflation cancels against the model-axis factor in N
-            # (verified numerically in tests/test_tensor_parallel.py)
-            for n in (mp_names if N > 1 else ()):
-                if n in frozen_names:
-                    synced[n] = jnp.zeros_like(g[n])
-                    continue
-                comp = mp_complement[n]
-                synced[n] = (jax.lax.psum(g[n], comp) if comp else g[n]) / N
-
-            # sparse AllReduce vars: densify AFTER the wire (local
-            # scatter-add of the gathered pairs — reference
-            # all_reduce_synchronizer.py:132-173's conversion back)
-            for n in sorted(sparse_wire):
-                if n in ps_names:
-                    continue
-                info = var_infos[n]
-                s_ids, s_vals = sparse_pairs[n]
-                synced[n] = embedding_lib.scatter_add_dense(
-                    s_ids, s_vals, int(info.shape[0]),
-                    tuple(info.shape[1:]))
-
-            # the three gradient-sync unit kernels, shared verbatim by the
-            # epilogue and the overlapped schedule — the schedule only
-            # changes WHEN each unit's collective may launch (barrier
-            # chaining), never its math, so the two lowerings are
-            # bit-identical (optimization_barrier is an identity op)
-            def _run_zero(n, gin):
-                synced[n] = zero_syncs[n].reduce_scatter(gin)
-                return synced[n]
-
-            def _run_bucket(b, gin):
-                bst = new_bucket_state.get(b.key)
-                bst_local = bst[0] if bst is not None else None
-                bucket_psum = psum
-                sched = getattr(b, "schedule", "auto")
-                if (b.spec == "DCN" or sched == "hier") and dcn:
-                    bucket_psum = lambda x: collectives.hierarchical_psum(  # noqa: E731
-                        x, ici, dcn)
-                elif sched == "rhd":
-                    bucket_psum = lambda x: collectives.rhd_psum(  # noqa: E731
-                        x, all_axes)
-                out, nst = collectives.bucket_reduce(
-                    b, gin, bst_local, bucket_psum, N, ring_axes=ring_axes)
-                synced.update(out)
-                if nst is not None:
-                    new_bucket_state[b.key] = jnp.expand_dims(nst, 0)
-                return out
-
-            def _run_var(n, gin):
-                s = syncs[n]
-                vst = new_var_state.get(n)
-                vst_local = jax.tree_util.tree_map(lambda a: a[0], vst) if vst is not None else None
-                synced[n], nst = s.sync(gin, vst_local)
-                if nst is not None:
-                    new_var_state[n] = jax.tree_util.tree_map(
-                        lambda a: jnp.expand_dims(a, 0), nst)
-                return synced[n]
-
-            if grad_schedule is not None:
-                # overlapped schedule: stages in reverse layer order, each
-                # stage's gradient inputs barrier-chained on a 1-element
-                # token of the previous stage's reduced output — a real
-                # data dependence that keeps the stages un-merged and
-                # ordered by backward readiness (see build-time comment)
-                bucket_by_key = {b.key: b for b in buckets}
-                token = None
-                for stg in grad_schedule.stages:
-                    op = stg.ops[0]
-                    kind, _, uname = op.unit.partition(":")
-                    if kind == "bucket":
-                        b = bucket_by_key[uname]
-                        gin = {n: g[n] for n in b.var_names}
-                        gin, token = collectives.barrier_chain(gin, token)
-                        out = _run_bucket(b, gin)
-                    elif kind == "zero":
-                        (gin,), token = collectives.barrier_chain(
-                            (g[uname],), token)
-                        out = _run_zero(uname, gin)
+                # PS gradients exit the device: mean-reduced, replicated, pushed
+                # to the host store by the caller (the reference's grad push to
+                # the PS accumulator, ps_synchronizer.py:556-633); sparse PS
+                # vars ship the (ids, values) pair itself — the store
+                # scatter-adds into each owner shard's index range
+                ps_grads = {}
+                for n in sorted(ps_names):
+                    if n in sparse_pairs:
+                        ps_grads[n] = sparse_pairs[n]
+                    elif N == 1:
+                        ps_grads[n] = g[n]
                     else:
-                        (gin,), token = collectives.barrier_chain(
-                            (g[uname],), token)
-                        out = _run_var(uname, gin)
-                    token = collectives.overlap_token(out)
-            else:
-                # epilogue lowering: ZeRO reduce-scatters, then concat
-                # buckets, then per-var syncs — one contiguous block after
-                # the full backward (the pre-overlap baseline, and the
-                # N == 1 / overlap-off path)
-                for n in sorted(zero_names):
-                    _run_zero(n, g[n])
-                for b in (buckets if N > 1 else []):
-                    _run_bucket(b, g)
-                for n in (syncs if N > 1 else ()):
-                    if n in bucketed_names or n in synced:
-                        continue
-                    _run_var(n, g[n])
-            # non-trainable vars: zero gradient so optimizer state stays
-            # clean and the value never moves; remaining unconfigured vars
-            # (shouldn't happen post-compile) get a plain mean-psum
-            for n in g_names:
-                if n in synced or n in ps_names:
-                    continue
-                if n in var_infos and not var_infos[n].trainable:
-                    synced[n] = jnp.zeros_like(g[n])
-                else:
-                    synced[n] = psum(g[n]) / N
+                        ps_grads[n] = jax.lax.psum(g[n], all_axes) / N
+                    if n in ps_quant:
+                        # quantize ON DEVICE: the D2H transfer (the PS push
+                        # wire) carries int8 + scales; the store dequantizes
+                        # at its boundary before the optimizer apply
+                        ps_grads[n] = collectives.quant_wire(ps_grads[n])
 
-            # device-side update covers only device-resident leaves (the
-            # holed structure); PS leaves update on the host, ZeRO-sharded
-            # leaves per-shard against sync_state['zero'] below
-            h_names, h_leaves, h_treedef = variable_utils.flatten_named(
-                state.params)
-            grads_storage = variable_utils.unflatten_named(
-                h_treedef, [synced[n] for n in h_names])
-            if zero_names:
-                grads_basis = ps_lib.hole_like(zero_basis_template,
-                                               grads_storage)
-                params_basis = ps_lib.hole_like(zero_basis_template,
-                                                state.params)
-            else:
-                grads_basis, params_basis = grads_storage, state.params
-            updates, new_opt = optimizer.update(
-                grads_basis, state.opt_state, params_basis)
-            lr_scale = (sync_state["sentinel"]["lr_scale"][0] if guard
-                        else None)
-            if guard:
-                # sentinel escalation: effective-LR scale from sync_state
-                # (local slice of the leading-device-axis layout) — the
-                # zero deltas below scale pre-gather to the same value
-                updates = jax.tree_util.tree_map(
-                    lambda u: (u * lr_scale).astype(u.dtype), updates)
-            new_zero_state = {}
-            if zero_names:
-                # the sharded weight update: optimizer on the owned 1/P
-                # shard only (per-var little trees, the SAME per-variable
-                # apply shape the host-PS store runs), then all-gather the
-                # UPDATE so every replica applies the identical delta to
-                # its full-precision replicated param copy
-                p_map = dict(zip(h_names, h_leaves))
-                zstate = sync_state["zero"]
-                zero_deltas = {}
-                for n in sorted(zero_names):
-                    zs = zero_syncs[n]
-                    opt_local = jax.tree_util.tree_map(
-                        lambda a: a[0], zstate[n])
-                    upd, nopt = optimizer.update(
-                        {"v": synced[n]}, opt_local,
-                        {"v": zs.local_shard(p_map[n])})
-                    d = upd["v"]
-                    if lr_scale is not None:
-                        d = (d * lr_scale).astype(d.dtype)
-                    zero_deltas[n] = zs.gather_update(d)
-                    new_zero_state[n] = jax.tree_util.tree_map(
-                        lambda a: jnp.expand_dims(a, 0), nopt)
-                updates = ps_lib.fill_holes(updates, zero_deltas)
-            # mask non-trainable updates (guards vs. weight decay etc.)
-            if frozen_names:
-                u_names, u_leaves, u_treedef = variable_utils.flatten_named(updates)
-                u = [jnp.zeros_like(leaf) if n in frozen_names else leaf
-                     for n, leaf in zip(u_names, u_leaves)]
-                updates = variable_utils.unflatten_named(u_treedef, u)
-            new_params = optax.apply_updates(state.params, updates)
+                sync_state = (dict(state.sync_state)
+                              if isinstance(state.sync_state, dict) else {})
+                new_bucket_state = dict(sync_state.get("bucket", {}))
+                new_var_state = dict(sync_state.get("var", {}))
+                synced: Dict[str, Any] = {}
+                psum = lambda x: jax.lax.psum(x, all_axes)  # noqa: E731
+
+                if N == 1:
+                    # single replica: gradients are already global; collectives
+                    # would only insert degenerate all-reduces that block fusion
+                    # (compressor states pass through unchanged)
+                    synced = {n: (jnp.zeros_like(v) if n in frozen_names else v)
+                              for n, v in g.items()
+                              if n not in ps_names and n not in sparse_wire}
+
+                # model-parallel vars: mean over the complement axes only; the /N
+                # (total devices) normalization is exact — shard_map AD transposes
+                # the forward psum/all_to_all into a sum over the model axes, and
+                # that inflation cancels against the model-axis factor in N
+                # (verified numerically in tests/test_tensor_parallel.py)
+                for n in (mp_names if N > 1 else ()):
+                    if n in frozen_names:
+                        synced[n] = jnp.zeros_like(g[n])
+                        continue
+                    comp = mp_complement[n]
+                    synced[n] = (jax.lax.psum(g[n], comp) if comp else g[n]) / N
+
+                # sparse AllReduce vars: densify AFTER the wire (local
+                # scatter-add of the gathered pairs — reference
+                # all_reduce_synchronizer.py:132-173's conversion back)
+                for n in sorted(sparse_wire):
+                    if n in ps_names:
+                        continue
+                    info = var_infos[n]
+                    s_ids, s_vals = sparse_pairs[n]
+                    synced[n] = embedding_lib.scatter_add_dense(
+                        s_ids, s_vals, int(info.shape[0]),
+                        tuple(info.shape[1:]))
+
+                # the three gradient-sync unit kernels, shared verbatim by the
+                # epilogue and the overlapped schedule — the schedule only
+                # changes WHEN each unit's collective may launch (barrier
+                # chaining), never its math, so the two lowerings are
+                # bit-identical (optimization_barrier is an identity op)
+                def _run_zero(n, gin):
+                    synced[n] = zero_syncs[n].reduce_scatter(gin)
+                    return synced[n]
+
+                def _run_bucket(b, gin):
+                    bst = new_bucket_state.get(b.key)
+                    bst_local = bst[0] if bst is not None else None
+                    bucket_psum = psum
+                    sched = getattr(b, "schedule", "auto")
+                    if (b.spec == "DCN" or sched == "hier") and dcn:
+                        bucket_psum = lambda x: collectives.hierarchical_psum(  # noqa: E731
+                            x, ici, dcn)
+                    elif sched == "rhd":
+                        bucket_psum = lambda x: collectives.rhd_psum(  # noqa: E731
+                            x, all_axes)
+                    out, nst = collectives.bucket_reduce(
+                        b, gin, bst_local, bucket_psum, N, ring_axes=ring_axes)
+                    synced.update(out)
+                    if nst is not None:
+                        new_bucket_state[b.key] = jnp.expand_dims(nst, 0)
+                    return out
+
+                def _run_var(n, gin):
+                    s = syncs[n]
+                    vst = new_var_state.get(n)
+                    vst_local = (jax.tree_util.tree_map(lambda a: a[0], vst)
+                                 if vst is not None else None)
+                    synced[n], nst = s.sync(gin, vst_local)
+                    if nst is not None:
+                        new_var_state[n] = jax.tree_util.tree_map(
+                            lambda a: jnp.expand_dims(a, 0), nst)
+                    return synced[n]
+
+                if grad_schedule is not None:
+                    # overlapped schedule: stages in reverse layer order, each
+                    # stage's gradient inputs barrier-chained on a 1-element
+                    # token of the previous stage's reduced output — a real
+                    # data dependence that keeps the stages un-merged and
+                    # ordered by backward readiness (see build-time comment)
+                    bucket_by_key = {b.key: b for b in buckets}
+                    token = None
+                    for stg in grad_schedule.stages:
+                        op = stg.ops[0]
+                        kind, _, uname = op.unit.partition(":")
+                        if kind == "bucket":
+                            b = bucket_by_key[uname]
+                            gin = {n: g[n] for n in b.var_names}
+                            gin, token = collectives.barrier_chain(gin, token)
+                            out = _run_bucket(b, gin)
+                        elif kind == "zero":
+                            (gin,), token = collectives.barrier_chain(
+                                (g[uname],), token)
+                            out = _run_zero(uname, gin)
+                        else:
+                            (gin,), token = collectives.barrier_chain(
+                                (g[uname],), token)
+                            out = _run_var(uname, gin)
+                        token = collectives.overlap_token(out)
+                else:
+                    # epilogue lowering: ZeRO reduce-scatters, then concat
+                    # buckets, then per-var syncs — one contiguous block after
+                    # the full backward (the pre-overlap baseline, and the
+                    # N == 1 / overlap-off path)
+                    for n in sorted(zero_names):
+                        _run_zero(n, g[n])
+                    for b in (buckets if N > 1 else []):
+                        _run_bucket(b, g)
+                    for n in (syncs if N > 1 else ()):
+                        if n in bucketed_names or n in synced:
+                            continue
+                        _run_var(n, g[n])
+                # non-trainable vars: zero gradient so optimizer state stays
+                # clean and the value never moves; remaining unconfigured vars
+                # (shouldn't happen post-compile) get a plain mean-psum
+                for n in g_names:
+                    if n in synced or n in ps_names:
+                        continue
+                    if n in var_infos and not var_infos[n].trainable:
+                        synced[n] = jnp.zeros_like(g[n])
+                    else:
+                        synced[n] = psum(g[n]) / N
+
+            with sc.scope(sc.OPTIMIZER):
+                # device-side update covers only device-resident leaves (the
+                # holed structure); PS leaves update on the host, ZeRO-sharded
+                # leaves per-shard against sync_state['zero'] below
+                h_names, h_leaves, h_treedef = variable_utils.flatten_named(
+                    state.params)
+                grads_storage = variable_utils.unflatten_named(
+                    h_treedef, [synced[n] for n in h_names])
+                if zero_names:
+                    grads_basis = ps_lib.hole_like(zero_basis_template,
+                                                   grads_storage)
+                    params_basis = ps_lib.hole_like(zero_basis_template,
+                                                    state.params)
+                else:
+                    grads_basis, params_basis = grads_storage, state.params
+                updates, new_opt = optimizer.update(
+                    grads_basis, state.opt_state, params_basis)
+                lr_scale = (sync_state["sentinel"]["lr_scale"][0] if guard
+                            else None)
+                if guard:
+                    # sentinel escalation: effective-LR scale from sync_state
+                    # (local slice of the leading-device-axis layout) — the
+                    # zero deltas below scale pre-gather to the same value
+                    updates = jax.tree_util.tree_map(
+                        lambda u: (u * lr_scale).astype(u.dtype), updates)
+                new_zero_state = {}
+                if zero_names:
+                    # the sharded weight update: optimizer on the owned 1/P
+                    # shard only (per-var little trees, the SAME per-variable
+                    # apply shape the host-PS store runs), then all-gather the
+                    # UPDATE so every replica applies the identical delta to
+                    # its full-precision replicated param copy
+                    p_map = dict(zip(h_names, h_leaves))
+                    zstate = sync_state["zero"]
+                    zero_deltas = {}
+                    for n in sorted(zero_names):
+                        zs = zero_syncs[n]
+                        opt_local = jax.tree_util.tree_map(
+                            lambda a: a[0], zstate[n])
+                        upd, nopt = optimizer.update(
+                            {"v": synced[n]}, opt_local,
+                            {"v": zs.local_shard(p_map[n])})
+                        d = upd["v"]
+                        if lr_scale is not None:
+                            d = (d * lr_scale).astype(d.dtype)
+                        zero_deltas[n] = zs.gather_update(d)
+                        new_zero_state[n] = jax.tree_util.tree_map(
+                            lambda a: jnp.expand_dims(a, 0), nopt)
+                    updates = ps_lib.fill_holes(updates, zero_deltas)
+                # mask non-trainable updates (guards vs. weight decay etc.)
+                if frozen_names:
+                    u_names, u_leaves, u_treedef = \
+                        variable_utils.flatten_named(updates)
+                    u = [jnp.zeros_like(leaf) if n in frozen_names else leaf
+                         for n, leaf in zip(u_names, u_leaves)]
+                    updates = variable_utils.unflatten_named(u_treedef, u)
+                new_params = optax.apply_updates(state.params, updates)
 
             global_loss = jax.lax.pmean(loss, all_axes)
             metrics = {"loss": global_loss}
@@ -1836,8 +1858,9 @@ class GraphTransformer:
                 new_sync["zero"] = new_zero_state
             if guard:
                 new_sync["sentinel"] = sync_state["sentinel"]
-                verdict = _health_verdict(synced, ps_grads, new_params,
-                                          global_loss)
+                with sc.scope(sc.SENTINEL):
+                    verdict = _health_verdict(synced, ps_grads, new_params,
+                                              global_loss)
                 metrics["sentinel"] = verdict
                 # in-graph SKIP: a bad verdict discards the whole update —
                 # params, optimizer state and compressor residuals carry
@@ -1850,11 +1873,12 @@ class GraphTransformer:
                 def _sel(new, old):
                     return jax.tree_util.tree_map(
                         lambda a, b: jnp.where(okb, a, b), new, old)
-                new_params = _sel(new_params, state.params)
-                new_opt = _sel(new_opt, state.opt_state)
-                new_sync = _sel(new_sync, dict(state.sync_state)
-                                if isinstance(state.sync_state, dict)
-                                else state.sync_state)
+                with sc.scope(sc.SENTINEL):
+                    new_params = _sel(new_params, state.params)
+                    new_opt = _sel(new_opt, state.opt_state)
+                    new_sync = _sel(new_sync, dict(state.sync_state)
+                                    if isinstance(state.sync_state, dict)
+                                    else state.sync_state)
             new_state = TrainState(step=state.step + 1, params=new_params,
                                    opt_state=new_opt, sync_state=new_sync)
             return new_state, ps_grads, metrics
@@ -1905,13 +1929,9 @@ class GraphTransformer:
         # forward-only metrics (Runner.evaluate): same param gather, no
         # grad/optimizer/collective-sync cost
         def local_eval(state: TrainState, ps_vals, batch):
-            ps_vals = _ps_dewire(ps_vals)
-            gathered = _tree_map_layouts(
-                lambda leaf, lay: lay.gather_full(leaf), state.params,
-                layout_tree)
-            full_params = (ps_lib.fill_holes(gathered, ps_vals)
-                           if ps_names else gathered)
-            out = loss_fn_cd(full_params, batch)
+            full_params = _full_params(state, ps_vals)
+            with sc.scope(sc.LOSS):
+                out = loss_fn_cd(full_params, batch)
             loss, aux = (out if has_aux else (out, None))
             metrics = {"loss": jax.lax.pmean(loss, all_axes)}
             if aux is not None:
@@ -1995,13 +2015,9 @@ class GraphTransformer:
                                                      flat_specs)
 
             def local_predict(state: TrainState, ps_vals, batch):
-                ps_vals = _ps_dewire(ps_vals)
-                gathered = _tree_map_layouts(
-                    lambda leaf, lay: lay.gather_full(leaf), state.params,
-                    layout_tree)
-                full_params = (ps_lib.fill_holes(gathered, ps_vals)
-                               if ps_names else gathered)
-                out = serve_fn(full_params, batch)
+                full_params = _full_params(state, ps_vals)
+                with sc.scope(sc.PREFILL):
+                    out = serve_fn(full_params, batch)
                 if N > 1:
                     # non-batch (replicated-spec) leaves must actually BE
                     # replicated on exit: reduce them the way eval
@@ -2083,13 +2099,9 @@ class GraphTransformer:
                                                      flat_specs)
 
             def local_decode(state: TrainState, ps_vals, dstate):
-                ps_vals = _ps_dewire(ps_vals)
-                gathered = _tree_map_layouts(
-                    lambda leaf, lay: lay.gather_full(leaf), state.params,
-                    layout_tree)
-                full_params = (ps_lib.fill_holes(gathered, ps_vals)
-                               if ps_names else gathered)
-                out = decode_fn(full_params, dstate)
+                full_params = _full_params(state, ps_vals)
+                with sc.scope(sc.DECODE):
+                    out = decode_fn(full_params, dstate)
                 if N > 1:
                     leaves = out_treedef.flatten_up_to(out)
                     leaves = [
@@ -2189,8 +2201,9 @@ class GraphTransformer:
                 if ps_names:
                     scale = (st.sync_state["sentinel"]["lr_scale"][0]
                              if guard else None)
-                    new_vals, new_opts = _ps_apply_device(vals, opts,
-                                                          ps_grads, scale)
+                    with sc.scope(sc.OPTIMIZER):
+                        new_vals, new_opts = _ps_apply_device(
+                            vals, opts, ps_grads, scale)
                     if guard:
                         # the microstep's verdict gates the device-
                         # emulated PS apply exactly like it gates the

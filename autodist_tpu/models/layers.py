@@ -11,6 +11,8 @@ import flax.linen as nn
 import jax.numpy as jnp
 import numpy as np
 
+from autodist_tpu.telemetry import scopes
+
 Dtype = Any
 
 
@@ -128,11 +130,12 @@ class TransformerBlock(nn.Module):
                  cursor=None, alive=None, return_kv=False):
         kv = None
         h = nn.LayerNorm(dtype=self.dtype)(x)
-        h = MultiHeadAttention(self.num_heads, self.head_dim, self.dtype,
-                               self.attn_fn,
-                               decode_attn=self.decode_attn)(
-            h, mask, cache=cache, cursor=cursor, alive=alive,
-            return_kv=return_kv)
+        with scopes.scope(scopes.ATTENTION):
+            h = MultiHeadAttention(self.num_heads, self.head_dim, self.dtype,
+                                   self.attn_fn,
+                                   decode_attn=self.decode_attn)(
+                h, mask, cache=cache, cursor=cursor, alive=alive,
+                return_kv=return_kv)
         if cache is not None or return_kv:
             h, kv = h
         if self.dropout_rate:

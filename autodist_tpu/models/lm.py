@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from autodist_tpu.models.layers import TransformerBlock, causal_mask, SparseEmbed
+from autodist_tpu.telemetry import scopes
 
 
 @dataclasses.dataclass
@@ -58,24 +59,26 @@ class TransformerLM(nn.Module):
         cfg = self.config
         seq_len = input_ids.shape[-1]  # LOCAL length under seq sharding
         # untied lm_head -> the token table can ride the sparse wire
-        x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                        name="embed")(input_ids)
-        x = x * np.sqrt(cfg.d_model)
-        positions = jnp.arange(seq_len)
-        if self.seq_parallel:
-            from autodist_tpu.parallel import sequence
-            positions = positions + sequence.position_offset(seq_len)
-        pos = SparseEmbed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
-                          name="pos_embed")(positions[None])
-        x = x + pos
+        with scopes.scope(scopes.EMBED):
+            x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+                            name="embed")(input_ids)
+            x = x * np.sqrt(cfg.d_model)
+            positions = jnp.arange(seq_len)
+            if self.seq_parallel:
+                from autodist_tpu.parallel import sequence
+                positions = positions + sequence.position_offset(seq_len)
+            pos = SparseEmbed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
+                              name="pos_embed")(positions[None])
+            x = x + pos
         # with an injected SP attention the causal structure is handled
         # inside the op; the local mask would be wrong and is skipped
         mask = None if self.attn_fn is not None else causal_mask(seq_len)
-        for i in range(cfg.num_layers):
-            x = TransformerBlock(cfg.num_heads, cfg.d_model // cfg.num_heads,
-                                 cfg.mlp_dim, dtype=cfg.dtype,
-                                 attn_fn=self.attn_fn,
-                                 name="layer_%d" % i)(x, mask)
+        with scopes.scope(scopes.BLOCKS):
+            for i in range(cfg.num_layers):
+                x = TransformerBlock(
+                    cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
+                    dtype=cfg.dtype, attn_fn=self.attn_fn,
+                    name="layer_%d" % i)(x, mask)
         return nn.LayerNorm(dtype=cfg.dtype, name="final_ln")(x)
 
     @nn.compact
@@ -100,24 +103,27 @@ class TransformerLM(nn.Module):
         parameters resolve unchanged."""
         cfg = self.config
         seq_len = input_ids.shape[-1]
-        x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                        name="embed")(input_ids)
-        x = x * np.sqrt(cfg.d_model)
-        positions = jnp.arange(seq_len)
-        pos = SparseEmbed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
-                          name="pos_embed")(positions[None])
-        x = x + pos
+        with scopes.scope(scopes.EMBED):
+            x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+                            name="embed")(input_ids)
+            x = x * np.sqrt(cfg.d_model)
+            positions = jnp.arange(seq_len)
+            pos = SparseEmbed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
+                              name="pos_embed")(positions[None])
+            x = x + pos
         mask = None if self.attn_fn is not None else causal_mask(seq_len)
         ks, vs = [], []
-        for i in range(cfg.num_layers):
-            x, (k, v) = TransformerBlock(
-                cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
-                dtype=cfg.dtype, attn_fn=self.attn_fn,
-                decode_attn=self.decode_attn,
-                name="layer_%d" % i)(x, mask, return_kv=True)
-            pad = [(0, 0), (0, cfg.max_seq_len - seq_len), (0, 0), (0, 0)]
-            ks.append(jnp.pad(k, pad))
-            vs.append(jnp.pad(v, pad))
+        with scopes.scope(scopes.BLOCKS):
+            for i in range(cfg.num_layers):
+                x, (k, v) = TransformerBlock(
+                    cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
+                    dtype=cfg.dtype, attn_fn=self.attn_fn,
+                    decode_attn=self.decode_attn,
+                    name="layer_%d" % i)(x, mask, return_kv=True)
+                pad = [(0, 0), (0, cfg.max_seq_len - seq_len), (0, 0),
+                       (0, 0)]
+                ks.append(jnp.pad(k, pad))
+                vs.append(jnp.pad(v, pad))
         x = nn.LayerNorm(dtype=cfg.dtype, name="final_ln")(x)
         idx = jnp.clip(length - 1, 0, seq_len - 1)
         last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
@@ -134,24 +140,26 @@ class TransformerLM(nn.Module):
         logits [B, vocab] and the updated caches. Fixed shapes for any
         slot occupancy — the zero-recompile decode contract."""
         cfg = self.config
-        x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                        name="embed")(token_ids[:, None])
-        x = x * np.sqrt(cfg.d_model)
-        pos_idx = jnp.clip(cursor, 0, cfg.max_seq_len - 1)
-        pos = SparseEmbed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
-                          name="pos_embed")(pos_idx[:, None])
-        x = x + pos
+        with scopes.scope(scopes.EMBED):
+            x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+                            name="embed")(token_ids[:, None])
+            x = x * np.sqrt(cfg.d_model)
+            pos_idx = jnp.clip(cursor, 0, cfg.max_seq_len - 1)
+            pos = SparseEmbed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
+                              name="pos_embed")(pos_idx[:, None])
+            x = x + pos
         new_ks, new_vs = [], []
-        for i in range(cfg.num_layers):
-            x, (k, v) = TransformerBlock(
-                cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
-                dtype=cfg.dtype, attn_fn=None,
-                decode_attn=self.decode_attn,
-                name="layer_%d" % i)(
-                x, cache=(k_cache[:, i], v_cache[:, i]),
-                cursor=cursor, alive=alive)
-            new_ks.append(k)
-            new_vs.append(v)
+        with scopes.scope(scopes.BLOCKS):
+            for i in range(cfg.num_layers):
+                x, (k, v) = TransformerBlock(
+                    cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
+                    dtype=cfg.dtype, attn_fn=None,
+                    decode_attn=self.decode_attn,
+                    name="layer_%d" % i)(
+                    x, cache=(k_cache[:, i], v_cache[:, i]),
+                    cursor=cursor, alive=alive)
+                new_ks.append(k)
+                new_vs.append(v)
         x = nn.LayerNorm(dtype=cfg.dtype, name="final_ln")(x)
         logits = nn.Dense(cfg.vocab_size, dtype=jnp.float32,
                           name="lm_head")(x[:, 0])

@@ -26,6 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from autodist_tpu.telemetry import scopes
+
 NEG_INF = -1e30
 
 
@@ -72,6 +74,7 @@ def chunked_softmax_xent(x, w, b, targets, chunk=8192):
     return nll
 
 
+@scopes.scoped(scopes.LEAN_HEAD)
 def _xent_fwd_impl(x, w, b, targets, chunk):
     n, _d = x.shape
     v = w.shape[1]
@@ -111,6 +114,9 @@ def _xent_fwd(x, w, b, targets, chunk):
     return _xent_fwd_impl(x, w, b, targets, chunk)
 
 
+# a custom_vjp rule is traced at transpose time, outside the forward's
+# scopes (JAX's own wrapper leaves it a bare transpose(jvp())): name it
+@scopes.scoped(scopes.LEAN_HEAD_BWD)
 def _xent_bwd(chunk, res, g):
     """g: cotangent [N]. d_nll/d_logit = softmax - onehot(target); each
     chunk's logits are recomputed from the saved activations, and dW/db

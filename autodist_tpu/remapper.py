@@ -113,6 +113,23 @@ class Remapper:
             return self._place_leaf(leaf, spec)
         return jax.tree_util.tree_map_with_path(place, batch)
 
+    def feed_avals(self, batch, stack: int = 0) -> Any:
+        """Shapes, dtypes and shardings of ``batch`` as :meth:`remap_feed`
+        places it (``stack=k``: as :meth:`remap_feed_stack` places k of
+        them stacked) without placing anything: what lowering a program
+        for that feed needs."""
+        def aval(path, leaf):
+            shape = tuple(np.shape(leaf))
+            spec = self._leaf_spec(shape, self.num_replicas, "global",
+                                   _normalize_path(path))
+            if stack:
+                shape, spec = (stack,) + shape, P(None, *spec)
+            dtype = (leaf.dtype if hasattr(leaf, "dtype")
+                     else np.asarray(leaf).dtype)
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(self.mesh, spec))
+        return jax.tree_util.tree_map_with_path(aval, batch)
+
     def remap_feed_stack(self, stacked_batch) -> Any:
         """Place a STACKED ``[k, ...]`` batch for the fused multi-step
         engine: dim 0 is the microstep (scan) dim, kept unsharded; the

@@ -19,9 +19,13 @@ import numpy as np
 
 from autodist_tpu import const
 from autodist_tpu.remapper import Remapper
+from autodist_tpu.telemetry import scopes
 from autodist_tpu.telemetry import spans as tel
 from autodist_tpu.train_state import TrainState
 from autodist_tpu.utils import logging
+
+
+_NO_BATCH = object()  # next(it, _NO_BATCH): the source is exhausted
 
 
 class MetricsHandle:
@@ -33,14 +37,18 @@ class MetricsHandle:
     round-trips: handles accumulate device-side and one readback
     materializes many steps' metrics at a ``metrics_every`` boundary."""
 
-    __slots__ = ("_device", "_remapper", "_host", "microsteps", "_observer")
+    __slots__ = ("_device", "_remapper", "_host", "microsteps", "_observer",
+                 "step")
 
     def __init__(self, device_metrics, remapper, microsteps: int = 1,
-                 observer=None):
+                 observer=None, step: Optional[int] = None):
         self._device = device_metrics
         self._remapper = remapper
         self._host = None
         self.microsteps = microsteps
+        # index of the (first) microstep these metrics belong to: the
+        # readback's spans carry it, so a step's spans share it
+        self.step = step
         # called once per MICROSTEP (in order) when the handle
         # materializes — the sentinel's verdict intake; consumed on first
         # result() so re-reads never replay observations
@@ -55,8 +63,14 @@ class MetricsHandle:
         Superstep handles return stacked ``[k, ...]`` leaves."""
         if self._host is None:
             with tel.span("runner.readback", "runner",
-                          microsteps=self.microsteps):
-                self._host = self._remapper.remap_fetch(self._device)
+                          microsteps=self.microsteps, step=self.step):
+                # the wait for the step's outputs is the DEVICE's time
+                # (goodput: compute), the copy after it the host's
+                with tel.span("runner.wait_device", "runner",
+                              step=self.step):
+                    jax.block_until_ready(self._device)
+                with tel.span("runner.fetch", "runner", step=self.step):
+                    self._host = self._remapper.remap_fetch(self._device)
             self._device = None  # free the device buffers
             tel.counter_add("runner.readbacks")
             tel.counter_add("runner.d2h_bytes", sum(
@@ -229,6 +243,55 @@ class Runner:
         self._profile_done_seq = -1
         self._profile_poll_at = 0.0
         self._profile_coord = None  # lazily shares an existing client
+        self._fused_k = 0  # microsteps of the last fused superstep
+        self._register_programs()
+
+    # ------------------------------------------- programs, by module name
+
+    def _register_programs(self):
+        """Make the compiled programs inspectable by name
+        (``telemetry.scope_map``) under XLA's module name, ``jit_`` + the
+        jitted function's name. Nothing is lowered until someone asks."""
+        dstep = self._dstep
+        for fn, lower in ((dstep._step_fn, self._lower_step),
+                          (dstep._eval_fn, self._lower_eval)):
+            if getattr(fn, "__name__", None):
+                scopes.register_program("jit_" + fn.__name__, lower)
+        if dstep._fused_builder is not None:
+            scopes.register_program("jit_local_multi", self._lower_fused)
+
+    def _state_avals(self):
+        if self.state is None:
+            raise RuntimeError("no state yet: Runner.init() comes first")
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding), self.state)
+
+    def _lower_on_example(self, fn):
+        """``fn`` (state, ps values, batch) as this runner runs it: the
+        live state's shapes and shardings, the build's example batch
+        placed as ``remap_feed`` places one."""
+        ps_avals, _ = self._dstep._ps_avals()
+        return fn.lower(
+            self._state_avals(), ps_avals,
+            self._remapper.feed_avals(self._dstep.model_item.example_batch))
+
+    def _lower_step(self):
+        return self._lower_on_example(self._dstep._step_fn)
+
+    def _lower_eval(self):
+        return self._lower_on_example(self._dstep._eval_fn)
+
+    def _lower_fused(self):
+        """The fused k-microstep program, at the k of the last superstep."""
+        if not self._fused_k:
+            raise RuntimeError("no fused superstep has run yet")
+        ps_avals, opt_avals = self._dstep._ps_avals(with_opt=True,
+                                                    wire=False)
+        return self._dstep._fused_fn().lower(
+            self._state_avals(), ps_avals, opt_avals,
+            self._remapper.feed_avals(self._dstep.model_item.example_batch,
+                                      stack=self._fused_k))
 
     def _connect_coordination(self, purpose: str = "staleness pacing"):
         from autodist_tpu.runtime.coordination import CoordinationClient
@@ -545,6 +608,7 @@ class Runner:
                                   seq_keys=getattr(dstep, "seq_feed_keys",
                                                    None))
         self._staleness = int(dstep.metadata.get("staleness", 0))
+        self._register_programs()  # a map of the old programs is stale
 
     def _poll_epoch(self):
         """Readback-boundary membership poll (throttled to
@@ -720,15 +784,17 @@ class Runner:
         counted in MICROSTEPS, so a fused superstep advances the pacing
         protocol by its true k optimizer applies."""
         self._compile_grace_end()
+        step = self._step_count
         self._step_count += microsteps
         self._superstep_count += 1
         tel.counter_add("runner.steps", microsteps)
         tel.counter_add("runner.supersteps")
-        self._maybe_fleet_profile_stop()
-        self._poll_profile_window()
-        self._poll_epoch()
-        self._preempt.poll()
-        self._maybe_heartbeat()
+        with tel.span("runner.control", "runner", step=step):
+            self._maybe_fleet_profile_stop()
+            self._poll_profile_window()
+            self._poll_epoch()
+            self._preempt.poll()
+            self._maybe_heartbeat()
         if self._coord is not None:
             # bounded staleness across processes (the reference's size-s
             # token-queue semantics, ps_synchronizer.py:388-458): report our
@@ -840,9 +906,10 @@ class Runner:
         # merged cluster timeline: every worker's dispatch for microstep
         # N carries step=N, so Perfetto (and cluster.step_alignment)
         # lines the tracks up per STEP, not just per run
+        step = self._step_count
         with tel.span("runner.dispatch", "runner", microsteps=1, sync=sync,
-                      step=self._step_count):
-            with tel.span("runner.feed", "runner"):
+                      step=step):
+            with tel.span("runner.feed", "runner", step=step):
                 sharded_batch = self._remapper.remap_feed(batch)
             self._maybe_fleet_profile()
             self._start_trace_if_due()
@@ -850,13 +917,14 @@ class Runner:
             # donate only the Runner-owned state; an explicitly-passed state
             # is a caller reference that must stay valid
             new_state, metrics = self._dstep(st, sharded_batch,
-                                             donate=state is None)
+                                             donate=state is None, step=step)
             if state is None:
                 self.state = new_state
             self._after_dispatch(1)
             self._stop_trace_if_due(metrics)
             handle = MetricsHandle(metrics, self._remapper, microsteps=1,
-                                   observer=self._sentinel_observer())
+                                   observer=self._sentinel_observer(),
+                                   step=step)
             if sync:
                 # result() pulls the metrics to host, so the step's device
                 # work is complete: this wall time is an honest per-step
@@ -884,21 +952,24 @@ class Runner:
         if self.state is None:
             raise RuntimeError("Runner.run_superstep before init()")
         self._compile_grace_begin()
-        with tel.span("runner.feed", "runner", stacked=True):
+        step = self._step_count
+        with tel.span("runner.feed", "runner", stacked=True, step=step):
             placed = self._remapper.remap_feed_stack(stacked_batch)
         leaves = jax.tree_util.tree_leaves(placed)
-        k = int(np.shape(leaves[0])[0]) if leaves else 1
+        k = self._fused_k = int(np.shape(leaves[0])[0]) if leaves else 1
         with tel.span("runner.dispatch", "runner", microsteps=k, sync=sync,
-                      step=self._step_count):
+                      step=step):
             self._maybe_fleet_profile()
             self._start_trace_if_due()
             self._check_ps_owner_health()
-            new_state, metrics = self._dstep.run_multi(self.state, placed)
+            new_state, metrics = self._dstep.run_multi(self.state, placed,
+                                                       step=step)
             self.state = new_state
             self._after_dispatch(k)
             self._stop_trace_if_due(metrics)
             handle = MetricsHandle(metrics, self._remapper, microsteps=k,
-                                   observer=self._sentinel_observer())
+                                   observer=self._sentinel_observer(),
+                                   step=step)
             if sync:
                 handle.result()
             self._record_step_time(t_begin)
@@ -1395,13 +1466,24 @@ class Runner:
                                        saver, max(1, fuse_steps),
                                        max(1, metrics_every))
         history = []
-        bounded = batches if steps is None else itertools.islice(batches, steps)
+        it = iter(batches if steps is None
+                  else itertools.islice(batches, steps))
         try:
-            for i, batch in enumerate(bounded):
+            for i in itertools.count():
+                # the loop's own host work under names of its own: the
+                # source's next() (a DevicePrefetcher places the
+                # replacement batch inside it) and the callbacks
+                with tel.span("runner.next_batch", "runner",
+                              step=self._step_count):
+                    batch = next(it, _NO_BATCH)
+                if batch is _NO_BATCH:
+                    break
                 metrics = self.run(batch)
-                history.append(metrics)
-                for cb in (callbacks or ()):
-                    cb(i, metrics)
+                with tel.span("runner.callbacks", "runner",
+                              step=self._step_count - 1):
+                    history.append(metrics)
+                    for cb in (callbacks or ()):
+                        cb(i, metrics)
                 if save_every > 0 and (i + 1) % save_every == 0:
                     saver.save(self)
             # the LAST step's verdict may have pended a rollback; act
@@ -1433,17 +1515,26 @@ class Runner:
             # checkpoint/log writes) on the way out
             while pending:
                 handle = pending.pop(0)
-                for m in handle.unstack():
-                    idx = len(history)
-                    history.append(m)
-                    for cb in (callbacks or ()):
-                        cb(idx, m)
+                per_step = handle.unstack()
+                with tel.span("runner.callbacks", "runner",
+                              step=handle.step):
+                    for m in per_step:
+                        idx = len(history)
+                        history.append(m)
+                        for cb in (callbacks or ()):
+                            cb(idx, m)
 
         # a DevicePrefetcher in matching stack mode yields pre-stacked,
         # pre-placed [k, ...] feeds — consume them whole; any other source
         # yields plain batches that are grouped and stacked here
         pre_stacked = k > 1 and getattr(batches, "stack_k", 1) == k
         it = iter(batches)
+
+        def next_batch():
+            # raises StopIteration through the span, like next(it)
+            with tel.span("runner.next_batch", "runner",
+                          step=self._step_count):
+                return next(it)
         micro_done, last_save, supersteps = 0, 0, 0
         try:
             while steps is None or micro_done < steps:
@@ -1455,7 +1546,7 @@ class Runner:
                             "stopping at %d microsteps", steps, k, micro_done)
                         break
                     try:
-                        stacked = next(it)
+                        stacked = next_batch()
                     except StopIteration:
                         break
                     handles = [self.run_superstep(stacked, sync=False)]
@@ -1465,7 +1556,7 @@ class Runner:
                                               or micro_done + len(group)
                                               < steps):
                         try:
-                            group.append(next(it))
+                            group.append(next_batch())
                         except StopIteration:
                             break
                     if not group:

@@ -39,6 +39,7 @@ reads (``serving/autoscale.py``).
 """
 import collections
 import dataclasses
+import itertools
 import threading
 import time
 import weakref
@@ -50,6 +51,7 @@ import numpy as np
 from autodist_tpu import const
 from autodist_tpu.serving.engine import (InferenceEngine, ServingConfig,
                                          ServingUnavailable)
+from autodist_tpu.telemetry import scopes
 from autodist_tpu.telemetry import spans as tel
 from autodist_tpu.utils import logging
 
@@ -129,14 +131,24 @@ class DecodeConfig:
             raise ValueError("max_queue must be >= 1")
 
 
+_request_ids = itertools.count(1)
+
+
 class _Request:
-    __slots__ = ("prompt", "max_new", "future", "t0")
+    """One submitted prompt. ``rid`` names it in the ``serve.prefill`` /
+    ``serve.decode_step`` spans; the ``t_*`` stamps (``perf_counter``
+    seconds) go into its result: submitted, taken off the queue by an
+    admission group, first token on the host (the prefill emits it)."""
+    __slots__ = ("prompt", "max_new", "future", "rid", "t_submit",
+                 "t_admitted", "t_first_token")
 
     def __init__(self, prompt, max_new: int):
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
         self.max_new = int(max_new)
         self.future = Future()
-        self.t0 = time.perf_counter()
+        self.rid = next(_request_ids)
+        self.t_submit = time.perf_counter()
+        self.t_admitted = self.t_first_token = None
 
 
 class _Slot:
@@ -250,8 +262,9 @@ class DecodeEngine:
         shard = NamedSharding(self._dstep.mesh, P(self._dstep.batch_axes))
 
         def _insert(k, v, idx, pk, pv):
-            return (k.at[idx].set(pk, mode="drop"),
-                    v.at[idx].set(pv, mode="drop"))
+            with scopes.scope(scopes.INSERT):
+                return (k.at[idx].set(pk, mode="drop"),
+                        v.at[idx].set(pv, mode="drop"))
 
         self._insert_prog = jax.jit(_insert, donate_argnums=(0, 1),
                                     out_shardings=(shard, shard))
@@ -286,6 +299,38 @@ class DecodeEngine:
                                         daemon=True)
         self._worker.start()
         _ACTIVE.add(self)
+        # inspectable by module name (telemetry.scope_map), on demand
+        scopes.register_program("jit_" + self._decode_prog.fn.__name__,
+                                self._lower_decode)
+        scopes.register_program("jit__insert", self._lower_insert)
+
+    # ------------------------------------------- programs, by module name
+
+    def _cache_aval(self):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.ShapeDtypeStruct(
+            self._cache_shape, self._cache_dtype,
+            sharding=NamedSharding(self._dstep.mesh,
+                                   P(self._dstep.batch_axes)))
+
+    def _lower_decode(self):
+        """The decode step as steady state runs it: committed device
+        caches, host-fed per-slot vectors."""
+        cache = self._cache_aval()
+        ps_avals, _ = self._dstep._ps_avals()
+        dstate = {"k": cache, "v": cache, "token": self._token,
+                  "cursor": self._cursor, "alive": self._alive}
+        return self._decode_prog.fn.lower(self._runner._state_avals(),
+                                          ps_avals, dstate)
+
+    def _lower_insert(self):
+        import jax
+        cache = self._cache_aval()
+        # the admitted rows and their slots arrive as host arrays
+        rows = jax.ShapeDtypeStruct(self._cache_shape, self._cache_dtype)
+        idx = jax.ShapeDtypeStruct((self.config.slots,), np.int32)
+        return self._insert_prog.lower(cache, cache, idx, rows, rows)
 
     # ----------------------------------------------------------- lint
 
@@ -357,7 +402,10 @@ class DecodeEngine:
     def submit(self, prompt, max_new_tokens: Optional[int] = None) -> Future:
         """Enqueue one prompt (1-D int token ids); resolves to
         ``{"tokens": generated ids (int32, EOS included when hit),
-        "prompt_len": int, "finished": "eos"|"length"}``. Sheds typed
+        "prompt_len": int, "finished": "eos"|"length", "t_submit",
+        "t_admitted", "t_first_token", "t_done"}`` — the four stamps on
+        the ``time.perf_counter`` clock: submitted, taken off the queue,
+        first token on the host (the prefill emits it), resolved. Sheds typed
         with :class:`ServingUnavailable` (Retry-After from the measured
         completion rate) when the queue is full or the engine is
         draining. Prompts longer than ``prefill_len`` are rejected —
@@ -457,40 +505,52 @@ class DecodeEngine:
         scatter the caches into freed slots (in-flight batching: live
         slots keep decoding across this boundary untouched)."""
         cfg = self.config
+        now = time.perf_counter()
+        rids = [r.rid for r in group]
         feeds = []
         for r in group:
+            r.t_admitted = now
             toks = np.zeros(cfg.prefill_len, np.int32)
             toks[:r.prompt.shape[0]] = r.prompt
             feeds.append({"tokens": toks,
                           "length": np.asarray(r.prompt.shape[0], np.int32)})
-        with tel.span("serve.prefill", "serve", n=len(group)):
+        # holds the bucket's serve.dispatch and serve.readback (the
+        # prefilled K/V rows coming back to the host)
+        with tel.span("serve.prefill", "serve", n=len(group), rids=rids):
             fetched, n = self._prefill.run_batch(feeds)
-        idx = np.full(cfg.slots, cfg.slots, np.int32)  # OOB rows drop
-        pk = np.zeros(self._cache_shape, self._cache_dtype)
-        pv = np.zeros(self._cache_shape, self._cache_dtype)
+        now = time.perf_counter()  # the prefill's tokens are on the host
+        for r in group:
+            r.t_first_token = now
         free = self.scheduler.free_slots()
         admitted = 0
-        for j, r in enumerate(group):
-            first = int(np.asarray(fetched["next_token"])[j])
-            plen = r.prompt.shape[0]
-            slot = _Slot(r, first)
-            # a request satisfied by its prefill alone (cap of 1, or EOS
-            # first token) never occupies a slot
-            done = self._finished(slot, plen)
-            if done:
-                self._resolve(slot, plen, done)
-            else:
-                s = free[admitted]
-                idx[admitted] = s
-                pk[admitted] = np.asarray(fetched["k"])[j]
-                pv[admitted] = np.asarray(fetched["v"])[j]
-                self.scheduler.occupy(s, slot)
-                self._token[s] = first
-                self._cursor[s] = plen
-                self._alive[s] = True
-                admitted += 1
+        # the host's copy of each admitted row into two fresh
+        # cache-shaped arrays (what the insert program takes)
+        with tel.span("serve.admit_copy", "serve", n=len(group), rids=rids):
+            idx = np.full(cfg.slots, cfg.slots, np.int32)  # OOB rows drop
+            pk = np.zeros(self._cache_shape, self._cache_dtype)
+            pv = np.zeros(self._cache_shape, self._cache_dtype)
+            for j, r in enumerate(group):
+                first = int(np.asarray(fetched["next_token"])[j])
+                plen = r.prompt.shape[0]
+                slot = _Slot(r, first)
+                # a request satisfied by its prefill alone (cap of 1, or
+                # EOS first token) never occupies a slot
+                done = self._finished(slot, plen)
+                if done:
+                    self._resolve(slot, plen, done)
+                else:
+                    s = free[admitted]
+                    idx[admitted] = s
+                    pk[admitted] = np.asarray(fetched["k"])[j]
+                    pv[admitted] = np.asarray(fetched["v"])[j]
+                    self.scheduler.occupy(s, slot)
+                    self._token[s] = first
+                    self._cursor[s] = plen
+                    self._alive[s] = True
+                    admitted += 1
         if admitted:
-            self._dispatch_insert(idx, pk, pv)
+            with tel.span("serve.insert", "serve", n=admitted, rids=rids):
+                self._dispatch_insert(idx, pk, pv)
         self.stats_local["prefill_admits"] += len(group)
         tel.counter_add("serve.prefill_admits", len(group))
         # every prefill emits each request's first token
@@ -516,14 +576,17 @@ class DecodeEngine:
         return None
 
     def _resolve(self, slot: _Slot, prompt_len: int, finished: str):
-        slot.req.future.set_result({
+        now = time.perf_counter()
+        req = slot.req
+        req.future.set_result({
             "tokens": np.asarray(slot.generated, np.int32),
             "prompt_len": int(prompt_len),
-            "finished": finished})
+            "finished": finished,
+            "t_submit": req.t_submit, "t_admitted": req.t_admitted,
+            "t_first_token": req.t_first_token, "t_done": now})
         self.stats_local["evictions"] += 1
         self.stats_local["completed"] += 1
         tel.counter_add("serve.evictions")
-        now = time.perf_counter()
         if self._last_complete_t is not None:
             dt = now - self._last_complete_t
             if dt > 0:
@@ -556,8 +619,11 @@ class DecodeEngine:
 
     def _step(self):
         live = self.scheduler.live_slots()
+        rids = ([self.scheduler.get(s).req.rid for s in live]
+                if tel.tracing_enabled() else None)
         t0 = time.perf_counter()
-        with tel.span("serve.decode_step", "serve", live=len(live)):
+        with tel.span("serve.decode_step", "serve", live=len(live),
+                      rids=rids):
             next_tok = self._dispatch_step()
         step_ms = (time.perf_counter() - t0) * 1e3
         # the step's wall time IS each live slot's per-token latency

@@ -36,6 +36,7 @@ import numpy as np
 
 from autodist_tpu import const
 from autodist_tpu.data.prefetch import stack_batches
+from autodist_tpu.telemetry import scopes
 from autodist_tpu.telemetry import spans as tel
 from autodist_tpu.utils import logging
 
@@ -146,6 +147,19 @@ class InferenceEngine:
                       "snapshot_refreshes": 0}
         self._warmed = False
         self._cache_size_after_warmup = None
+        # inspectable by module name (telemetry.scope_map), on demand
+        scopes.register_program("jit_" + self._program.fn.__name__,
+                                self._lower_largest_bucket)
+
+    def _lower_largest_bucket(self):
+        """The forward program at its largest bucket (one module name
+        covers every bucket; the others differ in shapes only)."""
+        ps_avals, _ = self._dstep._ps_avals()
+        feed = stack_batches([self._example_request],
+                             pad_to=self.buckets[-1])
+        return self._program.fn.lower(
+            self._runner._state_avals(), ps_avals,
+            self._runner.remapper.feed_avals(feed))
 
     @staticmethod
     def _resolve_buckets(buckets, replicas: int) -> Tuple[int, ...]:

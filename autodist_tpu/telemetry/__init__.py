@@ -6,6 +6,10 @@ The runtime observability layer (docs/observability.md):
   :class:`TraceRecorder` and the ``span()``/``counter_add()`` helpers the
   framework's hot paths are instrumented with (near-zero cost when
   ``ADT_TRACE=0``);
+- :mod:`~autodist_tpu.telemetry.scopes` — the one table of
+  ``jax.named_scope`` names inside the compiled programs, and
+  :func:`scope_map`: HLO instruction name → ``op_name`` of a running
+  program, by its XLA module name (computed on demand);
 - :mod:`~autodist_tpu.telemetry.export` — Chrome-trace/Perfetto JSON,
   Prometheus ``metrics_text()``, and cross-process publish/scrape over
   the coordination service;
@@ -27,6 +31,8 @@ The runtime observability layer (docs/observability.md):
 from autodist_tpu.telemetry.spans import (  # noqa: F401
     TraceRecorder, configure, counter_add, counters, current_span_id,
     gauge_set, get_recorder, instant, reset, span, tracing_enabled)
+from autodist_tpu.telemetry.scopes import (  # noqa: F401
+    SCOPES, register_program, registered_programs, scope, scope_map)
 from autodist_tpu.telemetry.export import (  # noqa: F401
     chrome_trace, merge_traces, metrics_text, publish_telemetry,
     scrape_cluster, validate_chrome_trace, write_trace)
@@ -46,6 +52,8 @@ __all__ = [
     "TraceRecorder", "configure", "counter_add", "counters",
     "current_span_id", "gauge_set", "get_recorder", "instant", "reset",
     "span", "tracing_enabled",
+    "SCOPES", "register_program", "registered_programs", "scope",
+    "scope_map",
     "chrome_trace", "merge_traces", "metrics_text", "publish_telemetry",
     "scrape_cluster", "validate_chrome_trace", "write_trace",
     "DriftReport", "build_report", "fit_calibration", "report_for_runner",

@@ -8,22 +8,32 @@ attributed buckets by walking the recorded span tree —
 ==================  ====================================================
 bucket              spans whose SELF time it aggregates
 ==================  ====================================================
-``compute``         ``runner.dispatch`` / ``dstep.dispatch`` self time
-                    (the jitted program, minus everything nested below)
+``compute``         ``runner.wait_device`` (the host waiting for the
+                    step's outputs: where the device's time shows on a
+                    backend that dispatches asynchronously) and
+                    ``runner.dispatch`` / ``dstep.dispatch`` self time
+                    (the jitted call: the whole program on a backend
+                    that runs it synchronously, the enqueue otherwise)
 ``collective_wait`` ``runner.barrier`` (staleness pacing / lockstep
                     waits), ``coord.backoff`` (control-plane retries)
 ``ps_wire``         ``ps.pull``/``ps.push``/``ps.apply``/``ps.absorb``,
                     ``dstep.pull_ps``/``dstep.flush_ps``
 ``host_input``      ``runner.feed`` (host→device batch placement),
-                    ``prefetch.place``
-``readback``        ``runner.readback`` (device→host metrics)
+                    ``prefetch.place``, ``runner.next_batch`` (the
+                    source's ``next()`` around the placement)
+``readback``        ``runner.fetch`` (device→host copy of the metrics)
+                    and ``runner.readback``'s own time around its two
+                    children (the wait is ``compute``)
+``host_loop``       ``runner.callbacks`` (user callbacks + history) and
+                    ``runner.control`` (heartbeat, epoch, preemption and
+                    profile-window polls after a dispatch)
 ``checkpoint``      every ``ckpt`` category span on the training thread
                     (async writer-thread time overlaps compute and is
                     deliberately NOT charged against the wall)
 ``rollback_replay`` ``sentinel.rollback`` self time (the restore's own
                     ckpt spans land in ``checkpoint``)
-``other``           everything else (fit-loop bookkeeping, spans this
-                    table does not know)
+``other``           everything else (``runner.fit``'s remaining own
+                    time, spans this table does not know)
 ==================  ====================================================
 
 **Self time** is a span's duration minus its same-thread children's, so
@@ -53,17 +63,21 @@ from autodist_tpu import const
 from autodist_tpu.telemetry import spans as spans_lib
 
 BUCKETS = ("compute", "collective_wait", "ps_wire", "host_input",
-           "readback", "checkpoint", "rollback_replay", "other")
+           "readback", "host_loop", "checkpoint", "rollback_replay",
+           "other")
 
 _SPAN_BUCKET = {
     "runner.dispatch": "compute", "dstep.dispatch": "compute",
+    "runner.wait_device": "compute",
     "runner.barrier": "collective_wait", "coord.backoff": "collective_wait",
     "ps.pull": "ps_wire", "ps.push": "ps_wire", "ps.apply": "ps_wire",
     "ps.absorb": "ps_wire", "dstep.pull_ps": "ps_wire",
     "dstep.flush_ps": "ps_wire",
     "ps_service.publish": "ps_wire", "ps_service.apply": "ps_wire",
     "runner.feed": "host_input", "prefetch.place": "host_input",
-    "runner.readback": "readback",
+    "runner.next_batch": "host_input",
+    "runner.readback": "readback", "runner.fetch": "readback",
+    "runner.callbacks": "host_loop", "runner.control": "host_loop",
     "sentinel.rollback": "rollback_replay",
 }
 _CAT_BUCKET = {"ckpt": "checkpoint"}

@@ -1,0 +1,230 @@
+"""Names inside the compiled programs, and the way back from a device
+trace to them.
+
+Host spans (:mod:`~autodist_tpu.telemetry.spans`) say what the host did;
+this module is the device-side half. Two pieces:
+
+- **One table of scope names** (:data:`SCOPES`) and :func:`scope`, the
+  only place in the package that calls ``jax.named_scope``. A scope is
+  HLO metadata: it names the ``op_name`` of every instruction traced
+  under it and costs the device nothing, so scopes are always on. JAX
+  wraps the scopes of a differentiated function itself: forward ops of
+  the loss read ``jvp(loss)/...``, backward ops
+  ``transpose(jvp(loss))/...``, recomputed ones carry
+  ``rematted_computation``.
+- **The map from HLO instruction name to ``op_name``**
+  (:func:`scope_map`). A profiler's device events carry only the
+  instruction's name (``fusion.37``); the compiled module's text says
+  which source scope each instruction came from. The owners of compiled
+  programs (``Runner``, ``DecodeEngine``) register how to lower each of
+  theirs under the XLA module's name; the map is computed ON DEMAND —
+  one extra lowering and compile, with both compile caches bypassed —
+  and never on a step's path.
+
+Why the caches are bypassed: ``jax_compilation_cache_include_metadata_in_key``
+is False, so the persistent cache hands a process an executable that an
+earlier process (another checkout of this tree, say) compiled, whose text
+carries THAT process's ``op_name``s; and JAX's in-memory cache answers a
+compile of what the jit already ran with that same executable.
+Instruction names do not depend on metadata, so a fresh compile of this
+tree's lowering names the same instructions the running executable has,
+with this tree's scopes.
+"""
+import contextlib
+import functools
+import re
+import threading
+import weakref
+from typing import Callable, Dict, List, Optional
+
+# ------------------------------------------------------------ scope table
+#
+# name -> what is traced under it. Call sites use the constants, so a
+# grep for a scope finds its sites and a typo is an AttributeError.
+
+PARAMS = "params"
+LOSS = "loss"
+GRAD_SYNC = "grad_sync"
+OPTIMIZER = "optimizer"
+SENTINEL = "sentinel"
+LEAN_HEAD = "lean_head"
+LEAN_HEAD_BWD = "lean_head_bwd"
+EMBED = "embed"
+BLOCKS = "blocks"
+ATTENTION = "attention"
+PREFILL = "prefill"
+DECODE = "decode"
+INSERT = "insert"
+
+SCOPES: Dict[str, str] = {
+    PARAMS: "parameter gather into the full layout and the host-PS "
+            "de-wire, at the top of every compiled program",
+    LOSS: "the user's loss; under value_and_grad JAX writes jvp(loss) on "
+          "its forward ops and transpose(jvp(loss)) on its backward ops",
+    GRAD_SYNC: "everything between the raw gradients and the synced ones: "
+               "buckets, per-variable syncs, ZeRO reduce-scatters, the "
+               "sparse wire, host-PS gradient reduction, casts and scaling",
+    OPTIMIZER: "optimizer update and apply (ZeRO shard update and its "
+               "all-gather included)",
+    SENTINEL: "the health sentinel's in-graph verdict and select",
+    LEAN_HEAD: "ops/xent.py chunked head, forward (and the part of its "
+               "backward JAX derives itself)",
+    LEAN_HEAD_BWD: "ops/xent.py chunked head, the custom_vjp backward rule",
+    EMBED: "token and position embedding of the LM",
+    BLOCKS: "the transformer blocks of the LM",
+    ATTENTION: "multi-head attention inside a block",
+    PREFILL: "serving: the prompt pass of a prefill bucket",
+    DECODE: "serving: one cached decode step",
+    INSERT: "serving: writing admitted rows into the decode state",
+}
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of the table."""
+    if name not in SCOPES:
+        raise KeyError("scope %r is not in telemetry.scopes.SCOPES" % name)
+    import jax
+    return jax.named_scope(name)
+
+
+def scoped(name: str):
+    """Decorator: trace the whole function under ``scope(name)``."""
+    def wrap(f):
+        @functools.wraps(f)
+        def under_scope(*args, **kwargs):
+            with scope(name):
+                return f(*args, **kwargs)
+        return under_scope
+    return wrap
+
+
+# ------------------------------------------------------- program registry
+#
+# XLA module name -> how to lower that program again. Owners register a
+# bound method weakly (the owner holds device state; the registry must
+# not keep it alive), the newest registration of a name wins.
+
+_programs: Dict[str, Callable[[], Optional[Callable]]] = {}
+_maps: Dict[str, Dict[str, List[str]]] = {}
+_lock = threading.Lock()
+
+
+def register_program(module_name: str, lower: Callable) -> None:
+    """Make ``module_name`` (``"jit_local_step"``) inspectable.
+
+    ``lower()`` returns the program's ``jax.stages.Lowered`` for the
+    arguments it runs with; it is called only by :func:`scope_map`.
+    A bound method is held weakly."""
+    ref = (weakref.WeakMethod(lower) if hasattr(lower, "__self__")
+           else (lambda: lower))
+    with _lock:
+        _programs[module_name] = ref
+        _maps.pop(module_name, None)
+
+
+def registered_programs() -> List[str]:
+    with _lock:
+        return sorted(n for n, ref in _programs.items() if ref() is not None)
+
+
+# ``lowered.compile()`` of a lowering the jit already ran is answered from
+# JAX's in-memory cache with the RUNNING executable (which may be one the
+# persistent cache loaded, with another process's metadata). Compiler
+# options are part of that cache's key: one debug option, set to its
+# default, asks for a compile of its own and changes nothing in it.
+_FRESH_COMPILE = {"xla_dump_disable_metadata": False}
+
+
+@contextlib.contextmanager
+def _persistent_cache_bypassed():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()  # the decision to use the cache is memoised
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        cc.reset_cache()
+
+
+def compiled_text(module_name: str) -> Optional[str]:
+    """Optimized HLO text of a registered program, from a compile of
+    THIS process's lowering with the persistent cache bypassed; None
+    where no live owner registered the name."""
+    with _lock:
+        ref = _programs.get(module_name)
+    lower = ref() if ref is not None else None
+    if lower is None:
+        return None
+    with _persistent_cache_bypassed():
+        return lower().compile(compiler_options=_FRESH_COMPILE).as_text()
+
+
+def scope_map(module_name: str) -> Optional[Dict[str, List[str]]]:
+    """``{HLO instruction name: [op_name, ...]}`` of a registered program
+    (see :func:`parse_scope_map` for the value), computed on first use
+    and kept; None where the program is not registered."""
+    with _lock:
+        got = _maps.get(module_name)
+    if got is not None:
+        return got
+    text = compiled_text(module_name)
+    if text is None:
+        return None
+    got = parse_scope_map(text)
+    with _lock:
+        _maps[module_name] = got
+    return got
+
+
+# ------------------------------------------------------------ HLO parsing
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_FUSION_CALLS = re.compile(r"\sfusion\(.*\bcalls=%?([\w.\-]+)")
+
+
+def parse_scope_map(hlo_text: str) -> Dict[str, List[str]]:
+    """Raw ``op_name`` metadata per instruction of an optimized HLO module.
+
+    The value is a list of strings exactly as the compiler printed them:
+    for a plain instruction its own ``op_name`` (an empty list where it
+    has none: parameters, compiler-made copies); for a FUSION its own
+    ``op_name`` first (``""`` where it has none) and then the ``op_name``
+    of every fused instruction that carries one. A fusion's own metadata
+    is its root's; its members routinely come from several scopes (the
+    last backward op with the optimizer's update), and which of them
+    names the fusion is the reader's rule, not this module's: the strings
+    are the program's, a classification is the consumer's. Instructions
+    of while bodies, branches and called computations are separate device
+    events and separate entries."""
+    members: Dict[str, List[str]] = {}   # computation -> members' op_names
+    own: Dict[str, Optional[str]] = {}
+    calls: Dict[str, str] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        if not line.startswith((" ", "\t")):
+            m = _COMPUTATION.match(line)
+            current = m.group(1) if m else None
+            if current is not None:
+                members[current] = []
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None or current is None:
+            continue
+        name = m.group(1)
+        op = _OP_NAME.search(line)
+        own[name] = op.group(1) if op else None
+        if op:
+            members[current].append(op.group(1))
+        fused = _FUSION_CALLS.search(line)
+        if fused:
+            calls[name] = fused.group(1)
+    # (a fused computation's members are no device events of their own;
+    # their entries are harmless: names are unique module-wide)
+    return {name: ([op or ""] + members.get(calls[name], [])
+                   if name in calls else [op] if op else [])
+            for name, op in own.items()}

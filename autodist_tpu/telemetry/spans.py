@@ -62,10 +62,25 @@ class SpanEvent:
                 % (self.cat, self.name, self.span_id, self.dur_ns / 1e6))
 
 
+def _trace_annotation(name, args):
+    """``jax.profiler.TraceAnnotation`` twin of a live span: inside any
+    ``jax.profiler`` session (the Runner's first-step and fleet windows,
+    a benchmark's trace) the host span lands in the profiler's own file,
+    on the clock the device ops are on. Outside a session it is one
+    flag check in the profiler's C++ side. Scalar args ride along
+    (``step=`` lines a step's spans up); containers stay in the ring."""
+    from jax.profiler import TraceAnnotation
+    if args:
+        args = {k: v for k, v in args.items()
+                if isinstance(v, (bool, int, float, str))}
+    return TraceAnnotation(name, **(args or {}))
+
+
 class _Span:
     """Live (entered) span — the enabled-path context manager."""
 
-    __slots__ = ("_rec", "name", "cat", "args", "_t0", "id", "_parent")
+    __slots__ = ("_rec", "name", "cat", "args", "_t0", "id", "_parent",
+                 "_ann")
 
     def __init__(self, rec, name, cat, args):
         self._rec = rec
@@ -79,11 +94,14 @@ class _Span:
         stack = rec._span_stack()
         self._parent = stack[-1] if stack else 0
         stack.append(self.id)
+        self._ann = _trace_annotation(self.name, self.args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(exc_type, exc, tb)
         rec = self._rec
         stack = rec._span_stack()
         if stack and stack[-1] == self.id:

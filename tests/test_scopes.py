@@ -1,0 +1,466 @@
+"""Names inside the compiled programs and the host's spans around them.
+
+The tracing contracts (``telemetry/scopes.py``, docs/observability.md
+"Scopes inside the compiled programs"):
+
+- the compiled step's instruction -> ``op_name`` map holds every scope of
+  the table, tells forward from backward ops of the loss, and finds the
+  lean head's ``custom_vjp`` backward rule under its own scope, with and
+  without remat;
+- the map is computed ON DEMAND: a fit, traced or not, lowers and
+  compiles nothing extra;
+- ``runner.readback`` is tiled by its two children (the wait for the
+  device, the copy), every span of a step carries that step's index, and
+  a fit of N steps yields N of each;
+- with tracing on, a ``jax.profiler`` session holds the spans as
+  ``TraceAnnotation``\\ s on the profiler's own clock;
+- the goodput report charges the wait to ``compute`` and still sums to
+  the wall;
+- ``DecodeEngine`` results carry the four timestamps, and its spans the
+  request ids.
+"""
+import glob
+import re
+
+import jax
+import numpy as np
+import optax
+import pytest
+
+import autodist_tpu
+from autodist_tpu import strategy as S
+from autodist_tpu import telemetry
+from autodist_tpu.data.prefetch import DevicePrefetcher
+from autodist_tpu.models import lm
+from autodist_tpu.runtime.runner import Runner
+from autodist_tpu.telemetry import scopes
+from autodist_tpu.telemetry import spans as tel
+
+STEP = "jit_local_step"
+PER_STEP = ("runner.next_batch", "runner.dispatch", "runner.feed",
+            "dstep.dispatch", "runner.control", "runner.readback",
+            "runner.wait_device", "runner.fetch", "runner.callbacks")
+
+
+@pytest.fixture(autouse=True)
+def _tracing_off_after():
+    yield
+    tel.configure(None)
+
+
+def build_lm(builder=None, **build_kw):
+    cfg = lm.LMConfig.tiny()
+    loss_fn, params, batch, _ = lm.make_train_setup(
+        cfg, seq_len=16, batch_size=8, lean_head=True)
+    ad = autodist_tpu.AutoDist(strategy_builder=builder or S.AllReduce())
+    runner = ad.build(loss_fn, optax.adam(1e-3), params, batch, **build_kw)
+    runner.init(params)
+    return runner, batch, cfg
+
+
+def components(op_name):
+    """Path components of an op_name with their transform wrappers."""
+    return op_name.split("/")
+
+
+def pass_of(op_name):
+    """"fwd" / "bwd" by the FIRST loss component of the path (under remat
+    a backward op reads transpose(jvp(loss))/jvp(loss)/checkpoint/...),
+    None outside the differentiated loss."""
+    for part in components(op_name):
+        if part.endswith("(loss))") or part.endswith("(loss)"):
+            return "bwd" if "transpose(" in part else "fwd"
+    return None
+
+
+def under(scope_map, *wanted, in_pass=None):
+    """Instructions with an op_name whose path holds every wanted
+    component, in the given pass of the loss if one is named."""
+    return [name for name, ops in scope_map.items()
+            if any(all(w in components(o) for w in wanted)
+                   and (in_pass is None or pass_of(o) == in_pass)
+                   for o in ops)]
+
+
+# ------------------------------------------------------------ (a) the map
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_step_map_holds_every_scope_and_tells_the_passes_apart(remat):
+    builder = S.WithRemat(S.AllReduce()) if remat else S.AllReduce()
+    runner, batch, _ = build_lm(builder, sentinel=True)
+    assert bool(runner.distributed_step.strategy.graph_config.remat) == remat
+    runner.run(batch)
+    assert STEP in telemetry.registered_programs()
+    m = telemetry.scope_map(STEP)
+    assert m is telemetry.scope_map(STEP)  # computed once, kept
+    # (replicated storage gathers nothing: scopes.PARAMS has its own test)
+    for name in (scopes.GRAD_SYNC, scopes.OPTIMIZER, scopes.SENTINEL):
+        assert under(m, name), name
+    # the loss: JAX itself wraps the scope per pass
+    assert under(m, "jvp(loss)", in_pass="fwd")
+    assert under(m, "transpose(jvp(loss))", in_pass="bwd")
+    # the model's scopes, in both passes
+    for name in (scopes.EMBED, scopes.BLOCKS, scopes.ATTENTION):
+        # (XLA merges a recomputed op with its forward twin and keeps one
+        # of the two names: under remat the lookup shows in one pass only)
+        assert under(m, name, in_pass="fwd") or (remat
+                                                 and name == scopes.EMBED)
+        assert under(m, name, in_pass="bwd"), name
+    # attention sits inside a block
+    assert all(o.index("/blocks/") < o.index("/attention/")
+               for ops in m.values() for o in ops if "/attention/" in o)
+    # the lean head: forward under the forward pass, and the custom_vjp
+    # rule, traced at transpose time, under its own scope in the backward
+    assert under(m, scopes.LEAN_HEAD, in_pass="fwd")
+    head_bwd = under(m, scopes.LEAN_HEAD_BWD, in_pass="bwd")
+    assert head_bwd and not under(m, scopes.LEAN_HEAD_BWD, in_pass="fwd")
+    dots = [o for n in head_bwd for o in m[n] if "dot_general" in o
+            and scopes.LEAN_HEAD_BWD in components(o)]
+    assert dots, "the rule's three chunk matmuls are under its scope"
+    if remat:
+        assert under(m, "rematted_computation", in_pass="bwd")
+        assert not under(m, "rematted_computation", in_pass="fwd")
+    # every scope the step uses is a name of the table
+    step_scopes = {scopes.PARAMS, scopes.LOSS, scopes.GRAD_SYNC,
+                   scopes.OPTIMIZER, scopes.SENTINEL, scopes.LEAN_HEAD,
+                   scopes.LEAN_HEAD_BWD, scopes.EMBED, scopes.BLOCKS,
+                   scopes.ATTENTION}
+    assert step_scopes <= set(scopes.SCOPES)
+    with pytest.raises(KeyError):
+        scopes.scope("no_such_scope")
+
+
+def test_partitioned_storage_gathers_under_the_params_scope():
+    runner, batch, _ = build_lm(S.PartitionedAR())
+    runner.run(batch)
+    m = telemetry.scope_map(STEP)
+    gathers = [o for n in under(m, scopes.PARAMS) for o in m[n]]
+    assert any("all_gather" in o for o in gathers), gathers[:5]
+    assert not under(m, scopes.PARAMS, in_pass="fwd")
+
+
+def test_map_names_are_the_running_executables_instructions():
+    """A fresh compile with the cache bypassed names the instructions the
+    jit's own executable has: metadata is no part of a name."""
+    runner, batch, _ = build_lm()
+    runner.run(batch)
+    dstep = runner.distributed_step
+    ran = dstep._step_fn.lower(
+        runner.state, {}, runner.remapper.remap_feed(batch)).compile()
+    assert set(scopes.parse_scope_map(ran.as_text())) == \
+        set(telemetry.scope_map(STEP))
+    # eval and the fused program are registered beside the step
+    assert {"jit_local_eval", "jit_local_multi"} <= set(
+        telemetry.registered_programs())
+    ev = telemetry.scope_map("jit_local_eval")
+    assert under(ev, scopes.LOSS) and not under(ev, "jvp(loss)")
+    assert not under(ev, scopes.LOSS, in_pass="bwd")
+    # the registry does not keep a runner alive
+    assert telemetry.scope_map("jit_no_such_program") is None
+
+
+def test_map_survives_an_executable_from_another_trees_cache(tmp_path,
+                                                             monkeypatch):
+    """The hazard: metadata is no part of the persistent cache's key, so a
+    process loads the executable an EARLIER tree compiled, without this
+    tree's scopes; and ``lowered.compile()`` of what the jit ran is
+    answered from memory with that same executable. The map must come
+    from a compile of its own."""
+    import contextlib
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    cc.reset_cache()
+    try:
+        # "the parent": the same program traced without any scope
+        with monkeypatch.context() as mp:
+            mp.setattr(scopes, "scope",
+                       lambda name: contextlib.nullcontext())
+            runner, batch, _ = build_lm()
+            loss = runner.run(batch)["loss"]
+        entries = sorted(p.name for p in tmp_path.glob(STEP + "-*-cache"))
+        assert len(entries) == 1
+        autodist_tpu.reset()
+        del runner
+        jax.clear_caches()
+        # this tree: its jit loads the parent's executable...
+        runner, batch, _ = build_lm()
+        assert runner.run(batch)["loss"] == loss
+        stale = runner.distributed_step._step_fn.lower(
+            runner.state, {}, runner.remapper.remap_feed(batch)).compile()
+        assert "optimizer/" not in stale.as_text()
+        # ...and the map still reads this tree's scopes, writing nothing
+        m = telemetry.scope_map(STEP)
+        assert under(m, scopes.OPTIMIZER) and under(m, scopes.LEAN_HEAD_BWD)
+        assert set(m) == set(scopes.parse_scope_map(stale.as_text()))
+        assert sorted(p.name for p in tmp_path.glob(
+            STEP + "-*-cache")) == entries
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_parse_scope_map_text():
+    text = """HloModule jit_f, is_scheduled=true
+
+%fused_computation.1 (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  %mul.3 = f32[8]{0} multiply(%p0, %p0), metadata={op_name="jit(f)/jvp(loss)/mul" source_file="a.py" source_line=3}
+  ROOT %add.1 = f32[8]{0} add(%mul.3, %p0), metadata={op_name="jit(f)/optimizer/add"}
+}
+
+%body.7 (arg: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %arg = (s32[], f32[8]{0}) parameter(0)
+  ROOT %dot.9 = f32[8]{0} dot(%arg), metadata={op_name="jit(f)/transpose(jvp(loss))/lean_head_bwd/while/body/dot_general"}
+}
+
+ENTRY %main.4 (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0), metadata={op_name="a"}
+  %copy.2 = f32[8]{0} copy(%a)
+  %while.5 = (s32[], f32[8]{0}) while(%a), condition=%cond.6, body=%body.7, metadata={op_name="jit(f)/transpose(jvp(loss))/lean_head_bwd/while"}
+  ROOT %fusion.1 = f32[8]{0} fusion(%copy.2), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(f)/optimizer/add"}
+}
+"""
+    m = scopes.parse_scope_map(text)
+    assert m["fusion.1"] == ["jit(f)/optimizer/add", "jit(f)/jvp(loss)/mul",
+                             "jit(f)/optimizer/add"]
+    assert m["copy.2"] == [] and m["a"] == ["a"]
+    assert m["dot.9"] == ["jit(f)/transpose(jvp(loss))/lean_head_bwd/"
+                          "while/body/dot_general"]
+    assert m["while.5"] == ["jit(f)/transpose(jvp(loss))/lean_head_bwd/while"]
+    # names printed without the percent sign parse the same
+    assert scopes.parse_scope_map(text.replace("%", "")) == m
+
+
+# ------------------------------------------- (b) nothing extra untraced
+
+
+@pytest.mark.parametrize("mode", ["0", "1"])
+def test_a_fit_lowers_and_compiles_nothing_extra(mode, monkeypatch):
+    lowered, compiled = [], []
+    for name in ("_lower_step", "_lower_eval", "_lower_fused"):
+        orig = getattr(Runner, name)
+        monkeypatch.setattr(
+            Runner, name,
+            lambda self, _o=orig, _n=name: lowered.append(_n) or _o(self))
+    orig_text = scopes.compiled_text
+    monkeypatch.setattr(scopes, "compiled_text",
+                        lambda n: compiled.append(n) or orig_text(n))
+    tel.configure(mode)
+    runner, batch, _ = build_lm()
+    runner.fit([batch] * 4)
+    dstep = runner.distributed_step
+    assert lowered == [] and compiled == []
+    assert dstep._step_fn._cache_size() == 1
+    # asking is what costs: one lowering, one compile, then kept
+    assert telemetry.scope_map(STEP) and telemetry.scope_map(STEP)
+    assert lowered == ["_lower_step"] and compiled == [STEP]
+    assert dstep._step_fn._cache_size() == 1  # the jit's cache is untouched
+    assert jax.config.jax_enable_compilation_cache  # and the flag restored
+
+
+# ----------------------------------------------------- (c) the host spans
+
+
+def by_name(events):
+    out = {}
+    for e in events:
+        out.setdefault(e.name, []).append(e)
+    return out
+
+
+@pytest.mark.parametrize("fit_kw", [{}, {"metrics_every": 3}])
+def test_every_step_has_its_spans_with_its_index(fit_kw):
+    tel.configure("1")
+    runner, batch, _ = build_lm()
+    runner.run(batch)  # compile outside the recorded fit
+    tel.get_recorder().clear()
+    n = 6
+    seen = []
+    runner.fit(DevicePrefetcher(iter([batch] * n), runner, depth=2),
+               callbacks=[lambda i, m: seen.append(i)], **fit_kw)
+    assert seen == list(range(n))
+    spans = by_name(tel.get_recorder().events())
+    per_group = fit_kw.get("metrics_every", 1)
+    for name in PER_STEP:
+        want = n
+        if name == "runner.next_batch":
+            want = n + 1            # the one that finds the source empty
+        if name == "runner.callbacks" and per_group > 1:
+            want = n                # one per materialized handle
+        assert len(spans[name]) == want, (name, len(spans[name]))
+        steps = sorted(e.args["step"] for e in spans[name])
+        # steps 1..n of this runner (step 0 ran before the fit)
+        assert steps[:n] == list(range(1, n + 1)), (name, steps)
+    assert len(spans["prefetch.place"]) == n
+    assert sorted(e.args["item"] for e in spans["prefetch.place"]) == \
+        list(range(n))
+    # placements happen inside the loop's next(), the polls after a
+    # dispatch inside runner.control
+    nb = {e.span_id for e in spans["runner.next_batch"]}
+    assert all(e.parent_id in nb for e in spans["prefetch.place"])
+    disp = {e.span_id for e in spans["runner.dispatch"]}
+    assert all(e.parent_id in disp for e in spans["runner.control"])
+    # the readback is tiled by its two children
+    for rb in spans["runner.readback"]:
+        kids = sorted((e for name in ("runner.wait_device", "runner.fetch")
+                       for e in spans[name] if e.parent_id == rb.span_id),
+                      key=lambda e: e.ts_ns)
+        assert [k.name for k in kids] == ["runner.wait_device",
+                                          "runner.fetch"]
+        assert all(k.args["step"] == rb.args["step"] for k in kids)
+        assert kids[0].ts_ns >= rb.ts_ns
+        assert kids[0].ts_ns + kids[0].dur_ns <= kids[1].ts_ns
+        assert kids[1].ts_ns + kids[1].dur_ns <= rb.ts_ns + rb.dur_ns
+        own = rb.dur_ns - sum(k.dur_ns for k in kids)
+        assert own < max(0.1 * rb.dur_ns, 200_000), (own, rb.dur_ns)
+
+
+def test_fused_supersteps_carry_their_first_microstep():
+    tel.configure("1")
+    runner, batch, _ = build_lm()
+    tel.get_recorder().clear()
+    runner.fit([batch] * 8, fuse_steps=4, metrics_every=2)
+    spans = by_name(tel.get_recorder().events())
+    for name in ("runner.dispatch", "dstep.dispatch", "runner.feed",
+                 "runner.control", "runner.readback", "runner.wait_device",
+                 "runner.fetch", "runner.callbacks"):
+        assert sorted(e.args["step"] for e in spans[name]) == [0, 4], name
+    # the fused program is inspectable at the k that ran
+    fused = telemetry.scope_map("jit_local_multi")
+    assert under(fused, "while", in_pass="bwd")
+    assert under(fused, scopes.OPTIMIZER)
+
+
+# ------------------------------------------ (d) spans in a profiler trace
+
+
+def test_spans_are_annotations_in_a_profiler_session(tmp_path):
+    from jax.profiler import ProfileData
+    tel.configure("1")
+    runner, batch, _ = build_lm()
+    runner.run(batch)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        runner.fit([batch] * 2)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0]
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if re.match(r"^(runner|dstep|prefetch)\.", ev.name):
+                    found.setdefault(ev.name, []).append(dict(ev.stats))
+    for name in ("runner.fit",) + PER_STEP:
+        assert name in found, (name, sorted(found))
+    # scalar args ride along: a step's annotations share its index
+    assert sorted(s["step"] for s in found["runner.dispatch"]) == [1, 2]
+    assert sorted(s["step"] for s in found["runner.wait_device"]) == [1, 2]
+
+
+def test_untraced_spans_open_no_annotation(monkeypatch):
+    made = []
+    monkeypatch.setattr(tel, "_trace_annotation",
+                        lambda name, args: made.append(name) or 1 / 0)
+    tel.configure("0")
+    runner, batch, _ = build_lm()
+    runner.fit([batch] * 2)
+    assert made == []
+
+
+# ------------------------------------------------------------ (e) goodput
+
+
+def test_goodput_charges_the_wait_to_compute():
+    from autodist_tpu.telemetry import goodput
+    assert goodput.classify("runner.wait_device", "runner") == "compute"
+    assert goodput.classify("runner.fetch", "runner") == "readback"
+    assert goodput.classify("runner.readback", "runner") == "readback"
+    assert goodput.classify("runner.next_batch", "runner") == "host_input"
+    assert goodput.classify("runner.callbacks", "runner") == "host_loop"
+    assert goodput.classify("runner.control", "runner") == "host_loop"
+    assert "host_loop" in goodput.BUCKETS
+    tel.configure("1")
+    runner, batch, _ = build_lm()
+    runner.run(batch)
+    tel.get_recorder().clear()
+    runner.fit(DevicePrefetcher(iter([batch] * 8), runner, depth=2),
+               callbacks=[lambda i, m: None])
+    report = runner.goodput_report()
+    assert abs(report.coverage - 1.0) < 0.02
+    spans = by_name(tel.get_recorder().events())
+    waited = sum(e.dur_ns for e in spans["runner.wait_device"]) / 1e9
+    fetched = sum(e.dur_ns for e in spans["runner.fetch"]) / 1e9
+    # the wait is in compute, the copy (and little else) in readback
+    assert report.buckets["compute"] >= waited
+    assert fetched <= report.buckets["readback"] < fetched + 0.2 * waited \
+        + 1e-3
+    assert report.buckets["host_loop"] > 0
+    assert report.buckets["host_input"] > 0
+    # runner.fit's own time is all that is left unnamed
+    fit = spans["runner.fit"][0]
+    kids = sum(e.dur_ns for e in tel.get_recorder().events()
+               if e.parent_id == fit.span_id)
+    assert report.buckets["other"] == pytest.approx(
+        (fit.dur_ns - kids) / 1e9, abs=1e-4)
+    assert "host_loop" in report.format_table()
+
+
+# ---------------------------------------------------- the decode engine
+
+
+def test_decode_results_carry_timestamps_and_spans_carry_request_ids():
+    from autodist_tpu.serving.decode import DecodeConfig, DecodeEngine
+    tel.configure("1")
+    runner, batch, cfg = build_lm()
+    runner.run(batch)
+    engine = DecodeEngine(runner, lm.make_decode_setup(cfg),
+                          DecodeConfig(slots=8, max_new_tokens=4,
+                                       prefill_len=8))
+    try:
+        engine.warmup()
+        tel.get_recorder().clear()
+        rng = np.random.RandomState(0)
+        futures = [engine.submit(rng.randint(0, cfg.vocab_size, (3 + i,)),
+                                 max_new_tokens=1 + i) for i in range(3)]
+        results = [f.result(timeout=120) for f in futures]
+    finally:
+        engine.close()
+    for i, r in enumerate(results):
+        assert len(r["tokens"]) == 1 + i
+        assert r["t_submit"] <= r["t_admitted"] <= r["t_first_token"] \
+            <= r["t_done"]
+    # one prefill emits its group's first tokens at one instant
+    spans = by_name(tel.get_recorder().events())
+    prefill_rids = [e.args["rids"] for e in spans["serve.prefill"]]
+    rids = sorted(r for group in prefill_rids for r in group)
+    assert len(rids) == 3 and len(set(rids)) == 3
+    # every admission group is copied; it is inserted unless its prefill
+    # alone satisfied all of it (the first request's cap is one token)
+    assert [e.args["rids"] for e in spans["serve.admit_copy"]] == \
+        prefill_rids
+    inserted = [e.args["rids"] for e in spans["serve.insert"]]
+    assert inserted and all(g in prefill_rids for g in inserted)
+    # the prefill span holds the bucket's dispatch and readback
+    pre = {e.span_id for e in spans["serve.prefill"]}
+    assert any(e.parent_id in pre for e in spans["serve.readback"])
+    # every decode step names the requests it advanced
+    steps = spans["serve.decode_step"]
+    assert steps and all(set(e.args["rids"]) <= set(rids) for e in steps)
+    assert all(len(e.args["rids"]) == e.args["live"] for e in steps)
+    # the three serving programs are inspectable under their scopes
+    assert under(telemetry.scope_map("jit_local_decode"), scopes.DECODE)
+    assert under(telemetry.scope_map("jit_local_decode"), scopes.DECODE,
+                 scopes.BLOCKS, scopes.ATTENTION)
+    assert under(telemetry.scope_map("jit__insert"), scopes.INSERT)
+    assert under(telemetry.scope_map("jit_local_predict"), scopes.PREFILL)
