@@ -4,7 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from autodist_tpu.ops.xent import chunked_softmax_xent
+from autodist_tpu import telemetry
+from autodist_tpu.kernel.common import op_info
+from autodist_tpu.ops.xent import _layout, chunked_softmax_xent
 
 
 def _ref_nll(x, w, b, targets):
@@ -13,11 +15,54 @@ def _ref_nll(x, w, b, targets):
     return -jnp.take_along_axis(logp, targets[:, None], axis=1)[:, 0]
 
 
+def _walk(jaxpr, visit):
+    """``visit(eqn)`` on every equation, sub-jaxprs (scan bodies, the
+    custom_vjp's calls) included."""
+    for eqn in jaxpr.eqns:
+        visit(eqn)
+        for sub in op_info.sub_jaxprs(eqn):
+            _walk(sub, visit)
+
+
+def _plan_columns(v, chunk):
+    """``_chunk_view``'s offsets and dead masks replayed in numpy: per
+    chunk, the vocabulary columns it holds LIVE."""
+    width, n = _layout(v, chunk)
+    live = []
+    for ci in range(n):
+        off = ci * width
+        start = min(off, v - width)
+        cols = start + np.arange(width)
+        live.append(cols[cols >= off])
+    return width, n, live
+
+
+@pytest.mark.parametrize("v,chunk,expect", [
+    (99183, 8192, (7680, 13)), (4096, 512, (512, 8)), (777, 256, None),
+    (1000, 1000, (1000, 1)), (50257, 8192, None), (200000, 8192, None),
+    (12397, 1024, None), (3001, 512, None), (300, 8192, (300, 1)),
+    (129, 128, (128, 2))])
+def test_layout_covers_every_column_once(v, chunk, expect):
+    """The plan keeps ``chunk`` as the ceiling and the fewest chunks it
+    allows, sizes them to the vocabulary in 128-lane steps, and the
+    clamped reads with their dead masks visit every column once."""
+    width, n, live = _plan_columns(v, chunk)
+    if expect is not None:
+        assert (width, n) == expect
+    assert n == -(-v // chunk) and 0 < width <= min(chunk, v)
+    assert width % 128 == 0 or width in (v, chunk)
+    assert 0 <= n * width - v < 129 * n
+    assert all(len(c) for c in live), "a chunk with no live column"
+    seen = np.concatenate(live)
+    np.testing.assert_array_equal(np.sort(seen), np.arange(v))
+
+
 @pytest.mark.parametrize("vocab,chunk", [(1000, 256), (1000, 1000),
-                                         (777, 256), (512, 512)])
+                                         (777, 256), (512, 512),
+                                         (12397, 1024), (3001, 512)])
 def test_matches_reference_fwd_and_grad(vocab, chunk):
     """Exact same nll and grads as log_softmax+gather, including the
-    ragged final chunk (vocab not a chunk multiple)."""
+    ragged final chunk (vocab not a chunk multiple, nor of 128)."""
     rng = np.random.RandomState(0)
     n, d = 64, 32
     x = jnp.asarray(rng.randn(n, d), jnp.float32)
@@ -71,18 +116,14 @@ def test_no_full_logits_in_program():
         return jnp.mean(chunked_softmax_xent(x, w, b, t, chunk))
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(x, w, b)
-    from autodist_tpu.kernel.common import op_info
-
-    def walk(jp, out):
-        for eqn in jp.eqns:
-            for v in list(eqn.outvars) + list(eqn.invars):
-                shape = tuple(getattr(getattr(v, "aval", None), "shape", ()))
-                if shape:
-                    out.add(shape)
-            for sub in op_info.sub_jaxprs(eqn):
-                walk(sub, out)
     shapes = set()
-    walk(jaxpr.jaxpr, shapes)
+
+    def visit(eqn):
+        for v in list(eqn.outvars) + list(eqn.invars):
+            shape = tuple(getattr(getattr(v, "aval", None), "shape", ()))
+            if shape:
+                shapes.add(shape)
+    _walk(jaxpr.jaxpr, visit)
     assert (n, vocab) not in shapes, "full logits materialized"
     assert any(s[-1] == chunk and s[0] in (n,) for s in shapes
                if len(s) == 2), shapes
@@ -131,3 +172,81 @@ def test_out_of_vocab_target_clamps_like_reference():
     gr = jax.grad(lambda w: jnp.mean(_ref_nll(
         x, w, b, jnp.clip(t, 0, vocab - 1))))(w)
     np.testing.assert_allclose(g, gr, rtol=2e-4, atol=1e-6)
+
+
+def _ragged_case():
+    """(x, w, b, vocab, chunk) whose final chunk is clamped: 4 x 256
+    columns for 777 words."""
+    n, d, vocab, chunk = 48, 16, 777, 256
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(n, d), jnp.float32)
+    w = jnp.asarray(rng.randn(d, vocab) * 0.1, jnp.float32)
+    b = jnp.asarray(rng.randn(vocab) * 0.1, jnp.float32)
+    return x, w, b, vocab, chunk
+
+
+def test_target_picked_without_a_gather():
+    """The target's logit is a masked row sum riding on the exp pass:
+    neither the forward nor the backward program holds a gather (on the
+    TPU one cost 4 ms a step, a scalar pick per token per chunk)."""
+    x, w, b, vocab, chunk = _ragged_case()
+    t = jnp.zeros((x.shape[0],), jnp.int32)
+
+    def loss(x, w, b):
+        return jnp.mean(chunked_softmax_xent(x, w, b, t, chunk))
+
+    for fn in (loss, jax.grad(loss, (0, 1, 2))):
+        prims = set()
+        _walk(jax.make_jaxpr(fn)(x, w, b).jaxpr,
+              lambda eqn: prims.add(eqn.primitive.name))
+        assert "dot_general" in prims, prims
+        assert not {p for p in prims if "gather" in p}, prims
+
+
+@pytest.mark.parametrize("where", ["first_live", "last_live", "overlap",
+                                   "before_clamped_start", "all_edges"])
+def test_targets_around_the_clamped_chunk(where):
+    """The final chunk of a ragged vocabulary is read at the clamped
+    offset ``v - width``: its first live column, its last, a column in
+    its dead overlap (live in the chunk before) and the column just under
+    its start all give the reference's nll and gradients."""
+    x, w, b, vocab, chunk = _ragged_case()
+    width, n, live = _plan_columns(vocab, chunk)
+    start = vocab - width
+    assert start < live[-1][0], "the case has no dead overlap"
+    cols = {"first_live": [live[-1][0]], "last_live": [vocab - 1],
+            "overlap": [start, live[-1][0] - 1],
+            "before_clamped_start": [start - 1]}
+    cols["all_edges"] = sorted(
+        {c for cs in cols.values() for c in cs}
+        | {c[0] for c in live} | {c[-1] for c in live})
+    t = jnp.asarray(np.resize(cols[where], x.shape[0]), jnp.int32)
+
+    np.testing.assert_allclose(chunked_softmax_xent(x, w, b, t, chunk),
+                               _ref_nll(x, w, b, t), rtol=1e-5, atol=1e-5)
+    gc = jax.grad(lambda *a: jnp.mean(
+        chunked_softmax_xent(*a, t, chunk)), (0, 1, 2))(x, w, b)
+    gr = jax.grad(lambda *a: jnp.mean(_ref_nll(*a, t)), (0, 1, 2))(x, w, b)
+    for a, bb in zip(gc, gr):
+        np.testing.assert_allclose(a, bb, rtol=2e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("vocab,chunk", [(777, 256), (4096, 512),
+                                         (300, 8192)])
+def test_plan_gauges_set_at_trace_time(vocab, chunk):
+    """One trace of the loss leaves the plan in the ``lean_head.*``
+    gauges: how many chunks, how wide, how many columns computed dead."""
+    telemetry.reset()
+    assert not [k for k in telemetry.get_recorder().gauges()
+                if k.startswith("lean_head.")]
+    x = jnp.zeros((8, 4), jnp.float32)
+    w = jnp.zeros((4, vocab), jnp.float32)
+    b = jnp.zeros((vocab,), jnp.float32)
+    t = jnp.zeros((8,), jnp.int32)
+    jax.make_jaxpr(lambda x: jnp.mean(
+        chunked_softmax_xent(x, w, b, t, chunk)))(x)
+    width, n = _layout(vocab, chunk)
+    gauges = telemetry.get_recorder().gauges()
+    assert gauges["lean_head.chunks"] == n
+    assert gauges["lean_head.chunk_width"] == width
+    assert gauges["lean_head.dead_cols"] == n * width - vocab
