@@ -1263,6 +1263,15 @@ class GraphTransformer:
                     sorted(uncaptured))
         sparse_wire = frozenset(sparse_specs)
 
+        # ----- device counters (telemetry/device_counters.py): scalars
+        # the loss adds while it is traced (a routed layer's expert load)
+        # leave the step beside the loss. A loss that counts says so
+        # (``loss_fn.device_counters``, the names); one that does not
+        # (most) pays nothing and lowers exactly as before.
+        from autodist_tpu.telemetry import device_counters
+        counter_names = tuple(getattr(item.loss_fn, "device_counters", ()))
+        counted = bool(counter_names)
+
         # ----- training health sentinel + gradient fault layer
         # Guards (and injected faults) are COMPILED INTO the step: both
         # read their configuration here, at transform time, so the clean
@@ -1420,6 +1429,17 @@ class GraphTransformer:
                 return _cd_up(out)
         else:
             loss_fn_cd = item.loss_fn
+        if counted:
+            loss_fn_uncounted = loss_fn_cd
+
+            def loss_fn_cd(params, batch):
+                """(loss, (the user's aux or None, {counter: scalar}))."""
+                with device_counters.collect(counter_names) as got:
+                    out = loss_fn_uncounted(params, batch)
+                loss, aux = out if item.has_aux else (out, None)
+                return loss, (aux, dict(got))
+        # what the differentiated function returns beside the loss
+        aux_out = item.has_aux or counted
 
         # the loss scope sits OUTSIDE remat and INSIDE the differentiated
         # function: JAX then names forward ops jvp(loss)/..., backward
@@ -1428,12 +1448,12 @@ class GraphTransformer:
         under_loss_scope = sc.scoped(sc.LOSS)
 
         grad_fn = jax.value_and_grad(
-            under_loss_scope(remat_wrap(loss_fn_cd)), has_aux=item.has_aux)
+            under_loss_scope(remat_wrap(loss_fn_cd)), has_aux=aux_out)
         if sparse_wire:
             def loss_with_taps(full_params, taps, batch):
                 with embedding_lib.capture(taps) as cap:
                     out = loss_fn_cd(full_params, batch)
-                loss, aux = (out if item.has_aux else (out, None))
+                loss, aux = (out if aux_out else (out, None))
                 return loss, (aux, cap.ids)
             sparse_grad_fn = jax.value_and_grad(
                 under_loss_scope(remat_wrap(loss_with_taps)),
@@ -1607,17 +1627,28 @@ class GraphTransformer:
                 return (ps_lib.fill_holes(gathered, ps_vals)
                         if ps_names else gathered)
 
+        def _over_replicas(tree):
+            """A loss's extra outputs as one value per step: floats are
+            averaged over the replicas, integers take the largest."""
+            return jax.tree_util.tree_map(
+                lambda a: (jax.lax.pmean(a, all_axes)
+                           if jnp.issubdtype(jnp.asarray(a).dtype, jnp.inexact)
+                           else jax.lax.pmax(a, all_axes)), tree)
+
         def local_step(state: TrainState, ps_vals, batch):
             full_params = _full_params(state, ps_vals)
             if sparse_wire:
                 taps = embedding_lib.make_taps(sparse_specs)
                 (loss, (aux, ids_seen)), (grads, tap_grads) = sparse_grad_fn(
                     full_params, taps, batch)
-            elif has_aux:
+            elif aux_out:
                 (loss, aux), grads = grad_fn(full_params, batch)
             else:
                 loss, grads = grad_fn(full_params, batch)
                 aux = None
+            counters = None
+            if counted:
+                aux, counters = aux
             g_names, g_leaves, _ = variable_utils.flatten_named(grads)
             g = dict(zip(g_names, g_leaves))
             if grad_plan.rules:
@@ -1845,10 +1876,9 @@ class GraphTransformer:
             global_loss = jax.lax.pmean(loss, all_axes)
             metrics = {"loss": global_loss}
             if aux is not None:
-                metrics["aux"] = jax.tree_util.tree_map(
-                    lambda a: (jax.lax.pmean(a, all_axes)
-                               if jnp.issubdtype(jnp.asarray(a).dtype, jnp.inexact)
-                               else jax.lax.pmax(a, all_axes)), aux)
+                metrics["aux"] = _over_replicas(aux)
+            if counters is not None:
+                metrics["counters"] = _over_replicas(counters)
             new_sync = {}
             if new_bucket_state:
                 new_sync["bucket"] = new_bucket_state
@@ -1920,6 +1950,8 @@ class GraphTransformer:
             loss_spec = jax.eval_shape(item.loss_fn, item.params,
                                        item.example_batch)
             metric_specs["aux"] = jax.tree_util.tree_map(lambda _: P(), loss_spec[1])
+        if counted:
+            metric_specs["counters"] = {n: P() for n in counter_names}
         if guard:
             # the verdict rides the existing metrics readback (replicated
             # scalars): zero extra dispatches, zero extra D2H
@@ -1932,14 +1964,12 @@ class GraphTransformer:
             full_params = _full_params(state, ps_vals)
             with sc.scope(sc.LOSS):
                 out = loss_fn_cd(full_params, batch)
-            loss, aux = (out if has_aux else (out, None))
+            loss, aux = (out if aux_out else (out, None))
+            if counted:
+                aux = aux[0]  # an evaluation counts nothing
             metrics = {"loss": jax.lax.pmean(loss, all_axes)}
             if aux is not None:
-                metrics["aux"] = jax.tree_util.tree_map(
-                    lambda a: (jax.lax.pmean(a, all_axes)
-                               if jnp.issubdtype(jnp.asarray(a).dtype,
-                                                 jnp.inexact)
-                               else jax.lax.pmax(a, all_axes)), aux)
+                metrics["aux"] = _over_replicas(aux)
             return metrics
 
         # check_vma=False: with the check on, differentiating w.r.t. a
@@ -1957,7 +1987,8 @@ class GraphTransformer:
         eval_fn = jax.jit(jax.shard_map(
             local_eval, mesh=self._mesh,
             in_specs=(state_specs, ps_specs, batch_specs),
-            out_specs=metric_specs, check_vma=False))
+            out_specs={k: spec for k, spec in metric_specs.items()
+                       if k != "counters"}, check_vma=False))
 
         # ----- serving forward-only lowering (DistributedStep.
         # predict_program): the SAME per-device gather-params +

@@ -20,8 +20,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from autodist_tpu.models.layers import TransformerBlock, causal_mask, SparseEmbed
-from autodist_tpu.telemetry import scopes
+from autodist_tpu.models.layers import (SparseEmbed, TransformerBlock,
+                                        causal_mask, make_norm)
+from autodist_tpu.telemetry import device_counters, scopes
+
+# what a routed layer sows into ``counters`` and the loss reports as the
+# device counters ``moe.<name>``, summed over layers
+ROUTER_LOAD = ("max_expert_pairs", "routed_pairs")
 
 
 @dataclasses.dataclass
@@ -33,11 +38,55 @@ class LMConfig:
     mlp_dim: int = 2048
     max_seq_len: int = 256
     dtype: Any = jnp.float32
+    # Architecture, as a model's public config.json names it. The
+    # defaults are the GPT-2 style model lm1b runs; a preset below sets
+    # what its source publishes. These are not tuning knobs: each value
+    # is another model, none selects between two ways to compute one.
+    norm: str = "layernorm"         # "layernorm" | "rmsnorm"
+    norm_eps: float = 1e-6
+    # rotary positions on q and k with this base; None = a learned table
+    rope_theta: Optional[float] = None
+    qk_norm: bool = False           # RMSNorm over the projected q and k
+    attention_bias: bool = True
+    head_bias: bool = True
+    embed_scale: bool = True        # token embedding x sqrt(d_model)
+    # > 0: the feed-forward is a routed SwiGLU one, ``experts_per_token``
+    # of ``num_experts`` experts each of width ``mlp_dim``, no token
+    # dropped; 0: the GELU MLP of width ``mlp_dim``
+    num_experts: int = 0
+    experts_per_token: int = 0
+    router_aux_loss_coef: float = 0.0   # load-balance loss, per layer
+    router_z_loss_coef: float = 0.0
+
+    def __post_init__(self):
+        if self.num_experts and not (
+                0 < self.experts_per_token <= self.num_experts):
+            raise ValueError(
+                "a routed feed-forward needs 0 < experts_per_token <= "
+                "num_experts, got %d of %d" % (self.experts_per_token,
+                                               self.num_experts))
 
     @classmethod
     def lm1b(cls, **kw):
         return cls(vocab_size=793470 // 8, d_model=1024, num_layers=8,
                    num_heads=16, mlp_dim=4096, **kw)
+
+    @classmethod
+    def olmoe_1b_7b(cls, **kw):
+        """OLMoE-1B-7B-0125-Instruct as its ``config.json`` publishes it
+        (huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct; arXiv
+        2409.02060): 16 layers of RMSNorm, QK-norm, RoPE, bias-free
+        attention and a dropless top-8-of-64 SwiGLU feed-forward, an
+        untied bias-free head. ``intermediate_size`` 1024 is one expert's
+        width; the two router loss coefficients are the paper's."""
+        kw.setdefault("num_layers", 16)
+        kw.setdefault("max_seq_len", 4096)
+        return cls(vocab_size=50304, d_model=2048, num_heads=16,
+                   mlp_dim=1024, norm="rmsnorm", norm_eps=1e-5,
+                   rope_theta=10000.0, qk_norm=True, attention_bias=False,
+                   head_bias=False, embed_scale=False, num_experts=64,
+                   experts_per_token=8, router_aux_loss_coef=0.01,
+                   router_z_loss_coef=0.001, **kw)
 
     @classmethod
     def tiny(cls, **kw):
@@ -51,42 +100,66 @@ class TransformerLM(nn.Module):
     seq_parallel: bool = False  # offset positions by the seq-shard index
     decode_attn: str = "reference"  # decode inner loop: "reference"|"flash"
 
+    def _embed(self, input_ids, positions):
+        """Token embedding (scaled by sqrt(d) where the config says so)
+        plus, for learned positions, the table's rows at ``positions``
+        [1 or B, S]; rotary positions enter in the blocks' attention."""
+        cfg = self.config
+        # untied lm_head -> the token table can ride the sparse wire
+        with scopes.scope(scopes.EMBED):
+            x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+                            name="embed")(input_ids)
+            if cfg.embed_scale:
+                x = x * np.sqrt(cfg.d_model)
+            if cfg.rope_theta is None:
+                pos = SparseEmbed(cfg.max_seq_len, cfg.d_model,
+                                  dtype=cfg.dtype,
+                                  name="pos_embed")(positions)
+                x = x + pos
+        return x
+
+    def _block(self, i, **kw):
+        cfg = self.config
+        return TransformerBlock(
+            cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
+            dtype=cfg.dtype, norm=cfg.norm, norm_eps=cfg.norm_eps,
+            attention_bias=cfg.attention_bias, qk_norm=cfg.qk_norm,
+            rope_theta=cfg.rope_theta, num_experts=cfg.num_experts,
+            experts_per_token=cfg.experts_per_token,
+            name="layer_%d" % i, **kw)
+
+    def _final_norm(self, x):
+        cfg = self.config
+        return make_norm(cfg.norm, cfg.norm_eps, cfg.dtype, "final_ln")(x)
+
+    def _head(self, x):
+        cfg = self.config
+        return nn.Dense(cfg.vocab_size, dtype=jnp.float32,
+                        use_bias=cfg.head_bias, name="lm_head")(x)
+
     @nn.compact
     def hidden(self, input_ids):
         """Final-layer-norm hidden states [B, S, d] — the lean-head loss
         applies the lm_head itself through ``ops.xent`` so the [N, vocab]
         logits tensor never materializes."""
-        cfg = self.config
         seq_len = input_ids.shape[-1]  # LOCAL length under seq sharding
-        # untied lm_head -> the token table can ride the sparse wire
-        with scopes.scope(scopes.EMBED):
-            x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                            name="embed")(input_ids)
-            x = x * np.sqrt(cfg.d_model)
-            positions = jnp.arange(seq_len)
-            if self.seq_parallel:
-                from autodist_tpu.parallel import sequence
-                positions = positions + sequence.position_offset(seq_len)
-            pos = SparseEmbed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
-                              name="pos_embed")(positions[None])
-            x = x + pos
+        positions = jnp.arange(seq_len)
+        if self.seq_parallel:
+            from autodist_tpu.parallel import sequence
+            positions = positions + sequence.position_offset(seq_len)
+        x = self._embed(input_ids, positions[None])
         # with an injected SP attention the causal structure is handled
         # inside the op; the local mask would be wrong and is skipped
         mask = None if self.attn_fn is not None else causal_mask(seq_len)
         with scopes.scope(scopes.BLOCKS):
-            for i in range(cfg.num_layers):
-                x = TransformerBlock(
-                    cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
-                    dtype=cfg.dtype, attn_fn=self.attn_fn,
-                    name="layer_%d" % i)(x, mask)
-        return nn.LayerNorm(dtype=cfg.dtype, name="final_ln")(x)
+            for i in range(self.config.num_layers):
+                x = self._block(i, attn_fn=self.attn_fn)(
+                    x, mask, positions=positions)
+        return self._final_norm(x)
 
     @nn.compact
     def __call__(self, input_ids):
-        cfg = self.config
-        x = self.hidden(input_ids)
-        logits = nn.Dense(cfg.vocab_size, dtype=jnp.float32, name="lm_head")(x)
-        return logits
+        return self._head(self.hidden(input_ids))
 
     @nn.compact
     def prefill(self, input_ids, length):
@@ -103,33 +176,23 @@ class TransformerLM(nn.Module):
         parameters resolve unchanged."""
         cfg = self.config
         seq_len = input_ids.shape[-1]
-        with scopes.scope(scopes.EMBED):
-            x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                            name="embed")(input_ids)
-            x = x * np.sqrt(cfg.d_model)
-            positions = jnp.arange(seq_len)
-            pos = SparseEmbed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
-                              name="pos_embed")(positions[None])
-            x = x + pos
+        positions = jnp.arange(seq_len)
+        x = self._embed(input_ids, positions[None])
         mask = None if self.attn_fn is not None else causal_mask(seq_len)
         ks, vs = [], []
         with scopes.scope(scopes.BLOCKS):
             for i in range(cfg.num_layers):
-                x, (k, v) = TransformerBlock(
-                    cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
-                    dtype=cfg.dtype, attn_fn=self.attn_fn,
-                    decode_attn=self.decode_attn,
-                    name="layer_%d" % i)(x, mask, return_kv=True)
+                x, (k, v) = self._block(
+                    i, attn_fn=self.attn_fn, decode_attn=self.decode_attn)(
+                    x, mask, return_kv=True, positions=positions)
                 pad = [(0, 0), (0, cfg.max_seq_len - seq_len), (0, 0),
                        (0, 0)]
                 ks.append(jnp.pad(k, pad))
                 vs.append(jnp.pad(v, pad))
-        x = nn.LayerNorm(dtype=cfg.dtype, name="final_ln")(x)
+        x = self._final_norm(x)
         idx = jnp.clip(length - 1, 0, seq_len - 1)
         last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-        logits = nn.Dense(cfg.vocab_size, dtype=jnp.float32,
-                          name="lm_head")(last)
-        return logits, jnp.stack(ks, axis=1), jnp.stack(vs, axis=1)
+        return self._head(last), jnp.stack(ks, axis=1), jnp.stack(vs, axis=1)
 
     @nn.compact
     def decode_step(self, token_ids, k_cache, v_cache, cursor, alive=None):
@@ -140,30 +203,19 @@ class TransformerLM(nn.Module):
         logits [B, vocab] and the updated caches. Fixed shapes for any
         slot occupancy — the zero-recompile decode contract."""
         cfg = self.config
-        with scopes.scope(scopes.EMBED):
-            x = SparseEmbed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                            name="embed")(token_ids[:, None])
-            x = x * np.sqrt(cfg.d_model)
-            pos_idx = jnp.clip(cursor, 0, cfg.max_seq_len - 1)
-            pos = SparseEmbed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype,
-                              name="pos_embed")(pos_idx[:, None])
-            x = x + pos
+        positions = jnp.clip(cursor, 0, cfg.max_seq_len - 1)[:, None]
+        x = self._embed(token_ids[:, None], positions)
         new_ks, new_vs = [], []
         with scopes.scope(scopes.BLOCKS):
             for i in range(cfg.num_layers):
-                x, (k, v) = TransformerBlock(
-                    cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
-                    dtype=cfg.dtype, attn_fn=None,
-                    decode_attn=self.decode_attn,
-                    name="layer_%d" % i)(
+                x, (k, v) = self._block(
+                    i, attn_fn=None, decode_attn=self.decode_attn)(
                     x, cache=(k_cache[:, i], v_cache[:, i]),
-                    cursor=cursor, alive=alive)
+                    cursor=cursor, alive=alive, positions=positions)
                 new_ks.append(k)
                 new_vs.append(v)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="final_ln")(x)
-        logits = nn.Dense(cfg.vocab_size, dtype=jnp.float32,
-                          name="lm_head")(x[:, 0])
-        return (logits, jnp.stack(new_ks, axis=1),
+        x = self._final_norm(x)
+        return (self._head(x[:, 0]), jnp.stack(new_ks, axis=1),
                 jnp.stack(new_vs, axis=1))
 
 
@@ -180,7 +232,16 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     (``ops.xent.chunked_softmax_xent``) — the [tokens, vocab] fp32 logits
     tensor (3.25 GB for lm1b at batch 32) never materializes, which is
     what lets lm1b train at batch 64 on a 16 GB chip. "auto" (default)
-    engages it at vocab >= 32768. Same math to float tolerance."""
+    engages it at vocab >= 32768, with or without a head bias. Same math
+    to float tolerance.
+
+    ``config`` decides the model, not this function: ``LMConfig.lm1b()``
+    (GPT-2 style blocks) and ``LMConfig.olmoe_1b_7b()`` (RMSNorm, QK-norm,
+    RoPE, dropless top-8-of-64 SwiGLU experts) go through the same
+    ``TransformerLM``, the same loss and the same lean-head rule. A
+    routed config adds its router losses to the mean NLL:
+    ``router_aux_loss_coef * L_lb + router_z_loss_coef * L_z``, each taken
+    per layer over the batch this loss sees and averaged over layers."""
     cfg = config or LMConfig()
     if lean_head == "auto":
         lean_head = cfg.vocab_size >= 32768
@@ -207,25 +268,52 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     model = TransformerLM(cfg, attn_fn=attn_fn)
     rng = jax.random.PRNGKey(seed)
     variables = jax.jit(model.init)(rng, jnp.zeros((1, seq_len), jnp.int32))
+    # (a routed model's init also fills what its layers sow)
+    variables = {"params": variables["params"]}
+
+    def forward(params, ids, method):
+        """(the method's output, the router losses' weighted sum). The
+        layers' load goes to the step's device counters from HERE, the
+        loss's own trace (``telemetry/device_counters.py``)."""
+        if not cfg.num_experts:
+            return model.apply(params, ids, method=method), None
+        out, sown = model.apply(params, ids, method=method,
+                                mutable=["losses", "counters"])
+        for layer in sown["counters"].values():
+            for name in ROUTER_LOAD:
+                device_counters.add("moe." + name, layer["moe"][name][0])
+        per_layer = sown["losses"].values()
+        lb = sum(layer["moe"]["router_lb"][0] for layer in per_layer)
+        z = sum(layer["moe"]["router_z"][0] for layer in per_layer)
+        return out, (cfg.router_aux_loss_coef * lb
+                     + cfg.router_z_loss_coef * z) / cfg.num_layers
+
+    def mean_loss(nll, router_loss):
+        loss = jnp.mean(nll)
+        return loss if router_loss is None else loss + router_loss
 
     def loss_fn(params, batch):
         tokens = batch["tokens"]
         targets = tokens[:, 1:]
         if lean_head:
             from autodist_tpu.ops.xent import chunked_softmax_xent
-            h = model.apply(params, tokens[:, :-1],
-                            method=TransformerLM.hidden)
+            h, router_loss = forward(params, tokens[:, :-1],
+                                     TransformerLM.hidden)
             head = params["params"]["lm_head"]
+            bias = (head["bias"].astype(jnp.float32) if cfg.head_bias
+                    else jnp.zeros((cfg.vocab_size,), jnp.float32))
             nll = chunked_softmax_xent(
                 h.reshape(-1, cfg.d_model),
-                head["kernel"].astype(jnp.float32),
-                head["bias"].astype(jnp.float32),
+                head["kernel"].astype(jnp.float32), bias,
                 targets.reshape(-1))
-            return jnp.mean(nll)
-        logits = model.apply(params, tokens[:, :-1])
+            return mean_loss(nll, router_loss)
+        logits, router_loss = forward(params, tokens[:, :-1], None)
         logp = jax.nn.log_softmax(logits)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(nll)
+        return mean_loss(nll, router_loss)
+
+    if cfg.num_experts:
+        loss_fn.device_counters = tuple("moe." + n for n in ROUTER_LOAD)
 
     npr = np.random.RandomState(seed)
     example_batch = {"tokens": npr.randint(

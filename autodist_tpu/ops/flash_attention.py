@@ -30,7 +30,8 @@ Design (standard FlashAttention-2 tiling, arXiv 2307.08691):
   instead yields a uniform average for such rows, so don't rely on
   empty-row values across paths.
 
-On the CPU backend the same kernels run under ``interpret=True`` so unit
+On the CPU backend the same kernels run under ``interpret=True``
+(``ops/pallas_mode.py`` decides, for every kernel of the tree) so unit
 tests exercise the identical code path (tests/test_flash_attention.py
 checks fwd+grad against ``ops.attention.reference_attention``).
 
@@ -45,22 +46,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from autodist_tpu.ops import pallas_mode
+
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp() NaN-free on masked rows
 _LANES = 128     # last-dim tile width; m/l scratch are lane-replicated
-
-
-def _interpret() -> bool:
-    """Compiled (Mosaic) on TPU, interpreted on the CPU test backend —
-    and nothing else: a machine that came up on some other backend must
-    not quietly run the kernel interpreted."""
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return False
-    if backend == "cpu":
-        return True
-    raise RuntimeError(
-        "pallas flash attention compiles for tpu and interprets on cpu; "
-        "the active jax backend is %r" % (backend,))
 
 
 def _pick_block(seq: int, want: int) -> int:
@@ -208,7 +197,7 @@ def _fwd(q, k, v, segs, causal, block_q, block_k):
                         pltpu.VMEM((bq, _LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(*operands)
     return out, lse
 
@@ -339,7 +328,7 @@ def _bwd(causal, block_q, block_k, res, do):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=params,
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(*operands)
 
     # kv-major grid: q is the reduction (innermost) dim
@@ -369,7 +358,7 @@ def _bwd(causal, block_q, block_k, res, do):
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=params,
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(*operands)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))

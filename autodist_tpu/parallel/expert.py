@@ -1,27 +1,55 @@
-"""Expert parallelism — Mixture-of-Experts with all_to_all token routing.
+"""Expert parallelism — Mixture-of-Experts feed-forwards, two routings.
 
 Beyond the reference (data-parallel only, reference
-``docs/design/architecture.rst:46-48``). Experts are stacked on a leading
-dim sharded over the ``expert`` mesh axis (``VarConfig.mp_axes = {0:
-'expert'}``); tokens are routed to their expert's owning device with one
-``lax.all_to_all`` each way (GShard, arXiv 2006.16668; Switch Transformer,
-arXiv 2101.03961). Static shapes throughout — the MXU-hostile part of MoE
-(data-dependent routing) is expressed as dense one-hot dispatch/combine
-einsums with a fixed per-expert capacity, which is the idiomatic TPU
-formulation (dynamic scatter would defeat XLA tiling).
+``docs/design/architecture.rst:46-48``). Expert weights are stacked on a
+leading dim (``[E, d, f]`` / ``[E, f, d]``) so they can shard over the
+``expert`` mesh axis (``VarConfig.mp_axes = {0: 'expert'}``). Which path
+runs when:
 
-All helpers degrade gracefully when the axis is unbound: single-device
-execution computes every expert locally — one model definition for both
-paths, as with ``parallel/tensor.py`` / ``parallel/pipeline.py``.
+- :func:`dropless_moe_ffn` — token-choice **top-k, no token dropped**,
+  wherever all experts are local: one device, or data-parallel replicas
+  each holding every expert (how OLMoE was trained, arXiv 2409.02060). The
+  T·k (token, expert) pairs are sorted by expert, the rows gathered into
+  that order, the three SwiGLU projections run as ONE grouped matmul
+  primitive (:func:`grouped_matmul`: the pallas ``megablox`` kernels that
+  ship with JAX) over ``group_sizes``, and the outputs are un-sorted and
+  summed back per token under their gates. Every shape is static
+  ([T·k, d]) whatever the routing; the data-dependent part is two row
+  gathers by a permutation, whose backward passes are the inverse
+  permutation's gathers.
+- :func:`moe_ffn` — **top-1 with a fixed per-expert capacity** (Switch,
+  arXiv 2101.03961; GShard, arXiv 2006.16668), the path for a BOUND
+  ``expert`` axis: tokens reach their expert's owning device with one
+  ``lax.all_to_all`` each way, which needs the fixed ``[E, C, d]`` layout;
+  dispatch and combine are dense one-hot ``[T, E, C]`` einsums and tokens
+  over capacity are dropped. With the axis unbound it computes every
+  expert locally (``models/moe_lm.py``). A dropless all-to-all is not
+  written yet (ROADMAP R4).
+
+The one-hot formulation is not what a TPU needs at these sizes: at
+OLMoE's shapes (T 8,192, E 64, k 8) the ``[T, E, C]`` dispatch tensor
+alone would be 1.07e9 elements a layer. A dynamic gather or scatter
+does not defeat XLA's tiling either: on a v5e the whole layer, forward and backward, takes 43.8 ms for 65,536 pairs of
+2,048 features, 29.1 ms of it the grouped matmuls; the same layer with
+the rows gathered by token and scatter-ADDED back takes 41.5 ms alone
+but made the OLMoE train step 2 % SLOWER than this form (the step's
+other fusions change around it), and with plain gathers, whose backward
+passes are [65536, 2048] scatters, the routing alone took 23.2 ms
+against 13.0 (my chip runs, PR 25; PERF.md section 6).
 """
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as gmm_kernel
+from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as tgmm_kernel
 
 from autodist_tpu import const
+from autodist_tpu.ops import pallas_mode
 from autodist_tpu.parallel.sequence import axis_bound
+from autodist_tpu.telemetry import scopes
 
 
 def top1_dispatch(router_probs, capacity: int):
@@ -116,3 +144,140 @@ def moe_ffn(x, router_w, w1, b1, w2, b2,
         y = _combine_a2a(y, axis_name, E)                    # [E, C, d]
     out = jnp.einsum("tec,ecd->td", combine, y)
     return out.reshape(lead + (d,)), aux.astype(jnp.float32)
+
+
+# ------------------------------------------------ dropless top-k routing
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv_perm):
+    """``x[perm]`` for a permutation ``perm`` of x's rows. The backward
+    pass of a gather is a scatter-add; of a permutation it is the gather
+    by the inverse permutation, which is what this rule says."""
+    return jnp.take(x, perm, axis=0)
+
+
+def _permute_rows_fwd(x, perm, inv_perm):
+    return jnp.take(x, perm, axis=0), (perm, inv_perm)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inv_perm = res
+    return jnp.take(g, inv_perm, axis=0), None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+# (rows, contraction, columns) tile of the grouped matmul kernels: the
+# fastest of those measured on a v5e at OLMoE's shapes ([65536, 2048] x
+# [64, 2048, 1024], groups as uneven as the cell's; PERF.md section 6,
+# PR 25). A row tile that two groups share is visited once for each, so a
+# smaller row tile also wastes less at the 63 group boundaries. The rows
+# of a call must be a multiple of the row tile: it shrinks to what
+# divides them.
+_GMM_TILE = (256, 2048, 1024)
+# the weight-gradient kernel keeps a [contraction, columns] float32 tile
+# in VMEM: at 2048 x 1024 the v5e's compiler refuses the train step
+_TGMM_TILE = (256, 1024, 1024)
+
+
+def _tile(limit, m, k, n):
+    return (math.gcd(m, limit[0]), min(k, limit[1]), min(n, limit[2]))
+
+
+@jax.custom_vjp
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs[rows of group e] @ rhs[e]`` for every group e, the rows of
+    ``lhs`` [M, K] sorted by group, ``rhs`` [E, K, N], ``group_sizes`` [E]
+    int32 summing to M: [M, N] in lhs's dtype, float32 accumulation. The
+    one grouped-matmul primitive of the tree: megablox's pallas kernels
+    (compiled on a TPU, interpreted on the CPU test backend) under one
+    backward rule. Both rules are traced under the CALLER's scopes."""
+    return _grouped_matmul_fwd(lhs, rhs, group_sizes)[0]
+
+
+def _gmm(lhs, rhs, group_sizes, transpose_rhs=False):
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    return gmm_kernel(
+        lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+        tiling=_tile(_GMM_TILE, lhs.shape[0], lhs.shape[1], n),
+        transpose_rhs=transpose_rhs, interpret=pallas_mode.interpret())
+
+
+def _grouped_matmul_fwd(lhs, rhs, group_sizes):
+    return _gmm(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _grouped_matmul_bwd(res, g):
+    lhs, rhs, group_sizes = res
+    d_lhs = _gmm(g, rhs, group_sizes, transpose_rhs=True)
+    d_rhs = tgmm_kernel(
+        lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
+        _tile(_TGMM_TILE, lhs.shape[0], lhs.shape[1], g.shape[1]),
+        interpret=pallas_mode.interpret())
+    return d_lhs, d_rhs, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def router_losses(logits, probs, counts):
+    """(load-balance loss, z-loss) of one layer over the tokens it sees.
+
+    ``L_lb = E * sum_e f_e P_e`` with ``f_e`` = routed pairs that chose e
+    / T (so sum_e f_e = k) and ``P_e`` the mean router probability of e;
+    ``L_z = mean_t logsumexp(logits_t)^2`` (arXiv 2409.02060 eqs. 2-3;
+    HF ``load_balancing_loss_func``). ``counts`` carries no gradient."""
+    T, E = probs.shape
+    frac = counts.astype(jnp.float32) / T
+    lb = E * jnp.sum(frac * jnp.mean(probs, axis=0))
+    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    return lb, z
+
+
+def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
+                     dtype=None):
+    """Token-choice top-k SwiGLU MoE with every expert local and no token
+    dropped. Returns (output with x's shape, load-balance loss, z-loss,
+    routed pairs per expert [E] int32: the layer's load).
+
+    - ``x``: [..., d] activations; flattened to T tokens internally.
+    - ``router_w``: [d, E]; logits and softmax in float32 over all E; the
+      gate is the softmax probability itself (no renormalisation over the
+      chosen k: HF ``norm_topk_prob`` false).
+    - ``w_gate``/``w_up``: [E, d, f], ``w_down``: [E, f, d]; computed in
+      ``dtype`` with float32 accumulation of the per-token combine:
+      ``sum_j g_j * down_ej(silu(gate_ej(x)) * up_ej(x))``.
+    """
+    dt = dtype or x.dtype
+    d = x.shape[-1]
+    tokens = x.reshape(-1, d)
+    T, E = tokens.shape[0], w_gate.shape[0]
+    with scopes.scope(scopes.MOE):
+        with scopes.scope(scopes.MOE_ROUTE):
+            # true float32 (a TPU's default would round to bf16 passes):
+            # [T, d] x [d, E] is small, and near-ties decide the routing
+            logits = jnp.dot(tokens.astype(jnp.float32),
+                             router_w.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            probs = jax.nn.softmax(logits, axis=-1)
+            gate, expert = jax.lax.top_k(probs, top_k)           # [T, k]
+            pair_expert = expert.reshape(-1)                     # [T*k]
+            order = jnp.argsort(pair_expert, stable=True)        # by expert
+            inv_order = jnp.argsort(order)
+            # pairs per expert by comparison, not bincount's scatter-add
+            counts = jnp.sum(pair_expert[:, None] == jnp.arange(E)[None, :],
+                             axis=0, dtype=jnp.int32)
+            pairs = jnp.repeat(tokens.astype(dt), top_k, axis=0)  # [T*k, d]
+            xs = _permute_rows(pairs, order, inv_order)
+        with scopes.scope(scopes.MOE_EXPERTS):
+            h = (jax.nn.silu(grouped_matmul(xs, w_gate.astype(dt), counts))
+                 * grouped_matmul(xs, w_up.astype(dt), counts))
+            ys = grouped_matmul(h, w_down.astype(dt), counts)    # [T*k, d]
+        with scopes.scope(scopes.MOE_ROUTE):
+            ys = _permute_rows(ys, inv_order, order)             # token order
+            out = jnp.sum(ys.reshape(T, top_k, d).astype(jnp.float32)
+                          * gate[:, :, None], axis=1)
+        lb, z = router_losses(logits, probs, counts)
+    return out.astype(dt).reshape(x.shape), lb, z, counts

@@ -73,6 +73,11 @@ class MetricsHandle:
                     self._host = self._remapper.remap_fetch(self._device)
             self._device = None  # free the device buffers
             tel.counter_add("runner.readbacks")
+            if tel.tracing_enabled() and isinstance(self._host, dict):
+                # what the DEVICE counted in these steps (the loss's
+                # telemetry.device_counters; [k] per name when fused)
+                for name, value in self._host.get("counters", {}).items():
+                    tel.counter_add(name, float(np.sum(value)))
             tel.counter_add("runner.d2h_bytes", sum(
                 getattr(np.asarray(leaf), "nbytes", 0)
                 for leaf in jax.tree_util.tree_leaves(self._host)))

@@ -52,6 +52,9 @@ LEAN_HEAD_BWD = "lean_head_bwd"
 EMBED = "embed"
 BLOCKS = "blocks"
 ATTENTION = "attention"
+MOE = "moe"
+MOE_ROUTE = "moe_route"
+MOE_EXPERTS = "moe_experts"
 PREFILL = "prefill"
 DECODE = "decode"
 INSERT = "insert"
@@ -73,6 +76,12 @@ SCOPES: Dict[str, str] = {
     EMBED: "token and position embedding of the LM",
     BLOCKS: "the transformer blocks of the LM",
     ATTENTION: "multi-head attention inside a block",
+    MOE: "the routed feed-forward of a block (parallel/expert.py "
+         "dropless_moe_ffn), its router losses included",
+    MOE_ROUTE: "inside moe: router matmul, softmax, top-k, the sort by "
+               "expert, the row gather, and the un-sort and gated combine",
+    MOE_EXPERTS: "inside moe: the three grouped matmuls over the sorted "
+                 "rows and the SwiGLU activation between them",
     PREFILL: "serving: the prompt pass of a prefill bucket",
     DECODE: "serving: one cached decode step",
     INSERT: "serving: writing admitted rows into the decode state",

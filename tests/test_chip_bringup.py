@@ -93,14 +93,17 @@ def test_compile_cache_is_placed_from_outside(monkeypatch):
 
 
 def test_kernels_interpret_on_cpu_only(monkeypatch):
-    from autodist_tpu.ops import flash_attention
+    from autodist_tpu.ops import pallas_mode
     assert jax.default_backend() == "cpu"
-    assert flash_attention._interpret() is True
+    assert pallas_mode.interpret() is True
+    with pallas_mode.compiling_for_tpu():  # a device-less compile
+        assert pallas_mode.interpret() is False
+    assert pallas_mode.interpret() is True
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert flash_attention._interpret() is False
+    assert pallas_mode.interpret() is False
     monkeypatch.setattr(jax, "default_backend", lambda: "rocm")
     with pytest.raises(RuntimeError, match="rocm"):
-        flash_attention._interpret()
+        pallas_mode.interpret()
 
 
 def test_chip_smoke_has_no_cpu_path():
