@@ -160,8 +160,16 @@ class DistributedStep:
         # per program build (the counter is pre-registered at zero, so
         # scrapers see the key either way); overlap.exposed_wait_ms
         # accrues in the runner's barrier wait when the program overlaps
-        ostages = int(self.metadata.get("overlap_stages", 0))
-        if self.metadata.get("overlap") and ostages:
+        # (PR 18's barrier schedule); on the default path, the groups of
+        # the exchange where the program compiled with asynchronous
+        # collectives (a program without them overlaps nothing: 0)
+        if self.metadata.get("overlap"):
+            ostages = int(self.metadata.get("overlap_stages", 0))
+        elif self.metadata.get("async_collectives"):
+            ostages = len(self.metadata.get("grad_sync_groups", ()))
+        else:
+            ostages = 0
+        if ostages:
             tel.counter_add("overlap.buckets", ostages)
 
     def _count_wire(self, microsteps: int = 1) -> None:
@@ -793,6 +801,46 @@ class GraphTransformer:
         return Replicator.apply(self._mesh, batch_axes, self._seq_axis,
                                 self._strategy.graph_config.seq_feed_keys)
 
+    def _local_batch_avals(self):
+        """The example batch as one device sees it inside the step's
+        shard_map. ReplicaInfo is the SAME source the shard_map in_specs
+        use, so these shapes cannot disagree with the actual split."""
+        rep = self._replica_info()
+
+        def local_aval(path, leaf):
+            return jax.ShapeDtypeStruct(
+                rep.local_shape(np.shape(leaf), _normalize_path(path)),
+                np.asarray(leaf).dtype
+                if not hasattr(leaf, "dtype") else leaf.dtype)
+        return jax.tree_util.tree_map_with_path(
+            local_aval, self._item.example_batch)
+
+    def _grad_ready_order(self, grad_jaxpr=None) -> Dict[str, int]:
+        """Where the backward pass completes each variable's gradient
+        (``collectives.grad_readiness``), on more than one replica only.
+        ``grad_jaxpr``: the loss's gradient jaxpr where the lowering has
+        traced one already (the sparse-wire safety check); otherwise one
+        is traced here on the per-device shapes. Where the loss cannot
+        be traced outside the step, the reverse position in the params
+        tree stands in (later layers' gradients come first): the order
+        only places collectives, it changes no value."""
+        item = self._item
+        names, _, _ = variable_utils.flatten_named(item.params)
+        if grad_jaxpr is None:
+            loss = ((lambda p, b: item.loss_fn(p, b)[0]) if item.has_aux
+                    else item.loss_fn)
+            from autodist_tpu.utils.axis_env import bound_axes
+            try:
+                with bound_axes():
+                    grad_jaxpr = jax.make_jaxpr(jax.grad(loss))(
+                        jax.eval_shape(lambda t: t, item.params),
+                        self._local_batch_avals()).jaxpr
+            except Exception as e:  # noqa: BLE001 — the order is best-effort
+                logging.warning("gradient readiness not read from the "
+                                "loss (%s); using reverse tree order", e)
+                return {n: -i for i, n in enumerate(names)}
+        return collectives.grad_readiness(grad_jaxpr, names)
+
     def _build_synchronizers(self, layouts, ps_names=frozenset(),
                              sparse_wire=frozenset(),
                              zero_names=frozenset()) -> Dict[str, Synchronizer]:
@@ -1158,6 +1206,7 @@ class GraphTransformer:
                      and not layouts[n].partitioned
                      and not layouts[n].mp_axes))}
         sparse_specs = {}
+        grad_jaxpr = None  # the backward pass, where a trace of it exists
         if sparse_candidates and item.loss_fn is not None:
             loss_plain = (lambda p, b: item.loss_fn(p, b)[0]) if item.has_aux \
                 else item.loss_fn
@@ -1165,15 +1214,7 @@ class GraphTransformer:
             # (local) batch shape, not the host-global one. ReplicaInfo is
             # the SAME source the shard_map in_specs use below, so the tap
             # shapes cannot disagree with the actual batch split.
-            rep = self._replica_info()
-
-            def local_aval(path, leaf):
-                return jax.ShapeDtypeStruct(
-                    rep.local_shape(np.shape(leaf), _normalize_path(path)),
-                    np.asarray(leaf).dtype
-                    if not hasattr(leaf, "dtype") else leaf.dtype)
-            local_batch = jax.tree_util.tree_map_with_path(
-                local_aval, item.example_batch)
+            local_batch = self._local_batch_avals()
             discovered = set()
             # the taps/safety traces run OUTSIDE the step's shard_map but
             # the loss may use mesh collectives (ring attention, Megatron
@@ -1199,9 +1240,11 @@ class GraphTransformer:
                     full_names, _, _ = variable_utils.flatten_named(
                         item.params)
                     with bound_axes():
-                        safe = embedding_lib.safe_sparse_names(
+                        grad_jaxpr = embedding_lib.tap_grad_jaxpr(
                             loss_plain, item.params, local_batch,
-                            sparse_specs, full_names)
+                            sparse_specs)
+                    safe = embedding_lib.safe_sparse_names(
+                        grad_jaxpr, sparse_specs, full_names)
                     tied = sorted(set(sparse_specs) - safe)
                     if tied:
                         # info, not warning: a deliberate, correct routing
@@ -1531,6 +1574,36 @@ class GraphTransformer:
             grad_schedule = collectives.build_grad_sync_schedule(
                 units, var_pos)
 
+        # ----- the exchange under the rest of the step: the default path
+        # on more than one replica. Variables whose sync is a plain
+        # mean-psum are summed in the order the backward pass completes
+        # their gradients, and the step compiles with the options that
+        # let the TPU run each all-reduce beside a matmul of the backward
+        # pass or an update of the optimizer (collectives.py, "the
+        # exchange under the rest of the step"). Compressed, quantized,
+        # routed, partitioned and ZeRO units keep their own kernels and
+        # their place behind the plain sums.
+        plain, sync_order, sync_groups = {}, [], []
+        if N > 1 and grad_schedule is None:
+            plain = {n: axes for n, s in syncs.items()
+                     if n not in bucketed_names
+                     and (axes := s.plain_sum_axes()) is not None}
+            ready = (self._grad_ready_order(grad_jaxpr) if len(plain) > 1
+                     else {})
+            entries = sorted(
+                ((n, ready.get(n, 0), int(var_infos[n].byte_size),
+                  (str(var_infos[n].dtype), axes))
+                 for n, axes in plain.items()), key=lambda e: (e[1], e[0]))
+            sync_order = [e[0] for e in entries]
+            sync_groups = collectives.plan_grad_sync_groups(entries)
+        train_options = collectives.async_collective_options(
+            self._mesh.devices.flat[0].platform, N)
+        # (no ``compiler_options`` argument at all where there are none:
+        # one replica, the CPU: those steps compile as they always did)
+        train_jit = (functools.partial(jax.jit,
+                                       compiler_options=train_options)
+                     if train_options else jax.jit)
+
         def _health_verdict(synced, ps_grads, new_params, global_loss):
             """The in-graph sentinel verdict: global gradient L2 norm,
             nonfinite counts over the synced gradients (incl. the PS
@@ -1792,10 +1865,15 @@ class GraphTransformer:
                             out = _run_var(uname, gin)
                         token = collectives.overlap_token(out)
                 else:
-                    # epilogue lowering: ZeRO reduce-scatters, then concat
-                    # buckets, then per-var syncs — one contiguous block after
-                    # the full backward (the pre-overlap baseline, and the
-                    # N == 1 / overlap-off path)
+                    # epilogue lowering (the default, and the N == 1 path):
+                    # the plain sums in the order the backward pass
+                    # completes their gradients (no value depends on the
+                    # order; on a TPU the compiled schedule runs them
+                    # beside the compute that is left), then ZeRO
+                    # reduce-scatters, concat buckets and the remaining
+                    # per-var syncs
+                    for n in sync_order:
+                        _run_var(n, g[n])
                     for n in sorted(zero_names):
                         _run_zero(n, g[n])
                     for b in (buckets if N > 1 else []):
@@ -1982,8 +2060,9 @@ class GraphTransformer:
             in_specs=(state_specs, ps_specs, batch_specs),
             out_specs=(state_specs, ps_out_specs, metric_specs),
             check_vma=False)
-        step_fn = jax.jit(sharded, donate_argnums=(0,) if self._donate else ())
-        step_fn_nodonate = jax.jit(sharded) if self._donate else step_fn
+        step_fn = train_jit(sharded,
+                            donate_argnums=(0,) if self._donate else ())
+        step_fn_nodonate = train_jit(sharded) if self._donate else step_fn
         eval_fn = jax.jit(jax.shard_map(
             local_eval, mesh=self._mesh,
             in_specs=(state_specs, ps_specs, batch_specs),
@@ -2264,8 +2343,8 @@ class GraphTransformer:
                 out_specs=(state_specs, ps_raw_specs, ps_opt_specs,
                            metric_specs),
                 check_vma=False)
-            return jax.jit(sharded_multi,
-                           donate_argnums=(0, 1, 2) if donate else ())
+            return train_jit(sharded_multi,
+                             donate_argnums=(0, 1, 2) if donate else ())
 
         ps_syncs = [s for s in syncs.values()
                     if s.__class__.__name__ == "PSSynchronizer"]
@@ -2306,6 +2385,22 @@ class GraphTransformer:
                 opt_total * var_infos[n].byte_size / params_total
                 * (self.num_replicas - 1) / self.num_replicas
                 for n in zero_names)
+        exchange = []
+        if N > 1 and grad_schedule is None:
+            exchange = (
+                [{"kind": "pack" if len(grp.var_names) > 1 else "var",
+                  "vars": list(grp.var_names), "bytes": grp.nbytes}
+                 for grp in sync_groups]
+                + [{"kind": "zero", "vars": [n],
+                    "bytes": int(var_infos[n].byte_size)}
+                   for n in sorted(zero_names)]
+                + [{"kind": "bucket", "vars": list(b.var_names),
+                    "bytes": b.total_size * np.dtype(b.dtype).itemsize}
+                   for b in buckets]
+                + [{"kind": "sync", "vars": [n],
+                    "bytes": int(var_infos[n].byte_size)}
+                   for n in syncs
+                   if n not in bucketed_names and n not in plain])
         metadata = {
             # proxied (device-cached) PS vars keep a single destination;
             # host-resident plans carry one owner per shard
@@ -2350,6 +2445,12 @@ class GraphTransformer:
                                if grad_schedule is not None else 0),
             "overlap_schedule": (grad_schedule.describe()
                                  if grad_schedule is not None else ""),
+            # the default path on more than one replica: the option names
+            # the training programs compile with (empty on one replica
+            # and off the TPU), and every collective the gradient
+            # exchange issues, in program order, with its payload
+            "async_collectives": sorted(train_options),
+            "grad_sync_groups": exchange,
         }
         logging.info("GraphTransformer: lowered %d vars (%d partitioned, "
                      "%d host-PS-resident, %d ZeRO-sharded, %d buckets%s) "

@@ -85,6 +85,16 @@ class AllReduceSynchronizer(Synchronizer):
             return rhd_psum(x, axes)
         return super().psum(x)
 
+    def plain_sum_axes(self):
+        axes = (self.mesh_axis,) + self.extra_axes
+        routed = (((self.spec == "DCN" or self.schedule == "hier")
+                   and any(a in self.dcn_axes for a in axes))
+                  or self.schedule == "rhd")
+        if (routed or self.compressor.name != "NoneCompressor"
+                or (self.layout is not None and self.layout.partitioned)):
+            return None
+        return axes
+
     def state_init(self, grad_shape, dtype):
         return self.compressor.state_init(grad_shape, dtype)
 

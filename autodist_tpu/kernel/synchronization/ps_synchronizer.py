@@ -62,6 +62,11 @@ class PSSynchronizer(Synchronizer):
                 "contradictory — a device-cached proxy updates in lockstep; "
                 "drop the proxy to get the async host-PS path", var_name)
 
+    def plain_sum_axes(self):
+        if self.layout is not None and self.layout.partitioned:
+            return None
+        return (self.mesh_axis,) + self.extra_axes
+
     def sync(self, grad, state):
         if self.layout is not None and self.layout.partitioned:
             local = self.psum_extra(self.layout.reduce_scatter_grad(grad))

@@ -41,6 +41,14 @@ class Synchronizer(ABC):
             return x
         return jax.lax.psum(x, self.extra_axes)
 
+    def plain_sum_axes(self):
+        """The mesh axes ``sync`` sums over, where ``sync`` is nothing but
+        ``psum(grad, axes) / num_replicas`` with no state; None otherwise.
+        The lowering issues such sums in the order the backward pass
+        completes their gradients, for the compiler to pack the small
+        ones (``parallel/collectives.py:plan_grad_sync_groups``)."""
+        return None
+
     @abstractmethod
     def sync(self, grad, state):
         """Inside shard_map: reduce this variable's gradient across the data

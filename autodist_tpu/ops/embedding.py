@@ -118,23 +118,27 @@ def discover(loss_fn, params, example_batch,
             if n in candidate_names}
 
 
-def safe_sparse_names(loss_fn, params, example_batch, specs,
-                      param_names) -> set:
+def tap_grad_jaxpr(loss_fn, params, example_batch, specs):
+    """The jaxpr of the loss's gradient w.r.t. ``params`` under tap
+    capture (one trace of the backward pass; its first outputs are the
+    gradients in params-flatten order)."""
+    def wrapped(p, taps, b):
+        with capture(taps):
+            return loss_fn(p, b)
+
+    return jax.make_jaxpr(jax.grad(wrapped, argnums=0))(
+        params, make_taps(specs), example_batch).jaxpr
+
+
+def safe_sparse_names(jaxpr, specs, param_names) -> set:
     """Subset of discovered sparse vars whose DENSE cotangent is
     structurally zero under tap capture — i.e. the table's only gradient
     path is through the lookups. A table with other differentiable uses
     (tied output embeddings, weight sharing) gets a real dense gradient
     that the sparse wire would silently drop, so those vars must stay on
-    the dense path. Checked on the gradient jaxpr: a clean table's grad is
-    a broadcast of literal zero."""
-    def wrapped(p, taps, b):
-        with capture(taps):
-            return loss_fn(p, b)
-
-    taps = make_taps(specs)
-    closed = jax.make_jaxpr(jax.grad(wrapped, argnums=0))(
-        params, taps, example_batch)
-    jaxpr = closed.jaxpr
+    the dense path. Checked on the gradient jaxpr
+    (:func:`tap_grad_jaxpr`): a clean table's grad is a broadcast of
+    literal zero."""
     producers = {}
     for eqn in jaxpr.eqns:
         for ov in eqn.outvars:

@@ -491,15 +491,19 @@ def test_a_declaration_the_loss_does_not_keep_is_refused():
 
 
 @pytest.fixture(scope="module")
-def one_v5e_chip():
+def v5e_2x2():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to test
         pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def test_the_routed_layer_compiles_for_a_v5e_at_the_published_widths(
@@ -527,6 +531,86 @@ def test_the_routed_layer_compiles_for_a_v5e_at_the_published_widths(
     # three grouped matmuls forward, six backward (gmm + tgmm each)
     assert text.count('custom_call_target="tpu_custom_call"') >= 9
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_four_chip_step_exchanges_gradients_beside_its_compute(
+        v5e_2x2, monkeypatch):
+    """The default ``AllReduce()`` step of a small LM (kernels of 1 and
+    4 MiB, as lm1b's attention and MLP kernels are against PACK_BYTES)
+    compiled for the four described chips (PR 26): the lowering gave the
+    program its asynchronous-collective options; XLA:TPU wrapped
+    gradient all-reduces with compute of the backward pass into
+    ``async_collective_fusion``s (an all-reduce overlaps nowhere else)
+    with backward compute still scheduled after the first of them; and
+    the program holds one gradient all-reduce per group the metadata
+    lists: the combiner merged the launch-bound ones and nothing else."""
+    import re
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from autodist_tpu.parallel import collectives
+    from autodist_tpu.parallel import mesh as mesh_lib
+    from autodist_tpu.train_state import TrainState
+    devices = list(v5e_2x2.devices)
+    monkeypatch.setattr(mesh_lib, "ordered_devices",
+                        lambda n=None, backend=None: devices)
+    cfg = lm.LMConfig(vocab_size=1024, d_model=512, num_layers=2,
+                      num_heads=8, mlp_dim=2048, max_seq_len=64,
+                      dtype=jnp.bfloat16)
+    loss_fn, params, batch, _ = lm.make_train_setup(cfg, seq_len=64,
+                                                    batch_size=16)
+    autodist_tpu.reset()
+    ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce(),
+                               resource_spec=cpu_spec(4))
+    opt = optax.adam(1e-3)
+    dstep = ad.build(loss_fn, opt, params, batch).distributed_step
+    meta = dstep.metadata
+    autodist_tpu.reset()
+    assert meta["async_collectives"] == sorted(
+        collectives.ASYNC_COLLECTIVE_OPTIONS)
+    groups = meta["grad_sync_groups"]
+    assert {g["kind"] for g in groups} == {"var", "pack"}
+
+    def sds(tree, pspec):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                np.shape(a), a.dtype,
+                sharding=NamedSharding(dstep.mesh, pspec)), tree)
+    state = TrainState(
+        step=sds(np.zeros((), np.int32), P()), params=sds(params, P()),
+        opt_state=sds(jax.eval_shape(opt.init, params), P()),
+        sync_state=sds(dstep._sync_state_init(), P(dstep.all_axes)))
+    text = dstep._step_fn.lower(
+        state, {}, sds(batch, P(dstep.batch_axes))).compile().as_text()
+
+    # computation name -> its lines; the entry computation's in order
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        if line[:1] not in (" ", "}") and "{" in line:
+            name = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)", line).group(1)
+            cur = comps[name] = []
+            entry = name if line.startswith("ENTRY") else entry
+        elif cur is not None:
+            cur.append(line)
+    is_grad_sum = lambda l: " all-reduce(" in l and "grad_sync" in l  # noqa: E731
+    fused = {n: ls for n, ls in comps.items()
+             if n.startswith("async_collective_fusion")
+             and any(is_grad_sum(l) for l in ls)}
+    assert fused, "no gradient all-reduce rides a compute op"
+    assert any("transpose(jvp(loss))" in l for ls in fused.values()
+               for l in ls), "none of them rides the backward pass"
+    calls = [i for i, l in enumerate(comps[entry])
+             for m in [re.search(r"calls=%([\w.\-]+)", l)]
+             if m and m.group(1) in fused]
+    backward = [i for i, l in enumerate(comps[entry])
+                if " fusion(" in l and "transpose(jvp(loss))" in l]
+    assert calls and backward and min(calls) < max(backward)
+    # one all-reduce of gradients per group: those left alone stand in
+    # the entry computation, a fused one counts once per chain (a long
+    # one is cut into steps over several fusions, all of one chain)
+    alone = sum(is_grad_sum(l) for l in comps[entry])
+    chains = {m.group(1) for ls in fused.values() for l in ls
+              if is_grad_sum(l)
+              for m in [re.search(r'chain_id="(\d+)"', l)] if m}
+    assert alone + len(chains) == len(groups)
 
 
 # ---- what the cell's loss_rtol refuses (benchmark/tools/loss_limit.py)
