@@ -23,11 +23,6 @@ jaxpr pretty-print — for hazards no plan-level rule can see:
   (``Runner.lowered_text(..., fuse_steps=k)``) the loop body IS the
   microstep, so one such transfer serializes every microstep on PCIe and
   undoes exactly the k× host-round-trip saving fusion exists for.
-- ``ADT409``: the overlap schedule is armed (``overlap_armed=True``) but
-  the program contains no ``optimization_barrier`` chain — the k-stage
-  bucketed sync degenerated to a single sync unit, so XLA's collective
-  combiner is free to merge every gradient reduce back into one epilogue
-  and no communication hides behind the backward pass.
 
 Text-based on purpose: it works on any ``as_text()`` dump (including ones
 saved from a real TPU run) without re-lowering, and it has no opinion
@@ -71,10 +66,6 @@ _BRANCH_BRACKET_TOKENS = ("cond[",)
 _LOOP_BRACE_TOKENS = ("stablehlo.while", "mhlo.while")
 _LOOP_BRACKET_TOKENS = ("scan[", "while[")
 
-# StableHLO / jaxpr spellings of the sequencing barrier the overlap
-# schedule chains stages with (k stages emit k-1 of them)
-_BARRIER_TOKENS = ("optimization_barrier", "opt-barrier")
-
 
 def _line_tensor_shapes(line: str) -> List[Tuple[int, ...]]:
     return [tuple(int(x) for x in m.group(1).split("x"))
@@ -82,17 +73,14 @@ def _line_tensor_shapes(line: str) -> List[Tuple[int, ...]]:
 
 
 def lint_lowered_text(text: str,
-                      mp_full_shapes: Optional[Dict[str, Sequence[int]]] = None,
-                      overlap_armed: bool = False) -> List[Diagnostic]:
+                      mp_full_shapes: Optional[Dict[str, Sequence[int]]] = None
+                      ) -> List[Diagnostic]:
     """Scan a lowered-program dump for communication hazards.
 
     ``mp_full_shapes`` maps model-parallel variable names to their FULL
     (global) shapes; an all-gather whose result matches one of them is
     flagged as ADT405. Without it the all-gather check is skipped (there
     is no way to tell an accidental full gather from a legitimate one).
-    ``overlap_armed`` says the plan lowered with the bucketed overlap
-    schedule (``DistributedStep.metadata["overlap"]``); the ADT409 check
-    then verifies the sequencing chain actually reached the program.
     """
     out: List[Diagnostic] = []
     full_shapes = {tuple(int(d) for d in shape): name
@@ -204,19 +192,6 @@ def lint_lowered_text(text: str,
             branch_spans.pop()
         while loop_spans and bracket_depth <= loop_spans[-1]:
             loop_spans.pop()
-    if overlap_armed:
-        barriers = sum(text.count(tok) for tok in _BARRIER_TOKENS)
-        if barriers == 0:
-            out.append(warning(
-                "ADT409",
-                "overlap schedule armed but the lowered program has no "
-                "optimization_barrier chain — the bucketed sync "
-                "degenerated to a single stage, so XLA may combine every "
-                "gradient collective back into one serialized epilogue "
-                "and nothing hides behind the backward pass",
-                fixit="split the gradient sync into >= 2 stages: shrink "
-                      "chunk_size (more, smaller buckets) or drop "
-                      "overlap and keep the plain epilogue"))
     return sort_diagnostics(out)
 
 
@@ -246,9 +221,6 @@ def lint_runner(runner, batch, state=None,
     (``analysis/numerics.py``) rides the same lowered text."""
     from autodist_tpu.analysis import numerics
     text = runner.lowered_text(batch, state, fuse_steps=fuse_steps)
-    out = lint_lowered_text(
-        text, mp_full_shapes_of(runner.distributed_step),
-        overlap_armed=bool(
-            runner.distributed_step.metadata.get("overlap", False)))
+    out = lint_lowered_text(text, mp_full_shapes_of(runner.distributed_step))
     out.extend(numerics.lint_text(text))
     return sort_diagnostics(out)

@@ -156,21 +156,13 @@ class DistributedStep:
         saved = float(self.metadata.get("zero_hbm_saved_bytes", 0.0))
         if saved:
             tel.gauge_set("zero.hbm_saved_bytes", saved)
-        # overlapped gradient-sync schedule: credit the stage count once
-        # per program build (the counter is pre-registered at zero, so
-        # scrapers see the key either way); overlap.exposed_wait_ms
-        # accrues in the runner's barrier wait when the program overlaps
-        # (PR 18's barrier schedule); on the default path, the groups of
-        # the exchange where the program compiled with asynchronous
-        # collectives (a program without them overlaps nothing: 0)
-        if self.metadata.get("overlap"):
-            ostages = int(self.metadata.get("overlap_stages", 0))
-        elif self.metadata.get("async_collectives"):
-            ostages = len(self.metadata.get("grad_sync_groups", ()))
-        else:
-            ostages = 0
-        if ostages:
-            tel.counter_add("overlap.buckets", ostages)
+        # the groups of the gradient exchange, credited once per program
+        # build where the program compiled with asynchronous collectives
+        # (a program without them overlaps nothing; the counter is
+        # pre-registered at zero, so scrapers see the key either way)
+        if self.metadata.get("async_collectives"):
+            tel.counter_add("overlap.buckets",
+                            len(self.metadata.get("grad_sync_groups", ())))
 
     def _count_wire(self, microsteps: int = 1) -> None:
         if self._wire_q_step:
@@ -1512,68 +1504,6 @@ class GraphTransformer:
         # int8 quantized rings: one ring per reduced mesh axis, in order
         ring_axes = tuple((a, int(self._mesh.shape[a])) for a in all_axes)
 
-        # ----- communication–computation overlap (graph_config.overlap):
-        # gradient sync lowers as a collective SCHEDULE
-        # (collectives.GradSyncSchedule) instead of one epilogue — the
-        # exact same sync units (concat buckets, per-var syncs, ZeRO
-        # reduce-scatters; identical membership and math, so values stay
-        # bit-identical), ordered by reverse layer position (the backward
-        # sweep produces the LAST layer's gradients first) and chained
-        # through optimization_barrier so XLA's all-reduce combiner cannot
-        # re-merge them into one epilogue payload and the latency-hiding
-        # scheduler can run each stage's collective under the remaining
-        # backward compute. The optimizer apply interleaves per-bucket at
-        # the dataflow level: each variable's update ops depend only on
-        # its own synced gradient, so XLA schedules them as stages drain
-        # rather than behind the full gradient. mp/sparse/PS collectives
-        # stay outside the schedule (they are forward-coupled or leave
-        # the device), and the sentinel verdict still judges the COMPLETE
-        # synced gradient — it consumes every stage's output.
-        overlap_req = bool(getattr(self._strategy.graph_config,
-                                   "overlap", False))
-        overlap_armed = overlap_req and N > 1
-        if overlap_armed and ps_store is not None and (
-                ps_store.max_staleness() > 0 or ps_store.any_async()):
-            # stale/async PS pushes already decouple from the step clock;
-            # barrier-ordering device collectives against a wire that
-            # intentionally lags would pin the schedule to the slowest
-            # (host) path. The searcher's canon never emits this combo —
-            # disarm defensively for hand-built strategies.
-            logging.warning(
-                "overlap disarmed: stale/async host-PS plan — the PS wire "
-                "is already decoupled from the step; remove staleness/"
-                "async or drop overlap to silence this")
-            overlap_armed = False
-        grad_schedule = None
-        if overlap_armed:
-            full_names, _, _ = variable_utils.flatten_named(item.params)
-            var_pos = {vn: i for i, vn in enumerate(full_names)}
-            units = []
-            for b in buckets:
-                units.append((
-                    "bucket:" + b.key, "reduce", tuple(b.var_names),
-                    b.total_size,
-                    "int8" if b.compressor_name.startswith("Int8")
-                    else "fp32", all_axes))
-            for n in sorted(syncs):
-                if n in bucketed_names:
-                    continue
-                units.append((
-                    "var:" + n, "reduce", (n,),
-                    int(getattr(var_infos[n], "num_elements", 0) or 0),
-                    "fp32", all_axes))
-            for n in sorted(zero_names):
-                units.append((
-                    "zero:" + n, "reduce_scatter", (n,),
-                    int(getattr(var_infos[n], "num_elements", 0) or 0),
-                    zero_syncs[n].wire_dtype, (axis,)))
-            # a degenerate (<= 1 stage) schedule still lowers as a
-            # schedule: there is nothing to overlap, and the ADT409 lint
-            # flags exactly that condition instead of silently falling
-            # back to the epilogue
-            grad_schedule = collectives.build_grad_sync_schedule(
-                units, var_pos)
-
         # ----- the exchange under the rest of the step: the default path
         # on more than one replica. Variables whose sync is a plain
         # mean-psum are summed in the order the backward pass completes
@@ -1584,7 +1514,7 @@ class GraphTransformer:
         # routed, partitioned and ZeRO units keep their own kernels and
         # their place behind the plain sums.
         plain, sync_order, sync_groups = {}, [], []
-        if N > 1 and grad_schedule is None:
+        if N > 1:
             plain = {n: axes for n, s in syncs.items()
                      if n not in bucketed_names
                      and (axes := s.plain_sum_axes()) is not None}
@@ -1801,11 +1731,8 @@ class GraphTransformer:
                         s_ids, s_vals, int(info.shape[0]),
                         tuple(info.shape[1:]))
 
-                # the three gradient-sync unit kernels, shared verbatim by the
-                # epilogue and the overlapped schedule — the schedule only
-                # changes WHEN each unit's collective may launch (barrier
-                # chaining), never its math, so the two lowerings are
-                # bit-identical (optimization_barrier is an identity op)
+                # the three gradient-sync unit kernels: a ZeRO
+                # reduce-scatter, a concat bucket, a per-variable sync
                 def _run_zero(n, gin):
                     synced[n] = zero_syncs[n].reduce_scatter(gin)
                     return synced[n]
@@ -1839,49 +1766,21 @@ class GraphTransformer:
                             lambda a: jnp.expand_dims(a, 0), nst)
                     return synced[n]
 
-                if grad_schedule is not None:
-                    # overlapped schedule: stages in reverse layer order, each
-                    # stage's gradient inputs barrier-chained on a 1-element
-                    # token of the previous stage's reduced output — a real
-                    # data dependence that keeps the stages un-merged and
-                    # ordered by backward readiness (see build-time comment)
-                    bucket_by_key = {b.key: b for b in buckets}
-                    token = None
-                    for stg in grad_schedule.stages:
-                        op = stg.ops[0]
-                        kind, _, uname = op.unit.partition(":")
-                        if kind == "bucket":
-                            b = bucket_by_key[uname]
-                            gin = {n: g[n] for n in b.var_names}
-                            gin, token = collectives.barrier_chain(gin, token)
-                            out = _run_bucket(b, gin)
-                        elif kind == "zero":
-                            (gin,), token = collectives.barrier_chain(
-                                (g[uname],), token)
-                            out = _run_zero(uname, gin)
-                        else:
-                            (gin,), token = collectives.barrier_chain(
-                                (g[uname],), token)
-                            out = _run_var(uname, gin)
-                        token = collectives.overlap_token(out)
-                else:
-                    # epilogue lowering (the default, and the N == 1 path):
-                    # the plain sums in the order the backward pass
-                    # completes their gradients (no value depends on the
-                    # order; on a TPU the compiled schedule runs them
-                    # beside the compute that is left), then ZeRO
-                    # reduce-scatters, concat buckets and the remaining
-                    # per-var syncs
-                    for n in sync_order:
-                        _run_var(n, g[n])
-                    for n in sorted(zero_names):
-                        _run_zero(n, g[n])
-                    for b in (buckets if N > 1 else []):
-                        _run_bucket(b, g)
-                    for n in (syncs if N > 1 else ()):
-                        if n in bucketed_names or n in synced:
-                            continue
-                        _run_var(n, g[n])
+                # the plain sums in the order the backward pass completes
+                # their gradients (no value depends on the order; on a TPU
+                # the compiled schedule runs them beside the compute that
+                # is left), then ZeRO reduce-scatters, concat buckets and
+                # the remaining per-var syncs
+                for n in sync_order:
+                    _run_var(n, g[n])
+                for n in sorted(zero_names):
+                    _run_zero(n, g[n])
+                for b in (buckets if N > 1 else []):
+                    _run_bucket(b, g)
+                for n in (syncs if N > 1 else ()):
+                    if n in bucketed_names or n in synced:
+                        continue
+                    _run_var(n, g[n])
                 # non-trainable vars: zero gradient so optimizer state stays
                 # clean and the value never moves; remaining unconfigured vars
                 # (shouldn't happen post-compile) get a plain mean-psum
@@ -2386,7 +2285,7 @@ class GraphTransformer:
                 * (self.num_replicas - 1) / self.num_replicas
                 for n in zero_names)
         exchange = []
-        if N > 1 and grad_schedule is None:
+        if N > 1:
             exchange = (
                 [{"kind": "pack" if len(grp.var_names) > 1 else "var",
                   "vars": list(grp.var_names), "bytes": grp.nbytes}
@@ -2434,17 +2333,6 @@ class GraphTransformer:
             # ADT60x numerics lints and step_stats report it)
             "compute_dtype": compute_dtype,
             "grad_fault_plan": grad_plan.describe(),
-            # communication–computation overlap: did gradient sync lower
-            # as a barrier-chained schedule (vs the single epilogue)?
-            # Consumed by the ADT409 lint, the drift report, and the
-            # overlap.* telemetry; ``overlap_stages`` is the schedule's
-            # stage count (the bucket-size knob's observable)
-            "overlap": grad_schedule is not None,
-            "overlap_requested": overlap_req,
-            "overlap_stages": (grad_schedule.num_stages
-                               if grad_schedule is not None else 0),
-            "overlap_schedule": (grad_schedule.describe()
-                                 if grad_schedule is not None else ""),
             # the default path on more than one replica: the option names
             # the training programs compile with (empty on one replica
             # and off the TPU), and every collective the gradient
@@ -2453,13 +2341,11 @@ class GraphTransformer:
             "grad_sync_groups": exchange,
         }
         logging.info("GraphTransformer: lowered %d vars (%d partitioned, "
-                     "%d host-PS-resident, %d ZeRO-sharded, %d buckets%s) "
+                     "%d host-PS-resident, %d ZeRO-sharded, %d buckets) "
                      "over %d replicas",
                      len(layouts),
                      sum(1 for l in layouts.values() if l.partitioned),
-                     len(ps_names), len(zero_names), len(buckets),
-                     (", overlap x%d stages" % grad_schedule.num_stages
-                      if grad_schedule is not None else ""), N)
+                     len(ps_names), len(zero_names), len(buckets), N)
         return DistributedStep(
             mesh=self._mesh, step_fn=step_fn, step_fn_nodonate=step_fn_nodonate,
             layouts=layouts, layout_tree=layout_tree, strategy=self._strategy,

@@ -104,7 +104,10 @@ def bucket_reduce(bucket: Bucket, grads: Dict[str, jnp.ndarray], state, psum,
     return out, new_state
 
 
-# ----------------------------------------------- collective-schedule IR
+# ------------------------------------------------ one collective, named
+#
+# What synthesize_collective_candidates returns and reduction_equivalent
+# compares (below), and what analysis/topology.py's ADT522 check reads.
 
 
 VALID_OP_KINDS = ("reduce", "reduce_scatter", "all_gather")
@@ -112,9 +115,9 @@ VALID_OP_KINDS = ("reduce", "reduce_scatter", "all_gather")
 
 @dataclasses.dataclass(frozen=True)
 class CollectiveOp:
-    """One collective in the gradient-sync schedule: ``kind`` over the
-    named mesh ``axes``, reducing/gathering the sync unit ``unit`` (a
-    bucket key, ``var:<name>`` or ``zero:<name>``)."""
+    """One collective of a gradient exchange: ``kind`` over the named
+    mesh ``axes``, reducing/gathering the sync unit ``unit`` (a bucket
+    key, ``var:<name>`` or ``zero:<name>``)."""
     kind: str                       # reduce | reduce_scatter | all_gather
     unit: str
     axes: Tuple[str, ...]
@@ -123,167 +126,16 @@ class CollectiveOp:
     wire_dtype: str = "fp32"
 
 
-@dataclasses.dataclass(frozen=True)
-class ScheduleStage:
-    """An ordered stage of the schedule. ``ready_rank`` is the position
-    in the backward pass (max var index of the unit's gradients, in
-    params-flatten order) after which every op in the stage is launchable
-    — stages are emitted in DESCENDING ready_rank, i.e. reverse layer
-    order, because later layers' gradients materialize first in the
-    backward sweep. ``deps`` names earlier stage indices that must
-    complete before this stage launches (the lowering realizes them as an
-    ``optimization_barrier`` chain)."""
-    index: int
-    ops: Tuple[CollectiveOp, ...]
-    ready_rank: int = 0
-    deps: Tuple[int, ...] = ()
-
-    @property
-    def var_names(self) -> Tuple[str, ...]:
-        return tuple(n for op in self.ops for n in op.var_names)
-
-
-@dataclasses.dataclass(frozen=True)
-class GradSyncSchedule:
-    """The gradient-synchronization schedule the overlapped lowering
-    executes: ordered stages of collectives with explicit ready
-    dependencies. ``validate()`` is the IR's one structural contract —
-    the lowering, the lint, and the cost model all consume a schedule
-    that passed it."""
-    stages: Tuple[ScheduleStage, ...]
-
-    @property
-    def num_stages(self) -> int:
-        return len(self.stages)
-
-    @property
-    def num_collectives(self) -> int:
-        return sum(len(st.ops) for st in self.stages)
-
-    def validate(self) -> None:
-        seen_units = set()
-        for pos, st in enumerate(self.stages):
-            if st.index != pos:
-                raise ValueError(
-                    "schedule stage %d carries index %d — stages must be "
-                    "densely numbered in emission order" % (pos, st.index))
-            if not st.ops:
-                raise ValueError("schedule stage %d has no ops" % pos)
-            for dep in st.deps:
-                if not 0 <= dep < pos:
-                    raise ValueError(
-                        "stage %d depends on stage %d which does not "
-                        "precede it" % (pos, dep))
-            for op in st.ops:
-                if op.kind not in VALID_OP_KINDS:
-                    raise ValueError("unknown collective kind %r (stage %d)"
-                                     % (op.kind, pos))
-                if not op.axes:
-                    raise ValueError("op %r reduces over no mesh axes"
-                                     % (op.unit,))
-                if (op.kind, op.unit) in seen_units:
-                    raise ValueError("unit %r scheduled twice for %s"
-                                     % (op.unit, op.kind))
-                seen_units.add((op.kind, op.unit))
-        ranks = [st.ready_rank for st in self.stages]
-        if ranks != sorted(ranks, reverse=True):
-            raise ValueError(
-                "stages are not in reverse-readiness order (ready_rank "
-                "must be non-increasing): %r" % (ranks,))
-
-    def describe(self) -> str:
-        lines = []
-        for st in self.stages:
-            ops = ", ".join("%s(%s%s)" % (
-                op.kind, op.unit,
-                ", int8" if op.wire_dtype == "int8" else "")
-                for op in st.ops)
-            dep = (" after %s" % (",".join(map(str, st.deps)))
-                   if st.deps else "")
-            lines.append("stage %d [ready@%d]%s: %s"
-                         % (st.index, st.ready_rank, dep, ops))
-        return "\n".join(lines)
-
-
-def build_grad_sync_schedule(units, var_positions) -> GradSyncSchedule:
-    """Order gradient-sync units into a :class:`GradSyncSchedule`.
-
-    ``units`` — iterable of ``(unit_id, kind, var_names, payload_elems,
-    wire_dtype, axes)`` — one entry per sync unit the lowering would
-    execute (a concat bucket, a per-var sync, a ZeRO reduce-scatter).
-    ``var_positions`` maps var_name -> index in params-flatten order.
-
-    Stages are emitted one unit each, sorted by DESCENDING max var
-    position (reverse layer order): in the backward sweep the LAST
-    layer's gradients are produced first, so its stage launches first and
-    overlaps with the remaining backward compute. Each stage depends on
-    its predecessor — the serialized launch chain keeps XLA's all-reduce
-    combiner from re-merging the collectives into one epilogue payload
-    while leaving each free to overlap with compute."""
-    entries = []
-    for unit_id, kind, var_names, payload, wire_dtype, axes in units:
-        if kind not in VALID_OP_KINDS:
-            raise ValueError("unknown unit kind %r" % (kind,))
-        rank = max((int(var_positions.get(n, 0)) for n in var_names),
-                   default=0)
-        entries.append((rank, unit_id, kind, tuple(var_names),
-                        int(payload), wire_dtype, tuple(axes)))
-    # descending readiness rank; unit_id tie-break keeps emission stable
-    entries.sort(key=lambda e: (-e[0], e[1]))
-    stages = []
-    for i, (rank, unit_id, kind, names, payload, wire, axes) in enumerate(
-            entries):
-        op = CollectiveOp(kind=kind, unit=unit_id, axes=axes,
-                          var_names=names, payload_elems=payload,
-                          wire_dtype=wire)
-        stages.append(ScheduleStage(index=i, ops=(op,), ready_rank=rank,
-                                    deps=(i - 1,) if i else ()))
-    sched = GradSyncSchedule(stages=tuple(stages))
-    sched.validate()
-    return sched
-
-
-def overlap_token(tree):
-    """Chain token for the overlapped lowering: a 1-element data-dependent
-    view of a unit's reduced output. Deliberately NOT an arithmetic zero —
-    XLA folds ``x * 0`` and would sever the dependency the barrier chain
-    exists to create."""
-    leaves = jax.tree_util.tree_leaves(tree)
-    if not leaves:
-        return None
-    return jnp.ravel(leaves[0])[:1].astype(jnp.float32)
-
-
-def barrier_chain(tree, token):
-    """Identity on ``tree`` that XLA cannot reorder before ``token``'s
-    producers: ``optimization_barrier`` over (leaves..., token). This is
-    the sequencing primitive the overlapped lowering threads between sync
-    units — values are bit-identical to the unchained program (the
-    barrier is an identity op), but the schedule's stage order becomes a
-    real data dependence, so the all-reduce combiner cannot merge the
-    per-stage collectives back into one epilogue reduce and the
-    latency-hiding scheduler can hide each under remaining backward
-    compute. Returns ``(tree, token)`` unchanged when ``token`` is None
-    (first stage — nothing to order after)."""
-    if token is None:
-        return tree, token
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    if not leaves:
-        return tree, token
-    out = jax.lax.optimization_barrier(tuple(leaves) + (token,))
-    return jax.tree_util.tree_unflatten(treedef, out[:-1]), out[-1]
-
-
 # ------------------------------ the exchange under the rest of the step
 #
-# On more than one replica the default lowering (no ``overlap=`` asked)
-# lets the gradient exchange run beside the compute that is left: each
-# all-reduce rides a matmul of the backward pass or an update of the
-# optimizer. XLA:TPU overlaps an all-reduce only where it wraps it with
-# ONE compute op into an ``async_collective_fusion``; an all-reduce left
-# alone in the entry computation runs alone wherever the schedule puts
-# it. Three things decide which happens (PERF.md section 6, PR 26, has
-# the device-less compiles and the chip's numbers):
+# On more than one replica the lowering lets the gradient exchange run
+# beside the compute that is left: each all-reduce rides a matmul of the
+# backward pass or an update of the optimizer. XLA:TPU overlaps an
+# all-reduce only where it wraps it with ONE compute op into an
+# ``async_collective_fusion``; an all-reduce left alone in the entry
+# computation runs alone wherever the schedule puts it. Three things
+# decide which happens (PERF.md section 6, PR 26, has the device-less
+# compiles and the chip's numbers):
 #
 # - all-reduces are made asynchronous (they are synchronous by default),
 #   and elementwise (kLoop) fusions may carry one too, so that the
@@ -383,29 +235,13 @@ def wire_block_size() -> int:
     return max(int(_const.ENV.ADT_WIRE_BLOCK.val), 8)
 
 
-def _quant_i8(c):
-    """Symmetric per-tensor int8 quantization: (q, scale). A non-finite
-    input poisons the scale (NaN) so divergence propagates to the output
-    like every other reduction path, instead of being silently zeroed."""
-    absmax = jnp.max(jnp.abs(c))
-    scale = jnp.where(jnp.isfinite(absmax),
-                      jnp.maximum(absmax, 1e-30), jnp.nan) / 127.0
-    safe = jnp.where(jnp.isfinite(scale), scale, 1.0)  # keep the i8 cast defined
-    q = jnp.clip(jnp.round(c / safe), -127, 127).astype(jnp.int8)
-    return q, scale
-
-
-def _dequant_i8(q, scale):
-    return q.astype(jnp.float32) * scale
-
-
 def quant_i8_block(x, block: int = 0):
     """Blockwise-scaled symmetric int8 quantization of a flat f32 vector
     (EQuARX's wire format, arXiv 2506.17615): pad to a block multiple,
     one absmax scale per ``block`` elements. Returns ``(q, s)`` with
-    ``q: int8 [nb, block]`` and ``s: f32 [nb]``. Like :func:`_quant_i8`,
-    a non-finite block poisons its scale (NaN) so divergence propagates
-    instead of clipping away."""
+    ``q: int8 [nb, block]`` and ``s: f32 [nb]``. A non-finite block
+    poisons its scale (NaN) so divergence propagates to the output like
+    every other reduction path, instead of clipping away."""
     block = block or wire_block_size()
     L = x.shape[0]
     nb = max(-(-L // block), 1)
@@ -511,52 +347,6 @@ def int8_wire_payload_bytes(num_elements: int, itemsize: int = 4,
     return nb * block + nb * 4, int(num_elements) * int(itemsize)
 
 
-def int8_ring_all_reduce(x, axis_name: str, n: int):
-    """Sum a flat f32 vector over ``axis_name`` with an int8 wire payload
-    (EQuARX-style quantized all-reduce, arXiv 2506.17615's setting).
-
-    XLA's all-reduce cannot accumulate int8 without overflow, so the 4x
-    wire compression needs an explicit ring: a reduce-scatter of n-1
-    ppermute hops (each hop ships one int8-quantized chunk + its f32
-    scale; accumulation stays f32 locally), then an all-gather of the
-    completed chunks, quantized once. Requantization noise is bounded by
-    ~1/254 of each hop's partial-sum magnitude; pair with error feedback
-    (Int8CompressorEF) for training.
-
-    Must run inside shard_map with ``axis_name`` bound and size ``n``.
-    """
-    L = x.shape[0]
-    chunk = -(-L // n)
-    xp = jnp.pad(x, (0, n * chunk - L)).reshape(n, chunk)
-    idx = jax.lax.axis_index(axis_name)
-    perm = [(i, (i + 1) % n) for i in range(n)]
-
-    def rs_body(t, acc):
-        send_idx = (idx - t) % n
-        q, s = _quant_i8(acc[send_idx])
-        q = jax.lax.ppermute(q, axis_name, perm)
-        s = jax.lax.ppermute(s, axis_name, perm)
-        recv_idx = (idx - t - 1) % n
-        return acc.at[recv_idx].add(_dequant_i8(q, s))
-
-    acc = jax.lax.fori_loop(0, n - 1, rs_body, xp)
-    own = (idx + 1) % n  # this replica's fully-reduced chunk
-
-    def ag_body(t, carry):
-        out, q, s = carry
-        q = jax.lax.ppermute(q, axis_name, perm)
-        s = jax.lax.ppermute(s, axis_name, perm)
-        return out.at[(own - t) % n].set(_dequant_i8(q, s)), q, s
-
-    q0, s0 = _quant_i8(acc[own])
-    # the owner uses its own quantized broadcast, not the f32 original:
-    # every replica must hold BIT-IDENTICAL reduced values or SPMD param
-    # copies drift apart step by step
-    out0 = jnp.zeros_like(xp).at[own].set(_dequant_i8(q0, s0))
-    out, _, _ = jax.lax.fori_loop(1, n, ag_body, (out0, q0, s0))
-    return out.reshape(-1)[:L]
-
-
 def int8_block_all_reduce(x, axis_name: str, n: int, block: int = 0):
     """Sum a flat f32 vector over ``axis_name`` with a blockwise-scaled
     int8 wire payload in the EQuARX two-phase shape (arXiv 2506.17615):
@@ -572,7 +362,7 @@ def int8_block_all_reduce(x, axis_name: str, n: int, block: int = 0):
        SAME bytes, so reduced values are bit-identical across replicas
        (the SPMD invariant that keeps param copies from drifting).
 
-    Two collectives total (vs the ring's 2(n-1) ppermute hops) and
+    Two collectives total (vs an explicit ring's 2(n-1) ppermute hops) and
     exactly two quantizations of any element; pair with error feedback
     (``Int8CompressorEF``) for training. Must run inside shard_map with
     ``axis_name`` bound at size ``n``.

@@ -811,19 +811,11 @@ class Runner:
             worker = const.ENV.ADT_WORKER.val or "chief"
             self._coord.report_step(worker, self._step_count)
             self._coord.heartbeat(worker)
-            t_bar = time.perf_counter()
             with tel.span("runner.barrier", "runner",
                           step=self._step_count,
                           staleness=self._staleness):
                 self._coord.wait_staleness(self._step_count,
                                            self._staleness)
-            if self.distributed_step.metadata.get("overlap"):
-                # under an overlapped schedule the residual barrier wait
-                # IS the exposed (un-hidden) collective time — the number
-                # the cost model's overlap_exposed_s predicts and the
-                # drift report's overlap row compares against
-                tel.counter_add("overlap.exposed_wait_ms",
-                                (time.perf_counter() - t_bar) * 1e3)
         self._maybe_check_mirrors()
 
     def _record_step_time(self, t_begin: float):

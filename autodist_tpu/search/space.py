@@ -38,7 +38,7 @@ from autodist_tpu.strategy.ps_strategy import reduction_devices, replica_devices
 
 # gradient-bucketing granularities the search may pick (vars per group,
 # AllReduce family; one huge bucket minimizes per-collective launches,
-# small buckets overlap earlier — the cost model prices the launch count)
+# small buckets are ready earlier — the cost model prices the launch count)
 CHUNK_SIZES = (8, 32, 128, 512)
 # plan-level staleness windows for host-PS variables (sync training)
 STALENESS_CHOICES = (0, 2)
@@ -108,10 +108,6 @@ class PlanSpec:
     staleness: int = 0
     remat: Optional[str] = None
     compute_dtype: str = "f32"
-    # lower the gradient sync as a bucketed overlap schedule (reverse
-    # layer order, barrier-chained) instead of one epilogue; chunk_size
-    # doubles as the bucket-size knob for how many stages it splits into
-    overlap: bool = False
 
     def choice_map(self) -> Dict[str, VarChoice]:
         return dict(self.choices)
@@ -150,8 +146,6 @@ class PlanSpec:
             bits.append("remat=%s" % self.remat)
         if self.compute_dtype != "f32":
             bits.append("compute=%s" % self.compute_dtype)
-        if self.overlap:
-            bits.append("overlap")
         return "plan[%s]" % ",".join(bits)
 
 
@@ -287,8 +281,7 @@ class PlanSpace:
 
     def make_plan(self, choices: Dict[str, VarChoice], chunk_size: int = 128,
                   staleness: int = 0, remat: Optional[str] = None,
-                  compute_dtype: str = "f32",
-                  overlap: bool = False) -> PlanSpec:
+                  compute_dtype: str = "f32") -> PlanSpec:
         canon = tuple((n, self.canon(choices.get(n, VarChoice()), n))
                       for n in self.var_names)
         if any(c.zero for _, c in canon):
@@ -302,17 +295,9 @@ class PlanSpace:
             # f32-master guarantee — clamp rather than emit an invalid
             # plan (only the managed tiers exist in this space)
             compute_dtype = "f32"
-        # overlap by construction: the schedule sequences SYNC gradient
-        # collectives behind the backward pass — a staleness window (the
-        # lowering would disarm it with a warning) or fewer than two
-        # AllReduce-family sync units (nothing to overlap: one stage is
-        # the epilogue) drop the bit in the SPEC so describe()/dedup and
-        # the built strategy agree
-        ar_units = sum(1 for _, c in canon if c.sync == "AllReduce")
-        overlap = bool(overlap) and staleness == 0 and ar_units >= 2
         return PlanSpec(choices=canon, chunk_size=chunk_size,
                         staleness=staleness, remat=remat,
-                        compute_dtype=compute_dtype, overlap=overlap)
+                        compute_dtype=compute_dtype)
 
     # ---------------------------------------------------------------- seeds
 
@@ -391,13 +376,6 @@ class PlanSpace:
             ("seed:ar-bf16c", self.make_plan(ar, compute_dtype="bf16")),
             ("seed:zero-bf16c", self.make_plan(zero,
                                                compute_dtype="bf16")),
-            # the overlapped bucketed schedule: small chunks split the
-            # backward into more stages (earlier launches, more hiding);
-            # make_plan drops the bit on single-sync-unit models
-            ("seed:ar-overlap", self.make_plan(ar, chunk_size=8,
-                                               overlap=True)),
-            ("seed:zero-overlap", self.make_plan(zero, chunk_size=8,
-                                                 overlap=True)),
         ]
         if "hier" in self.schedule_options:
             # the two-level schedule exists in this space (multi-host
@@ -470,8 +448,7 @@ class PlanSpace:
         if cd not in COMPUTE_DTYPES:
             return None  # an unmanaged compute tier: outside the space
         return self.make_plan(choices, staleness=staleness, remat=gc.remat,
-                              compute_dtype=cd,
-                              overlap=bool(getattr(gc, "overlap", False)))
+                              compute_dtype=cd)
 
     # ------------------------------------------------------------ mutations
 
@@ -609,23 +586,8 @@ class PlanSpace:
             def set_staleness():
                 opts = [s for s in STALENESS_CHOICES if s != plan.staleness]
                 s = opts[rng.randrange(len(opts))]
-                # arming a staleness window disarms the overlap schedule
-                # (the lowering would only warn and fall back — the spec
-                # states the truth so dedup/describe agree)
-                return (dataclasses.replace(
-                    plan, staleness=s,
-                    overlap=plan.overlap and s == 0), "stale=%d" % s)
+                return dataclasses.replace(plan, staleness=s), "stale=%d" % s
             ops.append(set_staleness)
-
-        # the overlap schedule needs >= 2 AllReduce-family sync units
-        # (else one stage IS the epilogue) and no staleness window
-        ar_units = sum(1 for n in names if cm[n].sync == "AllReduce")
-        if ar_units >= 2 and (plan.overlap or plan.staleness == 0):
-            def toggle_overlap():
-                target = not plan.overlap
-                return (dataclasses.replace(plan, overlap=target),
-                        "overlap=%s" % target)
-            ops.append(toggle_overlap)
 
         def set_remat():
             opts = [r for r in REMAT_CHOICES if r != plan.remat]
@@ -646,15 +608,6 @@ class PlanSpace:
             return None
         op = ops[rng.randrange(len(ops))]
         new_plan, desc = op()
-        if new_plan.overlap:
-            # a var-level mutation (flip_sync) may have dropped the plan
-            # below two AllReduce-family units — re-apply the plan-level
-            # canon so overlap never survives on a spec make_plan would
-            # refuse to mint
-            new_ar = sum(1 for _, c in new_plan.choices
-                         if c.sync == "AllReduce")
-            if new_ar < 2 or new_plan.staleness:
-                new_plan = dataclasses.replace(new_plan, overlap=False)
         if new_plan == plan:
             return None
         return new_plan, desc
@@ -742,5 +695,4 @@ class PlanSpace:
         return Strategy(node_config=nodes,
                         graph_config=GraphConfig(
                             replicas=list(self.replicas), remat=plan.remat,
-                            compute_dtype=plan.compute_dtype,
-                            overlap=plan.overlap))
+                            compute_dtype=plan.compute_dtype))

@@ -59,17 +59,13 @@ def _predict(breakdown, scales: Sequence[float]) -> float:
     """Step time under scaled terms — delegates to
     ``CostBreakdown.step_time_s`` on a scaled copy so the fit objective
     can never diverge from the formula simulate()/rank() use (the serial
-    epilogue sum, or the exposed-tail form when the plan lowers as an
-    overlapped schedule)."""
+    sum of the terms)."""
     c, a, p, l = scales
     return dataclasses.replace(
         breakdown, compute_s=breakdown.compute_s * c,
         allreduce_s=breakdown.allreduce_s * a,
         ps_s=breakdown.ps_s * p,
         mp_s=breakdown.mp_s * a,  # rides the same wire as gradient AR
-        # the exposed overlap tail is wire time too — same link, same
-        # bandwidth error, so the same scale corrects it
-        overlap_exposed_s=breakdown.overlap_exposed_s * a,
         latency_s=breakdown.latency_s * l).step_time_s
 
 
@@ -80,9 +76,8 @@ def _loss(breakdowns, measured, scales) -> float:
     # relative squared error: a 10ms model and a 200ms model weigh equally.
     # The log-space ridge term keeps UNIDENTIFIABLE scales at 1.0: a term
     # that is negligible in every measurement (e.g. launch latency under
-    # millisecond steps, or an overlap tail that hides almost all wire)
-    # gets no signal from the data, and without the penalty the line
-    # search would walk it to an arbitrary bound.
+    # millisecond steps) gets no signal from the data, and without the
+    # penalty the line search would walk it to an arbitrary bound.
     data = sum(((_predict(b, scales) - t) / t) ** 2
                for b, t in zip(breakdowns, measured))
     reg = _REGULARIZER * sum(math.log(s) ** 2 for s in scales)
@@ -105,9 +100,8 @@ def fit(breakdowns: Sequence, measured_s: Sequence[float],
         # golden-section comparison downstream
         raise ValueError("measured times must be positive finite seconds")
     scales = [1.0, 1.0, 1.0, 1.0]
-    # ar_scale covers everything on the collective wire (allreduce_s,
-    # mp_s AND the overlapped schedule's exposed tail — _predict applies
-    # it to all three), so an mp-only or overlap-only measurement set
+    # ar_scale covers everything on the collective wire (allreduce_s and
+    # mp_s — _predict applies it to both), so an mp-only measurement set
     # still exercises it
     terms = [lambda b: b.compute_s, lambda b: b.allreduce_s + b.mp_s,
              lambda b: b.ps_s, lambda b: b.latency_s]

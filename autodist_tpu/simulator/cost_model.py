@@ -165,15 +165,6 @@ class CostBreakdown:
     # remat shrinks activations
     hbm_bytes: float = 0.0
     hbm_capacity: float = float("inf")
-    # overlapped gradient-sync schedule (graph_config.overlap): did the
-    # plan lower sync as a barrier-chained per-bucket schedule, how many
-    # stages, and how much collective time stays EXPOSED past the end of
-    # the backward (the per-bucket max(compute_tail, wire) queueing
-    # recurrence in ``estimate()``, calibrated against the goodput
-    # report's measured collective_wait by the drift row "overlap")
-    overlap: bool = False
-    overlap_stages: int = 0
-    overlap_exposed_s: float = 0.0
 
     @property
     def feasible(self) -> bool:
@@ -181,19 +172,11 @@ class CostBreakdown:
 
     @property
     def step_time_s(self) -> float:
-        # The epilogue lowering computes the FULL gradient, then runs the
-        # collectives, then applies: compute and gradient wire add (they
-        # only overlap when the program is lowered as an overlap schedule
-        # — the old unconditional max(compute, wire) here silently
-        # credited every plan with an overlap the lowering never did).
-        # Under an overlapped schedule only the EXPOSED tail of the wire
-        # (what the backward could not hide — never less than the last
-        # bucket's reduce) is paid on top of compute. PS wire,
-        # model-parallel collectives and launch latency are serial in
-        # both lowerings, so they cancel in overlap-vs-epilogue
-        # comparisons.
-        wire = self.overlap_exposed_s if self.overlap else self.allreduce_s
-        return (self.compute_s + wire + self.ps_s
+        # Every term adds: the whole gradient exchange is priced as
+        # exposed. What the TPU lowering hides under the rest of the
+        # step has a measurement (the ledger's PR 26 line) and no fit
+        # yet (ROADMAP D10).
+        return (self.compute_s + self.allreduce_s + self.ps_s
                 + self.mp_s + self.latency_s)
 
 
@@ -678,16 +661,7 @@ class CostModel:
         groups = set()
         num_ps_transfers = 0
         num_zero_colls = 0
-        # overlapped-schedule stage accounting: one stage per concat
-        # bucket (group x compressor), per individually-synced AR var,
-        # and per ZeRO reduce-scatter — mirrors the lowering's
-        # build_grad_sync_schedule unit construction
-        from autodist_tpu.parallel.collectives import (_CONCATABLE,
-                                                       wire_quantizable)
-        overlap_groups = set()
-        overlap_pervar = 0
-        num_zero_vars = 0
-        ps_stale = False
+        from autodist_tpu.parallel.collectives import wire_quantizable
         mesh_cfg = strategy.graph_config.mesh_shape or {}
         for node in strategy.node_config:
             info = infos.get(node.var_name)
@@ -725,7 +699,6 @@ class CostModel:
                     ar_bytes += zero_wire_payload_bytes(
                         info.num_elements, n, wd) / max(len(syncs), 1)
                     num_zero_colls += 2
-                    num_zero_vars += 1
                 elif isinstance(sync, AllReduceSynchronizer):
                     if node.mp_axes and complement == 1:
                         continue  # whole mesh is model axes: no grad sync
@@ -741,28 +714,7 @@ class CostModel:
                         ar_sched_bytes[resolved] = (
                             ar_sched_bytes.get(resolved, 0.0) + contrib)
                     groups.add(sync.group)
-                    if not node.mp_axes:
-                        # schedule-unit classification, mirroring the
-                        # lowering: compressed concatable vars share a
-                        # bucket stage per (group, compressor); a
-                        # NoneCompressor var on the int8 wire gets
-                        # Int8CompressorEF substituted and buckets too;
-                        # everything else syncs as its own stage
-                        comp = (getattr(sync, "compressor", None)
-                                or "NoneCompressor")
-                        wd = getattr(sync, "wire_dtype", "fp32") or "fp32"
-                        if (comp == "NoneCompressor" and wd == "int8"
-                                and wire_quantizable(info)):
-                            comp = "Int8CompressorEF"
-                        if (not partitioned and comp != "NoneCompressor"
-                                and comp in _CONCATABLE):
-                            overlap_groups.add((sync.group, comp))
-                        else:
-                            overlap_pervar += 1
                 elif isinstance(sync, PSSynchronizer):
-                    if ((getattr(sync, "staleness", 0) or 0) > 0
-                            or not getattr(sync, "sync_mode", True)):
-                        ps_stale = True  # overlap disarms (lowering parity)
                     if sync.local_replication:
                         # proxied PS is device-resident: its sync is an
                         # on-device psum — ICI traffic, no PCIe (and no
@@ -855,41 +807,8 @@ class CostModel:
             ps_s *= cal.ps_scale
             latency_s *= cal.latency_scale
             mp_s *= cal.ar_scale  # same wire as the gradient collectives
-        # overlapped schedule (graph_config.overlap): per-bucket
-        # launch-as-ready recurrence over the CALIBRATED compute/wire
-        # terms. Buckets become launchable as the backward sweep reaches
-        # them (uniform spacing over the backward ~2/3 of compute); each
-        # reduce occupies the wire for ar/k, so
-        #   wire_free_i = max(ready_i, wire_free_{i-1}) + ar/k
-        # and the EXPOSED wait is what spills past the end of compute —
-        # never less than the tail bucket's ar/k (its gradients only
-        # exist once the backward finishes). The un-merged launch chain
-        # additionally pays one collective latency per stage, which is
-        # what makes a compute-bound spec (tiny ar, many stages) refuse
-        # overlap while a bandwidth-bound one hides ~ar*(k-1)/k.
-        overlap = (bool(getattr(strategy.graph_config, "overlap", False))
-                   and n > 1 and not ps_stale)
-        overlap_stages = 0
-        overlap_exposed_s = 0.0
-        if overlap:
-            k = max(len(overlap_groups) + overlap_pervar + num_zero_vars, 1)
-            overlap_stages = k
-            fwd = compute_s / 3.0
-            bwd = compute_s - fwd
-            w = allreduce_s / k
-            wire_free = 0.0
-            for i in range(1, k + 1):
-                ready = fwd + bwd * (i / k)
-                wire_free = max(ready, wire_free) + w
-            overlap_exposed_s = max(wire_free - compute_s, 0.0)
-            latency_s += (PER_COLLECTIVE_LATENCY_S
-                          * (cal.latency_scale if cal is not None else 1.0)
-                          * k)
         return CostBreakdown(compute_s=compute_s,
                              allreduce_s=allreduce_s, ps_s=ps_s,
                              latency_s=latency_s, mp_s=mp_s,
                              hbm_bytes=self.hbm_bytes(strategy),
-                             hbm_capacity=self._hbm_capacity,
-                             overlap=overlap,
-                             overlap_stages=overlap_stages,
-                             overlap_exposed_s=overlap_exposed_s)
+                             hbm_capacity=self._hbm_capacity)

@@ -20,8 +20,7 @@ from autodist_tpu.strategy.ps_strategy import replica_devices
 class AllReduce(StrategyBuilder):
     def __init__(self, chunk_size: int = 128, all_reduce_spec: str = "AUTO",
                  compressor: str = "NoneCompressor",
-                 wire_dtype: str = "fp32", compute_dtype: str = "f32",
-                 overlap: bool = False):
+                 wire_dtype: str = "fp32", compute_dtype: str = "f32"):
         if chunk_size < 1:
             raise ValueError("chunk_size must be >= 1")
         self.chunk_size = chunk_size
@@ -33,10 +32,6 @@ class AllReduce(StrategyBuilder):
         # "bf16": managed bf16 compute tier (f32 master params/opt-state/
         # accumulation — the shape rules.verify_numerics certifies)
         self.compute_dtype = compute_dtype
-        # overlap: lower gradient sync as a barrier-chained per-bucket
-        # schedule (reverse layer order) instead of one epilogue; pair
-        # with a small chunk_size to expose more stages to hide
-        self.overlap = overlap
 
     def build(self, model_item, resource_spec) -> Strategy:
         from autodist_tpu.parallel.collectives import wire_quantizable
@@ -56,5 +51,4 @@ class AllReduce(StrategyBuilder):
         return Strategy(node_config=nodes,
                         graph_config=GraphConfig(
                             replicas=replica_devices(resource_spec),
-                            compute_dtype=self.compute_dtype,
-                            overlap=self.overlap))
+                            compute_dtype=self.compute_dtype))

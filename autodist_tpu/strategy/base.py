@@ -295,13 +295,6 @@ class GraphConfig:
     # verdict stay f32 — the f32-master discipline the ADT60x numerics
     # rules certify (analysis/numerics.py, rules.verify_numerics)
     compute_dtype: str = "f32"
-    # communication–computation overlap: lower gradient sync as an ordered
-    # schedule of per-unit collectives chained through optimization_barrier
-    # (reverse layer order) instead of one epilogue, so XLA's latency-
-    # hiding scheduler can run each collective under the remaining
-    # backward compute. Values are bit-identical to the epilogue lowering
-    # (the barrier is an identity op); ignored at 1 replica.
-    overlap: bool = False
 
     def to_dict(self):
         return {"replicas": list(self.replicas), "mesh_shape": self.mesh_shape,
@@ -311,8 +304,7 @@ class GraphConfig:
                 "pp_schedule": self.pp_schedule,
                 "pp_virtual": self.pp_virtual,
                 "require_sparse": self.require_sparse,
-                "compute_dtype": self.compute_dtype,
-                "overlap": self.overlap}
+                "compute_dtype": self.compute_dtype}
 
     @classmethod
     def from_dict(cls, d):
@@ -326,8 +318,7 @@ class GraphConfig:
                    pp_schedule=d.get("pp_schedule"),
                    pp_virtual=d.get("pp_virtual"),
                    require_sparse=bool(d.get("require_sparse", False)),
-                   compute_dtype=d.get("compute_dtype", "f32") or "f32",
-                   overlap=bool(d.get("overlap", False)))
+                   compute_dtype=d.get("compute_dtype", "f32") or "f32")
 
 
 # ----------------------------------------------------------------- strategy

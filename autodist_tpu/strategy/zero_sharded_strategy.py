@@ -58,13 +58,9 @@ def zero_wire_quantizable(info, num_replicas: int) -> bool:
 
 class ZeroSharded(StrategyBuilder):
     def __init__(self, chunk_size: int = 128, wire_dtype: str = "fp32",
-                 compute_dtype: str = "f32", overlap: bool = False):
+                 compute_dtype: str = "f32"):
         if chunk_size < 1:
             raise ValueError("chunk_size must be >= 1")
-        # overlap: barrier-chained per-unit sync schedule (reverse layer
-        # order) — the per-var reduce-scatters launch as their gradients
-        # become ready instead of in one epilogue
-        self.overlap = overlap
         # chunk_size buckets the AllReduce FALLBACK vars (small/sparse)
         self.chunk_size = chunk_size
         # "int8": blockwise-quantized rs + update all-gather wire (dense
@@ -94,5 +90,4 @@ class ZeroSharded(StrategyBuilder):
         return Strategy(node_config=nodes,
                         graph_config=GraphConfig(
                             replicas=replica_devices(resource_spec),
-                            compute_dtype=self.compute_dtype,
-                            overlap=self.overlap))
+                            compute_dtype=self.compute_dtype))

@@ -262,13 +262,6 @@ def build_report(cost_model, strategy,
         TermDrift("mp", breakdown.mp_s, None),
         TermDrift("latency", breakdown.latency_s, None),
     ]
-    if breakdown.overlap:
-        # under the overlap schedule the residual barrier wait IS the
-        # exposed (un-hidden) collective tail — the predicted exposure
-        # joins the same measurement the allreduce row consumes, so the
-        # two rows together show how much wire the schedule actually hid
-        terms.append(TermDrift("overlap", breakdown.overlap_exposed_s,
-                               measured_wait))
 
     collectives: List[CollectiveDrift] = []
     if static_profile is not None:

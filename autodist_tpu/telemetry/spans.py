@@ -143,7 +143,7 @@ DEFAULT_COUNTERS = (
     "ps.dropped_pushes", "ps_service.applied", "ps_service.published",
     "wire.bytes_quantized", "wire.bytes_saved",
     "zero.rs_bytes", "zero.ag_bytes",
-    "overlap.buckets", "overlap.exposed_wait_ms",
+    "overlap.buckets",
     "coord.retries", "coord.reconnects", "coord.breaker_opens",
     "coord.backoff_s",
     "prefetch.batches", "prefetch.dropped_batches",

@@ -246,8 +246,6 @@ def bench_model(label, pairs=8, iters=4, deadline=None, batch_size=None):
                                     run_fw, iters)
     zero_extra = _zero_phases(loss_fn, opt, params, batch_np, run_fw,
                               iters)
-    overlap_extra = _overlap_phases(loss_fn, opt, params, batch_np,
-                                    run_fw, iters)
     bf16_extra = _bf16_phases(loss_fn, opt, params, batch_np, run_fw,
                               iters)
     adt.reset()
@@ -275,7 +273,6 @@ def bench_model(label, pairs=8, iters=4, deadline=None, batch_size=None):
     out.update(fused_extra)
     out.update(wire_extra)
     out.update(zero_extra)
-    out.update(overlap_extra)
     out.update(bf16_extra)
     out.update(search_extra)
     return out
@@ -417,45 +414,6 @@ def _zero_phases(loss_fn, opt, params, batch_np, run_fw, iters):
     except Exception as e:  # noqa: BLE001 — opt-in extra, never fatal
         print("  zero phases failed: %s" % e, file=sys.stderr, flush=True)
         return {"zero_error": "%s: %s" % (type(e).__name__, str(e)[:160])}
-
-
-def _overlap_phases(loss_fn, opt, params, batch_np, run_fw, iters):
-    """Opt-in (ADT_BENCH_OVERLAP=1) comm/compute-overlap harness for the
-    artifact rounds: builds the SAME model under
-    ``AllReduce(chunk_size=<small>, overlap=True)`` — the bucketed
-    gradient-sync schedule, reverse layer order, barrier-chained so XLA
-    can hide each bucket's reduce behind the remaining backward — trains
-    a short paired leg from identical params on identical batches,
-    ASSERTS loss parity with the epilogue path (the schedule reorders
-    WHEN collectives launch, never what they compute — tolerance
-    ADT_BENCH_OVERLAP_TOL, default 0.1%), checks the lowering really
-    armed a multi-stage schedule (metadata + overlap.buckets), and
-    reports the order-alternated paired throughput ratio. Best-effort:
-    a failure is recorded, never fatal."""
-    if (os.environ.get("ADT_BENCH_OVERLAP", "") or "").strip() not in ("1",):
-        return {}
-    from autodist_tpu import strategy
-    tol = float(os.environ.get("ADT_BENCH_OVERLAP_TOL", "0.001"))
-    steps = int(os.environ.get("ADT_BENCH_OVERLAP_STEPS", "8"))
-    chunk = int(os.environ.get("ADT_BENCH_OVERLAP_CHUNK", "8"))
-    try:
-        o_losses, f_losses, ratio, counters, orunner = \
-            _paired_strategy_phases(
-                strategy.AllReduce(chunk_size=chunk, overlap=True),
-                loss_fn, opt, params, batch_np, run_fw, iters, steps,
-                tol, "overlap schedule")
-        meta = orunner.distributed_step.metadata
-        assert meta.get("overlap"), "overlap never armed: %s" % meta
-        stages = int(meta.get("overlap_stages", 0))
-        assert stages >= 2, "degenerate %d-stage schedule" % stages
-        assert counters.get("overlap.buckets", 0.0) > 0, counters
-        return {"overlap_stages": stages,
-                "overlap_loss_final": [round(o_losses[-1], 6),
-                                       round(f_losses[-1], 6)],
-                "overlap_vs_epilogue": round(ratio, 4)}
-    except Exception as e:  # noqa: BLE001 — opt-in extra, never fatal
-        print("  overlap phases failed: %s" % e, file=sys.stderr, flush=True)
-        return {"overlap_error": "%s: %s" % (type(e).__name__, str(e)[:160])}
 
 
 def _bf16_phases(loss_fn, opt, params, batch_np, run_fw, iters):
@@ -688,7 +646,6 @@ def smoke_main(fused: bool = False):
                                       len(batches))
     quantized_result = _smoke_quantized_wire(loss_fn, params, batches)
     zero_result = _smoke_zero(loss_fn, params, batches)
-    overlap_result = _smoke_overlap(loss_fn, params, batches)
     bf16_result = _smoke_bf16(loss_fn, params, batches)
 
     t0 = time.perf_counter()
@@ -727,7 +684,6 @@ def smoke_main(fused: bool = False):
     result["sentinel"] = sentinel_result
     result["quantized_wire"] = quantized_result
     result["zero_sharded"] = zero_result
-    result["overlap"] = overlap_result
     result["bf16_compute"] = bf16_result
     result["search"] = _smoke_search(loss_fn, params, batches[0])
     result["topology"] = _smoke_topology(loss_fn, params, batches[0])
@@ -1337,72 +1293,6 @@ def _smoke_zero(loss_fn, params, batches):
             "rs_bytes": counters.get("zero.rs_bytes", 0.0),
             "ag_bytes": counters.get("zero.ag_bytes", 0.0),
             "dispatches": z_runner.distributed_step.dispatches}
-
-
-def _smoke_overlap(loss_fn, params, batches):
-    """Comm/compute-overlap leg of the smoke bench: train the smoke MLP
-    under ``AllReduce(chunk_size=1, overlap=True)`` — one sync unit per
-    variable, lowered as the reverse-layer-order barrier-chained
-    schedule — and ASSERT (a) per-step loss parity with the plain
-    epilogue loop (the schedule reorders WHEN collectives launch, never
-    what they compute), (b) the lowering really armed a multi-stage
-    schedule (metadata + the optimization_barrier chain in the lowered
-    StableHLO — the structural proof XLA received a launch order it can
-    hide), and (c) the cost model prices the schedule's exposed wire
-    tail strictly below the serial epilogue's allreduce term (the claim
-    the searcher's overlap knob ranks on). Real collective_wait
-    shrinkage needs a multi-process run (the goodput bucket reads the
-    coordinator barrier); on the CI host this leg proves structure +
-    parity + pricing instead. Gates every PR on the overlap lowering
-    compiling and staying numerically honest."""
-    import numpy as np
-    import optax
-    import autodist_tpu as adt
-    from autodist_tpu import strategy
-    from autodist_tpu.telemetry import spans as tel
-
-    def leg(builder):
-        adt.reset()
-        ad = adt.AutoDist(strategy_builder=builder)
-        runner = ad.build(loss_fn, optax.adam(1e-2), params, batches[0])
-        runner.init(params)
-        hist = runner.fit(list(batches))
-        return ([float(m["loss"]) for m in hist], runner,
-                dict(tel.counters()))
-
-    ar_losses, _ar_runner, _ = leg(strategy.AllReduce(chunk_size=1))
-    o_losses, o_runner, counters = leg(
-        strategy.AllReduce(chunk_size=1, overlap=True))
-    meta = o_runner.distributed_step.metadata
-    assert meta.get("overlap"), "overlap never armed: %s" % meta
-    stages = int(meta.get("overlap_stages", 0))
-    assert stages >= 2, "degenerate %d-stage schedule" % stages
-    text = o_runner.lowered_text(batches[0])
-    barriers = (text.count("optimization_barrier")
-                + text.count("opt-barrier"))
-    assert barriers >= 1, "no barrier chain reached the program"
-    assert counters.get("overlap.buckets", 0.0) > 0, counters
-    np.testing.assert_allclose(o_losses, ar_losses, rtol=1e-6, atol=1e-7)
-    # the pricing claim, on a spec with a real wire (the local CPU
-    # "mesh" has no modeled ICI): exposed tail < serial epilogue wire
-    from autodist_tpu.analysis.cli import default_spec
-    from autodist_tpu.model_item import ModelItem
-    from autodist_tpu.simulator.cost_model import CostModel
-    item = ModelItem(loss_fn=loss_fn, optimizer=optax.adam(1e-2),
-                     params=params, example_batch=batches[0]).prepare()
-    spec = default_spec(4)
-    cm = CostModel(item, spec)
-    bd = cm.estimate(
-        strategy.AllReduce(chunk_size=1, overlap=True).build(item, spec))
-    assert bd.overlap and bd.overlap_stages >= 2, bd
-    assert 0.0 < bd.overlap_exposed_s < bd.allreduce_s, (
-        "overlap pricing must expose less wire than the %0.3e s epilogue"
-        " (got %0.3e s)" % (bd.allreduce_s, bd.overlap_exposed_s))
-    return {"final_loss_epilogue": round(ar_losses[-1], 6),
-            "final_loss_overlap": round(o_losses[-1], 6),
-            "stages": stages, "barriers": barriers,
-            "predicted_exposed_ms": round(bd.overlap_exposed_s * 1e3, 6),
-            "predicted_epilogue_ms": round(bd.allreduce_s * 1e3, 6)}
 
 
 def _smoke_search(loss_fn, params, batch):

@@ -113,29 +113,6 @@ def test_powersgd_e2e_on_mesh():
     assert state.sync_state["var"]["w"]["error"].shape[-2:] == (16, 4)
 
 
-def test_int8_ring_all_reduce_matches_sum():
-    """The quantized ring produces bit-identical, ~1%-accurate sums."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
-    from autodist_tpu.parallel.collectives import int8_ring_all_reduce
-    devs = np.array(jax.devices())
-    mesh = Mesh(devs, ("data",))
-    rng = np.random.RandomState(0)
-    L = 1000  # not divisible by 8 -> exercises padding
-    x = rng.randn(8, L).astype(np.float32)
-    out = jax.jit(jax.shard_map(
-        lambda xs: int8_ring_all_reduce(xs.reshape(-1), "data", 8),
-        mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-        check_vma=False))(x.reshape(8 * L))
-    got = np.asarray(out).reshape(8, L)
-    exact = x.sum(axis=0)
-    # SPMD invariant: every replica holds bit-identical reduced values
-    assert np.max(np.abs(got - got[0])) == 0.0
-    rel = np.abs(got[0] - exact) / (np.abs(exact) + 1e-6)
-    assert np.median(rel) < 0.03, np.median(rel)
-
-
 def test_int8_ef_trains_to_convergence():
     """Int8CompressorEF through the full stack: error feedback recovers
     what quantization drops, converging like the uncompressed path."""

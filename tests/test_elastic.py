@@ -266,6 +266,14 @@ for i in range(start, 8):
     if is_worker and i == 2 and not os.path.exists(marker):
         with open(marker, "w") as f:
             f.write("x")
+        # only the chief writes, and it may still be writing: die once
+        # this step's checkpoint, the one the resumed job has to start
+        # from, is committed (its meta file is written last)
+        committed = os.path.join(os.environ["ADT_CKPT_DIR"],
+                                 "ckpt-%d.meta.json" % (i + 1))
+        deadline = time.time() + 60
+        while not os.path.exists(committed) and time.time() < deadline:
+            time.sleep(0.01)
         os._exit(3)  # first worker incarnation dies mid-lockstep
 with open(os.path.join(outdir, "out_%s.json" % role), "w") as f:
     json.dump({"start": start, "losses": losses,

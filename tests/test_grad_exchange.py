@@ -10,6 +10,8 @@ the same per-variable psums issued in the order they had before.
 (The device-less compile for a described v5e:2x2 lives in
 tests/test_olmoe.py, beside the one topology fixture the suite has.)
 """
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,6 +24,10 @@ from autodist_tpu.kernel import graph_transformer as gt
 from autodist_tpu.ops import embedding
 from autodist_tpu.parallel import collectives
 from autodist_tpu.resource_spec import ResourceSpec
+from autodist_tpu.strategy.base import (AllReduceSynchronizer, GraphConfig,
+                                        PSSynchronizer, Strategy, VarConfig)
+from autodist_tpu.strategy.ps_strategy import (reduction_devices,
+                                               replica_devices)
 from autodist_tpu.telemetry import spans as tel
 
 
@@ -66,11 +72,55 @@ MODELS = {"dense": _mlp, "sizes_400_to_1": lambda: _mlp(wide=True),
           "sparse_embedding": _embedding_model}
 
 
-def _train(model, fuse, sentinel, steps=6):
+class _HostPSAllReduceMix:
+    """Every other trainable variable on the host-PS store, the rest
+    all-reduced, each in a group of its own."""
+
+    def build(self, item, spec):
+        dest = reduction_devices(spec)[0]
+        nodes = [VarConfig(var_name=n, synchronizer=(
+            AllReduceSynchronizer(group=i) if i % 2 == 0 else
+            PSSynchronizer(reduction_destination=dest, sync=True)))
+            for i, n in enumerate(item.trainable_var_names)]
+        return Strategy(node_config=nodes, graph_config=GraphConfig(
+            replicas=list(replica_devices(spec))))
+
+
+# plan -> (builder, the kind each device-synced variable of the MLP
+# travels under in metadata["grad_sync_groups"]; None: not pinned here)
+PLANS = {
+    "AllReduce": (S.AllReduce, None),
+    # every variable a group of its own: four plain sums, launch-bound
+    "chunk1": (lambda: S.AllReduce(chunk_size=1),
+               dict(w1="pack", b1="pack", w2="pack", b2="pack")),
+    # two variables per concat bucket, compressed wire, bucket state
+    "compressed": (lambda: S.AllReduce(compressor="HorovodCompressor",
+                                       chunk_size=2),
+                   dict(w1="bucket", b1="bucket", w2="bucket", b2="bucket")),
+    # reduce-scatter + sharded apply; the sub-shard bias falls back
+    "ZeroSharded": (S.ZeroSharded,
+                    dict(w1="zero", b1="zero", w2="zero", b2="var")),
+    # b2 and w2 leave through the host-PS store, outside the exchange
+    "host_ps_mix": (_HostPSAllReduceMix, dict(w1="pack", b1="pack")),
+    # int8 wire under the bf16 compute tier: the matrices quantize (a
+    # bucket each), the sub-block biases stay plain sums
+    "int8_wire_bf16": (lambda: S.AllReduce(wire_dtype="int8", chunk_size=1,
+                                           compute_dtype="bf16"),
+                       dict(w1="bucket", b1="pack", w2="bucket", b2="pack")),
+}
+# the int8 plan runs on the wide MLP: on the 16-wide one every variable
+# is under one scale block and the wire self-gates to float32
+LOWERINGS = ([(m, "AllReduce") for m in sorted(MODELS)]
+             + [("dense", p) for p in ("chunk1", "compressed", "ZeroSharded",
+                                       "host_ps_mix")]
+             + [("sizes_400_to_1", "int8_wire_bf16")])
+
+
+def _train(model, fuse, sentinel, steps=6, plan="AllReduce"):
     params, loss_fn, batch = MODELS[model]()
     batches = [batch() for _ in range(steps if not fuse else 8)]
     autodist_tpu.reset()
-    ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
+    ad = autodist_tpu.AutoDist(strategy_builder=PLANS[plan][0]())
     runner = ad.build(loss_fn, optax.adam(0.05), params, batches[0],
                       sentinel=sentinel)
     runner.init(params)
@@ -82,33 +132,67 @@ def _train(model, fuse, sentinel, steps=6):
                                             runner.gather_params()),
            "opt": jax.tree_util.tree_map(
                np.asarray, dstep.gather_opt_state(runner.state)),
-           "meta": dstep.metadata}
+           "meta": dstep.metadata, "dispatches": dstep.dispatches,
+           "trainable": sorted(params),
+           "sentinel": runner.step_stats().get("sentinel")}
     autodist_tpu.reset()
     return out
 
 
-@pytest.mark.parametrize("sentinel", [None, True], ids=["plain", "sentinel"])
-@pytest.mark.parametrize("fuse", [0, 4], ids=["per_step", "fused_k4"])
-@pytest.mark.parametrize("model", sorted(MODELS))
-def test_default_lowering_is_the_per_variable_psums_bit_for_bit(
-        monkeypatch, model, fuse, sentinel):
-    """Eight replicas, six steps (eight under fuse_steps=4): parameters,
-    optimizer state, every metric and every sentinel verdict equal, bit
-    for bit, those of the same lowering with its per-variable psums in
-    the order they had before (no readiness read: all ties, tree order)."""
-    got = _train(model, fuse, sentinel)
-    monkeypatch.setattr(gt.GraphTransformer, "_grad_ready_order",
-                        lambda self, grad_jaxpr=None: {})
-    want = _train(model, fuse, sentinel)
-    assert (len(got["meta"]["grad_sync_groups"])
-            == len(want["meta"]["grad_sync_groups"]) > 0)
+def _assert_same_run(got, want):
     for key in ("metrics", "params", "opt"):
         a, b = (jax.tree_util.tree_leaves(t[key]) for t in (got, want))
         assert len(a) == len(b) and a
         for x, y in zip(a, b):
             np.testing.assert_array_equal(x, y, err_msg=key)
+
+
+@pytest.mark.parametrize("sentinel", [None, True], ids=["plain", "sentinel"])
+@pytest.mark.parametrize("fuse", [0, 4], ids=["per_step", "fused_k4"])
+@pytest.mark.parametrize("model,plan", LOWERINGS,
+                         ids=["%s/%s" % mp for mp in LOWERINGS])
+def test_default_lowering_is_the_per_variable_psums_bit_for_bit(
+        monkeypatch, model, plan, fuse, sentinel):
+    """Eight replicas, six steps (eight under fuse_steps=4): parameters,
+    optimizer state, every metric and every sentinel verdict equal, bit
+    for bit, those of the same lowering with its per-variable psums in
+    the order they had before (no readiness read: all ties, tree order).
+    Whatever the plan, step metadata names every device-synced variable
+    once, under the kind it travels as, and fusing four steps into one
+    program divides the dispatches by four."""
+    got = _train(model, fuse, sentinel, plan=plan)
+    monkeypatch.setattr(gt.GraphTransformer, "_grad_ready_order",
+                        lambda self, grad_jaxpr=None: {})
+    want = _train(model, fuse, sentinel, plan=plan)
+    assert (len(got["meta"]["grad_sync_groups"])
+            == len(want["meta"]["grad_sync_groups"]) > 0)
+    _assert_same_run(got, want)
     if sentinel:
         assert all("sentinel" in m for m in got["metrics"])
+    kinds = PLANS[plan][1]
+    if kinds is not None:
+        named = [(n, grp["kind"]) for grp in got["meta"]["grad_sync_groups"]
+                 for n in grp["vars"]]
+        assert sorted(named) == sorted(kinds.items())
+        assert sorted(kinds) == sorted(
+            set(got["trainable"]) - set(got["meta"]["ps_host_resident"]))
+    if fuse:
+        assert got["dispatches"] == want["dispatches"] == 8 // fuse
+
+
+def test_a_nan_gradient_is_skipped_once_whatever_the_order(monkeypatch):
+    """The sentinel judges the COMPLETE synced gradient: a NaN planted in
+    one variable's gradient at step 3 is skipped, once, and the run ends
+    in the same finite state with and without the readiness order."""
+    monkeypatch.setenv("ADT_GRAD_FAULT_PLAN", json.dumps(
+        {"faults": [{"var": "w1", "mode": "nan", "step": 3}]}))
+    got = _train("dense", 0, True, steps=8, plan="chunk1")
+    monkeypatch.setattr(gt.GraphTransformer, "_grad_ready_order",
+                        lambda self, grad_jaxpr=None: {})
+    want = _train("dense", 0, True, steps=8, plan="chunk1")
+    assert got["sentinel"]["skips"] == want["sentinel"]["skips"] == 1
+    assert all(np.isfinite(m["loss"]) for m in got["metrics"])
+    _assert_same_run(got, want)
 
 
 def test_the_order_is_the_backward_pass_own():

@@ -45,9 +45,10 @@ def test_block_codec_roundtrip_bound():
         sl = slice(64 * b, 64 * (b + 1))
         bound = np.abs(x[sl]).max() / 127.0 + 1e-12
         assert np.abs(back[sl] - x[sl]).max() <= bound * 1.0001
-    # per-tensor codec CANNOT hit block 0's bound (sanity of "blockwise")
-    qt, st = C._quant_i8(jnp.asarray(x))
-    back_t = np.asarray(C._dequant_i8(qt, st))
+    # one scale for the whole tensor CANNOT hit block 0's bound (sanity of
+    # "blockwise"): the same codec with the tensor as its one block
+    qt, st = C.quant_i8_block(jnp.asarray(x), block=128)
+    back_t = np.asarray(C.dequant_i8_block(qt, st, 128))
     assert (np.abs(back_t[:64] - x[:64]).max()
             > np.abs(back[:64] - x[:64]).max() * 10)
 

@@ -68,12 +68,12 @@ def _spec_2x2():
         {"nodes": [{"address": "127.0.0.1", "chief": True, "tpus": 4}]})
 
 
-def _spec_cluster(n_nodes=4, tpus=4):
+def _spec_cluster(n_nodes=4, tpus=4, ici=400):
     nodes = [{"address": "10.0.0.%d" % (i + 1), "tpus": tpus,
               "chief": i == 0, "network_bandwidth": 25}
              for i in range(n_nodes)]
     return ResourceSpec.from_dict(
-        {"nodes": nodes, "slice": {"type": "v5e", "ici_bandwidth": 400}})
+        {"nodes": nodes, "slice": {"type": "v5e", "ici_bandwidth": ici}})
 
 
 def _zoo_best_score(item, spec, sim):
@@ -168,6 +168,27 @@ def test_mutations_always_verify_or_are_pruned():
         frontier.append(child)
         checked += 1
     assert checked >= 60  # the walk genuinely explored
+
+
+def test_a_wire_bound_search_returns_a_plan_priced_with_its_whole_exchange():
+    """Where the wire dominates (4 x 2048^2 float32 gradients over a
+    10 Gbit/s interconnect) the searcher returns a plan the lowering runs
+    as the cost model priced it: the step time holds the plan's whole
+    gradient exchange, and neither the plan nor the built strategy
+    carries a switch the lowering does not read."""
+    item, spec = _mlp_item(2048, 4, 2048), _spec_cluster(2, 4, ici=10)
+    result = run_search(item, spec, SearchConfig(budget=96))
+    bd = result.record.breakdown
+    assert bd.allreduce_s > bd.compute_s > 0.0  # wire-bound, and on the wire
+    assert bd.step_time_s == pytest.approx(
+        bd.compute_s + bd.allreduce_s + bd.ps_s + bd.mp_s + bd.latency_s,
+        rel=1e-12)
+    assert "overlap" not in result.plan.describe()
+    assert "overlap" not in result.strategy.graph_config.to_dict()
+    assert not [f for f in vars(bd) if "overlap" in f]
+    # the built strategy prices the same without the searcher around it
+    again = Simulator(item, spec).simulate(result.strategy, "again").breakdown
+    assert again.step_time_s == pytest.approx(bd.step_time_s, rel=1e-12)
 
 
 def test_scorer_accounts_scored_plus_pruned():

@@ -392,6 +392,31 @@ def test_calibration_rejects_nan_measurement():
             cal_lib.fit([raw], [bad])
 
 
+def test_calibration_scales_the_whole_all_reduce():
+    """A measured set whose only error is the collective bandwidth lands
+    on ar_scale, which scales all of allreduce_s: the breakdown has no
+    other term for the gradient wire (none named overlap remains)."""
+    import dataclasses
+    from autodist_tpu.simulator import calibration as cal_lib
+    from autodist_tpu.simulator.cost_model import CostBreakdown
+    assert not [f.name for f in dataclasses.fields(CostBreakdown)
+                if "overlap" in f.name]
+    compute_only = CostBreakdown(compute_s=1e-3, allreduce_s=0.0,
+                                 ps_s=0.0, latency_s=1e-5)
+    wired = CostBreakdown(compute_s=1e-3, allreduce_s=4e-3,
+                          ps_s=0.0, latency_s=1e-5)
+    assert wired.step_time_s == pytest.approx(1e-3 + 4e-3 + 1e-5)
+    # the "hardware" runs the wire 2x slower than modeled; compute and
+    # latency are measured dead-on (pinning their scales near 1)
+    truth = dataclasses.replace(wired, allreduce_s=8e-3)
+    cal = cal_lib.fit([compute_only, wired],
+                      [compute_only.step_time_s, truth.step_time_s])
+    assert cal.ar_scale == pytest.approx(2.0, rel=0.05)
+    pred = cal_lib._predict(wired, (cal.compute_scale, cal.ar_scale,
+                                    cal.ps_scale, cal.latency_scale))
+    assert abs(pred - truth.step_time_s) / truth.step_time_s < 0.05
+
+
 # ---------------------------------------------- model-parallel accounting
 
 def _tp_case(seq_len=16, batch_size=8):

@@ -1,4 +1,12 @@
 """Strategy serialization round-trip (analog of reference ``tests/test_strategy_base.py``)."""
+import json
+
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+import autodist_tpu
+from autodist_tpu import strategy as S
 from autodist_tpu.strategy.base import (AllReduceSynchronizer, GraphConfig,
                                         PSSynchronizer, Strategy, VarConfig)
 
@@ -37,3 +45,40 @@ def test_var_config_partition_props():
 def test_nccl_alias_normalizes():
     ar = AllReduceSynchronizer(spec="NCCL")
     assert ar.spec == "ICI"
+
+
+def test_a_stored_strategy_with_the_retired_overlap_key_loads(tmp_path):
+    """A strategy file written before PR 27 may carry ``"overlap": true``
+    in its graph config. It is input from outside the program: it loads,
+    builds and lowers as the one gradient exchange there is."""
+    params = {"w": jnp.ones((8, 4), jnp.float32),
+              "b": jnp.zeros((4,), jnp.float32)}
+
+    def loss_fn(p, b):
+        return jnp.mean((b["x"] @ p["w"] + p["b"] - b["y"]) ** 2)
+
+    batch = {"x": np.ones((16, 8), np.float32),
+             "y": np.zeros((16, 4), np.float32)}
+
+    class Stored(S.AllReduce):
+        def build(self, item, spec):
+            d = super().build(item, spec).to_dict()
+            assert "overlap" not in d["graph_config"]
+            d["graph_config"]["overlap"] = True
+            path = tmp_path / "stored"
+            path.write_text(json.dumps(d))
+            loaded = Strategy.deserialize(path=str(path))
+            d["graph_config"].pop("overlap")
+            assert loaded.to_dict() == d
+            return loaded
+
+    autodist_tpu.reset()
+    ad = autodist_tpu.AutoDist(strategy_builder=Stored(chunk_size=1))
+    runner = ad.build(loss_fn, optax.sgd(0.1), params, batch)
+    runner.init(params)
+    meta = runner.distributed_step.metadata
+    autodist_tpu.reset()
+    assert [g["vars"] for g in meta["grad_sync_groups"]] == [["b", "w"]]
+    assert not [k for k in meta if k.startswith("overlap")]
+    assert not hasattr(runner.distributed_step.strategy.graph_config,
+                       "overlap")
