@@ -22,6 +22,7 @@ import numpy as np
 
 from autodist_tpu.models.layers import (SparseEmbed, TransformerBlock,
                                         causal_mask, make_norm)
+from autodist_tpu.telemetry import spans as tel
 from autodist_tpu.telemetry import device_counters, scopes
 
 # what a routed layer sows into ``counters`` and the loss reports as the
@@ -219,14 +220,46 @@ class TransformerLM(nn.Module):
                 jnp.stack(new_vs, axis=1))
 
 
+def auto_flash_attention(seq_len: int, head_dim: int, backend: str) -> bool:
+    """The ``attention="auto"`` rule: does the causal self-attention of a
+    training step lower through ``ops/flash_attention.py``? Decided from
+    the shapes and the backend alone, from readings of the whole train
+    step on a TPU v5e (PERF.md section 6, PR 28;
+    ``benchmark/records/pr28_runs.jsonl``), forced ``"flash"`` against
+    ``"default"``:
+
+    - heads of 128 (OLMoE's block, 8,192 tokens a step), sequences of
+      whole 512-row tiles: the kernel wins by +1.3 % of the step's
+      tokens/s at seq 512, +2.9 % at 1024, +6.5 % at 2048 (XLA's softmax
+      passes the [B, H, S, S] scores through HBM: 19.4 ms of attention a
+      step against 11.5). Shorter sequences at this width, and lengths
+      that leave the kernel a smaller tile (1000 tiles by 8 rows; alone,
+      128-row tiles lost to XLA, 20.5 against 15.5 ms), are not measured
+      and stay on XLA.
+    - heads of 64 at seq 256 (``lm1b_train_1chip``): the kernel LOSES 4.5 %
+      (scores of 134 MB a layer, one 256-row tile a head). Narrow heads at
+      long sequences are measured by no cell (alone, the kernel wins there
+      from seq 1024: 5.1 against 10.6 ms, not a step reading).
+    - from seq 8192 every width and length, as before this rule: XLA's
+      scores stop fitting in HBM there, so this is memory and not speed.
+    - any backend but a TPU: XLA (the kernel would run interpreted)."""
+    if backend != "tpu":
+        return False
+    if seq_len >= 8192:
+        return True
+    from autodist_tpu.ops.flash_attention import full_tiles
+    return head_dim >= 128 and full_tiles(seq_len)
+
+
 def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
                      batch_size: int = 32, seed: int = 0,
                      attention: str = "auto", lean_head="auto"):
-    """``attention``: "auto" (XLA softmax attention below seq 8192, the
-    pallas flash kernel at/above it on TPU, where XLA's [S, S] logits
-    stop fitting in HBM and the kernel's O(seq) memory keeps running),
-    "flash" (force the kernel; interpreted on the CPU backend), or
-    "default" (XLA always).
+    """``attention``: "auto" (:func:`auto_flash_attention` decides from
+    ``seq_len``, the head width and the backend: on a TPU the pallas
+    flash kernel where the chip showed it faster IN THE STEP, XLA's
+    softmax attention elsewhere and on every other backend), "flash"
+    (force the kernel; interpreted on the CPU backend), or "default"
+    (XLA always).
 
     ``lean_head``: True routes the loss through the chunked cross-entropy
     (``ops.xent.chunked_softmax_xent``) — the [tokens, vocab] fp32 logits
@@ -253,21 +286,20 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         raise ValueError("seq_len %d exceeds config.max_seq_len %d"
                          % (seq_len, cfg.max_seq_len))
     attn_fn = None
-    # "auto" (same policy as models/bert.py) switches where XLA's
-    # [S, S] logits stop fitting; which side is faster below that is not
-    # measured on today's code (the pre-PR-21 records that said XLA,
-    # ~290 vs ~244 seq/s at seq 256, were deleted).
-    if attention == "flash" or (attention == "auto"
-                                and jax.default_backend() == "tpu"
-                                and seq_len >= 8192):
-        from autodist_tpu.ops.flash_attention import make_flash_attn_fn
-        attn_fn = make_flash_attn_fn(causal=True)
-    elif attention not in ("auto", "flash", "default"):
+    if attention not in ("auto", "flash", "default"):
         raise ValueError("attention must be auto|flash|default, got %r"
                          % attention)
+    if attention == "flash" or (attention == "auto" and auto_flash_attention(
+            seq_len, cfg.d_model // cfg.num_heads, jax.default_backend())):
+        from autodist_tpu.ops.flash_attention import make_flash_attn_fn
+        attn_fn = make_flash_attn_fn(causal=True)
+    flash_layers = cfg.num_layers if attn_fn is not None else 0
     model = TransformerLM(cfg, attn_fn=attn_fn)
     rng = jax.random.PRNGKey(seed)
-    variables = jax.jit(model.init)(rng, jnp.zeros((1, seq_len), jnp.int32))
+    # the attention core holds no parameter: the init goes through XLA's
+    # path whatever ``attn_fn`` is, and builds no kernel of its own
+    variables = jax.jit(TransformerLM(cfg).init)(
+        rng, jnp.zeros((1, seq_len), jnp.int32))
     # (a routed model's init also fills what its layers sow)
     variables = {"params": variables["params"]}
 
@@ -293,6 +325,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         return loss if router_loss is None else loss + router_loss
 
     def loss_fn(params, batch):
+        # what the rule decided, once per trace, host side
+        tel.gauge_set("attention.flash_layers", flash_layers)
         tokens = batch["tokens"]
         targets = tokens[:, 1:]
         if lean_head:

@@ -155,8 +155,7 @@ def cached_attention(q, k_cache, v_cache, cursor):
     return jnp.einsum("bht,bthd->bhd", weights, v_cache)
 
 
-def flash_cached_attention(q, k_cache, v_cache, cursor,
-                           block_k: int = 128):
+def flash_cached_attention(q, k_cache, v_cache, cursor):
     """Decode-shape attention through the pallas flash kernel
     (``ops/flash_attention.py``) — the optional decode inner loop.
 
@@ -179,8 +178,7 @@ def flash_cached_attention(q, k_cache, v_cache, cursor,
     q_seg = jnp.zeros((B, 8), jnp.int32).at[:, 0].set(1)
     kv_seg = (jnp.arange(T)[None, :] <= cursor[:, None]).astype(jnp.int32)
     out = flash_attention(q_blk, k_cache, v_cache, causal=False,
-                          segment_ids=(q_seg, kv_seg),
-                          block_q=8, block_k=min(block_k, T))
+                          segment_ids=(q_seg, kv_seg))
     return out[:, 0]
 
 

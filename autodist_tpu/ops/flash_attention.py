@@ -1,11 +1,10 @@
 """Pallas TPU flash attention: fused, tiled, memory-linear exact attention.
 
 The reference has no kernel like this (its attention lives inside stock TF
-ops); on TPU the fused softmax-attention kernel is the single hottest op in
-every transformer benchmark (BERT / lm1b families, SURVEY §2.2), so it gets
-a hand-written pallas kernel: O(seq) memory instead of the O(seq^2) logits
-tensor XLA materializes, online-softmax accumulation in VMEM, matmuls on
-the MXU in fp32 accumulation.
+ops). XLA's softmax attention writes the [B, H, S, S] scores to HBM and
+reads them back several times in each direction; this kernel keeps a
+[block_q, block_k] tile of them in VMEM: O(seq) memory, online-softmax
+accumulation, matmuls on the MXU with fp32 accumulation.
 
 Design (standard FlashAttention-2 tiling, arXiv 2307.08691):
 - forward: grid (batch, heads, q_blocks, kv_blocks) with the kv dimension
@@ -15,9 +14,14 @@ Design (standard FlashAttention-2 tiling, arXiv 2307.08691):
   then two kernels — dQ over (q_blocks, kv_blocks) and dK/dV over
   (kv_blocks, q_blocks) — recompute P = exp(S - lse) tile by tile instead
   of storing it.
-- causal: fully-masked tiles are skipped at trace time via ``pl.when``
-  (upper-triangular tiles cost nothing), partial tiles are masked with
-  broadcasted iotas.
+- tiles: ``_tiles`` chooses (block_q, block_k) from the shapes; no caller
+  passes a tile size (PERF.md section 6, PR 28, has the chip's readings).
+- causal: a tile above the diagonal is skipped by ``pl.when`` AND its
+  operand blocks are not fetched (the block index maps clamp to the
+  nearest live tile, so the pipeline sees a repeated index and elides the
+  DMA).
+- layout: the model zoo's [batch, seq, heads, head_dim], transposed to
+  [batch, heads, seq, head_dim] around the kernels.
 - segment ids (BERT padding masks, packed sequences): attention is allowed
   iff ``q_seg[i] == kv_seg[j]``. Tiles whose q-segment range cannot
   intersect the kv-segment range are skipped dynamically (``pl.when`` on a
@@ -35,8 +39,7 @@ On the CPU backend the same kernels run under ``interpret=True``
 tests exercise the identical code path (tests/test_flash_attention.py
 checks fwd+grad against ``ops.attention.reference_attention``).
 
-Layout matches the rest of the model zoo: [batch, seq, heads, head_dim];
-segment ids are [batch, seq] int32.
+Segment ids are [batch, seq] int32.
 """
 import functools
 
@@ -50,6 +53,7 @@ from autodist_tpu.ops import pallas_mode
 
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp() NaN-free on masked rows
 _LANES = 128     # last-dim tile width; m/l scratch are lane-replicated
+_ROWS = 512      # rows of a q or kv tile
 
 
 def _pick_block(seq: int, want: int) -> int:
@@ -61,6 +65,62 @@ def _pick_block(seq: int, want: int) -> int:
         b //= 2
     return b if b >= 8 else 0
 
+
+def _tiles(sq: int, sk: int):
+    """(block_q, block_k) for ``sq`` queries against ``sk`` keys: as many
+    rows as divide the sequence, up to ``_ROWS``, whatever the head width,
+    the dtype and the segment ids. On the v5e larger tiles won up to 512
+    (PERF.md section 6, PR 28; [4, 2048, 16, 128] bfloat16 causal,
+    forward + backward alone: 20.5 ms at 128 rows, 9.0 at 256, 4.3 at
+    512). 1,024 rows read 4.2 alone and nothing in the step (123.81
+    against 123.79 ms), and whether the chip's VMEM held them moved with
+    dtype, segment ids, batch and heads; at 512 every case of
+    ``tests/test_olmoe.py::test_the_kernels_tiles_fit_the_v5es_vmem``
+    compiles for the v5e."""
+    return _pick_block(sq, _ROWS), _pick_block(sk, _ROWS)
+
+
+def full_tiles(seq: int) -> bool:
+    """Does ``seq`` split into whole ``_ROWS``-row tiles, the size the
+    kernel was read at in a training step? (1000 gives tiles of 8.)"""
+    return seq % _ROWS == 0
+
+
+# ----------------------------------------------------------------- layout
+
+def _specs(D, bq, bk, q_pos, k_pos):
+    """BlockSpecs of one grid (b, h, i, j) over [B, H, S, D] operands: a
+    [rows, D] tile of a q-side and of a kv-side array, a [rows, 1] tile of
+    a per-row q-side vector ([B, H, S, 1]), and the [rows, 1] q / kv
+    segment ids ([B, S, 1]: the trailing 1 satisfies the TPU's (8, 128)
+    rule as for the row vectors). ``q_pos(i, j)`` / ``k_pos(i, j)`` give
+    the row-block index. The kernels see the tiles without leading dims."""
+    def tile(rows, pos, width):
+        return pl.BlockSpec((None, None, rows, width),
+                            lambda b, h, i, j: (b, h, pos(i, j), 0))
+
+    def seg(rows, pos):
+        return pl.BlockSpec((None, rows, 1),
+                            lambda b, h, i, j: (b, pos(i, j), 0))
+
+    return (tile(bq, q_pos, D), tile(bk, k_pos, D), tile(bq, q_pos, 1),
+            seg(bq, q_pos), seg(bk, k_pos))
+
+
+def _kv_pos(causal, bq, bk):
+    """kv row-block of step (i = q block, j = kv block) of a q-major grid:
+    j, but a dead causal tile re-names the last kv block its q block sees,
+    so the pipeline finds a repeated index and fetches nothing."""
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
+
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+
+
+# ------------------------------------------------------------------ tiles
 
 def _mask_val(s, qi, ki, bq, bk, causal, qs, ks):
     """Apply causal and/or segment masking to a score tile [bq, bk]."""
@@ -86,6 +146,24 @@ def _tile_live(qi, ki, bq, bk, causal, qs, ks):
     return live
 
 
+def _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, body):
+    """Run ``body(mask)`` on a tile with a visible entry; ``mask(s)`` masks
+    a score tile [bq, bk]."""
+    qs = None if qs_ref is None else qs_ref[:, 0]
+    ks = None if ks_ref is None else ks_ref[:, 0]
+    if not causal and qs is None:
+        return body(lambda s: s)
+    pl.when(_tile_live(qi, ki, bq, bk, causal, qs, ks))(
+        lambda: body(lambda s: _mask_val(s, qi, ki, bq, bk, causal, qs, ks)))
+
+
+def _scores(q, k, scale, mask):
+    # native-dtype (bf16) MXU operands, fp32 accumulation
+    return mask(jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale)
+
+
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
@@ -103,106 +181,99 @@ def _fwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    qs = qs_ref[0, :, 0] if has_seg else None
-    ks = ks_ref[0, :, 0] if has_seg else None
-    live = _tile_live(qi, ki, bq, bk, causal, qs, ks)
+    def lanes(x, n):
+        """[rows, 128] lane-replicated -> [rows, n]."""
+        if n % _LANES == 0:
+            return jnp.tile(x, (1, n // _LANES))
+        return x[:, :n] if n < _LANES else jnp.broadcast_to(
+            x[:, :1], (x.shape[0], n))
 
-    @pl.when(live)
-    def _():
-        q, k, v = q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :]
-        # native-dtype (bf16) MXU operands, fp32 accumulation
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = _mask_val(s, qi, ki, bq, bk, causal, qs, ks)
-        m_prev = m_ref[:, :1]                            # [bq, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)        # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                           # [bq, bk]
-        # a row with NO visible key so far has m_new == NEG_INF and every
-        # score masked: exp(NEG_INF - NEG_INF) = 1 would average garbage
-        # values into the row — zero its contribution (empty rows emit 0)
-        p = jnp.where(m_new > NEG_INF * 0.5, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)                   # [bq, 1]
-        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    def tile(mask):
+        # m, l and what is derived from them stay [bq, 128], every lane the
+        # row's value: a [bq, 1] column costs a vector op as much and a
+        # lane broadcast on top (forward 1.97 -> 1.22 ms at 512 x 512
+        # tiles, PERF.md section 6, PR 28)
+        v = v_ref[...]
+        s = _scores(q_ref[...], k_ref[...], scale, mask)
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+        p = jnp.exp(s - lanes(m_new, bk))                # [bq, bk]
+        if has_seg:
+            # a row with NO visible key so far has m_new == NEG_INF and
+            # every score masked: exp(NEG_INF - NEG_INF) = 1 would average
+            # garbage values into the row — zero its contribution (empty
+            # rows emit 0). Causal rows all see column 0 in their first
+            # tile, so only segments can leave a row empty
+            p = jnp.where(lanes(m_new, bk) > NEG_INF * 0.5, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_prev * corr + jnp.sum(p, axis=1)[:, None]
+        m_ref[...] = m_new
+        acc_ref[:] = acc_ref[:] * lanes(corr, acc_ref.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, tile)
 
     @pl.when(ki == n_kv - 1)
     def _():
         l = l_ref[:, :1]
-        o_ref[0, 0, :, :] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         # empty rows (l == 0) record lse = 0, NOT NEG_INF + log(1e-30):
         # the backward pass computes p = exp(s - lse), and a huge-negative
         # lse would blow exp() up to garbage gradients for those rows;
         # with lse = 0, exp(NEG_INF - 0) = 0 and the row's grads vanish
-        lse_ref[0, 0, :, :] = jnp.where(
+        lse_ref[...] = jnp.where(
             l > 0, m_ref[:, :1] + jnp.log(jnp.maximum(l, 1e-30)), 0.0)
 
 
-def _seg_specs(bq, bk, q_major=True):
-    """BlockSpecs for segment-id arrays, carried as [B, S, 1] so the block
-    trailing dims (rows, 1) satisfy the TPU (8, 128)-divisibility rule
-    (same trick as the lse row vectors)."""
-    if q_major:
-        qs = pl.BlockSpec((1, bq, 1), lambda b, h, i, j: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-        ks = pl.BlockSpec((1, bk, 1), lambda b, h, i, j: (b, j, 0),
-                          memory_space=pltpu.VMEM)
-    else:  # kv-major grid (dk/dv kernel): i indexes kv, j indexes q
-        qs = pl.BlockSpec((1, bq, 1), lambda b, h, i, j: (b, j, 0),
-                          memory_space=pltpu.VMEM)
-        ks = pl.BlockSpec((1, bk, 1), lambda b, h, i, j: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    return qs, ks
-
-
-def _fwd(q, k, v, segs, causal, block_q, block_k):
+def _fwd(q, k, v, segs, causal):
     """q, k, v in [B, H, S, D] (kernel-internal layout); segs is None or
-    (q_seg [B, Sq], kv_seg [B, Sk]) int32."""
+    (q_seg [B, Sq], kv_seg [B, Sk]) int32. Returns (out, lse [B, H, Sq, 1])."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    bq, bk = _pick_block(Sq, block_q), _pick_block(Sk, block_k)
-    scale = float(1.0 / np.sqrt(D))
-    n_q, n_kv = Sq // bq, Sk // bk
-    grid = (B, H, n_q, n_kv)
     has_seg = segs is not None
+    bq, bk = _tiles(Sq, Sk)
+    n_q, n_kv = Sq // bq, Sk // bk
 
-    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0),
-                          memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0),
-                           memory_space=pltpu.VMEM)
-    lse_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0),
-                            memory_space=pltpu.VMEM)
+    q_spec, kv_spec, row_spec, qs_spec, ks_spec = _specs(
+        D, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk))
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k, v]
     if has_seg:
-        qs_spec, ks_spec = _seg_specs(bq, bk)
         in_specs += [qs_spec, ks_spec]
-        operands += [segs[0].astype(jnp.int32)[..., None],
-                     segs[1].astype(jnp.int32)[..., None]]
+        operands += [segs[0][..., None], segs[1][..., None]]
 
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          has_seg=has_seg, bq=bq, bk=bk, n_kv=n_kv),
-        grid=grid,
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=float(1.0 / np.sqrt(D)),
+                          causal=causal, has_seg=has_seg, bq=bq, bk=bk,
+                          n_kv=n_kv),
+        grid=(B, H, n_q, n_kv),
         in_specs=in_specs,
-        out_specs=[q_spec, lse_spec],
+        out_specs=[q_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
                         pltpu.VMEM((bq, _LANES), jnp.float32),
                         pltpu.VMEM((bq, _LANES), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=pallas_mode.interpret(),
+        name="flash_fwd",
     )(*operands)
-    return out, lse
 
 
 # ---------------------------------------------------------------- backward
+
+def _p_and_ds(q, k, v, do, lse, delta, scale, mask):
+    """(P, dS / scale) of one tile, both [bq, bk] float32: the softmax
+    weights recomputed from the saved log-sum-exp and the scores' gradient
+    before the scale, which the callers apply once to their [rows, D]
+    accumulators instead of to every tile."""
+    p = jnp.exp(_scores(q, k, scale, mask) - lse)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta)
+
 
 def _dq_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
     if has_seg:
@@ -218,29 +289,19 @@ def _dq_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    qs = qs_ref[0, :, 0] if has_seg else None
-    ks = ks_ref[0, :, 0] if has_seg else None
-    live = _tile_live(qi, ki, bq, bk, causal, qs, ks)
-
-    @pl.when(live)
-    def _():
-        q, k, v = q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :]                        # [bq, 1]
-        delta = delta_ref[0, 0, :, :]                    # [bq, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = _mask_val(s, qi, ki, bq, bk, causal, qs, ks)
-        p = jnp.exp(s - lse)                             # [bq, bk]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
+    def tile(mask):
+        k = k_ref[...]
+        _, ds = _p_and_ds(q_ref[...], k, v_ref[...], do_ref[...],
+                          lse_ref[...], delta_ref[...], scale, mask)
         acc_ref[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, tile)
 
     @pl.when(ki == n_kv - 1)
     def _():
-        dq_ref[0, 0, :, :] = acc_ref[:].astype(dq_ref.dtype)
+        dq_ref[...] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkdv_kernel(*refs, scale, causal, has_seg, bq, bk, n_q):
@@ -258,110 +319,81 @@ def _dkdv_kernel(*refs, scale, causal, has_seg, bq, bk, n_q):
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    qs = qs_ref[0, :, 0] if has_seg else None
-    ks = ks_ref[0, :, 0] if has_seg else None
-    live = _tile_live(qi, ki, bq, bk, causal, qs, ks)
-
-    @pl.when(live)
-    def _():
-        q, k, v = q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :]
-        delta = delta_ref[0, 0, :, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = _mask_val(s, qi, ki, bq, bk, causal, qs, ks)
-        p = jnp.exp(s - lse).astype(do.dtype)            # [bq, bk]
+    def tile(mask):
+        q, do = q_ref[...], do_ref[...]
+        p, ds = _p_and_ds(q, k_ref[...], v_ref[...], do, lse_ref[...],
+                          delta_ref[...], scale, mask)
         dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (jnp.exp(s - lse) * (dp - delta) * scale).astype(q.dtype)
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, tile)
 
     @pl.when(qi == n_q - 1)
     def _():
-        dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[...] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(causal, block_q, block_k, res, do):
-    """res tensors in [B, H, S, D]; do arrives/leaves in [B, S, H, D]."""
+def _bwd(causal, res, do):
+    """res tensors, do and the returned (dq, dk, dv) in [B, H, S, D]."""
     q, k, v, out, lse, q_seg, kv_seg = res
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    bq, bk = _pick_block(Sq, block_q), _pick_block(Sk, block_k)
-    scale = float(1.0 / np.sqrt(D))
-    n_q, n_kv = Sq // bq, Sk // bk
     has_seg = q_seg is not None
-    do = do.transpose(0, 2, 1, 3)
+    bq, bk = _tiles(Sq, Sk)
+    n_q, n_kv = Sq // bq, Sk // bk
+    static = dict(scale=float(1.0 / np.sqrt(D)), causal=causal,
+                  has_seg=has_seg, bq=bq, bk=bk)
 
     # delta_i = rowsum(dO_i * O_i): tiny elementwise reduce, XLA fuses it
-    delta = jnp.einsum("bhsd,bhsd->bhs", do.astype(jnp.float32),
-                       out.astype(jnp.float32))[..., None]
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)              # [B, H, Sq, 1]
 
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-
-    q_spec_i = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0),
-                            memory_space=pltpu.VMEM)
-    kv_spec_j = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0),
-                             memory_space=pltpu.VMEM)
-    row_spec_i = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0),
-                              memory_space=pltpu.VMEM)
-
-    in_specs = [q_spec_i, kv_spec_j, kv_spec_j, q_spec_i, row_spec_i,
-                row_spec_i]
     operands = [q, k, v, do, lse, delta]
     if has_seg:
-        qs_spec, ks_spec = _seg_specs(bq, bk)
-        in_specs += [qs_spec, ks_spec]
         operands += [q_seg[..., None], kv_seg[..., None]]
 
+    def in_specs(q_spec, kv_spec, row_spec, qs_spec, ks_spec):
+        specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+        return specs + [qs_spec, ks_spec] if has_seg else specs
+
+    specs = _specs(D, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          has_seg=has_seg, bq=bq, bk=bk, n_kv=n_kv),
+        functools.partial(_dq_kernel, n_kv=n_kv, **static),
         grid=(B, H, n_q, n_kv),
-        in_specs=in_specs,
-        out_specs=q_spec_i,
+        in_specs=in_specs(*specs),
+        out_specs=specs[0],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=params,
+        compiler_params=_PARAMS,
         interpret=pallas_mode.interpret(),
+        name="flash_dq",
     )(*operands)
 
-    # kv-major grid: q is the reduction (innermost) dim
-    q_spec_j = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, j, 0),
-                            memory_space=pltpu.VMEM)
-    kv_spec_i = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, i, 0),
-                             memory_space=pltpu.VMEM)
-    row_spec_j = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, j, 0),
-                              memory_space=pltpu.VMEM)
+    # kv-major grid (b, h, i = kv block, j = q block): q is the reduction
+    # (innermost) dim; a dead causal tile re-names the first live q block
+    def q_pos(i, j):
+        return jnp.clip(j, (i * bk) // bq, n_q - 1) if causal else j
 
-    in_specs = [q_spec_j, kv_spec_i, kv_spec_i, q_spec_j, row_spec_j,
-                row_spec_j]
-    operands = [q, k, v, do, lse, delta]
-    if has_seg:
-        qs_spec, ks_spec = _seg_specs(bq, bk, q_major=False)
-        in_specs += [qs_spec, ks_spec]
-        operands += [q_seg[..., None], kv_seg[..., None]]
-
+    specs = _specs(D, bq, bk, q_pos, lambda i, j: i)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkdv_kernel, scale=scale, causal=causal,
-                          has_seg=has_seg, bq=bq, bk=bk, n_q=n_q),
+        functools.partial(_dkdv_kernel, n_q=n_q, **static),
         grid=(B, H, n_kv, n_q),
-        in_specs=in_specs,
-        out_specs=[kv_spec_i, kv_spec_i],
+        in_specs=in_specs(*specs),
+        out_specs=[specs[1], specs[1]],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=params,
+        compiler_params=_PARAMS,
         interpret=pallas_mode.interpret(),
+        name="flash_dkdv",
     )(*operands)
-    return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
-            dv.transpose(0, 2, 1, 3))
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------- public op
@@ -371,36 +403,40 @@ def _seg_zero_cot(seg):
     return zero_cotangent(seg)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash(q, k, v, q_seg, kv_seg, causal, block_q, block_k):
+def _heads_first(x):
+    """[B, S, H, D] <-> [B, H, S, D]. The layout the kernels index: a
+    [rows, D] block of one head is contiguous. (A [B, S, H*D] view with a
+    lane block per head needs no transpose at heads of 128 and was 1.4 ms
+    a step SLOWER in ``olmoe_train_1chip``: PERF.md section 6, PR 28.)"""
+    return x.transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _flash(q, k, v, q_seg, kv_seg, causal):
+    return _flash_fwd(q, k, v, q_seg, kv_seg, causal)[0]
+
+
+def _flash_fwd(q, k, v, q_seg, kv_seg, causal):
+    qt, kt, vt = _heads_first(q), _heads_first(k), _heads_first(v)
     segs = None if q_seg is None else (q_seg, kv_seg)
-    out, _ = _fwd(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                  v.transpose(0, 2, 1, 3), segs, causal, block_q, block_k)
-    return out.transpose(0, 2, 1, 3)
+    out, lse = _fwd(qt, kt, vt, segs, causal)
+    return _heads_first(out), (qt, kt, vt, out, lse, q_seg, kv_seg)
 
 
-def _flash_fwd(q, k, v, q_seg, kv_seg, causal, block_q, block_k):
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    segs = None if q_seg is None else (q_seg, kv_seg)
-    out, lse = _fwd(qt, kt, vt, segs, causal, block_q, block_k)
-    return out.transpose(0, 2, 1, 3), (qt, kt, vt, out, lse, q_seg, kv_seg)
-
-
-def _flash_bwd(causal, block_q, block_k, res, do):
-    dq, dk, dv = _bwd(causal, block_q, block_k, res, do)
-    return dq, dk, dv, _seg_zero_cot(res[5]), _seg_zero_cot(res[6])
+def _flash_bwd(causal, res, do):
+    grads = _bwd(causal, res, _heads_first(do))
+    return tuple(_heads_first(g) for g in grads) + (
+        _seg_zero_cot(res[5]), _seg_zero_cot(res[6]))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _tileable(q, k, block_q, block_k):
-    return bool(_pick_block(q.shape[1], block_q)) and \
-        bool(_pick_block(k.shape[1], block_k))
+def _tileable(q, k):
+    return all(_pick_block(x.shape[1], _ROWS) for x in (q, k))
 
 
-def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
-                    block_q: int = 128, block_k: int = 128):
+def flash_attention(q, k, v, causal: bool = False, segment_ids=None):
     """Exact fused attention. q,k,v: [B, S, H, D] -> [B, S, H, D].
 
     ``segment_ids``: [B, S] int32 (shared q/kv for self-attention) or a
@@ -420,13 +456,13 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
         kv_seg = jnp.asarray(segment_ids[1], jnp.int32)
     else:
         q_seg = kv_seg = jnp.asarray(segment_ids, jnp.int32)
-    if not _tileable(q, k, block_q, block_k):
+    if not _tileable(q, k):
         from autodist_tpu.ops.attention import reference_attention
         from autodist_tpu.utils import logging
         logging.warning(
-            "flash_attention: q %s / k %s cannot be tiled (blocks %d/%d, "
-            "8-row minimum) — running the XLA reference path instead of "
-            "the kernel", tuple(q.shape), tuple(k.shape), block_q, block_k)
+            "flash_attention: q %s / k %s cannot be tiled (8-row minimum) "
+            "— running the XLA reference path instead of the kernel",
+            tuple(q.shape), tuple(k.shape))
         mask = None
         if causal:
             rows = jnp.arange(q.shape[1])[:, None]
@@ -437,11 +473,10 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
             mask = seg_mask if mask is None else jnp.logical_and(mask,
                                                                  seg_mask)
         return reference_attention(q, k, v, mask)
-    return _flash(q, k, v, q_seg, kv_seg, causal, block_q, block_k)
+    return _flash(q, k, v, q_seg, kv_seg, causal)
 
 
-def make_flash_attn_fn(causal: bool = True, block_q: int = 128,
-                       block_k: int = 128):
+def make_flash_attn_fn(causal: bool = True):
     """(q, k, v, mask) -> out adapter for model layers' ``attn_fn`` slot.
 
     A key-padding mask (boolean, broadcastable [B, 1, 1, S] / [B, S])
@@ -449,7 +484,7 @@ def make_flash_attn_fn(causal: bool = True, block_q: int = 128,
     Arbitrary dense masks are not expressible as segments and raise."""
     def attn(q, k, v, mask=None):
         if mask is None:
-            return flash_attention(q, k, v, causal, None, block_q, block_k)
+            return flash_attention(q, k, v, causal)
         m = jnp.asarray(mask)
         # accept [B, S] or the layers' [B, 1, 1, S] broadcast form
         if m.ndim == 4 and m.shape[1] == 1 and m.shape[2] == 1:
@@ -459,6 +494,5 @@ def make_flash_attn_fn(causal: bool = True, block_q: int = 128,
                 "flash attention supports key-padding masks ([B, S] or "
                 "[B, 1, 1, S]) via segment ids; got mask shape %s"
                 % (mask.shape,))
-        seg = m.astype(jnp.int32)
-        return flash_attention(q, k, v, causal, seg, block_q, block_k)
+        return flash_attention(q, k, v, causal, m.astype(jnp.int32))
     return attn

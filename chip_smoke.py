@@ -466,7 +466,7 @@ def phase_kernels(cfg, sizes, rehearse):
     for seq in sizes.kernel_seqs:
         shape = (2, seq, heads, dim)
         q, k, v = (rand(shape, s) for s in range(3))
-        check(fa._tileable(q, k, 128, 128), "seq %d does not tile" % seq)
+        check(fa._tileable(q, k), "seq %d does not tile" % seq)
         # two packed documents then padding, as segment ids
         seg = jnp.broadcast_to(jnp.asarray(
             np.repeat([1, 2, 0], [seq // 2, seq // 4, seq // 4]), jnp.int32),

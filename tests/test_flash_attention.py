@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from autodist_tpu.ops.attention import reference_attention
+from autodist_tpu.ops import flash_attention as fa
 from autodist_tpu.ops.flash_attention import flash_attention, make_flash_attn_fn
 
 
@@ -246,3 +247,59 @@ def test_empty_query_rows_emit_zeros_with_zero_grads():
         assert np.all(np.isfinite(np.asarray(g)))
     # empty query rows contribute nothing anywhere
     np.testing.assert_array_equal(np.asarray(dq[:, S // 2:]), 0.0)
+
+
+# ------------------------------------------- the tiles the chooser returns
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 5e-2)])
+def test_seq_2048_heads_of_128_at_the_choosers_tiles(dtype, tol):
+    """Forward and every gradient, causal, at the tiling ``_tiles`` gives
+    ``olmoe_train_1chip``'s attention (seq 2048, heads of 128: four
+    512-row tiles a side): the rows of tiles hold a
+    first tile, a tile wholly under the diagonal, tiles the diagonal
+    crosses, a tile above it (skipped, its blocks not fetched) and the
+    last tile. A cotangent of O(1) keeps the gradients O(1), so
+    the tolerance (float32: sum order; bfloat16: the file's 5e-2 against
+    the float32 reference) is not vacuous."""
+    shape = (1, 2048, 2, 128)
+    bq, bk = fa._tiles(shape[1], shape[1])
+    assert bq == bk and shape[1] // bq >= 2, (bq, bk)
+    q, k, v, cot = (_rand(shape, dtype, seed=i) for i in range(4))
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    mask = _mask(shape[1], True)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True).astype(jnp.float32)
+                       * cot.astype(jnp.float32))
+
+    def loss_ref(q, k, v):
+        return jnp.sum(reference_attention(q, k, v, mask)
+                       * cot.astype(jnp.float32))
+
+    out = jax.jit(lambda q, k, v: flash_attention(q, k, v, True))(q, k, v)
+    assert out.dtype == dtype
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               reference_attention(*f32, mask),
+                               atol=tol, rtol=tol)
+    got = jax.jit(jax.grad(loss_flash, (0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss_ref, (0, 1, 2)))(*f32)
+    for a, b in zip(got, want):
+        assert a.dtype == dtype and float(jnp.max(jnp.abs(b))) > 0.5
+        np.testing.assert_allclose(a.astype(jnp.float32), b,
+                                   atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("shape, tiles", [
+    ((2048, 2048), (512, 512)),    # olmoe_train_1chip
+    ((256, 256), (256, 256)),      # lm1b's cells: one tile
+    ((8, 1024), (8, 512)),         # the decode loop's 8-row query
+    ((8192, 8192), (512, 512)),    # no wider however long
+    ((1536, 1536), (512, 512)),    # three whole tiles
+    ((192, 192), (64, 64)),        # the largest power of two that divides
+    ((100, 100), (0, 0)),          # nothing of 8 rows divides
+])
+def test_tiles_come_from_the_shapes(shape, tiles):
+    assert fa._tiles(*shape) == tiles
+    assert fa.full_tiles(shape[0]) == (tiles[0] == 512)

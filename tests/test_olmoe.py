@@ -51,6 +51,12 @@ def close(got, want, rtol=RTOL):
                                atol=rtol * max(np.abs(want).max(), 1e-30))
 
 
+def flat(tree):
+    """{"params/layer_0/moe/router": leaf, ...} of a parameter-shaped tree."""
+    return {"/".join(str(k.key) for k in path): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
 def batches(n, rows=8, vocab=256, seed=1):
     rng = np.random.RandomState(seed)
     return [{"tokens": rng.randint(0, vocab, (rows, SEQ + 1)).astype(np.int32)}
@@ -91,9 +97,6 @@ def loss_and_grads(request, tiny):
         got = jax.jit(jax.value_and_grad(loss_fn))(params, batch)
         want = jax.jit(jax.value_and_grad(functools.partial(
             reference_loss, lb_coef=lb_coef, z_coef=z_coef)))(params, batch)
-    flat = lambda g: {  # noqa: E731
-        "/".join(str(k.key) for k in path): leaf
-        for path, leaf in jax.tree_util.tree_flatten_with_path(g)[0]}
     return got[0], want[0], flat(got[1]), flat(want[1])
 
 
@@ -125,6 +128,90 @@ def test_gradient_leaf_matches_the_reference(loss_and_grads, leaf):
     _, _, got, want = loss_and_grads
     assert np.abs(np.asarray(want["params/" + leaf])).max() > 0
     close(got["params/" + leaf], want["params/" + leaf])
+
+
+@pytest.fixture(scope="module")
+def flash_and_default(tiny):
+    """(loss, gradient leaves) of the tiny model with the attention core
+    through the flash kernel (interpreted here) and through XLA."""
+    cfg, _, params, _, batch = tiny
+    got = {}
+    for mode in ("flash", "default"):
+        loss_fn, same = lm.make_train_setup(
+            cfg, seq_len=SEQ, batch_size=8, seed=0, attention=mode)[:2]
+        jax.tree_util.tree_map(np.testing.assert_array_equal, same, params)
+        with jax.default_matmul_precision("highest"):
+            loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params, batch)
+        got[mode] = (float(loss), flat(grads))
+    return got
+
+
+def test_flash_attention_gives_the_default_paths_loss(flash_and_default):
+    flash, default = flash_and_default["flash"][0], \
+        flash_and_default["default"][0]
+    assert abs(flash - default) <= RTOL * abs(default)
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_flash_attention_gives_the_default_paths_gradient(flash_and_default,
+                                                          leaf):
+    """RoPE, QK-norm and the routed feed-forward around the kernel: the
+    same mathematics as the default path, every leaf to the file's 1e-5."""
+    close(flash_and_default["flash"][1]["params/" + leaf],
+          flash_and_default["default"][1]["params/" + leaf])
+
+
+# the three cells' attention shapes (seq, head width) and the side of the
+# rule each lands on where the backend is a TPU
+CELL_SHAPES = {"olmoe_train_1chip": (2048, 128, True),
+               "lm1b_train_1chip": (256, 64, False),
+               "lm1b_train_4chip_ar": (256, 64, False)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_auto_attention_decides_from_shapes_and_backend(cell):
+    seq, head_dim, flash = CELL_SHAPES[cell]
+    assert lm.auto_flash_attention(seq, head_dim, "tpu") is flash
+    assert lm.auto_flash_attention(seq, head_dim, "cpu") is False
+    assert lm.auto_flash_attention(1 << 20, head_dim, "cpu") is False
+
+
+@pytest.mark.parametrize("seq, head_dim, flash", [
+    (512, 128, True),     # the shortest sequence the sweep read
+    (256, 128, False),
+    (1000, 128, False),   # tiles of 8 rows: the parent kept these on XLA
+    (1200, 128, False),   # tiles of 16
+    (2000, 128, False),
+    (1536, 128, True),    # three whole 512-row tiles
+    (4096, 64, False),    # narrow heads at long sequences: no cell reads them
+    (8192, 64, True),     # XLA's scores stop fitting: memory, as before
+    (8200, 128, True),
+])
+def test_auto_attention_takes_the_kernel_only_at_tiles_the_chip_read(
+        seq, head_dim, flash):
+    assert lm.auto_flash_attention(seq, head_dim, "tpu") is flash
+    assert lm.auto_flash_attention(seq, head_dim, "cpu") is False
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_the_gauge_says_how_many_layers_took_the_kernel(cell, monkeypatch):
+    """``attention.flash_layers`` is set when the loss is traced, from what
+    the rule decided. The rule's TPU branch is steered HERE (the backend
+    probe answers "tpu", the kernels stay interpreted), at a cell's seq
+    and head width on a narrow one-layer model."""
+    from autodist_tpu.ops import pallas_mode
+    seq, head_dim, flash = CELL_SHAPES[cell]
+    cfg = tiny_config(d_model=2 * head_dim, num_heads=2, max_seq_len=seq,
+                      num_layers=1)
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: True)
+    for backend, layers in (("tpu", 1 if flash else 0), ("cpu", 0)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        loss_fn, params, batch, _ = lm.make_train_setup(
+            cfg, seq_len=seq, batch_size=1, seed=0)
+        telemetry.reset()
+        jax.eval_shape(loss_fn, params, batch)
+        assert telemetry.get_recorder().gauges()[
+            "attention.flash_layers"] == layers
 
 
 def routed_layer(rng, tokens, d, f, n_experts):
@@ -531,6 +618,100 @@ def test_the_routed_layer_compiles_for_a_v5e_at_the_published_widths(
     # three grouped matmuls forward, six backward (gmm + tgmm each)
     assert text.count('custom_call_target="tpu_custom_call"') >= 9
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_flash_attention_compiles_for_a_v5e_at_the_cells_shape(one_v5e_chip):
+    """The forward and both backward kernels at ``olmoe_train_1chip``'s
+    attention shape ([4, 2048, 16, 128] bfloat16, causal) through Mosaic
+    for a described chip: a tile ``_tiles`` chooses that the kernels' VMEM
+    cannot hold fails here and not on the chip."""
+    from autodist_tpu.ops import pallas_mode
+    from autodist_tpu.ops.flash_attention import flash_attention
+    x = jax.ShapeDtypeStruct((4, 2048, 16, 128), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+    with pallas_mode.compiling_for_tpu():
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # no [B, H, S, S] tensor: out, lse and delta are all it keeps
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("shape, dtype, segments", [
+    ((1, 8192, 16, 128), jnp.bfloat16, False),
+    ((16, 1024, 16, 64), jnp.bfloat16, False),   # lm1b's heads
+    ((4, 2048, 16, 128), jnp.bfloat16, True),
+    ((1, 32768, 8, 128), jnp.bfloat16, True),
+    ((2, 8192, 8, 256), jnp.float32, True),
+    ((8, 512, 12, 64), jnp.bfloat16, True),      # BERT's padding mask
+    # float32 (``LMConfig.dtype``'s default) at batch x heads of 64 and
+    # more: 1,024-row tiles were refused here and only here (PR 28's review)
+    ((4, 2048, 16, 64), jnp.float32, False),
+    ((4, 8192, 16, 64), jnp.float32, False),
+    ((4, 8192, 16, 128), jnp.float32, True),
+    ((4, 2048, 16, 256), jnp.float32, True),
+])
+def test_the_kernels_tiles_fit_the_v5es_vmem(one_v5e_chip, shape, dtype,
+                                             segments):
+    """Forward and both backward kernels, causal, compile for the described
+    chip at the one tile size ``_tiles`` gives, over head widths, dtypes,
+    segment ids and batch x heads (the fit of a larger tile moved with all
+    four: a device-less reading, PERF.md section 6, PR 28)."""
+    from autodist_tpu.ops import flash_attention as fa, pallas_mode
+    assert fa._tiles(shape[1], shape[1]) == (512, 512)
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+    seg = jax.ShapeDtypeStruct(shape[:2], jnp.int32,
+                               sharding=one_v5e_chip) if segments else None
+
+    def loss(q, k, v, seg):
+        return jnp.sum(fa.flash_attention(q, k, v, True, seg)
+                       .astype(jnp.float32))
+    with pallas_mode.compiling_for_tpu():
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x, seg).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+
+
+def test_the_cells_whole_step_compiles_with_its_kernels(v5e_2x2, monkeypatch):
+    """``olmoe_train_1chip``'s step as the benchmark builds it, compiled
+    for the described chip with every pallas kernel as a Mosaic call
+    (``benchmark/tools/aot_compile.py`` under ``compiling_for_tpu``): nine
+    grouped expert matmuls and, since ``attention="auto"`` puts seq 2048 x
+    heads of 128 on the kernel, attention's three. PR 25 lost a chip call
+    to a tile that exhausted VMEM only inside the whole step. The rule's
+    backend probe is steered here: JAX's default backend is the CPU."""
+    import sys
+    from autodist_tpu.ops import pallas_mode
+    from autodist_tpu.parallel import mesh as mesh_lib
+    path = list(sys.path)
+    from benchmark.tools import aot_compile  # (it re-points sys.path[0])
+    sys.path[:] = path
+    # compile_cell replaces these two for good: put them back afterwards
+    monkeypatch.setattr(mesh_lib, "ordered_devices", mesh_lib.ordered_devices)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = []
+    mem = aot_compile.mem
+    monkeypatch.setattr(aot_compile, "mem",
+                        lambda c: (compiled.append(c), mem(c))[1])
+    try:
+        with pallas_mode.compiling_for_tpu():
+            out = aot_compile.compile_cell("olmoe_train_1chip",
+                                           v5e_2x2.devices)
+    finally:
+        autodist_tpu.reset()
+    text = compiled[0].as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    assert sum(name in text for name in
+               ("flash_fwd", "flash_dq", "flash_dkdv")) == 3
+    # state + step scratch fit one chip's 16 GB with room to spare
+    assert out["train_step"]["live_bytes_estimate"] < 14 << 30
+    assert out["train_step"]["temp_size_in_bytes"] < 4426620928  # PR 27's
 
 
 def test_four_chip_step_exchanges_gradients_beside_its_compute(

@@ -279,6 +279,7 @@ def by_name(events):
 def _recorded_fit_untiled_readbacks(fit_kw):
     """One recorded fit held to everything but the wall-time bound of the
     tiling; returns the readbacks that broke that bound."""
+    autodist_tpu.reset()  # the second fit of one test builds anew
     tel.configure("1")
     runner, batch, _ = build_lm()
     runner.run(batch)  # compile outside the recorded fit
