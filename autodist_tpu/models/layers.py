@@ -5,15 +5,19 @@ shapes, MXU-sized matmuls. Attention routes through
 ``autodist_tpu.ops.attention`` so sequence-parallel (ring) execution can be
 swapped in by the strategy layer without touching model code.
 """
-from typing import Any, Callable, Optional
+import dataclasses
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from autodist_tpu.telemetry import scopes
 
 Dtype = Any
+KDA_CORE_OUT = "kda_core_out"   # the delta rule's output, by name
 
 
 def causal_mask(seq_len: int) -> jnp.ndarray:
@@ -158,6 +162,61 @@ class MultiHeadAttention(nn.Module):
         return out
 
 
+@dataclasses.dataclass(frozen=True)
+class KDAConfig:
+    """Kimi Delta Attention's sizes (``linear_attn_config``)."""
+    num_heads: int
+    head_dim: int
+    conv_size: int
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Latent attention's sizes; ``rope_theta`` None = NoPE (the
+    ``qk_rope_head_dim`` features exist and are not rotated)."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class RouterConfig:
+    """What a routed feed-forward's router does beyond OLMoE's softmax
+    gate: a sigmoid score per expert, the gates renormalised over the
+    chosen and scaled, experts every token passes, and the SHARE of the
+    experts this layer holds (None = all of them)."""
+    renormalize: bool
+    scaling_factor: float
+    shared_experts: int
+    held: Optional[Tuple[int, ...]]
+
+
+def linear(features, dtype, name):
+    """A projection without a bias (every one of Kimi-Linear's)."""
+    return nn.Dense(features, dtype=dtype, use_bias=False, name=name)
+
+
+def rms_normalize(x, eps):
+    """x / sqrt(mean(x^2) + eps) over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                             + eps)
+
+
+class SwiGLU(nn.Module):
+    """down(silu(gate(x)) * up(x)), no bias."""
+    width: int
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        h = nn.silu(linear(self.width, self.dtype, "gate_proj")(x)) \
+            * linear(self.width, self.dtype, "up_proj")(x)
+        return linear(x.shape[-1], self.dtype, "down_proj")(h)
+
+
 class MoEFeedForward(nn.Module):
     """Routed SwiGLU feed-forward: ``num_experts`` experts of width
     ``expert_dim``, ``experts_per_token`` chosen per token, none dropped
@@ -166,30 +225,161 @@ class MoEFeedForward(nn.Module):
     ``losses`` collection (``router_lb``, ``router_z``) and its load into
     ``counters`` (``max_expert_pairs``, ``routed_pairs``): the loss adds
     the first to itself and hands the second to
-    ``telemetry.device_counters``."""
+    ``telemetry.device_counters``.
+
+    With a ``router`` (:class:`RouterConfig`) the scores are sigmoids, the
+    choice is by score plus ``e_score_correction_bias`` (it only chooses, so
+    no gradient reaches it, and no rule here updates it: it stays at its
+    initial zero), there is
+    no router loss, the stacks hold only the experts this layer HOLDS,
+    ``counters`` also gets ``chosen_pairs`` (all T x k), and the shared
+    experts (one SwiGLU as wide as all of them) are added in full."""
     num_experts: int
     experts_per_token: int
     expert_dim: int
     dtype: Dtype = jnp.float32
+    router: Optional[RouterConfig] = None
 
     @nn.compact
     def __call__(self, x):
-        from autodist_tpu.parallel.expert import dropless_moe_ffn
+        from autodist_tpu.parallel.expert import (SigmoidRouting,
+                                                  dropless_moe_ffn)
         d, E, f = x.shape[-1], self.num_experts, self.expert_dim
+        cfg = self.router
+        held = E if cfg is None or cfg.held is None else len(cfg.held)
         stacked = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=1, out_axis=2, batch_axis=0)
         router = self.param("router", nn.initializers.lecun_normal(), (d, E))
-        w_gate = self.param("gate_proj", stacked, (E, d, f))
-        w_up = self.param("up_proj", stacked, (E, d, f))
-        w_down = self.param("down_proj", stacked, (E, f, d))
+        w_gate = self.param("gate_proj", stacked, (held, d, f))
+        w_up = self.param("up_proj", stacked, (held, d, f))
+        w_down = self.param("down_proj", stacked, (held, f, d))
+        routing = None
+        if cfg is not None:
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.zeros, (E,))
+            routing = SigmoidRouting(bias, cfg.renormalize,
+                                     cfg.scaling_factor, cfg.held)
         out, lb, z, counts = dropless_moe_ffn(
             x, router, w_gate, w_up, w_down, self.experts_per_token,
-            self.dtype)
-        self.sow("losses", "router_lb", lb)
-        self.sow("losses", "router_z", z)
+            self.dtype, routing=routing)
+        if cfg is None:
+            self.sow("losses", "router_lb", lb)
+            self.sow("losses", "router_z", z)
         self.sow("counters", "max_expert_pairs", jnp.max(counts))
         self.sow("counters", "routed_pairs", jnp.sum(counts))
+        if cfg is not None:
+            pairs = x.size // d * self.experts_per_token
+            self.sow("counters", "chosen_pairs", jnp.int32(pairs))
+            if cfg.shared_experts:
+                with scopes.scope(scopes.MOE), scopes.scope(scopes.MOE_SHARED):
+                    out = out + SwiGLU(cfg.shared_experts * f, self.dtype,
+                                       name="shared")(x)
         return out
+
+
+def causal_conv(x, w):
+    """Depthwise causal convolution along the sequence: x [B, S, C], w
+    [K, C]; y_t = sum_j w_j * x_{t-K+1+j}, zeros before the start."""
+    K, S = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, [(0, 0), (K - 1, 0), (0, 0)])
+    return sum(padded[:, j:j + S] * w[j] for j in range(K))
+
+
+class KimiDeltaAttention(nn.Module):
+    """The KDA token mixer (arXiv 2510.26692): q, k, v through a short
+    causal convolution and SiLU, q and k L2-normalised per head, a
+    per-channel decay and a per-head write strength, the gated delta rule
+    (``ops/kda.py``), a per-head RMSNorm gated by a sigmoid, the output
+    projection. No bias on a projection; no position signal. The decay is
+    ``-exp(A_log) * softplus(f(x) + dt_bias)`` with A_log per head, f and
+    the output gate low-rank through ``head_dim`` features."""
+    cfg: KDAConfig
+    norm_eps: float
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from autodist_tpu.ops.kda import kda_chunked
+        H, D, K = self.cfg.num_heads, self.cfg.head_dim, self.cfg.conv_size
+        dense = lambda n, name: linear(n, self.dtype, name)  # noqa: E731
+        heads = lambda t: t.reshape(t.shape[:-1] + (H, D))  # noqa: E731
+
+        def short_conv(name):
+            w = self.param(name + "_conv", nn.initializers.variance_scaling(
+                1.0, "fan_in", "uniform", in_axis=0, out_axis=1), (K, H * D))
+            return heads(nn.silu(causal_conv(dense(H * D, name + "_proj")(x),
+                                             w.astype(self.dtype))))
+
+        q, k, v = short_conv("q"), short_conv("k"), short_conv("v")
+        l2 = lambda t: t * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+        q = l2(q.astype(jnp.float32)) * D ** -0.5
+        k = l2(k.astype(jnp.float32))
+        a_log = self.param("A_log", lambda key, shape: jnp.log(
+            jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)), (H,))
+        # softplus(dt_bias) log-uniform in [1e-3, 1e-1]
+        dt_bias = self.param("dt_bias", lambda key, shape: (
+            lambda dt: dt + jnp.log(-jnp.expm1(-dt)))(jnp.exp(
+                jax.random.uniform(key, shape, jnp.float32,
+                                   np.log(1e-3), np.log(1e-1)))), (H * D,))
+        f = dense(H * D, "f_b_proj")(dense(D, "f_a_proj")(x))
+        g = -jnp.exp(a_log)[:, None] * heads(
+            jax.nn.softplus(f.astype(jnp.float32) + dt_bias))
+        beta = nn.sigmoid(dense(H, "b_proj")(x).astype(jnp.float32))
+        with scopes.scope(scopes.KDA_SCAN):
+            o, _ = kda_chunked(q, k, v, g, beta, self.dtype)
+        # a block recomputed in the backward pass keeps this and does not
+        # run the core a third time (``models/lm.py:TransformerLM._block``)
+        o = checkpoint_name(o, KDA_CORE_OUT)
+        scale = self.param("o_norm", nn.initializers.ones, (D,))
+        gate = nn.sigmoid(heads(
+            dense(H * D, "g_b_proj")(dense(D, "g_a_proj")(x))
+        ).astype(jnp.float32))
+        o = (rms_normalize(o, self.norm_eps) * scale * gate).astype(self.dtype)
+        return dense(x.shape[-1], "o_proj")(o.reshape(o.shape[:-2] + (H * D,)))
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention in its TRAINING form (no absorbed
+    matrices, no latent cache): a full-rank q of ``nope + rope`` features a
+    head; k and v up-projected from an RMS-normalised latent of
+    ``kv_lora_rank``, k's last ``rope`` features projected straight from x
+    and shared by all heads; scores over ``nope + rope`` features, values
+    of ``v_head_dim``. With ``rope_theta`` None nothing is rotated."""
+    num_heads: int
+    cfg: MLAConfig
+    norm_eps: float
+    dtype: Dtype = jnp.float32
+    attn_fn: Optional[Callable] = None  # (q, k, v, mask) -> out
+
+    @nn.compact
+    def __call__(self, x, mask=None, positions=None):
+        c, H = self.cfg, self.num_heads
+        qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+        dense = lambda n, name: linear(n, self.dtype, name)  # noqa: E731
+        q = dense(H * qk, "q_proj")(x).reshape(x.shape[:-1] + (H, qk))
+        kv_a = dense(c.kv_lora_rank + c.qk_rope_head_dim, "kv_a_proj")(x)
+        latent, k_pe = jnp.split(kv_a, [c.kv_lora_rank], axis=-1)
+        latent = make_norm("rmsnorm", self.norm_eps, self.dtype,
+                           "kv_a_norm")(latent)
+        kv = dense(H * (c.qk_nope_head_dim + c.v_head_dim), "kv_b_proj")(
+            latent).reshape(x.shape[:-1] + (H, -1))
+        k_nope, v = jnp.split(kv, [c.qk_nope_head_dim], axis=-1)
+        k_pe = k_pe[..., None, :]
+        if c.rope_theta is not None:
+            q_nope, q_pe = jnp.split(q, [c.qk_nope_head_dim], axis=-1)
+            q = jnp.concatenate(
+                [q_nope, rope(q_pe, positions, c.rope_theta)], axis=-1)
+            k_pe = rope(k_pe, positions, c.rope_theta)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:-1] + k_pe.shape[-1:])],
+            axis=-1)
+        from autodist_tpu.ops.attention import reference_attention
+        # (both scale the scores by 1 / sqrt(nope + rope) and take values
+        # narrower than the scores' features)
+        out = (self.attn_fn or reference_attention)(q, k, v, mask)
+        return dense(x.shape[-1], "o_proj")(
+            out.reshape(out.shape[:-2] + (H * c.v_head_dim,)))
 
 
 class TransformerBlock(nn.Module):
@@ -199,7 +389,9 @@ class TransformerBlock(nn.Module):
     fields after ``decode_attn`` are architecture read from a model's
     public config (``models/lm.py:LMConfig``): with ``num_experts`` the
     feed-forward is the routed SwiGLU one and ``mlp_dim`` is ONE expert's
-    width."""
+    width. A model whose layers differ names each block's token mixer by
+    its sizes (``kda`` or ``mla`` instead of the softmax attention above)
+    and gives a leading dense layer its SwiGLU width (``dense_dim``)."""
     num_heads: int
     head_dim: int
     mlp_dim: int
@@ -214,14 +406,15 @@ class TransformerBlock(nn.Module):
     rope_theta: Optional[float] = None
     num_experts: int = 0
     experts_per_token: int = 0
+    kda: Optional[KDAConfig] = None
+    mla: Optional[MLAConfig] = None
+    dense_dim: int = 0
+    router: Optional[RouterConfig] = None
 
-    @nn.compact
-    def __call__(self, x, mask=None, deterministic=True, cache=None,
-                 cursor=None, alive=None, return_kv=False, positions=None):
-        kv = None
-        h = make_norm(self.norm, self.norm_eps, self.dtype)(x)
-        with scopes.scope(scopes.ATTENTION):
-            h = MultiHeadAttention(
+    def _mix(self, h, mask, cache, cursor, alive, return_kv, positions):
+        """The block's token mixer on the normed input."""
+        if self.kda is None and self.mla is None:
+            return MultiHeadAttention(
                 self.num_heads, self.head_dim, self.dtype, self.attn_fn,
                 decode_attn=self.decode_attn, use_bias=self.attention_bias,
                 qk_norm_eps=self.norm_eps if self.qk_norm else None,
@@ -229,14 +422,38 @@ class TransformerBlock(nn.Module):
                 h, mask, cache=cache, cursor=cursor, alive=alive,
                 return_kv=return_kv, positions=positions)
         if cache is not None or return_kv:
+            raise NotImplementedError(
+                "prefill and cached decode keep K/V rows only: a kda "
+                "layer's recurrent state and an mla layer's latent have no "
+                "cache yet")
+        if self.kda is not None:
+            with scopes.scope(scopes.KDA):
+                return KimiDeltaAttention(self.kda, self.norm_eps, self.dtype,
+                                          name="kda")(h)
+        with scopes.scope(scopes.MLA):
+            return LatentAttention(self.num_heads, self.mla, self.norm_eps,
+                                   self.dtype, self.attn_fn, name="mla")(
+                h, mask, positions)
+
+    @nn.compact
+    def __call__(self, x, mask=None, deterministic=True, cache=None,
+                 cursor=None, alive=None, return_kv=False, positions=None):
+        kv = None
+        h = make_norm(self.norm, self.norm_eps, self.dtype)(x)
+        with scopes.scope(scopes.ATTENTION):
+            h = self._mix(h, mask, cache, cursor, alive, return_kv, positions)
+        if cache is not None or return_kv:
             h, kv = h
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
         x = x + h
         h = make_norm(self.norm, self.norm_eps, self.dtype)(x)
-        if self.num_experts:
+        if self.dense_dim:
+            h = SwiGLU(self.dense_dim, self.dtype, name="mlp")(h)
+        elif self.num_experts:
             h = MoEFeedForward(self.num_experts, self.experts_per_token,
-                               self.mlp_dim, self.dtype, name="moe")(h)
+                               self.mlp_dim, self.dtype, self.router,
+                               name="moe")(h)
         else:
             h = nn.Dense(self.mlp_dim, dtype=self.dtype)(h)
             h = nn.gelu(h)

@@ -13,14 +13,16 @@ embedding table is the PartitionedPS stress case, as in the reference
 benchmark.
 """
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from autodist_tpu.models.layers import (SparseEmbed, TransformerBlock,
+from autodist_tpu.models.layers import (KDA_CORE_OUT, KDAConfig, MLAConfig,
+                                        RouterConfig,
+                                        SparseEmbed, TransformerBlock,
                                         causal_mask, make_norm)
 from autodist_tpu.telemetry import spans as tel
 from autodist_tpu.telemetry import device_counters, scopes
@@ -28,6 +30,10 @@ from autodist_tpu.telemetry import device_counters, scopes
 # what a routed layer sows into ``counters`` and the loss reports as the
 # device counters ``moe.<name>``, summed over layers
 ROUTER_LOAD = ("max_expert_pairs", "routed_pairs")
+# ... and a layer that holds a share of its experts: every pair its
+# router chose, held here or not
+SHARE_LOAD = ROUTER_LOAD + ("chosen_pairs",)
+LAYER_TYPES = ("attention", "kda", "mla")
 
 
 @dataclasses.dataclass
@@ -58,6 +64,34 @@ class LMConfig:
     experts_per_token: int = 0
     router_aux_loss_coef: float = 0.0   # load-balance loss, per layer
     router_z_loss_coef: float = 0.0
+    # A model whose layers differ. ``layer_types[i]`` is layer i's token
+    # mixer: "attention" (the softmax attention above), "kda" (Kimi Delta
+    # Attention: ``kda_*``) or "mla" (latent attention: the four widths
+    # below; rotary on ``qk_rope_head_dim`` features iff ``rope_theta``).
+    # None = "attention" in every layer.
+    layer_types: Optional[Tuple[str, ...]] = None
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_size: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the first k layers' feed-forward is a dense SwiGLU of ``dense_dim``
+    first_k_dense_replace: int = 0
+    dense_dim: int = 0
+    # "softmax": the gate is the softmax probability and the router
+    # losses apply (OLMoE); "sigmoid": a score per expert, chosen by
+    # score + bias, gates renormalised over the chosen
+    # (``moe_renormalize``) and scaled, no router loss
+    router_activation: str = "softmax"
+    moe_renormalize: bool = False
+    routed_scaling_factor: float = 1.0
+    num_shared_experts: int = 0     # SwiGLU experts every token passes
+    # the experts of ``num_experts`` whose weights THIS model holds: one
+    # chip's share under expert parallelism (``parallel/expert.py``);
+    # None = all. The router scores all ``num_experts`` either way.
+    experts_held: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -66,6 +100,27 @@ class LMConfig:
                 "a routed feed-forward needs 0 < experts_per_token <= "
                 "num_experts, got %d of %d" % (self.experts_per_token,
                                                self.num_experts))
+        types = self.layer_types
+        if types is not None and (
+                len(types) != self.num_layers or set(types) - set(LAYER_TYPES)):
+            raise ValueError(
+                "layer_types names one of %s for each of the %d layers, got "
+                "%r" % (LAYER_TYPES, self.num_layers, types))
+        if self.router_activation not in ("softmax", "sigmoid"):
+            raise ValueError("router_activation must be softmax|sigmoid, got "
+                             "%r" % (self.router_activation,))
+        sigmoid_only = (self.moe_renormalize, self.routed_scaling_factor != 1.0,
+                        self.num_shared_experts, self.experts_held is not None)
+        if self.router_activation == "softmax" and any(sigmoid_only):
+            raise ValueError(
+                "renormalised or scaled gates, shared experts and a share of "
+                "the experts come with router_activation='sigmoid'")
+        held = self.experts_held
+        if held is not None and (
+                not held or len(set(held)) != len(held)
+                or not all(0 <= e < self.num_experts for e in held)):
+            raise ValueError("experts_held names distinct experts of the %d, "
+                             "got %r" % (self.num_experts, held))
 
     @classmethod
     def lm1b(cls, **kw):
@@ -90,6 +145,33 @@ class LMConfig:
                    router_z_loss_coef=0.001, **kw)
 
     @classmethod
+    def kimi_linear_48b_a3b(cls, **kw):
+        """Kimi-Linear-48B-A3B-Instruct as its ``config.json`` publishes it
+        (huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct; arXiv
+        2510.26692): 27 pre-norm RMSNorm layers without a bias or a
+        position signal, three KDA layers (32 heads of 128, conv 4) to one
+        NoPE latent-attention layer (32 heads, latent 512, 128 + 64 score
+        features, values of 128); a dense SwiGLU of 9,216 in layer 1, then
+        256 sigmoid-routed SwiGLU experts of 1,024, 8 a token,
+        renormalised gates x 2.446, one shared expert; an untied head.
+        ``num_layers`` cuts the published pattern from its start."""
+        full_attn = (4, 8, 12, 16, 20, 24, 27)   # numbered from 1
+        n = kw.setdefault("num_layers", 27)
+        kw.setdefault("max_seq_len", 1048576)
+        kw.setdefault("layer_types", tuple(
+            "mla" if i + 1 in full_attn else "kda" for i in range(n)))
+        return cls(vocab_size=163840, d_model=2304, num_heads=32,
+                   mlp_dim=1024, norm="rmsnorm", norm_eps=1e-5,
+                   attention_bias=False, head_bias=False, embed_scale=False,
+                   kda_num_heads=32, kda_head_dim=128, kda_conv_size=4,
+                   kv_lora_rank=512, qk_nope_head_dim=128,
+                   qk_rope_head_dim=64, v_head_dim=128,
+                   first_k_dense_replace=1, dense_dim=9216,
+                   num_experts=256, experts_per_token=8,
+                   router_activation="sigmoid", moe_renormalize=True,
+                   routed_scaling_factor=2.446, num_shared_experts=1, **kw)
+
+    @classmethod
     def tiny(cls, **kw):
         return cls(vocab_size=128, d_model=32, num_layers=2, num_heads=2,
                    mlp_dim=64, max_seq_len=64, **kw)
@@ -100,6 +182,8 @@ class TransformerLM(nn.Module):
     attn_fn: Optional[Any] = None
     seq_parallel: bool = False  # offset positions by the seq-shard index
     decode_attn: str = "reference"  # decode inner loop: "reference"|"flash"
+    # recompute each block in the backward pass (make_train_setup's rule)
+    remat_blocks: bool = False
 
     def _embed(self, input_ids, positions):
         """Token embedding (scaled by sqrt(d) where the config says so)
@@ -112,7 +196,7 @@ class TransformerLM(nn.Module):
                             name="embed")(input_ids)
             if cfg.embed_scale:
                 x = x * np.sqrt(cfg.d_model)
-            if cfg.rope_theta is None:
+            if cfg.rope_theta is None and cfg.layer_types is None:
                 pos = SparseEmbed(cfg.max_seq_len, cfg.d_model,
                                   dtype=cfg.dtype,
                                   name="pos_embed")(positions)
@@ -120,8 +204,32 @@ class TransformerLM(nn.Module):
         return x
 
     def _block(self, i, **kw):
+        """Layer i's block: what the config says of THIS layer (its token
+        mixer, a leading dense feed-forward) on top of what every layer
+        shares. Under ``remat_blocks`` its activations are recomputed in
+        the backward pass."""
         cfg = self.config
-        return TransformerBlock(
+        kind = "attention" if cfg.layer_types is None else cfg.layer_types[i]
+        if kind == "kda":
+            kw["kda"] = KDAConfig(cfg.kda_num_heads, cfg.kda_head_dim,
+                                  cfg.kda_conv_size)
+        elif kind == "mla":
+            kw["mla"] = MLAConfig(cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                                  cfg.qk_rope_head_dim, cfg.v_head_dim,
+                                  cfg.rope_theta)
+        if i < cfg.first_k_dense_replace:
+            kw["dense_dim"] = cfg.dense_dim
+        if cfg.router_activation == "sigmoid":
+            kw["router"] = RouterConfig(
+                cfg.moe_renormalize, cfg.routed_scaling_factor,
+                cfg.num_shared_experts, cfg.experts_held)
+        # (the delta rule recomputes itself, head group by head group: its
+        # output is kept, [tokens, 4096] a layer, or it would run thrice)
+        block = nn.remat(
+            TransformerBlock,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                KDA_CORE_OUT)) if self.remat_blocks else TransformerBlock
+        return block(
             cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
             dtype=cfg.dtype, norm=cfg.norm, norm_eps=cfg.norm_eps,
             attention_bias=cfg.attention_bias, qk_norm=cfg.qk_norm,
@@ -251,6 +359,34 @@ def auto_flash_attention(seq_len: int, head_dim: int, backend: str) -> bool:
     return head_dim >= 128 and full_tiles(seq_len)
 
 
+# logits of [tokens, vocab] float32 from which the loss goes through the
+# chunked head whatever the vocabulary: half a GiB, and the log-softmax
+# and its gradient are as large again
+LEAN_HEAD_LOGIT_BYTES = 1 << 29
+
+
+def auto_remat_blocks(param_count: int, num_layers: int,
+                      hbm_bytes: Optional[float]) -> bool:
+    """Is each block recomputed in the backward pass (``nn.remat`` around
+    the block; ``strategy/remat.py`` checkpoints the whole loss, which
+    does not lower the peak of a deep model)? Where the training state
+    alone, at this repo's 16 B a parameter (float32 master weight, Adam's
+    two moments, the gradient), takes over half the chip's memory and
+    there is more than one block to keep activations of. ``hbm_bytes``
+    None (no TPU): never."""
+    return (hbm_bytes is not None and num_layers > 1
+            and 16.0 * param_count > hbm_bytes / 2)
+
+
+def _chip_hbm_bytes() -> Optional[float]:
+    """The attached chip's memory by the chip table, None off a TPU."""
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    from autodist_tpu.resource_spec import CHIP_TABLE, chip_kind_of
+    return CHIP_TABLE[chip_kind_of(device.device_kind)].hbm_bytes
+
+
 def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
                      batch_size: int = 32, seed: int = 0,
                      attention: str = "auto", lean_head="auto"):
@@ -265,8 +401,9 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     (``ops.xent.chunked_softmax_xent``) — the [tokens, vocab] fp32 logits
     tensor (3.25 GB for lm1b at batch 32) never materializes, which is
     what lets lm1b train at batch 64 on a 16 GB chip. "auto" (default)
-    engages it at vocab >= 32768, with or without a head bias. Same math
-    to float tolerance.
+    engages it at vocab >= 32768, or where the batch's logits alone
+    would be ``LEAN_HEAD_LOGIT_BYTES``, with or without a head bias. Same
+    math to float tolerance.
 
     ``config`` decides the model, not this function: ``LMConfig.lm1b()``
     (GPT-2 style blocks) and ``LMConfig.olmoe_1b_7b()`` (RMSNorm, QK-norm,
@@ -274,10 +411,18 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     ``TransformerLM``, the same loss and the same lean-head rule. A
     routed config adds its router losses to the mean NLL:
     ``router_aux_loss_coef * L_lb + router_z_loss_coef * L_z``, each taken
-    per layer over the batch this loss sees and averaged over layers."""
+    per layer over the batch this loss sees and averaged over layers.
+    ``LMConfig.kimi_linear_48b_a3b()`` (KDA and latent-attention layers
+    by ``layer_types``, a leading dense layer, sigmoid-routed experts of
+    which ``experts_held`` are here, a shared expert) takes the same
+    path; its loss is the NLL alone. Whether each block is recomputed in
+    the backward pass is :func:`auto_remat_blocks`'s to say, from the
+    parameters it counts and the chip's memory."""
     cfg = config or LMConfig()
     if lean_head == "auto":
-        lean_head = cfg.vocab_size >= 32768
+        lean_head = (cfg.vocab_size >= 32768
+                     or 4 * batch_size * seq_len * cfg.vocab_size
+                     >= LEAN_HEAD_LOGIT_BYTES)
     elif not isinstance(lean_head, bool):
         raise ValueError("lean_head must be True, False or 'auto', got %r"
                          % (lean_head,))
@@ -289,31 +434,45 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     if attention not in ("auto", "flash", "default"):
         raise ValueError("attention must be auto|flash|default, got %r"
                          % attention)
+    types = cfg.layer_types or ("attention",) * cfg.num_layers
+    # the widest scores a softmax layer of this model contracts over
+    head_dim = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                if "mla" in types else cfg.d_model // cfg.num_heads)
     if attention == "flash" or (attention == "auto" and auto_flash_attention(
-            seq_len, cfg.d_model // cfg.num_heads, jax.default_backend())):
+            seq_len, head_dim, jax.default_backend())):
         from autodist_tpu.ops.flash_attention import make_flash_attn_fn
         attn_fn = make_flash_attn_fn(causal=True)
-    flash_layers = cfg.num_layers if attn_fn is not None else 0
-    model = TransformerLM(cfg, attn_fn=attn_fn)
+    flash_layers = (sum(t != "kda" for t in types)
+                    if attn_fn is not None else 0)
     rng = jax.random.PRNGKey(seed)
-    # the attention core holds no parameter: the init goes through XLA's
-    # path whatever ``attn_fn`` is, and builds no kernel of its own
-    variables = jax.jit(TransformerLM(cfg).init)(
-        rng, jnp.zeros((1, seq_len), jnp.int32))
-    # (a routed model's init also fills what its layers sow)
-    variables = {"params": variables["params"]}
+    # only the parameters leave the jit, so the forward pass the init
+    # traces (XLA's attention whatever ``attn_fn`` is, and what a routed
+    # model's layers sow) is dead code and never runs
+    variables = {"params": jax.jit(
+        lambda key, ids: TransformerLM(cfg).init(key, ids)["params"])(
+        rng, jnp.zeros((1, seq_len), jnp.int32))}
+    param_count = sum(a.size for a in jax.tree_util.tree_leaves(variables))
+    remat_blocks = auto_remat_blocks(param_count, cfg.num_layers,
+                                     _chip_hbm_bytes())
+    model = TransformerLM(cfg, attn_fn=attn_fn, remat_blocks=remat_blocks)
+    router_load = SHARE_LOAD if cfg.experts_held is not None else ROUTER_LOAD
+    router_losses = cfg.router_activation == "softmax"
+    # (leading dense layers route nothing)
+    routed = cfg.num_experts and cfg.first_k_dense_replace < cfg.num_layers
 
     def forward(params, ids, method):
         """(the method's output, the router losses' weighted sum). The
         layers' load goes to the step's device counters from HERE, the
         loss's own trace (``telemetry/device_counters.py``)."""
-        if not cfg.num_experts:
+        if not routed:
             return model.apply(params, ids, method=method), None
         out, sown = model.apply(params, ids, method=method,
                                 mutable=["losses", "counters"])
         for layer in sown["counters"].values():
-            for name in ROUTER_LOAD:
+            for name in router_load:
                 device_counters.add("moe." + name, layer["moe"][name][0])
+        if not router_losses:
+            return out, None
         per_layer = sown["losses"].values()
         lb = sum(layer["moe"]["router_lb"][0] for layer in per_layer)
         z = sum(layer["moe"]["router_z"][0] for layer in per_layer)
@@ -327,6 +486,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     def loss_fn(params, batch):
         # what the rule decided, once per trace, host side
         tel.gauge_set("attention.flash_layers", flash_layers)
+        tel.gauge_set("model.remat_blocks",
+                      cfg.num_layers if remat_blocks else 0)
         tokens = batch["tokens"]
         targets = tokens[:, 1:]
         if lean_head:
@@ -346,8 +507,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         return mean_loss(nll, router_loss)
 
-    if cfg.num_experts:
-        loss_fn.device_counters = tuple("moe." + n for n in ROUTER_LOAD)
+    if routed:
+        loss_fn.device_counters = tuple("moe." + n for n in router_load)
 
     npr = np.random.RandomState(seed)
     example_batch = {"tokens": npr.randint(

@@ -88,13 +88,16 @@ def full_tiles(seq: int) -> bool:
 
 # ----------------------------------------------------------------- layout
 
-def _specs(D, bq, bk, q_pos, k_pos):
-    """BlockSpecs of one grid (b, h, i, j) over [B, H, S, D] operands: a
-    [rows, D] tile of a q-side and of a kv-side array, a [rows, 1] tile of
-    a per-row q-side vector ([B, H, S, 1]), and the [rows, 1] q / kv
-    segment ids ([B, S, 1]: the trailing 1 satisfies the TPU's (8, 128)
-    rule as for the row vectors). ``q_pos(i, j)`` / ``k_pos(i, j)`` give
-    the row-block index. The kernels see the tiles without leading dims."""
+def _specs(D, Dv, bq, bk, q_pos, k_pos):
+    """BlockSpecs of one grid (b, h, i, j) over [B, H, S, .] operands: a
+    [rows, D] tile of q and of k, a [rows, Dv] tile of v and of the
+    output (the values may be narrower or wider than the scores'
+    features: latent attention scores over 192 and carries 128), a
+    [rows, 1] tile of a per-row q-side vector ([B, H, S, 1]), and the
+    [rows, 1] q / kv segment ids ([B, S, 1]: the trailing 1 satisfies the
+    TPU's (8, 128) rule as for the row vectors). ``q_pos(i, j)`` /
+    ``k_pos(i, j)`` give the row-block index. The kernels see the tiles
+    without leading dims."""
     def tile(rows, pos, width):
         return pl.BlockSpec((None, None, rows, width),
                             lambda b, h, i, j: (b, h, pos(i, j), 0))
@@ -103,7 +106,8 @@ def _specs(D, bq, bk, q_pos, k_pos):
         return pl.BlockSpec((None, rows, 1),
                             lambda b, h, i, j: (b, pos(i, j), 0))
 
-    return (tile(bq, q_pos, D), tile(bk, k_pos, D), tile(bq, q_pos, 1),
+    return (tile(bq, q_pos, D), tile(bk, k_pos, D), tile(bk, k_pos, Dv),
+            tile(bq, q_pos, Dv), tile(bq, q_pos, 1),
             seg(bq, q_pos), seg(bk, k_pos))
 
 
@@ -228,17 +232,18 @@ def _fwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
 
 
 def _fwd(q, k, v, segs, causal):
-    """q, k, v in [B, H, S, D] (kernel-internal layout); segs is None or
-    (q_seg [B, Sq], kv_seg [B, Sk]) int32. Returns (out, lse [B, H, Sq, 1])."""
+    """q, k [B, H, S, D], v [B, H, S, Dv] (kernel-internal layout); segs is
+    None or (q_seg [B, Sq], kv_seg [B, Sk]) int32. Returns (out
+    [B, H, Sq, Dv], lse [B, H, Sq, 1])."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     has_seg = segs is not None
     bq, bk = _tiles(Sq, Sk)
     n_q, n_kv = Sq // bq, Sk // bk
 
-    q_spec, kv_spec, row_spec, qs_spec, ks_spec = _specs(
-        D, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk))
-    in_specs = [q_spec, kv_spec, kv_spec]
+    q_spec, k_spec, v_spec, o_spec, row_spec, qs_spec, ks_spec = _specs(
+        D, Dv, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk))
+    in_specs = [q_spec, k_spec, v_spec]
     operands = [q, k, v]
     if has_seg:
         in_specs += [qs_spec, ks_spec]
@@ -250,10 +255,10 @@ def _fwd(q, k, v, segs, causal):
                           n_kv=n_kv),
         grid=(B, H, n_q, n_kv),
         in_specs=in_specs,
-        out_specs=[q_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=[o_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
                    jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bq, Dv), jnp.float32),
                         pltpu.VMEM((bq, _LANES), jnp.float32),
                         pltpu.VMEM((bq, _LANES), jnp.float32)],
         compiler_params=_PARAMS,
@@ -339,10 +344,10 @@ def _dkdv_kernel(*refs, scale, causal, has_seg, bq, bk, n_q):
 
 
 def _bwd(causal, res, do):
-    """res tensors, do and the returned (dq, dk, dv) in [B, H, S, D]."""
+    """res tensors, do and the returned (dq, dk, dv) in [B, H, S, .]."""
     q, k, v, out, lse, q_seg, kv_seg = res
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     has_seg = q_seg is not None
     bq, bk = _tiles(Sq, Sk)
     n_q, n_kv = Sq // bq, Sk // bk
@@ -357,11 +362,11 @@ def _bwd(causal, res, do):
     if has_seg:
         operands += [q_seg[..., None], kv_seg[..., None]]
 
-    def in_specs(q_spec, kv_spec, row_spec, qs_spec, ks_spec):
-        specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    def in_specs(q_spec, k_spec, v_spec, o_spec, row_spec, qs_spec, ks_spec):
+        specs = [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec]
         return specs + [qs_spec, ks_spec] if has_seg else specs
 
-    specs = _specs(D, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk))
+    specs = _specs(D, Dv, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, n_kv=n_kv, **static),
         grid=(B, H, n_q, n_kv),
@@ -379,16 +384,16 @@ def _bwd(causal, res, do):
     def q_pos(i, j):
         return jnp.clip(j, (i * bk) // bq, n_q - 1) if causal else j
 
-    specs = _specs(D, bq, bk, q_pos, lambda i, j: i)
+    specs = _specs(D, Dv, bq, bk, q_pos, lambda i, j: i)
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, n_q=n_q, **static),
         grid=(B, H, n_kv, n_q),
         in_specs=in_specs(*specs),
-        out_specs=[specs[1], specs[1]],
+        out_specs=[specs[1], specs[2]],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+                        pltpu.VMEM((bk, Dv), jnp.float32)],
         compiler_params=_PARAMS,
         interpret=pallas_mode.interpret(),
         name="flash_dkdv",
@@ -437,7 +442,8 @@ def _tileable(q, k):
 
 
 def flash_attention(q, k, v, causal: bool = False, segment_ids=None):
-    """Exact fused attention. q,k,v: [B, S, H, D] -> [B, S, H, D].
+    """Exact fused attention. q, k: [B, S, H, D], v: [B, S, H, Dv] ->
+    [B, S, H, Dv]; scores are scaled by 1 / sqrt(D).
 
     ``segment_ids``: [B, S] int32 (shared q/kv for self-attention) or a
     ``(q_seg, kv_seg)`` pair — attention is allowed iff the ids are equal.

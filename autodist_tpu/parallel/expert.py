@@ -17,6 +17,10 @@ runs when:
   ([T·k, d]) whatever the routing; the data-dependent part is two row
   gathers by a permutation, whose backward passes are the inverse
   permutation's gathers.
+  A layer that holds a SHARE of its experts (``SigmoidRouting.held``: one
+  chip's of an expert-parallel deployment, run without the exchange) routes
+  over all of them and runs every held expert on every token under its
+  gate (:func:`_held_experts`): constant work whatever the router does.
 - :func:`moe_ffn` — **top-1 with a fixed per-expert capacity** (Switch,
   arXiv 2101.03961; GShard, arXiv 2006.16668), the path for a BOUND
   ``expert`` axis: tokens reach their expert's owning device with one
@@ -38,7 +42,7 @@ passes are [65536, 2048] scatters, the routing alone took 23.2 ms
 against 13.0 (my chip runs, PR 25; PERF.md section 6).
 """
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -236,11 +240,33 @@ def router_losses(logits, probs, counts):
     return lb, z
 
 
+class SigmoidRouting(NamedTuple):
+    """A router that scores every expert by a sigmoid, chooses the top k
+    of ``score + bias`` (one group: plain top-k), and gates by the chosen
+    scores, renormalised to sum to one where ``renormalize`` and times
+    ``scaling_factor``. ``held``: the experts this layer holds, in the
+    order of its weight stacks (None = all, and the sorted dropless form;
+    a share runs every held expert on every token: ``_held_experts``)."""
+    bias: jax.Array
+    renormalize: bool
+    scaling_factor: float
+    held: Optional[Tuple[int, ...]]
+
+    def choose(self, logits, top_k):
+        scores = jax.nn.sigmoid(logits)
+        _, expert = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(self.bias), top_k)
+        gate = jnp.take_along_axis(scores, expert, axis=-1)
+        if self.renormalize:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        return gate * self.scaling_factor, expert
+
+
 def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
-                     dtype=None):
-    """Token-choice top-k SwiGLU MoE with every expert local and no token
-    dropped. Returns (output with x's shape, load-balance loss, z-loss,
-    routed pairs per expert [E] int32: the layer's load).
+                     dtype=None, routing: Optional[SigmoidRouting] = None):
+    """Token-choice top-k SwiGLU MoE with no token dropped. Returns
+    (output with x's shape, load-balance loss, z-loss, routed pairs per
+    expert of the stacks [E] int32: the layer's load).
 
     - ``x``: [..., d] activations; flattened to T tokens internally.
     - ``router_w``: [d, E]; logits and softmax in float32 over all E; the
@@ -249,6 +275,13 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
     - ``w_gate``/``w_up``: [E, d, f], ``w_down``: [E, f, d]; computed in
       ``dtype`` with float32 accumulation of the per-token combine:
       ``sum_j g_j * down_ej(silu(gate_ej(x)) * up_ej(x))``.
+    - ``routing``: a :class:`SigmoidRouting` scores and gates in its own
+      way and has no router loss (both returned as 0). Where it names the
+      experts ``held`` (the stacks' E of the router's E_all, one chip's
+      share under expert parallelism), the router still scores and
+      normalises over all E_all and the output is the part the held
+      experts give (:func:`_held_experts`); what absent experts would add
+      is left out. Nothing stands in for the chips that hold them.
     """
     dt = dtype or x.dtype
     d = x.shape[-1]
@@ -261,8 +294,17 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
             logits = jnp.dot(tokens.astype(jnp.float32),
                              router_w.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            probs = jax.nn.softmax(logits, axis=-1)
-            gate, expert = jax.lax.top_k(probs, top_k)           # [T, k]
+            if routing is None:
+                probs = jax.nn.softmax(logits, axis=-1)
+                gate, expert = jax.lax.top_k(probs, top_k)       # [T, k]
+            else:
+                gate, expert = routing.choose(logits, top_k)
+        if routing is not None and routing.held is not None:
+            out, counts = _held_experts(tokens.astype(dt), gate, expert,
+                                        routing.held, w_gate, w_up, w_down)
+            return (out.astype(dt).reshape(x.shape), jnp.float32(0.0),
+                    jnp.float32(0.0), counts)
+        with scopes.scope(scopes.MOE_ROUTE):
             pair_expert = expert.reshape(-1)                     # [T*k]
             order = jnp.argsort(pair_expert, stable=True)        # by expert
             inv_order = jnp.argsort(order)
@@ -279,5 +321,40 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
             ys = _permute_rows(ys, inv_order, order)             # token order
             out = jnp.sum(ys.reshape(T, top_k, d).astype(jnp.float32)
                           * gate[:, :, None], axis=1)
-        lb, z = router_losses(logits, probs, counts)
+        if routing is None:
+            lb, z = router_losses(logits, probs, counts)
+        else:
+            lb = z = jnp.float32(0.0)
     return out.astype(dt).reshape(x.shape), lb, z, counts
+
+
+def _held_experts(tokens, gate, expert, held, w_gate, w_up, w_down):
+    """The held experts' part of the routed sum, ([T, d] float32, pairs per
+    held expert [E]): EVERY held expert on EVERY token, its hidden
+    activations scaled by the token's gate for it (zero where the token
+    did not choose it), one matmul over all E f hidden features down.
+
+    Why not the sorted form above over the pairs that chose a held expert:
+    a dropless layer's static bound is all T k pairs whatever the share, so
+    that form sorts and gathers [T k, d] rows to run the grouped matmul
+    over the few that are held. On the v5e at Kimi-Linear's share (8 of
+    256 held, k = 8, T = 8,192) that was 110 ms a step of sort and row
+    gathers in four layers around 1 to 8 ms of kernels, and the STEP'S TIME
+    FOLLOWED THE ROUTING: with seeded weights under Adam every token of a
+    sequence soon chooses the same experts, so a held expert got no token
+    or all 8,192, and each such expert added 3.7 ms (0.5 %) to the step
+    (PERF.md section 6, PR 29). Here the work is T E rows (no more than
+    the T k the sorted form must provide for wherever E <= k), every row of
+    it real, and the same whatever the router does."""
+    dt = tokens.dtype
+    with scopes.scope(scopes.MOE_ROUTE):
+        chose = expert[:, :, None] == jnp.asarray(held)[None, None, :]
+        weight = jnp.sum(jnp.where(chose, gate[:, :, None], 0.0), axis=1)
+        counts = jnp.sum(chose, axis=(0, 1), dtype=jnp.int32)     # [E]
+    with scopes.scope(scopes.MOE_EXPERTS):
+        h = (jax.nn.silu(jnp.einsum("td,edf->tef", tokens, w_gate.astype(dt)))
+             * jnp.einsum("td,edf->tef", tokens, w_up.astype(dt)))
+        h = (h.astype(jnp.float32) * weight[:, :, None]).astype(dt)
+        out = jnp.einsum("tef,efd->td", h, w_down.astype(dt),
+                         preferred_element_type=jnp.float32)
+    return out, counts

@@ -55,6 +55,10 @@ ATTENTION = "attention"
 MOE = "moe"
 MOE_ROUTE = "moe_route"
 MOE_EXPERTS = "moe_experts"
+MOE_SHARED = "moe_shared"
+KDA = "kda"
+KDA_SCAN = "kda_scan"
+MLA = "mla"
 PREFILL = "prefill"
 DECODE = "decode"
 INSERT = "insert"
@@ -81,7 +85,18 @@ SCOPES: Dict[str, str] = {
     MOE_ROUTE: "inside moe: router matmul, softmax, top-k, the sort by "
                "expert, the row gather, and the un-sort and gated combine",
     MOE_EXPERTS: "inside moe: the three grouped matmuls over the sorted "
-                 "rows and the SwiGLU activation between them",
+                 "rows and the SwiGLU activation between them (a layer that "
+                 "holds a share of its experts: every held expert on every "
+                 "token)",
+    MOE_SHARED: "inside moe: the shared expert(s), a dense SwiGLU every token "
+                "passes",
+    KDA: "inside attention: a Kimi Delta Attention mixer (projections, short "
+         "convolutions, gates, the delta rule, the gated norm, the output "
+         "projection)",
+    KDA_SCAN: "inside kda: the chunked gated delta rule alone "
+              "(ops/kda.py): what a kernel would replace",
+    MLA: "inside attention: a latent-attention mixer (q, the latent and its "
+         "up-projection, the attention core, the output projection)",
     PREFILL: "serving: the prompt pass of a prefill bucket",
     DECODE: "serving: one cached decode step",
     INSERT: "serving: writing admitted rows into the decode state",
