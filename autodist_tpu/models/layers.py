@@ -17,7 +17,9 @@ from jax.ad_checkpoint import checkpoint_name
 from autodist_tpu.telemetry import scopes
 
 Dtype = Any
-KDA_CORE_OUT = "kda_core_out"   # the delta rule's output, by name
+# the delta rule's output, by name; ``ops/kda.py:KEPT`` is the same name,
+# on the per-chunk states its backward kernel reads
+KDA_CORE_OUT = "kda_core_out"
 
 
 def causal_mask(seq_len: int) -> jnp.ndarray:
@@ -326,10 +328,15 @@ class KimiDeltaAttention(nn.Module):
         g = -jnp.exp(a_log)[:, None] * heads(
             jax.nn.softplus(f.astype(jnp.float32) + dt_bias))
         beta = nn.sigmoid(dense(H, "b_proj")(x).astype(jnp.float32))
-        with scopes.scope(scopes.KDA_SCAN):
-            o, _ = kda_chunked(q, k, v, g, beta, self.dtype)
-        # a block recomputed in the backward pass keeps this and does not
-        # run the core a third time (``models/lm.py:TransformerLM._block``)
+        if self.is_initializing():
+            # (the core holds no parameter: an init traces no kernel for it)
+            o = v.astype(self.dtype)
+        else:
+            with scopes.scope(scopes.KDA_SCAN):
+                o, _ = kda_chunked(q, k, v, g, beta, self.dtype)
+        # a block recomputed in the backward pass keeps this (and, by the
+        # same name, the kernels' per-chunk states) and does not run the
+        # core again (``models/lm.py:TransformerLM._block``)
         o = checkpoint_name(o, KDA_CORE_OUT)
         scale = self.param("o_norm", nn.initializers.ones, (D,))
         gate = nn.sigmoid(heads(

@@ -223,8 +223,9 @@ class TransformerLM(nn.Module):
             kw["router"] = RouterConfig(
                 cfg.moe_renormalize, cfg.routed_scaling_factor,
                 cfg.num_shared_experts, cfg.experts_held)
-        # (the delta rule recomputes itself, head group by head group: its
-        # output is kept, [tokens, 4096] a layer, or it would run thrice)
+        # (what the delta rule's backward needs of its forward is kept by
+        # name, its output and the kernels' per-chunk states: a recomputed
+        # block would run the core a second time)
         block = nn.remat(
             TransformerBlock,
             policy=jax.checkpoint_policies.save_only_these_names(
@@ -444,6 +445,11 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         attn_fn = make_flash_attn_fn(causal=True)
     flash_layers = (sum(t != "kda" for t in types)
                     if attn_fn is not None else 0)
+    kda_kernel_layers = 0
+    if "kda" in types:
+        from autodist_tpu.ops.kda import runs_as_kernels
+        if runs_as_kernels(cfg.kda_head_dim, cfg.kda_head_dim):
+            kda_kernel_layers = types.count("kda")
     rng = jax.random.PRNGKey(seed)
     # only the parameters leave the jit, so the forward pass the init
     # traces (XLA's attention whatever ``attn_fn`` is, and what a routed
@@ -486,6 +492,7 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     def loss_fn(params, batch):
         # what the rule decided, once per trace, host side
         tel.gauge_set("attention.flash_layers", flash_layers)
+        tel.gauge_set("attention.kda_kernel_layers", kda_kernel_layers)
         tel.gauge_set("model.remat_blocks",
                       cfg.num_layers if remat_blocks else 0)
         tokens = batch["tokens"]

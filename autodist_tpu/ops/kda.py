@@ -21,10 +21,32 @@ chunk, with G the running sum of g from the chunk's start and w_j = b_j
 
 so the work is matmuls of [C, d] x [d, C] and [C, C] x [C, d], one
 unit-lower-triangular solve of size C, and one state read and update per
-chunk. What the chunks do not share (M, P, the solve) is computed for all
-chunks at once; autodiff's residuals of the scan are one state per chunk
-(67 MB for 8 heads of 128 x 128 and 128 chunks), so no ``custom_vjp`` is
-needed.
+chunk.
+
+Two renderings of these equations, chosen by the head widths
+(:func:`runs_as_kernels`; no argument, flag or environment variable):
+
+- heads of whole 128-lane tiles (the published 128): PALLAS KERNELS under
+  a ``jax.custom_vjp``. A grid step is one chunk of ``_heads_per_step``
+  heads; the chunks of a head follow each other ("arbitrary") with the
+  state in a VMEM scratch that never leaves the chip between them. The
+  forward kernel (``kda_fwd``) writes o and, as the backward pass's
+  residual, the state each chunk starts from (float32 [B, H, N, d_v, d_k]:
+  268 MB for 32 heads of 128 x 128 and 128 chunks; named ``KEPT`` so that
+  a recomputed block keeps it and the forward runs once a step). The
+  backward kernel (``kda_bwd``) walks the chunks in reverse with the
+  state's gradient in VMEM: it takes ``jax.vjp`` of the same chunk
+  function from the inputs and that state, so M, P and the solve are
+  recomputed in the kernel and no [C, C], [SUB, SUB, d] or per-chunk
+  float32 transient passes through HBM. The solve is an explicit inverse
+  of the factor by float32 matmuls (``_unit_lower_inverse``), no
+  ``triangular-solve`` call. The kernels read q, k, v, g in the model's
+  own [B, S, H * d] layout: nothing is transposed around them.
+- narrower heads (the tiny test models'), and the oracle the kernels'
+  gradients are tested against: the ``lax`` form. What the chunks do not
+  share (M, P, the solve) is computed for all chunks at once, the state is
+  carried by ``lax.scan``; autodiff's residuals of the scan are one state
+  per chunk.
 
 Numerics. Only the DIFFERENCES exp(G_i - G_l), l <= i, are <= 1: written
 as (q exp(G)) (k exp(-G))^T the second factor overflows float32 once a
@@ -42,10 +64,19 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from autodist_tpu.ops import pallas_mode
 
 CHUNK = 64   # tokens per chunk: one state update per chunk
 SUB = 16     # tokens per sub-block of the decay differences
-HEAD_GROUP = 8   # heads worked on together
+HEAD_GROUP = 8   # heads worked on together (the ``lax`` form)
+# what a recomputed block keeps of the core: ``models/layers.py`` gives the
+# output this name, the kernels' forward gives it to the state each chunk
+# starts from, so the backward kernel finds both and no forward runs twice
+KEPT = "kda_core_out"
 
 
 @jax.checkpoint
@@ -127,15 +158,15 @@ def _chunked(q, k, v, g, beta, dt):
     return jnp.moveaxis(o, 0, 2), state
 
 
-def kda_chunked(q, k, v, g, beta, dtype=None):
+def _kda_lax(q, k, v, g, beta, dtype=None):
     """q, k [B, S, H, d_k] (normalised and scaled by the caller), v
     [B, S, H, d_v], g [B, S, H, d_k] float32 log-decay (<= 0), beta
     [B, S, H]: (o [B, S, H, d_v] in ``dtype``, final state
     [B, H, d_k, d_v] float32). Any S: the tail is padded with tokens that
-    neither decay nor write. Heads are worked ``HEAD_GROUP`` at a time
-    (``lax.map``), each group recomputed in the backward pass, so the
-    float32 transients of all heads never live together (3 GB a layer at
-    32 heads of 128 and 8,192 tokens)."""
+    neither decay nor write. The ``lax`` form: heads are worked
+    ``HEAD_GROUP`` at a time (``lax.map``), each group recomputed in the
+    backward pass, so the float32 transients of all heads never live
+    together (3 GB a layer at 32 heads of 128 and 8,192 tokens)."""
     dt = dtype or q.dtype
     B, S, H, dk = q.shape
     dv = v.shape[-1]
@@ -157,3 +188,417 @@ def kda_chunked(q, k, v, g, beta, dtype=None):
     o = jnp.moveaxis(o, (0, 2), (3, 4)).reshape(B, N * CHUNK, H, dv)[:, :S]
     state = jnp.moveaxis(state, 0, 1).reshape(B, H, dk, dv)
     return o.astype(dt), state
+
+
+# ------------------------------------------------------------ the kernels
+
+_LANES = 128
+HEAD_LANES = 512     # lanes of heads one grid step of a kernel works on
+_HI = jax.lax.Precision.HIGHEST
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
+
+
+def _exact(a, b, dims=_NN):
+    """A float32 matmul at float32 precision on the MXU (the inverse of
+    the unit-triangular factor and its product with the right-hand side:
+    what the ``lax`` form leaves to float32 ops)."""
+    return jax.lax.dot_general(a, b, dims, precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _iotas(shape):
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def _sum_with(ones, x):
+    """``ones @ x`` for a 0/1 matrix and float32 x, exactly: x is split
+    into three bfloat16 parts that hold all of its mantissa, and a product
+    with 0 or 1 rounds nothing: three MXU passes where a float32 matmul
+    takes six."""
+    bf = jnp.bfloat16
+    hi = x.astype(bf)
+    rest = x - hi.astype(x.dtype)
+    mid = rest.astype(bf)
+    low = (rest - mid.astype(x.dtype)).astype(bf)
+    part = lambda t: jnp.dot(ones.astype(bf), t,   # noqa: E731
+                             preferred_element_type=jnp.float32)
+    return part(hi) + part(mid) + part(low)
+
+
+@jax.custom_vjp
+def _running_sum(g):
+    """cumsum over the rows of one chunk [C, d], float32."""
+    row, col = _iotas((g.shape[0],) * 2)
+    return _sum_with(jnp.where(row >= col, 1.0, 0.0), g)
+
+
+def _running_sum_bwd(_, dG):
+    row, col = _iotas((dG.shape[0],) * 2)
+    return (_sum_with(jnp.where(row <= col, 1.0, 0.0), dG),)
+
+
+_running_sum.defvjp(lambda g: (_running_sum(g), None), _running_sum_bwd)
+
+
+def _per_head(Xp):
+    """[C, h C] (h matrices side by side in the lanes) -> the [h C, h C]
+    block-diagonal matrix of them: ``Ap @ _per_head(Bp)`` is every head's
+    own product, side by side again, in ONE pass of C rows through the
+    MXU (a 64 x 64 product alone uses a quarter of the array)."""
+    C, W = Xp.shape
+    if W == C:
+        return Xp
+    row, col = _iotas((W, W))
+    return jnp.where(row // C == col // C,
+                     jnp.concatenate([Xp] * (W // C), axis=0), 0.0)
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(Np):
+    """(I + N)^-1 of strictly lower-triangular N [C, C], float32, for the
+    h matrices of Np [C, h C], by matmuls alone: the ``SUB``-wide diagonal
+    blocks by the finite Neumann product (N_d^SUB = 0), then block pairs
+    merged twice over ((D + E)^-1 = D^-1 - D^-1 E D^-1 where E holds one
+    block below each diagonal block of D)."""
+    C = Np.shape[0]
+    row, col = _iotas(Np.shape)
+    col = col % C
+    Nd = jnp.where(row // SUB == col // SUB, Np, 0.0)
+    T = jnp.where(row == col, 1.0, 0.0) - Nd
+    power, per_head, width = Nd, _per_head(Nd), 2
+    while width < SUB:
+        power = _exact(power, per_head)
+        per_head = _per_head(power)
+        T = T + _exact(T, per_head)
+        width *= 2
+    width = SUB
+    while width < C:
+        E = jnp.where((row // width == col // width + 1)
+                      & (row // (2 * width) == col // (2 * width)), Np, 0.0)
+        T = T - _exact(_exact(T, _per_head(E)), _per_head(T))
+        width *= 2
+    return T
+
+
+def _unit_lower_inverse_fwd(Np):
+    T = _unit_lower_inverse(Np)
+    return T, T
+
+
+def _unit_lower_inverse_bwd(T, dT):
+    """dN = -T^T dT T^T, below the diagonal, head by head."""
+    C, W = T.shape
+    row, col = _iotas(T.shape)
+    both = _exact(T, dT, _TN)               # [h C, h C]: every head pair
+    own = both[:C]
+    for h in range(1, W // C):
+        own = jnp.where(col // C == h, both[h * C:(h + 1) * C], own)
+    dN = -_exact(own, _per_head(T), _NT)
+    return (jnp.where(row > col % C, dN, 0.0),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+_invert = jax.jit(_unit_lower_inverse)
+
+
+def _products(q, k, G, dt):
+    """M (rows of k) and P (rows of q) against the columns of k, [C, C]
+    each, zero above the diagonal: ``_decayed_products`` from ops Mosaic
+    lowers."""
+    f32 = jnp.float32
+    C, dk = q.shape
+    n = C // SUB
+    row, col = _iotas((C, C))
+    r = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    # a later sub-block against every token before it: split at its anchor
+    off = [jnp.zeros((2 * SUB, C), f32)]
+    for i in range(1, n):
+        rows = slice(i * SUB, (i + 1) * SUB)
+        anchor = G[i * SUB:i * SUB + 1]
+        to_anchor = jnp.exp(G[rows] - anchor)                    # <= 1
+        from_anchor = jnp.exp(jnp.where(r < i * SUB, anchor - G, -jnp.inf))
+        off.append(_mm(jnp.concatenate([k[rows] * to_anchor,
+                                        q[rows] * to_anchor]),
+                       k * from_anchor, dt, _NT))
+    M = jnp.concatenate([o[:SUB] for o in off])
+    P = jnp.concatenate([o[SUB:] for o in off])
+    # inside a sub-block: column l of all n blocks at once, the
+    # [SUB, SUB, d] differences as they stand
+    own = lambda t, l: jnp.concatenate([   # noqa: E731
+        jnp.broadcast_to(t[i * SUB + l:i * SUB + l + 1], (SUB, dk))
+        for i in range(n)])
+    for l in range(SUB):
+        decay = jnp.exp(jnp.where(r % SUB >= l, G - own(G, l), -jnp.inf))
+        cols = own(k, l) * decay
+        hit = col == row - row % SUB + l
+        M = M + jnp.where(hit, jnp.sum(k * cols, axis=-1, keepdims=True), 0.0)
+        P = P + jnp.where(hit, jnp.sum(q * cols, axis=-1, keepdims=True), 0.0)
+    return M, P
+
+
+def _mm(a, b, dt, dims=_NN):
+    """Operands in the model's dtype, float32 accumulation: ``mm`` of the
+    ``lax`` form."""
+    return jax.lax.dot_general(
+        a.astype(dt), b.astype(dt), dims, preferred_element_type=jnp.float32,
+        precision=_HI if dt == jnp.float32 else None)
+
+
+@functools.partial(jax.jit, static_argnums=4)
+def _factors(q, k, g, beta, dt):
+    """What one head's chunk does not share with the chunk before it: (the
+    running sum G of g, P, and the strictly lower part N of the
+    unit-triangular factor I + tril(beta M, -1))."""
+    row, col = _iotas((CHUNK, CHUNK))
+    G = _running_sum(g)
+    M, P = _products(q, k, G, dt)
+    return G, P, jnp.where(row > col, beta * M, 0.0)
+
+
+@functools.partial(jax.jit, static_argnums=8)
+def _through_state(St, q, k, v, beta, G, P, T, dt):
+    """(o, the transposed state the next chunk starts from) of one head
+    from the state its chunk starts from and ``_factors``' parts, T the
+    factor's inverse."""
+    G_end = G[CHUNK - 1:]
+    decay_in = jnp.exp(G)                   # from the chunk's start, <= 1
+    decay_out = jnp.exp(G_end - G)          # to the chunk's end, <= 1
+    w = _exact(T, beta * (v - _mm(k * decay_in, St, dt, _NT)))
+    return (_mm(q * decay_in, St, dt, _NT) + _mm(P, w, dt),
+            St * jnp.exp(G_end) + _mm(w, k * decay_out, dt, _TN))
+
+
+def _chunk(heads, dt):
+    """One chunk of the heads of a grid step, from ops Mosaic lowers (and
+    differentiates: the backward kernel takes ``jax.vjp`` of this). Per
+    head: the state it starts from, TRANSPOSED (St [d_v, d_k] float32, so
+    the chunk's decay scales lanes), and q, k, g [C, d_k], v [C, d_v],
+    beta [C, 1] float32 -> (o [C, d_v] float32, the transposed state the
+    next chunk starts from). The header's equations; what the chunks do
+    not share first, head by head, then the triangular factors' inverses
+    two heads to an MXU pass, then the states. (The parts are ``jax.jit``s
+    so that a kernel's trace holds each once, not once a head: the
+    lowering inlines them.)"""
+    C = CHUNK
+    parts = [_factors(q, k, g, beta, dt) for _, q, k, _, g, beta in heads]
+    pack = _LANES // C              # factors side by side in one MXU pass
+    if len(heads) % pack:
+        pack = 1
+    inverses = []
+    for i in range(0, len(heads), pack):
+        Tp = _invert(jnp.concatenate(
+            [N for _, _, N in parts[i:i + pack]], axis=1))
+        inverses += [Tp[:, h * C:(h + 1) * C] for h in range(pack)]
+    return [_through_state(St, q, k, v, beta, G, P, T, dt)
+            for (St, q, k, v, _, beta), (G, P, _), T
+            in zip(heads, parts, inverses)]
+
+
+def _lanes(h, width):
+    """Head h's lanes of a block [CHUNK, heads * width]."""
+    return (slice(None), slice(h * width, (h + 1) * width))
+
+
+def _inputs(refs, starts, h):
+    """Head h's arguments of ``_chunk`` from a grid step's blocks."""
+    q_ref, k_ref, v_ref, g_ref, b_ref = refs
+    dv, dk = starts.shape[-2:]
+    return (starts[h], q_ref[_lanes(h, dk)], k_ref[_lanes(h, dk)],
+            v_ref[_lanes(h, dv)].astype(jnp.float32), g_ref[_lanes(h, dk)],
+            b_ref[h])
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, states_ref,
+                final_ref, state, *, dt, n_chunks):
+    c = pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    heads = range(state.shape[0])
+    dv = state.shape[1]
+    states_ref[...] = state[...]
+    ends = _chunk([_inputs((q_ref, k_ref, v_ref, g_ref, b_ref), state, h)
+                   for h in heads], dt)
+    for h, (o, end) in zip(heads, ends):
+        o_ref[_lanes(h, dv)] = o.astype(o_ref.dtype)
+        state[h] = end
+
+    @pl.when(c == n_chunks - 1)
+    def _():
+        final_ref[...] = state[...]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, states_ref, do_ref,
+                dfinal_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate,
+                *, dt):
+    @pl.when(pl.program_id(2) == 0)        # the LAST chunk: reversed grid
+    def _():
+        dstate[...] = dfinal_ref[...]
+
+    heads = range(dstate.shape[0])
+    dv, dk = dstate.shape[1:]
+    _, vjp = jax.vjp(
+        lambda a: _chunk(a, dt),
+        [_inputs((q_ref, k_ref, v_ref, g_ref, b_ref), states_ref, h)
+         for h in heads])
+    grads, = vjp([(do_ref[_lanes(h, dv)].astype(jnp.float32), dstate[h])
+                  for h in heads])
+    for h, (dS, dq, dk_, dv_, dg, db) in zip(heads, grads):
+        dstate[h] = dS
+        dq_ref[_lanes(h, dk)] = dq
+        dk_ref[_lanes(h, dk)] = dk_
+        dv_ref[_lanes(h, dv)] = dv_.astype(dv_ref.dtype)
+        dg_ref[_lanes(h, dk)] = dg
+        db_ref[h] = db
+
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _specs(hb, dk, dv, chunk_of):
+    """BlockSpecs of a grid (b, h, c), ``hb`` heads a step: a chunk of the
+    heads' k-wide and v-wide rows, side by side in the lanes of
+    [B, N * CHUNK, H * width] (the model's own layout: no transpose
+    around the kernels), of their one-wide rows ([B, H, N * CHUNK, 1]),
+    the states their chunk starts from ([B, H, N, d_v, d_k]) and their one
+    state; ``chunk_of(c)`` is the chunk step c works on."""
+    def rows(width):
+        return pl.BlockSpec((None, CHUNK, hb * width),
+                            lambda b, h, c: (b, chunk_of(c), h))
+    return (rows(dk), rows(dv),
+            pl.BlockSpec((None, hb, CHUNK, 1),
+                         lambda b, h, c: (b, h, chunk_of(c), 0)),
+            pl.BlockSpec((None, hb, None, dv, dk),
+                         lambda b, h, c: (b, h, chunk_of(c), 0, 0)),
+            pl.BlockSpec((None, hb, dv, dk), lambda b, h, c: (b, h, 0, 0)))
+
+
+def _heads_per_step(H, dk, dv):
+    """As many heads as fill ``HEAD_LANES`` (4 of 128: independent chains
+    of small matmuls side by side fill each other's latency, and two
+    triangular factors share an MXU pass; the v5e read 30.8 / 24.2 /
+    21.8 ms a layer forward + backward at 1 / 2 / 4 heads and its VMEM
+    refused 8: PERF.md section 6, PR 30)."""
+    return math.gcd(H, max(HEAD_LANES // max(dk, dv), 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kda_kernels(q, k, v, g, beta, dt, interpret):
+    return _kda_fwd(q, k, v, g, beta, dt, interpret)[0]
+
+
+def _grid(q, v, beta, chunk_of):
+    """(grid, the state scratch, ``_specs``) for these operands."""
+    B, H, S, _ = beta.shape
+    dk, dv = q.shape[-1] // H, v.shape[-1] // H
+    hb = _heads_per_step(H, dk, dv)
+    return ((B, H // hb, S // CHUNK), pltpu.VMEM((hb, dv, dk), jnp.float32),
+            _specs(hb, dk, dv, chunk_of))
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _forward(q, k, v, g, beta, dt, interpret):
+    """q, k, g [B, N * CHUNK, H * d_k] float32, v [.., H * d_v], beta
+    [B, H, N * CHUNK, 1] float32 -> (o [B, N * CHUNK, H * d_v] in ``dt``,
+    the state each chunk starts from and the final state, TRANSPOSED
+    [.., d_v, d_k]). (A ``jax.jit``, like ``_backward``: the layers of a
+    model share one trace and one lowering of each kernel; compiled or
+    interpreted is an argument of both and of the ``custom_vjp``, not
+    read inside, or a cached trace would outlive
+    ``pallas_mode.compiling_for_tpu``.)"""
+    grid, scratch, (wide, tall, one, per_chunk, per_head) = _grid(
+        q, v, beta, lambda c: c)
+    B, H, N = grid[0], beta.shape[1], grid[2]
+    o, states, final = pl.pallas_call(
+        functools.partial(_fwd_kernel, dt=dt, n_chunks=N),
+        grid=grid,
+        in_specs=[wide, wide, tall, wide, one],
+        out_specs=[tall, per_chunk, per_head],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, dt),
+                   jax.ShapeDtypeStruct((B, H, N) + scratch.shape[1:],
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct((B, H) + scratch.shape[1:],
+                                        jnp.float32)],
+        scratch_shapes=[scratch],
+        compiler_params=_PARAMS,
+        interpret=interpret,
+        name="kda_fwd",
+    )(q, k, v, g, beta)
+    return o, states, final
+
+
+def _kda_fwd(q, k, v, g, beta, dt, interpret):
+    o, states, final = _forward(q, k, v, g, beta, dt, interpret)
+    return (o, final), (q, k, v, g, beta, checkpoint_name(states, KEPT))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _backward(dt, interpret, res, cot):
+    q, k, v, g, beta, states = res
+    n_chunks = states.shape[2]
+    grid, scratch, (wide, tall, one, per_chunk, per_head) = _grid(
+        q, v, beta, lambda c: n_chunks - 1 - c)
+    return tuple(pl.pallas_call(
+        functools.partial(_bwd_kernel, dt=dt),
+        grid=grid,
+        in_specs=[wide, wide, tall, wide, one, per_chunk, tall, per_head],
+        out_specs=[wide, wide, tall, wide, one],
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype)
+                   for t in (q, k, v, g, beta)],
+        scratch_shapes=[scratch],
+        compiler_params=_PARAMS,
+        interpret=interpret,
+        name="kda_bwd",
+    )(q, k, v, g, beta, states, *cot))
+
+
+_kda_kernels.defvjp(_kda_fwd, _backward)
+
+
+def runs_as_kernels(dk: int, dv: int) -> bool:
+    """Do heads of these widths run through the pallas kernels? Where both
+    are whole 128-lane tiles (the published 128), on a TPU and, interpreted,
+    on the CPU test backend; any other backend raises
+    (``pallas_mode.interpret``). Narrower heads (the tiny test models')
+    take the ``lax`` form."""
+    if dk % _LANES or dv % _LANES:
+        return False
+    pallas_mode.interpret()
+    return True
+
+
+def _kda_pallas(q, k, v, g, beta, dtype=None):
+    dt = dtype or q.dtype
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    pad = -S % CHUNK
+
+    def chunks(t, to):
+        """[B, S, H, width] -> [B, N * CHUNK, H * width]."""
+        t = jnp.pad(t.astype(to), [(0, 0), (0, pad), (0, 0), (0, 0)])
+        return t.reshape(B, S + pad, -1)
+
+    f32 = jnp.float32
+    o, final = _kda_kernels(
+        chunks(q, f32), chunks(k, f32), chunks(v, v.dtype), chunks(g, f32),
+        jnp.swapaxes(chunks(beta[..., None], f32), 1, 2)[..., None], dt,
+        pallas_mode.interpret())
+    return (o[:, :S].reshape(B, S, H, dv), jnp.swapaxes(final, -1, -2))
+
+
+def kda_chunked(q, k, v, g, beta, dtype=None):
+    """q, k [B, S, H, d_k] (normalised and scaled by the caller), v
+    [B, S, H, d_v], g [B, S, H, d_k] float32 log-decay (<= 0), beta
+    [B, S, H]: (o [B, S, H, d_v] in ``dtype``, final state
+    [B, H, d_k, d_v] float32). Any S: the tail is padded with tokens that
+    neither decay nor write. One algorithm, two renderings chosen by the
+    head widths (:func:`runs_as_kernels`)."""
+    form = _kda_pallas if runs_as_kernels(q.shape[-1], v.shape[-1]) \
+        else _kda_lax
+    return form(q, k, v, g, beta, dtype)

@@ -94,7 +94,8 @@ SCOPES: Dict[str, str] = {
          "convolutions, gates, the delta rule, the gated norm, the output "
          "projection)",
     KDA_SCAN: "inside kda: the chunked gated delta rule alone "
-              "(ops/kda.py): what a kernel would replace",
+              "(ops/kda.py): at heads of whole 128-lane tiles the pallas "
+              "kernels kda_fwd / kda_bwd and the layout passes around them",
     MLA: "inside attention: a latent-attention mixer (q, the latent and its "
          "up-projection, the attention core, the output projection)",
     PREFILL: "serving: the prompt pass of a prefill bucket",

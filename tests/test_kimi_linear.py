@@ -61,6 +61,13 @@ def tiny_config(**kw):
         **sizes)
 
 
+def tiny_olmoe():
+    return dataclasses.replace(
+        lm.LMConfig.olmoe_1b_7b(num_layers=1, max_seq_len=16), vocab_size=64,
+        d_model=32, num_heads=2, num_experts=4, experts_per_token=2,
+        mlp_dim=16)
+
+
 def close(got, want, rtol=RTOL):
     """Within rtol of the reference's largest entry, elementwise."""
     got, want = np.asarray(got), np.asarray(want)
@@ -179,7 +186,7 @@ def test_one_layer_of_each_kind_matches_to_1e_5(kind, dense):
 # --------------------------- the chunked delta rule against the recurrence
 
 
-def kda_inputs(seq, decay, seed=0):
+def kda_inputs(seq, decay, seed=0, width=16, B=2, H=3, values=None):
     """q, k normalised as the mixer does; ``decay``: "seeded" draws the log
     decay as seeded parameters give it (A in [1, 16], softplus(dt_bias) in
     [1e-3, 1e-1]); "strongest" is the parameterisation's end: A = 16 and a
@@ -187,7 +194,7 @@ def kda_inputs(seq, decay, seed=0):
     is 0 in float32: a chunk's running sum reaches -10,240) beside
     channels that do not decay at all."""
     r = np.random.RandomState(seed)
-    B, H, dk, dv = 2, 3, 16, 16
+    dk, dv = width, values or width
     q = r.randn(B, seq, H, dk)
     k = r.randn(B, seq, H, dk)
     q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(dk)
@@ -210,25 +217,118 @@ def weighted(fn, *args):
                                       .reshape(state.shape))))
 
 
+def outputs_and_gradients(fn, args):
+    """(o, final state, dq, dk, dv, dg, dbeta) under ``weighted``."""
+    return tuple(fn(*args)) + tuple(jax.grad(
+        functools.partial(weighted, fn), argnums=(0, 1, 2, 3, 4))(*args))
+
+
+# the rendering ``kda_chunked`` picks by the head width, and a size for it
+# (the kernels run interpreted here, a grid step at a time)
+PATHS = {"lax": dict(width=16), "kernel": dict(width=128, B=1, H=2)}
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
 @pytest.mark.parametrize("decay", ["seeded", "strongest"])
 @pytest.mark.parametrize("seq", [64, 128, 100, 7])
-def test_chunked_kda_is_the_recurrence(seq, decay):
+def test_chunked_kda_is_the_recurrence(seq, decay, path):
     """Output, final state and the gradients of q, k, v, g, beta, at
-    lengths that are and are not whole chunks. Under the strongest decay
-    nothing overflows and nothing is NaN (``close`` asserts finite); a
-    running sum of -10,240 carries a float32 rounding of 1e-3 in absolute
-    terms, so there the tolerance is 1e-4."""
-    args = kda_inputs(seq, decay)
+    lengths that are and are not whole chunks, of the ``lax`` form (heads
+    of 16) and of the pallas kernels (heads of 128). Under the strongest
+    decay nothing overflows and nothing is NaN (``close`` asserts finite);
+    a running sum of -10,240 carries a float32 rounding of 1e-3 in
+    absolute terms, so there the tolerance is 1e-4."""
+    args = kda_inputs(seq, decay, **PATHS[path])
+    assert kda_op.runs_as_kernels(args[0].shape[-1], args[2].shape[-1]) \
+        is (path == "kernel")
     rtol = RTOL if decay == "seeded" else DEEP_RTOL
     with jax.default_matmul_precision("highest"):
-        got = kda_op.kda_chunked(*args)
-        want = ref.delta_rule(*args)
-        got_grads = jax.grad(functools.partial(weighted, kda_op.kda_chunked),
-                             argnums=(0, 1, 2, 3, 4))(*args)
-        want_grads = jax.grad(functools.partial(weighted, ref.delta_rule),
-                              argnums=(0, 1, 2, 3, 4))(*args)
-    for a, b in zip(got + got_grads, want + want_grads):
+        got = outputs_and_gradients(kda_op.kda_chunked, args)
+        want = outputs_and_gradients(ref.delta_rule, args)
+    for a, b in zip(got, want):
         close(a, b, rtol)
+
+
+@pytest.mark.parametrize("dtype, rtol, heads", [
+    (jnp.float32, RTOL, dict(H=2)),
+    (jnp.bfloat16, 5e-2, dict(H=2)),
+    # an odd number of heads (one a grid step, its triangular factor alone
+    # in an MXU pass) with values twice as wide as the keys
+    (jnp.float32, RTOL, dict(H=3, values=256))])
+def test_the_kernels_gradients_are_autodiffs_of_the_lax_form(dtype, rtol,
+                                                             heads):
+    """The backward kernel (``jax.vjp`` of the forward chunk, the state's
+    gradient carried in VMEM) against XLA's autodiff of the ``lax`` form on
+    the same inputs, two chunks and a tail: the five gradients, the output
+    and the final state. In float32 they differ by the order of sums; with
+    bfloat16 matmul operands by bfloat16's rounding (the tolerance of
+    ``tests/test_flash_attention.py``'s bfloat16 cases)."""
+    args = kda_inputs(150, "seeded", seed=1, width=128, B=1, **heads)
+    with jax.default_matmul_precision("highest"):
+        got = outputs_and_gradients(
+            functools.partial(kda_op._kda_pallas, dtype=dtype), args)
+        want = outputs_and_gradients(
+            functools.partial(kda_op._kda_lax, dtype=dtype), args)
+    assert got[0].dtype == dtype and got[1].dtype == jnp.float32
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        close(a.astype(jnp.float32), b.astype(jnp.float32), rtol)
+
+
+def test_the_head_width_picks_the_rendering(monkeypatch):
+    """Whole 128-lane tiles take the kernels, on a TPU compiled and on the
+    CPU interpreted; the tiny model's heads take the ``lax`` form whatever
+    the backend; a backend the kernels know nothing of raises, as for the
+    flash kernel (``pallas_mode.interpret``)."""
+    assert kda_op.runs_as_kernels(128, 128)
+    assert kda_op.runs_as_kernels(256, 128)
+    for narrow in ((16, 16), (64, 64), (128, 64), (192, 128)):
+        assert not kda_op.runs_as_kernels(*narrow)
+    args = kda_inputs(8, "seeded", width=16, B=1, H=1)
+    assert "pallas_call" not in str(jax.make_jaxpr(kda_op.kda_chunked)(*args))
+    args = kda_inputs(8, "seeded", width=128, B=1, H=1)
+    assert "pallas_call" in str(jax.make_jaxpr(kda_op.kda_chunked)(*args))
+    # compiled or interpreted follows ``pallas_mode`` through the kernels'
+    # cached traces (they are ``jax.jit``s under a ``custom_vjp``)
+    from autodist_tpu.ops import pallas_mode
+
+    def traced():
+        return str(jax.make_jaxpr(lambda *a: jax.grad(lambda *b: jnp.sum(
+            kda_op.kda_chunked(*b)[0]))(*a))(*args))
+    with pallas_mode.compiling_for_tpu():
+        for_tpu = traced()
+    assert for_tpu.count("interpret=False") == 2 == traced().count(
+        "interpret=True")
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert not kda_op.runs_as_kernels(16, 16)
+    with pytest.raises(RuntimeError, match="gpu"):
+        kda_op.runs_as_kernels(128, 128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kda_op.runs_as_kernels(128, 128)
+    assert layers.KDA_CORE_OUT == kda_op.KEPT
+
+
+GAUGE_CASES = {
+    # the cell's layer pattern at the published head width, narrow otherwise
+    "kimi_linear_train_1chip": (lambda: tiny_config(
+        kda_num_heads=1, kda_head_dim=128), 4),
+    "the tiny model's heads of 16": (lambda: tiny_config(), 0),
+    "olmoe_train_1chip": (lambda: tiny_olmoe(), 0),
+    "lm1b_train_1chip": (lm.LMConfig.tiny, 0),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(GAUGE_CASES))
+def test_the_gauge_says_how_many_layers_took_the_kda_kernels(cell):
+    """``attention.kda_kernel_layers`` is set when the loss is traced, from
+    what ``runs_as_kernels`` said of the configuration's KDA heads."""
+    make, layers_ = GAUGE_CASES[cell]
+    loss_fn, params, batch, _ = lm.make_train_setup(
+        make(), seq_len=16, batch_size=1, seed=0)
+    telemetry.reset()
+    jax.eval_shape(loss_fn, params, batch)
+    assert telemetry.get_recorder().gauges()[
+        "attention.kda_kernel_layers"] == layers_
 
 
 def test_the_factored_form_would_overflow_where_the_sub_blocks_do_not():
@@ -452,11 +552,7 @@ def test_the_share_reaches_the_counters_only_under_telemetry(tiny, tracing):
 
 
 def test_olmoes_loss_declares_the_counters_it_declared_before():
-    cfg = dataclasses.replace(
-        lm.LMConfig.olmoe_1b_7b(num_layers=1, max_seq_len=16), vocab_size=64,
-        d_model=32, num_heads=2, num_experts=4, experts_per_token=2,
-        mlp_dim=16)
-    loss_fn = lm.make_train_setup(cfg, seq_len=8, batch_size=2)[0]
+    loss_fn = lm.make_train_setup(tiny_olmoe(), seq_len=8, batch_size=2)[0]
     assert loss_fn.device_counters == ("moe.max_expert_pairs",
                                        "moe.routed_pairs")
     share = lm.make_train_setup(tiny_config(num_layers=2), seq_len=8,

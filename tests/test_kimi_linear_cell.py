@@ -298,9 +298,12 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     probe answers "tpu", the chip's memory is the v5e's, the kernels lower
     as Mosaic calls), at the published widths and 1 x 8,192 tokens: the
     three flash kernels at 192 / 128 (the forward once more for the
-    recomputed block), every held expert on every token in four routed
-    layers, every block recomputed, and state + scratch inside 16 GB with the
-    2.4 GB of initial parameters ``ModelItem`` keeps beside them."""
+    recomputed block), the delta rule's two kernels ONCE per KDA layer (a
+    recomputed block keeps their output and per-chunk states by name), no
+    triangular solve and no loop of the core left to XLA, every held
+    expert on every token in four routed layers, every block recomputed,
+    and state + scratch inside 16 GB with the 2.4 GB of initial parameters
+    ``ModelItem`` keeps beside them."""
     import sys
     import autodist_tpu
     path = list(sys.path)
@@ -318,12 +321,22 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     text = compiled[0].as_text()
     assert all(name in text for name in ("flash_fwd", "flash_dq",
                                          "flash_dkdv"))
-    # the three flash kernels and the recomputed forward; a share of the
-    # experts runs no grouped-matmul kernel (expert.py:_held_experts)
-    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    # the three flash kernels and the recomputed forward, four KDA layers'
+    # forward and backward kernels; a share of the experts runs no
+    # grouped-matmul kernel (expert.py:_held_experts)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 4 + 4 + 4
+    assert sum("kda_fwd" in line for line in calls) == 4
+    assert sum("kda_bwd" in line for line in calls) == 4
+    assert all("kda_scan" in line for line in calls if "kda_" in line)
+    assert "triangular-solve" not in text
+    # (the lean head's two scans are the step's only loops)
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "kda" in line]
     assert "rematted_computation" in text
     step = out["train_step"]
     # float32 master weights and Adam's two moments: 12 B a parameter
     assert abs(step["argument_size_in_bytes"] - 12 * 602434432) < 1 << 20
-    assert step["temp_size_in_bytes"] < 5.0e9          # 4.58 GB, PR 29
+    assert step["temp_size_in_bytes"] < 5.6e9   # 4.85 GB, PR 30 (4.58, PR 29)
     assert step["live_bytes_estimate"] + 4 * 602434432 < 15.2e9
