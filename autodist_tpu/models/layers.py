@@ -26,18 +26,59 @@ def causal_mask(seq_len: int) -> jnp.ndarray:
     return jnp.tril(jnp.ones((1, 1, seq_len, seq_len), jnp.bool_))
 
 
-def rope(x, positions, theta: float):
+def rotate(x, positions, inv_freq, amplitude: float = 1.0):
     """Rotary position embedding in the ``rotate_half`` form (HF
     ``apply_rotary_pos_emb``): x [B, S, H, D], positions [S] or [B, S]
-    (the index in the sequence). Angles and the rotation in float32."""
-    d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    (the index in the sequence), ``inv_freq`` [D/2] the angle a position
+    adds to each pair of features, cos and sin times ``amplitude`` (YaRN's
+    ``mscale`` ratio). Angles and the rotation in float32."""
     ang = positions.astype(jnp.float32)[..., None] * inv_freq  # [.., S, D/2]
     ang = jnp.concatenate([ang, ang], axis=-1)[..., None, :]   # [.., S, 1, D]
     xf = x.astype(jnp.float32)
     x1, x2 = jnp.split(xf, 2, axis=-1)
     rotated = jnp.concatenate([-x2, x1], axis=-1)
-    return (xf * jnp.cos(ang) + rotated * jnp.sin(ang)).astype(x.dtype)
+    # (no multiply by 1: plain RoPE's callers trace the equations they
+    # always did)
+    amp = (lambda t: t) if amplitude == 1.0 else (lambda t: t * amplitude)
+    return (xf * amp(jnp.cos(ang))
+            + rotated * amp(jnp.sin(ang))).astype(x.dtype)
+
+
+def rope(x, positions, theta: float):
+    """:func:`rotate` by plain RoPE's frequencies ``theta^(-2i/D)``."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    return rotate(x, positions, inv_freq)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term ``0.1 mscale ln(factor) + 1`` (1
+    where the context is not extended)."""
+    return 1.0 if factor <= 1 else float(0.1 * mscale * np.log(factor) + 1.0)
+
+
+def rotary_inv_freq(dim: int, theta: float, yarn: "Optional[YarnConfig]"):
+    """The angle a position adds to each of ``dim / 2`` rotary pairs,
+    float32, computed once, host side. Plain RoPE: pair i turns
+    ``theta^(-2i/dim)`` a position. With ``yarn`` YaRN's blend (arXiv
+    2309.00071, "NTK-by-parts"; the released DeepSeek-V2 code): that
+    (``extra``polation, as trained) or that over ``factor``
+    (``inter``polation), by a linear ramp between the pairs that make
+    ``beta_fast`` and ``beta_slow`` whole turns over the original window."""
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if yarn is None:
+        return extra.astype(np.float32)
+
+    def pair_turning(rotations):
+        return (dim * np.log(yarn.original_max_position_embeddings
+                             / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+    low = max(int(np.floor(pair_turning(yarn.beta_fast))), 0)
+    high = min(int(np.ceil(pair_turning(yarn.beta_slow))), dim - 1)
+    ramp = (np.arange(dim // 2) - low) / max(high - low, 1e-3)
+    keep = 1.0 - np.clip(ramp, 0.0, 1.0)       # 1 = extrapolated as trained
+    return (extra / yarn.factor * (1.0 - keep) + extra * keep).astype(
+        np.float32)
 
 
 def make_norm(kind: str, eps: float, dtype, name=None):
@@ -173,26 +214,46 @@ class KDAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """``rope_scaling`` of ``type: yarn`` as a config.json publishes it."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+@dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """Latent attention's sizes; ``rope_theta`` None = NoPE (the
-    ``qk_rope_head_dim`` features exist and are not rotated)."""
+    """Latent attention's sizes and its position signal: ``rope_theta``
+    None = NoPE (the ``qk_rope_head_dim`` features exist and are not
+    rotated); a base rotates q's and the shared key's ``qk_rope_head_dim``
+    features, by plain RoPE's frequencies or, with ``yarn``, by YaRN's
+    blended ones, which also rescale the softmax."""
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
     rope_theta: Optional[float] = None
+    yarn: Optional[YarnConfig] = None
 
 
 @dataclasses.dataclass(frozen=True)
 class RouterConfig:
-    """What a routed feed-forward's router does beyond OLMoE's softmax
-    gate: a sigmoid score per expert, the gates renormalised over the
-    chosen and scaled, experts every token passes, and the SHARE of the
-    experts this layer holds (None = all of them)."""
-    renormalize: bool
-    scaling_factor: float
-    shared_experts: int
-    held: Optional[Tuple[int, ...]]
+    """A routed feed-forward's router and what stands beside it: how it
+    scores (``activation`` "softmax" | "sigmoid", the latter choosing by
+    score + a bias), whether the gates are renormalised over the chosen
+    and scaled, whether a softmax router's balance loss is taken per
+    sequence (``seq_aux``) or over all tokens with a z-loss (OLMoE's
+    pair), the experts every token passes, and the SHARE of the experts
+    this layer holds (None = all of them). The defaults are OLMoE's."""
+    activation: str = "softmax"
+    renormalize: bool = False
+    scaling_factor: float = 1.0
+    shared_experts: int = 0
+    held: Optional[Tuple[int, ...]] = None
+    seq_aux: bool = False
 
 
 def linear(features, dtype, name):
@@ -229,53 +290,53 @@ class MoEFeedForward(nn.Module):
     the first to itself and hands the second to
     ``telemetry.device_counters``.
 
-    With a ``router`` (:class:`RouterConfig`) the scores are sigmoids, the
-    choice is by score plus ``e_score_correction_bias`` (it only chooses, so
-    no gradient reaches it, and no rule here updates it: it stays at its
-    initial zero), there is
-    no router loss, the stacks hold only the experts this layer HOLDS,
-    ``counters`` also gets ``chosen_pairs`` (all T x k), and the shared
-    experts (one SwiGLU as wide as all of them) are added in full."""
+    ``router`` (:class:`RouterConfig`) says the rest. A softmax router
+    sows its losses (``router_z`` stays 0 under ``seq_aux``); a sigmoid
+    router has none and chooses by score plus ``e_score_correction_bias``
+    (it only chooses, so no gradient reaches it, and no rule here updates
+    it: it stays at its initial zero). With a share (``held``) the stacks
+    hold only the experts this layer HOLDS, the losses are still over all
+    the router's outputs, and ``counters`` also gets ``chosen_pairs`` (all
+    T x k). The shared experts (one SwiGLU as wide as all of them, the
+    released DeepSeek and Kimi code's own form) are added in full."""
     num_experts: int
     experts_per_token: int
     expert_dim: int
     dtype: Dtype = jnp.float32
-    router: Optional[RouterConfig] = None
+    router: RouterConfig = RouterConfig()
 
     @nn.compact
     def __call__(self, x):
-        from autodist_tpu.parallel.expert import (SigmoidRouting,
-                                                  dropless_moe_ffn)
+        from autodist_tpu.parallel.expert import Routing, dropless_moe_ffn
         d, E, f = x.shape[-1], self.num_experts, self.expert_dim
         cfg = self.router
-        held = E if cfg is None or cfg.held is None else len(cfg.held)
+        held = E if cfg.held is None else len(cfg.held)
         stacked = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=1, out_axis=2, batch_axis=0)
         router = self.param("router", nn.initializers.lecun_normal(), (d, E))
         w_gate = self.param("gate_proj", stacked, (held, d, f))
         w_up = self.param("up_proj", stacked, (held, d, f))
         w_down = self.param("down_proj", stacked, (held, f, d))
-        routing = None
-        if cfg is not None:
-            bias = self.param("e_score_correction_bias",
-                              nn.initializers.zeros, (E,))
-            routing = SigmoidRouting(bias, cfg.renormalize,
-                                     cfg.scaling_factor, cfg.held)
+        softmax = cfg.activation == "softmax"
+        bias = None if softmax else self.param(
+            "e_score_correction_bias", nn.initializers.zeros, (E,))
         out, lb, z, counts = dropless_moe_ffn(
             x, router, w_gate, w_up, w_down, self.experts_per_token,
-            self.dtype, routing=routing)
-        if cfg is None:
+            self.dtype, Routing(cfg.activation, cfg.renormalize,
+                                cfg.scaling_factor, bias),
+            held=cfg.held, seq_aux=cfg.seq_aux)
+        if softmax:
             self.sow("losses", "router_lb", lb)
             self.sow("losses", "router_z", z)
         self.sow("counters", "max_expert_pairs", jnp.max(counts))
         self.sow("counters", "routed_pairs", jnp.sum(counts))
-        if cfg is not None:
+        if cfg.held is not None:
             pairs = x.size // d * self.experts_per_token
             self.sow("counters", "chosen_pairs", jnp.int32(pairs))
-            if cfg.shared_experts:
-                with scopes.scope(scopes.MOE), scopes.scope(scopes.MOE_SHARED):
-                    out = out + SwiGLU(cfg.shared_experts * f, self.dtype,
-                                       name="shared")(x)
+        if cfg.shared_experts:
+            with scopes.scope(scopes.MOE), scopes.scope(scopes.MOE_SHARED):
+                out = out + SwiGLU(cfg.shared_experts * f, self.dtype,
+                                   name="shared")(x)
         return out
 
 
@@ -352,7 +413,11 @@ class LatentAttention(nn.Module):
     head; k and v up-projected from an RMS-normalised latent of
     ``kv_lora_rank``, k's last ``rope`` features projected straight from x
     and shared by all heads; scores over ``nope + rope`` features, values
-    of ``v_head_dim``. With ``rope_theta`` None nothing is rotated."""
+    of ``v_head_dim``. With ``rope_theta`` None nothing is rotated (Kimi-
+    Linear); with it q's last ``rope`` features and the shared key are
+    rotated in the ``rotate_half`` pairing, and with ``yarn`` by YaRN's
+    blended frequencies, cos and sin times ``ms(mscale) / ms(mscale_all_dim)``
+    and the scores times ``ms(mscale_all_dim)^2`` (DeepSeek-V2)."""
     num_heads: int
     cfg: MLAConfig
     norm_eps: float
@@ -374,17 +439,34 @@ class LatentAttention(nn.Module):
         k_nope, v = jnp.split(kv, [c.qk_nope_head_dim], axis=-1)
         k_pe = k_pe[..., None, :]
         if c.rope_theta is not None:
+            if positions is None:
+                raise ValueError("rotary attention needs positions")
+            inv_freq = rotary_inv_freq(c.qk_rope_head_dim, c.rope_theta,
+                                       c.yarn)
+            amplitude = 1.0 if c.yarn is None else (
+                yarn_mscale(c.yarn.factor, c.yarn.mscale)
+                / yarn_mscale(c.yarn.factor, c.yarn.mscale_all_dim))
             q_nope, q_pe = jnp.split(q, [c.qk_nope_head_dim], axis=-1)
             q = jnp.concatenate(
-                [q_nope, rope(q_pe, positions, c.rope_theta)], axis=-1)
-            k_pe = rope(k_pe, positions, c.rope_theta)
+                [q_nope, rotate(q_pe, positions, inv_freq, amplitude)],
+                axis=-1)
+            k_pe = rotate(k_pe, positions, inv_freq, amplitude)
+        if c.yarn is not None and c.yarn.mscale_all_dim:
+            # YaRN's temperature: the scores times ms(mscale_all_dim)^2, put
+            # onto q (in float32: bfloat16 would round the factor itself by
+            # a quarter of a percent) so that the attention function below
+            # stays the one every model shares
+            q = (q.astype(jnp.float32) * yarn_mscale(
+                c.yarn.factor, c.yarn.mscale_all_dim) ** 2).astype(q.dtype)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:-1] + k_pe.shape[-1:])],
             axis=-1)
         from autodist_tpu.ops.attention import reference_attention
-        # (both scale the scores by 1 / sqrt(nope + rope) and take values
-        # narrower than the scores' features)
-        out = (self.attn_fn or reference_attention)(q, k, v, mask)
+        # (both scale the scores by 1 / sqrt(nope + rope), on top of
+        # YaRN's factor above, and take values narrower than the scores'
+        # features)
+        with scopes.scope(scopes.MLA_CORE):
+            out = (self.attn_fn or reference_attention)(q, k, v, mask)
         return dense(x.shape[-1], "o_proj")(
             out.reshape(out.shape[:-2] + (H * c.v_head_dim,)))
 
@@ -416,7 +498,7 @@ class TransformerBlock(nn.Module):
     kda: Optional[KDAConfig] = None
     mla: Optional[MLAConfig] = None
     dense_dim: int = 0
-    router: Optional[RouterConfig] = None
+    router: RouterConfig = RouterConfig()
 
     def _mix(self, h, mask, cache, cursor, alive, return_kv, positions):
         """The block's token mixer on the normed input."""

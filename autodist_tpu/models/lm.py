@@ -21,8 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from autodist_tpu.models.layers import (KDA_CORE_OUT, KDAConfig, MLAConfig,
-                                        RouterConfig,
-                                        SparseEmbed, TransformerBlock,
+                                        RouterConfig, SparseEmbed,
+                                        TransformerBlock, YarnConfig,
                                         causal_mask, make_norm)
 from autodist_tpu.telemetry import spans as tel
 from autodist_tpu.telemetry import device_counters, scopes
@@ -34,6 +34,7 @@ ROUTER_LOAD = ("max_expert_pairs", "routed_pairs")
 # router chose, held here or not
 SHARE_LOAD = ROUTER_LOAD + ("chosen_pairs",)
 LAYER_TYPES = ("attention", "kda", "mla")
+YARN_KEYS = tuple(f.name for f in dataclasses.fields(YarnConfig))
 
 
 @dataclasses.dataclass
@@ -53,6 +54,10 @@ class LMConfig:
     norm_eps: float = 1e-6
     # rotary positions on q and k with this base; None = a learned table
     rope_theta: Optional[float] = None
+    # the source's ``rope_scaling`` dict: None, or ``type: yarn`` with
+    # YaRN's six numbers (blended frequencies and a softmax scale of its
+    # own, on latent attention's rotary features)
+    rope_scaling: Optional[dict] = None
     qk_norm: bool = False           # RMSNorm over the projected q and k
     attention_bias: bool = True
     head_bias: bool = True
@@ -64,10 +69,15 @@ class LMConfig:
     experts_per_token: int = 0
     router_aux_loss_coef: float = 0.0   # load-balance loss, per layer
     router_z_loss_coef: float = 0.0
+    # the balance loss taken per SEQUENCE and summed over the routed
+    # layers (DeepSeek-V2's ``seq_aux``; ``router_aux_loss_coef`` is its
+    # ``aux_loss_alpha``), not over all tokens and averaged over layers
+    seq_aux: bool = False
     # A model whose layers differ. ``layer_types[i]`` is layer i's token
     # mixer: "attention" (the softmax attention above), "kda" (Kimi Delta
     # Attention: ``kda_*``) or "mla" (latent attention: the four widths
-    # below; rotary on ``qk_rope_head_dim`` features iff ``rope_theta``).
+    # below; rotary on ``qk_rope_head_dim`` features iff ``rope_theta``,
+    # by ``rope_scaling``'s frequencies where it is given).
     # None = "attention" in every layer.
     layer_types: Optional[Tuple[str, ...]] = None
     kda_num_heads: int = 0
@@ -80,10 +90,11 @@ class LMConfig:
     # the first k layers' feed-forward is a dense SwiGLU of ``dense_dim``
     first_k_dense_replace: int = 0
     dense_dim: int = 0
-    # "softmax": the gate is the softmax probability and the router
-    # losses apply (OLMoE); "sigmoid": a score per expert, chosen by
-    # score + bias, gates renormalised over the chosen
-    # (``moe_renormalize``) and scaled, no router loss
+    # "softmax": a probability over all experts, the router losses apply
+    # (OLMoE, DeepSeek-V2); "sigmoid": a score per expert, chosen by
+    # score + bias, no router loss (Kimi-Linear). Either way the gates
+    # are the chosen scores, renormalised over the chosen where
+    # ``moe_renormalize``, times ``routed_scaling_factor``
     router_activation: str = "softmax"
     moe_renormalize: bool = False
     routed_scaling_factor: float = 1.0
@@ -109,12 +120,39 @@ class LMConfig:
         if self.router_activation not in ("softmax", "sigmoid"):
             raise ValueError("router_activation must be softmax|sigmoid, got "
                              "%r" % (self.router_activation,))
-        sigmoid_only = (self.moe_renormalize, self.routed_scaling_factor != 1.0,
-                        self.num_shared_experts, self.experts_held is not None)
-        if self.router_activation == "softmax" and any(sigmoid_only):
+        routed_only = (self.moe_renormalize, self.routed_scaling_factor != 1.0,
+                       self.num_shared_experts, self.experts_held is not None,
+                       self.router_aux_loss_coef, self.router_z_loss_coef,
+                       self.seq_aux)
+        if not self.num_experts and any(routed_only):
             raise ValueError(
-                "renormalised or scaled gates, shared experts and a share of "
-                "the experts come with router_activation='sigmoid'")
+                "renormalised or scaled gates, shared experts, a share of "
+                "the experts and the router losses belong to a routed "
+                "feed-forward: num_experts is 0")
+        if self.router_activation == "sigmoid" and (
+                self.router_aux_loss_coef or self.router_z_loss_coef
+                or self.seq_aux):
+            raise ValueError(
+                "the router losses (router_aux_loss_coef, router_z_loss_coef, "
+                "seq_aux) are those of a softmax router; a sigmoid router "
+                "has none here")
+        if self.seq_aux and self.router_z_loss_coef:
+            raise ValueError(
+                "seq_aux is DeepSeek-V2's balance loss, per sequence and "
+                "summed over the routed layers: it comes without a z-loss")
+        if self.rope_scaling is not None:
+            scaling = dict(self.rope_scaling)
+            if scaling.pop("type", None) != "yarn" \
+                    or set(scaling) != set(YARN_KEYS):
+                raise ValueError(
+                    "rope_scaling is None or {type: 'yarn'} with %s, got %r"
+                    % (", ".join(YARN_KEYS), self.rope_scaling))
+            if self.rope_theta is None or set(types or ("attention",)) \
+                    != {"mla"}:
+                raise ValueError(
+                    "rope_scaling (YaRN) blends the frequencies of latent "
+                    "attention's rotary features: it needs rope_theta and "
+                    "layer_types of 'mla' alone")
         held = self.experts_held
         if held is not None and (
                 not held or len(set(held)) != len(held)
@@ -172,6 +210,35 @@ class LMConfig:
                    routed_scaling_factor=2.446, num_shared_experts=1, **kw)
 
     @classmethod
+    def deepseek_v2_lite(cls, **kw):
+        """DeepSeek-V2-Lite as its ``config.json`` publishes it
+        (huggingface.co/deepseek-ai/DeepSeek-V2-Lite; arXiv 2405.04434): 27
+        pre-norm RMSNorm layers without a bias, EVERY one latent attention
+        (16 heads, no low-rank q, latent 512, 128 + 64 score features,
+        values of 128) whose 64 rotary features turn by YaRN's blended
+        frequencies (factor 40 over an original window of 4,096) under a
+        softmax scale of its own; a dense SwiGLU of 10,944 in layer 0, then
+        64 softmax-routed SwiGLU experts of 1,408, 6 a token, the gate the
+        probability itself, beside 2 shared experts; the balance loss per
+        sequence (``seq_aux``) times ``aux_loss_alpha`` 0.001, summed over
+        the routed layers; an untied head."""
+        n = kw.setdefault("num_layers", 27)
+        kw.setdefault("max_seq_len", 163840)
+        kw.setdefault("layer_types", ("mla",) * n)
+        kw.setdefault("rope_scaling", {
+            "type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 0.707, "mscale_all_dim": 0.707,
+            "original_max_position_embeddings": 4096})
+        return cls(vocab_size=102400, d_model=2048, num_heads=16,
+                   mlp_dim=1408, norm="rmsnorm", norm_eps=1e-6,
+                   rope_theta=10000.0, attention_bias=False, head_bias=False,
+                   embed_scale=False, kv_lora_rank=512, qk_nope_head_dim=128,
+                   qk_rope_head_dim=64, v_head_dim=128,
+                   first_k_dense_replace=1, dense_dim=10944, num_experts=64,
+                   experts_per_token=6, num_shared_experts=2,
+                   router_aux_loss_coef=0.001, seq_aux=True, **kw)
+
+    @classmethod
     def tiny(cls, **kw):
         return cls(vocab_size=128, d_model=32, num_layers=2, num_heads=2,
                    mlp_dim=64, max_seq_len=64, **kw)
@@ -214,15 +281,18 @@ class TransformerLM(nn.Module):
             kw["kda"] = KDAConfig(cfg.kda_num_heads, cfg.kda_head_dim,
                                   cfg.kda_conv_size)
         elif kind == "mla":
+            yarn = cfg.rope_scaling and YarnConfig(
+                **{k: cfg.rope_scaling[k] for k in YARN_KEYS})
             kw["mla"] = MLAConfig(cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                                   cfg.qk_rope_head_dim, cfg.v_head_dim,
-                                  cfg.rope_theta)
+                                  cfg.rope_theta, yarn)
         if i < cfg.first_k_dense_replace:
             kw["dense_dim"] = cfg.dense_dim
-        if cfg.router_activation == "sigmoid":
+        elif cfg.num_experts:
             kw["router"] = RouterConfig(
-                cfg.moe_renormalize, cfg.routed_scaling_factor,
-                cfg.num_shared_experts, cfg.experts_held)
+                cfg.router_activation, cfg.moe_renormalize,
+                cfg.routed_scaling_factor, cfg.num_shared_experts,
+                cfg.experts_held, cfg.seq_aux)
         # (what the delta rule's backward needs of its forward is kept by
         # name, its output and the kernels' per-chunk states: a recomputed
         # block would run the core a second time)
@@ -410,9 +480,16 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     (GPT-2 style blocks) and ``LMConfig.olmoe_1b_7b()`` (RMSNorm, QK-norm,
     RoPE, dropless top-8-of-64 SwiGLU experts) go through the same
     ``TransformerLM``, the same loss and the same lean-head rule. A
-    routed config adds its router losses to the mean NLL:
+    softmax-routed config adds its router losses to the mean NLL, with
+    all its experts or a share of them held:
     ``router_aux_loss_coef * L_lb + router_z_loss_coef * L_z``, each taken
-    per layer over the batch this loss sees and averaged over layers.
+    per layer over the batch this loss sees and averaged over layers
+    (OLMoE), or under ``seq_aux`` ``router_aux_loss_coef * sum L_l``, each
+    routed layer's balance loss taken per sequence and the layers SUMMED
+    (``LMConfig.deepseek_v2_lite()``: latent attention with YaRN-scaled
+    rotary keys in every layer, a leading dense layer, ``experts_held`` of
+    64 softmax-routed experts beside two shared ones; the sum also leaves
+    the step as the device counter ``moe.aux_loss``).
     ``LMConfig.kimi_linear_48b_a3b()`` (KDA and latent-attention layers
     by ``layer_types``, a leading dense layer, sigmoid-routed experts of
     which ``experts_held`` are here, a shared expert) takes the same
@@ -481,6 +558,9 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
             return out, None
         per_layer = sown["losses"].values()
         lb = sum(layer["moe"]["router_lb"][0] for layer in per_layer)
+        if cfg.seq_aux:
+            device_counters.add("moe.aux_loss", lb)
+            return out, cfg.router_aux_loss_coef * lb
         z = sum(layer["moe"]["router_z"][0] for layer in per_layer)
         return out, (cfg.router_aux_loss_coef * lb
                      + cfg.router_z_loss_coef * z) / cfg.num_layers
@@ -515,7 +595,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         return mean_loss(nll, router_loss)
 
     if routed:
-        loss_fn.device_counters = tuple("moe." + n for n in router_load)
+        loss_fn.device_counters = tuple("moe." + n for n in router_load) + (
+            ("moe.aux_loss",) if cfg.seq_aux else ())
 
     npr = np.random.RandomState(seed)
     example_batch = {"tokens": npr.randint(

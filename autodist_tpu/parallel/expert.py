@@ -17,10 +17,14 @@ runs when:
   ([T·k, d]) whatever the routing; the data-dependent part is two row
   gathers by a permutation, whose backward passes are the inverse
   permutation's gathers.
-  A layer that holds a SHARE of its experts (``SigmoidRouting.held``: one
-  chip's of an expert-parallel deployment, run without the exchange) routes
-  over all of them and runs every held expert on every token under its
-  gate (:func:`_held_experts`): constant work whatever the router does.
+  How the router scores, chooses and gates is a :class:`Routing` (softmax
+  or sigmoid scores, renormalised and scaled gates, a choice-only bias);
+  WHICH experts the layer holds is apart from it (``held``). A layer that
+  holds a SHARE of its experts (one chip's of an expert-parallel
+  deployment, run without the exchange) routes over all of them, takes its
+  router losses over all of them, and runs every held expert on every
+  token under its gate (:func:`_held_experts`): constant work whatever the
+  router does.
 - :func:`moe_ffn` — **top-1 with a fixed per-expert capacity** (Switch,
   arXiv 2101.03961; GShard, arXiv 2006.16668), the path for a BOUND
   ``expert`` axis: tokens reach their expert's owning device with one
@@ -41,6 +45,7 @@ other fusions change around it), and with plain gathers, whose backward
 passes are [65536, 2048] scatters, the routing alone took 23.2 ms
 against 13.0 (my chip runs, PR 25; PERF.md section 6).
 """
+import functools
 import math
 from typing import NamedTuple, Optional, Tuple
 
@@ -240,53 +245,87 @@ def router_losses(logits, probs, counts):
     return lb, z
 
 
-class SigmoidRouting(NamedTuple):
-    """A router that scores every expert by a sigmoid, chooses the top k
-    of ``score + bias`` (one group: plain top-k), and gates by the chosen
-    scores, renormalised to sum to one where ``renormalize`` and times
-    ``scaling_factor``. ``held``: the experts this layer holds, in the
-    order of its weight stacks (None = all, and the sorted dropless form;
-    a share runs every held expert on every token: ``_held_experts``)."""
-    bias: jax.Array
-    renormalize: bool
-    scaling_factor: float
-    held: Optional[Tuple[int, ...]]
+def sequence_balance_loss(probs, expert, sequences: int):
+    """DeepSeek-V2's ``seq_aux`` balance loss of one layer (arXiv
+    2405.04434 eqs. 12-14; the released ``MoEGate``): per sequence b of S
+    tokens ``f_be = E / (k S) * #{(t, j): token t of b chose e}`` and
+    ``P_be = mean_t probs_te``, ``L = mean_b sum_e f_be P_be`` (1 under an
+    even router). ``probs`` [T, E] over ALL the router's outputs, ``expert``
+    [T, k] the chosen ones, the T = sequences x S rows in sequence order;
+    ``f`` is a count and carries no gradient."""
+    T, E = probs.shape
+    k, S = expert.shape[-1], T // sequences
+    chose = (expert.reshape(sequences, S * k, 1)
+             == jnp.arange(E)[None, None, :])                    # [B, S k, E]
+    frac = jnp.sum(chose, axis=1, dtype=jnp.float32) * (E / (k * S))
+    mean_prob = jnp.mean(probs.reshape(sequences, S, E), axis=1)
+    return jnp.mean(jnp.sum(frac * mean_prob, axis=-1))
+
+
+class Routing(NamedTuple):
+    """How a router scores, chooses and gates: a score per expert
+    (``activation``: the "softmax" over all experts or a "sigmoid" each),
+    the top k of ``score + bias`` chosen (one group: plain top-k; the bias
+    only chooses), the gates the chosen scores themselves, renormalised to
+    sum to one where ``renormalize``, times ``scaling_factor``. The
+    defaults are OLMoE's and DeepSeek-V2's gate (the softmax probability,
+    ``norm_topk_prob`` false); Kimi-Linear's is a sigmoid with a bias,
+    renormalised and scaled. Which experts a layer HOLDS is not the
+    router's business (``dropless_moe_ffn``'s ``held``)."""
+    activation: str = "softmax"
+    renormalize: bool = False
+    scaling_factor: float = 1.0
+    bias: Optional[jax.Array] = None
 
     def choose(self, logits, top_k):
-        scores = jax.nn.sigmoid(logits)
-        _, expert = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(self.bias), top_k)
-        gate = jnp.take_along_axis(scores, expert, axis=-1)
+        """(scores [T, E], gate [T, k], expert [T, k]) of float32 logits."""
+        scores = (jax.nn.softmax(logits, axis=-1)
+                  if self.activation == "softmax" else jax.nn.sigmoid(logits))
+        if self.bias is None:
+            gate, expert = jax.lax.top_k(scores, top_k)
+        else:
+            _, expert = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(self.bias), top_k)
+            gate = jnp.take_along_axis(scores, expert, axis=-1)
         if self.renormalize:
             gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
-        return gate * self.scaling_factor, expert
+        if self.scaling_factor != 1.0:
+            gate = gate * self.scaling_factor
+        return scores, gate, expert
 
 
 def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
-                     dtype=None, routing: Optional[SigmoidRouting] = None):
+                     dtype=None, routing: Routing = Routing(),
+                     held: Optional[Tuple[int, ...]] = None,
+                     seq_aux: bool = False):
     """Token-choice top-k SwiGLU MoE with no token dropped. Returns
     (output with x's shape, load-balance loss, z-loss, routed pairs per
     expert of the stacks [E] int32: the layer's load).
 
-    - ``x``: [..., d] activations; flattened to T tokens internally.
-    - ``router_w``: [d, E]; logits and softmax in float32 over all E; the
-      gate is the softmax probability itself (no renormalisation over the
-      chosen k: HF ``norm_topk_prob`` false).
+    - ``x``: [..., S, d] activations; flattened to T tokens internally.
+    - ``router_w``: [d, E_all]; logits and scores in float32 over all
+      E_all; ``routing`` says how they become k gates a token (by default
+      the softmax probability itself, no renormalisation over the chosen
+      k: HF ``norm_topk_prob`` false).
     - ``w_gate``/``w_up``: [E, d, f], ``w_down``: [E, f, d]; computed in
       ``dtype`` with float32 accumulation of the per-token combine:
       ``sum_j g_j * down_ej(silu(gate_ej(x)) * up_ej(x))``.
-    - ``routing``: a :class:`SigmoidRouting` scores and gates in its own
-      way and has no router loss (both returned as 0). Where it names the
-      experts ``held`` (the stacks' E of the router's E_all, one chip's
-      share under expert parallelism), the router still scores and
-      normalises over all E_all and the output is the part the held
-      experts give (:func:`_held_experts`); what absent experts would add
-      is left out. Nothing stands in for the chips that hold them.
+    - ``held``: the experts of the router's E_all whose weights the stacks
+      hold, in the stacks' order (one chip's share under expert
+      parallelism); None = all, in the sorted form. With a share the
+      router still scores, normalises and takes its losses over all E_all
+      and the output is the part the held experts give
+      (:func:`_held_experts`); what absent experts would add is left out.
+      Nothing stands in for the chips that hold them.
+    - the router losses, of a softmax router only (a sigmoid router's are
+      0): OLMoE's pair over all the tokens this call sees
+      (:func:`router_losses`), or with ``seq_aux`` DeepSeek-V2's balance
+      loss per sequence of x's second-to-last axis
+      (:func:`sequence_balance_loss`) and no z-loss.
     """
     dt = dtype or x.dtype
     d = x.shape[-1]
     tokens = x.reshape(-1, d)
-    T, E = tokens.shape[0], w_gate.shape[0]
     with scopes.scope(scopes.MOE):
         with scopes.scope(scopes.MOE_ROUTE):
             # true float32 (a TPU's default would round to bf16 passes):
@@ -294,41 +333,58 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
             logits = jnp.dot(tokens.astype(jnp.float32),
                              router_w.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            if routing is None:
-                probs = jax.nn.softmax(logits, axis=-1)
-                gate, expert = jax.lax.top_k(probs, top_k)       # [T, k]
-            else:
-                gate, expert = routing.choose(logits, top_k)
-        if routing is not None and routing.held is not None:
-            out, counts = _held_experts(tokens.astype(dt), gate, expert,
-                                        routing.held, w_gate, w_up, w_down)
-            return (out.astype(dt).reshape(x.shape), jnp.float32(0.0),
-                    jnp.float32(0.0), counts)
-        with scopes.scope(scopes.MOE_ROUTE):
-            pair_expert = expert.reshape(-1)                     # [T*k]
-            order = jnp.argsort(pair_expert, stable=True)        # by expert
-            inv_order = jnp.argsort(order)
-            # pairs per expert by comparison, not bincount's scatter-add
-            counts = jnp.sum(pair_expert[:, None] == jnp.arange(E)[None, :],
-                             axis=0, dtype=jnp.int32)
-            pairs = jnp.repeat(tokens.astype(dt), top_k, axis=0)  # [T*k, d]
-            xs = _permute_rows(pairs, order, inv_order)
-        with scopes.scope(scopes.MOE_EXPERTS):
-            h = (jax.nn.silu(grouped_matmul(xs, w_gate.astype(dt), counts))
-                 * grouped_matmul(xs, w_up.astype(dt), counts))
-            ys = grouped_matmul(h, w_down.astype(dt), counts)    # [T*k, d]
-        with scopes.scope(scopes.MOE_ROUTE):
-            ys = _permute_rows(ys, inv_order, order)             # token order
-            out = jnp.sum(ys.reshape(T, top_k, d).astype(jnp.float32)
-                          * gate[:, :, None], axis=1)
-        if routing is None:
-            lb, z = router_losses(logits, probs, counts)
-        else:
+            probs, gate, expert = routing.choose(logits, top_k)  # [T, k]
+        experts = _sorted_experts if held is None else functools.partial(
+            _held_experts, held=held)
+        out, counts = experts(tokens.astype(dt), gate, expert,
+                              w_gate, w_up, w_down)
+        if routing.activation != "softmax":
             lb = z = jnp.float32(0.0)
+        elif seq_aux:
+            with scopes.scope(scopes.MOE_ROUTE):
+                lb = sequence_balance_loss(probs, expert,
+                                           tokens.shape[0] // x.shape[-2])
+            z = jnp.float32(0.0)
+        else:   # over ALL the router's outputs, held here or not
+            lb, z = router_losses(
+                logits, probs, counts if held is None else _pairs_per_expert(
+                    expert.reshape(-1), router_w.shape[-1]))
     return out.astype(dt).reshape(x.shape), lb, z, counts
 
 
-def _held_experts(tokens, gate, expert, held, w_gate, w_up, w_down):
+def _sorted_experts(tokens, gate, expert, w_gate, w_up, w_down):
+    """All experts held: ([T, d] float32, pairs per expert [E]) by the
+    sorted form of the module docstring: the T k pairs sorted by expert,
+    one grouped matmul for each projection, the un-sort and the gated
+    sum."""
+    dt = tokens.dtype
+    (T, d), top_k, E = tokens.shape, expert.shape[-1], w_gate.shape[0]
+    with scopes.scope(scopes.MOE_ROUTE):
+        pair_expert = expert.reshape(-1)                     # [T*k]
+        order = jnp.argsort(pair_expert, stable=True)        # by expert
+        inv_order = jnp.argsort(order)
+        counts = _pairs_per_expert(pair_expert, E)
+        pairs = jnp.repeat(tokens, top_k, axis=0)            # [T*k, d]
+        xs = _permute_rows(pairs, order, inv_order)
+    with scopes.scope(scopes.MOE_EXPERTS):
+        h = (jax.nn.silu(grouped_matmul(xs, w_gate.astype(dt), counts))
+             * grouped_matmul(xs, w_up.astype(dt), counts))
+        ys = grouped_matmul(h, w_down.astype(dt), counts)    # [T*k, d]
+    with scopes.scope(scopes.MOE_ROUTE):
+        ys = _permute_rows(ys, inv_order, order)             # token order
+        out = jnp.sum(ys.reshape(T, top_k, d).astype(jnp.float32)
+                      * gate[:, :, None], axis=1)
+    return out, counts
+
+
+def _pairs_per_expert(pair_expert, n_experts):
+    """Routed pairs per expert [E] int32, by comparison and not
+    bincount's scatter-add."""
+    return jnp.sum(pair_expert[:, None] == jnp.arange(n_experts)[None, :],
+                   axis=0, dtype=jnp.int32)
+
+
+def _held_experts(tokens, gate, expert, w_gate, w_up, w_down, held):
     """The held experts' part of the routed sum, ([T, d] float32, pairs per
     held expert [E]): EVERY held expert on EVERY token, its hidden
     activations scaled by the token's gate for it (zero where the token
@@ -343,9 +399,12 @@ def _held_experts(tokens, gate, expert, held, w_gate, w_up, w_down):
     FOLLOWED THE ROUTING: with seeded weights under Adam every token of a
     sequence soon chooses the same experts, so a held expert got no token
     or all 8,192, and each such expert added 3.7 ms (0.5 %) to the step
-    (PERF.md section 6, PR 29). Here the work is T E rows (no more than
-    the T k the sorted form must provide for wherever E <= k), every row of
-    it real, and the same whatever the router does."""
+    (PERF.md section 6, PR 29). Here the work is T E rows, every row of it
+    real, and the same whatever the router does. Against the sorted form's
+    static T k rows that is no more wherever E <= k (Kimi-Linear's share:
+    8 and 8) and E / k of it otherwise: DeepSeek-V2-Lite's 8 held at k = 6
+    run 1.33 x T k rows, which is also 1.33 x what this chip's experts
+    receive in the 8-way deployment (8 chips' T k pairs over 8 chips)."""
     dt = tokens.dtype
     with scopes.scope(scopes.MOE_ROUTE):
         chose = expert[:, :, None] == jnp.asarray(held)[None, None, :]

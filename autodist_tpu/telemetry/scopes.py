@@ -59,6 +59,7 @@ MOE_SHARED = "moe_shared"
 KDA = "kda"
 KDA_SCAN = "kda_scan"
 MLA = "mla"
+MLA_CORE = "mla_core"
 PREFILL = "prefill"
 DECODE = "decode"
 INSERT = "insert"
@@ -98,6 +99,9 @@ SCOPES: Dict[str, str] = {
               "kernels kda_fwd / kda_bwd and the layout passes around them",
     MLA: "inside attention: a latent-attention mixer (q, the latent and its "
          "up-projection, the attention core, the output projection)",
+    MLA_CORE: "inside mla: the attention core alone, the call of the "
+              "attention function on q, k, v (the flash kernels flash_fwd / "
+              "flash_dq / flash_dkdv on a TPU, XLA's scores elsewhere)",
     PREFILL: "serving: the prompt pass of a prefill bucket",
     DECODE: "serving: one cached decode step",
     INSERT: "serving: writing admitted rows into the decode state",

@@ -248,8 +248,10 @@ def test_goodput_ignores_background_threads():
     """Async writer-thread time overlaps the wall; only the training
     thread's spans decompose it."""
     rec = tel.TraceRecorder(capacity=64, sample=1, pid=1, host="h")
+    t0 = time.perf_counter()
     with rec.span("runner.dispatch", "runner", step=0):
         time.sleep(0.002)
+    dispatch_s = time.perf_counter() - t0
 
     def background():
         with rec.span("ckpt.write", "ckpt"):
@@ -260,7 +262,10 @@ def test_goodput_ignores_background_threads():
     report = goodput.breakdown_from_events(
         goodput._normalize_recorder(rec))
     assert report.buckets["checkpoint"] == 0.0
-    assert report.wall_s < 0.009  # the 10ms background write is excluded
+    # the 10ms background write is excluded: the wall is the training
+    # thread's span alone (held against its own bracket, not a constant:
+    # on a loaded host a 2 ms sleep has taken 11)
+    assert report.wall_s <= dispatch_s + 1e-4
 
 
 def test_goodput_real_fit_coverage_within_two_percent(tmp_path):

@@ -406,12 +406,12 @@ def routed_layer(rng, tokens, d, f, n_all):
 def program_share(x, m, held, top_k=TOP_K, bias=None):
     """The routed part one chip computes: its stacks hold ``held`` only."""
     idx = jnp.asarray(held)
-    routing = expert.SigmoidRouting(
-        m["e_score_correction_bias"] if bias is None else bias, True,
-        ref.SCALING, tuple(held))
+    routing = expert.Routing(
+        "sigmoid", True, ref.SCALING,
+        m["e_score_correction_bias"] if bias is None else bias)
     return expert.dropless_moe_ffn(
         x, m["router"], m["gate_proj"][idx], m["up_proj"][idx],
-        m["down_proj"][idx], top_k, routing=routing)
+        m["down_proj"][idx], top_k, routing=routing, held=tuple(held))
 
 
 def test_all_shares_and_the_shared_expert_once_are_the_uncut_layer():
@@ -450,7 +450,7 @@ def test_gates_are_sigmoid_scores_renormalised_and_scaled_and_the_bias_only_choo
     logits = jnp.asarray(r.randn(6, 8), jnp.float32)
     # a bias that lifts expert 7 into every token's choice
     bias = jnp.asarray([0, 0, 0, 0, 0, 0, 0, 10.0], jnp.float32)
-    gate, chosen = expert.SigmoidRouting(bias, True, 2.446, None).choose(
+    _, gate, chosen = expert.Routing("sigmoid", True, 2.446, bias).choose(
         logits, 3)
     scores = 1 / (1 + np.exp(-np.asarray(logits)))
     by_hand = np.argsort(-(scores + np.asarray(bias)), axis=-1)[:, :3]
@@ -459,7 +459,7 @@ def test_gates_are_sigmoid_scores_renormalised_and_scaled_and_the_bias_only_choo
     picked = np.take_along_axis(scores, np.asarray(chosen), axis=-1)
     close(gate, picked / picked.sum(-1, keepdims=True) * 2.446)   # no bias in
     close(jnp.sum(gate, -1), np.full(6, 2.446))
-    plain, _ = expert.SigmoidRouting(bias, False, 1.0, None).choose(logits, 3)
+    _, plain, _ = expert.Routing("sigmoid", False, 1.0, bias).choose(logits, 3)
     close(plain, picked)
 
 

@@ -7,7 +7,9 @@ The tracing contracts (``telemetry/scopes.py``, docs/observability.md
   the table, tells forward from backward ops of the loss, and finds the
   lean head's ``custom_vjp`` backward rule under its own scope, with and
   without remat; a Kimi-Linear model's mixers (``kda`` > ``kda_scan``,
-  ``mla``, ``moe_shared``) are there too, with their recomputed ops;
+  ``mla`` > ``mla_core``, ``moe_shared``) are there too, with their
+  recomputed ops; a DeepSeek-V2 model's step has ``mla_core`` around its
+  scores alone and hands ``moe.aux_loss`` out beside the loss;
 - the map is computed ON DEMAND: a fit, traced or not, lowers and
   compiles nothing extra;
 - ``runner.readback`` is tiled by its two children (the wait for the
@@ -166,7 +168,8 @@ def kimi_linear_step_map():
 
 @pytest.mark.parametrize("scope, outer", [
     (scopes.KDA, scopes.ATTENTION), (scopes.KDA_SCAN, scopes.KDA),
-    (scopes.MLA, scopes.ATTENTION), (scopes.MOE_SHARED, scopes.MOE)])
+    (scopes.MLA, scopes.ATTENTION), (scopes.MLA_CORE, scopes.MLA),
+    (scopes.MOE_SHARED, scopes.MOE)])
 def test_a_mixers_scope_holds_its_forward_backward_and_recomputed_ops(
         kimi_linear_step_map, scope, outer):
     m = kimi_linear_step_map
@@ -195,6 +198,47 @@ def test_the_delta_rules_matmuls_are_under_its_scope(kimi_linear_step_map):
     assert [o for n in under(m, scopes.KDA) for o in m[n]
             if "dot_general" in o and scopes.KDA in components(o)
             and scopes.KDA_SCAN not in components(o)]
+
+
+def test_a_deepseek_v2_step_names_its_attention_cores_and_counts_its_balance_loss():
+    """``mla_core`` holds the scores' matmuls and the softmax and NOT the
+    mixer's projections, rotation or temperature; the step's metrics carry
+    the device counter ``moe.aux_loss`` (the routed layers' balance losses
+    summed: between 1 a layer, an even router, and E / k)."""
+    import dataclasses
+    cfg = dataclasses.replace(
+        lm.LMConfig.deepseek_v2_lite(num_layers=3, max_seq_len=32),
+        vocab_size=128, d_model=32, num_heads=2, mlp_dim=16, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, dense_dim=64,
+        num_experts=8, experts_per_token=2, experts_held=(0, 1))
+    loss_fn, params, batch, _ = lm.make_train_setup(
+        cfg, seq_len=16, batch_size=8)
+    assert "moe.aux_loss" in loss_fn.device_counters
+    autodist_tpu.reset()
+    try:
+        ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
+        runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
+        runner.init(params)
+        counters = runner.run(batch)["counters"]
+        m = telemetry.scope_map(STEP)
+    finally:
+        autodist_tpu.reset()
+    assert 2 * 1.0 <= float(counters["moe.aux_loss"]) <= 2 * 8 / 2
+    core = [o for ops in m.values() for o in ops
+            if scopes.MLA_CORE in components(o)]
+    assert core and all(
+        components(o).index(scopes.ATTENTION) < components(o).index(scopes.MLA)
+        < components(o).index(scopes.MLA_CORE) for o in core)
+    assert any("dot_general" in o for o in core)
+    assert under(m, scopes.MLA_CORE, in_pass="fwd")
+    assert under(m, scopes.MLA_CORE, in_pass="bwd")
+    # the projections and the rotation are the mixer's, not the core's
+    outside = [o for ops in m.values() for o in ops
+               if scopes.MLA in components(o)
+               and scopes.MLA_CORE not in components(o)]
+    assert any("dot_general" in o for o in outside)
+    assert any(o.endswith(("/cos", "/sin")) for o in outside)
+    assert not any(o.endswith(("/cos", "/sin")) for o in core)
 
 
 def test_partitioned_storage_gathers_under_the_params_scope():
