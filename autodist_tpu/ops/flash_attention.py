@@ -22,6 +22,21 @@ Design (standard FlashAttention-2 tiling, arXiv 2307.08691):
   DMA).
 - layout: the model zoo's [batch, seq, heads, head_dim], transposed to
   [batch, heads, seq, head_dim] around the kernels.
+- under a recomputing checkpoint: three of the backward kernels'
+  residuals carry the name ``KEPT``: the forward kernel's results ``out``
+  and ``lse``, and its operand ``q`` in the kernels' layout. A
+  ``jax.checkpoint`` / ``nn.remat`` whose policy saves that name
+  (``models/lm.py:TransformerLM._block``) keeps them, and its recomputed
+  forward then holds no ``flash_fwd`` (nothing reads its results, so it
+  is dead code) and nothing that only made q (its projection, rotation,
+  transpose). At [1, 16, 8192, .] bfloat16 that is 33.5 MB of ``out``,
+  0.5 MB of ``lse`` and 50 MB of ``q`` a layer against a second forward
+  kernel of 3.55 ms and 1.5 ms of q's making. ``k`` and ``v`` are NOT
+  kept: with them DeepSeek-V2-Lite's step asked for 3.75 GB of scratch
+  where q alone asks for 3.15 (PERF.md section 6, PR 32, has every
+  reading). With no policy, or one that does not list the name, the name
+  is an identity that lowers to nothing and the block recomputes as
+  before.
 - segment ids (BERT padding masks, packed sequences): attention is allowed
   iff ``q_seg[i] == kv_seg[j]``. Tiles whose q-segment range cannot
   intersect the kv-segment range are skipped dynamically (``pl.when`` on a
@@ -46,6 +61,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -54,6 +70,11 @@ from autodist_tpu.ops import pallas_mode
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp() NaN-free on masked rows
 _LANES = 128     # last-dim tile width; m/l scratch are lane-replicated
 _ROWS = 512      # rows of a q or kv tile
+# what a recomputed block keeps of the core: the forward kernel's output and
+# log-sum-exp, the two residuals of the backward kernels that only the
+# forward kernel can give, and q as the kernels read it
+# (``ops/kda.py:KEPT`` is the delta rule's)
+KEPT = "flash_core_kept"
 
 
 def _pick_block(seq: int, want: int) -> int:
@@ -424,7 +445,14 @@ def _flash(q, k, v, q_seg, kv_seg, causal):
 def _flash_fwd(q, k, v, q_seg, kv_seg, causal):
     qt, kt, vt = _heads_first(q), _heads_first(k), _heads_first(v)
     segs = None if q_seg is None else (q_seg, kv_seg)
+    qt = checkpoint_name(qt, KEPT)
     out, lse = _fwd(qt, kt, vt, segs, causal)
+    # (the log-sum-exp is named as [B, H, S]: as the kernels' [B, H, S, 1]
+    # a row is padded to 128 lanes in HBM, 67 MB a layer at 16 heads and
+    # seq 8192 where the values are 0.5; with the name on that form
+    # Kimi-Linear's step asked for 0.23 GB more scratch)
+    out = checkpoint_name(out, KEPT)
+    lse = checkpoint_name(lse[..., 0], KEPT)[..., None]
     return _heads_first(out), (qt, kt, vt, out, lse, q_seg, kv_seg)
 
 

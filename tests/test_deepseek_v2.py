@@ -26,7 +26,8 @@ from autodist_tpu.models import layers, lm
 from autodist_tpu.parallel import expert
 from benchmark.reference import deepseek_v2 as ref
 from benchmark.tools.loss_limit import patched
-from tests.test_kimi_linear import close, cpu_spec, flat
+from tests.test_kimi_linear import (check_recomputed_flash_blocks, close,
+                                    cpu_spec, flat)
 
 RTOL = 1e-5
 TOP_K = 3
@@ -481,3 +482,15 @@ def test_the_router_and_the_share_are_independent(what, make):
     nll = lm.make_train_setup(bare, seq_len=16, batch_size=2, seed=0)[0](
         params, batch)
     assert float(loss) > float(nll)
+
+
+# ------------------------------------------ per-block recompute, the kernel
+
+
+@pytest.mark.parametrize("against", ["blocks_not_recomputed", "unnamed"])
+def test_a_recomputed_block_runs_no_flash_forward_kernel(tiny, against,
+                                                         monkeypatch):
+    """Latent attention in all three layers."""
+    cfg, _, params, _, batch = tiny
+    check_recomputed_flash_blocks(cfg, params, batch, against, monkeypatch,
+                                  cfg.num_layers, RTOL)

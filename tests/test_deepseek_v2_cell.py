@@ -368,11 +368,12 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     """``deepseek_v2_lite_train_1chip``'s step as the benchmark builds it ON
     THE CHIP (``benchmark/tools/aot_compile_as_on_the_chip.py``), at the
     published widths and 1 x 8,192 tokens: the three flash kernels at
-    192 / 128 in each of six layers and the forward once more for each
-    recomputed block, no grouped-matmul kernel (a share of the experts
-    runs every held expert on every token), every block recomputed, and
-    state + scratch inside 16 GB with the 2.5 GB of initial parameters
-    ``ModelItem`` keeps beside them."""
+    192 / 128 ONCE in each of six layers (a recomputed block keeps the
+    forward kernel's output and log-sum-exp by name: until PR 32 it ran
+    the forward once more), no grouped-matmul kernel (a share of the
+    experts runs every held expert on every token), every block
+    recomputed, and state + scratch inside 16 GB with the 2.5 GB of
+    initial parameters ``ModelItem`` keeps beside them."""
     import sys
     import autodist_tpu
     path = list(sys.path)
@@ -391,19 +392,20 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     text = compiled[0].as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 6 * 4
-    for kernel, times in (("flash_fwd", 12), ("flash_dq", 6),
-                          ("flash_dkdv", 6)):
-        assert sum(kernel in line for line in calls) == times
+    assert len(calls) == 6 * 3
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkdv"):
+        assert sum(kernel in line for line in calls) == 6
     assert all("mla_core" in line for line in calls)
     assert "gmm" not in text and "rematted_computation" in text
     step = out["train_step"]
     total = CONFIG["parameters_as_built"]["total"]
     # float32 master weights and Adam's two moments: 12 B a parameter
     assert abs(step["argument_size_in_bytes"] - 12 * total) < 1 << 20
-    assert step["temp_size_in_bytes"] < 3.0e9    # 2.21 GB, PR 31
+    # 3.15 GB since PR 32 keeps the forward kernel's output and q in six
+    # layers (2.21 GB, PR 31); ISSUE 32's limit
+    assert step["temp_size_in_bytes"] < 3.5e9
     assert step["live_bytes_estimate"] + 4 * total < 15.0e9
-    record = bench_json("records", "pr31_aot_memory.json")["cells"][
-        "deepseek_v2_lite_train_1chip"]["train_step"]
+    record = bench_json("records", "pr32_aot_memory.json")["cells"][
+        "deepseek_v2_lite_train_1chip"]["change"]["train_step"]
     assert abs(record["temp_size_in_bytes"] - step["temp_size_in_bytes"]) \
         < 0.05 * step["temp_size_in_bytes"]

@@ -23,6 +23,16 @@ def _mask(s, causal):
     return jnp.tril(jnp.ones((s, s), jnp.bool_))[None, None] if causal else None
 
 
+def kernel_calls(jaxpr, name):
+    """How many ``pallas_call`` equations named ``name`` a jaxpr holds,
+    those of its inner jaxprs (a checkpoint's, a jit's) included."""
+    return sum(
+        (eqn.primitive.name == "pallas_call" and eqn.params["name"] == name)
+        + sum(kernel_calls(inner, name)
+              for inner in jax.core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("shape", [(1, 128, 2, 32), (2, 256, 4, 64)])
 def test_forward_matches_reference(causal, shape):
@@ -303,3 +313,42 @@ def test_seq_2048_heads_of_128_at_the_choosers_tiles(dtype, tol):
 def test_tiles_come_from_the_shapes(shape, tiles):
     assert fa._tiles(*shape) == tiles
     assert fa.full_tiles(shape[0]) == (tiles[0] == 512)
+
+
+# ------------------------------ what a recomputing checkpoint keeps by name
+
+
+@pytest.mark.parametrize("segments", [False, True],
+                         ids=["no_segments", "segment_ids"])
+def test_a_checkpoint_that_keeps_the_name_runs_the_forward_kernel_once(
+        segments):
+    """Latent attention's widths (scores over 192, values of 128), causal,
+    under ``jax.checkpoint``: with the policy that saves ``fa.KEPT`` the
+    gradient's jaxpr holds the forward kernel ONCE (its output and
+    log-sum-exp come from memory, so the recomputed one is dead code),
+    without a policy twice; the backward kernels once either way, and the
+    three gradients are the unwrapped function's to the last bit."""
+    q, k = (_rand((1, 128, 2, 192), seed=i) for i in range(2))
+    v, cot = (_rand((1, 128, 2, 128), seed=i) for i in (2, 3))
+    seg = jnp.asarray([[0] * 80 + [1] * 48], jnp.int32) if segments else None
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, seg) * cot)
+
+    keep = jax.checkpoint_policies.save_only_these_names(fa.KEPT)
+    grads = {"plain": jax.grad(f, (0, 1, 2)),
+             "kept": jax.grad(jax.checkpoint(f, policy=keep), (0, 1, 2)),
+             "recomputed": jax.grad(jax.checkpoint(f), (0, 1, 2))}
+    calls = {how: {kernel: kernel_calls(
+        jax.make_jaxpr(g)(q, k, v).jaxpr, kernel)
+        for kernel in ("flash_fwd", "flash_dq", "flash_dkdv")}
+        for how, g in grads.items()}
+    assert calls == {
+        "plain": {"flash_fwd": 1, "flash_dq": 1, "flash_dkdv": 1},
+        "kept": {"flash_fwd": 1, "flash_dq": 1, "flash_dkdv": 1},
+        "recomputed": {"flash_fwd": 2, "flash_dq": 1, "flash_dkdv": 1}}
+    want = jax.jit(grads["plain"])(q, k, v)
+    assert all(float(jnp.max(jnp.abs(g))) > 0.1 for g in want)
+    for how in ("kept", "recomputed"):
+        for got, ref in zip(jax.jit(grads[how])(q, k, v), want):
+            np.testing.assert_array_equal(got, ref)

@@ -596,6 +596,63 @@ def test_recomputed_blocks_give_the_same_loss_and_gradients(tiny):
     assert remats[1] - remats[0] == 5   # one per block, beside the op's own
 
 
+def check_recomputed_flash_blocks(cfg, params, batch, against, monkeypatch,
+                                 flash_layers, rtol):
+    """``cfg``'s model with every block recomputed and the latent layers'
+    cores on the flash kernel: its gradient holds one ``flash_fwd`` a
+    flash layer, the forward pass's. Against the same model without
+    recomputation ("blocks_not_recomputed") loss and gradients agree to
+    ``rtol``; against the kernel's residuals left without their name, as
+    before the name existed ("unnamed"), the recomputed blocks run the
+    kernel again and loss and every gradient leaf are equal to the last
+    bit (what is kept is what the second kernel call would have written)."""
+    from autodist_tpu.ops import flash_attention as fa
+    from tests.test_flash_attention import kernel_calls
+    ids, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+    attn_fn = fa.make_flash_attn_fn(causal=True)
+
+    def run(remat_blocks):
+        model = lm.TransformerLM(cfg, attn_fn=attn_fn,
+                                 remat_blocks=remat_blocks)
+
+        def loss(p):
+            logits = model.apply(p, ids, mutable=["counters"])[0]
+            return -jnp.mean(jnp.take_along_axis(
+                jax.nn.log_softmax(logits), targets[..., None], axis=-1))
+
+        with jax.default_matmul_precision("highest"):
+            traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+            value, grads = traced.lower().compile()(params)
+        return value, flat(grads), kernel_calls(traced.jaxpr.jaxpr,
+                                                "flash_fwd")
+
+    got, got_g, calls = run(True)
+    unnamed = against == "unnamed"
+    if unnamed:
+        monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    want, want_g, want_calls = run(unnamed)
+    assert calls == flash_layers
+    assert want_calls == (2 * flash_layers if unnamed else flash_layers)
+    if unnamed:
+        assert got == want
+    else:
+        close(got, want)
+    for name in got_g:
+        if unnamed:
+            np.testing.assert_array_equal(got_g[name], want_g[name])
+        else:
+            close(got_g[name], want_g[name], rtol)
+
+
+@pytest.mark.parametrize("against", ["blocks_not_recomputed", "unnamed"])
+def test_a_recomputed_block_runs_no_flash_forward_kernel(tiny, against,
+                                                         monkeypatch):
+    """One latent layer of five."""
+    cfg, _, params, _, batch = tiny
+    check_recomputed_flash_blocks(cfg, params, batch, against, monkeypatch,
+                                  cfg.layer_types.count("mla"), DEEP_RTOL)
+
+
 def test_serving_refuses_layers_whose_state_it_cannot_cache(tiny):
     cfg, _, params, _, batch = tiny
     with pytest.raises(NotImplementedError, match="recurrent state"):

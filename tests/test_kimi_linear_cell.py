@@ -299,9 +299,9 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     CHIP (``benchmark/tools/aot_compile_as_on_the_chip.py``: the backend
     probe answers "tpu", the chip's memory is the v5e's, the kernels lower
     as Mosaic calls), at the published widths and 1 x 8,192 tokens: the
-    three flash kernels at 192 / 128 (the forward once more for the
-    recomputed block), the delta rule's two kernels ONCE per KDA layer (a
-    recomputed block keeps their output and per-chunk states by name), no
+    three flash kernels at 192 / 128 and the delta rule's two kernels per
+    KDA layer, each ONCE (a recomputed block keeps by name what their
+    backward kernels read of the forward kernels' results), no
     triangular solve and no loop of the core left to XLA, every held
     expert on every token in four routed layers, every block recomputed,
     and state + scratch inside 16 GB with the 2.4 GB of initial parameters
@@ -323,12 +323,13 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     text = compiled[0].as_text()
     assert all(name in text for name in ("flash_fwd", "flash_dq",
                                          "flash_dkdv"))
-    # the three flash kernels and the recomputed forward, four KDA layers'
-    # forward and backward kernels; a share of the experts runs no
-    # grouped-matmul kernel (expert.py:_held_experts)
+    # the three flash kernels, four KDA layers' forward and backward
+    # kernels; a share of the experts runs no grouped-matmul kernel
+    # (expert.py:_held_experts)
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 4 + 4 + 4
+    assert len(calls) == 3 + 4 + 4
+    assert sum("flash_fwd" in line for line in calls) == 1
     assert sum("kda_fwd" in line for line in calls) == 4
     assert sum("kda_bwd" in line for line in calls) == 4
     assert all("kda_scan" in line for line in calls if "kda_" in line)
@@ -340,5 +341,7 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     step = out["train_step"]
     # float32 master weights and Adam's two moments: 12 B a parameter
     assert abs(step["argument_size_in_bytes"] - 12 * 602434432) < 1 << 20
-    assert step["temp_size_in_bytes"] < 5.6e9   # 4.85 GB, PR 30 (4.58, PR 29)
+    # 4.65 GB with the flash kernel's output and q kept (PR 32; 4.47, PR 30;
+    # 4.58, PR 29); ISSUE 32's limit
+    assert step["temp_size_in_bytes"] < 5.0e9
     assert step["live_bytes_estimate"] + 4 * 602434432 < 15.2e9

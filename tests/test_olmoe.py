@@ -14,6 +14,7 @@ import dataclasses
 import functools
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -159,6 +160,35 @@ def test_flash_attention_gives_the_default_paths_gradient(flash_and_default,
     same mathematics as the default path, every leaf to the file's 1e-5."""
     close(flash_and_default["flash"][1]["params/" + leaf],
           flash_and_default["default"][1]["params/" + leaf])
+
+
+def test_without_recomputed_blocks_the_kept_name_lowers_to_nothing(
+        tiny, monkeypatch):
+    """``olmoe_train_1chip`` runs the flash kernel and recomputes no block:
+    the names on the kernel's q, output and log-sum-exp
+    (``ops/flash_attention.py:KEPT``) are three ``name`` equations a layer
+    in the jaxpr of loss and gradient and nothing in the program lowered
+    from it: the StableHLO text is the one traced with no name at all."""
+    from autodist_tpu.ops import flash_attention as fa
+    cfg, _, params, _, batch = tiny
+
+    def traced():
+        jax.clear_caches()      # (the kernel's forward rule is traced once)
+        step = jax.jit(jax.value_and_grad(lm.make_train_setup(
+            cfg, seq_len=SEQ, batch_size=8, seed=0, attention="flash")[0])
+        ).trace(params, batch)
+        # (a private function's symbol ends in a running number that the
+        # jaxpr's equations shift: @argsort_98 / @argsort_97)
+        return str(step.jaxpr), re.sub(r"(@\w+?)_\d+\b", r"\1",
+                                       step.lower().as_text())
+
+    jaxpr, lowered = traced()
+    assert jaxpr.count("name[name=%s]" % fa.KEPT) == 3 * cfg.num_layers
+    assert "remat" not in jaxpr and "checkpoint" not in jaxpr
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    unnamed, lowered_unnamed = traced()
+    assert fa.KEPT not in unnamed
+    assert lowered_unnamed == lowered
 
 
 # the three cells' attention shapes (seq, head width) and the side of the
