@@ -28,6 +28,7 @@ from autodist_tpu.parallel import mesh as mesh_lib
 from autodist_tpu.resource_spec import ResourceSpec
 from autodist_tpu.runtime.runner import Runner, WrappedSession
 from autodist_tpu.strategy.base import Strategy, StrategyCompiler
+from autodist_tpu.telemetry import spans as tel
 from autodist_tpu.utils import logging
 
 _DEFAULT_AUTODIST = {}
@@ -311,17 +312,19 @@ class AutoDist:
             # chief-launched flow: workers were launched (and the runtime
             # joined) at construction; now that the strategy exists on
             # disk, ship it — the workers are waiting in their poll
-            self._coordinator.distribute_strategy()
+            with tel.span("setup.launch", tel.SETUP_CAT):
+                self._coordinator.distribute_strategy()
             return
         if (self._resource_spec.is_single_node() or not const.is_chief()
                 or const.ENV.ADT_EXTERNAL_LAUNCH.val):
             return
         from autodist_tpu.runtime.coordinator import Coordinator
         from autodist_tpu.runtime.cluster import SSHCluster
-        cluster = SSHCluster(self._resource_spec)
-        self._coordinator = Coordinator(strategy, cluster)
-        cluster.start()
-        self._coordinator.launch_clients()
+        with tel.span("setup.launch", tel.SETUP_CAT):
+            cluster = SSHCluster(self._resource_spec)
+            self._coordinator = Coordinator(strategy, cluster)
+            cluster.start()
+            self._coordinator.launch_clients()
 
     def build(self, loss_fn: Callable, optimizer, params, example_batch,
               has_aux: bool = False, apply_fn: Optional[Callable] = None,
@@ -336,18 +339,37 @@ class AutoDist:
         ``None`` defers to ``ADT_SENTINEL``, ``True`` uses the default
         :class:`~autodist_tpu.runtime.sentinel.SentinelPolicy`, a policy
         instance is used as-is — health guards are then compiled INTO
-        the step program (docs/sentinel.md)."""
+        the step program (docs/sentinel.md).
+
+        With tracing on, the build is the ``setup.build`` span and its
+        phases ``setup.capture`` / ``.strategy`` / ``.compile_strategy`` /
+        ``.launch`` / ``.mesh`` / ``.transform``, the first entries of the
+        set-up account (docs/observability.md, "Set-up")."""
+        with tel.span(tel.SETUP_ROOT, tel.SETUP_CAT):
+            return self._build(
+                sentinel, loss_fn=loss_fn, optimizer=optimizer,
+                params=params, example_batch=example_batch, has_aux=has_aux,
+                apply_fn=apply_fn, trainable_filter=trainable_filter,
+                mp_rules=mp_rules, mp_meta=mp_meta)
+
+    def _capture_and_plan(self, policy, **item_kw):
+        """The phases ``build`` and ``build_step`` share: capture the
+        model, build (or load) and verify the strategy, compile it."""
+        with tel.span("setup.capture", tel.SETUP_CAT):
+            item = ModelItem(**item_kw).prepare()
+        with tel.span("setup.strategy", tel.SETUP_CAT):
+            strategy = self._build_or_load_strategy(item)
+            self._verify_strategy(strategy, item, sentinel_policy=policy)
+        with tel.span("setup.compile_strategy", tel.SETUP_CAT):
+            compiled = StrategyCompiler(
+                item, self._resource_spec).compile(strategy)
+        return item, compiled
+
+    def _build(self, sentinel, **item_kw) -> Runner:
         from autodist_tpu.runtime.sentinel import resolve_policy
         self._check_live_device()
         policy = resolve_policy(sentinel)
-        item = ModelItem(loss_fn=loss_fn, optimizer=optimizer, params=params,
-                         example_batch=example_batch, has_aux=has_aux,
-                         apply_fn=apply_fn,
-                         trainable_filter=trainable_filter,
-                         mp_rules=mp_rules, mp_meta=mp_meta).prepare()
-        strategy = self._build_or_load_strategy(item)
-        self._verify_strategy(strategy, item, sentinel_policy=policy)
-        compiled = StrategyCompiler(item, self._resource_spec).compile(strategy)
+        item, compiled = self._capture_and_plan(policy, **item_kw)
         logging.info("compiled %r", compiled)
         logging.debug("compiled strategy:\n%s", compiled)
         # pipeline knobs are baked into the loss at model-build time; a
@@ -409,17 +431,20 @@ class AutoDist:
                 "ADT_ELASTIC_SYNC is set but the strategy is async PS: "
                 "unset it — async elastic restarts workers individually "
                 "and must not pin the process set with jax.distributed")
-        if is_async:
-            # async PS cannot ride global collectives (they are lockstep):
-            # each process runs its OWN local mesh — the reference's
-            # between-graph replication — and couples to peers only through
-            # the parameter service (runtime/ps_service.py)
-            mesh = mesh_lib.local_mesh(backend=self._backend)
-        else:
-            mesh = mesh_lib.mesh_from_strategy(compiled, self._resource_spec,
-                                               backend=self._backend)
-        dstep = GraphTransformer(compiled, mesh, item,
-                                 sentinel=policy).transform()
+        with tel.span("setup.mesh", tel.SETUP_CAT):
+            if is_async:
+                # async PS cannot ride global collectives (they are
+                # lockstep): each process runs its OWN local mesh — the
+                # reference's between-graph replication — and couples to
+                # peers only through the parameter service
+                # (runtime/ps_service.py)
+                mesh = mesh_lib.local_mesh(backend=self._backend)
+            else:
+                mesh = mesh_lib.mesh_from_strategy(
+                    compiled, self._resource_spec, backend=self._backend)
+        with tel.span("setup.transform", tel.SETUP_CAT):
+            dstep = GraphTransformer(compiled, mesh, item,
+                                     sentinel=policy).transform()
         if is_async and dstep.ps_store is not None:
             self._wire_async_ps(dstep)
         # in-run elastic (runtime/elastic.py): install the epoch-fenced
@@ -571,23 +596,26 @@ class AutoDist:
         like); the framework never looks inside the step. A ``sentinel``
         policy degrades to host-side loss monitoring here (the opaque
         step hides its gradients — ADT420)."""
+        with tel.span(tel.SETUP_ROOT, tel.SETUP_CAT, step_fn=True):
+            return self._build_step(sentinel, step_fn=step_fn, params=state,
+                                    example_batch=example_batch)
+
+    def _build_step(self, sentinel, **item_kw) -> Runner:
         from autodist_tpu.runtime.sentinel import resolve_policy
         self._check_live_device()
         policy = resolve_policy(sentinel)
-        item = ModelItem(step_fn=step_fn, params=state,
-                         example_batch=example_batch).prepare()
-        strategy = self._build_or_load_strategy(item)
-        self._verify_strategy(strategy, item, sentinel_policy=policy)
-        compiled = StrategyCompiler(item, self._resource_spec).compile(strategy)
+        item, compiled = self._capture_and_plan(policy, **item_kw)
         logging.info("compiled %r (step_fn mode)", compiled)
         if self._validate_async(compiled, item):
             raise ValueError("async host-PS strategies cannot lower an "
                              "opaque step_fn — use loss_fn mode")
         self._setup(compiled)
-        mesh = mesh_lib.mesh_from_strategy(compiled, self._resource_spec,
-                                           backend=self._backend)
-        dstep = GraphTransformer(compiled, mesh, item,
-                                 sentinel=policy).transform()
+        with tel.span("setup.mesh", tel.SETUP_CAT):
+            mesh = mesh_lib.mesh_from_strategy(
+                compiled, self._resource_spec, backend=self._backend)
+        with tel.span("setup.transform", tel.SETUP_CAT):
+            dstep = GraphTransformer(compiled, mesh, item,
+                                     sentinel=policy).transform()
         self._runner = Runner(
             dstep, tracing=self._tracing,
             hbm_budget_bytes=self._resource_spec.chip_hbm_bytes(),

@@ -337,7 +337,14 @@ class Runner:
         restart, or by the user for at-most-once resume), a committed
         checkpoint in ``ADT_CKPT_DIR`` is restored over the fresh init —
         every process calls init(), so the restore's collective placement
-        runs everywhere."""
+        runs everywhere.
+
+        With tracing on this is the ``setup.init`` span of the set-up
+        account, holding ``setup.init_state`` or ``setup.restore``."""
+        with tel.span("setup.init", tel.SETUP_CAT):
+            return self._init(params, opt_state)
+
+    def _init(self, params, opt_state) -> TrainState:
         m = self._membership
         if m is not None and getattr(m, "joined_late", False):
             # grow-on-join: this worker was admitted into a RUNNING job —
@@ -372,7 +379,8 @@ class Runner:
                 # init_state first would materialize the whole tree on
                 # device just to throw it away
                 try:
-                    _, step = saver.restore(self)
+                    with tel.span("setup.restore", tel.SETUP_CAT):
+                        _, step = saver.restore(self)
                 except FileNotFoundError as e:
                     # every candidate was skipped as torn/corrupt
                     if const.ENV.ADT_NUM_PROCESSES.val > 1:
@@ -405,7 +413,8 @@ class Runner:
                 logging.warning("ADT_AUTO_RESUME set but no valid "
                                 "checkpoint in %s; starting fresh",
                                 const.ENV.ADT_CKPT_DIR.val)
-        self.state = self._dstep.init_state(params, opt_state)
+        with tel.span("setup.init_state", tel.SETUP_CAT):
+            self.state = self._dstep.init_state(params, opt_state)
         self.notify_state_restored()  # fresh init resets the LR scale
         return self.state
 
@@ -428,7 +437,10 @@ class Runner:
             self._trace_started = True
 
     def _stop_trace_if_due(self, metrics):
-        if self._tracing and self._trace_started:
+        # not inside the first step: ``_first_step`` stops the trace after
+        # its span has ended (an annotation still open at the stop is lost)
+        if self._tracing and self._trace_started \
+                and self._first_step_s is not None:
             jax.block_until_ready(metrics)
             jax.profiler.stop_trace()
             self._trace_started = False
@@ -818,16 +830,19 @@ class Runner:
                                            self._staleness)
         self._maybe_check_mirrors()
 
-    def _record_step_time(self, t_begin: float):
-        elapsed = time.perf_counter() - t_begin
-        self._total_step_s += elapsed
-        if self._first_step_s is None:
-            self._first_step_s = elapsed  # includes trace + XLA compile
-        else:
-            self._recent_step_s.append(elapsed)
-            if len(self._recent_step_s) > self._RECENT_WINDOW:
-                del self._recent_step_s[:len(self._recent_step_s) // 2]
-            self._observe_straggler(elapsed)
+    def _record_step_time(self, t_begin: float, step: int):
+        with tel.span("runner.step_time", "runner", step=step):
+            elapsed = time.perf_counter() - t_begin
+            self._total_step_s += elapsed
+            if self._first_step_s is None:
+                # includes trace + XLA compile (``_first_step`` puts the
+                # setup.first_step span's duration here when tracing)
+                self._first_step_s = elapsed
+            else:
+                self._recent_step_s.append(elapsed)
+                if len(self._recent_step_s) > self._RECENT_WINDOW:
+                    del self._recent_step_s[:len(self._recent_step_s) // 2]
+                self._observe_straggler(elapsed)
 
     def _observe_straggler(self, elapsed: float):
         """Online slow-but-alive detection: sustained EWMA z-score
@@ -891,10 +906,37 @@ class Runner:
         never re-enters the host between steps; wall-time samples then
         measure dispatch-to-dispatch, not execution (the next forced
         readback re-syncs the clock)."""
+        if self._first_step_s is None:
+            return self._first_step(self._run, batch, state, sync)
+        return self._run(batch, state, sync)
+
+    def _first_step(self, run, *args):
+        """The first dispatch, which traces, lowers and compiles the step
+        program (or loads it from the compile cache), up to what its caller
+        gets back: ``setup.first_step``, the last phase of the set-up
+        account. With tracing on ``first_step_s`` is that span's own
+        duration: one pair of clock readings serves both. A first-step
+        profile (``tracing=True``) starts before the span and stops after
+        it, so holds it."""
+        self._start_trace_if_due()
+        with tel.span("setup.first_step", tel.SETUP_CAT,
+                      step=self._step_count) as span:
+            out = run(*args)
+        if span.id:
+            self._first_step_s = span.dur_ns / 1e9
+        self._stop_trace_if_due(out if self.state is None else self.state)
+        return out
+
+    def _prologue(self):
+        """Before a dispatch, at a SAFE point: what was left pending."""
+        with tel.span("runner.prologue", "runner", step=self._step_count):
+            self._maybe_sentinel_act()  # a pending rollback replaces state
+            self._maybe_preempt_act()   # a pending notice rescues/hands off
+            self._maybe_reconfigure()   # a pending epoch re-forms the mesh
+
+    def _run(self, batch, state, sync):
         t_begin = time.perf_counter()
-        self._maybe_sentinel_act()  # a pending rollback replaces self.state
-        self._maybe_preempt_act()   # a pending notice rescues/hands off
-        self._maybe_reconfigure()   # a pending epoch re-forms the mesh
+        self._prologue()
         st = state if state is not None else self.state
         if st is None:
             raise RuntimeError("Runner.run before init()")
@@ -917,6 +959,12 @@ class Runner:
                                              donate=state is None, step=step)
             if state is None:
                 self.state = new_state
+            with tel.span("runner.release", "runner", step=step):
+                # the last references to the donated state's arrays and to
+                # the placed batch go here, not when this frame is torn
+                # down after the span: freeing some 400 arrays per chip is
+                # host time worth a name (2 ms a step on four chips)
+                del st, sharded_batch
             self._after_dispatch(1)
             self._stop_trace_if_due(metrics)
             handle = MetricsHandle(metrics, self._remapper, microsteps=1,
@@ -927,10 +975,10 @@ class Runner:
                 # work is complete: this wall time is an honest per-step
                 # duration
                 host_metrics = handle.result()
-                self._record_step_time(t_begin)
+                self._record_step_time(t_begin, step)
                 return ((new_state, host_metrics) if state is not None
                         else host_metrics)
-            self._record_step_time(t_begin)
+            self._record_step_time(t_begin, step)
             return (new_state, handle) if state is not None else handle
 
     def run_superstep(self, stacked_batch, sync: bool = False):
@@ -942,10 +990,13 @@ class Runner:
         :class:`MetricsHandle` (``sync=True`` forces the readback before
         returning). Heartbeats and staleness pacing advance by the true
         k microsteps."""
+        if self._first_step_s is None:
+            return self._first_step(self._run_superstep, stacked_batch, sync)
+        return self._run_superstep(stacked_batch, sync)
+
+    def _run_superstep(self, stacked_batch, sync):
         t_begin = time.perf_counter()
-        self._maybe_sentinel_act()  # a pending rollback replaces self.state
-        self._maybe_preempt_act()   # a pending notice rescues/hands off
-        self._maybe_reconfigure()   # a pending epoch re-forms the mesh
+        self._prologue()
         if self.state is None:
             raise RuntimeError("Runner.run_superstep before init()")
         self._compile_grace_begin()
@@ -961,7 +1012,9 @@ class Runner:
             self._check_ps_owner_health()
             new_state, metrics = self._dstep.run_multi(self.state, placed,
                                                        step=step)
-            self.state = new_state
+            with tel.span("runner.release", "runner", step=step):
+                self.state = new_state  # the donated state's arrays go
+                del placed
             self._after_dispatch(k)
             self._stop_trace_if_due(metrics)
             handle = MetricsHandle(metrics, self._remapper, microsteps=k,
@@ -969,7 +1022,7 @@ class Runner:
                                    step=step)
             if sync:
                 handle.result()
-            self._record_step_time(t_begin)
+            self._record_step_time(t_begin, step)
             return handle.result() if sync else handle
 
     def lowered_text(self, batch, state: Optional[TrainState] = None,
@@ -1512,7 +1565,8 @@ class Runner:
             # checkpoint/log writes) on the way out
             while pending:
                 handle = pending.pop(0)
-                per_step = handle.unstack()
+                with tel.span("runner.unstack", "runner", step=handle.step):
+                    per_step = handle.unstack()
                 with tel.span("runner.callbacks", "runner",
                               step=handle.step):
                     for m in per_step:

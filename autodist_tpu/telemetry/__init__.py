@@ -5,7 +5,9 @@ The runtime observability layer (docs/observability.md):
 - :mod:`~autodist_tpu.telemetry.spans` — the thread-safe ring-buffer
   :class:`TraceRecorder` and the ``span()``/``counter_add()`` helpers the
   framework's hot paths are instrumented with (near-zero cost when
-  ``ADT_TRACE=0``);
+  ``ADT_TRACE=0``), and the set-up account (:func:`setup_account`): the
+  phases of build, init and the first step with what JAX compiled or
+  loaded beneath them, kept past ``clear()``;
 - :mod:`~autodist_tpu.telemetry.scopes` — the one table of
   ``jax.named_scope`` names inside the compiled programs, and
   :func:`scope_map`: HLO instruction name → ``op_name`` of a running
@@ -30,7 +32,8 @@ The runtime observability layer (docs/observability.md):
 """
 from autodist_tpu.telemetry.spans import (  # noqa: F401
     TraceRecorder, configure, counter_add, counters, current_span_id,
-    gauge_set, get_recorder, instant, reset, span, tracing_enabled)
+    gauge_set, get_recorder, instant, reset, setup_account, span,
+    tracing_enabled)
 from autodist_tpu.telemetry.scopes import (  # noqa: F401
     SCOPES, register_program, registered_programs, scope, scope_map)
 from autodist_tpu.telemetry.export import (  # noqa: F401
@@ -51,7 +54,7 @@ from autodist_tpu.telemetry.blackbox import (  # noqa: F401
 __all__ = [
     "TraceRecorder", "configure", "counter_add", "counters",
     "current_span_id", "gauge_set", "get_recorder", "instant", "reset",
-    "span", "tracing_enabled",
+    "setup_account", "span", "tracing_enabled",
     "SCOPES", "register_program", "registered_programs", "scope",
     "scope_map",
     "chrome_trace", "merge_traces", "metrics_text", "publish_telemetry",

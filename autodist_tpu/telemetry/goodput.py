@@ -24,9 +24,15 @@ bucket              spans whose SELF time it aggregates
 ``readback``        ``runner.fetch`` (device→host copy of the metrics)
                     and ``runner.readback``'s own time around its two
                     children (the wait is ``compute``)
-``host_loop``       ``runner.callbacks`` (user callbacks + history) and
+``host_loop``       ``runner.callbacks`` (user callbacks + history),
                     ``runner.control`` (heartbeat, epoch, preemption and
-                    profile-window polls after a dispatch)
+                    profile-window polls after a dispatch),
+                    ``runner.prologue`` (pending sentinel / preemption /
+                    reconfigure actions before one), ``runner.step_time``
+                    (step-time bookkeeping, the straggler observer),
+                    ``runner.release`` (the donated state's and the placed
+                    batch's arrays freed) and ``runner.unstack`` (a fused
+                    group's metrics split per microstep)
 ``checkpoint``      every ``ckpt`` category span on the training thread
                     (async writer-thread time overlaps compute and is
                     deliberately NOT charged against the wall)
@@ -78,6 +84,8 @@ _SPAN_BUCKET = {
     "runner.next_batch": "host_input",
     "runner.readback": "readback", "runner.fetch": "readback",
     "runner.callbacks": "host_loop", "runner.control": "host_loop",
+    "runner.prologue": "host_loop", "runner.step_time": "host_loop",
+    "runner.unstack": "host_loop", "runner.release": "host_loop",
     "sentinel.rollback": "rollback_replay",
 }
 _CAT_BUCKET = {"ckpt": "checkpoint"}
@@ -244,7 +252,10 @@ def breakdown_from_events(events: List[dict],
     rather than spending it."""
     if tid is None:
         tid = _training_tid(events)
-    mine = [e for e in events if e["tid"] == tid and e["dur"] > 0]
+    # jax.* spans say what JAX did INSIDE the span that was live (a jit
+    # traced within another is not its child): that span keeps the time
+    mine = [e for e in events if e["tid"] == tid and e["dur"] > 0
+            and e["cat"] != spans_lib.JAX_CAT]
     ids = {e["id"] for e in mine}
     child_time: Dict[int, float] = {}
     for e in mine:

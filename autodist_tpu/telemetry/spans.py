@@ -25,6 +25,20 @@ Span ids are per-recorder monotonic ints carried on a thread-local stack,
 so logs can correlate with traces (``utils/logging.py`` JSON mode embeds
 ``current_span_id()``) and children record their parent. Export formats
 live in :mod:`autodist_tpu.telemetry.export`.
+
+**The set-up account.** Spans of category ``setup`` (``setup.build`` and
+its phases, ``setup.init``, ``setup.first_step``: docs/observability.md
+has the table) are ordinary spans that are ALSO kept in a small store of
+their own, each with the seconds JAX traced, lowered, compiled or loaded
+beneath it (summed from the ``jax.*`` spans that ``jax.monitoring``'s
+duration events become) and the device memory at its end as args, beside
+every gauge set while one was live on its thread and the counters as they
+stood when the last of them ended.
+``TraceRecorder.clear()`` drops the WINDOW's state and keeps the account
+(a benchmark clears before its window; what the program decided at set-up
+was dropped before anyone could read it); ``reset()`` drops it, a new
+``setup.build`` starts it anew, :func:`setup_account` returns it as plain
+data. Recorded only while tracing is on.
 """
 import collections
 import itertools
@@ -100,15 +114,18 @@ class _Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        self._rec._append(self._end(exc_type, exc, tb))
+        return False
+
+    def _end(self, exc_type, exc, tb) -> SpanEvent:
         t1 = time.perf_counter_ns()
         self._ann.__exit__(exc_type, exc, tb)
         rec = self._rec
         stack = rec._span_stack()
         if stack and stack[-1] == self.id:
             stack.pop()
-        rec._append(SpanEvent(self.name, self.cat, self._t0, t1 - self._t0,
-                              rec._tid(), self.id, self._parent, self.args))
-        return False
+        return SpanEvent(self.name, self.cat, self._t0, t1 - self._t0,
+                         rec._tid(), self.id, self._parent, self.args)
 
 
 class _NoopSpan:
@@ -127,6 +144,79 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+SETUP_CAT = "setup"       # the phases of build, init and the first step
+SETUP_ROOT = "setup.build"  # entering it starts the account anew
+JAX_CAT = "jax"           # what JAX did beneath whichever span was live
+SETUP_CAPACITY = 256      # phases kept (a build has about ten)
+
+
+def device_memory():
+    """(bytes in use, peak bytes in use) of the fullest local device; zeros
+    where the backend reports none (the CPU)."""
+    import jax
+    in_use = peak = 0
+    try:
+        devices = jax.local_devices()
+    except RuntimeError:  # no backend came up: the phase is failing anyway
+        return 0, 0
+    for d in devices:
+        stats = d.memory_stats() or {}
+        in_use = max(in_use, int(stats.get("bytes_in_use", 0)))
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    return in_use, peak
+
+
+def _what_jax_did(events) -> dict:
+    """A phase's ``jax.*`` spans (one thread's, in order of their ends) as
+    span args: seconds tracing and lowering (a jit traced while another is
+    being traced lies inside it and ends first: counted once), compiling,
+    loading from the compile cache, and how many programs of each name
+    were compiled or loaded."""
+    outer, total, programs = [], {}, {}
+    for e in events:
+        if e.name in ("jax.trace", "jax.lower"):
+            while outer and outer[-1][0] >= e.ts_ns:
+                outer.pop()
+            outer.append((e.ts_ns, e.dur_ns))
+        else:
+            total[e.name] = total.get(e.name, 0) + e.dur_ns
+            fun = (e.args or {}).get("fun_name", "?")
+            programs[fun] = programs.get(fun, 0) + 1
+    return {"trace_lower_s": sum(d for _, d in outer) / 1e9,
+            "backend_compile_s": total.get("jax.backend_compile", 0) / 1e9,
+            "cache_load_s": total.get("jax.cache_load", 0) / 1e9,
+            "programs": programs}
+
+
+class _SetupSpan(_Span):
+    """A phase of set-up: a span like any other (ring, parent, trace
+    annotation) that also enters the recorder's set-up account, with the
+    device's memory at its end and what JAX did beneath it (not beneath a
+    phase inside it) as args. While one is live on a thread, gauges set
+    there enter the account too."""
+
+    __slots__ = ("dur_ns",)
+
+    def __enter__(self):
+        rec = self._rec
+        if self.name == SETUP_ROOT:
+            rec.clear_setup()
+        rec._live_setup().append((self.name, []))
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        rec = self._rec
+        live = rec._live_setup()
+        _, did = live.pop()
+        in_use, peak = device_memory()
+        self.args = dict(self.args or {}, hbm_in_use=in_use, hbm_peak=peak,
+                         **_what_jax_did(did))
+        event = self._end(exc_type, exc, tb)
+        self.dur_ns = event.dur_ns
+        rec._append(event)
+        rec._setup_append(event, last=not live)
+        return False
+
 
 # ---------------------------------------------------------------- recorder
 
@@ -144,6 +234,8 @@ DEFAULT_COUNTERS = (
     "wire.bytes_quantized", "wire.bytes_saved",
     "zero.rs_bytes", "zero.ag_bytes",
     "overlap.buckets",
+    "compile.traces", "compile.backend_compiles", "compile.cache_hits",
+    "compile.cache_misses",
     "coord.retries", "coord.reconnects", "coord.breaker_opens",
     "coord.backoff_s",
     "prefetch.batches", "prefetch.dropped_batches",
@@ -293,6 +385,11 @@ class TraceRecorder:
         # small-int thread ids with names, for readable trace tracks
         self._threads: Dict[int, int] = {}
         self._thread_names: Dict[int, str] = {}
+        # the set-up account (module docstring): outlives clear()
+        self._setup_events: collections.deque = collections.deque(
+            maxlen=SETUP_CAPACITY)
+        self._setup_counters: Dict[str, float] = {}
+        self._setup_gauges: Dict[str, float] = {}
 
     # ------------------------------------------------------------- plumbing
 
@@ -301,6 +398,14 @@ class TraceRecorder:
         if stack is None:
             stack = self._tls.stack = []
         return stack
+
+    def _live_setup(self) -> list:
+        """(name, its ``jax.*`` spans so far) of the set-up phases live on
+        this thread, outermost first."""
+        live = getattr(self._tls, "setup", None)
+        if live is None:
+            live = self._tls.setup = []
+        return live
 
     def _tid(self) -> int:
         ident = threading.get_ident()
@@ -326,6 +431,8 @@ class TraceRecorder:
     def span(self, name: str, cat: str = "app", **args):
         """Context manager timing a nested span. Honors the recorder's
         sampling stride; returns a shared no-op when sampled out."""
+        if cat == SETUP_CAT:  # a dozen a job: never sampled out
+            return _SetupSpan(self, name, cat, args or None)
         if self.sample > 1 and next(self._sample_tick) % self.sample:
             return _NOOP
         return _Span(self, name, cat, args or None)
@@ -355,6 +462,8 @@ class TraceRecorder:
     def gauge_set(self, name: str, value: float):
         with self._lock:
             self._gauges[name] = float(value)
+            if getattr(self._tls, "setup", None):
+                self._setup_gauges[name] = float(value)
 
     def hist_observe(self, name: str, value: float, bounds=None):
         """Record one observation into the named histogram (created with
@@ -419,12 +528,66 @@ class TraceRecorder:
         return [e.dur_ns / 1e9 for e in self.events() if e.name == name]
 
     def clear(self):
+        """Drop the window's state: events, counters, gauges, histograms.
+        The set-up account stays (:meth:`clear_setup` drops it)."""
         with self._lock:
             self._events.clear()
             self._appended = 0
             self._counters = dict.fromkeys(DEFAULT_COUNTERS, 0.0)
             self._gauges.clear()
             self._histograms.clear()
+
+    # ------------------------------------------------------ set-up account
+
+    def jax_event(self, name: str, duration_s: float, fun_name=None):
+        """A span for something JAX has just finished (it ends now), under
+        whichever span is live on this thread; where that is inside a
+        phase of set-up it carries the innermost one's name as ``phase``
+        and is summed into that phase's args when the phase ends."""
+        dur = int(duration_s * 1e9)
+        args = {"fun_name": fun_name} if fun_name else {}
+        live = getattr(self._tls, "setup", None)
+        if live:
+            args["phase"] = live[-1][0]
+        event = SpanEvent(name, JAX_CAT, time.perf_counter_ns() - dur, dur,
+                          self._tid(), next(self._ids),
+                          self.current_span_id(), args or None)
+        self._append(event)
+        if live:
+            live[-1][1].append(event)
+
+    def _setup_append(self, event: SpanEvent, last: bool):
+        """A phase ended; ``last``: no other is live on this thread, so
+        set-up may be over: the counters as they stand."""
+        self._setup_events.append(event)
+        if last:
+            with self._lock:
+                self._setup_counters = {k: v for k, v
+                                        in self._counters.items() if v}
+
+    def setup_account(self) -> dict:
+        """The account as plain data: ``phases`` (every ``setup.*`` span
+        in order of their ends, with ``start_ns`` / ``end_ns`` on the
+        perf_counter_ns clock, ``id``, ``parent`` and ``args``: the
+        span's own, the memory readings and what JAX did beneath it),
+        ``counters`` (those that had moved when the last phase ended) and
+        ``gauges`` (set while a phase was live). All empty where nothing
+        was recorded."""
+        with self._lock:
+            counters = dict(self._setup_counters)
+            gauges = dict(self._setup_gauges)
+        return {"phases": [{"name": e.name, "id": e.span_id,
+                            "parent": e.parent_id, "start_ns": e.ts_ns,
+                            "end_ns": e.ts_ns + e.dur_ns,
+                            "args": dict(e.args or {})}
+                           for e in list(self._setup_events)],
+                "counters": counters, "gauges": gauges}
+
+    def clear_setup(self):
+        self._setup_events.clear()
+        with self._lock:
+            self._setup_counters = {}
+            self._setup_gauges = {}
 
 
 # ------------------------------------------------------- module-level state
@@ -455,6 +618,65 @@ def _parse_mode(raw: str):
     return True, False  # "1"/"on"/anything truthy: record every span
 
 
+# jax.monitoring's names for what a jit does before it runs: tracing to a
+# jaxpr, lowering that to a module, compiling the module or loading the
+# executable from the persistent cache. The backend_compile event brackets
+# compile-OR-load, so after a load it names the load (fun_name) instead.
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+}
+_JAX_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+_LISTENING = False
+
+
+def _on_jax_duration(event, duration_secs, **kwargs):
+    if not _TRACING:
+        return
+    name = _JAX_DURATIONS.get(event)
+    if name is None:
+        return
+    rec = get_recorder()
+    if name == "jax.cache_load":
+        # the retrieval's own time; JAX names the executable in the
+        # backend_compile event it fires around the load, next on this thread
+        rec._tls.cache_load_s = duration_secs
+        return
+    if name == "jax.backend_compile":
+        loaded = getattr(rec._tls, "cache_load_s", None)
+        if loaded is not None:
+            rec._tls.cache_load_s = None
+            name, duration_secs = "jax.cache_load", loaded
+        else:
+            rec.counter_add("compile.backend_compiles")
+    elif name == "jax.trace":
+        rec.counter_add("compile.traces")
+    rec.jax_event(name, duration_secs, kwargs.get("fun_name"))
+
+
+def _on_jax_event(event, **kwargs):
+    if _TRACING and event in _JAX_COUNTS:
+        get_recorder().counter_add(_JAX_COUNTS[event])
+
+
+def _listen_to_jax():
+    """Register the two listeners, once, when tracing is first configured
+    on. They stay (a later ``configure("0")`` makes them return on the
+    flag check): a process that never traces registers nothing."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    _LISTENING = True
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    monitoring.register_event_listener(_on_jax_event)
+
+
 def _sync_mode():
     """Re-derive mode + the live recorder's sampling stride from ONE
     source (the configure() override when set, else the env) — a stale
@@ -464,6 +686,8 @@ def _sync_mode():
     mode, sample = (_OVERRIDE if _OVERRIDE is not None
                     else (const.ENV.ADT_TRACE.val, None))
     _TRACING, _SAMPLED = _parse_mode(mode)
+    if _TRACING:
+        _listen_to_jax()
     rec = _recorder
     if rec is not None:
         if not _SAMPLED:
@@ -565,6 +789,11 @@ def current_span_id() -> int:
     return rec.current_span_id() if rec is not None else 0
 
 
+def setup_account() -> dict:
+    """The set-up account of the last build (``TraceRecorder.setup_account``)."""
+    return get_recorder().setup_account()
+
+
 def reset():
     """Drop all recorded state (test isolation — wired into
     ``autodist_tpu.reset()``). The MODE is re-derived, not dropped: an
@@ -574,4 +803,5 @@ def reset():
     rec = _recorder
     if rec is not None:
         rec.clear()
+        rec.clear_setup()
     _sync_mode()

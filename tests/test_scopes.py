@@ -20,7 +20,12 @@ The tracing contracts (``telemetry/scopes.py``, docs/observability.md
 - the goodput report charges the wait to ``compute`` and still sums to
   the wall;
 - ``DecodeEngine`` results carry the four timestamps, and its spans the
-  request ids.
+  request ids;
+- set-up is an account of its own (docs/observability.md "Set-up"): every
+  phase of build, init and the first step once, children inside parents,
+  what JAX traced and compiled beneath them, the trace-time gauges; it
+  outlives ``clear()``, ``reset()`` and a new build drop it, a recompile
+  after set-up is the window's, and with tracing off there is none.
 """
 import glob
 import re
@@ -40,9 +45,11 @@ from autodist_tpu.telemetry import scopes
 from autodist_tpu.telemetry import spans as tel
 
 STEP = "jit_local_step"
-PER_STEP = ("runner.next_batch", "runner.dispatch", "runner.feed",
-            "dstep.dispatch", "runner.control", "runner.readback",
-            "runner.wait_device", "runner.fetch", "runner.callbacks")
+PER_STEP = ("runner.next_batch", "runner.prologue", "runner.dispatch",
+            "runner.feed", "dstep.dispatch", "runner.release",
+            "runner.control",
+            "runner.readback", "runner.wait_device", "runner.fetch",
+            "runner.step_time", "runner.callbacks")
 
 
 @pytest.fixture(autouse=True)
@@ -485,6 +492,30 @@ def test_spans_are_annotations_in_a_profiler_session(tmp_path):
     assert sorted(s["step"] for s in found["runner.wait_device"]) == [1, 2]
 
 
+def test_the_first_step_profile_holds_the_first_step_span(tmp_path,
+                                                          monkeypatch):
+    """``AutoDist(tracing=True)`` profiles the first step: the session
+    starts before ``setup.first_step`` is entered, so the span lies in the
+    profiler's own file with the step's spans and the device's ops."""
+    from jax.profiler import ProfileData
+    from autodist_tpu import const
+    monkeypatch.setattr(const, "DEFAULT_TRACE_DIR", str(tmp_path))
+    tel.configure("1")
+    cfg = lm.LMConfig.tiny()
+    loss_fn, params, batch, _ = lm.make_train_setup(
+        cfg, seq_len=16, batch_size=8)
+    ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce(), tracing=True)
+    runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
+    runner.init(params)
+    runner.run(batch)
+    runner.run(batch)  # only the first step is profiled
+    path = glob.glob(str(tmp_path / "*/plugins/profile/*/*.xplane.pb"))[0]
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"setup.first_step", "runner.dispatch", "dstep.dispatch"} <= names
+
+
 def test_untraced_spans_open_no_annotation(monkeypatch):
     made = []
     monkeypatch.setattr(tel, "_trace_annotation",
@@ -581,3 +612,207 @@ def test_decode_results_carry_timestamps_and_spans_carry_request_ids():
                  scopes.BLOCKS, scopes.ATTENTION)
     assert under(telemetry.scope_map("jit__insert"), scopes.INSERT)
     assert under(telemetry.scope_map("jit_local_predict"), scopes.PREFILL)
+
+
+# ------------------------------------------------- (f) the set-up account
+
+PHASES = {"setup.build": None, "setup.capture": "setup.build",
+          "setup.strategy": "setup.build",
+          "setup.compile_strategy": "setup.build",
+          "setup.mesh": "setup.build", "setup.transform": "setup.build",
+          "setup.init": None, "setup.init_state": "setup.init",
+          "setup.first_step": None}
+TRACE_TIME_GAUGES = ("lean_head.chunks", "lean_head.chunk_width",
+                     "lean_head.dead_cols", "attention.flash_layers",
+                     "attention.kda_kernel_layers", "model.remat_blocks")
+
+
+@pytest.fixture(scope="module")
+def set_up():
+    """A traced build -> init -> two steps of the tiny LM, the recorder
+    cleared as a benchmark clears it before its window: the account and
+    the window's state after it, as plain data."""
+    tel.configure("1")
+    try:
+        runner, batch, _ = build_lm()
+        runner.run(batch)
+        runner.run(batch)
+        before = telemetry.setup_account()
+        did = [e for e in tel.get_recorder().events() if e.cat == "jax"]
+        tel.get_recorder().clear()
+        yield {"before": before, "account": telemetry.setup_account(),
+               "jax": did,
+               "events": tel.get_recorder().events(),
+               "gauges": tel.gauges(),
+               "first_step_s": runner.step_stats()["first_step_s"]}
+    finally:
+        tel.configure(None)
+        autodist_tpu.reset()
+
+
+
+def inside(child, parent):
+    return (parent["start_ns"] <= child["start_ns"]
+            and child["end_ns"] <= parent["end_ns"])
+
+
+def test_set_up_yields_every_phase_once_children_inside_parents(set_up):
+    phases = set_up["account"]["phases"]
+    assert sorted(p["name"] for p in phases) == sorted(PHASES)
+    by = {p["name"]: p for p in phases}
+    for name, parent in PHASES.items():
+        if parent is None:
+            assert by[name]["parent"] == 0, name
+        else:
+            assert by[name]["parent"] == by[parent]["id"], name
+            assert inside(by[name], by[parent]), name
+        assert set(by[name]["args"]) >= {"hbm_in_use", "hbm_peak"}, name
+    # the three roots follow each other and do not overlap
+    build, init, first = (by[n] for n in ("setup.build", "setup.init",
+                                          "setup.first_step"))
+    assert build["end_ns"] <= init["start_ns"]
+    assert init["end_ns"] <= first["start_ns"]
+    # one pair of clock readings serves the span and first_step_s
+    assert set_up["first_step_s"] == round(
+        (first["end_ns"] - first["start_ns"]) / 1e9, 6)
+
+
+def test_what_jax_did_lies_under_the_phase_that_was_live(set_up):
+    by = {p["name"]: p for p in set_up["account"]["phases"]}
+    assert {e.name for e in set_up["jax"]} <= {
+        "jax.trace", "jax.lower", "jax.backend_compile", "jax.cache_load"}
+    # what the model's own init compiled before the build names no phase
+    did = [e for e in set_up["jax"] if "phase" in (e.args or {})]
+    assert all(e.ts_ns + e.dur_ns <= by["setup.build"]["start_ns"]
+               for e in set_up["jax"] if e not in did)
+    for e in did:
+        phase = by[e.args["phase"]]
+        assert phase["start_ns"] <= e.ts_ns, e
+        assert e.ts_ns + e.dur_ns <= phase["end_ns"], e
+    # the step program was traced, lowered and compiled (or loaded) in
+    # the first step, by name
+    first = [e for e in did if e.args["phase"] == "setup.first_step"]
+    for name in ("jax.trace", "jax.lower"):
+        assert any(e.name == name and "local_step" in e.args["fun_name"]
+                   for e in first), name
+    args = by["setup.first_step"]["args"]
+    assert "jit(local_step)" in args["programs"]
+    # the jits traced inside the step's trace are counted once: the
+    # phase's seconds are the outermost spans', not all of them
+    traced = [e for e in first if e.name in ("jax.trace", "jax.lower")]
+    step = [e for e in traced if e.args["fun_name"] in ("local_step",
+                                                       "jit(local_step)")]
+    assert len(step) == 2 < len(traced)
+    assert sum(e.dur_ns for e in step) / 1e9 <= args["trace_lower_s"] \
+        < sum(e.dur_ns for e in traced) / 1e9
+    assert args["backend_compile_s"] + args["cache_load_s"] > 0
+    # a phase holds what JAX did beneath it, not beneath one inside it
+    assert by["setup.init"]["args"]["programs"] == {}
+    assert by["setup.build"]["args"]["trace_lower_s"] == 0.0
+    c = set_up["account"]["counters"]
+    assert c["compile.traces"] > 0
+    assert c["compile.backend_compiles"] + c.get("compile.cache_hits", 0) > 0
+    assert c["dstep.dispatches"] == 1  # as they stood when set-up ended
+
+
+def test_clear_keeps_the_account_and_drops_the_windows_state(set_up):
+    assert set_up["account"] == set_up["before"]
+    assert set_up["events"] == [] and set_up["gauges"] == {}
+
+
+@pytest.mark.parametrize("gauge", TRACE_TIME_GAUGES)
+def test_a_gauge_set_at_trace_time_is_in_the_account(set_up, gauge):
+    assert gauge in set_up["account"]["gauges"]
+    if gauge in ("lean_head.chunks", "lean_head.chunk_width"):
+        assert set_up["account"]["gauges"][gauge] > 0
+
+
+def build_linear(builder, ad=None):
+    rng = np.random.RandomState(0)
+    params = {"w": np.zeros((4, 2), np.float32)}
+    batch = {"x": rng.randn(16, 4).astype(np.float32),
+             "y": rng.randn(16, 2).astype(np.float32)}
+
+    def loss_fn(p, b):
+        return ((b["x"] @ p["w"] - b["y"]) ** 2).mean()
+
+    ad = ad or autodist_tpu.AutoDist(strategy_builder=builder)
+    runner = ad.build(loss_fn, optax.adam(0.1), params, batch)
+    return ad, runner, params, batch
+
+
+def test_reset_drops_the_account_and_a_second_build_starts_a_fresh_one():
+    tel.configure("1")
+    ad, runner, params, batch = build_linear(S.AllReduce())
+    runner.init(params)
+    runner.run(batch)
+    first = telemetry.setup_account()
+    assert {"setup.build", "setup.init", "setup.first_step"} <= {
+        p["name"] for p in first["phases"]}
+    build_linear(None, ad=ad)
+    second = telemetry.setup_account()["phases"]
+    assert [p["name"] for p in second if p["parent"] == 0] == ["setup.build"]
+    assert second[-1]["id"] > max(p["id"] for p in first["phases"])
+    telemetry.reset()
+    assert telemetry.setup_account() == {"phases": [], "counters": {},
+                                         "gauges": {}}
+
+
+def test_what_the_lowering_decides_at_build_is_in_the_account():
+    """``zero.hbm_saved_bytes`` is set when the step is built: gone from
+    the window's gauges after ``clear()``, kept by the account."""
+    tel.configure("1")
+    build_linear(S.ZeroSharded())
+    tel.get_recorder().clear()
+    assert "zero.hbm_saved_bytes" not in tel.gauges()
+    assert telemetry.setup_account()["gauges"]["zero.hbm_saved_bytes"] > 0
+
+
+def test_a_recompile_after_set_up_is_the_windows_under_its_dispatch():
+    tel.configure("1")
+    _, runner, params, batch = build_linear(S.AllReduce())
+    runner.init(params)
+    runner.run(batch)
+    account = telemetry.setup_account()
+    tel.get_recorder().clear()
+    runner.run(batch)                                   # step 1: nothing
+    c = tel.counters()
+    assert c["compile.backend_compiles"] + c["compile.cache_hits"] == 0
+    runner.run({k: np.concatenate([v, v]) for k, v in batch.items()})
+    c = tel.counters()                                  # step 2: new shape
+    assert c["compile.backend_compiles"] + c["compile.cache_hits"] > 0
+    events = tel.get_recorder().events()
+    by_id = {e.span_id: e for e in events}
+    did = [e for e in events if e.cat == "jax"]
+    assert did and "phase" not in (did[-1].args or {})
+    compiled = [e for e in did if e.name in ("jax.backend_compile",
+                                             "jax.cache_load")]
+    assert compiled and all(
+        by_id[e.parent_id].name == "dstep.dispatch"
+        and by_id[e.parent_id].args["step"] == 2 for e in compiled)
+    assert telemetry.setup_account() == account          # not set-up's
+
+
+def test_with_tracing_off_no_account_and_no_listener(monkeypatch):
+    from jax import monitoring
+    registered = []
+    monkeypatch.setattr(tel, "_LISTENING", False)
+    for name in ("register_event_duration_secs_listener",
+                 "register_event_listener"):
+        monkeypatch.setattr(monitoring, name,
+                            lambda fn, _n=name: registered.append(_n))
+    tel.configure("0")
+    assert tel.span("setup.build", tel.SETUP_CAT) is tel._NOOP
+    _, runner, params, batch = build_linear(S.AllReduce())
+    runner.init(params)
+    runner.run(batch)
+    assert registered == []
+    assert telemetry.setup_account() == {"phases": [], "counters": {},
+                                         "gauges": {}}
+    assert runner.step_stats()["first_step_s"] > 0      # the host's clock
+    c = tel.counters()
+    assert c["compile.traces"] == c["compile.backend_compiles"] == 0
+    tel.configure("1")
+    tel.configure("1")
+    assert sorted(registered) == ["register_event_duration_secs_listener",
+                                  "register_event_listener"]

@@ -66,6 +66,79 @@ def test_nested_spans_record_parent_ids_and_durations():
     assert events["outer"].args == {"k": 2}
 
 
+def test_a_phase_of_set_up_keeps_gauges_counters_and_memory():
+    """The recorder's half of the set-up account, no build: a ``setup``
+    span is an ordinary span that is also kept, with the device's memory
+    as args, the gauges set while it was live and the counters as they
+    stood when the outermost ended; ``clear()`` keeps all of it."""
+    rec = tel.TraceRecorder(capacity=16)
+    rec.gauge_set("before", 1)
+    with rec.span("setup.build", tel.SETUP_CAT):
+        rec.gauge_set("lean_head.chunks", 13)
+        with rec.span("setup.capture", tel.SETUP_CAT, k=1):
+            rec.counter_add("compile.traces", 2)
+        rec.counter_add("compile.traces")   # after the inner phase ended
+    rec.gauge_set("after", 1)
+    rec.counter_add("compile.traces")       # after set-up: the window's
+    rec.clear()
+    acc = rec.setup_account()
+    assert [p["name"] for p in acc["phases"]] == ["setup.capture",
+                                                  "setup.build"]
+    inner, outer = acc["phases"]
+    assert inner["parent"] == outer["id"] and outer["parent"] == 0
+    assert inner["args"] == {
+        "k": 1, "hbm_in_use": 0, "hbm_peak": 0, "trace_lower_s": 0.0,
+        "backend_compile_s": 0.0, "cache_load_s": 0.0, "programs": {}}
+    assert acc["gauges"] == {"lean_head.chunks": 13.0}
+    assert acc["counters"] == {"compile.traces": 3.0}
+    assert rec.events() == [] and rec.gauges() == {}
+    rec.clear_setup()
+    assert rec.setup_account() == {"phases": [], "counters": {},
+                                   "gauges": {}}
+
+
+def test_jax_events_go_under_the_live_span_and_sum_into_the_phase():
+    rec = tel.TraceRecorder(capacity=16)
+    rec.jax_event("jax.trace", 0.001, "outside")   # no phase: ring only
+    with rec.span("setup.build", tel.SETUP_CAT):
+        rec.jax_event("jax.trace", 0.25, "loss_fn")
+        with rec.span("setup.first_step", tel.SETUP_CAT):
+            with rec.span("dstep.dispatch", "dstep") as live:
+                rec.jax_event("jax.trace", 0.001, "inner")
+                rec.jax_event("jax.backend_compile", 0.0005, "eager")
+                rec.jax_event("jax.trace", 0.5, "outer")  # began before both
+                rec.jax_event("jax.lower", 0.002, "jit(outer)")
+                rec.jax_event("jax.cache_load", 0.125, "jit(outer)")
+    first, build = rec.setup_account()["phases"]
+    # a trace inside a trace is counted once; each phase holds what JAX
+    # did beneath IT, not beneath a phase inside it
+    assert first["args"]["trace_lower_s"] == pytest.approx(0.502)
+    assert first["args"]["backend_compile_s"] == pytest.approx(0.0005)
+    assert first["args"]["cache_load_s"] == 0.125
+    assert first["args"]["programs"] == {"eager": 1, "jit(outer)": 1}
+    assert build["args"]["trace_lower_s"] == 0.25
+    assert build["args"]["programs"] == {}
+    ring = [e for e in rec.events() if e.cat == tel.JAX_CAT]
+    assert len(ring) == 7 and ring[0].parent_id == 0
+    assert ring[0].args == {"fun_name": "outside"}
+    assert ring[1].args == {"fun_name": "loss_fn", "phase": "setup.build"}
+    assert all(e.parent_id == live.id
+               and e.args["phase"] == "setup.first_step" for e in ring[2:])
+
+
+@pytest.mark.parametrize("mode, kept", [("0", 0), ("1", 1), ("sampled", 1)])
+def test_set_up_spans_follow_the_mode_and_are_never_sampled_out(mode, kept):
+    tel.configure(mode, sample=1000)
+    for _ in range(3):   # the stride would keep the first span only
+        with tel.span("hot", "test"):
+            pass
+    with tel.span("setup.build", tel.SETUP_CAT):
+        pass
+    assert len(tel.setup_account()["phases"]) == kept
+    assert [e.name for e in tel.get_recorder().events()
+            if e.cat == tel.SETUP_CAT] == ["setup.build"] * kept
+
+
 def test_counters_and_gauges_work_with_tracing_disabled():
     tel.configure("0")
     tel.counter_add("runner.steps", 3)
