@@ -11,9 +11,21 @@ Design (standard FlashAttention-2 tiling, arXiv 2307.08691):
   innermost/"arbitrary"; running (m, l, acc) live in VMEM scratch across kv
   steps; the log-sum-exp per row is written out for the backward pass.
 - backward: delta = rowsum(dO * O) precomputed in XLA (cheap elementwise),
-  then two kernels — dQ over (q_blocks, kv_blocks) and dK/dV over
-  (kv_blocks, q_blocks) — recompute P = exp(S - lse) tile by tile instead
-  of storing it.
+  then ONE kernel, ``flash_bwd``, over (kv_blocks, q_blocks) with the q
+  blocks innermost: each live tile recomputes P = exp(S - lse) and
+  dS = P * (dP - delta) once, instead of storing them, and adds its three
+  products to dK / dV (float32 scratch of the kv block, as k and v stay put)
+  and to dQ, whose float32 accumulator for the WHOLE head ([Sq, D]) stays
+  in VMEM across the head's grid steps and is scaled, cast and written a
+  q block at a time during the head's last kv pass; ``vmem_limit_bytes``
+  is counted from the shapes (``_fused_vmem``; 21.5 MB at seq 8192 x 192,
+  Mosaic's default scope is 16 MiB of the v5e's 128). Where that count
+  passes half of VMEM (seq 65,536 x 192: dq alone is 67 MB) the two
+  kernels of before run instead, ``flash_dq`` over (q_blocks, kv_blocks)
+  and ``flash_dkdv``, each recomputing the tile: same products, same
+  order, same gradients to the last bit (``_bwd`` decides from the
+  shapes alone; counters ``attention.flash_bwd_fused`` / ``_split`` say
+  which form each traced backward pass took; PERF.md section 6, PR 34).
 - tiles: ``_tiles`` chooses (block_q, block_k) from the shapes; no caller
   passes a tile size (PERF.md section 6, PR 28, has the chip's readings).
 - causal: a tile above the diagonal is skipped by ``pl.when`` AND its
@@ -66,10 +78,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from autodist_tpu.ops import pallas_mode
+from autodist_tpu.telemetry import spans as tel
 
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp() NaN-free on masked rows
 _LANES = 128     # last-dim tile width; m/l scratch are lane-replicated
 _ROWS = 512      # rows of a q or kv tile
+_VMEM = 128 << 20  # a v5e core's vector memory (Mosaic scopes 16 MiB of it
+#                    to a kernel that asks for no more)
 # what a recomputed block keeps of the core: the forward kernel's output and
 # log-sum-exp, the two residuals of the backward kernels that only the
 # forward kernel can give, and q as the kernels read it
@@ -330,14 +345,19 @@ def _dq_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
         dq_ref[...] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkdv_kernel(*refs, scale, causal, has_seg, bq, bk, n_q):
-    if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref, ks_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
+def _bwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_q, n_kv, fused):
+    """dk and dv of one kv block, summed over its q blocks in float32
+    scratch. ``fused``: dq too, from the SAME ``_p_and_ds`` of each tile:
+    it sums over the kv blocks in ``dq_acc``, one float32 [bq, D] slab a q
+    block, which stays in VMEM for the whole head and leaves it in the
+    head's last kv pass."""
+    n_in = 8 if has_seg else 6
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    qs_ref, ks_ref = refs[6:n_in] if has_seg else (None, None)
+    if fused:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[n_in:]
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
-        qs_ref = ks_ref = None
+        dk_ref, dv_ref, dk_acc, dv_acc = refs[n_in:]
     ki, qi = pl.program_id(2), pl.program_id(3)
 
     @pl.when(qi == 0)
@@ -345,16 +365,26 @@ def _dkdv_kernel(*refs, scale, causal, has_seg, bq, bk, n_q):
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    if fused:
+        @pl.when(ki == 0)
+        def _():
+            dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
     def tile(mask):
-        q, do = q_ref[...], do_ref[...]
-        p, ds = _p_and_ds(q, k_ref[...], v_ref[...], do, lse_ref[...],
+        q, k, do = q_ref[...], k_ref[...], do_ref[...]
+        p, ds = _p_and_ds(q, k, v_ref[...], do, lse_ref[...],
                           delta_ref[...], scale, mask)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        ds = ds.astype(q.dtype)
         dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if fused:
+            dq_acc[qi] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, tile)
 
@@ -362,6 +392,37 @@ def _dkdv_kernel(*refs, scale, causal, has_seg, bq, bk, n_q):
     def _():
         dk_ref[...] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
+
+    if fused:
+        @pl.when(ki == n_kv - 1)
+        def _():
+            dq_ref[...] = (dq_acc[qi] * scale).astype(dq_ref.dtype)
+
+
+def _vmem_bytes(rows, cols, dtype):
+    """Bytes of a [rows, cols] buffer in VMEM: whole (sublane, 128-lane)
+    tiles, 8 sublanes of 32 bits."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item
+    return -(-rows // sub) * sub * -(-cols // _LANES) * _LANES * item
+
+
+def _fused_vmem(Sq, D, Dv, bq, bk, dtype, has_seg):
+    """Bytes of VMEM the fused backward kernel asks for, from the shapes:
+    the head's float32 dq accumulator, the two dk / dv accumulators, every
+    block the pipeline holds twice (operands, row vectors, segment ids,
+    the three results) and a tile's float32 [bq, bk] temporaries (scores,
+    P, dP, dS, the two operands cast for the MXU and their transposes)."""
+    f32 = jnp.float32
+    rows = 2 * _vmem_bytes(bq, 1, f32)               # lse, delta
+    if has_seg:
+        rows += _vmem_bytes(bq, 1, jnp.int32) + _vmem_bytes(bk, 1, jnp.int32)
+    blocks = (2 * _vmem_bytes(bq, D, dtype) + _vmem_bytes(bq, Dv, dtype)
+              + 2 * _vmem_bytes(bk, D, dtype) + 2 * _vmem_bytes(bk, Dv, dtype)
+              + rows)
+    return (Sq // bq * _vmem_bytes(bq, D, f32)
+            + _vmem_bytes(bk, D, f32) + _vmem_bytes(bk, Dv, f32)
+            + 2 * blocks + 8 * _vmem_bytes(bq, bk, f32))
 
 
 def _bwd(causal, res, do):
@@ -387,6 +448,49 @@ def _bwd(causal, res, do):
         specs = [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec]
         return specs + [qs_spec, ks_spec] if has_seg else specs
 
+    # kv-major grid (b, h, i = kv block, j = q block): q is the reduction
+    # (innermost) dim; a dead causal tile re-names the first live q block
+    def q_pos(i, j):
+        return jnp.clip(j, (i * bk) // bq, n_q - 1) if causal else j
+
+    kv_major = _specs(D, Dv, bq, bk, q_pos, lambda i, j: i)
+    # one kernel or two? One, whenever what ``_fused_vmem`` counts fits half
+    # the core's vector memory; a longer head's dq accumulator (seq 131,072
+    # x 192 float32 is 134 MB) stays on the two kernels, whose dq lives a q
+    # block at a time. Counted by form as the backward pass is traced
+    vmem = _fused_vmem(Sq, D, Dv, bq, bk, q.dtype, has_seg)
+    fused = vmem <= _VMEM // 2
+    tel.counter_add("attention.flash_bwd_fused" if fused
+                    else "attention.flash_bwd_split")
+    # (block, result like, float32 accumulator) of the kv-major kernel
+    outs = [(kv_major[1], k, (bk, D)), (kv_major[2], v, (bk, Dv))]
+    params = _PARAMS
+    if fused:
+        # dq's rows leave the accumulator in the head's last kv pass: until
+        # then its block keeps one index, so nothing is written back
+        dq_spec = pl.BlockSpec(
+            (None, None, bq, D),
+            lambda b, h, i, j: (b, h, jnp.where(i == n_kv - 1, j, 0), 0))
+        outs.insert(0, (dq_spec, q, (n_q, bq, D)))
+        params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=vmem)
+    grads = pl.pallas_call(
+        functools.partial(_bwd_kernel, n_q=n_q, n_kv=n_kv, fused=fused,
+                          **static),
+        grid=(B, H, n_kv, n_q),
+        in_specs=in_specs(*kv_major),
+        out_specs=[spec for spec, _, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for _, x, _ in outs],
+        scratch_shapes=[pltpu.VMEM(acc, jnp.float32) for _, _, acc in outs],
+        compiler_params=params,
+        interpret=pallas_mode.interpret(),
+        name="flash_bwd" if fused else "flash_dkdv",
+    )(*operands)
+    if fused:
+        return tuple(grads)
+
     specs = _specs(D, Dv, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, n_kv=n_kv, **static),
@@ -399,27 +503,7 @@ def _bwd(causal, res, do):
         interpret=pallas_mode.interpret(),
         name="flash_dq",
     )(*operands)
-
-    # kv-major grid (b, h, i = kv block, j = q block): q is the reduction
-    # (innermost) dim; a dead causal tile re-names the first live q block
-    def q_pos(i, j):
-        return jnp.clip(j, (i * bk) // bq, n_q - 1) if causal else j
-
-    specs = _specs(D, Dv, bq, bk, q_pos, lambda i, j: i)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkdv_kernel, n_q=n_q, **static),
-        grid=(B, H, n_kv, n_q),
-        in_specs=in_specs(*specs),
-        out_specs=[specs[1], specs[2]],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, Dv), jnp.float32)],
-        compiler_params=_PARAMS,
-        interpret=pallas_mode.interpret(),
-        name="flash_dkdv",
-    )(*operands)
-    return dq, dk, dv
+    return (dq, *grads)
 
 
 # ---------------------------------------------------------------- public op

@@ -101,7 +101,7 @@ SCOPES: Dict[str, str] = {
          "up-projection, the attention core, the output projection)",
     MLA_CORE: "inside mla: the attention core alone, the call of the "
               "attention function on q, k, v (the flash kernels flash_fwd / "
-              "flash_dq / flash_dkdv on a TPU, XLA's scores elsewhere)",
+              "flash_bwd on a TPU, XLA's scores elsewhere)",
     PREFILL: "serving: the prompt pass of a prefill bucket",
     DECODE: "serving: one cached decode step",
     INSERT: "serving: writing admitted rows into the decode state",

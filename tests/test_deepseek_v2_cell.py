@@ -367,8 +367,9 @@ def test_the_cell_file_says_what_the_record_shows(fault):
 def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     """``deepseek_v2_lite_train_1chip``'s step as the benchmark builds it ON
     THE CHIP (``benchmark/tools/aot_compile_as_on_the_chip.py``), at the
-    published widths and 1 x 8,192 tokens: the three flash kernels at
-    192 / 128 ONCE in each of six layers (a recomputed block keeps the
+    published widths and 1 x 8,192 tokens: the two flash kernels at
+    192 / 128 (``flash_fwd`` and, since PR 34, the one backward kernel
+    ``flash_bwd``) ONCE in each of six layers (a recomputed block keeps the
     forward kernel's output and log-sum-exp by name: until PR 32 it ran
     the forward once more), no grouped-matmul kernel (a share of the
     experts runs every held expert on every token), every block
@@ -392,8 +393,8 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     text = compiled[0].as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 6 * 3
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkdv"):
+    assert len(calls) == 6 * 2
+    for kernel in ("flash_fwd", "flash_bwd"):
         assert sum(kernel in line for line in calls) == 6
     assert all("mla_core" in line for line in calls)
     assert "gmm" not in text and "rematted_computation" in text

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from autodist_tpu import telemetry as tel
 from autodist_tpu.ops.attention import reference_attention
 from autodist_tpu.ops import flash_attention as fa
 from autodist_tpu.ops.flash_attention import flash_attention, make_flash_attn_fn
@@ -326,7 +327,7 @@ def test_a_checkpoint_that_keeps_the_name_runs_the_forward_kernel_once(
     under ``jax.checkpoint``: with the policy that saves ``fa.KEPT`` the
     gradient's jaxpr holds the forward kernel ONCE (its output and
     log-sum-exp come from memory, so the recomputed one is dead code),
-    without a policy twice; the backward kernels once either way, and the
+    without a policy twice; the backward kernel once either way, and the
     three gradients are the unwrapped function's to the last bit."""
     q, k = (_rand((1, 128, 2, 192), seed=i) for i in range(2))
     v, cot = (_rand((1, 128, 2, 128), seed=i) for i in (2, 3))
@@ -341,14 +342,187 @@ def test_a_checkpoint_that_keeps_the_name_runs_the_forward_kernel_once(
              "recomputed": jax.grad(jax.checkpoint(f), (0, 1, 2))}
     calls = {how: {kernel: kernel_calls(
         jax.make_jaxpr(g)(q, k, v).jaxpr, kernel)
-        for kernel in ("flash_fwd", "flash_dq", "flash_dkdv")}
+        for kernel in ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkdv")}
         for how, g in grads.items()}
+    one_backward = {"flash_bwd": 1, "flash_dq": 0, "flash_dkdv": 0}
     assert calls == {
-        "plain": {"flash_fwd": 1, "flash_dq": 1, "flash_dkdv": 1},
-        "kept": {"flash_fwd": 1, "flash_dq": 1, "flash_dkdv": 1},
-        "recomputed": {"flash_fwd": 2, "flash_dq": 1, "flash_dkdv": 1}}
+        "plain": dict(one_backward, flash_fwd=1),
+        "kept": dict(one_backward, flash_fwd=1),
+        "recomputed": dict(one_backward, flash_fwd=2)}
     want = jax.jit(grads["plain"])(q, k, v)
     assert all(float(jnp.max(jnp.abs(g))) > 0.1 for g in want)
     for how in ("kept", "recomputed"):
         for got, ref in zip(jax.jit(grads[how])(q, k, v), want):
             np.testing.assert_array_equal(got, ref)
+
+
+# ------------------------------- one backward kernel, or two, by the shapes
+
+
+def _segments(kind, B, Sq, Sk):
+    """(q_seg, kv_seg) int32 [B, S] of a named layout, or None."""
+    if kind is None:
+        return None
+    if kind == "padding":       # BERT's key padding: 1 = token, 0 = pad
+        lengths = np.random.RandomState(7).randint(Sq // 4, Sq, (B,))
+        seg = (np.arange(Sq)[None, :] < lengths[:, None]).astype(np.int32)
+        return seg, seg
+    if kind == "packed":        # three documents in a row, uneven
+        seg = np.zeros((B, Sq), np.int32)
+        seg[:, Sq // 3:] = 1
+        seg[:, Sq - Sq // 5:] = 2
+        return seg, seg
+    assert kind == "empty_rows"  # the last third of the queries sees NO key
+    q_seg = np.zeros((B, Sq), np.int32)
+    q_seg[:, Sq - Sq // 3:] = 7
+    return q_seg, np.zeros((B, Sk), np.int32)
+
+
+# (Sq, Sk, D, Dv, dtype, causal, segments): tiles of 512 rows from seq 1,024
+# on, so dq sums over several kv blocks and dk / dv over several q blocks
+BACKWARD_CASES = {
+    "float32": (1024, 1024, 32, 32, jnp.float32, False, None),
+    "float32_causal": (1024, 1024, 32, 32, jnp.float32, True, None),
+    "bfloat16": (1024, 1024, 32, 32, jnp.bfloat16, False, None),
+    "bfloat16_causal": (1024, 1024, 32, 32, jnp.bfloat16, True, None),
+    "latent_192_128_causal": (1024, 1024, 192, 128, jnp.float32, True, None),
+    "latent_192_128_bfloat16": (1024, 1024, 192, 128, jnp.bfloat16, True,
+                                None),
+    "more_queries_than_keys": (1536, 1024, 32, 32, jnp.float32, False, None),
+    "uneven_blocks_of_64": (64, 192, 32, 32, jnp.float32, False, None),
+    "one_tile": (256, 256, 32, 32, jnp.float32, True, None),
+    "padding_mask": (1024, 1024, 32, 32, jnp.float32, False, "padding"),
+    "padding_mask_causal": (1024, 1024, 32, 32, jnp.float32, True, "padding"),
+    "packed_segments": (1024, 1024, 32, 32, jnp.float32, False, "packed"),
+    "packed_segments_causal": (1536, 1536, 32, 32, jnp.float32, True,
+                               "packed"),
+    "rows_with_no_visible_key": (1024, 1024, 32, 32, jnp.float32, False,
+                                 "empty_rows"),
+}
+
+
+def _backward_case(name):
+    """(inputs in the kernels' [B, H, S, .] layout, segs, causal, the
+    reference's float32 gradients, tolerance)."""
+    Sq, Sk, D, Dv, dtype, causal, kind = BACKWARD_CASES[name]
+    B, H = 2, 2
+    q = _rand((B, H, Sq, D), dtype, seed=0)
+    k = _rand((B, H, Sk, D), dtype, seed=1)
+    v = _rand((B, H, Sk, Dv), dtype, seed=2)
+    do = _rand((B, H, Sq, Dv), dtype, seed=3)
+    segs = _segments(kind, B, Sq, Sk)
+    mask = None
+    if causal:
+        mask = (jnp.arange(Sq)[:, None] >= jnp.arange(Sk)[None, :])[None, None]
+    if segs is not None:
+        seg_mask = jnp.asarray(_seg_mask(*segs))
+        mask = seg_mask if mask is None else jnp.logical_and(mask, seg_mask)
+
+    def loss_ref(q, k, v):
+        # (reference_attention takes the models' [B, S, H, .])
+        out = reference_attention(*(x.transpose(0, 2, 1, 3)
+                                    for x in (q, k, v)), mask)
+        return jnp.sum(out.transpose(0, 2, 1, 3) * do.astype(jnp.float32))
+
+    want = jax.grad(loss_ref, (0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    if kind == "empty_rows":
+        # XLA's softmax averages over an all-masked row; the kernels give
+        # such a query no weight anywhere
+        empty = np.asarray(segs[0] == 7)[:, None, :, None]
+        mask = jnp.logical_and(mask, ~jnp.asarray(empty))
+        want = jax.grad(lambda q, k, v: jnp.sum(jnp.where(
+            empty, 0.0, reference_attention(
+                *(x.transpose(0, 2, 1, 3) for x in (q, k, v)), mask)
+            .transpose(0, 2, 1, 3)) * do), (0, 1, 2))(q, k, v)
+    segs = None if segs is None else tuple(jnp.asarray(s) for s in segs)
+    tol = (dict(atol=5e-5, rtol=5e-4) if dtype == jnp.float32
+           else dict(atol=5e-2, rtol=5e-2))     # the file's two tolerances
+    return (q, k, v, do), segs, causal, want, tol
+
+
+def _backward(inputs, segs, causal):
+    """(dq, dk, dv) of ``fa._bwd`` on the forward kernel's own residuals."""
+    q, k, v, do = inputs
+    out, lse = fa._fwd(q, k, v, segs, causal)
+    q_seg, kv_seg = (None, None) if segs is None else segs
+    return fa._bwd(causal, (q, k, v, out, lse, q_seg, kv_seg), do)
+
+
+@pytest.mark.parametrize("case", sorted(BACKWARD_CASES))
+def test_the_fused_backward_matches_the_references_gradients(case):
+    """dq, dk and dv of the ONE backward kernel (``flash_bwd``: every live
+    tile's scores, P and dS once, dq summed in VMEM across the head's kv
+    blocks) against the XLA reference's, over dtypes, causal or not, the
+    latent widths 192 / 128, Sq != Sk, blocks under 512 rows, a padding
+    mask, packed documents and queries that see no key (zero gradients)."""
+    inputs, segs, causal, want, tol = _backward_case(case)
+    q, k, v, do = inputs
+    before = tel.counters().get("attention.flash_bwd_fused", 0)
+    got = _backward(inputs, segs, causal)
+    assert tel.counters()["attention.flash_bwd_fused"] == before + 1
+    for g, x, w in zip(got, (q, k, v), want):
+        assert g.dtype == x.dtype and g.shape == x.shape
+        assert float(jnp.max(jnp.abs(w))) > 0.1     # not vacuous
+        np.testing.assert_allclose(g.astype(jnp.float32), w, **tol)
+    if case == "rows_with_no_visible_key":
+        np.testing.assert_array_equal(
+            np.asarray(got[0][:, :, q.shape[2] - q.shape[2] // 3:]), 0.0)
+
+
+@pytest.mark.parametrize("case", sorted(BACKWARD_CASES))
+def test_both_backward_forms_give_the_same_gradients(case, monkeypatch):
+    """The two kernels that stay for heads whose dq accumulator does not fit
+    VMEM (``flash_dq`` + ``flash_dkdv``) sum the same products in the same
+    order as the fused one: the three gradients agree to the last bit."""
+    inputs, segs, causal, _, _ = _backward_case(case)
+    fused = _backward(inputs, segs, causal)
+    monkeypatch.setattr(fa, "_VMEM", 0)      # no head fits: the far side
+    split = _backward(inputs, segs, causal)
+    for a, b in zip(fused, split):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("shape, dv, dtype, segments, fused", [
+    # the two latent cells, OLMoE's and the widest of the VMEM-fit cases
+    # (tests/test_olmoe.py::test_the_kernels_tiles_fit_the_v5es_vmem)
+    ((1, 8192, 16, 192), 128, jnp.bfloat16, False, True),
+    ((4, 2048, 16, 128), 128, jnp.bfloat16, False, True),
+    ((1, 32768, 8, 128), 128, jnp.bfloat16, True, True),
+    ((2, 8192, 8, 256), 256, jnp.float32, True, True),
+    ((8, 512, 12, 64), 64, jnp.bfloat16, True, True),
+    # the far side: dq's float32 accumulator alone is 134 MB, 67 MB
+    ((1, 131072, 2, 192), 128, jnp.bfloat16, False, False),
+    ((1, 131072, 2, 64), 64, jnp.float32, True, False),
+    # ... and the longest that still fits half of VMEM
+    ((1, 65536, 2, 128), 128, jnp.bfloat16, False, True),
+])
+def test_the_backward_form_follows_from_the_shapes(shape, dv, dtype, segments,
+                                                   fused):
+    """One backward kernel wherever the head's float32 dq accumulator, the
+    blocks and a tile's temporaries fit half of a v5e core's VMEM, the two
+    kernels past that: read off the shapes alone as the gradient is TRACED
+    (nothing runs), and counted by form in the telemetry."""
+    B, S, H, D = shape
+    need = fa._fused_vmem(S, D, dv, 512, 512, dtype, segments)
+    assert (need <= 64 << 20) == fused
+    # the accumulator's lanes are padded to whole 128s: 192 takes 256
+    assert need > S * -(-D // 128) * 128 * 4
+    x = jax.ShapeDtypeStruct(shape, dtype)
+    vv = jax.ShapeDtypeStruct((B, S, H, dv), dtype)
+    seg = jax.ShapeDtypeStruct((B, S), jnp.int32) if segments else None
+
+    def loss(q, k, v, seg):
+        return jnp.sum(flash_attention(q, k, v, True, seg)
+                       .astype(jnp.float32))
+    before = tel.counters()
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(x, x, vv, seg).jaxpr
+    calls = {name: kernel_calls(jaxpr, name)
+             for name in ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkdv")}
+    assert calls == {"flash_fwd": 1, "flash_bwd": int(fused),
+                     "flash_dq": int(not fused), "flash_dkdv": int(not fused)}
+    after = tel.counters()
+    counted = {form: after.get("attention.flash_bwd_" + form, 0)
+               - before.get("attention.flash_bwd_" + form, 0)
+               for form in ("fused", "split")}
+    assert counted == {"fused": int(fused), "split": int(not fused)}

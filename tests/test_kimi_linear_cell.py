@@ -210,12 +210,14 @@ def test_the_loss_and_its_gradient_trace_to_the_parents_jaxpr(before, which):
 
 
 def test_the_kernel_with_equal_widths_is_the_kernel_it_was():
-    """q, k and v of one width lower to the same three pallas calls (block
-    shapes and scratch) as before the value width became its own."""
+    """q, k and v of one width lower to the same pallas calls (block shapes
+    and scratch) as before the value width became its own: the forward
+    kernel and, since PR 34, ONE backward kernel where there were two."""
     x = jnp.zeros((1, 64, 2, 16), jnp.float32)
     text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v, causal=True)), argnums=(0, 1, 2)))(x, x, x))
-    assert text.count("pallas_call") == 3
+    assert text.count("pallas_call") == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
     assert "192" not in text and text.count("(64, 16)") > 0
     fn = make_flash_attn_fn(causal=True)
     assert fn(x, x, x).shape == x.shape
@@ -299,7 +301,8 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     CHIP (``benchmark/tools/aot_compile_as_on_the_chip.py``: the backend
     probe answers "tpu", the chip's memory is the v5e's, the kernels lower
     as Mosaic calls), at the published widths and 1 x 8,192 tokens: the
-    three flash kernels at 192 / 128 and the delta rule's two kernels per
+    two flash kernels at 192 / 128 (``flash_fwd`` and, since PR 34, the one
+    backward kernel ``flash_bwd``) and the delta rule's two kernels per
     KDA layer, each ONCE (a recomputed block keeps by name what their
     backward kernels read of the forward kernels' results), no
     triangular solve and no loop of the core left to XLA, every held
@@ -321,15 +324,16 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     finally:
         autodist_tpu.reset()
     text = compiled[0].as_text()
-    assert all(name in text for name in ("flash_fwd", "flash_dq",
-                                         "flash_dkdv"))
-    # the three flash kernels, four KDA layers' forward and backward
+    assert all(name in text for name in ("flash_fwd", "flash_bwd"))
+    assert not any(name in text for name in ("flash_dq", "flash_dkdv"))
+    # the two flash kernels, four KDA layers' forward and backward
     # kernels; a share of the experts runs no grouped-matmul kernel
     # (expert.py:_held_experts)
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 3 + 4 + 4
+    assert len(calls) == 2 + 4 + 4
     assert sum("flash_fwd" in line for line in calls) == 1
+    assert sum("flash_bwd" in line for line in calls) == 1
     assert sum("kda_fwd" in line for line in calls) == 4
     assert sum("kda_bwd" in line for line in calls) == 4
     assert all("kda_scan" in line for line in calls if "kda_" in line)

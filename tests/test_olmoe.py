@@ -651,7 +651,7 @@ def test_the_routed_layer_compiles_for_a_v5e_at_the_published_widths(
 
 
 def test_flash_attention_compiles_for_a_v5e_at_the_cells_shape(one_v5e_chip):
-    """The forward and both backward kernels at ``olmoe_train_1chip``'s
+    """The forward and the backward kernel at ``olmoe_train_1chip``'s
     attention shape ([4, 2048, 16, 128] bfloat16, causal) through Mosaic
     for a described chip: a tile ``_tiles`` chooses that the kernels' VMEM
     cannot hold fails here and not on the chip."""
@@ -667,7 +667,8 @@ def test_flash_attention_compiles_for_a_v5e_at_the_cells_shape(one_v5e_chip):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             x, x, x).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # flash_fwd and, since PR 34, ONE backward kernel, flash_bwd
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     # no [B, H, S, S] tensor: out, lse and delta are all it keeps
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
@@ -688,7 +689,9 @@ def test_flash_attention_compiles_for_a_v5e_at_the_cells_shape(one_v5e_chip):
 ])
 def test_the_kernels_tiles_fit_the_v5es_vmem(one_v5e_chip, shape, dtype,
                                              segments):
-    """Forward and both backward kernels, causal, compile for the described
+    """The forward kernel and the ONE backward kernel (PR 34: dq's float32
+    accumulator of the whole head in VMEM beside the tiles, its
+    ``vmem_limit_bytes`` from the shapes), causal, compile for the described
     chip at the one tile size ``_tiles`` gives, over head widths, dtypes,
     segment ids and batch x heads (the fit of a larger tile moved with all
     four: a device-less reading, PERF.md section 6, PR 28)."""
@@ -704,8 +707,9 @@ def test_the_kernels_tiles_fit_the_v5es_vmem(one_v5e_chip, shape, dtype,
     with pallas_mode.compiling_for_tpu():
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             x, x, x, seg).compile()
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 3
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
 
 
 def test_the_cells_whole_step_compiles_with_its_kernels(v5e_2x2, monkeypatch):
@@ -713,7 +717,8 @@ def test_the_cells_whole_step_compiles_with_its_kernels(v5e_2x2, monkeypatch):
     for the described chip with every pallas kernel as a Mosaic call
     (``benchmark/tools/aot_compile.py`` under ``compiling_for_tpu``): nine
     grouped expert matmuls and, since ``attention="auto"`` puts seq 2048 x
-    heads of 128 on the kernel, attention's three. PR 25 lost a chip call
+    heads of 128 on the kernel, attention's two (three until PR 34 made
+    the backward one kernel). PR 25 lost a chip call
     to a tile that exhausted VMEM only inside the whole step. The rule's
     backend probe is steered here: JAX's default backend is the CPU."""
     import sys
@@ -736,9 +741,9 @@ def test_the_cells_whole_step_compiles_with_its_kernels(v5e_2x2, monkeypatch):
     finally:
         autodist_tpu.reset()
     text = compiled[0].as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 12
-    assert sum(name in text for name in
-               ("flash_fwd", "flash_dq", "flash_dkdv")) == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 11
+    assert all(name in text for name in ("flash_fwd", "flash_bwd"))
+    assert not any(name in text for name in ("flash_dq", "flash_dkdv"))
     # state + step scratch fit one chip's 16 GB with room to spare
     assert out["train_step"]["live_bytes_estimate"] < 14 << 30
     assert out["train_step"]["temp_size_in_bytes"] < 4426620928  # PR 27's
