@@ -118,8 +118,70 @@ class SparseEmbed(nn.Module):
         return embedding_lookup(table.astype(self.dtype), ids, name=name)
 
 
+@dataclasses.dataclass(frozen=True)
+class IndexerConfig:
+    """A learned sparse attention's indexer (``sa_config``): ``num_heads``
+    heads of ``head_dim`` over ONE key head choose ``topk`` keys a query,
+    its scores made ``q_chunk`` queries at a time. ``rope_dim`` of the
+    features are rotated (by the attention's ``rope_theta``)."""
+    num_heads: int
+    head_dim: int
+    topk: int
+    q_chunk: int = 512
+    rope_dim: int = 0
+
+
+class SparseIndexer(nn.Module):
+    """DeepSeek Sparse Attention's indexer on the block's normed input x
+    with the gradient STOPPED (the choice is discrete: the NLL has no
+    gradient to these weights; DeepSeek-V3.2 trains them by a loss of
+    their own, which no config here carries): ``q_idx = x W_q`` in heads,
+    ``k_idx = LayerNorm(x W_k)`` one head, ``w = x W_w`` a weight a head;
+    the first ``rope_dim`` features of q_idx and k_idx rotated by
+    position. Returns ``ops/dsa.py:chosen_keys``' selection [B, S, S] and
+    sows ``selected_pairs`` / ``causal_pairs`` into ``counters``. All in
+    float32, whatever the model's dtype."""
+    cfg: IndexerConfig
+    rope_theta: Optional[float] = None
+
+    @nn.compact
+    def __call__(self, x, positions):
+        from autodist_tpu.ops import dsa
+        c = self.cfg
+        x = jax.lax.stop_gradient(x).astype(jnp.float32)
+        with scopes.scope(scopes.DSA_INDEX):
+            dense = lambda n, name: nn.Dense(  # noqa: E731
+                n, use_bias=False, precision=jax.lax.Precision.HIGHEST,
+                name=name)
+            q = dense(c.num_heads * c.head_dim, "wq")(x).reshape(
+                x.shape[:-1] + (c.num_heads, c.head_dim))
+            k = nn.LayerNorm(epsilon=1e-6, name="k_norm")(
+                dense(c.head_dim, "wk")(x))[..., None, :]
+            w = dense(c.num_heads, "weights_proj")(x)
+            if c.rope_dim and self.rope_theta is not None:
+                def turn(t):
+                    pe, nope = jnp.split(t, [c.rope_dim], axis=-1)
+                    return jnp.concatenate(
+                        [rope(pe, positions, self.rope_theta), nope], axis=-1)
+                q, k = turn(q), turn(k)
+        chosen = dsa.chosen_keys(q, k[..., 0, :], w, c.topk, c.q_chunk)
+        S = x.shape[-2]
+        self.sow("counters", "selected_pairs",
+                 jnp.sum(chosen, dtype=jnp.int32))
+        self.sow("counters", "causal_pairs",
+                 jnp.int32(x.size // x.shape[-1] * (S + 1) // 2))
+        return chosen
+
+
 class MultiHeadAttention(nn.Module):
-    """Standard MHA with an injectable attention implementation.
+    """Softmax attention over heads with an injectable attention
+    implementation: ``num_heads`` query heads of ``head_dim`` (its own
+    size, not ``d_model / num_heads``) over as many K/V heads, or over
+    ``num_kv_heads`` fewer that groups of query heads share (grouped-query
+    attention: query head h reads K/V head ``h // (num_heads /
+    num_kv_heads)``); optionally an RMSNorm of q and k (over all projected
+    features, OLMoE's, or per head, Qwen3's), rotary positions, and a
+    learned choice of the keys each query attends (``indexer``).
 
     Three modes share one parameter set (submodules are created in the
     same order on every path, so flax resolves identical names):
@@ -144,17 +206,27 @@ class MultiHeadAttention(nn.Module):
     # split (OLMoE's QK-norm); None = off
     qk_norm_eps: Optional[float] = None
     rope_theta: Optional[float] = None  # rotary q and k; needs positions
+    num_kv_heads: Optional[int] = None  # None = as many as query heads
+    # RMSNorm over each head's ``head_dim`` features of q and of k, one
+    # learned weight shared by the heads (Qwen3's); None = off
+    head_norm_eps: Optional[float] = None
+    # a learned choice of the keys each query attends; None = all it sees
+    indexer: Optional[IndexerConfig] = None
 
     @nn.compact
     def __call__(self, x, mask=None, cache=None, cursor=None, alive=None,
                  return_kv=False, positions=None):
         d_model = x.shape[-1]
-        dense = lambda name: nn.DenseGeneral(  # noqa: E731
-            features=(self.num_heads, self.head_dim), dtype=self.dtype,
+        kv_heads = self.num_kv_heads or self.num_heads
+        if self.num_heads % kv_heads:
+            raise ValueError("%d query heads do not share %d K/V heads"
+                             % (self.num_heads, kv_heads))
+        dense = lambda name, heads=self.num_heads: nn.DenseGeneral(  # noqa: E731
+            features=(heads, self.head_dim), dtype=self.dtype,
             axis=-1, use_bias=self.use_bias, name=name)
         q = dense("query")(x)
-        k = dense("key")(x)
-        v = dense("value")(x)
+        k = dense("key", kv_heads)(x)
+        v = dense("value", kv_heads)(x)
         if self.qk_norm_eps is not None:
             def full_width_norm(t, name):
                 flat = t.reshape(t.shape[:-2] + (-1,))
@@ -162,13 +234,27 @@ class MultiHeadAttention(nn.Module):
                                  name)(flat).reshape(t.shape)
             q = full_width_norm(q, "q_norm")
             k = full_width_norm(k, "k_norm")
+        if self.head_norm_eps is not None:
+            q = make_norm("rmsnorm", self.head_norm_eps, self.dtype,
+                          "q_norm")(q)
+            k = make_norm("rmsnorm", self.head_norm_eps, self.dtype,
+                          "k_norm")(k)
         if self.rope_theta is not None:
             if positions is None:
                 raise ValueError("rotary attention needs positions")
             q = rope(q, positions, self.rope_theta)
             k = rope(k, positions, self.rope_theta)
         new_cache = None
-        if cache is not None:
+        grouped_or_chosen = (self.indexer is not None
+                             or kv_heads != self.num_heads)
+        if grouped_or_chosen and (cache is not None or return_kv):
+            raise NotImplementedError(
+                "prefill and cached decode keep as many K/V rows as query "
+                "heads and attend all of them: grouped K/V heads and an "
+                "indexer's own key cache have no decode path yet")
+        if grouped_or_chosen:
+            out = self._grouped_or_chosen(x, q, k, v, mask, positions)
+        elif cache is not None:
             from autodist_tpu.ops.attention import (cached_attention,
                                                     flash_cached_attention)
             if cursor is None:
@@ -203,6 +289,29 @@ class MultiHeadAttention(nn.Module):
         if return_kv:
             return out, (k, v)
         return out
+
+    def _grouped_or_chosen(self, x, q, k, v, mask, positions):
+        """The core over K/V heads that groups of query heads share and,
+        with an indexer, over the keys it chose: through ``attn_fn`` (the
+        flash kernels take both as they are) or XLA's scores with the K/V
+        heads repeated."""
+        from autodist_tpu.ops.attention import reference_attention
+        chosen = None
+        if self.indexer is not None:
+            chosen = SparseIndexer(self.indexer, self.rope_theta,
+                                   name="indexer")(x, positions)
+        with scopes.scope(scopes.DSA_CORE):
+            if self.attn_fn is not None:
+                # (an attention function that knows no selection still
+                # serves grouped heads)
+                return self.attn_fn(q, k, v, mask, **(
+                    {} if chosen is None else {"select": chosen}))
+            group = self.num_heads // k.shape[-2]
+            k, v = (jnp.repeat(t, group, axis=-2) for t in (k, v))
+            if chosen is not None:
+                chosen = (chosen != 0)[:, None]
+                mask = chosen if mask is None else mask & chosen
+            return reference_attention(q, k, v, mask)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -499,6 +608,9 @@ class TransformerBlock(nn.Module):
     mla: Optional[MLAConfig] = None
     dense_dim: int = 0
     router: RouterConfig = RouterConfig()
+    num_kv_heads: Optional[int] = None
+    qk_head_norm: bool = False
+    indexer: Optional[IndexerConfig] = None
 
     def _mix(self, h, mask, cache, cursor, alive, return_kv, positions):
         """The block's token mixer on the normed input."""
@@ -507,7 +619,9 @@ class TransformerBlock(nn.Module):
                 self.num_heads, self.head_dim, self.dtype, self.attn_fn,
                 decode_attn=self.decode_attn, use_bias=self.attention_bias,
                 qk_norm_eps=self.norm_eps if self.qk_norm else None,
-                rope_theta=self.rope_theta)(
+                rope_theta=self.rope_theta, num_kv_heads=self.num_kv_heads,
+                head_norm_eps=self.norm_eps if self.qk_head_norm else None,
+                indexer=self.indexer)(
                 h, mask, cache=cache, cursor=cursor, alive=alive,
                 return_kv=return_kv, positions=positions)
         if cache is not None or return_kv:

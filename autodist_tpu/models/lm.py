@@ -20,10 +20,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from autodist_tpu.models.layers import (KDA_CORE_OUT, KDAConfig, MLAConfig,
-                                        RouterConfig, SparseEmbed,
-                                        TransformerBlock, YarnConfig,
-                                        causal_mask, make_norm)
+from autodist_tpu.models.layers import (KDA_CORE_OUT, IndexerConfig,
+                                        KDAConfig, MLAConfig, RouterConfig,
+                                        SparseEmbed, TransformerBlock,
+                                        YarnConfig, causal_mask, make_norm)
 from autodist_tpu.telemetry import spans as tel
 from autodist_tpu.telemetry import device_counters, scopes
 
@@ -33,6 +33,9 @@ ROUTER_LOAD = ("max_expert_pairs", "routed_pairs")
 # ... and a layer that holds a share of its experts: every pair its
 # router chose, held here or not
 SHARE_LOAD = ROUTER_LOAD + ("chosen_pairs",)
+# what a sparse attention's indexer sows and the loss reports as
+# ``dsa.<name>``: the (query, key) pairs it chose, and all a query sees
+INDEXER_CHOICE = ("selected_pairs", "causal_pairs")
 LAYER_TYPES = ("attention", "kda", "mla")
 YARN_KEYS = tuple(f.name for f in dataclasses.fields(YarnConfig))
 
@@ -59,6 +62,22 @@ class LMConfig:
     # own, on latent attention's rotary features)
     rope_scaling: Optional[dict] = None
     qk_norm: bool = False           # RMSNorm over the projected q and k
+    # the softmax attention's heads where they are not ``num_heads`` of
+    # ``d_model / num_heads``: a head's size of its own, fewer K/V heads
+    # that groups of query heads share, and the RMSNorm of q and k taken
+    # per head (one weight of ``head_dim``) instead of ``qk_norm``'s
+    head_dim: Optional[int] = None
+    num_kv_heads: Optional[int] = None
+    qk_head_norm: bool = False
+    # a learned sparse attention (``sa_config``): > 0 heads of an indexer
+    # that chooses ``indexer_topk`` keys for every query, its scores made
+    # ``indexer_q_chunk`` queries at a time, ``indexer_rope_dim`` of its
+    # features rotated; 0 = every query attends all it sees
+    indexer_num_heads: int = 0
+    indexer_head_dim: int = 0
+    indexer_topk: int = 0
+    indexer_q_chunk: int = 512
+    indexer_rope_dim: int = 0
     attention_bias: bool = True
     head_bias: bool = True
     embed_scale: bool = True        # token embedding x sqrt(d_model)
@@ -153,6 +172,17 @@ class LMConfig:
                     "rope_scaling (YaRN) blends the frequencies of latent "
                     "attention's rotary features: it needs rope_theta and "
                     "layer_types of 'mla' alone")
+        if self.indexer_num_heads and not (
+                self.indexer_head_dim and self.indexer_topk
+                and self.indexer_rope_dim <= self.indexer_head_dim
+                and set(types or ("attention",)) == {"attention"}):
+            raise ValueError(
+                "an indexer chooses keys for the softmax attention: it needs "
+                "indexer_head_dim, indexer_topk and layer_types of "
+                "'attention' alone")
+        if self.qk_norm and self.qk_head_norm:
+            raise ValueError("qk_norm (over all features) or qk_head_norm "
+                             "(per head), not both")
         held = self.experts_held
         if held is not None and (
                 not held or len(set(held)) != len(held)
@@ -239,6 +269,32 @@ class LMConfig:
                    router_aux_loss_coef=0.001, seq_aux=True, **kw)
 
     @classmethod
+    def keye_vl2_30b_a3b(cls, **kw):
+        """Keye-VL-2.0-30B-A3B's language model as its ``config.json``
+        publishes it (huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B), text
+        only: 48 pre-norm RMSNorm layers without a bias, each 32 query
+        heads over 4 K/V heads of 128 (4,096 query features from a hidden
+        size of 2,048) with a per-head RMSNorm of q and k and RoPE at theta
+        1e7 (M-RoPE's sections are plain RoPE for text), a sparse
+        attention indexer (16 heads of 64 over one key head) that chooses
+        2,048 keys for every query, and 128 softmax-routed SwiGLU experts
+        of 768, 8 a token, gates renormalised over the chosen, no shared
+        expert; an untied head. The per-head norm and the indexer's
+        LayerNorm and half-rotated features are assumptions the
+        benchmark's configuration file lists."""
+        kw.setdefault("num_layers", 48)
+        kw.setdefault("max_seq_len", 262144)
+        return cls(vocab_size=151936, d_model=2048, num_heads=32,
+                   head_dim=128, num_kv_heads=4, qk_head_norm=True,
+                   mlp_dim=768, norm="rmsnorm", norm_eps=1e-6,
+                   rope_theta=1e7, attention_bias=False, head_bias=False,
+                   embed_scale=False, indexer_num_heads=16,
+                   indexer_head_dim=64, indexer_topk=2048,
+                   indexer_q_chunk=512, indexer_rope_dim=32,
+                   num_experts=128, experts_per_token=8,
+                   moe_renormalize=True, **kw)
+
+    @classmethod
     def tiny(cls, **kw):
         return cls(vocab_size=128, d_model=32, num_layers=2, num_heads=2,
                    mlp_dim=64, max_seq_len=64, **kw)
@@ -286,6 +342,15 @@ class TransformerLM(nn.Module):
             kw["mla"] = MLAConfig(cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                                   cfg.qk_rope_head_dim, cfg.v_head_dim,
                                   cfg.rope_theta, yarn)
+        if kind == "attention" and (cfg.num_kv_heads or cfg.qk_head_norm
+                                    or cfg.indexer_num_heads):
+            kw.update(num_kv_heads=cfg.num_kv_heads,
+                      qk_head_norm=cfg.qk_head_norm,
+                      indexer=IndexerConfig(
+                          cfg.indexer_num_heads, cfg.indexer_head_dim,
+                          cfg.indexer_topk, cfg.indexer_q_chunk,
+                          cfg.indexer_rope_dim)
+                      if cfg.indexer_num_heads else None)
         if i < cfg.first_k_dense_replace:
             kw["dense_dim"] = cfg.dense_dim
         elif cfg.num_experts:
@@ -299,16 +364,20 @@ class TransformerLM(nn.Module):
         # per-chunk states; the flash kernel's output and log-sum-exp,
         # 33.5 MB a layer at 16 heads of 128 and seq 8192 against a
         # forward kernel of 3.55 ms, and its q, 50 MB against 1.5 ms of
-        # projection and rotation. A name no op of the block carries
+        # projection and rotation; a sparse attention's choice of keys,
+        # 67 MB a layer at seq 8192 against its index scores and a choice
+        # among them for every query. A name no op of the block carries
         # keeps nothing)
+        from autodist_tpu.ops.dsa import KEPT as DSA_CHOICE_KEPT
         from autodist_tpu.ops.flash_attention import KEPT as FLASH_CORE_KEPT
         block = nn.remat(
             TransformerBlock,
             policy=jax.checkpoint_policies.save_only_these_names(
-                KDA_CORE_OUT, FLASH_CORE_KEPT)
+                KDA_CORE_OUT, FLASH_CORE_KEPT, DSA_CHOICE_KEPT)
         ) if self.remat_blocks else TransformerBlock
         return block(
-            cfg.num_heads, cfg.d_model // cfg.num_heads, cfg.mlp_dim,
+            cfg.num_heads, cfg.head_dim or cfg.d_model // cfg.num_heads,
+            cfg.mlp_dim,
             dtype=cfg.dtype, norm=cfg.norm, norm_eps=cfg.norm_eps,
             attention_bias=cfg.attention_bias, qk_norm=cfg.qk_norm,
             rope_theta=cfg.rope_theta, num_experts=cfg.num_experts,
@@ -522,7 +591,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     types = cfg.layer_types or ("attention",) * cfg.num_layers
     # the widest scores a softmax layer of this model contracts over
     head_dim = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-                if "mla" in types else cfg.d_model // cfg.num_heads)
+                if "mla" in types
+                else cfg.head_dim or cfg.d_model // cfg.num_heads)
     if attention == "flash" or (attention == "auto" and auto_flash_attention(
             seq_len, head_dim, jax.default_backend())):
         from autodist_tpu.ops.flash_attention import make_flash_attn_fn
@@ -549,19 +619,28 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     router_losses = cfg.router_activation == "softmax"
     # (leading dense layers route nothing)
     routed = cfg.num_experts and cfg.first_k_dense_replace < cfg.num_layers
+    indexed = bool(cfg.indexer_num_heads)
 
     def forward(params, ids, method):
         """(the method's output, the router losses' weighted sum). The
-        layers' load goes to the step's device counters from HERE, the
-        loss's own trace (``telemetry/device_counters.py``)."""
-        if not routed:
+        layers' load and an indexer's choice go to the step's device
+        counters from HERE, the loss's own trace
+        (``telemetry/device_counters.py``)."""
+        if not (routed or indexed):
             return model.apply(params, ids, method=method), None
         out, sown = model.apply(params, ids, method=method,
                                 mutable=["losses", "counters"])
         for layer in sown["counters"].values():
-            for name in router_load:
-                device_counters.add("moe." + name, layer["moe"][name][0])
-        if not router_losses:
+            if "moe" in layer:
+                for name in router_load:
+                    device_counters.add("moe." + name, layer["moe"][name][0])
+            for mixer in layer.values():
+                if "indexer" in mixer:
+                    for name in INDEXER_CHOICE:
+                        device_counters.add("dsa." + name,
+                                            mixer["indexer"][name][0])
+        if not (routed and router_losses and (
+                cfg.router_aux_loss_coef or cfg.router_z_loss_coef)):
             return out, None
         per_layer = sown["losses"].values()
         lb = sum(layer["moe"]["router_lb"][0] for layer in per_layer)
@@ -601,9 +680,14 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         return mean_loss(nll, router_loss)
 
+    declared = []
     if routed:
-        loss_fn.device_counters = tuple("moe." + n for n in router_load) + (
-            ("moe.aux_loss",) if cfg.seq_aux else ())
+        declared += ["moe." + n for n in router_load]
+        declared += ["moe.aux_loss"] if cfg.seq_aux else []
+    if indexed:
+        declared += ["dsa." + n for n in INDEXER_CHOICE]
+    if declared:
+        loss_fn.device_counters = tuple(declared)
 
     npr = np.random.RandomState(seed)
     example_batch = {"tokens": npr.randint(

@@ -124,27 +124,32 @@ def full_tiles(seq: int) -> bool:
 
 # ----------------------------------------------------------------- layout
 
-def _specs(D, Dv, bq, bk, q_pos, k_pos):
+def _specs(D, Dv, bq, bk, q_pos, k_pos, group=1):
     """BlockSpecs of one grid (b, h, i, j) over [B, H, S, .] operands: a
     [rows, D] tile of q and of k, a [rows, Dv] tile of v and of the
     output (the values may be narrower or wider than the scores'
     features: latent attention scores over 192 and carries 128), a
-    [rows, 1] tile of a per-row q-side vector ([B, H, S, 1]), and the
+    [rows, 1] tile of a per-row q-side vector ([B, H, S, 1]), the
     [rows, 1] q / kv segment ids ([B, S, 1]: the trailing 1 satisfies the
-    TPU's (8, 128) rule as for the row vectors). ``q_pos(i, j)`` /
-    ``k_pos(i, j)`` give the row-block index. The kernels see the tiles
-    without leading dims."""
-    def tile(rows, pos, width):
+    TPU's (8, 128) rule as for the row vectors), and the [bq, bk] tile of
+    a selection ([B, Sq, Sk], every head's alike). ``q_pos(i, j)`` /
+    ``k_pos(i, j)`` give the row-block index; query head h reads K/V head
+    ``h // group`` of [B, H / group, S, .] (nothing is repeated in HBM).
+    The kernels see the tiles without leading dims."""
+    def tile(rows, pos, width, head=lambda h: h):
         return pl.BlockSpec((None, None, rows, width),
-                            lambda b, h, i, j: (b, h, pos(i, j), 0))
+                            lambda b, h, i, j: (b, head(h), pos(i, j), 0))
 
     def seg(rows, pos):
         return pl.BlockSpec((None, rows, 1),
                             lambda b, h, i, j: (b, pos(i, j), 0))
 
-    return (tile(bq, q_pos, D), tile(bk, k_pos, D), tile(bk, k_pos, Dv),
-            tile(bq, q_pos, Dv), tile(bq, q_pos, 1),
-            seg(bq, q_pos), seg(bk, k_pos))
+    kv_head = (lambda h: h) if group == 1 else (lambda h: h // group)
+    sel = pl.BlockSpec((None, bq, bk),
+                       lambda b, h, i, j: (b, q_pos(i, j), k_pos(i, j)))
+    return (tile(bq, q_pos, D), tile(bk, k_pos, D, kv_head),
+            tile(bk, k_pos, Dv, kv_head), tile(bq, q_pos, Dv),
+            tile(bq, q_pos, 1), seg(bq, q_pos), seg(bk, k_pos), sel)
 
 
 def _kv_pos(causal, bq, bk):
@@ -186,15 +191,23 @@ def _tile_live(qi, ki, bq, bk, causal, qs, ks):
     return live
 
 
-def _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, body):
+def _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, sel_ref, body):
     """Run ``body(mask)`` on a tile with a visible entry; ``mask(s)`` masks
-    a score tile [bq, bk]."""
+    a score tile [bq, bk]. A selection masks elementwise within the tile
+    (its [bq, bk] int8 block, non-zero = attend) and skips none: whether a
+    tile holds a chosen pair is not known without reading it."""
     qs = None if qs_ref is None else qs_ref[:, 0]
     ks = None if ks_ref is None else ks_ref[:, 0]
+
+    def mask(s):
+        s = _mask_val(s, qi, ki, bq, bk, causal, qs, ks)
+        if sel_ref is None:
+            return s
+        return jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, NEG_INF)
+
     if not causal and qs is None:
-        return body(lambda s: s)
-    pl.when(_tile_live(qi, ki, bq, bk, causal, qs, ks))(
-        lambda: body(lambda s: _mask_val(s, qi, ki, bq, bk, causal, qs, ks)))
+        return body(mask if sel_ref is not None else (lambda s: s))
+    pl.when(_tile_live(qi, ki, bq, bk, causal, qs, ks))(lambda: body(mask))
 
 
 def _scores(q, k, scale, mask):
@@ -206,13 +219,19 @@ def _scores(q, k, scale, mask):
 
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
-    if has_seg:
-        (q_ref, k_ref, v_ref, qs_ref, ks_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-        qs_ref = ks_ref = None
+def _split_refs(refs, n_fixed, has_seg, has_sel):
+    """(the fixed operands, q / kv segment ids or None, the selection or
+    None, results and scratch) of a kernel's refs."""
+    n_in = n_fixed + 2 * has_seg + has_sel
+    qs_ref, ks_ref = refs[n_fixed:n_fixed + 2] if has_seg else (None, None)
+    sel_ref = refs[n_in - 1] if has_sel else None
+    return refs[:n_fixed], qs_ref, ks_ref, sel_ref, refs[n_in:]
+
+
+def _fwd_kernel(*refs, scale, causal, has_seg, has_sel, bq, bk, n_kv):
+    (q_ref, k_ref, v_ref), qs_ref, ks_ref, sel_ref, (
+        o_ref, lse_ref, acc_ref, m_ref, l_ref) = _split_refs(
+            refs, 3, has_seg, has_sel)
     qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -238,12 +257,12 @@ def _fwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
         p = jnp.exp(s - lanes(m_new, bk))                # [bq, bk]
-        if has_seg:
+        if has_seg or has_sel:
             # a row with NO visible key so far has m_new == NEG_INF and
             # every score masked: exp(NEG_INF - NEG_INF) = 1 would average
             # garbage values into the row — zero its contribution (empty
             # rows emit 0). Causal rows all see column 0 in their first
-            # tile, so only segments can leave a row empty
+            # tile, so only segments or a selection can leave a row empty
             p = jnp.where(lanes(m_new, bk) > NEG_INF * 0.5, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_prev * corr + jnp.sum(p, axis=1)[:, None]
@@ -253,7 +272,7 @@ def _fwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, tile)
+    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, sel_ref, tile)
 
     @pl.when(ki == n_kv - 1)
     def _():
@@ -267,28 +286,33 @@ def _fwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
             l > 0, m_ref[:, :1] + jnp.log(jnp.maximum(l, 1e-30)), 0.0)
 
 
-def _fwd(q, k, v, segs, causal):
-    """q, k [B, H, S, D], v [B, H, S, Dv] (kernel-internal layout); segs is
-    None or (q_seg [B, Sq], kv_seg [B, Sk]) int32. Returns (out
+def _fwd(q, k, v, segs, sel, causal):
+    """q [B, H, S, D], k [B, H / group, S, D], v [B, H / group, S, Dv]
+    (kernel-internal layout); segs is None or (q_seg [B, Sq], kv_seg
+    [B, Sk]) int32; sel None or [B, Sq, Sk] int8. Returns (out
     [B, H, Sq, Dv], lse [B, H, Sq, 1])."""
     B, H, Sq, D = q.shape
     Sk, Dv = k.shape[2], v.shape[3]
-    has_seg = segs is not None
+    has_seg, has_sel = segs is not None, sel is not None
     bq, bk = _tiles(Sq, Sk)
     n_q, n_kv = Sq // bq, Sk // bk
 
-    q_spec, k_spec, v_spec, o_spec, row_spec, qs_spec, ks_spec = _specs(
-        D, Dv, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk))
+    (q_spec, k_spec, v_spec, o_spec, row_spec, qs_spec, ks_spec,
+     sel_spec) = _specs(D, Dv, bq, bk, lambda i, j: i,
+                        _kv_pos(causal, bq, bk), H // k.shape[1])
     in_specs = [q_spec, k_spec, v_spec]
     operands = [q, k, v]
     if has_seg:
         in_specs += [qs_spec, ks_spec]
         operands += [segs[0][..., None], segs[1][..., None]]
+    if has_sel:
+        in_specs.append(sel_spec)
+        operands.append(sel)
 
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=float(1.0 / np.sqrt(D)),
-                          causal=causal, has_seg=has_seg, bq=bq, bk=bk,
-                          n_kv=n_kv),
+                          causal=causal, has_seg=has_seg, has_sel=has_sel,
+                          bq=bq, bk=bk, n_kv=n_kv),
         grid=(B, H, n_q, n_kv),
         in_specs=in_specs,
         out_specs=[o_spec, row_spec],
@@ -316,14 +340,9 @@ def _p_and_ds(q, k, v, do, lse, delta, scale, mask):
     return p, p * (dp - delta)
 
 
-def _dq_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
-    if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref, ks_ref,
-         dq_ref, acc_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, acc_ref) = refs
-        qs_ref = ks_ref = None
+def _dq_kernel(*refs, scale, causal, has_seg, has_sel, bq, bk, n_kv):
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), qs_ref, ks_ref, \
+        sel_ref, (dq_ref, acc_ref) = _split_refs(refs, 6, has_seg, has_sel)
     qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -338,26 +357,26 @@ def _dq_kernel(*refs, scale, causal, has_seg, bq, bk, n_kv):
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, tile)
+    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, sel_ref, tile)
 
     @pl.when(ki == n_kv - 1)
     def _():
         dq_ref[...] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_q, n_kv, fused):
+def _bwd_kernel(*refs, scale, causal, has_seg, has_sel, bq, bk, n_q, n_kv,
+                fused):
     """dk and dv of one kv block, summed over its q blocks in float32
     scratch. ``fused``: dq too, from the SAME ``_p_and_ds`` of each tile:
     it sums over the kv blocks in ``dq_acc``, one float32 [bq, D] slab a q
     block, which stays in VMEM for the whole head and leaves it in the
     head's last kv pass."""
-    n_in = 8 if has_seg else 6
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    qs_ref, ks_ref = refs[6:n_in] if has_seg else (None, None)
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), qs_ref, ks_ref, \
+        sel_ref, outs = _split_refs(refs, 6, has_seg, has_sel)
     if fused:
-        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[n_in:]
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = outs
     else:
-        dk_ref, dv_ref, dk_acc, dv_acc = refs[n_in:]
+        dk_ref, dv_ref, dk_acc, dv_acc = outs
     ki, qi = pl.program_id(2), pl.program_id(3)
 
     @pl.when(qi == 0)
@@ -386,7 +405,7 @@ def _bwd_kernel(*refs, scale, causal, has_seg, bq, bk, n_q, n_kv, fused):
                 ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, tile)
+    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, sel_ref, tile)
 
     @pl.when(qi == n_q - 1)
     def _():
@@ -407,34 +426,39 @@ def _vmem_bytes(rows, cols, dtype):
     return -(-rows // sub) * sub * -(-cols // _LANES) * _LANES * item
 
 
-def _fused_vmem(Sq, D, Dv, bq, bk, dtype, has_seg):
+def _fused_vmem(Sq, D, Dv, bq, bk, dtype, has_seg, has_sel=False):
     """Bytes of VMEM the fused backward kernel asks for, from the shapes:
     the head's float32 dq accumulator, the two dk / dv accumulators, every
-    block the pipeline holds twice (operands, row vectors, segment ids,
-    the three results) and a tile's float32 [bq, bk] temporaries (scores,
-    P, dP, dS, the two operands cast for the MXU and their transposes)."""
+    block the pipeline holds twice (operands, row vectors, segment ids, a
+    selection's tile, the three results) and a tile's float32 [bq, bk]
+    temporaries (scores, P, dP, dS, the two operands cast for the MXU and
+    their transposes; a selection's tile widened to 32 bits)."""
     f32 = jnp.float32
     rows = 2 * _vmem_bytes(bq, 1, f32)               # lse, delta
     if has_seg:
         rows += _vmem_bytes(bq, 1, jnp.int32) + _vmem_bytes(bk, 1, jnp.int32)
+    if has_sel:
+        rows += _vmem_bytes(bq, bk, jnp.int8)
     blocks = (2 * _vmem_bytes(bq, D, dtype) + _vmem_bytes(bq, Dv, dtype)
               + 2 * _vmem_bytes(bk, D, dtype) + 2 * _vmem_bytes(bk, Dv, dtype)
               + rows)
     return (Sq // bq * _vmem_bytes(bq, D, f32)
             + _vmem_bytes(bk, D, f32) + _vmem_bytes(bk, Dv, f32)
-            + 2 * blocks + 8 * _vmem_bytes(bq, bk, f32))
+            + 2 * blocks + (9 if has_sel else 8) * _vmem_bytes(bq, bk, f32))
 
 
 def _bwd(causal, res, do):
-    """res tensors, do and the returned (dq, dk, dv) in [B, H, S, .]."""
-    q, k, v, out, lse, q_seg, kv_seg = res
+    """res tensors, do and the returned (dq, dk, dv) in [B, H, S, .] (dk
+    and dv [B, H / group, S, .] as k and v are)."""
+    q, k, v, out, lse, q_seg, kv_seg, sel = res
     B, H, Sq, D = q.shape
     Sk, Dv = k.shape[2], v.shape[3]
-    has_seg = q_seg is not None
+    has_seg, has_sel = q_seg is not None, sel is not None
+    group = H // k.shape[1]
     bq, bk = _tiles(Sq, Sk)
     n_q, n_kv = Sq // bq, Sk // bk
     static = dict(scale=float(1.0 / np.sqrt(D)), causal=causal,
-                  has_seg=has_seg, bq=bq, bk=bk)
+                  has_seg=has_seg, has_sel=has_sel, bq=bq, bk=bk)
 
     # delta_i = rowsum(dO_i * O_i): tiny elementwise reduce, XLA fuses it
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -443,27 +467,42 @@ def _bwd(causal, res, do):
     operands = [q, k, v, do, lse, delta]
     if has_seg:
         operands += [q_seg[..., None], kv_seg[..., None]]
+    if has_sel:
+        operands.append(sel)
 
-    def in_specs(q_spec, k_spec, v_spec, o_spec, row_spec, qs_spec, ks_spec):
+    def in_specs(q_spec, k_spec, v_spec, o_spec, row_spec, qs_spec, ks_spec,
+                 sel_spec):
         specs = [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec]
-        return specs + [qs_spec, ks_spec] if has_seg else specs
+        if has_seg:
+            specs += [qs_spec, ks_spec]
+        return specs + [sel_spec] if has_sel else specs
 
     # kv-major grid (b, h, i = kv block, j = q block): q is the reduction
     # (innermost) dim; a dead causal tile re-names the first live q block
     def q_pos(i, j):
         return jnp.clip(j, (i * bk) // bq, n_q - 1) if causal else j
 
-    kv_major = _specs(D, Dv, bq, bk, q_pos, lambda i, j: i)
+    kv_major = _specs(D, Dv, bq, bk, q_pos, lambda i, j: i, group)
     # one kernel or two? One, whenever what ``_fused_vmem`` counts fits half
     # the core's vector memory; a longer head's dq accumulator (seq 131,072
     # x 192 float32 is 134 MB) stays on the two kernels, whose dq lives a q
     # block at a time. Counted by form as the backward pass is traced
-    vmem = _fused_vmem(Sq, D, Dv, bq, bk, q.dtype, has_seg)
+    vmem = _fused_vmem(Sq, D, Dv, bq, bk, q.dtype, has_seg, has_sel)
     fused = vmem <= _VMEM // 2
     tel.counter_add("attention.flash_bwd_fused" if fused
                     else "attention.flash_bwd_split")
-    # (block, result like, float32 accumulator) of the kv-major kernel
-    outs = [(kv_major[1], k, (bk, D)), (kv_major[2], v, (bk, Dv))]
+    # (block, result like, float32 accumulator) of the kv-major kernel.
+    # K/V heads that ``group`` query heads share: each query head writes
+    # the dk / dv of ITS scores ([B, H, Sk, .], a q-shaped tile spec with
+    # kv rows), summed over the group below
+    if group == 1:
+        outs = [(kv_major[1], k, (bk, D)), (kv_major[2], v, (bk, Dv))]
+    else:
+        per_head = _specs(D, Dv, bq, bk, q_pos, lambda i, j: i)
+        outs = [(per_head[1], jax.ShapeDtypeStruct((B, H, Sk, D), k.dtype),
+                 (bk, D)),
+                (per_head[2], jax.ShapeDtypeStruct((B, H, Sk, Dv), v.dtype),
+                 (bk, Dv))]
     params = _PARAMS
     if fused:
         # dq's rows leave the accumulator in the head's last kv pass: until
@@ -488,10 +527,17 @@ def _bwd(causal, res, do):
         interpret=pallas_mode.interpret(),
         name="flash_bwd" if fused else "flash_dkdv",
     )(*operands)
+    if group > 1:
+        grads = list(grads)
+        for n, like in ((-2, k), (-1, v)):
+            g = grads[n].astype(jnp.float32)
+            grads[n] = jnp.sum(g.reshape((B, H // group, group) + g.shape[2:]),
+                               axis=2).astype(like.dtype)
     if fused:
         return tuple(grads)
 
-    specs = _specs(D, Dv, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk))
+    specs = _specs(D, Dv, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk),
+                   group)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, n_kv=n_kv, **static),
         grid=(B, H, n_q, n_kv),
@@ -521,29 +567,29 @@ def _heads_first(x):
     return x.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _flash(q, k, v, q_seg, kv_seg, causal):
-    return _flash_fwd(q, k, v, q_seg, kv_seg, causal)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _flash(q, k, v, q_seg, kv_seg, sel, causal):
+    return _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal)[0]
 
 
-def _flash_fwd(q, k, v, q_seg, kv_seg, causal):
+def _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal):
     qt, kt, vt = _heads_first(q), _heads_first(k), _heads_first(v)
     segs = None if q_seg is None else (q_seg, kv_seg)
     qt = checkpoint_name(qt, KEPT)
-    out, lse = _fwd(qt, kt, vt, segs, causal)
+    out, lse = _fwd(qt, kt, vt, segs, sel, causal)
     # (the log-sum-exp is named as [B, H, S]: as the kernels' [B, H, S, 1]
     # a row is padded to 128 lanes in HBM, 67 MB a layer at 16 heads and
     # seq 8192 where the values are 0.5; with the name on that form
     # Kimi-Linear's step asked for 0.23 GB more scratch)
     out = checkpoint_name(out, KEPT)
     lse = checkpoint_name(lse[..., 0], KEPT)[..., None]
-    return _heads_first(out), (qt, kt, vt, out, lse, q_seg, kv_seg)
+    return _heads_first(out), (qt, kt, vt, out, lse, q_seg, kv_seg, sel)
 
 
 def _flash_bwd(causal, res, do):
     grads = _bwd(causal, res, _heads_first(do))
-    return tuple(_heads_first(g) for g in grads) + (
-        _seg_zero_cot(res[5]), _seg_zero_cot(res[6]))
+    return tuple(_heads_first(g) for g in grads) + tuple(
+        _seg_zero_cot(ids) for ids in res[5:])
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -553,9 +599,20 @@ def _tileable(q, k):
     return all(_pick_block(x.shape[1], _ROWS) for x in (q, k))
 
 
-def flash_attention(q, k, v, causal: bool = False, segment_ids=None):
+def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
+                    select=None):
     """Exact fused attention. q, k: [B, S, H, D], v: [B, S, H, Dv] ->
-    [B, S, H, Dv]; scores are scaled by 1 / sqrt(D).
+    [B, S, H, Dv]; scores are scaled by 1 / sqrt(D). k and v may have
+    fewer heads than q, a divisor of H: query head h reads K/V head
+    ``h // (H / Hkv)`` (grouped-query attention; the kernels index the
+    shared head, nothing is repeated in HBM, and each query head's dk and
+    dv are summed over its group after the backward kernel).
+
+    ``select``: [B, Sq, Sk], non-zero = this query attends this key, every
+    head alike (a learned sparse attention's choice of keys; composes with
+    ``causal`` and the segment ids). It is read a [rows, rows] int8 tile
+    at a time and masks within the tile; no gradient flows to it. Every
+    query must keep at least one key.
 
     ``segment_ids``: [B, S] int32 (shared q/kv for self-attention) or a
     ``(q_seg, kv_seg)`` pair — attention is allowed iff the ids are equal.
@@ -590,8 +647,16 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None):
             seg_mask = (q_seg[:, :, None] == kv_seg[:, None, :])[:, None]
             mask = seg_mask if mask is None else jnp.logical_and(mask,
                                                                  seg_mask)
+        if select is not None:
+            chosen = (jnp.asarray(select) != 0)[:, None]
+            mask = chosen if mask is None else jnp.logical_and(mask, chosen)
+        group = q.shape[2] // k.shape[2]
+        if group > 1:
+            k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
         return reference_attention(q, k, v, mask)
-    return _flash(q, k, v, q_seg, kv_seg, causal)
+    if select is not None:
+        select = jnp.asarray(select).astype(jnp.int8)
+    return _flash(q, k, v, q_seg, kv_seg, select, causal)
 
 
 def make_flash_attn_fn(causal: bool = True):
@@ -599,10 +664,12 @@ def make_flash_attn_fn(causal: bool = True):
 
     A key-padding mask (boolean, broadcastable [B, 1, 1, S] / [B, S])
     becomes segment ids (valid=1, pad=0) — the masked-tile block path.
-    Arbitrary dense masks are not expressible as segments and raise."""
-    def attn(q, k, v, mask=None):
+    Arbitrary dense masks are not expressible as segments and raise;
+    ``select`` ([B, Sq, Sk], a sparse attention's chosen keys) goes to
+    the kernels as it is."""
+    def attn(q, k, v, mask=None, select=None):
         if mask is None:
-            return flash_attention(q, k, v, causal)
+            return flash_attention(q, k, v, causal, select=select)
         m = jnp.asarray(mask)
         # accept [B, S] or the layers' [B, 1, 1, S] broadcast form
         if m.ndim == 4 and m.shape[1] == 1 and m.shape[2] == 1:
@@ -612,5 +679,5 @@ def make_flash_attn_fn(causal: bool = True):
                 "flash attention supports key-padding masks ([B, S] or "
                 "[B, 1, 1, S]) via segment ids; got mask shape %s"
                 % (mask.shape,))
-        return flash_attention(q, k, v, causal, m.astype(jnp.int32))
+        return flash_attention(q, k, v, causal, m.astype(jnp.int32), select)
     return attn

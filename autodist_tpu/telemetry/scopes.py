@@ -60,6 +60,9 @@ KDA = "kda"
 KDA_SCAN = "kda_scan"
 MLA = "mla"
 MLA_CORE = "mla_core"
+DSA_INDEX = "dsa_index"
+DSA_TOPK = "dsa_topk"
+DSA_CORE = "dsa_core"
 PREFILL = "prefill"
 DECODE = "decode"
 INSERT = "insert"
@@ -102,6 +105,15 @@ SCOPES: Dict[str, str] = {
     MLA_CORE: "inside mla: the attention core alone, the call of the "
               "attention function on q, k, v (the flash kernels flash_fwd / "
               "flash_bwd on a TPU, XLA's scores elsewhere)",
+    DSA_INDEX: "inside attention: a sparse attention's indexer, its three "
+               "projections, the key's LayerNorm, the rotation and the index "
+               "scores of each block of queries (ops/dsa.py), all float32",
+    DSA_TOPK: "inside attention: the choice alone, each query's topk keys "
+              "of a block's index scores (ops/dsa.py:choose); a recomputed "
+              "block holds none",
+    DSA_CORE: "inside attention: the attention core over grouped K/V heads "
+              "and, with an indexer, the chosen keys alone (the flash kernels "
+              "with a selection on a TPU, XLA's scores elsewhere)",
     PREFILL: "serving: the prompt pass of a prefill bucket",
     DECODE: "serving: one cached decode step",
     INSERT: "serving: writing admitted rows into the decode state",

@@ -398,7 +398,30 @@ BACKWARD_CASES = {
                                "packed"),
     "rows_with_no_visible_key": (1024, 1024, 32, 32, jnp.float32, False,
                                  "empty_rows"),
+    # a sparse attention's choice of keys (a selection [B, Sq, Sk], every
+    # head alike), and K/V heads that two query heads share
+    "chosen_keys_causal": (1024, 1024, 32, 32, jnp.float32, True, "chosen"),
+    "chosen_keys_bfloat16": (1024, 1024, 128, 128, jnp.bfloat16, True,
+                             "chosen"),
+    "chosen_keys_packed": (1024, 1024, 32, 32, jnp.float32, True,
+                           "chosen_packed"),
+    "grouped_heads_causal": (1024, 1024, 32, 32, jnp.float32, True,
+                             "grouped"),
+    "chosen_keys_grouped_heads": (1024, 1024, 32, 32, jnp.float32, True,
+                                  "chosen_grouped"),
 }
+
+
+def _selection(kind, B, Sq, Sk):
+    """None, or [B, Sq, Sk] int8: about a third of the keys, always the
+    query's own (every query keeps a key); the last queries keep none of
+    the first tile's keys, so their rows are empty until a later tile."""
+    if kind is None or "chosen" not in kind:
+        return None
+    chosen = np.random.RandomState(11).rand(B, Sq, Sk) < 0.3
+    chosen[:, 700:, :512] = False
+    chosen[:, np.arange(Sq), np.arange(Sq)] = True
+    return jnp.asarray(chosen, jnp.int8)
 
 
 def _backward_case(name):
@@ -406,20 +429,29 @@ def _backward_case(name):
     reference's float32 gradients, tolerance)."""
     Sq, Sk, D, Dv, dtype, causal, kind = BACKWARD_CASES[name]
     B, H = 2, 2
+    grouped = kind is not None and "grouped" in kind
+    kv_heads = 1 if grouped else H
     q = _rand((B, H, Sq, D), dtype, seed=0)
-    k = _rand((B, H, Sk, D), dtype, seed=1)
-    v = _rand((B, H, Sk, Dv), dtype, seed=2)
+    k = _rand((B, kv_heads, Sk, D), dtype, seed=1)
+    v = _rand((B, kv_heads, Sk, Dv), dtype, seed=2)
     do = _rand((B, H, Sq, Dv), dtype, seed=3)
-    segs = _segments(kind, B, Sq, Sk)
+    segs = _segments({"chosen_packed": "packed", "chosen": None, "grouped": None,
+                      "chosen_grouped": None}.get(kind, kind), B, Sq, Sk)
+    sel = _selection(kind, B, Sq, Sk)
     mask = None
     if causal:
         mask = (jnp.arange(Sq)[:, None] >= jnp.arange(Sk)[None, :])[None, None]
     if segs is not None:
         seg_mask = jnp.asarray(_seg_mask(*segs))
         mask = seg_mask if mask is None else jnp.logical_and(mask, seg_mask)
+    if sel is not None:
+        mask = jnp.logical_and(mask, (sel != 0)[:, None])
 
     def loss_ref(q, k, v):
-        # (reference_attention takes the models' [B, S, H, .])
+        # (reference_attention takes the models' [B, S, H, .] and as many
+        # K/V heads as query heads: a shared head is repeated, and its
+        # gradient is the sum over the heads that read it)
+        k, v = (jnp.repeat(x, H // kv_heads, axis=1) for x in (k, v))
         out = reference_attention(*(x.transpose(0, 2, 1, 3)
                                     for x in (q, k, v)), mask)
         return jnp.sum(out.transpose(0, 2, 1, 3) * do.astype(jnp.float32))
@@ -438,15 +470,16 @@ def _backward_case(name):
     segs = None if segs is None else tuple(jnp.asarray(s) for s in segs)
     tol = (dict(atol=5e-5, rtol=5e-4) if dtype == jnp.float32
            else dict(atol=5e-2, rtol=5e-2))     # the file's two tolerances
-    return (q, k, v, do), segs, causal, want, tol
+    return (q, k, v, do), (segs, sel), causal, want, tol
 
 
-def _backward(inputs, segs, causal):
+def _backward(inputs, masks, causal):
     """(dq, dk, dv) of ``fa._bwd`` on the forward kernel's own residuals."""
     q, k, v, do = inputs
-    out, lse = fa._fwd(q, k, v, segs, causal)
+    segs, sel = masks
+    out, lse = fa._fwd(q, k, v, segs, sel, causal)
     q_seg, kv_seg = (None, None) if segs is None else segs
-    return fa._bwd(causal, (q, k, v, out, lse, q_seg, kv_seg), do)
+    return fa._bwd(causal, (q, k, v, out, lse, q_seg, kv_seg, sel), do)
 
 
 @pytest.mark.parametrize("case", sorted(BACKWARD_CASES))
@@ -526,3 +559,72 @@ def test_the_backward_form_follows_from_the_shapes(shape, dv, dtype, segments,
                - before.get("attention.flash_bwd_" + form, 0)
                for form in ("fused", "split")}
     assert counted == {"fused": int(fused), "split": int(not fused)}
+
+
+# ------------------------- a selection of keys, and the calls without one
+
+
+PARENT_CALLS = {
+    "causal_2x64x4x16": ((2, 64, 4, 16), True, False),
+    "causal_seg_1x32x2x32": ((1, 32, 2, 32), True, True),
+    "full_1x32x2x16": ((1, 32, 2, 16), False, False)}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_CALLS))
+def test_a_call_without_a_selection_traces_to_the_parents_kernels(case):
+    """``flash_attention`` without ``select`` and with as many K/V heads as
+    query heads, differentiated: the same jaxpr, equation for equation and
+    kernel body for kernel body, as at the parent commit of PR 37 (its
+    text's hash, ``tests/data/lm_before_keye_vl2.json``). The selection
+    operand and the grouped heads changed no program that does not ask
+    for them."""
+    import hashlib
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(__file__), "data",
+                           "lm_before_keye_vl2.json")) as f:
+        before = json.load(f)["flash_attention"][case]
+    shape, causal, seg = PARENT_CALLS[case]
+    q = jnp.zeros(shape, jnp.float32)
+    ids = jnp.zeros(shape[:2], jnp.int32) if seg else None
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal, ids))
+    text = str(jax.make_jaxpr(jax.value_and_grad(f, (0, 1, 2)))(q, q, q))
+    assert {"jaxpr_sha256": hashlib.sha256(text.encode()).hexdigest(),
+            "jaxpr_lines": text.count("\n")} == before
+
+
+@pytest.mark.parametrize("kv_heads", [4, 1])
+def test_a_selection_through_the_public_op_and_the_adapter(kv_heads):
+    """[B, S, H, D] in, a bool selection and K/V heads shared four ways:
+    values and gradients of XLA's masked scores; the ``attn_fn`` adapter
+    hands ``select`` on; an untileable length takes the XLA path with the
+    same answer."""
+    B, S, H, D = 2, 64, 4, 16
+    q = _rand((B, S, H, D), seed=0)
+    k, v = (_rand((B, S, kv_heads, D), seed=i) for i in (1, 2))
+    chosen = np.random.RandomState(3).rand(B, S, S) < 0.4
+    chosen[:, np.arange(S), np.arange(S)] = True
+    chosen = jnp.asarray(chosen)
+    mask = jnp.logical_and(_mask(S, True), chosen[:, None])
+
+    def want(q, k, v):
+        k, v = (jnp.repeat(x, H // kv_heads, axis=2) for x in (k, v))
+        return jnp.sum(jnp.sin(reference_attention(q, k, v, mask)))
+
+    def got(q, k, v):
+        return jnp.sum(jnp.sin(make_flash_attn_fn(True)(q, k, v, None,
+                                                        select=chosen)))
+    a = jax.value_and_grad(got, (0, 1, 2))(q, k, v)
+    b = jax.value_and_grad(want, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(a[0], b[0], rtol=1e-5)
+    for x, y in zip(a[1], b[1]):
+        np.testing.assert_allclose(x, y, atol=3e-5, rtol=1e-4)
+    odd = slice(0, 60)                       # 60 rows: tiles of 4, no kernel
+    np.testing.assert_allclose(
+        flash_attention(q[:, odd], k[:, odd], v[:, odd], True,
+                        select=chosen[:, odd, odd]),
+        reference_attention(q[:, odd], jnp.repeat(k[:, odd], H // kv_heads, 2),
+                            jnp.repeat(v[:, odd], H // kv_heads, 2),
+                            mask[:, :, odd, odd]), atol=2e-5, rtol=2e-5)

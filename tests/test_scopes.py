@@ -9,7 +9,10 @@ The tracing contracts (``telemetry/scopes.py``, docs/observability.md
   without remat; a Kimi-Linear model's mixers (``kda`` > ``kda_scan``,
   ``mla`` > ``mla_core``, ``moe_shared``) are there too, with their
   recomputed ops; a DeepSeek-V2 model's step has ``mla_core`` around its
-  scores alone and hands ``moe.aux_loss`` out beside the loss;
+  scores alone and hands ``moe.aux_loss`` out beside the loss; a Keye-VL-2.0
+  model's step has ``dsa_index``, ``dsa_topk`` and ``dsa_core`` inside
+  ``attention``, recomputes neither of the first two, and hands out the
+  pairs its indexers chose;
 - the map is computed ON DEMAND: a fit, traced or not, lowers and
   compiles nothing extra;
 - ``runner.readback`` is tiled by its two children (the wait for the
@@ -246,6 +249,65 @@ def test_a_deepseek_v2_step_names_its_attention_cores_and_counts_its_balance_los
     assert any("dot_general" in o for o in outside)
     assert any(o.endswith(("/cos", "/sin")) for o in outside)
     assert not any(o.endswith(("/cos", "/sin")) for o in core)
+
+
+def test_a_keye_vl2_step_names_its_indexer_choice_and_core_and_counts_the_pairs():
+    """``dsa_index`` holds the indexer's projections and index scores,
+    ``dsa_topk`` the choice alone (no matmul) and ``dsa_core`` the
+    attention function's call (scores and softmax, NOT the q/k/v
+    projections or the rotation), each inside ``attention``; with every
+    block recomputed in the backward pass no recomputed op is the
+    indexer's or the choice's (the selection is kept by name); the step's
+    metrics carry ``dsa.selected_pairs`` and ``dsa.causal_pairs``."""
+    from tests.test_keye_vl2 import tiny_config
+    cfg = tiny_config(indexer_topk=4)
+    chip = lm._chip_hbm_bytes
+    lm._chip_hbm_bytes = lambda: 1e5
+    try:
+        loss_fn, params, batch, _ = lm.make_train_setup(
+            cfg, seq_len=16, batch_size=8)
+    finally:
+        lm._chip_hbm_bytes = chip
+    assert {"dsa.selected_pairs", "dsa.causal_pairs"} <= set(
+        loss_fn.device_counters)
+    autodist_tpu.reset()
+    try:
+        ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
+        runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
+        runner.init(params)
+        counters = runner.run(batch)["counters"]
+        m = telemetry.scope_map(STEP)
+    finally:
+        autodist_tpu.reset()
+    # a replica's row of 16 positions (8 rows over 8 devices), two layers:
+    # 4 keys a query from position 3 on
+    assert int(counters["dsa.causal_pairs"]) == 2 * 16 * 17 // 2
+    assert int(counters["dsa.selected_pairs"]) == 2 * (4 * 5 // 2 + 12 * 4)
+    ops = [o for names in m.values() for o in names]
+    for scope in (scopes.DSA_INDEX, scopes.DSA_TOPK, scopes.DSA_CORE):
+        assert scope in scopes.SCOPES
+        # (a reduction's own scalar computation inside a loop's body is
+        # named from the body's scope on: no device op of its own)
+        inside = [o for o in ops if scope in components(o)
+                  and scopes.BLOCKS in components(o)]
+        assert inside and all(
+            components(o).index(scopes.BLOCKS)
+            < components(o).index(scopes.ATTENTION)
+            < components(o).index(scope) for o in inside)
+    index = [o for o in ops if scopes.DSA_INDEX in components(o)]
+    topk = [o for o in ops if scopes.DSA_TOPK in components(o)]
+    core = [o for o in ops if scopes.DSA_CORE in components(o)]
+    assert any("dot_general" in o for o in index)
+    assert any(o.endswith(("/cos", "/sin")) for o in index)
+    assert not any("dot_general" in o for o in topk)
+    assert any("dot_general" in o for o in core)
+    assert not any(o.endswith(("/cos", "/sin")) for o in core)
+    # forward only and once: nothing of the indexer or the choice is
+    # differentiated or recomputed; the core is both
+    assert any("rematted_computation" in o for o in ops)
+    assert not any("rematted_computation" in o or "transpose(" in o
+                   for o in index + topk)
+    assert any("transpose(" in o for o in core)
 
 
 def test_partitioned_storage_gathers_under_the_params_scope():
