@@ -7,31 +7,51 @@ reads them back several times in each direction; this kernel keeps a
 accumulation, matmuls on the MXU with fp32 accumulation.
 
 Design (standard FlashAttention-2 tiling, arXiv 2307.08691):
-- forward: grid (batch, heads, q_blocks, kv_blocks) with the kv dimension
-  innermost/"arbitrary"; running (m, l, acc) live in VMEM scratch across kv
-  steps; the log-sum-exp per row is written out for the backward pass.
+- grid: every kernel takes ONE grid form, (batch, heads, T), whose last
+  axis ("arbitrary") walks a TABLE of tiles made in numpy from the shapes
+  as the call is traced and handed over through scalar prefetch
+  (``_tile_table``: for each step its q block, its kv block, its bits and
+  the q block whose dq leaves next). The block index maps read the table;
+  so do the kernels' "first / last tile of this q block / kv block". A
+  call that is not causal walks the whole rectangle; there is no second
+  grid form.
+- causal: the table lists the lower triangle only (136 of 256 tiles at
+  seq 8192 x 512 rows), so no grid step is spent on a tile above the
+  diagonal, and only the tiles the diagonal crosses (16 of the 136, bit
+  ``CROSSED``) build the positional mask: under it every row passes every
+  column, and that tile runs the same body without the two ``iota``s, the
+  compare and the select. Counters ``attention.flash_tiles`` /
+  ``attention.flash_tiles_masked`` say, per traced launch and head, how
+  many steps the table has and how many carry the mask (PERF.md section
+  6, PR 39). The sums are the rectangle's, tile for tile in the same
+  order; on the CPU the values are the masked-everywhere kernel's to the
+  last bit wherever segment ids or a selection mask the tile, and within
+  a float32 rounding (1e-7) on a plain causal call's interior tiles, whose
+  ``s * scale - m`` XLA:CPU contracts once no select stands between.
+- forward: q-major walk (a q block's kv blocks in a run); running
+  (m, l, acc) live in VMEM scratch across the run; the log-sum-exp per row
+  is written out for the backward pass.
 - backward: delta = rowsum(dO * O) precomputed in XLA (cheap elementwise),
-  then ONE kernel, ``flash_bwd``, over (kv_blocks, q_blocks) with the q
-  blocks innermost: each live tile recomputes P = exp(S - lse) and
+  then ONE kernel, ``flash_bwd``, over the kv-major walk (a kv block's q
+  blocks in a run): each tile recomputes P = exp(S - lse) and
   dS = P * (dP - delta) once, instead of storing them, and adds its three
   products to dK / dV (float32 scratch of the kv block, as k and v stay put)
   and to dQ, whose float32 accumulator for the WHOLE head ([Sq, D]) stays
-  in VMEM across the head's grid steps and is scaled, cast and written a
-  q block at a time during the head's last kv pass; ``vmem_limit_bytes``
+  in VMEM across the head's grid steps; a q block's rows are scaled, cast
+  and written at its LAST tile of the walk (causal: the first step of kv
+  pass j completes q block j; not causal: all leave in the last kv pass),
+  its output block named from the table over the run of steps that ends
+  there; ``vmem_limit_bytes``
   is counted from the shapes (``_fused_vmem``; 21.5 MB at seq 8192 x 192,
   Mosaic's default scope is 16 MiB of the v5e's 128). Where that count
   passes half of VMEM (seq 65,536 x 192: dq alone is 67 MB) the two
-  kernels of before run instead, ``flash_dq`` over (q_blocks, kv_blocks)
+  kernels of before run instead, ``flash_dq`` over the q-major walk
   and ``flash_dkdv``, each recomputing the tile: same products, same
   order, same gradients to the last bit (``_bwd`` decides from the
   shapes alone; counters ``attention.flash_bwd_fused`` / ``_split`` say
   which form each traced backward pass took; PERF.md section 6, PR 34).
 - tiles: ``_tiles`` chooses (block_q, block_k) from the shapes; no caller
   passes a tile size (PERF.md section 6, PR 28, has the chip's readings).
-- causal: a tile above the diagonal is skipped by ``pl.when`` AND its
-  operand blocks are not fetched (the block index maps clamp to the
-  nearest live tile, so the pipeline sees a repeated index and elides the
-  DMA).
 - layout: the model zoo's [batch, seq, heads, head_dim], transposed to
   [batch, heads, seq, head_dim] around the kernels.
 - under a recomputing checkpoint: three of the backward kernels'
@@ -68,6 +88,7 @@ checks fwd+grad against ``ops.attention.reference_attention``).
 
 Segment ids are [batch, seq] int32.
 """
+import collections
 import functools
 
 import jax
@@ -122,50 +143,147 @@ def full_tiles(seq: int) -> bool:
     return seq % _ROWS == 0
 
 
-# ----------------------------------------------------------------- layout
+# ------------------------------------------------------------------ tiles
 
-def _specs(D, Dv, bq, bk, q_pos, k_pos, group=1):
-    """BlockSpecs of one grid (b, h, i, j) over [B, H, S, .] operands: a
-    [rows, D] tile of q and of k, a [rows, Dv] tile of v and of the
-    output (the values may be narrower or wider than the scores'
+def _tile_live(qi, ki, bq, bk, causal):
+    """Does tile (q block ``qi``, kv block ``ki``) hold a visible entry by
+    position? Under a causal mask: does its last row reach its first
+    column. Known from the shapes alone (ints or numpy arrays)."""
+    return qi * bq + bq - 1 >= ki * bk if causal else True
+
+
+# the table's columns ...
+_Q, _KV, _FLAGS, _Q_OUT = range(4)
+# ... and the bits of its flags: the first / last tile of the walk that
+# holds this q block, the same of this kv block, and a tile the diagonal
+# crosses (some column passes some row: the only ones the causal compare
+# changes)
+Q_FIRST, Q_LAST, KV_FIRST, KV_LAST, CROSSED = 1, 2, 4, 8, 16
+
+
+def _tile_table(n_q, n_kv, bq, bk, causal, kv_major):
+    """The tiles a kernel walks, int32 [4, T], made from the shapes as the
+    call is traced: column ``_Q`` / ``_KV`` of step t is the tile's q / kv
+    block, ``_FLAGS`` its bits, ``_Q_OUT`` the q block whose rows leave
+    next (below). Every tile ``_tile_live`` finds a visible entry in, once:
+    the whole rectangle, or the causal lower triangle; q-major (a q block's
+    kv blocks in a run, rising: the forward kernel's and ``flash_dq``'s
+    order of summing) or kv-major (``flash_bwd`` / ``flash_dkdv``).
+
+    ``_Q_OUT``: a q block's float32 dq rows are complete at its LAST tile
+    of the walk, so the output block of step t names the q block whose
+    last tile comes soonest at or after t: each q block is named over one
+    run of steps that ends where it is written. q-major that is the tile's
+    own q block; kv-major and causal, q block j is done at the first step
+    of kv pass j; with no mask all are done in the last kv pass. (Every q
+    block sees kv block 0, so none is left out; a kv block past the last
+    query's position has no tile: ``_bwd`` zeroes its dk / dv.)"""
+    grid = np.indices((n_kv, n_q) if kv_major else (n_q, n_kv))
+    q, kv = (x.ravel() for x in (grid[::-1] if kv_major else grid))
+    if causal:
+        live = _tile_live(q, kv, bq, bk, causal)
+        q, kv = q[live], kv[live]
+    steps = np.arange(q.size)
+
+    def ends(block, n):
+        first, last = np.full(n, q.size), np.full(n, -1)
+        np.minimum.at(first, block, steps)
+        np.maximum.at(last, block, steps)
+        return first, last
+
+    q_first, q_last = ends(q, n_q)
+    kv_first, kv_last = ends(kv, n_kv)
+    flags = (Q_FIRST * (q_first[q] == steps) + Q_LAST * (q_last[q] == steps)
+             + KV_FIRST * (kv_first[kv] == steps)
+             + KV_LAST * (kv_last[kv] == steps))
+    if causal:
+        flags = flags + CROSSED * (kv * bk + bk - 1 > q * bq)
+    leaving = np.argsort(q_last)
+    q_out = leaving[np.searchsorted(q_last[leaving], steps)]
+    return np.stack([q, kv, flags, q_out]).astype(np.int32)
+
+
+def _table(Sq, Sk, bq, bk, causal, kv_major):
+    """(``_tile_table`` flat, as the kernels' scalar-prefetch operand; its
+    steps), counted as the launch is traced."""
+    table = _tile_table(Sq // bq, Sk // bk, bq, bk, causal, kv_major)
+    tel.counter_add("attention.flash_tiles", table.shape[1])
+    tel.counter_add("attention.flash_tiles_masked",
+                    int(np.count_nonzero(table[_FLAGS] & CROSSED)))
+    return jnp.asarray(table.reshape(-1)), table.shape[1]
+
+
+def _at(tab, col, steps, t):
+    """Column ``col`` of the flat table at step t."""
+    return tab[col * steps + t]
+
+
+def _step(tab_ref, steps):
+    """(q block, kv block, flags) of this grid step."""
+    t = pl.program_id(2)
+    return tuple(_at(tab_ref, col, steps, t) for col in (_Q, _KV, _FLAGS))
+
+
+_Specs = collections.namedtuple(
+    "_Specs", "q k v out row q_seg kv_seg sel dq")
+
+
+def _specs(D, Dv, bq, bk, steps, group=1):
+    """BlockSpecs of one grid (b, h, t) over [B, H, S, .] operands, t a
+    step of the tile table (the scalar-prefetch operand, handed to every
+    index map): a [rows, D] tile of q and of k, a [rows, Dv] tile of v and
+    of the output (the values may be narrower or wider than the scores'
     features: latent attention scores over 192 and carries 128), a
     [rows, 1] tile of a per-row q-side vector ([B, H, S, 1]), the
     [rows, 1] q / kv segment ids ([B, S, 1]: the trailing 1 satisfies the
-    TPU's (8, 128) rule as for the row vectors), and the [bq, bk] tile of
-    a selection ([B, Sq, Sk], every head's alike). ``q_pos(i, j)`` /
-    ``k_pos(i, j)`` give the row-block index; query head h reads K/V head
+    TPU's (8, 128) rule as for the row vectors), the [bq, bk] tile of a
+    selection ([B, Sq, Sk], every head's alike), and the [bq, D] tile of
+    dq where its rows leave (``_Q_OUT``). Query head h reads K/V head
     ``h // group`` of [B, H / group, S, .] (nothing is repeated in HBM).
     The kernels see the tiles without leading dims."""
-    def tile(rows, pos, width, head=lambda h: h):
-        return pl.BlockSpec((None, None, rows, width),
-                            lambda b, h, i, j: (b, head(h), pos(i, j), 0))
+    def tile(rows, col, width, head=lambda h: h):
+        return pl.BlockSpec(
+            (None, None, rows, width),
+            lambda b, h, t, tab: (b, head(h), _at(tab, col, steps, t), 0))
 
-    def seg(rows, pos):
-        return pl.BlockSpec((None, rows, 1),
-                            lambda b, h, i, j: (b, pos(i, j), 0))
+    def seg(rows, col):
+        return pl.BlockSpec(
+            (None, rows, 1),
+            lambda b, h, t, tab: (b, _at(tab, col, steps, t), 0))
 
     kv_head = (lambda h: h) if group == 1 else (lambda h: h // group)
-    sel = pl.BlockSpec((None, bq, bk),
-                       lambda b, h, i, j: (b, q_pos(i, j), k_pos(i, j)))
-    return (tile(bq, q_pos, D), tile(bk, k_pos, D, kv_head),
-            tile(bk, k_pos, Dv, kv_head), tile(bq, q_pos, Dv),
-            tile(bq, q_pos, 1), seg(bq, q_pos), seg(bk, k_pos), sel)
+    sel = pl.BlockSpec(
+        (None, bq, bk),
+        lambda b, h, t, tab: (b, _at(tab, _Q, steps, t),
+                              _at(tab, _KV, steps, t)))
+    return _Specs(tile(bq, _Q, D), tile(bk, _KV, D, kv_head),
+                  tile(bk, _KV, Dv, kv_head), tile(bq, _Q, Dv),
+                  tile(bq, _Q, 1), seg(bq, _Q), seg(bk, _KV), sel,
+                  tile(bq, _Q_OUT, D))
 
 
-def _kv_pos(causal, bq, bk):
-    """kv row-block of step (i = q block, j = kv block) of a q-major grid:
-    j, but a dead causal tile re-names the last kv block its q block sees,
-    so the pipeline finds a repeated index and fetches nothing."""
-    if not causal:
-        return lambda i, j: j
-    return lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
+def _launch(kernel, name, table, grid, operands, in_specs, outs, scratch,
+            **params):
+    """One kernel over the tile table ``table`` (its scalar-prefetch
+    operand), grid (batch, heads, steps); ``outs``: (block, result like) of
+    each result."""
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[spec for spec, _ in outs],
+            scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                            for shape in scratch]),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for _, x in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **params),
+        interpret=pallas_mode.interpret(),
+        name=name,
+    )(table, *operands)
 
-
-_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-
-
-# ------------------------------------------------------------------ tiles
 
 def _mask_val(s, qi, ki, bq, bk, causal, qs, ks):
     """Apply causal and/or segment masking to a score tile [bq, bk]."""
@@ -178,36 +296,41 @@ def _mask_val(s, qi, ki, bq, bk, causal, qs, ks):
     return s
 
 
-def _tile_live(qi, ki, bq, bk, causal, qs, ks):
-    """Skip condition: False only when the tile provably has no visible
-    entry. Causal skips are static (upper-triangular tiles); segment skips
-    compare the blocks' id ranges (exact for sorted segments, safe
-    over-approximation otherwise)."""
-    live = (qi * bq + bq - 1 >= ki * bk) if causal else True
-    if qs is not None:
-        overlap = ((jnp.max(qs) >= jnp.min(ks))
-                   & (jnp.min(qs) <= jnp.max(ks)))
-        live = jnp.logical_and(live, overlap)
-    return live
-
-
-def _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, sel_ref, body):
-    """Run ``body(mask)`` on a tile with a visible entry; ``mask(s)`` masks
-    a score tile [bq, bk]. A selection masks elementwise within the tile
-    (its [bq, bk] int8 block, non-zero = attend) and skips none: whether a
-    tile holds a chosen pair is not known without reading it."""
+def _on_live_tile(qi, ki, flags, bq, bk, causal, qs_ref, ks_ref, sel_ref,
+                  body):
+    """Run ``body(mask)`` on a tile of the table unless its segment ids
+    say no entry is visible; ``mask(s)`` masks a score tile [bq, bk]. The
+    table lists no tile above the diagonal, and only one the diagonal
+    crosses (``CROSSED``) pays the causal compare: under it every row
+    passes every column. Segment ids skip a tile whose blocks' id ranges
+    do not meet (exact for sorted segments, a safe over-approximation
+    otherwise) and mask the others elementwise. A selection masks
+    elementwise within the tile (its [bq, bk] int8 block, non-zero =
+    attend) and skips none: whether a tile holds a chosen pair is not
+    known without reading it."""
     qs = None if qs_ref is None else qs_ref[:, 0]
     ks = None if ks_ref is None else ks_ref[:, 0]
 
-    def mask(s):
-        s = _mask_val(s, qi, ki, bq, bk, causal, qs, ks)
-        if sel_ref is None:
-            return s
-        return jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, NEG_INF)
+    def run(diagonal):
+        def mask(s):
+            s = _mask_val(s, qi, ki, bq, bk, diagonal, qs, ks)
+            if sel_ref is None:
+                return s
+            return jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, NEG_INF)
+        return lambda: body(mask)
 
-    if not causal and qs is None:
-        return body(mask if sel_ref is not None else (lambda s: s))
-    pl.when(_tile_live(qi, ki, bq, bk, causal, qs, ks))(lambda: body(mask))
+    if qs is None:
+        when = pl.when
+    else:
+        meet = (jnp.max(qs) >= jnp.min(ks)) & (jnp.min(qs) <= jnp.max(ks))
+
+        def when(cond):
+            return pl.when(jnp.logical_and(meet, cond))
+    if not causal:
+        return run(False)() if qs is None else pl.when(meet)(run(False))
+    crossed = (flags & CROSSED) != 0
+    when(crossed)(run(True))
+    when(jnp.logical_not(crossed))(run(False))
 
 
 def _scores(q, k, scale, mask):
@@ -228,13 +351,14 @@ def _split_refs(refs, n_fixed, has_seg, has_sel):
     return refs[:n_fixed], qs_ref, ks_ref, sel_ref, refs[n_in:]
 
 
-def _fwd_kernel(*refs, scale, causal, has_seg, has_sel, bq, bk, n_kv):
+def _fwd_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
+                steps):
     (q_ref, k_ref, v_ref), qs_ref, ks_ref, sel_ref, (
         o_ref, lse_ref, acc_ref, m_ref, l_ref) = _split_refs(
             refs, 3, has_seg, has_sel)
-    qi, ki = pl.program_id(2), pl.program_id(3)
+    qi, ki, flags = _step(tab_ref, steps)
 
-    @pl.when(ki == 0)
+    @pl.when((flags & Q_FIRST) != 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -272,9 +396,10 @@ def _fwd_kernel(*refs, scale, causal, has_seg, has_sel, bq, bk, n_kv):
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, sel_ref, tile)
+    _on_live_tile(qi, ki, flags, bq, bk, causal, qs_ref, ks_ref, sel_ref,
+                  tile)
 
-    @pl.when(ki == n_kv - 1)
+    @pl.when((flags & Q_LAST) != 0)
     def _():
         l = l_ref[:, :1]
         o_ref[...] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -295,36 +420,26 @@ def _fwd(q, k, v, segs, sel, causal):
     Sk, Dv = k.shape[2], v.shape[3]
     has_seg, has_sel = segs is not None, sel is not None
     bq, bk = _tiles(Sq, Sk)
-    n_q, n_kv = Sq // bq, Sk // bk
+    table, steps = _table(Sq, Sk, bq, bk, causal, kv_major=False)
 
-    (q_spec, k_spec, v_spec, o_spec, row_spec, qs_spec, ks_spec,
-     sel_spec) = _specs(D, Dv, bq, bk, lambda i, j: i,
-                        _kv_pos(causal, bq, bk), H // k.shape[1])
-    in_specs = [q_spec, k_spec, v_spec]
+    specs = _specs(D, Dv, bq, bk, steps, H // k.shape[1])
+    in_specs = [specs.q, specs.k, specs.v]
     operands = [q, k, v]
     if has_seg:
-        in_specs += [qs_spec, ks_spec]
+        in_specs += [specs.q_seg, specs.kv_seg]
         operands += [segs[0][..., None], segs[1][..., None]]
     if has_sel:
-        in_specs.append(sel_spec)
+        in_specs.append(specs.sel)
         operands.append(sel)
 
-    return pl.pallas_call(
+    return _launch(
         functools.partial(_fwd_kernel, scale=float(1.0 / np.sqrt(D)),
                           causal=causal, has_seg=has_seg, has_sel=has_sel,
-                          bq=bq, bk=bk, n_kv=n_kv),
-        grid=(B, H, n_q, n_kv),
-        in_specs=in_specs,
-        out_specs=[o_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bq, Dv), jnp.float32),
-                        pltpu.VMEM((bq, _LANES), jnp.float32),
-                        pltpu.VMEM((bq, _LANES), jnp.float32)],
-        compiler_params=_PARAMS,
-        interpret=pallas_mode.interpret(),
-        name="flash_fwd",
-    )(*operands)
+                          bq=bq, bk=bk, steps=steps),
+        "flash_fwd", table, (B, H, steps), operands, in_specs,
+        [(specs.out, jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype)),
+         (specs.row, jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32))],
+        [(bq, Dv), (bq, _LANES), (bq, _LANES)])
 
 
 # ---------------------------------------------------------------- backward
@@ -340,12 +455,13 @@ def _p_and_ds(q, k, v, do, lse, delta, scale, mask):
     return p, p * (dp - delta)
 
 
-def _dq_kernel(*refs, scale, causal, has_seg, has_sel, bq, bk, n_kv):
+def _dq_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
+               steps):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), qs_ref, ks_ref, \
         sel_ref, (dq_ref, acc_ref) = _split_refs(refs, 6, has_seg, has_sel)
-    qi, ki = pl.program_id(2), pl.program_id(3)
+    qi, ki, flags = _step(tab_ref, steps)
 
-    @pl.when(ki == 0)
+    @pl.when((flags & Q_FIRST) != 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
@@ -357,35 +473,36 @@ def _dq_kernel(*refs, scale, causal, has_seg, has_sel, bq, bk, n_kv):
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, sel_ref, tile)
+    _on_live_tile(qi, ki, flags, bq, bk, causal, qs_ref, ks_ref, sel_ref,
+                  tile)
 
-    @pl.when(ki == n_kv - 1)
+    @pl.when((flags & Q_LAST) != 0)
     def _():
         dq_ref[...] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_kernel(*refs, scale, causal, has_seg, has_sel, bq, bk, n_q, n_kv,
-                fused):
+def _bwd_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
+                steps, fused):
     """dk and dv of one kv block, summed over its q blocks in float32
     scratch. ``fused``: dq too, from the SAME ``_p_and_ds`` of each tile:
     it sums over the kv blocks in ``dq_acc``, one float32 [bq, D] slab a q
-    block, which stays in VMEM for the whole head and leaves it in the
-    head's last kv pass."""
+    block, which stays in VMEM for the whole head; a q block's rows leave
+    at its last tile of the walk (``_tile_table``)."""
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), qs_ref, ks_ref, \
         sel_ref, outs = _split_refs(refs, 6, has_seg, has_sel)
     if fused:
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = outs
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = outs
-    ki, qi = pl.program_id(2), pl.program_id(3)
+    qi, ki, flags = _step(tab_ref, steps)
 
-    @pl.when(qi == 0)
+    @pl.when((flags & KV_FIRST) != 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     if fused:
-        @pl.when(ki == 0)
+        @pl.when((flags & Q_FIRST) != 0)
         def _():
             dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
@@ -405,15 +522,16 @@ def _bwd_kernel(*refs, scale, causal, has_seg, has_sel, bq, bk, n_q, n_kv,
                 ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    _on_live_tile(qi, ki, bq, bk, causal, qs_ref, ks_ref, sel_ref, tile)
+    _on_live_tile(qi, ki, flags, bq, bk, causal, qs_ref, ks_ref, sel_ref,
+                  tile)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when((flags & KV_LAST) != 0)
     def _():
         dk_ref[...] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
 
     if fused:
-        @pl.when(ki == n_kv - 1)
+        @pl.when((flags & Q_LAST) != 0)
         def _():
             dq_ref[...] = (dq_acc[qi] * scale).astype(dq_ref.dtype)
 
@@ -456,7 +574,6 @@ def _bwd(causal, res, do):
     has_seg, has_sel = q_seg is not None, sel is not None
     group = H // k.shape[1]
     bq, bk = _tiles(Sq, Sk)
-    n_q, n_kv = Sq // bq, Sk // bk
     static = dict(scale=float(1.0 / np.sqrt(D)), causal=causal,
                   has_seg=has_seg, has_sel=has_sel, bq=bq, bk=bk)
 
@@ -470,85 +587,66 @@ def _bwd(causal, res, do):
     if has_sel:
         operands.append(sel)
 
-    def in_specs(q_spec, k_spec, v_spec, o_spec, row_spec, qs_spec, ks_spec,
-                 sel_spec):
-        specs = [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec]
+    def call(kernel, name, table, steps, specs, outs, **params):
+        """``outs``: (block, result like, float32 accumulator) of each
+        result."""
+        in_specs = [specs.q, specs.k, specs.v, specs.out, specs.row,
+                    specs.row]
         if has_seg:
-            specs += [qs_spec, ks_spec]
-        return specs + [sel_spec] if has_sel else specs
+            in_specs += [specs.q_seg, specs.kv_seg]
+        if has_sel:
+            in_specs.append(specs.sel)
+        return _launch(functools.partial(kernel, steps=steps, **static),
+                       name, table, (B, H, steps), operands, in_specs,
+                       [out[:2] for out in outs], [out[2] for out in outs],
+                       **params)
 
-    # kv-major grid (b, h, i = kv block, j = q block): q is the reduction
-    # (innermost) dim; a dead causal tile re-names the first live q block
-    def q_pos(i, j):
-        return jnp.clip(j, (i * bk) // bq, n_q - 1) if causal else j
-
-    kv_major = _specs(D, Dv, bq, bk, q_pos, lambda i, j: i, group)
-    # one kernel or two? One, whenever what ``_fused_vmem`` counts fits half
-    # the core's vector memory; a longer head's dq accumulator (seq 131,072
-    # x 192 float32 is 134 MB) stays on the two kernels, whose dq lives a q
-    # block at a time. Counted by form as the backward pass is traced
+    # kv-major walk: a kv block's q blocks in a run, dk and dv summed over
+    # them. One kernel or two? One, whenever what ``_fused_vmem`` counts
+    # fits half the core's vector memory; a longer head's dq accumulator
+    # (seq 131,072 x 192 float32 is 134 MB) stays on the two kernels, whose
+    # dq lives a q block at a time. Counted by form as the backward pass is
+    # traced
     vmem = _fused_vmem(Sq, D, Dv, bq, bk, q.dtype, has_seg, has_sel)
     fused = vmem <= _VMEM // 2
     tel.counter_add("attention.flash_bwd_fused" if fused
                     else "attention.flash_bwd_split")
-    # (block, result like, float32 accumulator) of the kv-major kernel.
+    table, steps = _table(Sq, Sk, bq, bk, causal, kv_major=True)
+    specs = _specs(D, Dv, bq, bk, steps, group)
     # K/V heads that ``group`` query heads share: each query head writes
     # the dk / dv of ITS scores ([B, H, Sk, .], a q-shaped tile spec with
     # kv rows), summed over the group below
     if group == 1:
-        outs = [(kv_major[1], k, (bk, D)), (kv_major[2], v, (bk, Dv))]
+        outs = [(specs.k, k, (bk, D)), (specs.v, v, (bk, Dv))]
     else:
-        per_head = _specs(D, Dv, bq, bk, q_pos, lambda i, j: i)
-        outs = [(per_head[1], jax.ShapeDtypeStruct((B, H, Sk, D), k.dtype),
+        per_head = _specs(D, Dv, bq, bk, steps)
+        outs = [(per_head.k, jax.ShapeDtypeStruct((B, H, Sk, D), k.dtype),
                  (bk, D)),
-                (per_head[2], jax.ShapeDtypeStruct((B, H, Sk, Dv), v.dtype),
+                (per_head.v, jax.ShapeDtypeStruct((B, H, Sk, Dv), v.dtype),
                  (bk, Dv))]
-    params = _PARAMS
     if fused:
-        # dq's rows leave the accumulator in the head's last kv pass: until
-        # then its block keeps one index, so nothing is written back
-        dq_spec = pl.BlockSpec(
-            (None, None, bq, D),
-            lambda b, h, i, j: (b, h, jnp.where(i == n_kv - 1, j, 0), 0))
-        outs.insert(0, (dq_spec, q, (n_q, bq, D)))
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary"),
-            vmem_limit_bytes=vmem)
-    grads = pl.pallas_call(
-        functools.partial(_bwd_kernel, n_q=n_q, n_kv=n_kv, fused=fused,
-                          **static),
-        grid=(B, H, n_kv, n_q),
-        in_specs=in_specs(*kv_major),
-        out_specs=[spec for spec, _, _ in outs],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for _, x, _ in outs],
-        scratch_shapes=[pltpu.VMEM(acc, jnp.float32) for _, _, acc in outs],
-        compiler_params=params,
-        interpret=pallas_mode.interpret(),
-        name="flash_bwd" if fused else "flash_dkdv",
-    )(*operands)
-    if group > 1:
-        grads = list(grads)
-        for n, like in ((-2, k), (-1, v)):
-            g = grads[n].astype(jnp.float32)
-            grads[n] = jnp.sum(g.reshape((B, H // group, group) + g.shape[2:]),
-                               axis=2).astype(like.dtype)
+        outs.insert(0, (specs.dq, q, (Sq // bq, bq, D)))
+    grads = list(call(
+        functools.partial(_bwd_kernel, fused=fused),
+        "flash_bwd" if fused else "flash_dkdv", table, steps, specs, outs,
+        **({"vmem_limit_bytes": vmem} if fused else {})))
+    seen = (Sq - 1) // bk + 1           # kv blocks with a tile in the table
+    for n, like in ((-2, k), (-1, v)):
+        g = grads[n]
+        if group > 1:
+            g = g.astype(jnp.float32)
+            g = jnp.sum(g.reshape((B, H // group, group) + g.shape[2:]),
+                        axis=2).astype(like.dtype)
+        if causal and seen * bk < Sk:   # ... the others were never written
+            g = jnp.where(jnp.arange(Sk)[:, None] < seen * bk, g, 0)
+        grads[n] = g
     if fused:
         return tuple(grads)
 
-    specs = _specs(D, Dv, bq, bk, lambda i, j: i, _kv_pos(causal, bq, bk),
-                   group)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, n_kv=n_kv, **static),
-        grid=(B, H, n_q, n_kv),
-        in_specs=in_specs(*specs),
-        out_specs=specs[0],
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_PARAMS,
-        interpret=pallas_mode.interpret(),
-        name="flash_dq",
-    )(*operands)
+    table, steps = _table(Sq, Sk, bq, bk, causal, kv_major=False)
+    specs = _specs(D, Dv, bq, bk, steps, group)
+    dq, = call(_dq_kernel, "flash_dq", table, steps, specs,
+               [(specs.q, q, (bq, D))])
     return (dq, *grads)
 
 

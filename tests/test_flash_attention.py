@@ -316,6 +316,98 @@ def test_tiles_come_from_the_shapes(shape, tiles):
     assert fa.full_tiles(shape[0]) == (tiles[0] == 512)
 
 
+# ------------------------------------------ the table of tiles a kernel walks
+
+
+# (n_q, n_kv, block_q, block_k)
+TABLES = {
+    "seq_8192": (16, 16, 512, 512),
+    "seq_2048": (4, 4, 512, 512),
+    "one_tile": (1, 1, 256, 256),
+    "more_queries_than_keys": (3, 2, 512, 512),
+    "more_keys_than_queries": (2, 3, 512, 512),
+    "q_tiles_of_512_kv_tiles_of_256": (3, 3, 512, 256),
+    "q_tiles_of_64_kv_tiles_of_256": (6, 2, 64, 256),
+    "the_decode_loops_8_rows": (1, 2, 8, 512),
+}
+
+
+@pytest.mark.parametrize("kv_major", [False, True],
+                         ids=["q_major", "kv_major"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("case", sorted(TABLES))
+def test_the_tile_table_lists_every_live_tile_once(case, causal, kv_major):
+    """``_tile_table``: every tile ``_tile_live`` finds a visible entry in
+    appears exactly once, in the walk's row order, and none that it calls
+    dead; ``CROSSED`` exactly where some column passes some row; the first
+    / last bits mark each q block's and each kv block's first and last
+    tile of the walk; ``_Q_OUT`` names every q block over ONE run of steps
+    that ends at the block's last tile (where the kernel writes its dq)."""
+    n_q, n_kv, bq, bk = TABLES[case]
+    table = fa._tile_table(n_q, n_kv, bq, bk, causal, kv_major)
+    assert table.dtype == np.int32 and table.shape[0] == 4
+    q, kv, flags, q_out = (table[c].tolist() for c in (
+        fa._Q, fa._KV, fa._FLAGS, fa._Q_OUT))
+    rect = ([(qi, ki) for ki in range(n_kv) for qi in range(n_q)] if kv_major
+            else [(qi, ki) for qi in range(n_q) for ki in range(n_kv)])
+    walk = list(zip(q, kv))
+    assert walk == [t for t in rect if fa._tile_live(*t, bq, bk, causal)]
+
+    def passes(qi, ki):     # some column of the tile lies right of some row
+        rows = qi * bq + np.arange(bq)[:, None]
+        cols = ki * bk + np.arange(bk)[None, :]
+        assert not causal or (rows >= cols).any()       # ... and it is live
+        return bool(causal and (cols > rows).any())
+    assert [bool(f & fa.CROSSED) for f in flags] == [
+        passes(*t) for t in walk]
+    for bit_first, bit_last, blocks in ((fa.Q_FIRST, fa.Q_LAST, q),
+                                        (fa.KV_FIRST, fa.KV_LAST, kv)):
+        first = {b: blocks.index(b) for b in set(blocks)}
+        last = {b: len(blocks) - 1 - blocks[::-1].index(b)
+                for b in set(blocks)}
+        assert [bool(f & bit_first) for f in flags] == [
+            first[b] == t for t, b in enumerate(blocks)]
+        assert [bool(f & bit_last) for f in flags] == [
+            last[b] == t for t, b in enumerate(blocks)]
+    # every q block has a tile (it sees kv block 0) and leaves once
+    runs = [b for t, b in enumerate(q_out) if t == 0 or q_out[t - 1] != b]
+    assert sorted(runs) == list(range(n_q))
+    ends = [t for t in range(len(q_out))
+            if t == len(q_out) - 1 or q_out[t + 1] != q_out[t]]
+    assert all(q[t] == q_out[t] and flags[t] & fa.Q_LAST for t in ends)
+    if not kv_major:
+        assert q_out == q
+    if case == "seq_8192":
+        assert (len(walk), sum(bool(f & fa.CROSSED) for f in flags)) == (
+            (136, 16) if causal else (256, 0))
+
+
+@pytest.mark.parametrize("seq, causal, launches, tiles, masked", [
+    (8192, True, 2, 136, 16),       # the three cells at 8,192 positions
+    (8192, False, 2, 256, 0),       # every tile, none masked
+    (2048, True, 2, 10, 4),         # olmoe_train_1chip
+    (256, True, 2, 1, 1),           # one tile: the diagonal crosses it
+    (131072, True, 3, 32896, 256),  # the far side: flash_dkdv and flash_dq
+])
+def test_the_counters_say_how_many_tiles_a_launch_walks(seq, causal, launches,
+                                                        tiles, masked):
+    """``attention.flash_tiles`` / ``attention.flash_tiles_masked``: steps
+    of the tile table a head, and those that pay the positional mask, added
+    once a traced launch (forward, backward): read as the gradient is
+    TRACED, nothing runs."""
+    x = jax.ShapeDtypeStruct((1, seq, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal))
+    before = tel.counters()
+    jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(x, x, x)
+    after = tel.counters()
+    assert {name: after[name] - before.get(name, 0) for name in (
+        "attention.flash_tiles", "attention.flash_tiles_masked")} == {
+            "attention.flash_tiles": launches * tiles,
+            "attention.flash_tiles_masked": launches * masked}
+
+
 # ------------------------------ what a recomputing checkpoint keeps by name
 
 
@@ -391,6 +483,13 @@ BACKWARD_CASES = {
     "more_queries_than_keys": (1536, 1024, 32, 32, jnp.float32, False, None),
     "uneven_blocks_of_64": (64, 192, 32, 32, jnp.float32, False, None),
     "one_tile": (256, 256, 32, 32, jnp.float32, True, None),
+    # the causal triangle where the tile table is no square: keys past the
+    # last query (kv blocks with no tile: dk = dv = 0 there), and q tiles
+    # of 512 rows over kv tiles of 256
+    "more_keys_than_queries_causal": (1024, 1536, 32, 32, jnp.float32, True,
+                                      None),
+    "tiles_of_512_by_256_causal": (1536, 768, 32, 32, jnp.float32, True,
+                                   None),
     "padding_mask": (1024, 1024, 32, 32, jnp.float32, False, "padding"),
     "padding_mask_causal": (1024, 1024, 32, 32, jnp.float32, True, "padding"),
     "packed_segments": (1024, 1024, 32, 32, jnp.float32, False, "packed"),
@@ -577,7 +676,9 @@ def test_a_call_without_a_selection_traces_to_the_parents_kernels(case):
     kernel body for kernel body, as at the parent commit of PR 37 (its
     text's hash, ``tests/data/lm_before_keye_vl2.json``). The selection
     operand and the grouped heads changed no program that does not ask
-    for them."""
+    for them. (Since PR 39 the kernels walk a tile table through scalar
+    prefetch: the three hashes are that tree's, ``re_pinned_in_pr39`` in
+    the data file; a later change to these programs shows here.)"""
     import hashlib
     import json
     import os

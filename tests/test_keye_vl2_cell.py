@@ -397,7 +397,10 @@ def test_the_loss_and_its_gradient_trace_to_the_parents_jaxpr(before, which):
     parent commit (its text's hash), and the same loss and gradient norm
     bit for bit. Grouped heads, the per-head norm, the indexer, the
     selection operand of the kernels and the new counters changed no
-    equation of theirs."""
+    equation of theirs. (The two ``*_flash_step`` jaxprs are PR 39's,
+    whose flash kernels walk a tile table: ``re_pinned_in_pr39`` in the
+    data file; their losses, gradient norms and parameter trees are the
+    parent's of PR 37 still, bit for bit.)"""
     make, seq, rows, attention = step_configs()[which]
     loss_fn, params, batch, _ = lm.make_train_setup(
         make(), seq_len=seq, batch_size=rows, seed=0, attention=attention)
