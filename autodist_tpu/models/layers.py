@@ -366,7 +366,8 @@ class RouterConfig:
 
 
 def linear(features, dtype, name):
-    """A projection without a bias (every one of Kimi-Linear's)."""
+    """A projection without a bias (every one of Kimi-Linear's, a gated
+    short convolution's two)."""
     return nn.Dense(features, dtype=dtype, use_bias=False, name=name)
 
 
@@ -455,6 +456,29 @@ def causal_conv(x, w):
     K, S = w.shape[0], x.shape[1]
     padded = jnp.pad(x, [(0, 0), (K - 1, 0), (0, 0)])
     return sum(padded[:, j:j + S] * w[j] for j in range(K))
+
+
+class ShortConv(nn.Module):
+    """LFM2's gated short convolution as a token mixer: ``[B, C, u] =
+    W_in x`` (d -> 3 d, split in thirds in that order), ``z = B * u``, a
+    depthwise causal convolution of ``kernel_size`` taps (``conv_L_cache``)
+    over z (one filter a channel, zeros before the sequence's start, no
+    activation), ``y = W_out (C * conv(z))``. No bias, no position signal,
+    no state beyond the last ``kernel_size - 1`` inputs."""
+    kernel_size: int
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        bcu = linear(3 * d, self.dtype, "in_proj")(x)
+        w = self.param("conv", nn.initializers.variance_scaling(
+            1.0, "fan_in", "uniform", in_axis=0, out_axis=1),
+            (self.kernel_size, d))
+        with scopes.scope(scopes.CONV_CORE):
+            b, c, u = jnp.split(bcu, 3, axis=-1)
+            y = c * causal_conv(b * u, w.astype(self.dtype))
+        return linear(d, self.dtype, "out_proj")(y)
 
 
 class KimiDeltaAttention(nn.Module):
@@ -588,8 +612,9 @@ class TransformerBlock(nn.Module):
     public config (``models/lm.py:LMConfig``): with ``num_experts`` the
     feed-forward is the routed SwiGLU one and ``mlp_dim`` is ONE expert's
     width. A model whose layers differ names each block's token mixer by
-    its sizes (``kda`` or ``mla`` instead of the softmax attention above)
-    and gives a leading dense layer its SwiGLU width (``dense_dim``)."""
+    its sizes (``kda``, ``mla`` or ``conv_size`` instead of the softmax
+    attention above) and gives a leading dense layer its SwiGLU width
+    (``dense_dim``)."""
     num_heads: int
     head_dim: int
     mlp_dim: int
@@ -611,10 +636,11 @@ class TransformerBlock(nn.Module):
     num_kv_heads: Optional[int] = None
     qk_head_norm: bool = False
     indexer: Optional[IndexerConfig] = None
+    conv_size: int = 0
 
     def _mix(self, h, mask, cache, cursor, alive, return_kv, positions):
         """The block's token mixer on the normed input."""
-        if self.kda is None and self.mla is None:
+        if self.kda is None and self.mla is None and not self.conv_size:
             return MultiHeadAttention(
                 self.num_heads, self.head_dim, self.dtype, self.attn_fn,
                 decode_attn=self.decode_attn, use_bias=self.attention_bias,
@@ -627,8 +653,11 @@ class TransformerBlock(nn.Module):
         if cache is not None or return_kv:
             raise NotImplementedError(
                 "prefill and cached decode keep K/V rows only: a kda "
-                "layer's recurrent state and an mla layer's latent have no "
-                "cache yet")
+                "layer's recurrent state, an mla layer's latent and a conv "
+                "layer's last inputs have no cache yet")
+        if self.conv_size:
+            with scopes.scope(scopes.CONV_MIX):
+                return ShortConv(self.conv_size, self.dtype, name="conv")(h)
         if self.kda is not None:
             with scopes.scope(scopes.KDA):
                 return KimiDeltaAttention(self.kda, self.norm_eps, self.dtype,

@@ -6,11 +6,11 @@ words/sec ``lm1b_train.py:62-75``). Re-designed transformer-first for TPU —
 LSTMs serialize on the sequence axis and starve the MXU; a causal
 transformer with ``lax``-friendly static shapes is the idiomatic
 equivalent at the same objective (next-word prediction on lm1b). The token
-embedding and the lm_head are deliberately UNTIED so the big table can
+embedding and the lm_head are UNTIED by default so the big table can
 ride the sparse (ids, values) gradient wire (``models/layers.SparseEmbed``
-— a tied table would need dense gradients and is auto-kept dense). The big
-embedding table is the PartitionedPS stress case, as in the reference
-benchmark.
+— a tied table, ``LMConfig.tie_embedding``, has a dense gradient through
+the logits and is auto-kept dense). The big embedding table is the
+PartitionedPS stress case, as in the reference benchmark.
 """
 import dataclasses
 from typing import Any, Optional, Tuple
@@ -36,7 +36,7 @@ SHARE_LOAD = ROUTER_LOAD + ("chosen_pairs",)
 # what a sparse attention's indexer sows and the loss reports as
 # ``dsa.<name>``: the (query, key) pairs it chose, and all a query sees
 INDEXER_CHOICE = ("selected_pairs", "causal_pairs")
-LAYER_TYPES = ("attention", "kda", "mla")
+LAYER_TYPES = ("attention", "kda", "mla", "conv")
 YARN_KEYS = tuple(f.name for f in dataclasses.fields(YarnConfig))
 
 
@@ -81,6 +81,8 @@ class LMConfig:
     attention_bias: bool = True
     head_bias: bool = True
     embed_scale: bool = True        # token embedding x sqrt(d_model)
+    # logits = h E^T with E the token embedding: no ``lm_head`` of its own
+    tie_embedding: bool = False
     # > 0: the feed-forward is a routed SwiGLU one, ``experts_per_token``
     # of ``num_experts`` experts each of width ``mlp_dim``, no token
     # dropped; 0: the GELU MLP of width ``mlp_dim``
@@ -94,11 +96,14 @@ class LMConfig:
     seq_aux: bool = False
     # A model whose layers differ. ``layer_types[i]`` is layer i's token
     # mixer: "attention" (the softmax attention above), "kda" (Kimi Delta
-    # Attention: ``kda_*``) or "mla" (latent attention: the four widths
+    # Attention: ``kda_*``), "mla" (latent attention: the four widths
     # below; rotary on ``qk_rope_head_dim`` features iff ``rope_theta``,
-    # by ``rope_scaling``'s frequencies where it is given).
+    # by ``rope_scaling``'s frequencies where it is given) or "conv" (a
+    # gated short convolution of ``conv_size`` taps, ``conv_L_cache``, without
+    # a bias).
     # None = "attention" in every layer.
     layer_types: Optional[Tuple[str, ...]] = None
+    conv_size: int = 0
     kda_num_heads: int = 0
     kda_head_dim: int = 0
     kda_conv_size: int = 0
@@ -136,6 +141,12 @@ class LMConfig:
             raise ValueError(
                 "layer_types names one of %s for each of the %d layers, got "
                 "%r" % (LAYER_TYPES, self.num_layers, types))
+        if ("conv" in (types or ())) != bool(self.conv_size):
+            raise ValueError(
+                "conv_size is the taps of the layers layer_types names "
+                "'conv': got conv_size %d with %r" % (self.conv_size, types))
+        if self.tie_embedding and self.head_bias:
+            raise ValueError("a tied head is h E^T alone: head_bias is set")
         if self.router_activation not in ("softmax", "sigmoid"):
             raise ValueError("router_activation must be softmax|sigmoid, got "
                              "%r" % (self.router_activation,))
@@ -295,6 +306,34 @@ class LMConfig:
                    moe_renormalize=True, **kw)
 
     @classmethod
+    def lfm2_24b_a2b(cls, **kw):
+        """LFM2-24B-A2B as its ``config.json`` publishes it
+        (huggingface.co/LiquidAI/LFM2-24B-A2B, ``lfm2_moe``): 40 pre-norm
+        RMSNorm layers without a bias, three gated short convolutions (3
+        taps, ``conv_L_cache``) to one softmax attention (32 query heads of
+        64 over 8 K/V heads, a per-head RMSNorm of q and k, RoPE at theta
+        1e6), conv conv attention conv ten times; a dense SwiGLU of 11,776
+        in layers 0-1 (``num_dense_layers``), then 64 sigmoid-routed SwiGLU
+        experts of 1,536, 4 a token chosen by score + bias, gates
+        renormalised over the chosen, no shared expert; the head is the
+        embedding's transpose. ``num_layers`` cuts the published pattern
+        from its start. The tied head and the per-head norm are the LFM2
+        family's convention without a key in the row: assumptions the
+        benchmark's configuration file lists."""
+        n = kw.setdefault("num_layers", 40)
+        kw.setdefault("max_seq_len", 128000)
+        kw.setdefault("layer_types", tuple(
+            "attention" if i % 4 == 2 else "conv" for i in range(n)))
+        return cls(vocab_size=65536, d_model=2048, num_heads=32,
+                   num_kv_heads=8, qk_head_norm=True, mlp_dim=1536,
+                   norm="rmsnorm", norm_eps=1e-5, rope_theta=1e6,
+                   attention_bias=False, head_bias=False, embed_scale=False,
+                   tie_embedding=True, conv_size=3,
+                   first_k_dense_replace=2, dense_dim=11776, num_experts=64,
+                   experts_per_token=4, router_activation="sigmoid",
+                   moe_renormalize=True, **kw)
+
+    @classmethod
     def tiny(cls, **kw):
         return cls(vocab_size=128, d_model=32, num_layers=2, num_heads=2,
                    mlp_dim=64, max_seq_len=64, **kw)
@@ -342,6 +381,8 @@ class TransformerLM(nn.Module):
             kw["mla"] = MLAConfig(cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                                   cfg.qk_rope_head_dim, cfg.v_head_dim,
                                   cfg.rope_theta, yarn)
+        elif kind == "conv":
+            kw["conv_size"] = cfg.conv_size
         if kind == "attention" and (cfg.num_kv_heads or cfg.qk_head_norm
                                     or cfg.indexer_num_heads):
             kw.update(num_kv_heads=cfg.num_kv_heads,
@@ -390,6 +431,11 @@ class TransformerLM(nn.Module):
 
     def _head(self, x):
         cfg = self.config
+        if cfg.tie_embedding:
+            # (``_embed`` ran before on every path: the table is there)
+            table = self.get_variable("params", "embed")["embedding"]
+            return jnp.dot(x.astype(jnp.float32),
+                           table.astype(jnp.float32).T)
         return nn.Dense(cfg.vocab_size, dtype=jnp.float32,
                         use_bias=cfg.head_bias, name="lm_head")(x)
 
@@ -497,6 +543,15 @@ def auto_flash_attention(seq_len: int, head_dim: int, backend: str) -> bool:
       from seq 1024: 5.1 against 10.6 ms, not a step reading).
     - from seq 8192 every width and length, as before this rule: XLA's
       scores stop fitting in HBM there, so this is memory and not speed.
+      Heads of 64 there ARE read in a step since PR 40
+      (``lfm2_24b_a2b_train_1chip``, [1, 8192, 32, 64] over 8 K/V heads,
+      one layer; ``benchmark/records/pr40_runs.jsonl``): ``flash_fwd``
+      4.56 ms and ``flash_bwd`` 9.60 ms a launch, 15.95 ms under the
+      core's scope with the layout passes = 26 % of the bf16 peak by the
+      model's causal FLOPs, what heads of 128 cost a head (Keye-VL-2.0's
+      32 heads of 128 with a selection: 5.21 + 9.69): a live tile's
+      contraction over 64 features fills half the MXU's depth, so half
+      the FLOPs buy no time. 5.5 % of that step; no rule or tile changed.
     - any backend but a TPU: XLA (the kernel would run interpreted)."""
     if backend != "tpu":
         return False
@@ -569,9 +624,13 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     ``LMConfig.kimi_linear_48b_a3b()`` (KDA and latent-attention layers
     by ``layer_types``, a leading dense layer, sigmoid-routed experts of
     which ``experts_held`` are here, a shared expert) takes the same
-    path; its loss is the NLL alone. Whether each block is recomputed in
-    the backward pass is :func:`auto_remat_blocks`'s to say, from the
-    parameters it counts and the chip's memory."""
+    path; its loss is the NLL alone, and so is
+    ``LMConfig.lfm2_24b_a2b()``'s (gated short convolutions beside
+    grouped-query attention by ``layer_types``, two leading dense layers,
+    sigmoid-routed experts of which ``experts_held`` are here, a tied
+    head: either head takes the embedding's transpose). Whether each block
+    is recomputed in the backward pass is :func:`auto_remat_blocks`'s to
+    say, from the parameters it counts and the chip's memory."""
     cfg = config or LMConfig()
     if lean_head == "auto":
         lean_head = (cfg.vocab_size >= 32768
@@ -597,7 +656,7 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
             seq_len, head_dim, jax.default_backend())):
         from autodist_tpu.ops.flash_attention import make_flash_attn_fn
         attn_fn = make_flash_attn_fn(causal=True)
-    flash_layers = (sum(t != "kda" for t in types)
+    flash_layers = (sum(t in ("attention", "mla") for t in types)
                     if attn_fn is not None else 0)
     kda_kernel_layers = 0
     if "kda" in types:
@@ -667,13 +726,15 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
             from autodist_tpu.ops.xent import chunked_softmax_xent
             h, router_loss = forward(params, tokens[:, :-1],
                                      TransformerLM.hidden)
-            head = params["params"]["lm_head"]
-            bias = (head["bias"].astype(jnp.float32) if cfg.head_bias
+            p = params["params"]
+            kernel = (p["embed"]["embedding"].T if cfg.tie_embedding
+                      else p["lm_head"]["kernel"])
+            bias = (p["lm_head"]["bias"].astype(jnp.float32)
+                    if cfg.head_bias
                     else jnp.zeros((cfg.vocab_size,), jnp.float32))
             nll = chunked_softmax_xent(
-                h.reshape(-1, cfg.d_model),
-                head["kernel"].astype(jnp.float32), bias,
-                targets.reshape(-1))
+                h.reshape(-1, cfg.d_model), kernel.astype(jnp.float32),
+                bias, targets.reshape(-1))
             return mean_loss(nll, router_loss)
         logits, router_loss = forward(params, tokens[:, :-1], None)
         logp = jax.nn.log_softmax(logits)

@@ -63,6 +63,8 @@ MLA_CORE = "mla_core"
 DSA_INDEX = "dsa_index"
 DSA_TOPK = "dsa_topk"
 DSA_CORE = "dsa_core"
+CONV_MIX = "conv_mix"
+CONV_CORE = "conv_core"
 PREFILL = "prefill"
 DECODE = "decode"
 INSERT = "insert"
@@ -114,6 +116,12 @@ SCOPES: Dict[str, str] = {
     DSA_CORE: "inside attention: the attention core over grouped K/V heads "
               "and, with an indexer, the chosen keys alone (the flash kernels "
               "with a selection on a TPU, XLA's scores elsewhere)",
+    CONV_MIX: "inside attention: a gated short convolution mixer (the "
+              "input projection to three times the width, the two gates, the "
+              "depthwise causal convolution, the output projection)",
+    CONV_CORE: "inside conv_mix: what lies between the two projections, the "
+               "split in thirds, the two element-wise gates and the short "
+               "depthwise causal convolution",
     PREFILL: "serving: the prompt pass of a prefill bucket",
     DECODE: "serving: one cached decode step",
     INSERT: "serving: writing admitted rows into the decode state",

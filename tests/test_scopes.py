@@ -12,7 +12,8 @@ The tracing contracts (``telemetry/scopes.py``, docs/observability.md
   scores alone and hands ``moe.aux_loss`` out beside the loss; a Keye-VL-2.0
   model's step has ``dsa_index``, ``dsa_topk`` and ``dsa_core`` inside
   ``attention``, recomputes neither of the first two, and hands out the
-  pairs its indexers chose;
+  pairs its indexers chose; an LFM2 model's step has ``conv_mix`` >
+  ``conv_core`` inside ``attention``, the projections outside the core;
 - the map is computed ON DEMAND: a fit, traced or not, lowers and
   compiles nothing extra;
 - ``runner.readback`` is tiled by its two children (the wait for the
@@ -308,6 +309,56 @@ def test_a_keye_vl2_step_names_its_indexer_choice_and_core_and_counts_the_pairs(
     assert not any("rematted_computation" in o or "transpose(" in o
                    for o in index + topk)
     assert any("transpose(" in o for o in core)
+
+
+def test_an_lfm2_step_names_its_conv_mixers_and_their_cores():
+    """``conv_mix`` holds a gated short convolution whole (both
+    projections' matmuls), ``conv_core`` what lies between them (the gates
+    and the 3-tap convolution: no matmul), each inside ``attention``, so
+    ``attn_ms_per_step`` stays the mixers' total; the grouped core of the
+    one attention layer is under ``dsa_core``; with every block recomputed
+    the core is forward, backward and recomputed."""
+    from tests.test_lfm2_moe import tiny_config
+    chip = lm._chip_hbm_bytes
+    lm._chip_hbm_bytes = lambda: 1e5
+    try:
+        loss_fn, params, batch, _ = lm.make_train_setup(
+            tiny_config(), seq_len=16, batch_size=8)
+    finally:
+        lm._chip_hbm_bytes = chip
+    assert loss_fn.device_counters == (
+        "moe.max_expert_pairs", "moe.routed_pairs", "moe.chosen_pairs")
+    autodist_tpu.reset()
+    try:
+        ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
+        runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
+        runner.init(params)
+        counters = runner.run(batch)["counters"]
+        m = telemetry.scope_map(STEP)
+    finally:
+        autodist_tpu.reset()
+    # a replica's row of 16 positions, four routed layers, top-3
+    assert int(counters["moe.chosen_pairs"]) == 4 * 16 * 3
+    ops = [o for names in m.values() for o in names]
+    for scope in (scopes.CONV_MIX, scopes.CONV_CORE):
+        assert scope in scopes.SCOPES
+        inside = [o for o in ops if scope in components(o)
+                  and scopes.BLOCKS in components(o)]
+        assert inside and all(
+            components(o).index(scopes.BLOCKS)
+            < components(o).index(scopes.ATTENTION)
+            < components(o).index(scopes.CONV_MIX)
+            <= components(o).index(scope) for o in inside)
+    mix = [o for o in ops if scopes.CONV_MIX in components(o)]
+    core = [o for o in ops if scopes.CONV_CORE in components(o)]
+    assert any("dot_general" in o for o in mix)
+    assert not any("dot_general" in o for o in core)
+    assert any(o.endswith("/mul") for o in core)
+    assert any("transpose(" in o for o in core)
+    assert any("rematted_computation" in o for o in core)
+    grouped = [o for o in ops if scopes.DSA_CORE in components(o)]
+    assert any("dot_general" in o for o in grouped)
+    assert not any(scopes.CONV_MIX in components(o) for o in grouped)
 
 
 def test_partitioned_storage_gathers_under_the_params_scope():
