@@ -346,6 +346,9 @@ class TransformerLM(nn.Module):
     decode_attn: str = "reference"  # decode inner loop: "reference"|"flash"
     # recompute each block in the backward pass (make_train_setup's rule)
     remat_blocks: bool = False
+    # ... of whose routed layers the LAST this many keep their held
+    # experts' hidden products (``auto_kept_expert_layers``'s rule)
+    kept_expert_layers: int = 0
 
     def _embed(self, input_ids, positions):
         """Token embedding (scaled by sqrt(d) where the config says so)
@@ -408,13 +411,20 @@ class TransformerLM(nn.Module):
         # projection and rotation; a sparse attention's choice of keys,
         # 67 MB a layer at seq 8192 against its index scores and a choice
         # among them for every query. A name no op of the block carries
-        # keeps nothing)
+        # keeps nothing. The held experts' gate and up products are the
+        # other way round, 4 T E f bytes a layer, 369 MB at 8 experts of
+        # 1,408 on 8,192 tokens, against two of the layer's eleven expert
+        # matmuls: the last ``kept_expert_layers`` routed layers keep
+        # them, whose products live shortest)
         from autodist_tpu.ops.dsa import KEPT as DSA_CHOICE_KEPT
         from autodist_tpu.ops.flash_attention import KEPT as FLASH_CORE_KEPT
+        from autodist_tpu.parallel.expert import KEPT as HELD_EXPERTS_KEPT
+        kept = (KDA_CORE_OUT, FLASH_CORE_KEPT, DSA_CHOICE_KEPT)
+        if i >= cfg.num_layers - self.kept_expert_layers:
+            kept += (HELD_EXPERTS_KEPT,)
         block = nn.remat(
             TransformerBlock,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                KDA_CORE_OUT, FLASH_CORE_KEPT, DSA_CHOICE_KEPT)
+            policy=jax.checkpoint_policies.save_only_these_names(*kept)
         ) if self.remat_blocks else TransformerBlock
         return block(
             cfg.num_heads, cfg.head_dim or cfg.d_model // cfg.num_heads,
@@ -580,6 +590,46 @@ def auto_remat_blocks(param_count: int, num_layers: int,
             and 16.0 * param_count > hbm_bytes / 2)
 
 
+# of the chip's memory by the chip table, what the state at 16 B a parameter
+# and the kept products together leave to the step's other scratch. Drawn
+# where the v5e has LOADED a step, cold and from the compile cache (PERF.md
+# section 6, PR 41): DeepSeek-V2-Lite's cell with all five layers kept is
+# 0.751 of 16e9 by this count, the fullest of the four cells that hold a
+# share (Kimi-Linear's 0.669, Keye-VL-2.0's 0.688, LFM2's 0.659), and 15.36
+# GB on the chip with its other 3.15 GB of scratch. Nothing has been seen to
+# fail, so the line says what has been shown, not what is possible.
+KEPT_EXPERTS_HBM_LEFT = 0.24
+
+
+def auto_kept_expert_layers(remat_blocks: bool, param_count: int,
+                            routed_layers: int, hbm_bytes: Optional[float],
+                            tokens: int,
+                            held_stack: Optional[Tuple[int, int, int]],
+                            itemsize: int = 2) -> int:
+    """Of a recomputed model's routed layers, how many keep their held
+    experts' gate and up products across the recomputation
+    (``parallel/expert.py:KEPT``; the LAST so many, ``TransformerLM._block``)
+    and so run 9 expert matmuls a step where the others run 11? As many as
+    fit: a layer keeps two ``[tokens, E, f]`` arrays of ``itemsize`` bytes,
+    ``held_stack`` being the held gate stack's ``[E, d, f]``, and the state
+    at 16 B a parameter plus what is kept leaves ``KEPT_EXPERTS_HBM_LEFT``
+    of the chip's memory free. None where blocks are not recomputed
+    (nothing is made twice), where no share is held (``held_stack`` None:
+    the sorted form's grouped matmuls carry no name) and off a TPU."""
+    if not remat_blocks or held_stack is None or hbm_bytes is None:
+        return 0
+    a_layer = held_expert_kept_bytes(tokens, held_stack, itemsize)
+    room = (1.0 - KEPT_EXPERTS_HBM_LEFT) * hbm_bytes - 16.0 * param_count
+    return int(min(routed_layers, max(0.0, room) // a_layer))
+
+
+def held_expert_kept_bytes(tokens: int, held_stack: Tuple[int, int, int],
+                           itemsize: int = 2) -> int:
+    """What one routed layer keeps under :data:`parallel.expert.KEPT`."""
+    n_held, _, width = held_stack
+    return 2 * itemsize * tokens * n_held * width
+
+
 def _chip_hbm_bytes() -> Optional[float]:
     """The attached chip's memory by the chip table, None off a TPU."""
     device = jax.devices()[0]
@@ -671,13 +721,24 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         lambda key, ids: TransformerLM(cfg).init(key, ids)["params"])(
         rng, jnp.zeros((1, seq_len), jnp.int32))}
     param_count = sum(a.size for a in jax.tree_util.tree_leaves(variables))
-    remat_blocks = auto_remat_blocks(param_count, cfg.num_layers,
-                                     _chip_hbm_bytes())
-    model = TransformerLM(cfg, attn_fn=attn_fn, remat_blocks=remat_blocks)
+    hbm_bytes = _chip_hbm_bytes()
+    remat_blocks = auto_remat_blocks(param_count, cfg.num_layers, hbm_bytes)
+    # (leading dense layers route nothing)
+    routed_layers = (max(0, cfg.num_layers - cfg.first_k_dense_replace)
+                     if cfg.num_experts else 0)
+    routed = routed_layers > 0
+    held_stack = (None if cfg.experts_held is None else
+                  (len(cfg.experts_held), cfg.d_model, cfg.mlp_dim))
+    # (a replica sees no more tokens a step than the whole batch)
+    kept = (batch_size * seq_len, held_stack, jnp.dtype(cfg.dtype).itemsize)
+    kept_expert_layers = auto_kept_expert_layers(
+        remat_blocks, param_count, routed_layers, hbm_bytes, *kept)
+    kept_expert_bytes = kept_expert_layers and (
+        kept_expert_layers * held_expert_kept_bytes(*kept))
+    model = TransformerLM(cfg, attn_fn=attn_fn, remat_blocks=remat_blocks,
+                          kept_expert_layers=kept_expert_layers)
     router_load = SHARE_LOAD if cfg.experts_held is not None else ROUTER_LOAD
     router_losses = cfg.router_activation == "softmax"
-    # (leading dense layers route nothing)
-    routed = cfg.num_experts and cfg.first_k_dense_replace < cfg.num_layers
     indexed = bool(cfg.indexer_num_heads)
 
     def forward(params, ids, method):
@@ -720,6 +781,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         tel.gauge_set("attention.kda_kernel_layers", kda_kernel_layers)
         tel.gauge_set("model.remat_blocks",
                       cfg.num_layers if remat_blocks else 0)
+        tel.gauge_set("model.kept_expert_layers", kept_expert_layers)
+        tel.gauge_set("model.kept_expert_bytes", kept_expert_bytes)
         tokens = batch["tokens"]
         targets = tokens[:, 1:]
         if lean_head:

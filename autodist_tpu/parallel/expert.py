@@ -52,6 +52,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as gmm_kernel
 from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as tgmm_kernel
 
@@ -384,6 +385,13 @@ def _pairs_per_expert(pair_expert, n_experts):
                    axis=0, dtype=jnp.int32)
 
 
+# what a block recomputed in the backward pass keeps of the held experts by
+# name (``models/lm.py:TransformerLM._block``, as ``ops/flash_attention.py:
+# KEPT`` and ``ops/dsa.py:KEPT`` are kept): the gate and the up projection's
+# products [T, E, f], which SiLU's and the product's backward read
+KEPT = "held_experts_kept"
+
+
 def _held_experts(tokens, gate, expert, w_gate, w_up, w_down, held):
     """The held experts' part of the routed sum, ([T, d] float32, pairs per
     held expert [E]): EVERY held expert on EVERY token, its hidden
@@ -404,15 +412,22 @@ def _held_experts(tokens, gate, expert, w_gate, w_up, w_down, held):
     static T k rows that is no more wherever E <= k (Kimi-Linear's share:
     8 and 8) and E / k of it otherwise: DeepSeek-V2-Lite's 8 held at k = 6
     run 1.33 x T k rows, which is also 1.33 x what this chip's experts
-    receive in the 8-way deployment (8 chips' T k pairs over 8 chips)."""
+    receive in the 8-way deployment (8 chips' T k pairs over 8 chips).
+
+    The two hidden products carry the name :data:`KEPT`: of a layer's 11
+    matmuls in a step whose block is recomputed (3 forward, 6 backward, the
+    two the backward reads made again) a policy that saves the name leaves
+    9, for 4 T E f bytes. The identity under any other policy or none."""
     dt = tokens.dtype
     with scopes.scope(scopes.MOE_ROUTE):
         chose = expert[:, :, None] == jnp.asarray(held)[None, None, :]
         weight = jnp.sum(jnp.where(chose, gate[:, :, None], 0.0), axis=1)
         counts = jnp.sum(chose, axis=(0, 1), dtype=jnp.int32)     # [E]
     with scopes.scope(scopes.MOE_EXPERTS):
-        h = (jax.nn.silu(jnp.einsum("td,edf->tef", tokens, w_gate.astype(dt)))
-             * jnp.einsum("td,edf->tef", tokens, w_up.astype(dt)))
+        g, u = (checkpoint_name(
+            jnp.einsum("td,edf->tef", tokens, w.astype(dt)), KEPT)
+            for w in (w_gate, w_up))
+        h = jax.nn.silu(g) * u
         h = (h.astype(jnp.float32) * weight[:, :, None]).astype(dt)
         out = jnp.einsum("tef,efd->td", h, w_down.astype(dt),
                          preferred_element_type=jnp.float32)

@@ -737,7 +737,8 @@ PHASES = {"setup.build": None, "setup.capture": "setup.build",
           "setup.first_step": None}
 TRACE_TIME_GAUGES = ("lean_head.chunks", "lean_head.chunk_width",
                      "lean_head.dead_cols", "attention.flash_layers",
-                     "attention.kda_kernel_layers", "model.remat_blocks")
+                     "attention.kda_kernel_layers", "model.remat_blocks",
+                     "model.kept_expert_layers", "model.kept_expert_bytes")
 
 
 @pytest.fixture(scope="module")
@@ -838,6 +839,31 @@ def test_a_gauge_set_at_trace_time_is_in_the_account(set_up, gauge):
     assert gauge in set_up["account"]["gauges"]
     if gauge in ("lean_head.chunks", "lean_head.chunk_width"):
         assert set_up["account"]["gauges"][gauge] > 0
+
+
+@pytest.mark.parametrize("layers_that_fit", [0, 1, 2])
+def test_the_kept_expert_layers_are_gauges_of_the_traced_loss(
+        monkeypatch, layers_that_fit):
+    """``model.kept_expert_layers`` / ``model.kept_expert_bytes`` beside
+    ``model.remat_blocks``, set as the loss is traced: a tiny model that
+    holds 2 of its 8 experts in two routed layers, on a chip made so small
+    that its blocks are recomputed and so many layers' products fit."""
+    from tests.test_held_experts_kept import tiny_share
+    cfg, params, _ = tiny_share()
+    count = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    a_layer = lm.held_expert_kept_bytes(4 * 16, (2, 32, 16), itemsize=4)
+    assert a_layer == 2 * 4 * 64 * 2 * 16
+    hbm = (16 * count + (layers_that_fit + 0.5) * a_layer) / (
+        1 - lm.KEPT_EXPERTS_HBM_LEFT)
+    monkeypatch.setattr(lm, "_chip_hbm_bytes", lambda: hbm)
+    loss_fn, params, batch, _ = lm.make_train_setup(
+        cfg, seq_len=16, batch_size=4)
+    telemetry.reset()
+    jax.eval_shape(loss_fn, params, batch)
+    gauges = telemetry.get_recorder().gauges()
+    assert gauges["model.remat_blocks"] == 3
+    assert gauges["model.kept_expert_layers"] == layers_that_fit
+    assert gauges["model.kept_expert_bytes"] == layers_that_fit * a_layer
 
 
 def build_linear(builder, ad=None):
