@@ -488,29 +488,33 @@ class KimiDeltaAttention(nn.Module):
     (``ops/kda.py``), a per-head RMSNorm gated by a sigmoid, the output
     projection. No bias on a projection; no position signal. The decay is
     ``-exp(A_log) * softplus(f(x) + dt_bias)`` with A_log per head, f and
-    the output gate low-rank through ``head_dim`` features."""
+    the output gate low-rank through ``head_dim`` features.
+
+    Where the delta rule runs as kernels (``ops/kda.py:runs_as_kernels``:
+    heads of whole 128-lane tiles), everything element-wise between the
+    projections and the core, and between the core and ``o_proj``, runs as
+    one pass over HBM a direction in the kernels' own [B, S, H * d] layout
+    (``kda_pre``, ``kda_post``); narrower heads take the ``jnp`` form
+    below, which is what the fused passes are tested against."""
     cfg: KDAConfig
     norm_eps: float
     dtype: Dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):
-        from autodist_tpu.ops.kda import kda_chunked
+        from autodist_tpu.ops import kda
         H, D, K = self.cfg.num_heads, self.cfg.head_dim, self.cfg.conv_size
         dense = lambda n, name: linear(n, self.dtype, name)  # noqa: E731
-        heads = lambda t: t.reshape(t.shape[:-1] + (H, D))  # noqa: E731
+        # (the core and the passes around it hold no parameter: an init
+        # traces no kernel for them)
+        fused = not self.is_initializing() and kda.runs_as_kernels(D, D)
 
-        def short_conv(name):
+        def projected(name):
             w = self.param(name + "_conv", nn.initializers.variance_scaling(
                 1.0, "fan_in", "uniform", in_axis=0, out_axis=1), (K, H * D))
-            return heads(nn.silu(causal_conv(dense(H * D, name + "_proj")(x),
-                                             w.astype(self.dtype))))
+            return dense(H * D, name + "_proj")(x), w
 
-        q, k, v = short_conv("q"), short_conv("k"), short_conv("v")
-        l2 = lambda t: t * jax.lax.rsqrt(  # noqa: E731
-            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-        q = l2(q.astype(jnp.float32)) * D ** -0.5
-        k = l2(k.astype(jnp.float32))
+        (xq, wq), (xk, wk), (xv, wv) = (projected(n) for n in "qkv")
         a_log = self.param("A_log", lambda key, shape: jnp.log(
             jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)), (H,))
         # softplus(dt_bias) log-uniform in [1e-3, 1e-1]
@@ -519,25 +523,58 @@ class KimiDeltaAttention(nn.Module):
                 jax.random.uniform(key, shape, jnp.float32,
                                    np.log(1e-3), np.log(1e-1)))), (H * D,))
         f = dense(H * D, "f_b_proj")(dense(D, "f_a_proj")(x))
-        g = -jnp.exp(a_log)[:, None] * heads(
-            jax.nn.softplus(f.astype(jnp.float32) + dt_bias))
         beta = nn.sigmoid(dense(H, "b_proj")(x).astype(jnp.float32))
-        if self.is_initializing():
-            # (the core holds no parameter: an init traces no kernel for it)
-            o = v.astype(self.dtype)
-        else:
+        if fused:
+            q, k, v, g = kda.kda_pre(xq, xk, xv, f, wq, wk, wv, a_log,
+                                     dt_bias, self.dtype)
             with scopes.scope(scopes.KDA_SCAN):
-                o, _ = kda_chunked(q, k, v, g, beta, self.dtype)
+                o, _ = kda.kda_whole_chunks(q, k, v, g, beta, self.dtype)
+        else:
+            q, k, v, g = kda_inputs(xq, xk, xv, f, wq, wk, wv, a_log, dt_bias,
+                                    self.dtype)
+            if self.is_initializing():
+                o = v.astype(self.dtype)
+            else:
+                with scopes.scope(scopes.KDA_SCAN):
+                    o, _ = kda.kda_chunked(q, k, v, g, beta, self.dtype)
         # a block recomputed in the backward pass keeps this (and, by the
         # same name, the kernels' per-chunk states) and does not run the
         # core again (``models/lm.py:TransformerLM._block``)
         o = checkpoint_name(o, KDA_CORE_OUT)
         scale = self.param("o_norm", nn.initializers.ones, (D,))
-        gate = nn.sigmoid(heads(
-            dense(H * D, "g_b_proj")(dense(D, "g_a_proj")(x))
-        ).astype(jnp.float32))
-        o = (rms_normalize(o, self.norm_eps) * scale * gate).astype(self.dtype)
-        return dense(x.shape[-1], "o_proj")(o.reshape(o.shape[:-2] + (H * D,)))
+        gate = dense(H * D, "g_b_proj")(dense(D, "g_a_proj")(x))
+        gated = kda.kda_post if fused else kda_output
+        return dense(x.shape[-1], "o_proj")(
+            gated(o, gate, scale, self.norm_eps, self.dtype))
+
+
+def kda_inputs(xq, xk, xv, f, wq, wk, wv, a_log, dt_bias, dtype):
+    """The ``jnp`` form of what lies between a KDA mixer's projections and
+    its delta rule: the outputs [B, S, H * d] of ``q_proj``, ``k_proj``,
+    ``v_proj`` and ``f_b_proj``, the [K, H * d] filters, ``A_log`` [H],
+    ``dt_bias`` [H * d] -> q, k (float32, L2-normalised per head, q times
+    d^-0.5), v (``dtype``) and the log decay g (float32), [B, S, H, d]."""
+    d = xq.shape[-1] // a_log.shape[0]
+    heads = lambda t: t.reshape(t.shape[:-1] + (-1, d))  # noqa: E731
+    q, k, v = (heads(nn.silu(causal_conv(t, w.astype(dtype))))
+               for t, w in ((xq, wq), (xk, wk), (xv, wv)))
+    l2 = lambda t: t * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+    q = l2(q.astype(jnp.float32)) * d ** -0.5
+    k = l2(k.astype(jnp.float32))
+    g = -jnp.exp(a_log)[:, None] * heads(
+        jax.nn.softplus(f.astype(jnp.float32) + dt_bias))
+    return q, k, v, g
+
+
+def kda_output(o, gate, o_norm, eps, dtype):
+    """The ``jnp`` form of what lies between the delta rule and ``o_proj``:
+    o [B, seq, H, d], the gate projection's output [B, seq, H * d] and
+    ``o_norm`` [d] -> ``rms_normalize(o) * o_norm * sigmoid(gate)``,
+    [B, seq, H * d] in ``dtype``."""
+    gate = nn.sigmoid(gate.reshape(o.shape).astype(jnp.float32))
+    o = (rms_normalize(o, eps) * o_norm * gate).astype(dtype)
+    return o.reshape(o.shape[:-2] + (-1,))
 
 
 class LatentAttention(nn.Module):

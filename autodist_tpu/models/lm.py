@@ -779,6 +779,9 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         # what the rule decided, once per trace, host side
         tel.gauge_set("attention.flash_layers", flash_layers)
         tel.gauge_set("attention.kda_kernel_layers", kda_kernel_layers)
+        # (one rule for both: where the delta rule runs as kernels, the
+        # mixer's element-wise passes around it run fused)
+        tel.gauge_set("attention.kda_fused_mixer_layers", kda_kernel_layers)
         tel.gauge_set("model.remat_blocks",
                       cfg.num_layers if remat_blocks else 0)
         tel.gauge_set("model.kept_expert_layers", kept_expert_layers)

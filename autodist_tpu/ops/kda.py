@@ -48,6 +48,28 @@ Two renderings of these equations, chosen by the head widths
   carried by ``lax.scan``; autodiff's residuals of the scan are one state
   per chunk.
 
+Around the core, by the same rule: where the delta rule runs as kernels,
+everything element-wise the mixer does between its projections and the
+core (``kda_pre``: the K-tap causal filter, SiLU, the per-head L2 norm, the
+decay's softplus) and between the core and ``o_proj`` (``kda_post``: the
+per-head RMSNorm and its sigmoid gate) is ONE pass over HBM a direction,
+each a ``jax.custom_vjp`` of two pallas kernels (``kda_pre_fwd`` /
+``kda_pre_bwd``, ``kda_post_fwd`` / ``kda_post_bwd``) that read and write
+the layout the core's kernels read: [B, S, H * d], lane-dense, padded to
+whole chunks by the kernel that writes it. A grid step is ``ROW_TILE`` rows
+of ``HEAD_LANES`` lanes (whole heads: a head's sum of squares is reduced
+and spread again inside the tile and never passes through HBM); the filter
+reads the last rows of the tile before its own through a second BlockSpec
+on the same array (zeros before the sequence's start), its gradient walks
+the tiles last to first and carries what a tile owes the rows before it
+in VMEM; the gradients of what has no row axis (the filters, ``dt_bias``,
+``A_log``, ``o_norm``) add up in a block that stays resident over a lane
+block's tiles. The backward kernels make the forward's intermediates again
+from the projections' outputs, their only residuals: no float32 tensor is
+kept. Arithmetic in the tile is float32 whatever ``dtype`` (XLA's form, which
+narrower heads keep in ``models/layers.py:kda_inputs`` / ``kda_output`` and
+which the passes are tested against, filters and gates in ``dtype``).
+
 Numerics. Only the DIFFERENCES exp(G_i - G_l), l <= i, are <= 1: written
 as (q exp(G)) (k exp(-G))^T the second factor overflows float32 once a
 channel decays by e^-88 inside a chunk (g = -1.4 a token does). So M and P
@@ -573,6 +595,18 @@ def runs_as_kernels(dk: int, dv: int) -> bool:
     return True
 
 
+def kda_whole_chunks(q, k, v, g, beta, dtype):
+    """The kernels on operands already in their layout: q, k, g float32
+    [B, N * CHUNK, H * d_k] and v [.., H * d_v] (rows past the sequence's
+    end neither decay nor write: zeros), beta [B, S, H] ->
+    (o [B, N * CHUNK, H * d_v] in ``dtype``, the final state TRANSPOSED
+    [B, H, d_v, d_k] float32)."""
+    beta = jnp.pad(beta.astype(jnp.float32),
+                   [(0, 0), (0, q.shape[1] - beta.shape[1]), (0, 0)])
+    return _kda_kernels(q, k, v, g, jnp.swapaxes(beta, 1, 2)[..., None],
+                        dtype, pallas_mode.interpret())
+
+
 def _kda_pallas(q, k, v, g, beta, dtype=None):
     dt = dtype or q.dtype
     B, S, H, dk = q.shape
@@ -585,11 +619,353 @@ def _kda_pallas(q, k, v, g, beta, dtype=None):
         return t.reshape(B, S + pad, -1)
 
     f32 = jnp.float32
-    o, final = _kda_kernels(
+    o, final = kda_whole_chunks(
         chunks(q, f32), chunks(k, f32), chunks(v, v.dtype), chunks(g, f32),
-        jnp.swapaxes(chunks(beta[..., None], f32), 1, 2)[..., None], dt,
-        pallas_mode.interpret())
+        beta, dt)
     return (o[:, :S].reshape(B, S, H, dv), jnp.swapaxes(final, -1, -2))
+
+
+# ------------------------------------- the mixer's passes around the core
+
+ROW_TILE = 256   # rows of [B, S, H * d] one grid step of a pass works on
+_HALO = 16       # rows a step reads of the tile before its own: one
+#                  bfloat16 tile, of which the filter's K - 1 <= 8 count
+_PASS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=64 * 2 ** 20)
+
+
+def _head_sums(x, d):
+    """[R, h * d] float32 -> the same shape, every lane holding the sum
+    over its own head's d lanes: reduced and spread again inside the
+    tile, so a row statistic never passes through HBM."""
+    return jnp.concatenate([jnp.broadcast_to(jnp.sum(
+        x[:, h:h + d], axis=1, keepdims=True), (x.shape[0], d))
+        for h in range(0, x.shape[1], d)], axis=1)
+
+
+def _sigmoid(x):
+    """1 / (1 + exp(-x)) as one transcendental and no divide."""
+    return 0.5 * jnp.tanh(0.5 * x) + 0.5
+
+
+def _live_rows(ref, tile, seq):
+    """[rows, 1]: which rows of tile ``tile``, shaped like ``ref``'s block,
+    lie inside the sequence."""
+    rows = ref.shape[0]
+    return (tile * rows + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0)) < seq
+
+
+def _taps(ext, K, rows):
+    """The K shifted views of a tile behind 8 rows of the tile before it:
+    tap j is the input of K - 1 - j rows ago."""
+    return [ext[8 - (K - 1) + j:8 - (K - 1) + j + rows] for j in range(K)]
+
+
+def _filtered(x_ref, halo_ref, w_ref, live, first):
+    """The K-tap causal filter of one tile, float32: (y, sigmoid(y), the
+    taps). Rows past the sequence's end read as zeros, and so do the rows
+    before its start."""
+    f32 = jnp.float32
+    x = jnp.where(live, x_ref[...].astype(f32), 0.0)
+    halo = jnp.where(first, 0.0, halo_ref[...].astype(f32)[_HALO - 8:])
+    w = w_ref[...].astype(f32)
+    taps = _taps(jnp.concatenate([halo, x]), w.shape[0], x.shape[0])
+    y = sum(w[j:j + 1] * tap for j, tap in enumerate(taps))
+    return y, _sigmoid(y), taps
+
+
+def _l2(s, d):
+    """(1 / sqrt(sum over the head of s^2 + 1e-6), s times it)."""
+    r = jax.lax.rsqrt(_head_sums(s * s, d) + 1e-6)
+    return r, s * r
+
+
+def _pre_fwd_kernel(xq_ref, xk_ref, xv_ref, f_ref, hq_ref, hk_ref, hv_ref,
+                    wq_ref, wk_ref, wv_ref, a_ref, bias_ref,
+                    q_ref, k_ref, v_ref, g_ref, *, seq, d):
+    live = _live_rows(q_ref, pl.program_id(2), seq)
+    first = pl.program_id(2) == 0
+
+    def conv_silu(x_ref, halo_ref, w_ref):
+        y, gate, _ = _filtered(x_ref, halo_ref, w_ref, live, first)
+        return jnp.where(live, y * gate, 0.0)
+
+    q_ref[...] = _l2(conv_silu(xq_ref, hq_ref, wq_ref), d)[1] * d ** -0.5
+    k_ref[...] = _l2(conv_silu(xk_ref, hk_ref, wk_ref), d)[1]
+    v_ref[...] = conv_silu(xv_ref, hv_ref, wv_ref).astype(v_ref.dtype)
+    g_ref[...] = jnp.where(live, a_ref[...] * jax.nn.softplus(
+        f_ref[...].astype(jnp.float32) + bias_ref[...]), 0.0)
+
+
+def _pre_bwd_kernel(dq_ref, dk_ref, dv_ref, dg_ref, xq_ref, xk_ref, xv_ref,
+                    f_ref, hq_ref, hk_ref, hv_ref, wq_ref, wk_ref, wv_ref,
+                    a_ref, bias_ref, dxq_ref, dxk_ref, dxv_ref, df_ref,
+                    small_ref, carry, *, seq, d, tiles):
+    """The tiles of a lane block LAST to first: what a tile's filter
+    gradient owes the K - 1 rows before it waits in ``carry`` for the next
+    step, and the gradients of what has no row axis (the filters, the
+    decay's two vectors) add up in ``small_ref``'s resident block."""
+    f32 = jnp.float32
+    c = pl.program_id(2)
+    rows = dq_ref.shape[0]
+    live = _live_rows(dq_ref, tiles - 1 - c, seq)
+    first = c == tiles - 1
+
+    @pl.when(c == 0)
+    def _():
+        carry[...] = jnp.zeros_like(carry)
+        small_ref[...] = jnp.zeros_like(small_ref)
+
+    def through_filter(i, x_ref, halo_ref, w_ref, dx_ref, ds_of):
+        """dx of one filtered input from ``ds_of(s)``, the gradient of
+        s = silu(y); returns the filter's gradient [K, lanes]."""
+        y, gate, taps = _filtered(x_ref, halo_ref, w_ref, live, first)
+        dy = jnp.where(live, ds_of(y * gate) * gate * (1.0 + y * (1.0 - gate)),
+                       0.0)
+        w = w_ref[...].astype(f32)
+        K = w.shape[0]
+        ahead = jnp.concatenate([dy, carry[i]])
+        dx_ref[...] = sum(
+            w[j:j + 1] * ahead[K - 1 - j:K - 1 - j + rows]
+            for j in range(K)).astype(dx_ref.dtype)
+        carry[i] = dy[:8]
+        return [jnp.sum(dy * tap, axis=0, keepdims=True) for tap in taps]
+
+    def l2_of(dout_ref, scale):
+        def ds_of(s):
+            r, n = _l2(s, d)
+            dn = dout_ref[...] * scale
+            return r * (dn - n * _head_sums(dn * n, d))
+        return ds_of
+
+    small = through_filter(0, xq_ref, hq_ref, wq_ref, dxq_ref,
+                           l2_of(dq_ref, d ** -0.5))
+    small += through_filter(1, xk_ref, hk_ref, wk_ref, dxk_ref,
+                            l2_of(dk_ref, 1.0))
+    small += through_filter(2, xv_ref, hv_ref, wv_ref, dxv_ref,
+                            lambda s: dv_ref[...].astype(f32))
+    z = jnp.where(live, f_ref[...].astype(f32) + bias_ref[...], 0.0)
+    dg = jnp.where(live, dg_ref[...], 0.0)
+    dz = dg * a_ref[...] * _sigmoid(z)
+    df_ref[...] = dz.astype(df_ref.dtype)
+    small += [jnp.sum(dz, axis=0, keepdims=True),
+              jnp.sum(dg * jax.nn.softplus(z), axis=0, keepdims=True)]
+    small.append(jnp.zeros((small_ref.shape[0] - len(small),
+                            small_ref.shape[1]), f32))
+    small_ref[...] += jnp.concatenate(small)
+
+
+def _pass_specs(B, seq, HD, d, rows, tile_of=lambda t: t):
+    """(grid, a tile, the 16 rows before it, a [n, lanes] block that has no
+    row axis, a [B, n, HD] one that adds up over the tiles) of a pass over
+    [B, seq, HD] in tiles of ``rows`` rows and as many whole heads as fill
+    ``HEAD_LANES``; ``tile_of(t)`` is the tile step t works on."""
+    lanes = _heads_per_step(HD // d, d, d) * d
+    tile = pl.BlockSpec((None, rows, lanes),
+                        lambda b, j, t: (b, tile_of(t), j))
+    halo = pl.BlockSpec(
+        (None, _HALO, lanes), lambda b, j, t: (
+            b, jnp.maximum(tile_of(t) * (rows // _HALO) - 1, 0), j))
+    return ((B, HD // lanes, pl.cdiv(seq, rows)), tile, halo,
+            lambda n: pl.BlockSpec((n, lanes), lambda b, j, t: (0, j)),
+            lambda n: pl.BlockSpec((None, n, lanes),
+                                   lambda b, j, t: (b, 0, j)))
+
+
+def _tile_rows(rows, padded):
+    """Rows a step of a pass over ``padded`` (whole chunks) works on: whole
+    chunks, so no tile lies wholly past the sequence's end."""
+    return min(rows - rows % CHUNK or CHUNK, padded)
+
+
+@functools.partial(jax.jit, static_argnums=(9, 10, 11, 12))
+def _pre_forward(xq, xk, xv, f, wq, wk, wv, neg_a, bias, d, dt, rows,
+                 interpret):
+    B, S, HD = xq.shape
+    padded = S + -S % CHUNK
+    rows = _tile_rows(rows, padded)
+    grid, tile, halo, flat, _ = _pass_specs(B, padded, HD, d, rows)
+    wide = jax.ShapeDtypeStruct((B, padded, HD), jnp.float32)
+    K = wq.shape[0]
+    return tuple(pl.pallas_call(
+        functools.partial(_pre_fwd_kernel, seq=S, d=d),
+        grid=grid,
+        in_specs=[tile] * 4 + [halo] * 3 + [flat(K)] * 3 + [flat(1)] * 2,
+        out_specs=[tile] * 4,
+        out_shape=[wide, wide, jax.ShapeDtypeStruct(wide.shape, dt), wide],
+        compiler_params=_PASS,
+        interpret=interpret,
+        name="kda_pre_fwd",
+    )(xq, xk, xv, f, xq, xk, xv, wq, wk, wv, neg_a[None], bias[None]))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _pre_backward(d, dt, rows, interpret, res, cot):
+    xq, xk, xv, f, wq, wk, wv, neg_a, bias = res
+    dq, dk, dv, dg = cot
+    B, S, HD = xq.shape
+    padded = dq.shape[1]
+    rows = _tile_rows(rows, padded)
+    tiles = pl.cdiv(padded, rows)
+    grid, tile, halo, flat, summed = _pass_specs(
+        B, padded, HD, d, rows, lambda t: tiles - 1 - t)
+    K = wq.shape[0]
+    n_small = -(-(3 * K + 2) // 8) * 8
+    lanes = tile.block_shape[-1]
+    dxq, dxk, dxv, df, small = pl.pallas_call(
+        functools.partial(_pre_bwd_kernel, seq=S, d=d, tiles=tiles),
+        grid=grid,
+        in_specs=[tile] * 8 + [halo] * 3 + [flat(K)] * 3 + [flat(1)] * 2,
+        out_specs=[tile] * 4 + [summed(n_small)],
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype)
+                   for t in (xq, xk, xv, f)]
+        + [jax.ShapeDtypeStruct((B, n_small, HD), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((3, 8, lanes), jnp.float32)],
+        compiler_params=_PASS,
+        interpret=interpret,
+        name="kda_pre_bwd",
+    )(dq, dk, dv, dg, xq, xk, xv, f, xq, xk, xv, wq, wk, wv, neg_a[None],
+      bias[None])
+    small = jnp.sum(small, axis=0)
+    dw = [small[i * K:(i + 1) * K].astype(w.dtype)
+          for i, w in enumerate((wq, wk, wv))]
+    return (dxq, dxk, dxv, df, *dw, small[3 * K + 1].astype(neg_a.dtype),
+            small[3 * K].astype(bias.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12))
+def _kda_pre(xq, xk, xv, f, wq, wk, wv, neg_a, bias, d, dt, rows, interpret):
+    return _pre_forward(xq, xk, xv, f, wq, wk, wv, neg_a, bias, d, dt, rows,
+                        interpret)
+
+
+def _kda_pre_fwd(*args):
+    return _pre_forward(*args), args[:9]
+
+
+_kda_pre.defvjp(_kda_pre_fwd, _pre_backward)
+
+
+def kda_pre(xq, xk, xv, f, wq, wk, wv, a_log, dt_bias, dtype):
+    """Everything element-wise between the mixer's projections and the
+    delta rule, ONE pass over HBM a direction in the kernels' own layout:
+    from the outputs [B, S, H * d] of ``q_proj``, ``k_proj``, ``v_proj``
+    and ``f_b_proj``, the three [K, H * d] filters, ``A_log`` [H] and
+    ``dt_bias`` [H * d] to ``kda_whole_chunks``' q, k, g (float32) and v
+    (``dtype``), [B, N * CHUNK, H * d] with zeros past the sequence's end:
+    the K-tap causal filter (zeros before the start), SiLU, q and k
+    L2-normalised over their head with 1e-6 and q times d^-0.5, g =
+    -exp(A_log) softplus(f + dt_bias). Float32 arithmetic in the tile; the
+    backward pass makes the intermediates again from the projections'
+    outputs, its only residuals."""
+    if wq.shape[0] > 9:
+        raise ValueError("a filter of %d taps reaches past the 8 rows a "
+                         "tile reads of the tile before it" % wq.shape[0])
+    d = xq.shape[-1] // a_log.shape[0]
+    neg_a = -jnp.repeat(jnp.exp(a_log.astype(jnp.float32)), d)
+    return _kda_pre(xq, xk, xv, f, wq, wk, wv, neg_a,
+                    dt_bias.astype(jnp.float32), d, dtype, ROW_TILE,
+                    pallas_mode.interpret())
+
+
+# ---- after the core: the per-head RMSNorm and its sigmoid gate
+
+
+def _post_fwd_kernel(o_ref, gate_ref, w_ref, out_ref, *, d, eps):
+    f32 = jnp.float32
+    o = o_ref[...].astype(f32)
+    r = jax.lax.rsqrt(_head_sums(o * o, d) * (1.0 / d) + eps)
+    out_ref[...] = (o * r * w_ref[...] * _sigmoid(
+        gate_ref[...].astype(f32))).astype(out_ref.dtype)
+
+
+def _post_bwd_kernel(dout_ref, o_ref, gate_ref, w_ref, do_ref, dgate_ref,
+                     dw_ref, *, seq, d, eps):
+    f32 = jnp.float32
+    live = _live_rows(do_ref, pl.program_id(2), seq)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    o = jnp.where(live, o_ref[...].astype(f32), 0.0)
+    r = jax.lax.rsqrt(_head_sums(o * o, d) * (1.0 / d) + eps)
+    n = o * r
+    gate = _sigmoid(jnp.where(live, gate_ref[...].astype(f32), 0.0))
+    dout = jnp.where(live, dout_ref[...].astype(f32), 0.0)
+    w = w_ref[...]
+    through = dout * n * gate          # what reaches the scale, lane by lane
+    dgate_ref[...] = (through * w * (1.0 - gate)).astype(dgate_ref.dtype)
+    dn = dout * w * gate
+    do_ref[...] = (r * (dn - n * _head_sums(dn * n, d) * (1.0 / d))
+                   ).astype(do_ref.dtype)
+    dw_ref[...] += jnp.concatenate([
+        jnp.sum(through, axis=0, keepdims=True),
+        jnp.zeros((dw_ref.shape[0] - 1, dw_ref.shape[1]), f32)])
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _post_forward(o, gate, w, d, eps, seq, dt, rows, interpret):
+    B, padded, HD = o.shape
+    rows = _tile_rows(rows, padded)
+    grid, tile, _, flat, _ = _pass_specs(B, seq, HD, d, rows)
+    return pl.pallas_call(
+        functools.partial(_post_fwd_kernel, d=d, eps=eps),
+        grid=grid,
+        in_specs=[tile, tile, flat(1)],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((B, seq, HD), dt),
+        compiler_params=_PASS,
+        interpret=interpret,
+        name="kda_post_fwd",
+    )(o, gate, w[None])
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+def _post_backward(d, eps, seq, dt, rows, interpret, res, dout):
+    o, gate, w = res
+    B, padded, HD = o.shape
+    rows = _tile_rows(rows, padded)
+    grid, tile, _, flat, summed = _pass_specs(B, padded, HD, d, rows)
+    do, dgate, dw = pl.pallas_call(
+        functools.partial(_post_bwd_kernel, seq=seq, d=d, eps=eps),
+        grid=grid,
+        in_specs=[tile, tile, tile, flat(1)],
+        out_specs=[tile, tile, summed(8)],
+        out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype),
+                   jax.ShapeDtypeStruct(gate.shape, gate.dtype),
+                   jax.ShapeDtypeStruct((B, 8, HD), jnp.float32)],
+        compiler_params=_PASS,
+        interpret=interpret,
+        name="kda_post_bwd",
+    )(dout, o, gate, w[None])
+    return do, dgate, jnp.sum(dw[:, 0], axis=0).astype(w.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _kda_post(o, gate, w, d, eps, seq, dt, rows, interpret):
+    return _post_forward(o, gate, w, d, eps, seq, dt, rows, interpret)
+
+
+def _kda_post_fwd(*args):
+    return _post_forward(*args), args[:3]
+
+
+_kda_post.defvjp(_kda_post_fwd, _post_backward)
+
+
+def kda_post(o, gate, o_norm, eps, dtype):
+    """Everything element-wise between the delta rule and ``o_proj``, one
+    pass a direction: the core's o [B, N * CHUNK, H * d] (``dtype``), the
+    gate projection's output [B, seq, H * d] and ``o_norm`` [d] ->
+    ``rms_normalize(o) * o_norm * sigmoid(gate)`` per head, [B, seq, H * d]
+    in ``dtype``; the mean square stays in the tile."""
+    d = o_norm.shape[0]
+    w = jnp.tile(o_norm.astype(jnp.float32), o.shape[-1] // d)
+    return _kda_post(o, gate, w, d, eps, gate.shape[1], dtype, ROW_TILE,
+                     pallas_mode.interpret())
 
 
 def kda_chunked(q, k, v, g, beta, dtype=None):

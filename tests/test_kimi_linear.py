@@ -299,6 +299,20 @@ def test_the_head_width_picks_the_rendering(monkeypatch):
         for_tpu = traced()
     assert for_tpu.count("interpret=False") == 2 == traced().count(
         "interpret=True")
+    # the mixer's passes around the core follow the same rule: heads of 128
+    # trace them, narrow heads trace none and keep the ``jnp`` form
+    from tests.test_flash_attention import kernel_calls
+    for (heads, width), fused in (((1, 128), 1), ((4, 16), 0)):
+        mixer = layers.KimiDeltaAttention(
+            layers.KDAConfig(heads, width, 4), 1e-5)
+        x = jnp.zeros((1, 8, 48))
+        params = mixer.init(jax.random.PRNGKey(0), x)
+        assert "pallas_call" not in str(jax.make_jaxpr(
+            lambda: mixer.init(jax.random.PRNGKey(0), x))())
+        grad = jax.make_jaxpr(jax.grad(
+            lambda p: jnp.sum(mixer.apply(p, x))))(params).jaxpr
+        assert [kernel_calls(grad, name) for name in FUSED_PASSES] \
+            == [fused] * 4
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     assert not kda_op.runs_as_kernels(16, 16)
     with pytest.raises(RuntimeError, match="gpu"):
@@ -318,17 +332,128 @@ GAUGE_CASES = {
 }
 
 
+@pytest.mark.parametrize("gauge", ["attention.kda_kernel_layers",
+                                   "attention.kda_fused_mixer_layers"])
 @pytest.mark.parametrize("cell", sorted(GAUGE_CASES))
-def test_the_gauge_says_how_many_layers_took_the_kda_kernels(cell):
-    """``attention.kda_kernel_layers`` is set when the loss is traced, from
-    what ``runs_as_kernels`` said of the configuration's KDA heads."""
-    make, layers_ = GAUGE_CASES[cell]
+def test_the_gauge_says_how_many_layers_took_the_kda_kernels(cell, gauge):
+    """``attention.kda_kernel_layers`` and ``attention.kda_fused_mixer_layers``
+    (the delta rule as kernels, the element-wise passes around it fused)
+    are set when the loss is traced, from what ``runs_as_kernels`` said of
+    the configuration's KDA heads."""
+    assert gauges_of_a_traced_loss(cell)[gauge] == GAUGE_CASES[cell][1]
+
+
+@functools.lru_cache(maxsize=None)
+def gauges_of_a_traced_loss(cell):
     loss_fn, params, batch, _ = lm.make_train_setup(
-        make(), seq_len=16, batch_size=1, seed=0)
+        GAUGE_CASES[cell][0](), seq_len=16, batch_size=1, seed=0)
     telemetry.reset()
     jax.eval_shape(loss_fn, params, batch)
-    assert telemetry.get_recorder().gauges()[
-        "attention.kda_kernel_layers"] == layers_
+    return dict(telemetry.get_recorder().gauges())
+
+
+# ------------------ the mixer's fused passes against the ``jnp`` form
+
+
+FUSED_PASSES = ("kda_pre_fwd", "kda_pre_bwd", "kda_post_fwd", "kda_post_bwd")
+# (sequence, rows a grid step works on, sequences, heads): the tiles are
+# whole chunks of 64
+FUSED_CASES = {
+    "neither a whole row tile nor a whole chunk": (100, 64, 2, 2),
+    "a row tile boundary cuts the filter's window": (130, 64, 1, 1),
+    "the first K - 1 tokens": (3, 256, 1, 1),
+}
+FUSED_DTYPES = {"float32": (jnp.float32, RTOL), "bfloat16": (jnp.bfloat16, 5e-2)}
+
+
+def fused_inputs(seq, dtype, H=2, d=128, K=4, B=2, seed=0):
+    """What a KDA mixer hands its element-wise passes: the outputs of
+    ``q_proj``, ``k_proj``, ``v_proj``, ``f_b_proj`` and ``g_b_proj``, the
+    three filters, ``A_log`` and ``dt_bias`` as they are seeded, ``o_norm``;
+    and a weight for every entry of every output."""
+    r = np.random.RandomState(seed)
+    wide = lambda t=dtype: jnp.asarray(r.randn(B, seq, H * d), t)  # noqa: E731
+    xq, xk, xv, f, gate = (wide() for _ in range(5))
+    filters = [jnp.asarray(r.uniform(-.5, .5, (K, H * d)), jnp.float32)
+               for _ in range(3)]
+    a_log = jnp.asarray(np.log(r.uniform(1, 16, H)), jnp.float32)
+    dt = np.exp(r.uniform(np.log(1e-3), np.log(1e-1), H * d))
+    dt_bias = jnp.asarray(dt + np.log(-np.expm1(-dt)), jnp.float32)
+    o_norm = jnp.asarray(r.uniform(.5, 1.5, d), jnp.float32)
+    weights = [wide(jnp.float32) for _ in range(4)]
+    return (xq, xk, xv, f, *filters, a_log, dt_bias), gate, o_norm, weights
+
+
+def values_and_gradients(fn, args, weights, seq):
+    """(the outputs' first ``seq`` rows as [B, seq, H * d], the gradient of
+    their weighted sum by every argument)."""
+    def outputs(*a):
+        return [o.reshape(o.shape[:2] + (-1,))[:, :seq] for o in fn(*a)]
+
+    def weighted_sum(*a):
+        return sum(jnp.sum(o.astype(jnp.float32) * w)
+                   for o, w in zip(outputs(*a), weights))
+    return outputs(*args), jax.grad(
+        weighted_sum, argnums=tuple(range(len(args))))(*args)
+
+
+@pytest.mark.parametrize("dtype", sorted(FUSED_DTYPES))
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_the_fused_prologue_is_the_jnp_form(case, dtype, monkeypatch):
+    """``kda_pre`` (``kda_pre_fwd`` / ``kda_pre_bwd``, interpreted, heads of
+    128) against ``layers.kda_inputs``: q, k, v, g and EVERY gradient (the
+    four projections' outputs, the three filters, ``A_log``, ``dt_bias``);
+    zeros before the sequence's start, zeros in the rows that pad the last
+    chunk. In bfloat16 the ``jnp`` form filters and gates in bfloat16 and
+    the kernel in float32: bfloat16's own tolerance."""
+    (seq, rows, B, H), (dt, rtol) = FUSED_CASES[case], FUSED_DTYPES[dtype]
+    monkeypatch.setattr(kda_op, "ROW_TILE", rows)
+    args, _, _, weights = fused_inputs(seq, dt, H=H, B=B)
+    whole = kda_op.kda_pre(*args, dt)
+    assert [o.dtype for o in whole] == [jnp.float32, jnp.float32, dt,
+                                        jnp.float32]
+    for o in whole:
+        assert o.shape == (B, seq + -seq % kda_op.CHUNK, H * 128)
+        assert not np.any(np.asarray(o[:, seq:], np.float32))
+    got = values_and_gradients(
+        lambda *a: kda_op.kda_pre(*a, dt), args, weights, seq)
+    want = values_and_gradients(
+        lambda *a: layers.kda_inputs(*a, dt), args, weights, seq)
+    for a, b in zip(got[0] + list(got[1]), want[0] + list(want[1])):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        close(a.astype(jnp.float32), b.astype(jnp.float32), rtol)
+
+
+@pytest.mark.parametrize("dtype", sorted(FUSED_DTYPES))
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_the_fused_epilogue_is_the_jnp_form(case, dtype, monkeypatch):
+    """``kda_post`` (``kda_post_fwd`` / ``kda_post_bwd``) against
+    ``layers.kda_output``: the gated per-head RMSNorm from the core's
+    padded output, and the gradients of the core's output (zeros in the
+    padding rows), of the gate projection's output and of ``o_norm``."""
+    (seq, rows, B, H), (dt, rtol) = FUSED_CASES[case], FUSED_DTYPES[dtype]
+    monkeypatch.setattr(kda_op, "ROW_TILE", rows)
+    _, gate, o_norm, weights = fused_inputs(seq, dt, H=H, B=B)
+    padded = seq + -seq % kda_op.CHUNK
+    o = jnp.asarray(np.random.RandomState(1).randn(B, padded, H * 128), dt)
+    got = values_and_gradients(
+        lambda *a: [kda_op.kda_post(*a, 1e-5, dt)], (o, gate, o_norm),
+        weights, seq)
+    want = values_and_gradients(
+        lambda o, *a: [layers.kda_output(
+            o[:, :seq].reshape(B, seq, H, 128), *a, 1e-5, dt)],
+        (o, gate, o_norm), weights, seq)
+    assert got[0][0].dtype == dt
+    assert not np.any(np.asarray(got[1][0][:, seq:], np.float32))
+    for a, b in zip(got[0] + list(got[1]), want[0] + list(want[1])):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        close(a.astype(jnp.float32), b.astype(jnp.float32), rtol)
+
+
+def test_a_filter_longer_than_the_halo_is_refused():
+    args, _, _, _ = fused_inputs(8, jnp.float32, K=10)
+    with pytest.raises(ValueError, match="10 taps"):
+        kda_op.kda_pre(*args, jnp.float32)
 
 
 def test_the_factored_form_would_overflow_where_the_sub_blocks_do_not():
@@ -594,6 +719,50 @@ def test_recomputed_blocks_give_the_same_loss_and_gradients(tiny):
         for model in (lm.TransformerLM(cfg),
                       lm.TransformerLM(cfg, remat_blocks=True))]
     assert remats[1] - remats[0] == 5   # one per block, beside the op's own
+
+
+@pytest.mark.parametrize("against", ["blocks_not_recomputed", "jnp_form"])
+def test_a_recomputed_block_of_wide_heads_runs_the_prologue_again_and_the_core_not(
+        against, monkeypatch):
+    """One KDA layer with a head of 128, its block recomputed: the
+    gradient's jaxpr holds, by name, 1 ``kda_fwd``, 1 ``kda_bwd``, 2
+    ``kda_pre_fwd`` and 1 ``kda_pre_bwd`` (the core's output and states
+    are kept by name, its operands are made again), 2 ``kda_post_fwd`` and
+    1 ``kda_post_bwd``. Loss and gradients are those of the same model
+    with no block recomputed, and of the ``jnp`` form around the ``lax``
+    core (what every head took until the passes were fused)."""
+    from tests.test_flash_attention import kernel_calls
+    cfg = tiny_config(num_layers=1, layer_types=("kda",), kda_num_heads=1,
+                      kda_head_dim=128)
+    params = lm.make_train_setup(cfg, seq_len=SEQ, batch_size=2, seed=0)[1]
+    batch = batches(1)[0]
+    ids, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+
+    def run(remat_blocks):
+        model = lm.TransformerLM(cfg, remat_blocks=remat_blocks)
+
+        def loss(p):
+            logits = model.apply(p, ids, mutable=["counters"])[0]
+            return -jnp.mean(jnp.take_along_axis(
+                jax.nn.log_softmax(logits), targets[..., None], axis=-1))
+
+        with jax.default_matmul_precision("highest"):
+            traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+            value, grads = traced.lower().compile()(params)
+        return value, flat(grads), {
+            name: kernel_calls(traced.jaxpr.jaxpr, name)
+            for name in ("kda_fwd", "kda_bwd") + FUSED_PASSES}
+
+    got, got_g, calls = run(True)
+    assert calls == {"kda_fwd": 1, "kda_bwd": 1, "kda_pre_fwd": 2,
+                     "kda_pre_bwd": 1, "kda_post_fwd": 2, "kda_post_bwd": 1}
+    if against == "jnp_form":
+        monkeypatch.setattr(kda_op, "runs_as_kernels", lambda dk, dv: False)
+    want, want_g, want_calls = run(False)
+    assert set(want_calls.values()) == ({0} if against == "jnp_form" else {1})
+    close(got, want)
+    for name in got_g:
+        close(got_g[name], want_g[name], DEEP_RTOL)
 
 
 def check_recomputed_flash_blocks(cfg, params, batch, against, monkeypatch,

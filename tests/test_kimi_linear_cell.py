@@ -304,7 +304,11 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     two flash kernels at 192 / 128 (``flash_fwd`` and, since PR 34, the one
     backward kernel ``flash_bwd``) and the delta rule's two kernels per
     KDA layer, each ONCE (a recomputed block keeps by name what their
-    backward kernels read of the forward kernels' results), no
+    backward kernels read of the forward kernels' results), the mixer's
+    fused element-wise passes around them (PR 42: ``kda_pre_fwd`` and
+    ``kda_post_fwd`` twice a layer, the recomputed block's included,
+    ``kda_pre_bwd`` and ``kda_post_bwd`` once, under ``kda`` and outside
+    ``kda_scan``), no float32 [8192, 4096] layout copy between them, no
     triangular solve and no loop of the core left to XLA, every held
     expert on every token in four routed layers, every block recomputed,
     and state + scratch inside 16 GB with the 2.4 GB of initial parameters
@@ -331,12 +335,26 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     # (expert.py:_held_experts)
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 2 + 4 + 4
-    assert sum("flash_fwd" in line for line in calls) == 1
-    assert sum("flash_bwd" in line for line in calls) == 1
-    assert sum("kda_fwd" in line for line in calls) == 4
-    assert sum("kda_bwd" in line for line in calls) == 4
-    assert all("kda_scan" in line for line in calls if "kda_" in line)
+    assert len(calls) == 2 + 4 + 4 + 3 * (4 + 4)
+    launches = {name: [line for line in calls
+                       if line.split(" = ")[0].split("%")[-1].rsplit(
+                           ".", 1)[0] == name]
+                for name in ("flash_fwd", "flash_bwd", "kda_fwd", "kda_bwd",
+                             "kda_pre_fwd", "kda_pre_bwd", "kda_post_fwd",
+                             "kda_post_bwd")}
+    assert {name: len(lines) for name, lines in launches.items()} == {
+        "flash_fwd": 1, "flash_bwd": 1, "kda_fwd": 4, "kda_bwd": 4,
+        "kda_pre_fwd": 8, "kda_pre_bwd": 4, "kda_post_fwd": 8,
+        "kda_post_bwd": 4}
+    for name, lines in launches.items():
+        if name.startswith("kda_"):
+            assert all("/kda/" in line for line in lines)
+            assert all(("kda_scan" in line) is (name in ("kda_fwd", "kda_bwd"))
+                       for line in lines)
+    # nothing re-lays a float32 [8192, 4096] between the projections and
+    # the kernels (24 copies f32[1024, 8, 32, 128] a step until PR 42)
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "f32[1024,8,32,128]" in line]
     assert "triangular-solve" not in text
     # (the lean head's two scans are the step's only loops)
     assert not [line for line in text.splitlines()
@@ -345,11 +363,13 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     step = out["train_step"]
     # float32 master weights and Adam's two moments: 12 B a parameter
     assert abs(step["argument_size_in_bytes"] - 12 * 602434432) < 1 << 20
-    # 5.45 GB since PR 41 keeps the held experts' gate and up products in
-    # all four routed layers (0.80 GB of the closed form's 4 x 268 MB; 4.65
-    # with the flash kernel's output and q kept, PR 32; 4.47, PR 30; 4.58,
-    # PR 29); the chip loaded it, cold and from the cache (PERF.md section 6)
-    assert 5.0e9 < step["temp_size_in_bytes"] < 5.6e9
+    # 4.85 GB since PR 42's fused passes keep no float32 intermediate of
+    # the KDA mixers (5.45 since PR 41 keeps the held experts' gate and up
+    # products in all four routed layers, 0.80 GB of the closed form's
+    # 4 x 268 MB; 4.65 with the flash kernel's output and q kept, PR 32;
+    # 4.47, PR 30; 4.58, PR 29); the chip loaded it, cold and from the
+    # cache (PERF.md section 6)
+    assert 4.5e9 < step["temp_size_in_bytes"] < 5.0e9
     assert step["live_bytes_estimate"] + 4 * 602434432 < 15.2e9
     # 9 expert products a routed layer, none made a second time
     products = [line for line in text.splitlines()

@@ -737,7 +737,8 @@ PHASES = {"setup.build": None, "setup.capture": "setup.build",
           "setup.first_step": None}
 TRACE_TIME_GAUGES = ("lean_head.chunks", "lean_head.chunk_width",
                      "lean_head.dead_cols", "attention.flash_layers",
-                     "attention.kda_kernel_layers", "model.remat_blocks",
+                     "attention.kda_kernel_layers",
+                     "attention.kda_fused_mixer_layers", "model.remat_blocks",
                      "model.kept_expert_layers", "model.kept_expert_bytes")
 
 
