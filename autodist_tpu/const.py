@@ -210,8 +210,6 @@ class ENV(Enum):
     ADT_TRACE_BUFFER = ("ADT_TRACE_BUFFER", int, 65536)
     # sampled-mode stride: record one span out of every N
     ADT_TRACE_SAMPLE = ("ADT_TRACE_SAMPLE", int, 16)
-    # where bench/CLI write exported traces by default
-    ADT_TRACE_FILE = ("ADT_TRACE_FILE", str, "")
     # log line format: "text" (default) or "json" (structured lines
     # carrying span ids so logs correlate with traces)
     ADT_LOG_FORMAT = ("ADT_LOG_FORMAT", str, "text")

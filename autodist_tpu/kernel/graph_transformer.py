@@ -134,7 +134,7 @@ class DistributedStep:
         self._fused_ps_opt = None
         self._fused_ps_dirty = False
         # jitted-dispatch counter: one per __call__ / run_multi — the
-        # honest "host round-trips per training job" number bench and the
+        # honest "host round-trips per training job" number the
         # fused-parity tests assert on
         self.dispatches = 0
         # static per-microstep quantized-AR wire bytes (int8 payload +

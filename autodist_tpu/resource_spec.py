@@ -37,7 +37,7 @@ class ChipSpec:
 
 # THE chip table — the single source of every memory budget (cost-model
 # feasibility gate, ADT5xx static HBM analyzer, Runner budget) and every
-# peak (cost-model compute term, bench/chip_smoke plausibility checks),
+# peak (cost-model compute term, chip_smoke's plausibility checks),
 # keyed by generation. A device that is not here is an error, not a
 # default (``chip_kind_of``). v2/v3 rows are per TensorCore: JAX exposes
 # each of those chips' two cores as its own device.

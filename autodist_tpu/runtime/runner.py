@@ -1246,7 +1246,7 @@ class Runner:
             {k: round(v, 6) for k, v in report.buckets.items()}
             if report is not None else None)
         # elastic plane (stable shape): epoch/reconfigure accounting for
-        # monitoring and the bench --smoke downtime leg
+        # monitoring
         m = getattr(self, "_membership", None)
         out["elastic"] = {
             "epoch": m.epoch if m is not None else None,
@@ -1258,7 +1258,7 @@ class Runner:
             "fenced_writes": c.get("elastic.fenced_writes", 0.0),
         }
         # preemption plane (stable shape): notice/rescue/handoff
-        # accounting for monitoring and the bench --smoke downtime leg
+        # accounting for monitoring
         guard = getattr(self, "_preempt", None)
         out["preempt"] = (guard.stats() if guard is not None else
                           {"notice": None, "notices": 0.0,

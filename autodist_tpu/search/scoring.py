@@ -45,7 +45,7 @@ class ScoreRecord:
 def zoo_best(model_item, resource_spec, sim: Simulator):
     """``(label, premium-adjusted score seconds, SimulationResult)`` of
     the best zoo candidate under ``sim`` — the comparison baseline the
-    search CLI, the bench legs, and the tests all quote, in one place so
+    search CLI and the tests quote, in one place so
     the ranking key can never diverge between them. ``(None, None,
     None)`` when no zoo candidate builds or survives the OOM skip."""
     from autodist_tpu.strategy.auto_strategy import default_candidates

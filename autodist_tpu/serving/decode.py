@@ -7,8 +7,8 @@ The engine compiles ONE donated, fixed-shape decode-step program
 ``[slots, layers, max_len, heads, head_dim]`` + per-slot token/cursor/
 alive → next-token per slot + updated caches. Every step runs that same
 executable regardless of which sequences occupy which slots — ZERO
-recompiles in steady state (asserted by :meth:`recompiles_after_warmup`
-and the CI ``--serve-decode`` smoke leg). Slot occupancy is pure host
+recompiles in steady state (:meth:`recompiles_after_warmup`; held by
+``tests/test_decode.py``). Slot occupancy is pure host
 bookkeeping: a finished sequence flips its ``alive`` bit and the next
 admission overwrites its rows; the masked attention in
 ``ops.attention.cached_attention`` never reads a dead slot's garbage.
@@ -21,9 +21,7 @@ plain serving) and the resulting caches are scattered into the live
 cache by a third fixed-shape program (insert: ``cache.at[idx].set(rows,
 mode="drop")`` with out-of-bounds indices for padding rows, output
 sharding pinned to the decode program's so admission steps never
-re-specialize it). ``admission="static"`` degrades the scheduler to the
-classic static batch — admit only when EVERY slot is free — which is the
-head-to-head baseline ``bench.py --serve-decode`` runs.
+re-specialize it).
 
 Shutdown is drain-aware like the micro-batcher: :meth:`drain` stops
 admitting, sheds the queue typed with a Retry-After computed from the
@@ -100,10 +98,8 @@ class DecodeConfig:
     prefill dispatch runs at (prompts longer than this are rejected
     typed). ``prefill_buckets``: padded prefill group sizes (None =
     {1, slots} rounded to replica multiples). ``eos_id``: token ending a
-    sequence early (None = length-only stopping). ``admission``:
-    "continuous" (admit into any freed slot between steps) or "static"
-    (admit only when ALL slots are free — the baseline bench compares
-    against). ``max_queue``: backpressure bound on queued prompts.
+    sequence early (None = length-only stopping). ``max_queue``:
+    backpressure bound on queued prompts.
     ``hbm_budget_bytes``: arms the ADT442 cache-vs-HBM projection lint
     at construction (None skips it)."""
 
@@ -112,7 +108,6 @@ class DecodeConfig:
     prefill_len: int = 16
     prefill_buckets: Optional[Sequence[int]] = None
     eos_id: Optional[int] = None
-    admission: str = "continuous"
     max_queue: int = 1024
     snapshot_max_age_s: float = 0.1
     hbm_budget_bytes: Optional[float] = None
@@ -124,9 +119,6 @@ class DecodeConfig:
             raise ValueError("max_new_tokens must be >= 1")
         if self.prefill_len < 1:
             raise ValueError("prefill_len must be >= 1")
-        if self.admission not in ("continuous", "static"):
-            raise ValueError("admission must be 'continuous' or 'static', "
-                             "got %r" % (self.admission,))
         if self.max_queue < 1:
             raise ValueError("max_queue must be >= 1")
 
@@ -163,9 +155,10 @@ class _Slot:
 
 
 class SlotScheduler:
-    """Host-side slot bookkeeping + admission policy. Pure state machine
-    — no device work — so admission/eviction semantics are unit-testable
-    without a compiled engine.
+    """Host-side slot bookkeeping: any freed slot takes queued work
+    between steps. Pure state machine — no device work — so
+    admission/eviction semantics are unit-testable without a compiled
+    engine.
 
     Lifecycle of a slot: FREE → (admit: prefill seeds cache, cursor =
     prompt_len, first token already generated) → LIVE (each step appends
@@ -173,9 +166,8 @@ class SlotScheduler:
     / cache exhaustion (cursor reaching max_len) → FREE again; the next
     admission overwrites the rows, nothing is ever zeroed."""
 
-    def __init__(self, slots: int, admission: str = "continuous"):
+    def __init__(self, slots: int):
         self.n_slots = int(slots)
-        self.admission = admission
         self._slots: list = [None] * self.n_slots
 
     def free_slots(self) -> list:
@@ -188,13 +180,9 @@ class SlotScheduler:
         return (self.n_slots - len(self.free_slots())) / self.n_slots
 
     def admissible(self, queued: int) -> int:
-        """How many queued prompts the policy admits right now.
-        Continuous: any freed slot takes work. Static: only a fully
-        drained batch re-admits (the classic static-batching idle)."""
-        free = len(self.free_slots())
-        if self.admission == "static" and free != self.n_slots:
-            return 0
-        return min(free, queued)
+        """How many queued prompts are admitted right now: one a free
+        slot."""
+        return min(len(self.free_slots()), queued)
 
     def occupy(self, idx: int, slot: _Slot):
         assert self._slots[idx] is None
@@ -229,7 +217,7 @@ class DecodeEngine:
             raise ValueError(
                 "prefill_len %d exceeds the model's max_len %d"
                 % (cfg.prefill_len, setup.max_len))
-        self.scheduler = SlotScheduler(cfg.slots, cfg.admission)
+        self.scheduler = SlotScheduler(cfg.slots)
 
         # prefill rides the EXISTING bucketed forward path: shared PS
         # snapshot + degradation ladder + padded-bucket discipline
@@ -375,8 +363,7 @@ class DecodeEngine:
                     np.zeros(self._cache_shape, self._cache_dtype),
                     np.zeros(self._cache_shape, self._cache_dtype))
                 self._dispatch_step()
-            # warmup's fake step must not leak into the accounting the
-            # bench and smoke legs assert on
+            # warmup's fake step must not leak into the accounting
             self.stats_local["steps"] = 0
             self.stats_local["tokens"] = 0
             self._token_ms.clear()
@@ -671,7 +658,6 @@ class DecodeEngine:
         ms = self._token_ms
         out.update(
             slots=self.config.slots,
-            admission=self.config.admission,
             queue_depth=self.queue_depth(),
             slot_occupancy=self.scheduler.occupancy(),
             peak_occupancy=self._peak_occupancy,

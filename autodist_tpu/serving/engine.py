@@ -7,7 +7,7 @@ The engine owns the serving-side compiled programs of a built Runner:
   ``Runner.evaluate`` runs (``DistributedStep.predict_program``) with the
   batch buffers donated — after :meth:`warmup` every request executes a
   cached XLA executable, ZERO recompiles in steady state (asserted by
-  :meth:`recompiles_after_warmup` in tests and the CI smoke leg);
+  :meth:`recompiles_after_warmup` in ``tests/test_serving.py``);
 - **a host-PS snapshot** shared across requests: values are pulled once
   and refreshed at most every ``snapshot_max_age_s`` — a high-QPS tier
   must not pay one PCIe pull per request for values that change at
@@ -282,7 +282,7 @@ class InferenceEngine:
         with self._lock:
             # stats read-modify-writes stay under the engine lock: run_batch
             # may race predict() from another thread, and a dropped += would
-            # silently underreport batches/padded_rows in stats() and bench
+            # silently underreport batches/padded_rows in stats()
             if bucket > n:
                 self.stats["padded_rows"] += bucket - n
                 tel.counter_add("serve.padded_rows", bucket - n)

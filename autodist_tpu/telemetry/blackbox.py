@@ -156,7 +156,8 @@ class FlightRecorder:
             os.makedirs(directory, exist_ok=True)
             from autodist_tpu.telemetry import spans as spans_lib
             rec = spans_lib.get_recorder()
-            name = "blackbox-%s-%d-%d.json" % (
+            # the counter is padded: _prune keeps the last names as sorted
+            name = "blackbox-%s-%d-%06d.json" % (
                 time.strftime("%Y%m%d-%H%M%S"), rec.pid, self.dumps)
             path = os.path.join(directory, name)
             tmp = path + ".tmp"

@@ -151,8 +151,7 @@ def merge_traces(traces: Iterable[dict]) -> dict:
             "otherData": {"processes": per_proc}}
 
 
-# the minimal contract a Perfetto-loadable export satisfies — the CI
-# smoke leg validates the bench trace against this before uploading it
+# the minimal contract a Perfetto-loadable export satisfies
 def validate_chrome_trace(trace: dict) -> List[str]:
     """Schema check; returns a list of violations (empty = valid)."""
     errors: List[str] = []
@@ -348,7 +347,7 @@ def scrape_cluster(client, workers: Iterable[str]) -> dict:
         texts.append(metrics_text(shadow, labels={"worker": w}))
     # coordinator-side cluster gauges: appended to the exposition (a
     # scraper sees them next to the per-worker series) AND set on the
-    # local registry (step_stats/bench readers see them without parsing
+    # local registry (step_stats readers see them without parsing
     # text)
     spans_lib.gauge_set("cluster.workers_missing", float(len(missing)))
     spans_lib.counter_add("cluster.scrapes")

@@ -595,7 +595,7 @@ class TraceRecorder:
 # The module-level helpers are what the framework calls on hot paths, so
 # the enabled/disabled decision must be ONE attribute check. `_TRACING`
 # caches the parsed ADT_TRACE mode; configure() overrides it at runtime
-# (tests, bench) and refresh_from_env() re-reads the environment.
+# (tests, the benchmark) and refresh_from_env() re-reads the environment.
 
 _recorder: Optional[TraceRecorder] = None
 _recorder_lock = threading.Lock()

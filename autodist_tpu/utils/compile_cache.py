@@ -7,8 +7,8 @@ inside the checkout (``<repo>/.jax_cache``, gitignored) — fixed because a
 later process can only find the cache again at the same path; a temp
 dir, a pid or a timestamp in the path never hits.
 
-Entry points that compile (``chip_smoke.py``, ``bench.py``'s children,
-the ``examples/`` trainers) call :func:`enable_compile_cache` before
+Entry points that compile (``chip_smoke.py``, ``benchmark/run.py``, the
+``examples/`` trainers) call :func:`enable_compile_cache` before
 their first compile. Library code never does: where a process keeps its
 cache is the entry point's decision.
 """

@@ -137,17 +137,6 @@ def phase_device_gate(rehearse):
 
 # ------------------------------------------------------------------ P1
 
-def lm_train_flops(cfg, batch, seq):
-    """Model FLOPs of one lm1b train step, closed form: 6 x matmul
-    parameters per token (forward + backward) plus attention's
-    12 x seq x d per token per layer. Recomputation is not counted."""
-    d, layers = cfg.d_model, cfg.num_layers
-    matmul_params = layers * (4 * d * d + 2 * d * cfg.mlp_dim) \
-        + d * cfg.vocab_size
-    tokens = batch * seq
-    return 6.0 * tokens * matmul_params + 12.0 * tokens * layers * seq * d
-
-
 def build_runner(cfg, sizes, builder=None, resource_spec=None, **setup_kw):
     import optax
     import autodist_tpu as adt
@@ -208,7 +197,7 @@ def phase_train(cfg, sizes, p0, rehearse):
     jax.block_until_ready(runner.state)
     step_s = (time.perf_counter() - t0) / k
     losses += [float(h["loss"]) for h in handles]
-    # the same step timed by value readback — what bench.py's _sync does
+    # the same step timed by value readback
     t0 = time.perf_counter()
     losses.append(float(runner.run(batch)["loss"]))
     readback_step_s = time.perf_counter() - t0
@@ -266,7 +255,11 @@ def phase_train(cfg, sizes, p0, rehearse):
         out["peak_bytes_in_use"] = peaks
         check(max(peaks) < chip.hbm_bytes, "peak device memory %s >= the "
               "chip table's HBM %g" % (peaks, chip.hbm_bytes))
-        flops = lm_train_flops(cfg, sizes.batch, sizes.train_seq)
+        # the benchmark's closed form (recomputation is not counted)
+        from benchmark.families import lm as family
+        flops = sizes.batch * sizes.train_seq * family.train_flops_per_token(
+            {k: getattr(cfg, k) for k in family.SIZE_KEYS},
+            {"seq": sizes.train_seq})
         rate = flops / step_s / n
         out.update(model_flops_per_step=flops,
                    implied_flops_per_chip=float("%.4g" % rate),

@@ -107,7 +107,7 @@ def main():
 def decode(runner, cfg, n_tokens: int, batch_size: int):
     """Autoregressive generation from the trained checkpoint through the
     continuous-batching decode engine — the runnable entry point behind
-    ``bench.py --serve-decode`` and docs/serving.md."""
+    docs/serving.md."""
     import numpy as np
 
     from autodist_tpu.serving.decode import DecodeConfig, DecodeEngine

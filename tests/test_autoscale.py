@@ -9,19 +9,28 @@ with a pending preemption notice, planned drain-then-shrink through
 ``retire_worker``, and the epoch fence (a decision computed against a
 stale epoch is dropped as ``FencedOut``, never double-applied). The
 ADT440/441 lints run at controller construction. The end-to-end load
-ramp (2→4→2 with live traffic) is the bench leg
-(``bench.py --autoscale``); the oscillating-load chaos leg is nightly.
+ramp (2→4→2 with live traffic behind the real engine and micro-batcher)
+closes the file; its oscillating-load twin is ``slow`` (the nightly chaos
+workflow runs it).
 """
 import socket
+import time
 import types
 
+import numpy as np
+import optax
 import pytest
+
+import autodist_tpu
+from autodist_tpu import strategy as S
 
 from autodist_tpu.analysis import rules
 from autodist_tpu.analysis.diagnostics import DiagnosticError
 from autodist_tpu.runtime import elastic, preemption
 from autodist_tpu.runtime.coordination import (CoordinationClient,
                                                CoordinationServer)
+from autodist_tpu.serving import (InferenceEngine, MicroBatcher,
+                                  ServingConfig, ServingUnavailable)
 from autodist_tpu.serving.autoscale import (AutoscalePolicy,
                                             AutoscaleSignals,
                                             FleetAutoscaler, lint_policy)
@@ -380,3 +389,154 @@ def test_admit_worker_is_idempotent(server):
     assert elastic.admit_worker(client, W2) == 2
     assert elastic.admit_worker(client, W2) == 2  # already a member
     assert elastic.read_epoch(client) == (2, [CHIEF, W2])
+
+
+# ------------------------- the closed loop under live traffic (real stack)
+
+
+class _LoadedFleet:
+    """The real serving stack (engine + micro-batcher over a tiny MLP's
+    runner) under an in-run membership ``[me, replica-b]`` with a pool
+    ``[replica-c, replica-d]`` of phantom peers, so a ramp exercises the
+    real admission / retirement wire in one process. Each batch takes
+    15 ms more than the MLP needs, so a burst SUSTAINS a backlog on a
+    CPU."""
+
+    ME = "127.0.0.1"
+
+    def __init__(self, port, monkeypatch, policy):
+        for k, v in {"ADT_COORDSVC_PORT": str(port), "ADT_ELASTIC": "1",
+                     "ADT_ELASTIC_SYNC": "1", "ADT_ELASTIC_INRUN": "1",
+                     "ADT_ELASTIC_POLL_S": "0.01",
+                     "ADT_PREEMPT_POLL_S": "0.01"}.items():
+            monkeypatch.setenv(k, v)
+        autodist_tpu.reset()
+        rng = np.random.RandomState(0)
+        params = {"w1": rng.randn(16, 32).astype(np.float32) * 0.1,
+                  "b1": np.zeros((32,), np.float32),
+                  "w2": rng.randn(32, 4).astype(np.float32) * 0.1}
+        batch = {"x": rng.randn(32, 16).astype(np.float32),
+                 "y": rng.randn(32, 4).astype(np.float32)}
+        self.rows = batch["x"]
+
+        def hidden(p, b):
+            import jax.numpy as jnp
+            return jnp.tanh(b["x"] @ p["w1"] + p["b1"]) @ p["w2"]
+
+        def loss_fn(p, b):
+            return ((hidden(p, b) - b["y"]) ** 2).mean()
+
+        self.client = CoordinationClient("127.0.0.1", port)
+        elastic.publish_epoch(self.client, 1, [self.ME, "replica-b"])
+        ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
+        runner = ad.build(loss_fn, optax.adam(1e-2), params, batch)
+        runner.init(params)
+        replicas = runner.remapper.num_replicas
+        engine = InferenceEngine(
+            runner, lambda p, b: {"y": hidden(p, b)}, {"x": self.rows[0]},
+            ServingConfig(buckets=(replicas, 8 * replicas), max_delay_ms=2.0,
+                          max_queue=64, brownout_queue_frac=0.5,
+                          brownout_sustain_s=0.02,
+                          brownout_delay_factor=4.0)).warmup()
+        real_run = engine.run_batch
+
+        def slow_run(reqs):
+            time.sleep(0.015)
+            return real_run(reqs)
+
+        engine.run_batch = slow_run
+        self.mb = MicroBatcher(engine)
+        self.scaler = FleetAutoscaler(
+            self.client, policy, self.ME, pool=["replica-c", "replica-d"],
+            notice_deadline_s=60.0)
+        self.futures, self.hints = [], []
+
+    def burst(self, n, deadline_every=0):
+        for i in range(n):
+            expired = deadline_every and i % deadline_every == 0
+            try:
+                self.futures.append(self.mb.submit(
+                    {"x": self.rows[i % len(self.rows)]},
+                    deadline_s=0.001 if expired else None))
+            except ServingUnavailable as e:
+                self.hints.append(e.retry_after_s)
+
+    def settle(self):
+        for f in self.futures:
+            try:
+                f.result(timeout=30)
+            except ServingUnavailable as e:
+                self.hints.append(e.retry_after_s)
+        self.futures.clear()
+
+    def close(self):
+        self.mb.close()
+        self.client.close()
+
+
+def _ramp_policy(**kw):
+    return AutoscalePolicy(min_replicas=2, max_replicas=4, queue_high=8,
+                           queue_low=2, **kw)
+
+
+def test_a_load_ramp_grows_the_fleet_and_idles_it_back_by_planned_departures(
+        server, monkeypatch):
+    """2 → 4 → 2 under live traffic: sustained queue depth grows the
+    fleet, idleness shrinks it back through the planned-departure path
+    (a notice and a survivor epoch, never the checkpoint fallback),
+    nothing is shed outside the overload window, every shed carries a
+    ``Retry-After``, and the overload window saw both degradation paths:
+    a brownout entry and an expired-deadline shed."""
+    fleet = _LoadedFleet(server, monkeypatch, _ramp_policy(
+        sustain_s=0.05, grow_cooldown_s=0.02, shrink_cooldown_s=0.02))
+    try:
+        give_up = time.perf_counter() + 30.0
+        while ((fleet.scaler.stats()["grows"] < 2
+                or fleet.mb.stats()["brownout"]["entries"] < 1)
+               and time.perf_counter() < give_up):
+            fleet.burst(24, deadline_every=8)
+            fleet.scaler.step()
+            time.sleep(0.01)
+        fleet.settle()
+        shed_in_overload = tel.counters().get("serve.shed", 0.0)
+        give_up = time.perf_counter() + 30.0
+        while (fleet.scaler.stats()["shrinks"] < 2
+               and time.perf_counter() < give_up):
+            fleet.scaler.step()
+            time.sleep(0.02)
+        scaled = fleet.scaler.stats()
+        served = fleet.mb.stats()
+        counters = tel.counters()
+        epoch = elastic.read_epoch(fleet.client)
+    finally:
+        fleet.close()
+    assert scaled["grows"] >= 1 and scaled["shrinks"] >= 1, scaled
+    assert epoch is not None and len(epoch[1]) == 2, epoch
+    assert counters.get("preempt.notices", 0.0) >= 1
+    assert counters.get("ckpt.fallback", 0.0) == 0
+    assert counters.get("serve.shed", 0.0) == shed_in_overload
+    assert fleet.hints and all(h is not None for h in fleet.hints)
+    assert served["brownout"]["entries"] >= 1, served["brownout"]
+    assert served["deadline_shed"] >= 1, served
+
+
+@pytest.mark.slow
+@pytest.mark.chaos
+def test_load_that_oscillates_inside_the_sustain_window_does_not_flap_the_fleet(
+        server, monkeypatch):
+    """Bursts shorter than the policy's sustain window, drained between
+    spikes: the hysteresis band and the window hold the fleet still (at
+    most two scale events over forty decisions)."""
+    fleet = _LoadedFleet(server, monkeypatch, _ramp_policy(
+        sustain_s=0.5, grow_cooldown_s=30.0, shrink_cooldown_s=30.0))
+    try:
+        for _ in range(40):
+            fleet.burst(12)
+            fleet.scaler.step()
+            time.sleep(0.05)
+        fleet.settle()
+        scaled = fleet.scaler.stats()
+    finally:
+        fleet.close()
+    assert scaled["grows"] + scaled["shrinks"] <= 2, scaled
+    assert scaled["holds"] >= 10, scaled

@@ -468,10 +468,6 @@ def test_blackbox_bounded_retention_and_log_tail(monkeypatch, tmp_path):
     from autodist_tpu.utils import logging as adt_logging
     fr = blackbox.get_flight_recorder()
     fr.clear()
-    # the dump counter is the process's, and the names it numbers sort as
-    # text: count as a process that runs this file alone does, whatever
-    # this worker ran before (ROADMAP D17: across 9 -> 10 the order breaks)
-    monkeypatch.setattr(fr, "dumps", 0)
     adt_logging.warning("blackbox tail marker %d", 42)
     for i in range(4):
         fr.record("test.event", i=i)
@@ -482,6 +478,28 @@ def test_blackbox_bounded_retention_and_log_tail(monkeypatch, tmp_path):
     assert any("blackbox tail marker 42" in rec["msg"]
                for rec in d["logs"])
     assert [e["data"]["i"] for e in d["events"]] == [0, 1, 2, 3]
+
+
+def test_blackbox_retention_keeps_the_newest_across_the_tenth_dump(
+        monkeypatch, tmp_path):
+    """A process's ninth, tenth, eleventh and twelfth dumps within one
+    second: the names sort in the order they were written, so the two kept
+    are the last two."""
+    monkeypatch.setenv("ADT_BLACKBOX_KEEP", "2")
+    monkeypatch.setattr(blackbox.time, "strftime",
+                        lambda fmt: "20260101-000000")
+    fr = blackbox.get_flight_recorder()
+    fr.clear()
+    monkeypatch.setattr(fr, "dumps", 8)
+    paths = []
+    for i in range(4):
+        fr.record("test.event", i=i)
+        paths.append(fr.dump("retention-test", directory=str(tmp_path)))
+    kept = sorted(os.path.join(str(tmp_path), f)
+                  for f in os.listdir(str(tmp_path)))
+    assert kept == paths[-2:]
+    assert [e["data"]["i"] for e in blackbox.load_dump(kept[-1])["events"]] \
+        == [0, 1, 2, 3]
 
 
 def test_blackbox_disabled_writes_nothing(monkeypatch, tmp_path):

@@ -40,7 +40,7 @@ from autodist_tpu.serving.decode import (DecodeConfig, DecodeEngine,
 
 class TestSlotScheduler:
     def test_continuous_admits_into_any_freed_slot(self):
-        sched = SlotScheduler(4, "continuous")
+        sched = SlotScheduler(4)
         assert sched.admissible(queued=10) == 4
         sched.occupy(0, object())
         sched.occupy(2, object())
@@ -48,15 +48,6 @@ class TestSlotScheduler:
         assert sched.admissible(queued=10) == 2
         assert sched.admissible(queued=1) == 1
         assert sched.occupancy() == 0.5
-
-    def test_static_admits_only_when_all_slots_free(self):
-        sched = SlotScheduler(4, "static")
-        assert sched.admissible(queued=10) == 4
-        sched.occupy(1, object())
-        # the classic static-batching idle: three free slots, zero admits
-        assert sched.admissible(queued=10) == 0
-        sched.evict(1)
-        assert sched.admissible(queued=2) == 2
 
     def test_evict_frees_for_readmission(self):
         sched = SlotScheduler(2)
@@ -73,8 +64,6 @@ class TestSlotScheduler:
         assert sched.get(0) is c
 
     def test_config_validation(self):
-        with pytest.raises(ValueError, match="admission"):
-            DecodeConfig(admission="greedy")
         with pytest.raises(ValueError):
             DecodeConfig(slots=0)
         with pytest.raises(ValueError):
@@ -294,6 +283,36 @@ def test_engine_parity_ps():
         assert engine.recompiles_after_warmup() == 0
     finally:
         engine.close()
+
+
+def test_a_mixed_length_trace_on_host_ps_refills_freed_slots_with_no_recompile():
+    """Six requests a slot, one long generation among shorts in every
+    group of eight, all queued at once on a host-PS runner: every
+    sequence runs to its own length, none errors, the one decode program
+    serves every occupancy, and freed slots take queued work while a long
+    sequence still runs (a batch drained before it is refilled would take
+    ``groups * (longest - 1)`` steps)."""
+    runner, cfg, _ = _build_lm_runner(S.PS)
+    slots, groups, longest, short = 8, 6, 48, 8
+    rng = np.random.RandomState(7)
+    trace = [(rng.randint(0, cfg.vocab_size, (1 + i % 6,)).astype(np.int32),
+              longest if i % slots == 0 else short)
+             for i in range(groups * slots)]
+    engine = DecodeEngine(runner, lm.make_decode_setup(cfg),
+                          DecodeConfig(slots=slots, max_new_tokens=longest,
+                                       prefill_len=8))
+    try:
+        engine.warmup()
+        futures = [engine.submit(p, max_new_tokens=m) for p, m in trace]
+        lengths = [len(f.result(timeout=300)["tokens"]) for f in futures]
+        stats = engine.stats()
+    finally:
+        engine.close()
+    assert lengths == [m for _, m in trace]
+    assert stats["recompiles_after_warmup"] == 0, stats
+    assert stats["errors"] == 0
+    assert stats["completed"] == stats["evictions"] == len(trace)
+    assert 0 < stats["steps"] < groups * (longest - 1), stats["steps"]
 
 
 def test_drain_completes_in_flight_and_sheds_queued():

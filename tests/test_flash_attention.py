@@ -673,17 +673,15 @@ PARENT_CALLS = {
 def test_a_call_without_a_selection_traces_to_the_parents_kernels(case):
     """``flash_attention`` without ``select`` and with as many K/V heads as
     query heads, differentiated: the same jaxpr, equation for equation and
-    kernel body for kernel body, as at the parent commit of PR 37 (its
-    text's hash, ``tests/data/lm_before_keye_vl2.json``). The selection
-    operand and the grouped heads changed no program that does not ask
-    for them. (Since PR 39 the kernels walk a tile table through scalar
-    prefetch: the three hashes are that tree's, ``re_pinned_in_pr39`` in
-    the data file; a later change to these programs shows here.)"""
+    kernel body for kernel body, as when it was last pinned (its text's
+    hash, ``flash_attention`` in ``tests/data/lm_pins.json``). The
+    selection operand and the grouped heads changed no program that does
+    not ask for them; a later change to these programs shows here."""
     import hashlib
     import json
     import os
     with open(os.path.join(os.path.dirname(__file__), "data",
-                           "lm_before_keye_vl2.json")) as f:
+                           "lm_pins.json")) as f:
         before = json.load(f)["flash_attention"][case]
     shape, causal, seg = PARENT_CALLS[case]
     q = jnp.zeros(shape, jnp.float32)

@@ -1,11 +1,10 @@
 """Kimi-Linear's configuration and cell (``tests/test_kimi_linear.py`` holds
 the model to its reference): the preset against the catalog row, the cell's
 configuration file against the tree it builds, what the model refuses, the
-shape rules of ``make_train_setup``, lm1b's and OLMoE's models held to what
-they built before the model got a per-layer pattern, and what the cell's
-``loss_rtol`` refuses (``benchmark/tools/loss_limit_kimi_linear.py``)."""
+shape rules of ``make_train_setup``, OLMoE's parameter tree held to what it
+was before the model got a per-layer pattern (every preset's step:
+``tests/test_lm_pins.py``), and what the cell's ``loss_rtol`` refuses (``benchmark/tools/loss_limit_kimi_linear.py``)."""
 import dataclasses
-import hashlib
 import json
 import os
 
@@ -58,7 +57,7 @@ def test_blocks_are_recomputed_where_the_state_takes_half_the_chip(
     (20480, 1, 2048, False),
     (99183, 64, 256, True),      # lm1b, as before
     (50304, 4, 2048, True),      # OLMoE, as before
-    (32000, 32, 128, False),     # the default config at bench.py's batch
+    (32000, 32, 128, False),     # the default config at 32 rows of 128
     (128, 4, 16, False)])
 def test_the_lean_head_engages_on_the_logits_bytes_too(vocab, rows, seq, lean,
                                                       monkeypatch):
@@ -162,13 +161,7 @@ def test_an_architecture_the_model_cannot_build_is_refused(change, says):
         tiny_config(**change)
 
 
-# --------------------------------- lm1b and OLMoE are what they were
-
-
-@pytest.fixture(scope="module")
-def before():
-    with open(os.path.join(HERE, "data", "lm_before_kimi_linear.json")) as f:
-        return json.load(f)
+# ------------------------------------- OLMoE's tree is what it was
 
 
 def tree_of(cfg):
@@ -179,34 +172,10 @@ def tree_of(cfg):
             if path[0].key == "params"]
 
 
-def test_olmoe_parameter_tree_is_unchanged(before):
-    assert tree_of(lm.LMConfig.olmoe_1b_7b(num_layers=1)) \
-        == before["olmoe_tree"]
-
-
-TINY_OLMOE = dict(vocab_size=256, d_model=64, num_heads=4, num_experts=8,
-                  experts_per_token=2, mlp_dim=32)
-
-
-@pytest.mark.parametrize("which", ["tiny_lm_step", "tiny_olmoe_step"])
-def test_the_loss_and_its_gradient_trace_to_the_parents_jaxpr(before, which):
-    """The differentiated loss of a tiny lm1b-style model and of a tiny
-    OLMoE, as ``make_train_setup`` builds them: the same jaxpr, equation
-    for equation, as at the parent commit (its text's hash), and the same
-    loss and gradient norm bit for bit."""
-    cfg = lm.LMConfig.tiny() if which == "tiny_lm_step" else \
-        dataclasses.replace(
-            lm.LMConfig.olmoe_1b_7b(num_layers=2, max_seq_len=16),
-            **TINY_OLMOE)
-    loss_fn, params, batch, _ = lm.make_train_setup(
-        cfg, seq_len=16, batch_size=4, seed=0)
-    text = str(jax.make_jaxpr(jax.value_and_grad(loss_fn))(params, batch))
-    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params, batch)
-    norm = jnp.sqrt(sum(jnp.sum(g * g)
-                        for g in jax.tree_util.tree_leaves(grads)))
-    assert {"jaxpr_sha256": hashlib.sha256(text.encode()).hexdigest(),
-            "jaxpr_lines": text.count("\n"), "loss": float(loss).hex(),
-            "gradnorm": float(norm).hex()} == before[which]
+def test_olmoe_parameter_tree_is_unchanged():
+    with open(os.path.join(HERE, "data", "lm_pins.json")) as f:
+        assert tree_of(lm.LMConfig.olmoe_1b_7b(num_layers=1)) \
+            == json.load(f)["olmoe_tree"]
 
 
 def test_the_kernel_with_equal_widths_is_the_kernel_it_was():

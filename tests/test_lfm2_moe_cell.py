@@ -3,12 +3,10 @@ the model to its reference): the configuration file against the catalog's
 row key by key and against the tree it builds, the closed-form FLOPs and
 bytes against the program's own products at a tiny size, the shape rules of
 ``make_train_setup`` for this cell, the grouped core at heads of 64
-compiled for a described v5e, the model through ``Runner.fit``, the other
-five presets held to what they built before the conv mixer and the tied
-head came, and what the cell's ``loss_rtol`` refuses
+compiled for a described v5e, the model through ``Runner.fit``, and what
+the cell's ``loss_rtol`` refuses
 (``benchmark/tools/loss_limit_lfm2_moe.py``)."""
 import dataclasses
-import hashlib
 import json
 import os
 
@@ -23,8 +21,7 @@ from autodist_tpu import strategy as S
 from autodist_tpu import telemetry
 from autodist_tpu.models import lm
 from benchmark.reference import lfm2_moe as ref
-from tests.test_keye_vl2_cell import (bench_json, bench_lines, dot_flops,
-                                      tree_digest)
+from tests.test_keye_vl2_cell import bench_json, bench_lines, dot_flops
 from tests.test_kimi_linear import close, cpu_spec, flat
 from tests.test_lfm2_moe import HELD, SEQ, TOP_K, batches, tiny_config
 
@@ -351,39 +348,6 @@ def test_fit_gives_the_reference_losses_of_steps_0_and_1(tiny, devices):
     for name in before:
         moved = np.any(np.asarray(after[name]) != np.asarray(before[name]))
         assert moved == ("e_score_correction_bias" not in name), name
-
-
-# --------------------- the other five presets are what they were (PR 39's)
-
-
-def step_configs():
-    from tests.test_keye_vl2 import tiny_config as keye
-    from tests.test_keye_vl2_cell import step_configs as before_keye
-    return dict(before_keye(),
-                tiny_keye_vl2_step=(keye, 32, 2, "auto"),
-                tiny_keye_vl2_flash_step=(keye, 32, 2, "flash"))
-
-
-@pytest.mark.parametrize("which", sorted(step_configs()))
-def test_the_other_presets_trace_to_the_parents_jaxpr(which):
-    """The differentiated loss of a tiny lm1b-style model, OLMoE,
-    Kimi-Linear, DeepSeek-V2-Lite and Keye-VL-2.0 as ``make_train_setup``
-    builds them (three of them also forced onto the flash kernels): the
-    same parameter tree and the same jaxpr, equation for equation, as at
-    the parent commit (its text's hash; ``tests/test_keye_vl2_cell.py``
-    runs six of them and holds loss and gradient norm bit for bit). The
-    conv mixer, the tied head and the lean head's choice of kernel changed
-    no equation of theirs."""
-    with open(os.path.join(HERE, "data", "lm_before_lfm2_moe.json")) as f:
-        before = json.load(f)[which]
-    make, seq, rows, attention = step_configs()[which]
-    loss_fn, params, batch, _ = lm.make_train_setup(
-        make(), seq_len=seq, batch_size=rows, seed=0, attention=attention)
-    text = str(jax.make_jaxpr(jax.value_and_grad(loss_fn))(params, batch))
-    assert (hashlib.sha256(text.encode()).hexdigest(), text.count("\n"),
-            tree_digest(params)) == (
-        before["jaxpr_sha256"], before["jaxpr_lines"],
-        before["param_tree_sha256"])
 
 
 # ---- what the cell's loss_rtol refuses (benchmark/tools/loss_limit_lfm2_moe.py)

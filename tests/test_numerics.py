@@ -409,10 +409,13 @@ def test_bf16_lowered_program_lints_clean(name, builder):
 def test_bf16_e2e_loss_parity_and_f32_master():
     """Acceptance: a bf16 plan TRAINS — the loss tracks the f32 curve
     within the sentinel-scale band, step_stats reports the tier, and
-    gathered params stay float32 (the master never leaves f32)."""
+    gathered params stay float32 (the master never leaves f32). With
+    the sentinel armed (ADT604: half precision ships with the skip /
+    rollback net) bf16 rounding alone trips no guard, and the casts add
+    no dispatch."""
     import jax
 
-    def leg(compute_dtype):
+    def leg(compute_dtype, sentinel=None):
         autodist_tpu.reset()
         item, batch = _mlp_item()
         rng = np.random.RandomState(1)
@@ -422,19 +425,23 @@ def test_bf16_e2e_loss_parity_and_f32_master():
         ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce(
             compute_dtype=compute_dtype))
         runner = ad.build(item.loss_fn, optax.adam(1e-2),
-                          dict(item.params), batches[0])
+                          dict(item.params), batches[0], sentinel=sentinel)
         runner.init(dict(item.params))
         hist = runner.fit(batches)
-        stats = runner.step_stats()
+        stats = dict(runner.step_stats(),
+                     dispatches=runner.distributed_step.dispatches)
         leaves = {str(x.dtype) for x in jax.tree_util.tree_leaves(
             runner.gather_params())}
         return [float(m["loss"]) for m in hist], stats, leaves
 
     f_losses, f_stats, f_leaves = leg("f32")
-    b_losses, b_stats, b_leaves = leg("bf16")
+    b_losses, b_stats, b_leaves = leg("bf16", sentinel=True)
     autodist_tpu.reset()
     assert f_stats["compute_dtype"] == "f32"
     assert b_stats["compute_dtype"] == "bf16"
+    assert b_stats["sentinel"]["skips"] == 0
+    assert b_stats["sentinel"]["rollbacks"] == 0
+    assert b_stats["dispatches"] == f_stats["dispatches"]
     assert f_leaves == b_leaves == {"float32"}
     np.testing.assert_allclose(b_losses, f_losses, rtol=0.3, atol=5e-3)
     assert abs(b_losses[-1] - f_losses[-1]) <= (

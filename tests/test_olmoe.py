@@ -439,7 +439,7 @@ def test_prefill_and_cached_decode_equal_full_recompute(tiny):
 
 @pytest.fixture(scope="module")
 def before():
-    with open(os.path.join(HERE, "data", "lm_before_olmoe.json")) as f:
+    with open(os.path.join(HERE, "data", "lm_pins.json")) as f:
         return json.load(f)
 
 

@@ -641,7 +641,7 @@ from autodist_tpu.runtime.coordination import (CoordinationClient,
                                                CoordinationServer)
 from autodist_tpu.telemetry import spans as tel
 
-outdir = sys.argv[1]
+outdir, announced = sys.argv[1], sys.argv[2] == "announced"
 port = int(os.environ["ADT_COORDSVC_PORT"])
 srv = CoordinationServer(port)
 srv.start()
@@ -683,7 +683,7 @@ assert m is not None and m.roster == [me, "peer-leaving"], m.roster
 losses = []
 for i in range(10):
     losses.append(float(runner.run(batch)["loss"]))
-    if i == 3:
+    if i == 3 and announced:
         # the peer announces its departure: every process (this
         # survivor included) joins the rescue checkpoint and pre-stages
         # its snapshot for the announced shrink
@@ -715,13 +715,19 @@ srv.stop()
 """
 
 
-def test_planned_peer_departure_reconfigures_without_fallback(tmp_path):
+@pytest.mark.parametrize("announced", [True, False],
+                         ids=["announced", "unannounced"])
+def test_peer_departure_reconfigures_without_fallback(tmp_path,
+                                                      announced):
     """Acceptance core: a planned eviction of a sync peer completes the
     handoff from LIVE state — the surviving process rescue-checkpoints
     at the agreed step, pre-stages its snapshot, reconfigures under the
     announced shrink epoch with the ``planned`` flag on the downtime
     span, and ``ckpt.fallback`` stays at ZERO while the loss trajectory
-    matches the uninterrupted run exactly."""
+    matches the uninterrupted run exactly. The same shrink with no
+    notice before it: one reconfigure all the same, its snapshot taken
+    inside the span (no ``planned`` flag, no rescue save), the same
+    losses."""
     script = tmp_path / "driver.py"
     script.write_text(PEER_DRIVER)
     env = dict(os.environ)
@@ -738,18 +744,19 @@ def test_planned_peer_departure_reconfigures_without_fallback(tmp_path):
             ([os.environ["PYTHONPATH"]] if os.environ.get("PYTHONPATH")
              else [])),
     })
-    proc = subprocess.run([sys.executable, str(script), str(tmp_path)],
-                          env=env, capture_output=True, text=True,
-                          timeout=240)
+    proc = subprocess.run(
+        [sys.executable, str(script), str(tmp_path),
+         "announced" if announced else "unannounced"],
+        env=env, capture_output=True, text=True, timeout=240)
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-4000:]
     out = json.loads((tmp_path / "out.json").read_text())
     assert out["reconfigs"] == 1 and out["epoch"] == 2, out
     # the survivor joined the cluster-agreed rescue checkpoint
-    assert out["preempt"]["rescue_saves"] == 1.0, out["preempt"]
+    assert out["preempt"]["rescue_saves"] == float(announced), out["preempt"]
     # the handoff used LIVE state: the reconfigure ran with the
     # pre-staged snapshot (planned flag) and NEVER touched the
     # last-good-checkpoint fallback
-    assert out["planned_flags"] == [True], out
+    assert out["planned_flags"] == [announced], out
     assert out["ckpt_fallback"] == 0.0, out
     assert out["reconfigure_s"][0] > 0
     np.testing.assert_allclose(out["losses"], out["ref"],

@@ -123,6 +123,8 @@ def test_transient_nan_skipped_and_converges(monkeypatch, name,
     assert stats["skips"] == 1
     assert tel.counters()["sentinel.skips"] == 1
     assert tel.counters()["sentinel.nan_steps"] == 1
+    # the discard is in-graph: a skipped step is still one dispatch
+    assert runner.distributed_step.dispatches == 30
     # one discarded update costs one step of progress, not convergence
     assert losses[-1] == pytest.approx(loss_clean, rel=0.15)
     if name == "PS":

@@ -570,3 +570,151 @@ def test_brownout_config_validation():
         ServingConfig(brownout_queue_frac=0.0)
     with pytest.raises(ValueError, match="brownout_delay_factor"):
         ServingConfig(brownout_delay_factor=0.5)
+
+
+# ------------------ the recommendation flagships behind the micro-batcher
+
+
+def _flagship(label):
+    """(runner, serve_fn, requests) of a tiny DLRM under Parallax (tables
+    on load-balanced PS, dense MLPs on AllReduce) or a tiny NCF on host
+    PS, one train step in."""
+    if label == "dlrm":
+        from autodist_tpu.models.dlrm import DLRMConfig, make_train_setup
+        loss_fn, params, batch, apply_fn = make_train_setup(
+            DLRMConfig.tiny(), batch_size=64)
+        keys, builder = ("dense", "sparse"), S.Parallax()
+    else:
+        from autodist_tpu.models.ncf import NCFConfig, make_train_setup
+        loss_fn, params, batch, apply_fn = make_train_setup(
+            NCFConfig.tiny(), batch_size=64)
+        keys, builder = ("user", "item"), S.PS()
+    autodist_tpu.reset()
+    ad = autodist_tpu.AutoDist(strategy_builder=builder)
+    runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
+    runner.init(params)
+    runner.run(batch)
+    requests = [{k: np.asarray(batch[k])[i] for k in keys} for i in range(64)]
+
+    def serve_fn(p, b):
+        return {"score": apply_fn(p, *(b[k] for k in keys))}
+    return runner, serve_fn, requests
+
+
+def _closed_loop(mb, requests, clients=8, each=25):
+    """``clients`` threads, each submitting ``each`` requests one at a
+    time and waiting for the answer. Returns (answered, shed, errored)."""
+    counts = [[0, 0, 0] for _ in range(clients)]
+
+    def client(i):
+        rng = np.random.RandomState(i)
+        for _ in range(each):
+            try:
+                mb.submit(requests[rng.randint(len(requests))]).result(
+                    timeout=60)
+                counts[i][0] += 1
+            except ServingUnavailable:
+                counts[i][1] += 1
+            except Exception:  # noqa: BLE001 - counted, the caller asserts
+                counts[i][2] += 1
+    threads = [threading.Thread(target=client, args=(i,), daemon=True)
+               for i in range(clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    return tuple(sum(c[j] for c in counts) for j in range(3))
+
+
+def _flagship_engine(runner, serve_fn, requests):
+    replicas = runner.remapper.num_replicas
+    return InferenceEngine(
+        runner, serve_fn, requests[0],
+        ServingConfig(buckets=(4 * replicas, 8 * replicas),
+                      max_delay_ms=1.0)).warmup()
+
+
+@pytest.mark.parametrize("label", ["dlrm", "ncf"])
+def test_a_flagship_under_closed_loop_traffic_answers_all_and_recompiles_nothing(
+        label):
+    """Eight closed-loop clients against a tiny DLRM (Parallax) and a tiny
+    NCF (host PS), two-feature requests: every one answered, none shed or
+    errored, no bucket compiled again; and, traced, a request's whole
+    path is in a trace that validates: enqueue, the group's batch, its
+    dispatch and its readback."""
+    from autodist_tpu.telemetry import export
+    tel.configure("1")
+    try:
+        runner, serve_fn, requests = _flagship(label)
+        with MicroBatcher(_flagship_engine(runner, serve_fn, requests)) as mb:
+            assert _closed_loop(mb, requests) == (200, 0, 0)
+            stats = mb.stats()
+        names = {e.name for e in tel.get_recorder().events()}
+        trace = export.chrome_trace()
+    finally:
+        tel.configure(None)
+    assert stats["errors"] == 0 and stats["shed"] == 0
+    assert stats["recompiles_after_warmup"] == 0
+    assert stats["fan_out"] == 200 and stats["batches"] <= 200
+    assert {"serve.enqueue", "serve.batch", "serve.dispatch",
+            "serve.readback"} <= names
+    assert export.validate_chrome_trace(trace) == []
+
+
+def test_a_replica_behind_a_faulted_wire_answers_or_sheds_typed_and_counts_it(
+        monkeypatch, tmp_path):
+    """Degraded but alive, on the real wire: NCF's PS store re-wired as a
+    serving replica that owns nothing and fetches every value over the
+    coordination service through a ``FaultyProxy`` that resets every bulk
+    read. The first batches are answered from the last good snapshot
+    (counted as degraded), the rest shed typed once the window is spent;
+    no request errors and none hangs."""
+    import socket
+
+    from autodist_tpu.parallel.ps import PSStore
+    from autodist_tpu.runtime import ps_service
+    from autodist_tpu.runtime.coordination import CoordinationServer
+    from autodist_tpu.runtime.faultinject import FaultPlan, FaultyProxy
+    from autodist_tpu.runtime.resilience import ResilientCoordinationClient
+
+    monkeypatch.setenv("ADT_BLACKBOX_DIR", str(tmp_path))  # the breaker dumps
+    runner, serve_fn, requests = _flagship("ncf")
+    engine = _flagship_engine(runner, serve_fn, requests)
+    store = runner.distributed_step.ps_store
+    owner_host, = {d.split(":")[0] for p in store.plans.values()
+                   for d in p.destinations if d}
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    server = CoordinationServer(port=port)
+    server.start()
+    plan = FaultPlan({"seed": 3, "faults": [
+        {"op": "reset", "match": "BGETB", "nth": 1, "repeat": True}]})
+    proxy = FaultyProxy("127.0.0.1", port, plan=plan).start()
+    owner = PSStore(dict(store.plans), store._var_infos, store._optimizer)
+
+    def service(host):
+        return ps_service.CoordPSService(
+            lambda: ResilientCoordinationClient(
+                "127.0.0.1", proxy.port, rpc_timeout=2.0, max_retries=2,
+                seed=0), prefix="ps:" + host)
+    try:
+        owner.init_params(store.full_values())
+        owner.enable_serving(service, my_host=owner_host)
+        store.enable_serving(service, my_host="serving-replica")
+        engine.config.snapshot_max_age_s = 0.0  # a refresh every batch
+        before = tel.counters()
+        with MicroBatcher(engine) as mb:
+            answered, shed, errored = _closed_loop(mb, requests, each=10)
+            stats = mb.stats()
+        after = tel.counters()
+    finally:
+        proxy.stop()
+        owner.close()
+        server.stop()
+    assert errored == 0 and answered + shed == 80
+    assert answered > 0 and shed > 0, (answered, shed)
+    assert stats["recompiles_after_warmup"] == 0
+    assert len(plan.injected) > 0
+    assert after["serve.degraded"] - before.get("serve.degraded", 0) >= 1
+    assert after["serve.shed"] - before.get("serve.shed", 0) == shed

@@ -198,6 +198,9 @@ def test_zero_hbm_saved_gauge_and_metadata():
     assert meta["zero_hbm_saved_bytes"] > 0
     from autodist_tpu.telemetry.spans import get_recorder
     assert get_recorder().gauges().get("zero.hbm_saved_bytes", 0) > 0
+    # the fp32 wire credits its reduce-scatter and all-gather too
+    assert tel.counters()["zero.rs_bytes"] > 0
+    assert tel.counters()["zero.ag_bytes"] > 0
 
 
 def test_zero_single_replica_degrades_to_allreduce():
