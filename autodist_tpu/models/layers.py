@@ -651,7 +651,9 @@ class TransformerBlock(nn.Module):
     width. A model whose layers differ names each block's token mixer by
     its sizes (``kda``, ``mla`` or ``conv_size`` instead of the softmax
     attention above) and gives a leading dense layer its SwiGLU width
-    (``dense_dim``)."""
+    (``dense_dim``). ``sandwich_norm`` norms each sub-layer's OUTPUT too,
+    before it joins the residual (``x + N(f(N(x)))``: four norms a block,
+    the looped Ouro models' block)."""
     num_heads: int
     head_dim: int
     mlp_dim: int
@@ -674,6 +676,7 @@ class TransformerBlock(nn.Module):
     qk_head_norm: bool = False
     indexer: Optional[IndexerConfig] = None
     conv_size: int = 0
+    sandwich_norm: bool = False
 
     def _mix(self, h, mask, cache, cursor, alive, return_kv, positions):
         """The block's token mixer on the normed input."""
@@ -713,6 +716,9 @@ class TransformerBlock(nn.Module):
             h = self._mix(h, mask, cache, cursor, alive, return_kv, positions)
         if cache is not None or return_kv:
             h, kv = h
+        if self.sandwich_norm:
+            h = make_norm(self.norm, self.norm_eps, self.dtype,
+                          "attn_out_norm")(h)
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
         x = x + h
@@ -727,6 +733,9 @@ class TransformerBlock(nn.Module):
             h = nn.Dense(self.mlp_dim, dtype=self.dtype)(h)
             h = nn.gelu(h)
             h = nn.Dense(x.shape[-1], dtype=self.dtype)(h)
+        if self.sandwich_norm:
+            h = make_norm(self.norm, self.norm_eps, self.dtype,
+                          "mlp_out_norm")(h)
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
         x = x + h

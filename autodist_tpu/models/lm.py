@@ -127,6 +127,18 @@ class LMConfig:
     # chip's share under expert parallelism (``parallel/expert.py``);
     # None = all. The router scores all ``num_experts`` either way.
     experts_held: Optional[Tuple[int, ...]] = None
+    # A looped model (the Ouro family, arXiv 2510.25741): the WHOLE stack
+    # of layers and the final norm run ``loop_steps`` times over the same
+    # weights, each pass on the previous pass's normed output; the head
+    # reads after every pass and an exit gate ``sigmoid(w x + b)`` on each
+    # pass's state turns the passes' losses into one, less
+    # ``exit_entropy_coef`` times the entropy of the exit distribution
+    # (``make_train_setup``). 1 = every layer once, no gate.
+    loop_steps: int = 1
+    exit_entropy_coef: float = 0.0
+    # each sub-layer's OUTPUT is normed too before it joins the residual
+    # (four norms a block)
+    sandwich_norm: bool = False
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -194,6 +206,19 @@ class LMConfig:
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm (over all features) or qk_head_norm "
                              "(per head), not both")
+        if self.loop_steps < 1 or (self.loop_steps == 1
+                                   and self.exit_entropy_coef):
+            raise ValueError(
+                "loop_steps counts the passes over the stack (>= 1), and "
+                "exit_entropy_coef belongs to the exit gate of a looped "
+                "model: got loop_steps %d with exit_entropy_coef %g"
+                % (self.loop_steps, self.exit_entropy_coef))
+        if self.loop_steps > 1 and (self.num_experts
+                                    or self.indexer_num_heads):
+            raise ValueError(
+                "loop_steps > 1 scans the stack over shared weights: a "
+                "routed layer's losses and counters and an indexer's "
+                "choice, sown once a pass, have no way out of the scan yet")
         held = self.experts_held
         if held is not None and (
                 not held or len(set(held)) != len(held)
@@ -334,6 +359,28 @@ class LMConfig:
                    moe_renormalize=True, **kw)
 
     @classmethod
+    def ouro_2_6b(cls, **kw):
+        """Ouro-2.6B as its ``config.json`` publishes it
+        (huggingface.co/ByteDance/Ouro-2.6B, ``model_type: ouro``; arXiv
+        2510.25741): 48 layers of hidden 2,048 without a bias, 16 heads of
+        128 over as many K/V heads, RoPE at theta 1e6 over all 128
+        features, a SwiGLU of 5,632, RMSNorm eps 1e-6 before AND after each
+        sub-layer, an untied head over 49,152 words; the whole stack and
+        the final norm run ``total_ut_steps`` = 4 times over the same
+        weights and an exit gate weighs the four losses. The sandwich
+        norms, the normed state carried on, the gate's bias and the
+        entropy term's 0.05 are the paper's, without a key in the row:
+        assumptions the benchmark's configuration file lists."""
+        n = kw.setdefault("num_layers", 48)
+        kw.setdefault("max_seq_len", 65536)
+        return cls(vocab_size=49152, d_model=2048, num_heads=16,
+                   head_dim=128, mlp_dim=5632, first_k_dense_replace=n,
+                   dense_dim=5632, norm="rmsnorm", norm_eps=1e-6,
+                   rope_theta=1e6, attention_bias=False, head_bias=False,
+                   embed_scale=False, loop_steps=4, exit_entropy_coef=0.05,
+                   sandwich_norm=True, **kw)
+
+    @classmethod
     def tiny(cls, **kw):
         return cls(vocab_size=128, d_model=32, num_layers=2, num_heads=2,
                    mlp_dim=64, max_seq_len=64, **kw)
@@ -433,7 +480,7 @@ class TransformerLM(nn.Module):
             attention_bias=cfg.attention_bias, qk_norm=cfg.qk_norm,
             rope_theta=cfg.rope_theta, num_experts=cfg.num_experts,
             experts_per_token=cfg.experts_per_token,
-            name="layer_%d" % i, **kw)
+            sandwich_norm=cfg.sandwich_norm, name="layer_%d" % i, **kw)
 
     def _final_norm(self, x):
         cfg = self.config
@@ -449,11 +496,46 @@ class TransformerLM(nn.Module):
         return nn.Dense(cfg.vocab_size, dtype=jnp.float32,
                         use_bias=cfg.head_bias, name="lm_head")(x)
 
+    def _looped(self, x, mask, positions):
+        """A looped model's passes: all the blocks and the final norm as
+        ONE body scanned ``loop_steps`` times with the parameters
+        broadcast, so that the trace holds each block once whatever the
+        passes; the compiler sees them unrolled, as straight-line code
+        (one loop read 1.3 % slower a step on the v5e with 5.2 GB more
+        scratch, PERF.md section 6, PR 44). Carries the normed state and
+        returns every pass's, [T, B, S, d]. Each
+        block stays recomputed (with its kept names) where
+        ``remat_blocks`` says so: the scan then saves a block's input and
+        what it keeps, once a pass."""
+        cfg = self.config
+
+        def one_pass(model, x, _):
+            for i in range(cfg.num_layers):
+                x = model._block(i, attn_fn=model.attn_fn)(
+                    x, mask, positions=positions)
+            x = model._final_norm(x)
+            return x, x
+
+        with scopes.scope(scopes.LOOP):
+            _, states = nn.scan(
+                one_pass, variable_broadcast="params",
+                split_rngs={"params": False}, length=cfg.loop_steps,
+                unroll=cfg.loop_steps)(self, x, None)
+        return states
+
+    def _refuse_a_looped_model(self, what):
+        if self.config.loop_steps > 1:
+            raise NotImplementedError(
+                "%s keeps one K/V cache a layer: loop_steps = %d passes "
+                "need a cache per (pass, layer) and an exit rule, which "
+                "serving does not have yet" % (what, self.config.loop_steps))
+
     @nn.compact
     def hidden(self, input_ids):
         """Final-layer-norm hidden states [B, S, d] — the lean-head loss
         applies the lm_head itself through ``ops.xent`` so the [N, vocab]
-        logits tensor never materializes."""
+        logits tensor never materializes. A looped model (``loop_steps``
+        > 1) returns the normed state after EVERY pass, [T, B, S, d]."""
         seq_len = input_ids.shape[-1]  # LOCAL length under seq sharding
         positions = jnp.arange(seq_len)
         if self.seq_parallel:
@@ -464,14 +546,28 @@ class TransformerLM(nn.Module):
         # inside the op; the local mask would be wrong and is skipped
         mask = None if self.attn_fn is not None else causal_mask(seq_len)
         with scopes.scope(scopes.BLOCKS):
+            if self.config.loop_steps > 1:
+                states = self._looped(x, mask, positions)
+                if self.is_initializing():
+                    self._exit_gate(states[0])
+                return states
             for i in range(self.config.num_layers):
                 x = self._block(i, attn_fn=self.attn_fn)(
                     x, mask, positions=positions)
         return self._final_norm(x)
 
+    def _exit_gate(self, x):
+        """A looped model's exit gate on one pass's normed state: the
+        logit of ``lambda = sigmoid(w x + b)``, float32 (the loss applies
+        it to all the passes at once from the parameters themselves, as
+        the lean head takes ``lm_head``'s)."""
+        return nn.Dense(1, dtype=jnp.float32, name="exit_gate")(x)[..., 0]
+
     @nn.compact
     def __call__(self, input_ids):
-        return self._head(self.hidden(input_ids))
+        """Logits [B, S, vocab]; a looped model's are the LAST pass's."""
+        h = self.hidden(input_ids)
+        return self._head(h[-1] if self.config.loop_steps > 1 else h)
 
     @nn.compact
     def prefill(self, input_ids, length):
@@ -486,6 +582,7 @@ class TransformerLM(nn.Module):
         reads them before a decode step overwrites them. Submodules are
         created in exactly :meth:`hidden`'s order so the training
         parameters resolve unchanged."""
+        self._refuse_a_looped_model("prefill")
         cfg = self.config
         seq_len = input_ids.shape[-1]
         positions = jnp.arange(seq_len)
@@ -514,6 +611,7 @@ class TransformerLM(nn.Module):
         [B] bool gating cache writes for dead slots. Returns next-token
         logits [B, vocab] and the updated caches. Fixed shapes for any
         slot occupancy — the zero-recompile decode contract."""
+        self._refuse_a_looped_model("decode_step")
         cfg = self.config
         positions = jnp.clip(cursor, 0, cfg.max_seq_len - 1)[:, None]
         x = self._embed(token_ids[:, None], positions)
@@ -578,16 +676,22 @@ LEAN_HEAD_LOGIT_BYTES = 1 << 29
 
 
 def auto_remat_blocks(param_count: int, num_layers: int,
-                      hbm_bytes: Optional[float]) -> bool:
+                      hbm_bytes: Optional[float],
+                      loop_steps: int = 1) -> bool:
     """Is each block recomputed in the backward pass (``nn.remat`` around
     the block; ``strategy/remat.py`` checkpoints the whole loss, which
     does not lower the peak of a deep model)? Where the training state
     alone, at this repo's 16 B a parameter (float32 master weight, Adam's
     two moments, the gradient), takes over half the chip's memory and
-    there is more than one block to keep activations of. ``hbm_bytes``
-    None (no TPU): never."""
-    return (hbm_bytes is not None and num_layers > 1
-            and 16.0 * param_count > hbm_bytes / 2)
+    there is more than one block APPLICATION to keep activations of. The
+    line says: a state under half the chip leaves the blocks' activations
+    the other half. A looped model applies every block ``loop_steps``
+    times, so the same parameters make that many times the activations,
+    and it is held to the state plus as much again for EACH pass: ``16 B
+    x parameters x (1 + loop_steps)`` over the chip (one pass: twice the
+    state, the line above). ``hbm_bytes`` None (no TPU): never."""
+    return (hbm_bytes is not None and num_layers * loop_steps > 1
+            and 16.0 * param_count * (1 + loop_steps) > hbm_bytes)
 
 
 # of the chip's memory by the chip table, what the state at 16 B a parameter
@@ -623,11 +727,35 @@ def auto_kept_expert_layers(remat_blocks: bool, param_count: int,
     return int(min(routed_layers, max(0.0, room) // a_layer))
 
 
+def flash_kept_bytes(tokens: int, num_heads: int, qk_dim: int, v_dim: int,
+                     itemsize: int = 2) -> int:
+    """What ONE application of a block on the flash kernels keeps under
+    :data:`ops.flash_attention.KEPT`: q ``[tokens, heads, qk_dim]`` and the
+    output ``[tokens, heads, v_dim]`` of ``itemsize`` bytes, the
+    log-sum-exp ``[heads, tokens]`` in float32."""
+    return tokens * num_heads * (itemsize * (qk_dim + v_dim) + 4)
+
+
 def held_expert_kept_bytes(tokens: int, held_stack: Tuple[int, int, int],
                            itemsize: int = 2) -> int:
     """What one routed layer keeps under :data:`parallel.expert.KEPT`."""
     n_held, _, width = held_stack
     return 2 * itemsize * tokens * n_held * width
+
+
+def exit_log_distribution(gate_logits):
+    """``log p^t`` [T, N] of a looped model's exit distribution from the
+    exit gate's logits after passes 1 .. T-1, [T-1, N] float32 (the last
+    pass exits whatever its gate says): with ``lambda^t = sigmoid(g^t)``
+    and ``S^t = prod_{s <= t} (1 - lambda^s)`` the mass still in the loop
+    after pass t, ``p^t = lambda^t S^{t-1}`` for t < T and ``p^T =
+    S^{T-1}``, taken in logs (``log lambda = log_sigmoid(g)``, ``log (1 -
+    lambda) = log_sigmoid(-g)``), so a saturated gate gives a finite
+    entropy. The T masses sum to 1."""
+    log_left = jnp.cumsum(jax.nn.log_sigmoid(-gate_logits), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(log_left[:1]), log_left[:-1]])
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(gate_logits) + before, log_left[-1:]])
 
 
 def _chip_hbm_bytes() -> Optional[float]:
@@ -722,7 +850,14 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         rng, jnp.zeros((1, seq_len), jnp.int32))}
     param_count = sum(a.size for a in jax.tree_util.tree_leaves(variables))
     hbm_bytes = _chip_hbm_bytes()
-    remat_blocks = auto_remat_blocks(param_count, cfg.num_layers, hbm_bytes)
+    remat_blocks = auto_remat_blocks(param_count, cfg.num_layers, hbm_bytes,
+                                     cfg.loop_steps)
+    # what the recomputed blocks on the flash kernels keep by name, once
+    # an APPLICATION (a looped model's passes each keep their own)
+    kept_core_bytes = flash_layers * cfg.loop_steps * flash_kept_bytes(
+        batch_size * seq_len, cfg.num_heads, head_dim,
+        cfg.v_head_dim if "mla" in types else head_dim,
+        jnp.dtype(cfg.dtype).itemsize) if remat_blocks else 0
     # (leading dense layers route nothing)
     routed_layers = (max(0, cfg.num_layers - cfg.first_k_dense_replace)
                      if cfg.num_experts else 0)
@@ -775,6 +910,56 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         loss = jnp.mean(nll)
         return loss if router_loss is None else loss + router_loss
 
+    def head_weights(p):
+        """The head's kernel [d, vocab] as stored (the embedding's
+        transpose where tied) and its float32 bias (zeros without one)."""
+        kernel = (p["embed"]["embedding"].T if cfg.tie_embedding
+                  else p["lm_head"]["kernel"])
+        bias = (p["lm_head"]["bias"].astype(jnp.float32) if cfg.head_bias
+                else jnp.zeros((cfg.vocab_size,), jnp.float32))
+        return kernel, bias
+
+    def looped_loss(params, ids, targets):
+        """A looped model's loss: the head on the normed state after EVERY
+        pass against the one kernel (the lean head in ONE call on the
+        [T N, d] stack, so that each vocabulary chunk of the kernel is
+        streamed once a step and the kernel's gradient is the sum over the
+        passes), the exit gate on the same stack, and
+        ``mean_i [sum_t p_i^t nll_i^t - exit_entropy_coef H_i]`` with p
+        the exit distribution (:func:`exit_log_distribution`) and H its
+        entropy, in float32. The batch means of p and H leave the step as
+        the device counters ``loop.exit_mass_<t>`` and
+        ``loop.exit_entropy``."""
+        T = cfg.loop_steps
+        states, _ = forward(params, ids, TransformerLM.hidden)
+        stack = states.reshape(-1, cfg.d_model)             # [T N, d]
+        kernel, bias = head_weights(params["params"])
+        kernel = kernel.astype(jnp.float32)
+        picked = jnp.tile(targets.reshape(-1), T)
+        if lean_head:
+            from autodist_tpu.ops.xent import chunked_softmax_xent
+            nll = chunked_softmax_xent(stack, kernel, bias, picked)
+        else:
+            logp = jax.nn.log_softmax(
+                jnp.dot(stack.astype(jnp.float32), kernel) + bias)
+            nll = -jnp.take_along_axis(logp, picked[:, None], axis=-1)[:, 0]
+        nll = nll.reshape(T, -1)
+        with scopes.scope(scopes.EXIT_GATE):
+            gate = params["params"]["exit_gate"]
+            logits = jnp.dot(states[:-1].astype(jnp.float32),
+                             gate["kernel"][:, 0].astype(jnp.float32)) \
+                + gate["bias"].astype(jnp.float32)
+            log_p = exit_log_distribution(logits.reshape(T - 1, -1))
+            mass = jnp.exp(log_p)
+            entropy = -jnp.sum(mass * log_p, axis=0)
+            loss = jnp.mean(jnp.sum(mass * nll, axis=0)
+                            - cfg.exit_entropy_coef * entropy)
+            for t in range(T):
+                device_counters.add("loop.exit_mass_%d" % (t + 1),
+                                    jnp.mean(mass[t]))
+            device_counters.add("loop.exit_entropy", jnp.mean(entropy))
+        return loss
+
     def loss_fn(params, batch):
         # what the rule decided, once per trace, host side
         tel.gauge_set("attention.flash_layers", flash_layers)
@@ -786,18 +971,19 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
                       cfg.num_layers if remat_blocks else 0)
         tel.gauge_set("model.kept_expert_layers", kept_expert_layers)
         tel.gauge_set("model.kept_expert_bytes", kept_expert_bytes)
+        tel.gauge_set("model.loop_steps", cfg.loop_steps)
+        tel.gauge_set("model.block_applications",
+                      cfg.num_layers * cfg.loop_steps)
+        tel.gauge_set("model.kept_core_bytes", kept_core_bytes)
         tokens = batch["tokens"]
         targets = tokens[:, 1:]
+        if cfg.loop_steps > 1:
+            return looped_loss(params, tokens[:, :-1], targets)
         if lean_head:
             from autodist_tpu.ops.xent import chunked_softmax_xent
             h, router_loss = forward(params, tokens[:, :-1],
                                      TransformerLM.hidden)
-            p = params["params"]
-            kernel = (p["embed"]["embedding"].T if cfg.tie_embedding
-                      else p["lm_head"]["kernel"])
-            bias = (p["lm_head"]["bias"].astype(jnp.float32)
-                    if cfg.head_bias
-                    else jnp.zeros((cfg.vocab_size,), jnp.float32))
+            kernel, bias = head_weights(params["params"])
             nll = chunked_softmax_xent(
                 h.reshape(-1, cfg.d_model), kernel.astype(jnp.float32),
                 bias, targets.reshape(-1))
@@ -813,6 +999,9 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         declared += ["moe.aux_loss"] if cfg.seq_aux else []
     if indexed:
         declared += ["dsa." + n for n in INDEXER_CHOICE]
+    if cfg.loop_steps > 1:
+        declared += ["loop.exit_mass_%d" % (t + 1)
+                     for t in range(cfg.loop_steps)] + ["loop.exit_entropy"]
     if declared:
         loss_fn.device_counters = tuple(declared)
 

@@ -65,6 +65,8 @@ DSA_TOPK = "dsa_topk"
 DSA_CORE = "dsa_core"
 CONV_MIX = "conv_mix"
 CONV_CORE = "conv_core"
+LOOP = "loop"
+EXIT_GATE = "exit_gate"
 PREFILL = "prefill"
 DECODE = "decode"
 INSERT = "insert"
@@ -122,6 +124,12 @@ SCOPES: Dict[str, str] = {
     CONV_CORE: "inside conv_mix: what lies between the two projections, the "
                "split in thirds, the two element-wise gates and the short "
                "depthwise causal convolution",
+    LOOP: "inside blocks: the passes of a looped model (LMConfig.loop_steps), "
+          "every block and the final norm once a pass over shared weights "
+          "(one body traced, compiled as straight-line code)",
+    EXIT_GATE: "a looped model's exit gate in the loss: the gate's logits "
+               "on the passes' normed states, the exit distribution, its "
+               "entropy and the weighted sum of the passes' losses",
     PREFILL: "serving: the prompt pass of a prefill bucket",
     DECODE: "serving: one cached decode step",
     INSERT: "serving: writing admitted rows into the decode state",

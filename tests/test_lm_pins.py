@@ -49,6 +49,7 @@ def steps():
     from tests.test_keye_vl2 import tiny_config as keye
     from tests.test_kimi_linear import tiny_config as kimi
     from tests.test_lfm2_moe import tiny_config as lfm2
+    from tests.test_ouro import tiny_config as ouro
     return {
         "tiny_lm_step": (("lm1b", "tiny"), lm.LMConfig.tiny, 16, 4, "auto"),
         "tiny_olmoe_step": (("olmoe_1b_7b",), tiny_olmoe, 16, 4, "auto"),
@@ -63,7 +64,8 @@ def steps():
         "tiny_keye_vl2_step": (("keye_vl2_30b_a3b",), keye, 32, 2, "auto"),
         "tiny_keye_vl2_flash_step": (("keye_vl2_30b_a3b",), keye, 32, 2,
                                      "flash"),
-        "tiny_lfm2_moe_step": (("lfm2_24b_a2b",), lfm2, 32, 2, "auto")}
+        "tiny_lfm2_moe_step": (("lfm2_24b_a2b",), lfm2, 32, 2, "auto"),
+        "tiny_ouro_step": (("ouro_2_6b",), ouro, 32, 2, "auto")}
 
 
 def tree_digest(params):

@@ -739,7 +739,9 @@ TRACE_TIME_GAUGES = ("lean_head.chunks", "lean_head.chunk_width",
                      "lean_head.dead_cols", "attention.flash_layers",
                      "attention.kda_kernel_layers",
                      "attention.kda_fused_mixer_layers", "model.remat_blocks",
-                     "model.kept_expert_layers", "model.kept_expert_bytes")
+                     "model.kept_expert_layers", "model.kept_expert_bytes",
+                     "model.loop_steps", "model.block_applications",
+                     "model.kept_core_bytes")
 
 
 @pytest.fixture(scope="module")
