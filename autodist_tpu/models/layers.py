@@ -20,6 +20,10 @@ Dtype = Any
 # the delta rule's output, by name; ``ops/kda.py:KEPT`` is the same name,
 # on the per-chunk states its backward kernel reads
 KDA_CORE_OUT = "kda_core_out"
+# a block's dense feed-forward's gate and up products, by name: what a block
+# recomputed in the backward pass keeps of it where the model's rule finds
+# the room (``models/lm.py:TransformerLM._block``, ``auto_kept_layers``)
+DENSE_FFN_KEPT = "dense_ffn_kept"
 
 
 def causal_mask(seq_len: int) -> jnp.ndarray:
@@ -379,14 +383,26 @@ def rms_normalize(x, eps):
 
 
 class SwiGLU(nn.Module):
-    """down(silu(gate(x)) * up(x)), no bias."""
+    """down(silu(gate(x)) * up(x)), no bias. With ``kept`` the gate's and
+    the up projection's products carry that name (``checkpoint_name``: the
+    identity but under a policy that saves it), which SiLU's and the
+    product's backward read: of the 11 matmuls a step makes of a recomputed
+    SwiGLU (3 forward, 6 backward, the two the backward reads made again) a
+    block that keeps the name leaves 9, for 4 T f bytes. ``silu(g) * u``
+    carries none: the forward would have to write it, which costs more
+    than remaking it saves (PERF.md section 6, PR 41). The shared experts'
+    SwiGLU carries no name: the rule that books the room counts one
+    consumer."""
     width: int
     dtype: Dtype = jnp.float32
+    kept: Optional[str] = None
 
     @nn.compact
     def __call__(self, x):
-        h = nn.silu(linear(self.width, self.dtype, "gate_proj")(x)) \
-            * linear(self.width, self.dtype, "up_proj")(x)
+        def product(name):
+            out = linear(self.width, self.dtype, name)(x)
+            return checkpoint_name(out, self.kept) if self.kept else out
+        h = nn.silu(product("gate_proj")) * product("up_proj")
         return linear(x.shape[-1], self.dtype, "down_proj")(h)
 
 
@@ -724,7 +740,8 @@ class TransformerBlock(nn.Module):
         x = x + h
         h = make_norm(self.norm, self.norm_eps, self.dtype)(x)
         if self.dense_dim:
-            h = SwiGLU(self.dense_dim, self.dtype, name="mlp")(h)
+            h = SwiGLU(self.dense_dim, self.dtype, DENSE_FFN_KEPT,
+                       name="mlp")(h)
         elif self.num_experts:
             h = MoEFeedForward(self.num_experts, self.experts_per_token,
                                self.mlp_dim, self.dtype, self.router,

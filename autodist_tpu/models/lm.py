@@ -20,8 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from autodist_tpu.models.layers import (KDA_CORE_OUT, IndexerConfig,
-                                        KDAConfig, MLAConfig, RouterConfig,
+from autodist_tpu.models.layers import (DENSE_FFN_KEPT, KDA_CORE_OUT,
+                                        IndexerConfig, KDAConfig, MLAConfig,
+                                        RouterConfig,
                                         SparseEmbed, TransformerBlock,
                                         YarnConfig, causal_mask, make_norm)
 from autodist_tpu.telemetry import spans as tel
@@ -394,8 +395,10 @@ class TransformerLM(nn.Module):
     # recompute each block in the backward pass (make_train_setup's rule)
     remat_blocks: bool = False
     # ... of whose routed layers the LAST this many keep their held
-    # experts' hidden products (``auto_kept_expert_layers``'s rule)
+    # experts' hidden products, and of whose dense layers the LAST this
+    # many their feed-forward's (``auto_kept_layers``'s rule for both)
     kept_expert_layers: int = 0
+    kept_dense_layers: int = 0
 
     def _embed(self, input_ids, positions):
         """Token embedding (scaled by sqrt(d) where the config says so)
@@ -462,13 +465,20 @@ class TransformerLM(nn.Module):
         # other way round, 4 T E f bytes a layer, 369 MB at 8 experts of
         # 1,408 on 8,192 tokens, against two of the layer's eleven expert
         # matmuls: the last ``kept_expert_layers`` routed layers keep
-        # them, whose products live shortest)
+        # them, whose products live shortest. A dense feed-forward's are
+        # the same trade, 4 T f bytes a layer APPLICATION, 92 MB at 5,632
+        # wide on 4,096 tokens, against two of its eleven matmuls: the
+        # last ``kept_dense_layers`` dense layers keep them, a looped
+        # model's in every pass)
         from autodist_tpu.ops.dsa import KEPT as DSA_CHOICE_KEPT
         from autodist_tpu.ops.flash_attention import KEPT as FLASH_CORE_KEPT
         from autodist_tpu.parallel.expert import KEPT as HELD_EXPERTS_KEPT
         kept = (KDA_CORE_OUT, FLASH_CORE_KEPT, DSA_CHOICE_KEPT)
         if i >= cfg.num_layers - self.kept_expert_layers:
             kept += (HELD_EXPERTS_KEPT,)
+        dense = num_dense_layers(cfg)
+        if dense - self.kept_dense_layers <= i < dense:
+            kept += (DENSE_FFN_KEPT,)
         block = nn.remat(
             TransformerBlock,
             policy=jax.checkpoint_policies.save_only_these_names(*kept)
@@ -701,30 +711,64 @@ def auto_remat_blocks(param_count: int, num_layers: int,
 # 0.751 of 16e9 by this count, the fullest of the four cells that hold a
 # share (Kimi-Linear's 0.669, Keye-VL-2.0's 0.688, LFM2's 0.659), and 15.36
 # GB on the chip with its other 3.15 GB of scratch. Nothing has been seen to
-# fail, so the line says what has been shown, not what is possible.
+# fail, so the line says what has been shown, not what is possible. Since PR
+# 45 the dense feed-forwards' products are booked under the same line, after
+# the experts' (``auto_kept_layers``): Ouro's cell is 0.699 of 16e9 with its
+# 24 applications and the cores, LFM2's 0.711, Kimi-Linear's 0.699, and the
+# fullest step device-less is still DeepSeek-V2-Lite's 15.35 GB (Ouro's
+# 15.20, Kimi-Linear's 14.98, LFM2's 13.20: at rest + scratch,
+# benchmark/records/pr45_aot_memory.json), where nothing dense is kept.
 KEPT_EXPERTS_HBM_LEFT = 0.24
 
 
-def auto_kept_expert_layers(remat_blocks: bool, param_count: int,
-                            routed_layers: int, hbm_bytes: Optional[float],
-                            tokens: int,
-                            held_stack: Optional[Tuple[int, int, int]],
-                            itemsize: int = 2) -> int:
-    """Of a recomputed model's routed layers, how many keep their held
-    experts' gate and up products across the recomputation
-    (``parallel/expert.py:KEPT``; the LAST so many, ``TransformerLM._block``)
-    and so run 9 expert matmuls a step where the others run 11? As many as
-    fit: a layer keeps two ``[tokens, E, f]`` arrays of ``itemsize`` bytes,
-    ``held_stack`` being the held gate stack's ``[E, d, f]``, and the state
-    at 16 B a parameter plus what is kept leaves ``KEPT_EXPERTS_HBM_LEFT``
-    of the chip's memory free. None where blocks are not recomputed
-    (nothing is made twice), where no share is held (``held_stack`` None:
-    the sorted form's grouped matmuls carry no name) and off a TPU."""
-    if not remat_blocks or held_stack is None or hbm_bytes is None:
-        return 0
-    a_layer = held_expert_kept_bytes(tokens, held_stack, itemsize)
-    room = (1.0 - KEPT_EXPERTS_HBM_LEFT) * hbm_bytes - 16.0 * param_count
-    return int(min(routed_layers, max(0.0, room) // a_layer))
+def num_dense_layers(cfg: LMConfig) -> int:
+    """The leading layers whose feed-forward is the dense SwiGLU."""
+    return min(cfg.first_k_dense_replace, cfg.num_layers) if cfg.dense_dim \
+        else 0
+
+
+def auto_kept_layers(remat_blocks: bool, param_count: int,
+                     hbm_bytes: Optional[float], tokens: int,
+                     itemsize: int = 2, *, routed_layers: int = 0,
+                     held_stack: Optional[Tuple[int, int, int]] = None,
+                     dense_layers: int = 0, dense_width: int = 0,
+                     loop_steps: int = 1,
+                     core_bytes: int = 0) -> Tuple[int, int]:
+    """Of a recomputed model's layers, how many keep their feed-forward's
+    gate and up products across the recomputation, and so run 9 of its
+    matmuls a step where the others run 11: ``(routed layers that keep
+    their held experts', dense layers that keep their SwiGLU's)``, the LAST
+    so many of each (``TransformerLM._block``). ONE booking of ONE room,
+    what the state at 16 B a parameter leaves under
+    ``KEPT_EXPERTS_HBM_LEFT`` of the chip's memory free, in this order:
+
+    - the held experts' (``parallel/expert.py:KEPT``): a layer keeps two
+      ``[tokens, E, f]`` arrays of ``itemsize`` bytes, ``held_stack`` being
+      the held gate stack's ``[E, d, f]``; as many layers as fit. None
+      where no share is held (``held_stack`` None: the sorted form's
+      grouped matmuls carry no name);
+    - from what they leave, less ``core_bytes`` (what the flash cores keep
+      by name in any case, :func:`flash_kept_bytes` an application), the
+      dense feed-forwards' (``models/layers.py:DENSE_FFN_KEPT``): a layer
+      keeps two ``[tokens, dense_width]`` arrays an APPLICATION, a looped
+      model's ``loop_steps`` times that; as many whole layers as fit.
+
+    (0, 0) where blocks are not recomputed (nothing is made twice) and
+    off a TPU."""
+    if not remat_blocks or hbm_bytes is None:
+        return 0, 0
+    room = max(0.0, (1.0 - KEPT_EXPERTS_HBM_LEFT) * hbm_bytes
+               - 16.0 * param_count)
+    experts = dense = 0
+    if held_stack is not None:
+        a_layer = held_expert_kept_bytes(tokens, held_stack, itemsize)
+        experts = int(min(routed_layers, room // a_layer))
+        room -= experts * a_layer
+    if dense_layers and dense_width:
+        a_layer = loop_steps * dense_kept_bytes(tokens, dense_width, itemsize)
+        dense = int(min(dense_layers,
+                        max(0.0, room - core_bytes) // a_layer))
+    return experts, dense
 
 
 def flash_kept_bytes(tokens: int, num_heads: int, qk_dim: int, v_dim: int,
@@ -741,6 +785,12 @@ def held_expert_kept_bytes(tokens: int, held_stack: Tuple[int, int, int],
     """What one routed layer keeps under :data:`parallel.expert.KEPT`."""
     n_held, _, width = held_stack
     return 2 * itemsize * tokens * n_held * width
+
+
+def dense_kept_bytes(tokens: int, width: int, itemsize: int = 2) -> int:
+    """What ONE application of a dense layer keeps under
+    :data:`models.layers.DENSE_FFN_KEPT`."""
+    return 2 * itemsize * tokens * width
 
 
 def exit_log_distribution(gate_logits):
@@ -865,13 +915,20 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     held_stack = (None if cfg.experts_held is None else
                   (len(cfg.experts_held), cfg.d_model, cfg.mlp_dim))
     # (a replica sees no more tokens a step than the whole batch)
-    kept = (batch_size * seq_len, held_stack, jnp.dtype(cfg.dtype).itemsize)
-    kept_expert_layers = auto_kept_expert_layers(
-        remat_blocks, param_count, routed_layers, hbm_bytes, *kept)
+    tokens, itemsize = batch_size * seq_len, jnp.dtype(cfg.dtype).itemsize
+    kept_expert_layers, kept_dense_layers = auto_kept_layers(
+        remat_blocks, param_count, hbm_bytes, tokens, itemsize,
+        routed_layers=routed_layers, held_stack=held_stack,
+        dense_layers=num_dense_layers(cfg), dense_width=cfg.dense_dim,
+        loop_steps=cfg.loop_steps, core_bytes=kept_core_bytes)
     kept_expert_bytes = kept_expert_layers and (
-        kept_expert_layers * held_expert_kept_bytes(*kept))
+        kept_expert_layers
+        * held_expert_kept_bytes(tokens, held_stack, itemsize))
+    kept_dense_bytes = kept_dense_layers * cfg.loop_steps * dense_kept_bytes(
+        tokens, cfg.dense_dim, itemsize)
     model = TransformerLM(cfg, attn_fn=attn_fn, remat_blocks=remat_blocks,
-                          kept_expert_layers=kept_expert_layers)
+                          kept_expert_layers=kept_expert_layers,
+                          kept_dense_layers=kept_dense_layers)
     router_load = SHARE_LOAD if cfg.experts_held is not None else ROUTER_LOAD
     router_losses = cfg.router_activation == "softmax"
     indexed = bool(cfg.indexer_num_heads)
@@ -971,6 +1028,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
                       cfg.num_layers if remat_blocks else 0)
         tel.gauge_set("model.kept_expert_layers", kept_expert_layers)
         tel.gauge_set("model.kept_expert_bytes", kept_expert_bytes)
+        tel.gauge_set("model.kept_dense_layers", kept_dense_layers)
+        tel.gauge_set("model.kept_dense_bytes", kept_dense_bytes)
         tel.gauge_set("model.loop_steps", cfg.loop_steps)
         tel.gauge_set("model.block_applications",
                       cfg.num_layers * cfg.loop_steps)

@@ -2,7 +2,8 @@
 block (``parallel/expert.py:KEPT``): what a saving policy takes out of the
 differentiated layer, that the values kept are the forward's to the last
 bit, and the rule that says how many routed layers keep them
-(``models/lm.py:auto_kept_expert_layers``)."""
+(``models/lm.py:auto_kept_layers``, whose second count is
+``tests/test_dense_products_kept.py``'s)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,11 +32,11 @@ def dots(jaxpr, rematted=False):
     return count(jaxpr, lambda e: e.primitive.name == "dot_general", rematted)
 
 
-def names_kept(jaxpr):
-    """``name`` equations carrying ``expert.KEPT``: (in all, inside a
-    checkpoint, whose policy is there to save them)."""
+def names_kept(jaxpr, name=expert.KEPT):
+    """``name`` equations carrying ``name``: (in all, inside a checkpoint,
+    whose policy is there to save them)."""
     match = lambda e: (e.primitive.name == "name"  # noqa: E731
-                       and e.params["name"] == expert.KEPT)
+                       and e.params["name"] == name)
     return count(jaxpr, match), count(jaxpr, match, rematted=True)
 
 
@@ -124,7 +125,7 @@ LFM2 = cell(558424448, 4, 8, 1536)
     ("no TPU", dict(DEEPSEEK, hbm_bytes=None), 0)])
 def test_as_many_routed_layers_keep_their_products_as_fit(
         what, inputs, layers):
-    assert lm.auto_kept_expert_layers(**inputs) == layers
+    assert lm.auto_kept_layers(**inputs) == (layers, 0)
     if layers:
         a_layer = lm.held_expert_kept_bytes(
             inputs["tokens"], inputs["held_stack"], inputs.get("itemsize", 2))

@@ -740,6 +740,7 @@ TRACE_TIME_GAUGES = ("lean_head.chunks", "lean_head.chunk_width",
                      "attention.kda_kernel_layers",
                      "attention.kda_fused_mixer_layers", "model.remat_blocks",
                      "model.kept_expert_layers", "model.kept_expert_bytes",
+                     "model.kept_dense_layers", "model.kept_dense_bytes",
                      "model.loop_steps", "model.block_applications",
                      "model.kept_core_bytes")
 
