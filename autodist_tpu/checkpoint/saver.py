@@ -410,8 +410,8 @@ class Saver:
         params = self.restore_params(dstep.model_item.params, path)
         if dstep.model_item.optimizer is not None:
             opt_flat = _read_npz(path + ".opt.npz")
-            opt_template = dstep.model_item.optimizer.init(
-                dstep.model_item.params)
+            opt_template = jax.eval_shape(dstep.model_item.optimizer.init,
+                                          dstep.model_item.params)
             opt_state = _flat_to_tree(opt_template, opt_flat)
         else:
             # step_fn mode: whatever optimizer state exists lives inside

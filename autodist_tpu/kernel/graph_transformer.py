@@ -29,7 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from autodist_tpu import const
 from autodist_tpu.kernel.partitioner import VariablePartitioner, VarLayout
-from autodist_tpu.model_item import _normalize_path
+from autodist_tpu.model_item import _normalize_path, shapes_of
 from autodist_tpu.kernel.common import variable_utils
 from autodist_tpu.kernel.synchronization.synchronizer import Synchronizer
 from autodist_tpu.parallel import collectives
@@ -673,6 +673,13 @@ class DistributedStep:
         step0 = self._put(np.zeros((), np.int32), P())
         return TrainState(step=step0, params=params_placed,
                           opt_state=opt_placed, sync_state=sync_placed)
+
+    def release_initial_params(self):
+        """Keep the shapes of the initial parameters the build captured and
+        let their arrays go (``ModelItem.params`` and the holed template,
+        which is the same tree where nothing is host-resident)."""
+        self.model_item.release_params()
+        self._holed_template = shapes_of(self._holed_template)
 
     def gather_params(self, state: TrainState):
         """Params back in the original (full, unpadded) layout, on host —
@@ -2005,10 +2012,7 @@ class GraphTransformer:
                     for l in jax.tree_util.tree_leaves(local_batch)
                     if np.ndim(l) >= 1]
             local_rows = lead[0] if lead else 0
-            param_avals = jax.tree_util.tree_map(
-                lambda l: jax.ShapeDtypeStruct(
-                    np.shape(l), l.dtype if hasattr(l, "dtype")
-                    else np.asarray(l).dtype), item.params)
+            param_avals = shapes_of(item.params)
             with bound_axes():
                 out_aval = jax.eval_shape(serve_fn, param_avals,
                                           local_batch)
@@ -2091,10 +2095,7 @@ class GraphTransformer:
                  for l in state_leaves])
             local_slots = ([np.shape(l)[0] // n_batch for l in state_leaves
                             if np.ndim(l) >= 1] or [0])[0]
-            param_avals = jax.tree_util.tree_map(
-                lambda l: jax.ShapeDtypeStruct(
-                    np.shape(l), l.dtype if hasattr(l, "dtype")
-                    else np.asarray(l).dtype), item.params)
+            param_avals = shapes_of(item.params)
             with bound_axes():
                 out_aval = jax.eval_shape(decode_fn, param_avals,
                                           local_dstate)

@@ -52,6 +52,15 @@ def names_of(tree) -> List[str]:
     return [n for n, _ in flatten_with_names(tree)]
 
 
+def shapes_of(tree):
+    """``tree`` with a ``jax.ShapeDtypeStruct`` for every leaf: what a
+    trace, ``eval_shape`` or a restore's template reads of it."""
+    return jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(
+            np.shape(leaf), leaf.dtype if hasattr(leaf, "dtype")
+            else np.asarray(leaf).dtype), tree)
+
+
 @dataclasses.dataclass
 class VarInfo:
     """Metadata for one trainable variable."""
@@ -193,6 +202,13 @@ class ModelItem:
       by ``GraphTransformer._transform_step_fn`` (jit in/out_shardings from
       the layouts; AllReduce/Partitioned families; entry:
       ``AutoDist.build_step``).
+
+    ``params`` is the caller's initial parameter tree until ``Runner.init``
+    has a placed state, and from then on a tree of ``jax.ShapeDtypeStruct``
+    of the same structure, shapes and dtypes (:meth:`release_params`): the
+    state owns copies, and all that is asked of ``params`` afterwards is a
+    trace or a shape, so keeping the arrays would hold 4 B a parameter of
+    device memory for the whole run.
     """
 
     def __init__(self,
@@ -301,6 +317,12 @@ class ModelItem:
 
     def total_bytes(self) -> int:
         return sum(v.byte_size for v in self.var_infos.values())
+
+    def release_params(self):
+        """Let go of the initial parameters' arrays and keep their shapes
+        (``Runner.init``, once the state is placed). The caller's own
+        reference stays valid."""
+        self.params = shapes_of(self.params)
 
     # ------------------------------------------------------------ serialization
 

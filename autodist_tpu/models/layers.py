@@ -24,6 +24,11 @@ KDA_CORE_OUT = "kda_core_out"
 # recomputed in the backward pass keeps of it where the model's rule finds
 # the room (``models/lm.py:TransformerLM._block``, ``auto_kept_layers``)
 DENSE_FFN_KEPT = "dense_ffn_kept"
+# a sandwich-normed sub-layer's output (the attention's ``out`` product, the
+# feed-forward's ``down_proj``) as its output norm reads it, by name: the
+# norm's backward needs its INPUT, so a recomputed block that does not keep
+# it runs both products a second time
+SUBLAYER_OUT_KEPT = "sublayer_out_kept"
 
 
 def causal_mask(seq_len: int) -> jnp.ndarray:
@@ -391,8 +396,9 @@ class SwiGLU(nn.Module):
     block that keeps the name leaves 9, for 4 T f bytes. ``silu(g) * u``
     carries none: the forward would have to write it, which costs more
     than remaking it saves (PERF.md section 6, PR 41). The shared experts'
-    SwiGLU carries no name: the rule that books the room counts one
-    consumer."""
+    SwiGLU carries the dense name (``MoEFeedForward``): a block is dense or
+    routed, never both, so the name says which products and the block's
+    policy whose (``models/lm.py:auto_kept_layers`` books each in turn)."""
     width: int
     dtype: Dtype = jnp.float32
     kept: Optional[str] = None
@@ -462,7 +468,7 @@ class MoEFeedForward(nn.Module):
         if cfg.shared_experts:
             with scopes.scope(scopes.MOE), scopes.scope(scopes.MOE_SHARED):
                 out = out + SwiGLU(cfg.shared_experts * f, self.dtype,
-                                   name="shared")(x)
+                                   DENSE_FFN_KEPT, name="shared")(x)
         return out
 
 
@@ -669,7 +675,8 @@ class TransformerBlock(nn.Module):
     attention above) and gives a leading dense layer its SwiGLU width
     (``dense_dim``). ``sandwich_norm`` norms each sub-layer's OUTPUT too,
     before it joins the residual (``x + N(f(N(x)))``: four norms a block,
-    the looped Ouro models' block)."""
+    the looped Ouro models' block), and names what the two output norms
+    read (:data:`SUBLAYER_OUT_KEPT`)."""
     num_heads: int
     head_dim: int
     mlp_dim: int
@@ -734,7 +741,8 @@ class TransformerBlock(nn.Module):
             h, kv = h
         if self.sandwich_norm:
             h = make_norm(self.norm, self.norm_eps, self.dtype,
-                          "attn_out_norm")(h)
+                          "attn_out_norm")(
+                checkpoint_name(h, SUBLAYER_OUT_KEPT))
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
         x = x + h
@@ -752,7 +760,8 @@ class TransformerBlock(nn.Module):
             h = nn.Dense(x.shape[-1], dtype=self.dtype)(h)
         if self.sandwich_norm:
             h = make_norm(self.norm, self.norm_eps, self.dtype,
-                          "mlp_out_norm")(h)
+                          "mlp_out_norm")(
+                checkpoint_name(h, SUBLAYER_OUT_KEPT))
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
         x = x + h

@@ -13,7 +13,7 @@ the logits and is auto-kept dense). The big embedding table is the
 PartitionedPS stress case, as in the reference benchmark.
 """
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -21,8 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from autodist_tpu.models.layers import (DENSE_FFN_KEPT, KDA_CORE_OUT,
-                                        IndexerConfig, KDAConfig, MLAConfig,
-                                        RouterConfig,
+                                        SUBLAYER_OUT_KEPT, IndexerConfig,
+                                        KDAConfig, MLAConfig, RouterConfig,
                                         SparseEmbed, TransformerBlock,
                                         YarnConfig, causal_mask, make_norm)
 from autodist_tpu.telemetry import spans as tel
@@ -395,10 +395,15 @@ class TransformerLM(nn.Module):
     # recompute each block in the backward pass (make_train_setup's rule)
     remat_blocks: bool = False
     # ... of whose routed layers the LAST this many keep their held
-    # experts' hidden products, and of whose dense layers the LAST this
-    # many their feed-forward's (``auto_kept_layers``'s rule for both)
+    # experts' hidden products, of whose dense layers the LAST this many
+    # their feed-forward's, of whose sandwich-normed layers the LAST this
+    # many what their two output norms read, and of whose routed layers the
+    # LAST this many their shared experts' hidden products
+    # (``auto_kept_layers``'s rule for all four)
     kept_expert_layers: int = 0
     kept_dense_layers: int = 0
+    kept_sublayer_out_layers: int = 0
+    kept_shared_layers: int = 0
 
     def _embed(self, input_ids, positions):
         """Token embedding (scaled by sqrt(d) where the config says so)
@@ -469,7 +474,15 @@ class TransformerLM(nn.Module):
         # the same trade, 4 T f bytes a layer APPLICATION, 92 MB at 5,632
         # wide on 4,096 tokens, against two of its eleven matmuls: the
         # last ``kept_dense_layers`` dense layers keep them, a looped
-        # model's in every pass)
+        # model's in every pass; a routed layer's SHARED experts are one
+        # SwiGLU under the dense name, 92 MB at two of 1,408 on 8,192
+        # tokens, kept in the last ``kept_shared_layers`` routed layers.
+        # What a sandwich-normed block's two output norms READ, 2 T d bytes
+        # an application each, 16.8 MB at 4,096 x 2,048, against the
+        # attention's output product and the feed-forward's down
+        # projection, which the norms' backward would otherwise have the
+        # recomputed forward make again: the last
+        # ``kept_sublayer_out_layers`` layers keep both)
         from autodist_tpu.ops.dsa import KEPT as DSA_CHOICE_KEPT
         from autodist_tpu.ops.flash_attention import KEPT as FLASH_CORE_KEPT
         from autodist_tpu.parallel.expert import KEPT as HELD_EXPERTS_KEPT
@@ -477,8 +490,11 @@ class TransformerLM(nn.Module):
         if i >= cfg.num_layers - self.kept_expert_layers:
             kept += (HELD_EXPERTS_KEPT,)
         dense = num_dense_layers(cfg)
-        if dense - self.kept_dense_layers <= i < dense:
+        if (dense - self.kept_dense_layers <= i < dense
+                or i >= max(dense, cfg.num_layers - self.kept_shared_layers)):
             kept += (DENSE_FFN_KEPT,)
+        if i >= cfg.num_layers - self.kept_sublayer_out_layers:
+            kept += (SUBLAYER_OUT_KEPT,)
         block = nn.remat(
             TransformerBlock,
             policy=jax.checkpoint_policies.save_only_these_names(*kept)
@@ -704,20 +720,26 @@ def auto_remat_blocks(param_count: int, num_layers: int,
             and 16.0 * param_count * (1 + loop_steps) > hbm_bytes)
 
 
-# of the chip's memory by the chip table, what the state at 16 B a parameter
-# and the kept products together leave to the step's other scratch. Drawn
-# where the v5e has LOADED a step, cold and from the compile cache (PERF.md
-# section 6, PR 41): DeepSeek-V2-Lite's cell with all five layers kept is
-# 0.751 of 16e9 by this count, the fullest of the four cells that hold a
-# share (Kimi-Linear's 0.669, Keye-VL-2.0's 0.688, LFM2's 0.659), and 15.36
-# GB on the chip with its other 3.15 GB of scratch. Nothing has been seen to
-# fail, so the line says what has been shown, not what is possible. Since PR
-# 45 the dense feed-forwards' products are booked under the same line, after
-# the experts' (``auto_kept_layers``): Ouro's cell is 0.699 of 16e9 with its
-# 24 applications and the cores, LFM2's 0.711, Kimi-Linear's 0.699, and the
-# fullest step device-less is still DeepSeek-V2-Lite's 15.35 GB (Ouro's
-# 15.20, Kimi-Linear's 14.98, LFM2's 13.20: at rest + scratch,
-# benchmark/records/pr45_aot_memory.json), where nothing dense is kept.
+# of the chip's memory by the chip table, what the training state at 12 B a
+# parameter (float32 master weight, Adam's two moments: all that is at rest
+# since set-up lets go of the caller's initial parameters,
+# ``model_item.py:ModelItem.release_params``) and the kept values together
+# leave to the step's other scratch. Drawn where the v5e has LOADED a step,
+# cold and from the compile cache (PERF.md section 6, PR 41), WITH that dead
+# copy of the parameters still on the chip and so booked at 16 B a parameter:
+# DeepSeek-V2-Lite's cell with all five layers' held products kept was 0.751
+# of 16e9 by that count and 15.35 GB on the chip, at rest + scratch, the
+# fullest of the five cells that recompute (Ouro's 15.20, Kimi-Linear's
+# 14.98, Keye-VL-2.0's 14.32, LFM2's 13.20:
+# benchmark/records/pr45_aot_memory.json). Nothing has been seen to fail, so
+# the line says what has been shown, not what is possible, and it stays where
+# it was drawn: the room grows by exactly the 4 B a parameter that left the
+# chip, so the rule books at most that much more than went, and no cell
+# stands above what it stood at with the copy (the fullest is now
+# DeepSeek-V2-Lite's 13.63 GB device-less, Ouro's 13.34:
+# benchmark/records/pr46_aot_memory.json). ``auto_remat_blocks``' line keeps
+# its 16 B: at 12 DeepSeek-V2-Lite's cell would stop recomputing, which does
+# not fit.
 KEPT_EXPERTS_HBM_LEFT = 0.24
 
 
@@ -727,48 +749,81 @@ def num_dense_layers(cfg: LMConfig) -> int:
         else 0
 
 
+class KeptLayers(NamedTuple):
+    """:func:`auto_kept_layers`' counts, the LAST so many layers of each
+    kind, in the order they are booked."""
+    experts: int = 0        # routed layers, their held experts' products
+    dense: int = 0          # dense layers, their SwiGLU's products
+    sublayer_outs: int = 0  # sandwich-normed layers, the output norms' inputs
+    shared: int = 0         # routed layers, their shared experts' products
+
+
 def auto_kept_layers(remat_blocks: bool, param_count: int,
                      hbm_bytes: Optional[float], tokens: int,
                      itemsize: int = 2, *, routed_layers: int = 0,
                      held_stack: Optional[Tuple[int, int, int]] = None,
                      dense_layers: int = 0, dense_width: int = 0,
-                     loop_steps: int = 1,
-                     core_bytes: int = 0) -> Tuple[int, int]:
-    """Of a recomputed model's layers, how many keep their feed-forward's
-    gate and up products across the recomputation, and so run 9 of its
-    matmuls a step where the others run 11: ``(routed layers that keep
-    their held experts', dense layers that keep their SwiGLU's)``, the LAST
-    so many of each (``TransformerLM._block``). ONE booking of ONE room,
-    what the state at 16 B a parameter leaves under
-    ``KEPT_EXPERTS_HBM_LEFT`` of the chip's memory free, in this order:
+                     sandwich_layers: int = 0, d_model: int = 0,
+                     shared_width: int = 0, loop_steps: int = 1,
+                     core_bytes: int = 0) -> KeptLayers:
+    """Of a recomputed model's layers, how many keep by name what the
+    recomputed forward would otherwise make a second time only for the
+    backward to read (``TransformerLM._block`` saves the names in the LAST
+    so many layers of each kind). ONE booking of ONE room, what the state at
+    12 B a parameter leaves under ``KEPT_EXPERTS_HBM_LEFT`` of the chip's
+    memory free; whole layers, ``loop_steps`` applications each, as many as
+    fit, in this order:
 
-    - the held experts' (``parallel/expert.py:KEPT``): a layer keeps two
-      ``[tokens, E, f]`` arrays of ``itemsize`` bytes, ``held_stack`` being
-      the held gate stack's ``[E, d, f]``; as many layers as fit. None
-      where no share is held (``held_stack`` None: the sorted form's
-      grouped matmuls carry no name);
+    - the held experts' gate and up products (``parallel/expert.py:KEPT``):
+      two ``[tokens, E, f]`` arrays of ``itemsize`` bytes a routed layer,
+      ``held_stack`` being the held gate stack's ``[E, d, f]``. None where
+      no share is held (``held_stack`` None: the sorted form's grouped
+      matmuls carry no name);
     - from what they leave, less ``core_bytes`` (what the flash cores keep
       by name in any case, :func:`flash_kept_bytes` an application), the
-      dense feed-forwards' (``models/layers.py:DENSE_FFN_KEPT``): a layer
-      keeps two ``[tokens, dense_width]`` arrays an APPLICATION, a looped
-      model's ``loop_steps`` times that; as many whole layers as fit.
+      dense feed-forwards' (``models/layers.py:DENSE_FFN_KEPT``): two
+      ``[tokens, dense_width]`` arrays an application of a dense layer;
+    - what a sandwich-normed block's two output norms read
+      (``models/layers.py:SUBLAYER_OUT_KEPT``): two ``[tokens, d_model]``
+      arrays an application, for the attention's output product and the
+      feed-forward's down projection;
+    - the shared experts' SwiGLU (the dense name in a routed block): two
+      ``[tokens, shared_width]`` arrays a routed layer.
 
-    (0, 0) where blocks are not recomputed (nothing is made twice) and
-    off a TPU."""
+    All 0 where blocks are not recomputed (nothing is made twice) and off a
+    TPU."""
     if not remat_blocks or hbm_bytes is None:
-        return 0, 0
+        return KeptLayers()
     room = max(0.0, (1.0 - KEPT_EXPERTS_HBM_LEFT) * hbm_bytes
-               - 16.0 * param_count)
-    experts = dense = 0
-    if held_stack is not None:
-        a_layer = held_expert_kept_bytes(tokens, held_stack, itemsize)
-        experts = int(min(routed_layers, room // a_layer))
-        room -= experts * a_layer
-    if dense_layers and dense_width:
-        a_layer = loop_steps * dense_kept_bytes(tokens, dense_width, itemsize)
-        dense = int(min(dense_layers,
-                        max(0.0, room - core_bytes) // a_layer))
-    return experts, dense
+               - 12.0 * param_count)
+    a_layer = kept_layer_bytes(tokens, itemsize, held_stack, dense_width,
+                               d_model, shared_width, loop_steps)
+
+    def book(layers, nbytes):
+        nonlocal room
+        kept = int(min(layers, room // nbytes)) if layers and nbytes else 0
+        room -= kept * nbytes
+        return kept
+
+    experts = book(routed_layers, a_layer.experts)
+    room = max(0.0, room - core_bytes)
+    return KeptLayers(experts, book(dense_layers, a_layer.dense),
+                      book(sandwich_layers, a_layer.sublayer_outs),
+                      book(routed_layers, a_layer.shared))
+
+
+def kept_layer_bytes(tokens: int, itemsize: int,
+                     held_stack: Optional[Tuple[int, int, int]],
+                     dense_width: int, d_model: int, shared_width: int,
+                     loop_steps: int = 1) -> KeptLayers:
+    """What ONE layer of each kind keeps by name over all its
+    ``loop_steps`` applications (0 for a kind the model has none of)."""
+    return KeptLayers(
+        held_expert_kept_bytes(tokens, held_stack, itemsize)
+        if held_stack is not None else 0,
+        loop_steps * dense_kept_bytes(tokens, dense_width, itemsize),
+        loop_steps * sublayer_out_kept_bytes(tokens, d_model, itemsize),
+        loop_steps * dense_kept_bytes(tokens, shared_width, itemsize))
 
 
 def flash_kept_bytes(tokens: int, num_heads: int, qk_dim: int, v_dim: int,
@@ -788,9 +843,17 @@ def held_expert_kept_bytes(tokens: int, held_stack: Tuple[int, int, int],
 
 
 def dense_kept_bytes(tokens: int, width: int, itemsize: int = 2) -> int:
-    """What ONE application of a dense layer keeps under
+    """What ONE application of a SwiGLU of this width (a dense layer's, a
+    routed layer's shared experts') keeps under
     :data:`models.layers.DENSE_FFN_KEPT`."""
     return 2 * itemsize * tokens * width
+
+
+def sublayer_out_kept_bytes(tokens: int, d_model: int,
+                            itemsize: int = 2) -> int:
+    """What ONE application of a sandwich-normed block keeps under
+    :data:`models.layers.SUBLAYER_OUT_KEPT`: both output norms' inputs."""
+    return 2 * itemsize * tokens * d_model
 
 
 def exit_log_distribution(gate_logits):
@@ -916,19 +979,23 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
                   (len(cfg.experts_held), cfg.d_model, cfg.mlp_dim))
     # (a replica sees no more tokens a step than the whole batch)
     tokens, itemsize = batch_size * seq_len, jnp.dtype(cfg.dtype).itemsize
-    kept_expert_layers, kept_dense_layers = auto_kept_layers(
+    shared_width = cfg.num_shared_experts * cfg.mlp_dim if routed else 0
+    kept = auto_kept_layers(
         remat_blocks, param_count, hbm_bytes, tokens, itemsize,
         routed_layers=routed_layers, held_stack=held_stack,
         dense_layers=num_dense_layers(cfg), dense_width=cfg.dense_dim,
+        sandwich_layers=cfg.num_layers if cfg.sandwich_norm else 0,
+        d_model=cfg.d_model, shared_width=shared_width,
         loop_steps=cfg.loop_steps, core_bytes=kept_core_bytes)
-    kept_expert_bytes = kept_expert_layers and (
-        kept_expert_layers
-        * held_expert_kept_bytes(tokens, held_stack, itemsize))
-    kept_dense_bytes = kept_dense_layers * cfg.loop_steps * dense_kept_bytes(
-        tokens, cfg.dense_dim, itemsize)
+    # (applications counted)
+    kept_bytes = [n * nbytes for n, nbytes in zip(kept, kept_layer_bytes(
+        tokens, itemsize, held_stack, cfg.dense_dim, cfg.d_model,
+        shared_width, cfg.loop_steps))]
     model = TransformerLM(cfg, attn_fn=attn_fn, remat_blocks=remat_blocks,
-                          kept_expert_layers=kept_expert_layers,
-                          kept_dense_layers=kept_dense_layers)
+                          kept_expert_layers=kept.experts,
+                          kept_dense_layers=kept.dense,
+                          kept_sublayer_out_layers=kept.sublayer_outs,
+                          kept_shared_layers=kept.shared)
     router_load = SHARE_LOAD if cfg.experts_held is not None else ROUTER_LOAD
     router_losses = cfg.router_activation == "softmax"
     indexed = bool(cfg.indexer_num_heads)
@@ -1026,10 +1093,10 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         tel.gauge_set("attention.kda_fused_mixer_layers", kda_kernel_layers)
         tel.gauge_set("model.remat_blocks",
                       cfg.num_layers if remat_blocks else 0)
-        tel.gauge_set("model.kept_expert_layers", kept_expert_layers)
-        tel.gauge_set("model.kept_expert_bytes", kept_expert_bytes)
-        tel.gauge_set("model.kept_dense_layers", kept_dense_layers)
-        tel.gauge_set("model.kept_dense_bytes", kept_dense_bytes)
+        for what, layers, nbytes in zip(("expert", "dense", "sublayer_out",
+                                         "shared"), kept, kept_bytes):
+            tel.gauge_set("model.kept_%s_layers" % what, layers)
+            tel.gauge_set("model.kept_%s_bytes" % what, nbytes)
         tel.gauge_set("model.loop_steps", cfg.loop_steps)
         tel.gauge_set("model.block_applications",
                       cfg.num_layers * cfg.loop_steps)

@@ -342,7 +342,12 @@ class Runner:
         With tracing on this is the ``setup.init`` span of the set-up
         account, holding ``setup.init_state`` or ``setup.restore``."""
         with tel.span("setup.init", tel.SETUP_CAT):
-            return self._init(params, opt_state)
+            state = self._init(params, opt_state)
+            # the state owns copies (``init_state``'s ``place_var``): what
+            # the build kept of the initial parameters is read for shapes
+            # only from here on
+            self._dstep.release_initial_params()
+            return state
 
     def _init(self, params, opt_state) -> TrainState:
         m = self._membership

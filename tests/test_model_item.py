@@ -157,3 +157,95 @@ def test_gather_walker_sees_through_shard_map():
     sm = [e for e in jaxpr.eqns if e.primitive.name == "shard_map"]
     assert sm and not hasattr(sm[0].params["jaxpr"], "jaxpr")
     assert detect_sparse_vars(wrapped, params, batch) == {"emb"}
+
+
+# ------------------------------- what the item holds once the state is placed
+
+
+def _built(builder):
+    import autodist_tpu
+    params = _params()
+    ad = autodist_tpu.AutoDist(strategy_builder=builder)
+    runner = ad.build(_loss, optax.adam(0.05), params, _batch())
+    return runner, params
+
+
+def _builders():
+    from autodist_tpu import strategy as S
+    return [("allreduce", S.AllReduce), ("host_ps", S.PS),
+            ("partitioned_ps", S.PartitionedPS), ("zero", S.ZeroSharded)]
+
+
+@pytest.mark.parametrize("name, builder", _builders(),
+                         ids=[b[0] for b in _builders()])
+def test_init_lets_go_of_the_initial_parameters_and_keeps_their_shapes(
+        name, builder):
+    """Before ``Runner.init`` the item holds the caller's tree; once the
+    state is placed it holds ``jax.ShapeDtypeStruct``s of the same
+    structure, shapes and dtypes and NO device buffer (the holed template
+    of the lowering neither), the caller's arrays are still readable (the
+    state owns copies), and everything set-up's readers ask of the tree
+    still answers: a trace of the loss, the optimizer's shapes, a step."""
+    runner, params = _built(builder())
+    item = runner.distributed_step.model_item
+    before = jax.tree_util.tree_map(np.asarray, params)
+    assert all(isinstance(leaf, jax.Array)
+               for leaf in jax.tree_util.tree_leaves(item.params))
+    runner.init(params)
+    held = jax.tree_util.tree_leaves(item.params) + [
+        leaf for leaf in jax.tree_util.tree_leaves(
+            runner.distributed_step._holed_template)]
+    assert held and all(isinstance(leaf, jax.ShapeDtypeStruct)
+                        for leaf in held)
+    assert jax.tree_util.tree_structure(item.params) \
+        == jax.tree_util.tree_structure(params)
+    jax.tree_util.tree_map(
+        lambda kept, mine: (kept.shape, kept.dtype) == (mine.shape,
+                                                        mine.dtype)
+        or pytest.fail("%r against %r" % (kept, mine)), item.params, params)
+    losses = [float(runner.run(_batch())["loss"]) for _ in range(3)]
+    assert losses[2] < losses[0]
+    jax.tree_util.tree_map(np.testing.assert_array_equal, before,
+                           jax.tree_util.tree_map(np.asarray, params))
+    assert jax.eval_shape(item.loss_fn, item.params, _batch()).shape == ()
+    assert jax.tree_util.tree_structure(item.opt_state_spec) \
+        == jax.tree_util.tree_structure(optax.adam(0.05).init(params))
+
+
+@pytest.mark.parametrize("name, builder", _builders(),
+                         ids=[b[0] for b in _builders()])
+def test_a_second_init_a_save_and_a_restore_work_on_the_shapes(
+        name, builder, tmp_path):
+    """After the release: ``init`` again from the caller's tree starts
+    over bit for bit, a save and a restore (templates are the item's
+    shapes) bring back the saved step's parameters and optimizer state,
+    and the gathered parameters are the trained ones."""
+    from autodist_tpu.checkpoint.saver import Saver
+    runner, params = _built(builder())
+    runner.init(params)
+    first = [float(runner.run(_batch())["loss"]) for _ in range(3)]
+    saver = Saver(directory=str(tmp_path))
+    saver.save(runner)
+    saved = runner.gather_params()
+    after = [float(runner.run(_batch())["loss"]) for _ in range(2)]
+    _, step = saver.restore(runner)
+    assert step == 3
+    jax.tree_util.tree_map(np.testing.assert_array_equal, saved,
+                           runner.gather_params())
+    assert [float(runner.run(_batch())["loss"]) for _ in range(2)] == after
+    runner.init(params)
+    assert [float(runner.run(_batch())["loss"]) for _ in range(3)] == first
+    assert all(isinstance(leaf, jax.ShapeDtypeStruct) for leaf in
+               jax.tree_util.tree_leaves(
+                   runner.distributed_step.model_item.params))
+
+
+def test_shapes_of_takes_arrays_numbers_and_shapes_alike():
+    from autodist_tpu.model_item import shapes_of
+    tree = {"a": jnp.ones((2, 3), jnp.bfloat16), "b": np.zeros((4,), np.int32),
+            "c": 1.5, "d": jax.ShapeDtypeStruct((5,), jnp.float32)}
+    got = shapes_of(tree)
+    assert {k: (v.shape, str(v.dtype)) for k, v in got.items()} == {
+        "a": ((2, 3), "bfloat16"), "b": ((4,), "int32"),
+        "c": ((), "float64"), "d": ((5,), "float32")}
+    assert shapes_of(got) == got

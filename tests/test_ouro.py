@@ -411,12 +411,12 @@ def test_what_an_application_keeps_of_its_flash_core(name, tokens, heads, qk,
 
 
 def test_the_kept_cores_of_the_cell_count_applications():
-    """The cell: 24 applications of 33.8 MB, 0.81 GB beside 8.15 GB of
+    """The cell: 24 applications of 33.8 MB, 0.81 GB beside 6.12 GB of
     state, leave the share of the chip free that the held experts'
-    products are held to, with room for 118."""
+    products are held to, with room for 178."""
     one = lm.flash_kept_bytes(4096, 16, 128, 128, 2)
-    room = (1 - lm.KEPT_EXPERTS_HBM_LEFT) * 16e9 - 16 * 509_661_185
-    assert 24 * one < room and room // one == 118
+    room = (1 - lm.KEPT_EXPERTS_HBM_LEFT) * 16e9 - 12 * 509_661_185
+    assert 24 * one < room and room // one == 178
 
 
 def test_the_gauge_counts_what_every_application_keeps(monkeypatch):

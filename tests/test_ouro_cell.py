@@ -239,7 +239,7 @@ def test_the_programs_own_rules_decide_this_cells_step():
     assert not lm.auto_flash_attention(4096, 128, "cpu")
     assert CONFIG["vocab_size"] >= 32768
     one = lm.flash_kept_bytes(4096, 16, 128, 128, 2)
-    assert 16 * total + 24 * one < (1 - lm.KEPT_EXPERTS_HBM_LEFT) * 16e9
+    assert 12 * total + 24 * one < (1 - lm.KEPT_EXPERTS_HBM_LEFT) * 16e9
 
 
 # ---------------------------------------------------- the normal path, fit

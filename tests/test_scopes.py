@@ -741,6 +741,9 @@ TRACE_TIME_GAUGES = ("lean_head.chunks", "lean_head.chunk_width",
                      "attention.kda_fused_mixer_layers", "model.remat_blocks",
                      "model.kept_expert_layers", "model.kept_expert_bytes",
                      "model.kept_dense_layers", "model.kept_dense_bytes",
+                     "model.kept_sublayer_out_layers",
+                     "model.kept_sublayer_out_bytes",
+                     "model.kept_shared_layers", "model.kept_shared_bytes",
                      "model.loop_steps", "model.block_applications",
                      "model.kept_core_bytes")
 
@@ -857,7 +860,7 @@ def test_the_kept_expert_layers_are_gauges_of_the_traced_loss(
     count = sum(a.size for a in jax.tree_util.tree_leaves(params))
     a_layer = lm.held_expert_kept_bytes(4 * 16, (2, 32, 16), itemsize=4)
     assert a_layer == 2 * 4 * 64 * 2 * 16
-    hbm = (16 * count + (layers_that_fit + 0.5) * a_layer) / (
+    hbm = (12 * count + (layers_that_fit + 0.5) * a_layer) / (
         1 - lm.KEPT_EXPERTS_HBM_LEFT)
     monkeypatch.setattr(lm, "_chip_hbm_bytes", lambda: hbm)
     loss_fn, params, batch, _ = lm.make_train_setup(
