@@ -365,13 +365,34 @@ class RouterConfig:
     and scaled, whether a softmax router's balance loss is taken per
     sequence (``seq_aux``) or over all tokens with a z-loss (OLMoE's
     pair), the experts every token passes, and the SHARE of the experts
-    this layer holds (None = all of them). The defaults are OLMoE's."""
+    this layer holds (None = all of them). ``gated`` is the form of every
+    expert, routed and shared: ``down(silu(gate x) * up x)`` with three
+    matrices, or ``down(relu(up x)^2)`` with two (Nemotron-H's ``relu2``);
+    ``shared_width`` the shared expert's own width where it is not
+    ``shared_experts`` times a routed expert's. The defaults are OLMoE's."""
     activation: str = "softmax"
     renormalize: bool = False
     scaling_factor: float = 1.0
     shared_experts: int = 0
     held: Optional[Tuple[int, ...]] = None
     seq_aux: bool = False
+    gated: bool = True
+    shared_width: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class Mamba2Config:
+    """A Mamba-2 mixer's sizes as ``nemotron_h``'s config names them:
+    ``num_heads`` heads of ``head_dim`` (the inner width is their product,
+    not ``expand`` times the hidden size), ``n_groups`` groups of B and C
+    of ``state_size``, a causal filter of ``conv_size`` taps, the dual
+    form's ``chunk``."""
+    num_heads: int
+    head_dim: int
+    n_groups: int
+    state_size: int
+    conv_size: int
+    chunk: int
 
 
 def linear(features, dtype, name):
@@ -412,6 +433,23 @@ class SwiGLU(nn.Module):
         return linear(x.shape[-1], self.dtype, "down_proj")(h)
 
 
+class Relu2MLP(nn.Module):
+    """down(relu(up(x))^2), no bias and no gate (``mlp_hidden_act: relu2``).
+    With ``kept`` the up projection's product carries that name, which the
+    squared ReLU's backward reads: ONE array of 2 T f bytes where a SwiGLU
+    names two."""
+    width: int
+    dtype: Dtype = jnp.float32
+    kept: Optional[str] = None
+
+    @nn.compact
+    def __call__(self, x):
+        u = linear(self.width, self.dtype, "up_proj")(x)
+        u = checkpoint_name(u, self.kept) if self.kept else u
+        return linear(x.shape[-1], self.dtype, "down_proj")(
+            jnp.square(nn.relu(u)))
+
+
 class MoEFeedForward(nn.Module):
     """Routed SwiGLU feed-forward: ``num_experts`` experts of width
     ``expert_dim``, ``experts_per_token`` chosen per token, none dropped
@@ -430,7 +468,9 @@ class MoEFeedForward(nn.Module):
     hold only the experts this layer HOLDS, the losses are still over all
     the router's outputs, and ``counters`` also gets ``chosen_pairs`` (all
     T x k). The shared experts (one SwiGLU as wide as all of them, the
-    released DeepSeek and Kimi code's own form) are added in full."""
+    released DeepSeek and Kimi code's own form) are added in full. Experts
+    without a gate (``router.gated`` False) hold no ``gate_proj``, routed
+    or shared."""
     num_experts: int
     experts_per_token: int
     expert_dim: int
@@ -446,7 +486,8 @@ class MoEFeedForward(nn.Module):
         stacked = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=1, out_axis=2, batch_axis=0)
         router = self.param("router", nn.initializers.lecun_normal(), (d, E))
-        w_gate = self.param("gate_proj", stacked, (held, d, f))
+        w_gate = self.param("gate_proj", stacked, (held, d, f)) \
+            if cfg.gated else None
         w_up = self.param("up_proj", stacked, (held, d, f))
         w_down = self.param("down_proj", stacked, (held, f, d))
         softmax = cfg.activation == "softmax"
@@ -466,10 +507,30 @@ class MoEFeedForward(nn.Module):
             pairs = x.size // d * self.experts_per_token
             self.sow("counters", "chosen_pairs", jnp.int32(pairs))
         if cfg.shared_experts:
+            ffn = SwiGLU if cfg.gated else Relu2MLP
             with scopes.scope(scopes.MOE), scopes.scope(scopes.MOE_SHARED):
-                out = out + SwiGLU(cfg.shared_experts * f, self.dtype,
-                                   DENSE_FFN_KEPT, name="shared")(x)
+                out = out + ffn(cfg.shared_width or cfg.shared_experts * f,
+                                self.dtype, DENSE_FFN_KEPT, name="shared")(x)
         return out
+
+
+# one filter a channel of a depthwise causal convolution, [taps, channels]
+# (a gated short convolution's, KDA's three, a Mamba-2 mixer's)
+conv_filter_init = nn.initializers.variance_scaling(
+    1.0, "fan_in", "uniform", in_axis=0, out_axis=1)
+
+
+def a_log_init(key, shape):
+    """``A_log = log U(1, 16)``: the decay's rate a head (KDA, Mamba-2)."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+
+
+def dt_bias_init(key, shape):
+    """The inverse softplus of a log-uniform draw in [1e-3, 1e-1]: a time
+    step's bias (KDA, Mamba-2)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def causal_conv(x, w):
@@ -494,9 +555,7 @@ class ShortConv(nn.Module):
     def __call__(self, x):
         d = x.shape[-1]
         bcu = linear(3 * d, self.dtype, "in_proj")(x)
-        w = self.param("conv", nn.initializers.variance_scaling(
-            1.0, "fan_in", "uniform", in_axis=0, out_axis=1),
-            (self.kernel_size, d))
+        w = self.param("conv", conv_filter_init, (self.kernel_size, d))
         with scopes.scope(scopes.CONV_CORE):
             b, c, u = jnp.split(bcu, 3, axis=-1)
             y = c * causal_conv(b * u, w.astype(self.dtype))
@@ -532,18 +591,13 @@ class KimiDeltaAttention(nn.Module):
         fused = not self.is_initializing() and kda.runs_as_kernels(D, D)
 
         def projected(name):
-            w = self.param(name + "_conv", nn.initializers.variance_scaling(
-                1.0, "fan_in", "uniform", in_axis=0, out_axis=1), (K, H * D))
+            w = self.param(name + "_conv", conv_filter_init, (K, H * D))
             return dense(H * D, name + "_proj")(x), w
 
         (xq, wq), (xk, wk), (xv, wv) = (projected(n) for n in "qkv")
-        a_log = self.param("A_log", lambda key, shape: jnp.log(
-            jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)), (H,))
+        a_log = self.param("A_log", a_log_init, (H,))
         # softplus(dt_bias) log-uniform in [1e-3, 1e-1]
-        dt_bias = self.param("dt_bias", lambda key, shape: (
-            lambda dt: dt + jnp.log(-jnp.expm1(-dt)))(jnp.exp(
-                jax.random.uniform(key, shape, jnp.float32,
-                                   np.log(1e-3), np.log(1e-1)))), (H * D,))
+        dt_bias = self.param("dt_bias", dt_bias_init, (H * D,))
         f = dense(H * D, "f_b_proj")(dense(D, "f_a_proj")(x))
         beta = nn.sigmoid(dense(H, "b_proj")(x).astype(jnp.float32))
         if fused:
@@ -597,6 +651,55 @@ def kda_output(o, gate, o_norm, eps, dtype):
     gate = nn.sigmoid(gate.reshape(o.shape).astype(jnp.float32))
     o = (rms_normalize(o, eps) * o_norm * gate).astype(dtype)
     return o.reshape(o.shape[:-2] + (-1,))
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 token mixer (arXiv 2405.21060) as ``nemotron_h`` builds
+    it: ``[z | xBC | dt] = W_in u`` (widths inner | inner + 2 G N | H with
+    inner = H P); ``xBC <- silu(conv(xBC) + b)``, a depthwise causal filter
+    with a bias; ``xBC`` split into x [H, P], B [G, N], C [G, N]; ``dt <-
+    softplus(dt + dt_bias)`` (no clamp), ``A = -exp(A_log)`` one scalar a
+    head; the recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
+    ``y_t = S_t C_t + D x_t`` (``ops/ssd.py``); ``y <- N_g(y * silu(z))``,
+    the gate FIRST and the RMS statistic over each group's inner / G
+    features, one learned weight of inner; ``W_out y``. No bias on a
+    projection, no position signal. Sows ``chunk_carry`` into
+    ``counters``: what of a chunk's incoming state survives it."""
+    cfg: Mamba2Config
+    norm_eps: float
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from autodist_tpu.ops import ssd
+        c = self.cfg
+        H, P, G, N = c.num_heads, c.head_dim, c.n_groups, c.state_size
+        inner, conv_dim = H * P, H * P + 2 * G * N
+        z, xbc, dt = jnp.split(
+            linear(inner + conv_dim + H, self.dtype, "in_proj")(x),
+            [inner, inner + conv_dim], axis=-1)
+        w = self.param("conv", conv_filter_init, (c.conv_size, conv_dim))
+        b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
+        a_log = self.param("A_log", a_log_init, (H,))
+        d_skip = self.param("D", nn.initializers.ones, (H,))
+        # softplus(dt_bias) log-uniform in [time_step_min, time_step_max]
+        # (its floor, 1e-4, lies under the range)
+        dt_bias = self.param("dt_bias", dt_bias_init, (H,))
+        scale = self.param("norm", nn.initializers.ones, (inner,))
+        xbc = nn.silu(causal_conv(xbc, w.astype(self.dtype))
+                      + b.astype(self.dtype))
+        xs, bs, cs = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+        heads = lambda t, n: t.reshape(t.shape[:-1] + (n, -1))  # noqa: E731
+        with scopes.scope(scopes.SSD_SCAN):
+            y, carry = ssd.ssd_chunked(
+                heads(xs, H), jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                -jnp.exp(a_log), heads(bs, G), heads(cs, G), d_skip,
+                c.chunk, self.dtype)
+        self.sow("counters", "chunk_carry", carry)
+        y = heads(y.reshape(z.shape) * nn.silu(z), G)
+        y = (rms_normalize(y, self.norm_eps).reshape(z.shape)
+             * scale).astype(self.dtype)
+        return linear(x.shape[-1], self.dtype, "out_proj")(y)
 
 
 class LatentAttention(nn.Module):
@@ -676,7 +779,10 @@ class TransformerBlock(nn.Module):
     (``dense_dim``). ``sandwich_norm`` norms each sub-layer's OUTPUT too,
     before it joins the residual (``x + N(f(N(x)))``: four norms a block,
     the looped Ouro models' block), and names what the two output norms
-    read (:data:`SUBLAYER_OUT_KEPT`)."""
+    read (:data:`SUBLAYER_OUT_KEPT`). ``only`` leaves out the half a layer
+    of single sub-layers does not have: "mixer" = ``x + Mix(N(x))`` alone,
+    "ffn" = ``x + FFN(N(x))`` alone (the Nemotron-H layers, each ONE
+    sub-layer behind ONE norm)."""
     num_heads: int
     head_dim: int
     mlp_dim: int
@@ -700,10 +806,13 @@ class TransformerBlock(nn.Module):
     indexer: Optional[IndexerConfig] = None
     conv_size: int = 0
     sandwich_norm: bool = False
+    mamba: Optional[Mamba2Config] = None
+    only: Optional[str] = None          # None (both) | "mixer" | "ffn"
 
     def _mix(self, h, mask, cache, cursor, alive, return_kv, positions):
         """The block's token mixer on the normed input."""
-        if self.kda is None and self.mla is None and not self.conv_size:
+        if (self.kda is None and self.mla is None and not self.conv_size
+                and self.mamba is None):
             return MultiHeadAttention(
                 self.num_heads, self.head_dim, self.dtype, self.attn_fn,
                 decode_attn=self.decode_attn, use_bias=self.attention_bias,
@@ -716,8 +825,13 @@ class TransformerBlock(nn.Module):
         if cache is not None or return_kv:
             raise NotImplementedError(
                 "prefill and cached decode keep K/V rows only: a kda "
-                "layer's recurrent state, an mla layer's latent and a conv "
-                "layer's last inputs have no cache yet")
+                "layer's recurrent state, an mla layer's latent, a conv "
+                "layer's last inputs and a mamba2 layer's state and filter "
+                "inputs have no cache yet")
+        if self.mamba is not None:
+            with scopes.scope(scopes.MAMBA):
+                return Mamba2Mixer(self.mamba, self.norm_eps, self.dtype,
+                                   name="mamba")(h)
         if self.conv_size:
             with scopes.scope(scopes.CONV_MIX):
                 return ShortConv(self.conv_size, self.dtype, name="conv")(h)
@@ -730,9 +844,8 @@ class TransformerBlock(nn.Module):
                                    self.dtype, self.attn_fn, name="mla")(
                 h, mask, positions)
 
-    @nn.compact
-    def __call__(self, x, mask=None, deterministic=True, cache=None,
-                 cursor=None, alive=None, return_kv=False, positions=None):
+    def _mixer_sublayer(self, x, mask, deterministic, cache, cursor, alive,
+                        return_kv, positions):
         kv = None
         h = make_norm(self.norm, self.norm_eps, self.dtype)(x)
         with scopes.scope(scopes.ATTENTION):
@@ -745,7 +858,9 @@ class TransformerBlock(nn.Module):
                 checkpoint_name(h, SUBLAYER_OUT_KEPT))
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
-        x = x + h
+        return x + h, kv
+
+    def _ffn_sublayer(self, x, deterministic):
         h = make_norm(self.norm, self.norm_eps, self.dtype)(x)
         if self.dense_dim:
             h = SwiGLU(self.dense_dim, self.dtype, DENSE_FFN_KEPT,
@@ -764,7 +879,21 @@ class TransformerBlock(nn.Module):
                 checkpoint_name(h, SUBLAYER_OUT_KEPT))
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
-        x = x + h
+        return x + h
+
+    @nn.compact
+    def __call__(self, x, mask=None, deterministic=True, cache=None,
+                 cursor=None, alive=None, return_kv=False, positions=None):
+        kv = None
+        if self.only != "ffn":
+            x, kv = self._mixer_sublayer(x, mask, deterministic, cache,
+                                         cursor, alive, return_kv, positions)
+        elif cache is not None or return_kv:
+            raise NotImplementedError(
+                "prefill and cached decode read K/V rows from every layer: "
+                "a layer that is its feed-forward alone has none")
+        if self.only != "mixer":
+            x = self._ffn_sublayer(x, deterministic)
         if cache is not None or return_kv:
             return x, kv
         return x

@@ -22,7 +22,8 @@ import numpy as np
 
 from autodist_tpu.models.layers import (DENSE_FFN_KEPT, KDA_CORE_OUT,
                                         SUBLAYER_OUT_KEPT, IndexerConfig,
-                                        KDAConfig, MLAConfig, RouterConfig,
+                                        KDAConfig, Mamba2Config, MLAConfig,
+                                        RouterConfig,
                                         SparseEmbed, TransformerBlock,
                                         YarnConfig, causal_mask, make_norm)
 from autodist_tpu.telemetry import spans as tel
@@ -37,7 +38,10 @@ SHARE_LOAD = ROUTER_LOAD + ("chosen_pairs",)
 # what a sparse attention's indexer sows and the loss reports as
 # ``dsa.<name>``: the (query, key) pairs it chose, and all a query sees
 INDEXER_CHOICE = ("selected_pairs", "causal_pairs")
-LAYER_TYPES = ("attention", "kda", "mla", "conv")
+LAYER_TYPES = ("attention", "kda", "mla", "conv", "mamba2", "moe")
+# ``nemotron_h``'s ``hybrid_override_pattern``, a letter a layer (its "-", a
+# dense feed-forward alone, is not built)
+NEMOTRON_H_LAYERS = {"M": "mamba2", "*": "attention", "E": "moe"}
 YARN_KEYS = tuple(f.name for f in dataclasses.fields(YarnConfig))
 
 
@@ -99,12 +103,24 @@ class LMConfig:
     # mixer: "attention" (the softmax attention above), "kda" (Kimi Delta
     # Attention: ``kda_*``), "mla" (latent attention: the four widths
     # below; rotary on ``qk_rope_head_dim`` features iff ``rope_theta``,
-    # by ``rope_scaling``'s frequencies where it is given) or "conv" (a
+    # by ``rope_scaling``'s frequencies where it is given), "conv" (a
     # gated short convolution of ``conv_size`` taps, ``conv_L_cache``, without
-    # a bias).
+    # a bias) or "mamba2" (a Mamba-2 state-space mixer: ``mamba_*`` and
+    # ``ssm_state_size``).
     # None = "attention" in every layer.
     layer_types: Optional[Tuple[str, ...]] = None
+    # every layer is ONE sub-layer behind one norm, ``x + f(N(x))``
+    # (``nemotron_h``): an "attention" or "mamba2" layer is its mixer
+    # alone, and ``layer_types`` may name a layer "moe", its routed
+    # feed-forward alone
+    single_sublayer: bool = False
     conv_size: int = 0
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 0
+    ssm_state_size: int = 0
+    mamba_conv_size: int = 0
+    mamba_chunk: int = 0
     kda_num_heads: int = 0
     kda_head_dim: int = 0
     kda_conv_size: int = 0
@@ -124,6 +140,11 @@ class LMConfig:
     moe_renormalize: bool = False
     routed_scaling_factor: float = 1.0
     num_shared_experts: int = 0     # SwiGLU experts every token passes
+    # every expert, routed and shared, is ``down(silu(gate x) * up x)`` or,
+    # False, ``down(relu(up x)^2)``: two matrices and no gate
+    expert_gated: bool = True
+    # the shared expert's own width; 0 = ``num_shared_experts x mlp_dim``
+    shared_expert_dim: int = 0
     # the experts of ``num_experts`` whose weights THIS model holds: one
     # chip's share under expert parallelism (``parallel/expert.py``);
     # None = all. The router scores all ``num_experts`` either way.
@@ -158,6 +179,41 @@ class LMConfig:
             raise ValueError(
                 "conv_size is the taps of the layers layer_types names "
                 "'conv': got conv_size %d with %r" % (self.conv_size, types))
+        mamba = (self.mamba_num_heads, self.mamba_head_dim,
+                 self.mamba_n_groups, self.ssm_state_size,
+                 self.mamba_conv_size, self.mamba_chunk)
+        if not ((all(mamba)
+                 and not self.mamba_num_heads % self.mamba_n_groups)
+                if "mamba2" in (types or ()) else not any(mamba)):
+            raise ValueError(
+                "mamba_num_heads (a multiple of mamba_n_groups), "
+                "mamba_head_dim, mamba_n_groups, ssm_state_size, "
+                "mamba_conv_size and mamba_chunk are the sizes of the layers "
+                "layer_types names 'mamba2': got %r with %r" % (mamba, types))
+        single_only = {"mamba2", "moe"} & set(types or ())
+        if single_only and not self.single_sublayer:
+            raise ValueError(
+                "%s layers are built as single sub-layers alone "
+                "(single_sublayer): a block of a mixer AND a feed-forward "
+                "has neither" % sorted(single_only))
+        if self.single_sublayer:
+            not_built = [what for what, on in (
+                ("no layer_types", types is None),
+                ("kda, mla or conv layers",
+                 set(types or ()) - {"attention", "mamba2", "moe"}),
+                ("loop_steps > 1", self.loop_steps > 1),
+                ("sandwich_norm", self.sandwich_norm),
+                ("an indexer", self.indexer_num_heads),
+                ("a dense feed-forward layer (first_k_dense_replace, "
+                 "dense_dim; the pattern's '-')",
+                 self.first_k_dense_replace or self.dense_dim),
+                ("'moe' layers without num_experts",
+                 "moe" in (types or ()) and not self.num_experts)) if on]
+            if not_built:
+                raise ValueError(
+                    "single_sublayer builds 'attention', 'mamba2' and routed "
+                    "'moe' layers, each ONE sub-layer behind one norm; not "
+                    "built with it: " + "; ".join(not_built))
         if self.tie_embedding and self.head_bias:
             raise ValueError("a tied head is h E^T alone: head_bias is set")
         if self.router_activation not in ("softmax", "sigmoid"):
@@ -165,13 +221,17 @@ class LMConfig:
                              "%r" % (self.router_activation,))
         routed_only = (self.moe_renormalize, self.routed_scaling_factor != 1.0,
                        self.num_shared_experts, self.experts_held is not None,
+                       not self.expert_gated, self.shared_expert_dim,
                        self.router_aux_loss_coef, self.router_z_loss_coef,
                        self.seq_aux)
         if not self.num_experts and any(routed_only):
             raise ValueError(
                 "renormalised or scaled gates, shared experts, a share of "
-                "the experts and the router losses belong to a routed "
-                "feed-forward: num_experts is 0")
+                "the experts, their form and the router losses belong to a "
+                "routed feed-forward: num_experts is 0")
+        if self.shared_expert_dim and not self.num_shared_experts:
+            raise ValueError("shared_expert_dim is the width of the shared "
+                             "expert: num_shared_experts is 0")
         if self.router_activation == "sigmoid" and (
                 self.router_aux_loss_coef or self.router_z_loss_coef
                 or self.seq_aux):
@@ -382,6 +442,43 @@ class LMConfig:
                    sandwich_norm=True, **kw)
 
     @classmethod
+    def nemotron_twotower_30b_a3b(cls, **kw):
+        """The Nemotron-H tower of Nemotron-Labs-TwoTower-30B-A3B-Base-BF16
+        as its ``config.json`` publishes it
+        (huggingface.co/nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16,
+        ``model_type: nemotron_h``): 52 layers of hidden 2,688 by
+        ``hybrid_override_pattern``, each ONE sub-layer behind an RMSNorm
+        (eps 1e-5), no bias on a projection: 23 Mamba-2 mixers (64 heads of
+        64, 8 groups of B and C of 128, a 4-tap filter with a bias, chunks
+        of 128), 6 softmax attentions (32 query heads over 2 K/V heads of
+        128, no rotation and no position signal at all) and 23 routed
+        feed-forwards (128 sigmoid-routed ``relu(up x)^2`` experts of 1,856,
+        6 a token chosen by score + bias, gates renormalised x 2.5, beside
+        one shared expert of 3,712); an untied head over 131,072 words.
+        ``num_layers`` cuts the pattern from its start. The second,
+        denoising tower of the release (adaLN modulation, attention that is
+        bidirectional inside a block, conditioning across the towers) has
+        no key in the row and is not built. The inner width from the heads,
+        the gate before the grouped norm and the attention without rotation
+        are ``nemotron_h``'s code without a key in the row: assumptions the
+        benchmark's configuration file lists."""
+        pattern = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+        n = kw.setdefault("num_layers", len(pattern))
+        kw.setdefault("max_seq_len", 262144)
+        kw.setdefault("layer_types", tuple(
+            NEMOTRON_H_LAYERS[c] for c in pattern[:n]))
+        return cls(vocab_size=131072, d_model=2688, num_heads=32,
+                   head_dim=128, num_kv_heads=2, mlp_dim=1856,
+                   norm="rmsnorm", norm_eps=1e-5, attention_bias=False,
+                   head_bias=False, embed_scale=False, single_sublayer=True,
+                   mamba_num_heads=64, mamba_head_dim=64, mamba_n_groups=8,
+                   ssm_state_size=128, mamba_conv_size=4, mamba_chunk=128,
+                   num_experts=128, experts_per_token=6,
+                   router_activation="sigmoid", moe_renormalize=True,
+                   routed_scaling_factor=2.5, num_shared_experts=1,
+                   expert_gated=False, shared_expert_dim=3712, **kw)
+
+    @classmethod
     def tiny(cls, **kw):
         return cls(vocab_size=128, d_model=32, num_layers=2, num_heads=2,
                    mlp_dim=64, max_seq_len=64, **kw)
@@ -441,6 +538,12 @@ class TransformerLM(nn.Module):
                                   cfg.rope_theta, yarn)
         elif kind == "conv":
             kw["conv_size"] = cfg.conv_size
+        elif kind == "mamba2":
+            kw["mamba"] = Mamba2Config(
+                cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.mamba_n_groups,
+                cfg.ssm_state_size, cfg.mamba_conv_size, cfg.mamba_chunk)
+        if cfg.single_sublayer:
+            kw["only"] = "ffn" if kind == "moe" else "mixer"
         if kind == "attention" and (cfg.num_kv_heads or cfg.qk_head_norm
                                     or cfg.indexer_num_heads):
             kw.update(num_kv_heads=cfg.num_kv_heads,
@@ -456,7 +559,8 @@ class TransformerLM(nn.Module):
             kw["router"] = RouterConfig(
                 cfg.router_activation, cfg.moe_renormalize,
                 cfg.routed_scaling_factor, cfg.num_shared_experts,
-                cfg.experts_held, cfg.seq_aux)
+                cfg.experts_held, cfg.seq_aux, cfg.expert_gated,
+                cfg.shared_expert_dim)
         # (what a core's backward kernels need of its forward kernel is
         # kept by name, or a recomputed block would run the core a second
         # time only to make it again: the delta rule's output and
@@ -487,11 +591,14 @@ class TransformerLM(nn.Module):
         from autodist_tpu.ops.flash_attention import KEPT as FLASH_CORE_KEPT
         from autodist_tpu.parallel.expert import KEPT as HELD_EXPERTS_KEPT
         kept = (KDA_CORE_OUT, FLASH_CORE_KEPT, DSA_CHOICE_KEPT)
-        if i >= cfg.num_layers - self.kept_expert_layers:
+        # (the last k routed layers are the last k layers that ARE routed)
+        routed = routed_layer_indices(cfg)
+        last = lambda k: routed[max(0, len(routed) - k):]  # noqa: E731
+        if i in last(self.kept_expert_layers):
             kept += (HELD_EXPERTS_KEPT,)
         dense = num_dense_layers(cfg)
         if (dense - self.kept_dense_layers <= i < dense
-                or i >= max(dense, cfg.num_layers - self.kept_shared_layers)):
+                or i in last(self.kept_shared_layers)):
             kept += (DENSE_FFN_KEPT,)
         if i >= cfg.num_layers - self.kept_sublayer_out_layers:
             kept += (SUBLAYER_OUT_KEPT,)
@@ -749,6 +856,18 @@ def num_dense_layers(cfg: LMConfig) -> int:
         else 0
 
 
+def routed_layer_indices(cfg: LMConfig) -> Tuple[int, ...]:
+    """The layers whose feed-forward is routed, by index: every layer after
+    the leading dense ones, or under ``single_sublayer`` those
+    ``layer_types`` names "moe" (they lie BETWEEN the mixers there)."""
+    if not cfg.num_experts:
+        return ()
+    if cfg.single_sublayer:
+        return tuple(i for i, t in enumerate(cfg.layer_types) if t == "moe")
+    return tuple(range(min(cfg.first_k_dense_replace, cfg.num_layers),
+                       cfg.num_layers))
+
+
 class KeptLayers(NamedTuple):
     """:func:`auto_kept_layers`' counts, the LAST so many layers of each
     kind, in the order they are booked."""
@@ -765,7 +884,8 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
                      dense_layers: int = 0, dense_width: int = 0,
                      sandwich_layers: int = 0, d_model: int = 0,
                      shared_width: int = 0, loop_steps: int = 1,
-                     core_bytes: int = 0) -> KeptLayers:
+                     core_bytes: int = 0,
+                     expert_products: int = 2) -> KeptLayers:
     """Of a recomputed model's layers, how many keep by name what the
     recomputed forward would otherwise make a second time only for the
     backward to read (``TransformerLM._block`` saves the names in the LAST
@@ -775,8 +895,10 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
     fit, in this order:
 
     - the held experts' gate and up products (``parallel/expert.py:KEPT``):
-      two ``[tokens, E, f]`` arrays of ``itemsize`` bytes a routed layer,
-      ``held_stack`` being the held gate stack's ``[E, d, f]``. None where
+      two ``[tokens, E, f]`` arrays of ``itemsize`` bytes a routed layer
+      (``expert_products``: ONE where the experts have no gate, and so for
+      the shared experts below), ``held_stack`` being the held up stack's
+      ``[E, d, f]``. None where
       no share is held (``held_stack`` None: the sorted form's grouped
       matmuls carry no name);
     - from what they leave, less ``core_bytes`` (what the flash cores keep
@@ -797,7 +919,8 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
     room = max(0.0, (1.0 - KEPT_EXPERTS_HBM_LEFT) * hbm_bytes
                - 12.0 * param_count)
     a_layer = kept_layer_bytes(tokens, itemsize, held_stack, dense_width,
-                               d_model, shared_width, loop_steps)
+                               d_model, shared_width, loop_steps,
+                               expert_products)
 
     def book(layers, nbytes):
         nonlocal room
@@ -815,15 +938,17 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
 def kept_layer_bytes(tokens: int, itemsize: int,
                      held_stack: Optional[Tuple[int, int, int]],
                      dense_width: int, d_model: int, shared_width: int,
-                     loop_steps: int = 1) -> KeptLayers:
+                     loop_steps: int = 1,
+                     expert_products: int = 2) -> KeptLayers:
     """What ONE layer of each kind keeps by name over all its
     ``loop_steps`` applications (0 for a kind the model has none of)."""
     return KeptLayers(
-        held_expert_kept_bytes(tokens, held_stack, itemsize)
+        held_expert_kept_bytes(tokens, held_stack, itemsize, expert_products)
         if held_stack is not None else 0,
         loop_steps * dense_kept_bytes(tokens, dense_width, itemsize),
         loop_steps * sublayer_out_kept_bytes(tokens, d_model, itemsize),
-        loop_steps * dense_kept_bytes(tokens, shared_width, itemsize))
+        loop_steps * dense_kept_bytes(tokens, shared_width, itemsize,
+                                      expert_products))
 
 
 def flash_kept_bytes(tokens: int, num_heads: int, qk_dim: int, v_dim: int,
@@ -836,17 +961,19 @@ def flash_kept_bytes(tokens: int, num_heads: int, qk_dim: int, v_dim: int,
 
 
 def held_expert_kept_bytes(tokens: int, held_stack: Tuple[int, int, int],
-                           itemsize: int = 2) -> int:
-    """What one routed layer keeps under :data:`parallel.expert.KEPT`."""
+                           itemsize: int = 2, products: int = 2) -> int:
+    """What one routed layer keeps under :data:`parallel.expert.KEPT`:
+    ``products`` hidden arrays (gate and up; up alone without a gate)."""
     n_held, _, width = held_stack
-    return 2 * itemsize * tokens * n_held * width
+    return products * itemsize * tokens * n_held * width
 
 
-def dense_kept_bytes(tokens: int, width: int, itemsize: int = 2) -> int:
-    """What ONE application of a SwiGLU of this width (a dense layer's, a
-    routed layer's shared experts') keeps under
-    :data:`models.layers.DENSE_FFN_KEPT`."""
-    return 2 * itemsize * tokens * width
+def dense_kept_bytes(tokens: int, width: int, itemsize: int = 2,
+                     products: int = 2) -> int:
+    """What ONE application of a feed-forward of this width (a dense
+    layer's SwiGLU, a routed layer's shared experts') keeps under
+    :data:`models.layers.DENSE_FFN_KEPT`: ``products`` hidden arrays."""
+    return products * itemsize * tokens * width
 
 
 def sublayer_out_kept_bytes(tokens: int, d_model: int,
@@ -971,26 +1098,29 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         batch_size * seq_len, cfg.num_heads, head_dim,
         cfg.v_head_dim if "mla" in types else head_dim,
         jnp.dtype(cfg.dtype).itemsize) if remat_blocks else 0
-    # (leading dense layers route nothing)
-    routed_layers = (max(0, cfg.num_layers - cfg.first_k_dense_replace)
-                     if cfg.num_experts else 0)
-    routed = routed_layers > 0
+    # (leading dense layers route nothing; single sub-layers by kind)
+    n_routed = len(routed_layer_indices(cfg))
+    routed = n_routed > 0
+    mamba_layers = types.count("mamba2")
     held_stack = (None if cfg.experts_held is None else
                   (len(cfg.experts_held), cfg.d_model, cfg.mlp_dim))
     # (a replica sees no more tokens a step than the whole batch)
     tokens, itemsize = batch_size * seq_len, jnp.dtype(cfg.dtype).itemsize
-    shared_width = cfg.num_shared_experts * cfg.mlp_dim if routed else 0
+    shared_width = (cfg.shared_expert_dim
+                    or cfg.num_shared_experts * cfg.mlp_dim) if routed else 0
+    expert_products = 2 if cfg.expert_gated else 1
     kept = auto_kept_layers(
         remat_blocks, param_count, hbm_bytes, tokens, itemsize,
-        routed_layers=routed_layers, held_stack=held_stack,
+        routed_layers=n_routed, held_stack=held_stack,
         dense_layers=num_dense_layers(cfg), dense_width=cfg.dense_dim,
         sandwich_layers=cfg.num_layers if cfg.sandwich_norm else 0,
         d_model=cfg.d_model, shared_width=shared_width,
-        loop_steps=cfg.loop_steps, core_bytes=kept_core_bytes)
+        loop_steps=cfg.loop_steps, core_bytes=kept_core_bytes,
+        expert_products=expert_products)
     # (applications counted)
     kept_bytes = [n * nbytes for n, nbytes in zip(kept, kept_layer_bytes(
         tokens, itemsize, held_stack, cfg.dense_dim, cfg.d_model,
-        shared_width, cfg.loop_steps))]
+        shared_width, cfg.loop_steps, expert_products))]
     model = TransformerLM(cfg, attn_fn=attn_fn, remat_blocks=remat_blocks,
                           kept_expert_layers=kept.experts,
                           kept_dense_layers=kept.dense,
@@ -1005,7 +1135,7 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         layers' load and an indexer's choice go to the step's device
         counters from HERE, the loss's own trace
         (``telemetry/device_counters.py``)."""
-        if not (routed or indexed):
+        if not (routed or indexed or mamba_layers):
             return model.apply(params, ids, method=method), None
         out, sown = model.apply(params, ids, method=method,
                                 mutable=["losses", "counters"])
@@ -1013,6 +1143,10 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
             if "moe" in layer:
                 for name in router_load:
                     device_counters.add("moe." + name, layer["moe"][name][0])
+            if "mamba" in layer:    # the mean over the Mamba layers too
+                device_counters.add(
+                    "mamba.chunk_carry",
+                    layer["mamba"]["chunk_carry"][0] / mamba_layers)
             for mixer in layer.values():
                 if "indexer" in mixer:
                     for name in INDEXER_CHOICE:
@@ -1101,6 +1235,9 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         tel.gauge_set("model.block_applications",
                       cfg.num_layers * cfg.loop_steps)
         tel.gauge_set("model.kept_core_bytes", kept_core_bytes)
+        tel.gauge_set("model.mamba_layers", mamba_layers)
+        tel.gauge_set("model.single_sublayer_blocks",
+                      cfg.num_layers if cfg.single_sublayer else 0)
         tokens = batch["tokens"]
         targets = tokens[:, 1:]
         if cfg.loop_steps > 1:
@@ -1125,6 +1262,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         declared += ["moe.aux_loss"] if cfg.seq_aux else []
     if indexed:
         declared += ["dsa." + n for n in INDEXER_CHOICE]
+    if mamba_layers:
+        declared += ["mamba.chunk_carry"]
     if cfg.loop_steps > 1:
         declared += ["loop.exit_mass_%d" % (t + 1)
                      for t in range(cfg.loop_steps)] + ["loop.exit_entropy"]

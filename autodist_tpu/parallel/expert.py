@@ -299,7 +299,8 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
                      dtype=None, routing: Routing = Routing(),
                      held: Optional[Tuple[int, ...]] = None,
                      seq_aux: bool = False):
-    """Token-choice top-k SwiGLU MoE with no token dropped. Returns
+    """Token-choice top-k MoE with no token dropped, its experts gated
+    (SwiGLU) or, with ``w_gate`` None, not (``relu(up x)^2``). Returns
     (output with x's shape, load-balance loss, z-loss, routed pairs per
     expert of the stacks [E] int32: the layer's load).
 
@@ -310,7 +311,9 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
       k: HF ``norm_topk_prob`` false).
     - ``w_gate``/``w_up``: [E, d, f], ``w_down``: [E, f, d]; computed in
       ``dtype`` with float32 accumulation of the per-token combine:
-      ``sum_j g_j * down_ej(silu(gate_ej(x)) * up_ej(x))``.
+      ``sum_j g_j * down_ej(silu(gate_ej(x)) * up_ej(x))``. ``w_gate``
+      None: two stacks an expert and no gate,
+      ``sum_j g_j * down_ej(relu(up_ej(x))^2)`` (Nemotron-H's ``relu2``).
     - ``held``: the experts of the router's E_all whose weights the stacks
       hold, in the stacks' order (one chip's share under expert
       parallelism); None = all, in the sorted form. With a share the
@@ -359,7 +362,7 @@ def _sorted_experts(tokens, gate, expert, w_gate, w_up, w_down):
     one grouped matmul for each projection, the un-sort and the gated
     sum."""
     dt = tokens.dtype
-    (T, d), top_k, E = tokens.shape, expert.shape[-1], w_gate.shape[0]
+    (T, d), top_k, E = tokens.shape, expert.shape[-1], w_up.shape[0]
     with scopes.scope(scopes.MOE_ROUTE):
         pair_expert = expert.reshape(-1)                     # [T*k]
         order = jnp.argsort(pair_expert, stable=True)        # by expert
@@ -368,8 +371,9 @@ def _sorted_experts(tokens, gate, expert, w_gate, w_up, w_down):
         pairs = jnp.repeat(tokens, top_k, axis=0)            # [T*k, d]
         xs = _permute_rows(pairs, order, inv_order)
     with scopes.scope(scopes.MOE_EXPERTS):
-        h = (jax.nn.silu(grouped_matmul(xs, w_gate.astype(dt), counts))
-             * grouped_matmul(xs, w_up.astype(dt), counts))
+        product = lambda w: grouped_matmul(xs, w.astype(dt), counts)  # noqa: E731
+        h = (jnp.square(jax.nn.relu(product(w_up))) if w_gate is None
+             else jax.nn.silu(product(w_gate)) * product(w_up))
         ys = grouped_matmul(h, w_down.astype(dt), counts)    # [T*k, d]
     with scopes.scope(scopes.MOE_ROUTE):
         ys = _permute_rows(ys, inv_order, order)             # token order
@@ -388,7 +392,8 @@ def _pairs_per_expert(pair_expert, n_experts):
 # what a block recomputed in the backward pass keeps of the held experts by
 # name (``models/lm.py:TransformerLM._block``, as ``ops/flash_attention.py:
 # KEPT`` and ``ops/dsa.py:KEPT`` are kept): the gate and the up projection's
-# products [T, E, f], which SiLU's and the product's backward read
+# products [T, E, f], which SiLU's and the product's backward read (experts
+# without a gate: the one up product, which the squared ReLU's reads)
 KEPT = "held_experts_kept"
 
 
@@ -417,17 +422,22 @@ def _held_experts(tokens, gate, expert, w_gate, w_up, w_down, held):
     The two hidden products carry the name :data:`KEPT`: of a layer's 11
     matmuls in a step whose block is recomputed (3 forward, 6 backward, the
     two the backward reads made again) a policy that saves the name leaves
-    9, for 4 T E f bytes. The identity under any other policy or none."""
+    9, for 4 T E f bytes. The identity under any other policy or none.
+    Experts without a gate (``w_gate`` None) have ONE hidden product to
+    name: 7 matmuls recomputed, 6 with the name, for 2 T E f bytes."""
     dt = tokens.dtype
     with scopes.scope(scopes.MOE_ROUTE):
         chose = expert[:, :, None] == jnp.asarray(held)[None, None, :]
         weight = jnp.sum(jnp.where(chose, gate[:, :, None], 0.0), axis=1)
         counts = jnp.sum(chose, axis=(0, 1), dtype=jnp.int32)     # [E]
     with scopes.scope(scopes.MOE_EXPERTS):
-        g, u = (checkpoint_name(
+        product = lambda w: checkpoint_name(  # noqa: E731
             jnp.einsum("td,edf->tef", tokens, w.astype(dt)), KEPT)
-            for w in (w_gate, w_up))
-        h = jax.nn.silu(g) * u
+        if w_gate is None:
+            h = jnp.square(jax.nn.relu(product(w_up)))
+        else:
+            g, u = product(w_gate), product(w_up)
+            h = jax.nn.silu(g) * u
         h = (h.astype(jnp.float32) * weight[:, :, None]).astype(dt)
         out = jnp.einsum("tef,efd->td", h, w_down.astype(dt),
                          preferred_element_type=jnp.float32)

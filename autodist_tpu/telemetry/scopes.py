@@ -65,6 +65,8 @@ DSA_TOPK = "dsa_topk"
 DSA_CORE = "dsa_core"
 CONV_MIX = "conv_mix"
 CONV_CORE = "conv_core"
+MAMBA = "mamba"
+SSD_SCAN = "ssd_scan"
 LOOP = "loop"
 EXIT_GATE = "exit_gate"
 PREFILL = "prefill"
@@ -124,6 +126,12 @@ SCOPES: Dict[str, str] = {
     CONV_CORE: "inside conv_mix: what lies between the two projections, the "
                "split in thirds, the two element-wise gates and the short "
                "depthwise causal convolution",
+    MAMBA: "inside attention: a Mamba-2 mixer whole (the input projection, "
+           "the short causal filter with its bias and SiLU, the state-space "
+           "recurrence, the gated grouped norm, the output projection)",
+    SSD_SCAN: "inside mamba: the recurrence's core alone (ops/ssd.py): the "
+              "decays, the chunked dual form's four products, the states "
+              "carried from chunk to chunk and D x",
     LOOP: "inside blocks: the passes of a looped model (LMConfig.loop_steps), "
           "every block and the final norm once a pass over shared weights "
           "(one body traced, compiled as straight-line code)",

@@ -355,7 +355,11 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     for kernel in ("flash_fwd", "flash_bwd"):
         assert sum(kernel in line for line in calls) == 6
     assert all("mla_core" in line for line in calls)
-    assert "gmm" not in text and "rematted_computation" in text
+    # (no kernel of the twelve is the grouped matmul's; the module's TEXT may
+    # name megablox's file among its source files where an earlier test of
+    # the same process traced a cached ``jnp`` function from inside it)
+    assert not any("gmm" in line for line in calls)
+    assert "rematted_computation" in text
     step = out["train_step"]
     total = CONFIG["parameters_as_built"]["total"]
     # float32 master weights and Adam's two moments: 12 B a parameter

@@ -49,6 +49,7 @@ def steps():
     from tests.test_keye_vl2 import tiny_config as keye
     from tests.test_kimi_linear import tiny_config as kimi
     from tests.test_lfm2_moe import tiny_config as lfm2
+    from tests.test_nemotron_h import tiny_config as nemotron_h
     from tests.test_ouro import tiny_config as ouro
     return {
         "tiny_lm_step": (("lm1b", "tiny"), lm.LMConfig.tiny, 16, 4, "auto"),
@@ -65,7 +66,9 @@ def steps():
         "tiny_keye_vl2_flash_step": (("keye_vl2_30b_a3b",), keye, 32, 2,
                                      "flash"),
         "tiny_lfm2_moe_step": (("lfm2_24b_a2b",), lfm2, 32, 2, "auto"),
-        "tiny_ouro_step": (("ouro_2_6b",), ouro, 32, 2, "auto")}
+        "tiny_ouro_step": (("ouro_2_6b",), ouro, 32, 2, "auto"),
+        "tiny_nemotron_h_step": (("nemotron_twotower_30b_a3b",), nemotron_h,
+                                 32, 2, "auto")}
 
 
 def tree_digest(params):
