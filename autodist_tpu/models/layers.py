@@ -660,7 +660,10 @@ class Mamba2Mixer(nn.Module):
     with a bias; ``xBC`` split into x [H, P], B [G, N], C [G, N]; ``dt <-
     softplus(dt + dt_bias)`` (no clamp), ``A = -exp(A_log)`` one scalar a
     head; the recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
-    ``y_t = S_t C_t + D x_t`` (``ops/ssd.py``); ``y <- N_g(y * silu(z))``,
+    ``y_t = S_t C_t + D x_t`` (``ops/ssd.py``: two pallas kernels where
+    the heads fill whole tiles, their y and per-chunk states kept by name
+    across a recomputed block, the ``jnp`` form where they do not);
+    ``y <- N_g(y * silu(z))``,
     the gate FIRST and the RMS statistic over each group's inner / G
     features, one learned weight of inner; ``W_out y``. No bias on a
     projection, no position signal. Sows ``chunk_carry`` into

@@ -569,7 +569,9 @@ class TransformerLM(nn.Module):
         # forward kernel of 3.55 ms, and its q, 50 MB against 1.5 ms of
         # projection and rotation; a sparse attention's choice of keys,
         # 67 MB a layer at seq 8192 against its index scores and a choice
-        # among them for every query. A name no op of the block carries
+        # among them for every query; the state-space scan's output and
+        # the state that enters each chunk, 67 + 134 MB a layer at 64
+        # heads of 64 x 128 and seq 8192. A name no op of the block carries
         # keeps nothing. The held experts' gate and up products are the
         # other way round, 4 T E f bytes a layer, 369 MB at 8 experts of
         # 1,408 on 8,192 tokens, against two of the layer's eleven expert
@@ -589,8 +591,9 @@ class TransformerLM(nn.Module):
         # ``kept_sublayer_out_layers`` layers keep both)
         from autodist_tpu.ops.dsa import KEPT as DSA_CHOICE_KEPT
         from autodist_tpu.ops.flash_attention import KEPT as FLASH_CORE_KEPT
+        from autodist_tpu.ops.ssd import KEPT as SSD_CORE_KEPT
         from autodist_tpu.parallel.expert import KEPT as HELD_EXPERTS_KEPT
-        kept = (KDA_CORE_OUT, FLASH_CORE_KEPT, DSA_CHOICE_KEPT)
+        kept = (KDA_CORE_OUT, FLASH_CORE_KEPT, DSA_CHOICE_KEPT, SSD_CORE_KEPT)
         # (the last k routed layers are the last k layers that ARE routed)
         routed = routed_layer_indices(cfg)
         last = lambda k: routed[max(0, len(routed) - k):]  # noqa: E731
@@ -901,8 +904,9 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
       ``[E, d, f]``. None where
       no share is held (``held_stack`` None: the sorted form's grouped
       matmuls carry no name);
-    - from what they leave, less ``core_bytes`` (what the flash cores keep
-      by name in any case, :func:`flash_kept_bytes` an application), the
+    - from what they leave, less ``core_bytes`` (what the flash cores and
+      the state-space scans keep by name in any case,
+      :func:`flash_kept_bytes` and :func:`ssd_kept_bytes` an application), the
       dense feed-forwards' (``models/layers.py:DENSE_FFN_KEPT``): two
       ``[tokens, dense_width]`` arrays an application of a dense layer;
     - what a sandwich-normed block's two output norms read
@@ -958,6 +962,18 @@ def flash_kept_bytes(tokens: int, num_heads: int, qk_dim: int, v_dim: int,
     output ``[tokens, heads, v_dim]`` of ``itemsize`` bytes, the
     log-sum-exp ``[heads, tokens]`` in float32."""
     return tokens * num_heads * (itemsize * (qk_dim + v_dim) + 4)
+
+
+def ssd_kept_bytes(batch_size: int, seq_len: int, num_heads: int,
+                   head_dim: int, state_size: int, chunk: int,
+                   itemsize: int = 2) -> int:
+    """What ONE Mamba-2 layer on the scan kernels keeps under
+    :data:`ops.ssd.KEPT`: y ``[B, S, heads, head_dim]`` of ``itemsize``
+    bytes and the float32 state ``[heads, head_dim, state_size]`` that
+    enters each of the padded sequence's chunks."""
+    padded = seq_len + -seq_len % chunk
+    return batch_size * num_heads * head_dim * (
+        padded * itemsize + padded // chunk * state_size * 4)
 
 
 def held_expert_kept_bytes(tokens: int, held_stack: Tuple[int, int, int],
@@ -1081,6 +1097,14 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         from autodist_tpu.ops.kda import runs_as_kernels
         if runs_as_kernels(cfg.kda_head_dim, cfg.kda_head_dim):
             kda_kernel_layers = types.count("kda")
+    mamba_layers = types.count("mamba2")
+    ssd_kernel_layers = 0
+    if mamba_layers:
+        from autodist_tpu.ops.ssd import runs_as_kernels
+        if runs_as_kernels(cfg.mamba_head_dim, cfg.ssm_state_size,
+                           cfg.mamba_num_heads // cfg.mamba_n_groups,
+                           cfg.mamba_chunk):
+            ssd_kernel_layers = mamba_layers
     rng = jax.random.PRNGKey(seed)
     # only the parameters leave the jit, so the forward pass the init
     # traces (XLA's attention whatever ``attn_fn`` is, and what a routed
@@ -1092,20 +1116,25 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     hbm_bytes = _chip_hbm_bytes()
     remat_blocks = auto_remat_blocks(param_count, cfg.num_layers, hbm_bytes,
                                      cfg.loop_steps)
-    # what the recomputed blocks on the flash kernels keep by name, once
-    # an APPLICATION (a looped model's passes each keep their own)
-    kept_core_bytes = flash_layers * cfg.loop_steps * flash_kept_bytes(
+    # what the recomputed blocks on the flash kernels and on the scan
+    # kernels keep by name, once an APPLICATION (a looped model's passes
+    # each keep their own)
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    core_bytes = flash_layers * flash_kept_bytes(
         batch_size * seq_len, cfg.num_heads, head_dim,
-        cfg.v_head_dim if "mla" in types else head_dim,
-        jnp.dtype(cfg.dtype).itemsize) if remat_blocks else 0
+        cfg.v_head_dim if "mla" in types else head_dim, itemsize)
+    if ssd_kernel_layers:
+        core_bytes += ssd_kernel_layers * ssd_kept_bytes(
+            batch_size, seq_len, cfg.mamba_num_heads, cfg.mamba_head_dim,
+            cfg.ssm_state_size, cfg.mamba_chunk, itemsize)
+    kept_core_bytes = cfg.loop_steps * core_bytes if remat_blocks else 0
     # (leading dense layers route nothing; single sub-layers by kind)
     n_routed = len(routed_layer_indices(cfg))
     routed = n_routed > 0
-    mamba_layers = types.count("mamba2")
     held_stack = (None if cfg.experts_held is None else
                   (len(cfg.experts_held), cfg.d_model, cfg.mlp_dim))
     # (a replica sees no more tokens a step than the whole batch)
-    tokens, itemsize = batch_size * seq_len, jnp.dtype(cfg.dtype).itemsize
+    tokens = batch_size * seq_len
     shared_width = (cfg.shared_expert_dim
                     or cfg.num_shared_experts * cfg.mlp_dim) if routed else 0
     expert_products = 2 if cfg.expert_gated else 1
@@ -1236,6 +1265,7 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
                       cfg.num_layers * cfg.loop_steps)
         tel.gauge_set("model.kept_core_bytes", kept_core_bytes)
         tel.gauge_set("model.mamba_layers", mamba_layers)
+        tel.gauge_set("model.ssd_kernel_layers", ssd_kernel_layers)
         tel.gauge_set("model.single_sublayer_blocks",
                       cfg.num_layers if cfg.single_sublayer else 0)
         tokens = batch["tokens"]

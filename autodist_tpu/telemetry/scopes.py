@@ -131,7 +131,9 @@ SCOPES: Dict[str, str] = {
            "recurrence, the gated grouped norm, the output projection)",
     SSD_SCAN: "inside mamba: the recurrence's core alone (ops/ssd.py): the "
               "decays, the chunked dual form's four products, the states "
-              "carried from chunk to chunk and D x",
+              "carried from chunk to chunk and D x (the ssd_fwd / ssd_bwd "
+              "kernels and the layout passes made for them where the heads "
+              "fill whole tiles, the jnp form elsewhere)",
     LOOP: "inside blocks: the passes of a looped model (LMConfig.loop_steps), "
           "every block and the final norm once a pass over shared weights "
           "(one body traced, compiled as straight-line code)",

@@ -47,6 +47,12 @@ KEYE = cell(562290560, 8192, routed=5, held=(16, 2048, 768),
 KIMI = cell(602434432, 8192, dense=1, width=9216, routed=4,
             held=(8, 2304, 1024), cores=cores(8192, 1, 32, 192, 128),
             d=2304, shared=1024)
+# (experts without a gate: ONE kept product; the four scans' y and entering
+# states are cores too, ``lm.ssd_kept_bytes``)
+NEMOTRON = dict(cell(566838016, 8192, routed=3, held=(8, 2688, 1856),
+                     cores=cores(8192, 1, 32, 128, 128)
+                     + 4 * lm.ssd_kept_bytes(1, 8192, 64, 64, 128, 128),
+                     d=2688, shared=3712), expert_products=1)
 # (their blocks are not recomputed: 16 B a parameter x 2 is under the chip)
 OLMOE = cell(625741824, 8192, routed=1, cores=0, d=2048, remat=False)
 LM1B = cell(304209775, 16384, d=1024, remat=False)
@@ -61,6 +67,13 @@ LM1B = cell(304209775, 16384, d=1024, remat=False)
     ("keye_vl2_train_1chip: no dense layer, no shared expert",
      KEYE, (5, 0, 0, 0)),
     ("kimi_linear_train_1chip: its shared expert too", KIMI, (4, 1, 0, 4)),
+    ("nemotron_twotower_train_1chip: the scans' 0.8 GB beside the flash "
+     "core's, and still all three of both", NEMOTRON, (3, 0, 0, 3)),
+    ("... a fuller chip pays for the scans with shared experts' layers",
+     dict(NEMOTRON, param_count=865.8e6), (3, 0, 0, 1)),
+    ("... which the flash core alone would have left",
+     dict(NEMOTRON, param_count=865.8e6,
+          core_bytes=cores(8192, 1, 32, 128, 128)), (3, 0, 0, 3)),
     ("olmoe_train_1chip: nothing is recomputed", OLMOE, (0, 0, 0, 0)),
     ("lm1b_train_1chip: nothing is recomputed", LM1B, (0, 0, 0, 0)),
     ("lm1b_train_4chip_ar: nothing is recomputed",
@@ -91,6 +104,7 @@ def test_each_tenant_takes_what_those_before_it_leave(what, inputs, kept):
     if not any(kept):
         return
     itemsize, tokens = inputs.get("itemsize", 2), inputs["tokens"]
+    products = inputs.get("expert_products", 2)
     # (the cores are charged against what follows the experts only)
     booked = 12 * inputs["param_count"] \
         + any(got[1:]) * inputs["core_bytes"] + inputs["loop_steps"] * (
@@ -99,10 +113,10 @@ def test_each_tenant_takes_what_those_before_it_leave(what, inputs, kept):
             + got.sublayer_outs * lm.sublayer_out_kept_bytes(
                 tokens, inputs["d_model"], itemsize)
             + got.shared * lm.dense_kept_bytes(
-                tokens, inputs["shared_width"], itemsize))
+                tokens, inputs["shared_width"], itemsize, products))
     if got.experts:
         booked += got.experts * lm.held_expert_kept_bytes(
-            tokens, inputs["held_stack"], itemsize)
+            tokens, inputs["held_stack"], itemsize, products)
     assert booked <= (1 - lm.KEPT_EXPERTS_HBM_LEFT) * inputs["hbm_bytes"]
     # ... and never more than the 4 B a parameter that left the chip over
     # what the 16 B line had booked

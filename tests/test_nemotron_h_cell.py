@@ -243,7 +243,16 @@ def test_the_programs_own_rules_decide_this_cells_step():
     assert CONFIG["vocab_size"] < 32768     # the bytes engage it, not the rows
     cfg = family.model_config(CONFIG, 8192)
     assert lm.routed_layer_indices(cfg) == (1, 3, 6)
-    core = lm.flash_kept_bytes(8192, 32, 128, 128)
+    # what the cores keep by name in any case: the one attention layer's q,
+    # o and log-sum-exp, and the four scans' y (67 MB) and entering states
+    # (134 MB): 0.94 GB, booked before the shared experts' room
+    from autodist_tpu.ops import ssd
+    assert ssd.runs_as_kernels(cfg.mamba_head_dim, cfg.ssm_state_size,
+                               cfg.mamba_num_heads // cfg.mamba_n_groups,
+                               cfg.mamba_chunk)
+    core = lm.flash_kept_bytes(8192, 32, 128, 128) + 4 * lm.ssd_kept_bytes(
+        1, 8192, 64, 64, 128, 128)
+    assert core == 135266304 + 4 * (67108864 + 134217728)
     kept = lm.auto_kept_layers(
         True, total, 16e9, 8192, 2, routed_layers=3,
         held_stack=(8, 2688, 1856), shared_width=3712, core_bytes=core,
@@ -279,6 +288,33 @@ def test_the_last_k_routed_layers_are_the_last_k_that_are_routed():
     assert none - one == 1 and none - all3 == 3
 
 
+def test_the_scans_at_the_published_head_shape_are_booked_and_counted():
+    """The tiny model with the cell's Mamba sizes (64 heads of 64 over 8
+    groups of 128 states, chunks of 128) and every block recomputed, traced
+    and not run: all four scans take the kernels, and what they keep by
+    name is booked beside the flash core's, by closed form."""
+    cfg = tiny_config(mamba_num_heads=64, mamba_head_dim=64,
+                      mamba_n_groups=8, ssm_state_size=128, mamba_chunk=128)
+    chip = lm._chip_hbm_bytes
+    lm._chip_hbm_bytes = lambda: 1e5
+    try:
+        loss_fn, params, batch, _ = lm.make_train_setup(
+            cfg, seq_len=48, batch_size=2, seed=0, attention="flash")
+    finally:
+        lm._chip_hbm_bytes = chip
+    telemetry.reset()
+    jax.eval_shape(loss_fn, params, batch)
+    gauges = telemetry.get_recorder().gauges()
+    assert gauges["model.remat_blocks"] == 8
+    assert gauges["model.mamba_layers"] == 4
+    assert gauges["model.ssd_kernel_layers"] == 4
+    # y of the 48 tokens' one padded chunk in float32, one entering state
+    a_scan = 2 * 64 * 64 * (128 * 4 + 1 * 128 * 4)
+    assert lm.ssd_kept_bytes(2, 48, 64, 64, 128, 128, 4) == a_scan
+    assert gauges["model.kept_core_bytes"] == lm.flash_kept_bytes(
+        2 * 48, 4, 12, 12, 4) + 4 * a_scan
+
+
 def test_the_gauges_count_the_layers_by_kind():
     loss_fn, params, batch, _ = lm.make_train_setup(
         tiny_config(), seq_len=16, batch_size=1, seed=0, attention="flash")
@@ -287,6 +323,7 @@ def test_the_gauges_count_the_layers_by_kind():
     gauges = telemetry.get_recorder().gauges()
     assert gauges["attention.flash_layers"] == 1
     assert gauges["model.mamba_layers"] == 4
+    assert gauges["model.ssd_kernel_layers"] == 0       # heads of 8: jnp
     assert gauges["model.single_sublayer_blocks"] == 8
     assert gauges["model.kept_expert_layers"] == 0      # no TPU: no recompute
     assert loss_fn.device_counters == (
