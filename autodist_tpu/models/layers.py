@@ -279,17 +279,12 @@ class MultiHeadAttention(nn.Module):
             v_cache = jnp.where(sel, v.astype(v_cache.dtype), v_cache)
             attn = (flash_cached_attention if self.decode_attn == "flash"
                     else cached_attention)
-            out = attn(q[:, 0], k_cache, v_cache, cursor)[:, None]
+            with scopes.scope(scopes.ATTN_CORE):
+                out = attn(q[:, 0], k_cache, v_cache, cursor)[:, None]
             new_cache = (k_cache, v_cache)
-        elif self.attn_fn is not None:
-            out = self.attn_fn(q, k, v, mask)
         else:
-            scale = 1.0 / np.sqrt(self.head_dim)
-            logits = jnp.einsum("...qhd,...khd->...hqk", q, k) * scale
-            if mask is not None:
-                logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
-            weights = nn.softmax(logits.astype(jnp.float32)).astype(self.dtype)
-            out = jnp.einsum("...hqk,...khd->...qhd", weights, v)
+            with scopes.scope(scopes.ATTN_CORE):
+                out = self._plain_core(q, k, v, mask)
         out = nn.DenseGeneral(features=d_model, axis=(-2, -1),
                               dtype=self.dtype, use_bias=self.use_bias,
                               name="out")(out)
@@ -298,6 +293,18 @@ class MultiHeadAttention(nn.Module):
         if return_kv:
             return out, (k, v)
         return out
+
+    def _plain_core(self, q, k, v, mask):
+        """The core over as many K/V heads as query heads: ``attn_fn`` or
+        XLA's scores."""
+        if self.attn_fn is not None:
+            return self.attn_fn(q, k, v, mask)
+        scale = 1.0 / np.sqrt(self.head_dim)
+        logits = jnp.einsum("...qhd,...khd->...hqk", q, k) * scale
+        if mask is not None:
+            logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
+        weights = nn.softmax(logits.astype(jnp.float32)).astype(self.dtype)
+        return jnp.einsum("...hqk,...khd->...qhd", weights, v)
 
     def _grouped_or_chosen(self, x, q, k, v, mask, positions):
         """The core over K/V heads that groups of query heads share and,
@@ -309,7 +316,7 @@ class MultiHeadAttention(nn.Module):
         if self.indexer is not None:
             chosen = SparseIndexer(self.indexer, self.rope_theta,
                                    name="indexer")(x, positions)
-        with scopes.scope(scopes.DSA_CORE):
+        with scopes.scope(scopes.ATTN_CORE), scopes.scope(scopes.DSA_CORE):
             if self.attn_fn is not None:
                 # (an attention function that knows no selection still
                 # serves grouped heads)
@@ -763,7 +770,7 @@ class LatentAttention(nn.Module):
         # (both scale the scores by 1 / sqrt(nope + rope), on top of
         # YaRN's factor above, and take values narrower than the scores'
         # features)
-        with scopes.scope(scopes.MLA_CORE):
+        with scopes.scope(scopes.ATTN_CORE), scopes.scope(scopes.MLA_CORE):
             out = (self.attn_fn or reference_attention)(q, k, v, mask)
         return dense(x.shape[-1], "o_proj")(
             out.reshape(out.shape[:-2] + (H * c.v_head_dim,)))
@@ -866,16 +873,18 @@ class TransformerBlock(nn.Module):
     def _ffn_sublayer(self, x, deterministic):
         h = make_norm(self.norm, self.norm_eps, self.dtype)(x)
         if self.dense_dim:
-            h = SwiGLU(self.dense_dim, self.dtype, DENSE_FFN_KEPT,
-                       name="mlp")(h)
+            with scopes.scope(scopes.DENSE_FFN):
+                h = SwiGLU(self.dense_dim, self.dtype, DENSE_FFN_KEPT,
+                           name="mlp")(h)
         elif self.num_experts:
             h = MoEFeedForward(self.num_experts, self.experts_per_token,
                                self.mlp_dim, self.dtype, self.router,
                                name="moe")(h)
         else:
-            h = nn.Dense(self.mlp_dim, dtype=self.dtype)(h)
-            h = nn.gelu(h)
-            h = nn.Dense(x.shape[-1], dtype=self.dtype)(h)
+            with scopes.scope(scopes.DENSE_FFN):
+                h = nn.Dense(self.mlp_dim, dtype=self.dtype)(h)
+                h = nn.gelu(h)
+                h = nn.Dense(x.shape[-1], dtype=self.dtype)(h)
         if self.sandwich_norm:
             h = make_norm(self.norm, self.norm_eps, self.dtype,
                           "mlp_out_norm")(

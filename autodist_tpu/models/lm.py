@@ -703,7 +703,8 @@ class TransformerLM(nn.Module):
     def __call__(self, input_ids):
         """Logits [B, S, vocab]; a looped model's are the LAST pass's."""
         h = self.hidden(input_ids)
-        return self._head(h[-1] if self.config.loop_steps > 1 else h)
+        with scopes.scope(scopes.PLAIN_HEAD):
+            return self._head(h[-1] if self.config.loop_steps > 1 else h)
 
     @nn.compact
     def prefill(self, input_ids, length):
@@ -1227,9 +1228,11 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
             from autodist_tpu.ops.xent import chunked_softmax_xent
             nll = chunked_softmax_xent(stack, kernel, bias, picked)
         else:
-            logp = jax.nn.log_softmax(
-                jnp.dot(stack.astype(jnp.float32), kernel) + bias)
-            nll = -jnp.take_along_axis(logp, picked[:, None], axis=-1)[:, 0]
+            with scopes.scope(scopes.PLAIN_HEAD):
+                logp = jax.nn.log_softmax(
+                    jnp.dot(stack.astype(jnp.float32), kernel) + bias)
+                nll = -jnp.take_along_axis(
+                    logp, picked[:, None], axis=-1)[:, 0]
         nll = nll.reshape(T, -1)
         with scopes.scope(scopes.EXIT_GATE):
             gate = params["params"]["exit_gate"]
@@ -1251,9 +1254,6 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         # what the rule decided, once per trace, host side
         tel.gauge_set("attention.flash_layers", flash_layers)
         tel.gauge_set("attention.kda_kernel_layers", kda_kernel_layers)
-        # (one rule for both: where the delta rule runs as kernels, the
-        # mixer's element-wise passes around it run fused)
-        tel.gauge_set("attention.kda_fused_mixer_layers", kda_kernel_layers)
         tel.gauge_set("model.remat_blocks",
                       cfg.num_layers if remat_blocks else 0)
         for what, layers, nbytes in zip(("expert", "dense", "sublayer_out",
@@ -1282,8 +1282,10 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
                 bias, targets.reshape(-1))
             return mean_loss(nll, router_loss)
         logits, router_loss = forward(params, tokens[:, :-1], None)
-        logp = jax.nn.log_softmax(logits)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        with scopes.scope(scopes.PLAIN_HEAD):
+            logp = jax.nn.log_softmax(logits)
+            nll = -jnp.take_along_axis(
+                logp, targets[..., None], axis=-1)[..., 0]
         return mean_loss(nll, router_loss)
 
     declared = []
@@ -1385,8 +1387,10 @@ def make_sp_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         local_len = tokens.shape[1]
         logits = sp_model.apply(params, tokens)
         targets = sequence.shift_left(tokens, const.SEQUENCE_AXIS, axis=1)
-        logp = jax.nn.log_softmax(logits)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        with scopes.scope(scopes.PLAIN_HEAD):
+            logp = jax.nn.log_softmax(logits)
+            nll = -jnp.take_along_axis(
+                logp, targets[..., None], axis=-1)[..., 0]
         # mask the final GLOBAL position (its target wrapped around)
         pos = jnp.arange(local_len) + sequence.position_offset(
             local_len, const.SEQUENCE_AXIS)

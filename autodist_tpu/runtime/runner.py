@@ -1081,7 +1081,10 @@ class Runner:
         the donated program that actually runs in steady state;
         ``fuse_steps=k`` analyzes the fused superstep program (whose
         un-donated carry is the ``ADT503`` hazard). See
-        docs/performance.md for reading the report and sizing budgets."""
+        docs/performance.md for reading the report and sizing budgets.
+        This is the lint BEFORE any compile, an estimate;
+        ``telemetry.step_account()`` is the compiler's own word on the
+        program that runs, read off the compile the scope map pays."""
         from autodist_tpu.analysis import hlo as hlo_lib
         from autodist_tpu.analysis import memory as memory_lib
         text = self.lowered_text(batch, state, fuse_steps=fuse_steps,

@@ -11,7 +11,9 @@ The runtime observability layer (docs/observability.md):
 - :mod:`~autodist_tpu.telemetry.scopes` — the one table of
   ``jax.named_scope`` names inside the compiled programs, and
   :func:`scope_map`: HLO instruction name → ``op_name`` of a running
-  program, by its XLA module name (computed on demand);
+  program, by its XLA module name (computed on demand), and
+  :func:`step_account`: what the same compile says the program holds on
+  a device (scratch, arguments, outputs, generated code);
 - :mod:`~autodist_tpu.telemetry.export` — Chrome-trace/Perfetto JSON,
   Prometheus ``metrics_text()``, and cross-process publish/scrape over
   the coordination service;
@@ -35,7 +37,8 @@ from autodist_tpu.telemetry.spans import (  # noqa: F401
     gauge_set, get_recorder, instant, reset, setup_account, span,
     tracing_enabled)
 from autodist_tpu.telemetry.scopes import (  # noqa: F401
-    SCOPES, register_program, registered_programs, scope, scope_map)
+    SCOPES, register_program, registered_programs, scope, scope_map,
+    step_account)
 from autodist_tpu.telemetry.export import (  # noqa: F401
     chrome_trace, merge_traces, metrics_text, publish_telemetry,
     scrape_cluster, validate_chrome_trace, write_trace)
@@ -56,7 +59,7 @@ __all__ = [
     "current_span_id", "gauge_set", "get_recorder", "instant", "reset",
     "setup_account", "span", "tracing_enabled",
     "SCOPES", "register_program", "registered_programs", "scope",
-    "scope_map",
+    "scope_map", "step_account",
     "chrome_trace", "merge_traces", "metrics_text", "publish_telemetry",
     "scrape_cluster", "validate_chrome_trace", "write_trace",
     "DriftReport", "build_report", "fit_calibration", "report_for_runner",

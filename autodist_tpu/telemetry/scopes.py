@@ -2,7 +2,7 @@
 trace to them.
 
 Host spans (:mod:`~autodist_tpu.telemetry.spans`) say what the host did;
-this module is the device-side half. Two pieces:
+this module is the device-side half. Three pieces:
 
 - **One table of scope names** (:data:`SCOPES`) and :func:`scope`, the
   only place in the package that calls ``jax.named_scope``. A scope is
@@ -20,6 +20,10 @@ this module is the device-side half. Two pieces:
   theirs under the XLA module's name; the map is computed ON DEMAND —
   one extra lowering and compile, with both compile caches bypassed —
   and never on a step's path.
+- **The step account** (:func:`step_account`): what that same compile
+  says the program holds on a device (scratch, arguments, outputs,
+  aliases, generated code), beside the map. The compile is done once per
+  registered program and both products are kept.
 
 Why the caches are bypassed: ``jax_compilation_cache_include_metadata_in_key``
 is False, so the persistent cache hands a process an executable that an
@@ -52,6 +56,9 @@ LEAN_HEAD_BWD = "lean_head_bwd"
 EMBED = "embed"
 BLOCKS = "blocks"
 ATTENTION = "attention"
+ATTN_CORE = "attn_core"
+DENSE_FFN = "dense_ffn"
+PLAIN_HEAD = "plain_head"
 MOE = "moe"
 MOE_ROUTE = "moe_route"
 MOE_EXPERTS = "moe_experts"
@@ -90,6 +97,19 @@ SCOPES: Dict[str, str] = {
     EMBED: "token and position embedding of the LM",
     BLOCKS: "the transformer blocks of the LM",
     ATTENTION: "multi-head attention inside a block",
+    ATTN_CORE: "inside attention: every call of a softmax attention core on "
+               "q, k, v, whatever the mixer (plain heads, grouped or chosen "
+               "keys with dsa_core, a latent mixer's with mla_core, the "
+               "cached decode step's): the flash kernels on a TPU, XLA's "
+               "scores elsewhere; KDA, the short convolution and Mamba-2 "
+               "have no such core",
+    DENSE_FFN: "inside blocks: a block's DENSE feed-forward alone, the "
+               "SwiGLU of a leading dense layer or the two-matmul GELU one; "
+               "its pre-norm, a sandwich norm, dropout and the residual add "
+               "lie outside, a shared expert stays under moe_shared",
+    PLAIN_HEAD: "inside loss: the head that makes the full [tokens, vocab] "
+                "logits and the log-softmax and target pick that follow it "
+                "(a step has this or the lean head's two)",
     MOE: "the routed feed-forward of a block (parallel/expert.py "
          "dropless_moe_ffn), its router losses included",
     MOE_ROUTE: "inside moe: router matmul, softmax, top-k, the sort by "
@@ -172,7 +192,7 @@ def scoped(name: str):
 # not keep it alive), the newest registration of a name wins.
 
 _programs: Dict[str, Callable[[], Optional[Callable]]] = {}
-_maps: Dict[str, Dict[str, List[str]]] = {}
+_accounts: Dict[str, dict] = {}  # name -> step_account()'s value
 _lock = threading.Lock()
 
 
@@ -180,13 +200,13 @@ def register_program(module_name: str, lower: Callable) -> None:
     """Make ``module_name`` (``"jit_local_step"``) inspectable.
 
     ``lower()`` returns the program's ``jax.stages.Lowered`` for the
-    arguments it runs with; it is called only by :func:`scope_map`.
-    A bound method is held weakly."""
+    arguments it runs with; it is called only by :func:`scope_map` and
+    :func:`step_account`. A bound method is held weakly."""
     ref = (weakref.WeakMethod(lower) if hasattr(lower, "__self__")
            else (lambda: lower))
     with _lock:
         _programs[module_name] = ref
-        _maps.pop(module_name, None)
+        _accounts.pop(module_name, None)
 
 
 def registered_programs() -> List[str]:
@@ -216,34 +236,72 @@ def _persistent_cache_bypassed():
         cc.reset_cache()
 
 
-def compiled_text(module_name: str) -> Optional[str]:
-    """Optimized HLO text of a registered program, from a compile of
+def compiled(module_name: str):
+    """A registered program's ``jax.stages.Compiled``, from a compile of
     THIS process's lowering with the persistent cache bypassed; None
-    where no live owner registered the name."""
+    where no live owner registered the name. The one place that pays."""
     with _lock:
         ref = _programs.get(module_name)
     lower = ref() if ref is not None else None
     if lower is None:
         return None
     with _persistent_cache_bypassed():
-        return lower().compile(compiler_options=_FRESH_COMPILE).as_text()
+        return lower().compile(compiler_options=_FRESH_COMPILE)
+
+
+# the account's name of a field -> ``CompiledMemoryStats``'s
+_MEMORY_FIELDS = {"temp_bytes": "temp_size_in_bytes",
+                  "argument_bytes": "argument_size_in_bytes",
+                  "output_bytes": "output_size_in_bytes",
+                  "alias_bytes": "alias_size_in_bytes",
+                  "code_bytes": "generated_code_size_in_bytes",
+                  "peak_bytes": "peak_memory_in_bytes"}
+
+
+def _memory(fresh) -> Optional[Dict[str, Optional[int]]]:
+    """``Compiled.memory_analysis()`` field for field (a field the
+    backend does not give is None); None where it gives no analysis."""
+    stats = fresh.memory_analysis()
+    if stats is None:
+        return None
+    return {ours: getattr(stats, xla, None)
+            for ours, xla in _MEMORY_FIELDS.items()}
+
+
+def step_account(module_name: str = "jit_local_step") -> Optional[dict]:
+    """What the compiled program holds, read off ONE fresh compile on first
+    use and kept until the name is registered anew:
+    ``{"module", "memory", "instructions"}``. ``memory`` is the COMPILER's
+    word on the program that runs, per device as XLA reports it for the
+    partitioned program: ``temp_bytes`` (the step's scratch, which
+    ``memory_stats()`` leaves out), ``argument_bytes``, ``output_bytes``,
+    ``alias_bytes`` (outputs that reuse an argument's buffer),
+    ``code_bytes`` and ``peak_bytes`` (None where the backend gives none);
+    None for ``memory`` on a backend without an analysis, never a guess.
+    ``instructions`` is :func:`scope_map`'s value: asking for either after
+    the other compiles nothing more. None where the program is not
+    registered."""
+    with _lock:
+        got = _accounts.get(module_name)
+    if got is not None:
+        return got
+    fresh = compiled(module_name)
+    if fresh is None:
+        return None
+    got = {"module": module_name, "memory": _memory(fresh),
+           "instructions": parse_scope_map(fresh.as_text())}
+    with _lock:
+        _accounts[module_name] = got
+    return got
 
 
 def scope_map(module_name: str) -> Optional[Dict[str, List[str]]]:
     """``{HLO instruction name: [op_name, ...]}`` of a registered program
     (see :func:`parse_scope_map` for the value), computed on first use
-    and kept; None where the program is not registered."""
-    with _lock:
-        got = _maps.get(module_name)
-    if got is not None:
-        return got
-    text = compiled_text(module_name)
-    if text is None:
-        return None
-    got = parse_scope_map(text)
-    with _lock:
-        _maps[module_name] = got
-    return got
+    and kept (the account's ``instructions``); None where the program is
+    not registered."""
+    account = step_account(module_name)
+    return None if account is None else account["instructions"]
 
 
 # ------------------------------------------------------------ HLO parsing

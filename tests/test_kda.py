@@ -170,14 +170,13 @@ GAUGE_CASES = {
 }
 
 
-@pytest.mark.parametrize("gauge", ["attention.kda_kernel_layers",
-                                   "attention.kda_fused_mixer_layers"])
+@pytest.mark.parametrize("gauge", ["attention.kda_kernel_layers"])
 @pytest.mark.parametrize("cell", sorted(GAUGE_CASES))
 def test_the_gauge_says_how_many_layers_took_the_kda_kernels(cell, gauge):
-    """``attention.kda_kernel_layers`` and ``attention.kda_fused_mixer_layers``
-    (the delta rule as kernels, the element-wise passes around it fused)
-    are set when the loss is traced, from what ``runs_as_kernels`` said of
-    the configuration's KDA heads."""
+    """``attention.kda_kernel_layers`` (the delta rule as kernels, and with
+    it the element-wise passes around it fused: one rule for both) is set
+    when the loss is traced, from what ``runs_as_kernels`` said of the
+    configuration's KDA heads."""
     assert gauges_of_a_traced_loss(cell)[gauge] == GAUGE_CASES[cell][1]
 
 

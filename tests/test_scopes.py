@@ -14,8 +14,13 @@ The tracing contracts (``telemetry/scopes.py``, docs/observability.md
   ``attention``, recomputes neither of the first two, and hands out the
   pairs its indexers chose; an LFM2 model's step has ``conv_mix`` >
   ``conv_core`` inside ``attention``, the projections outside the core;
+- every matmul of a family's step lies under a sub-layer's name
+  (``attention``, ``moe``, ``dense_ffn``; a head's or the exit gate's in
+  the loss), ``attn_core`` is a second name on every softmax core, a
+  shared expert stays under ``moe_shared``;
 - the map is computed ON DEMAND: a fit, traced or not, lowers and
-  compiles nothing extra;
+  compiles nothing extra; the step account (``telemetry.step_account``)
+  is read off the map's one compile;
 - ``runner.readback`` is tiled by its two children (the wait for the
   device, the copy), every span of a step carries that step's index, and
   a fit of N steps yields N of each;
@@ -31,6 +36,7 @@ The tracing contracts (``telemetry/scopes.py``, docs/observability.md
   outlives ``clear()``, ``reset()`` and a new build drop it, a recompile
   after set-up is the window's, and with tracing off there is none.
 """
+import contextlib
 import glob
 import re
 
@@ -99,15 +105,133 @@ def under(scope_map, *wanted, in_pass=None):
 # ------------------------------------------------------------ (a) the map
 
 
-@pytest.mark.parametrize("remat", [False, True])
-def test_step_map_holds_every_scope_and_tells_the_passes_apart(remat):
+@contextlib.contextmanager
+def small_chip():
+    """``lm._chip_hbm_bytes`` made so small that ``auto_remat_blocks``
+    recomputes every block in the backward pass."""
+    chip = lm._chip_hbm_bytes
+    lm._chip_hbm_bytes = lambda: 1e5
+    try:
+        yield
+    finally:
+        lm._chip_hbm_bytes = chip
+
+
+def tiny_lm1b(remat):
     builder = S.WithRemat(S.AllReduce()) if remat else S.AllReduce()
     runner, batch, _ = build_lm(builder, sentinel=True)
     assert bool(runner.distributed_step.strategy.graph_config.remat) == remat
-    runner.run(batch)
-    assert STEP in telemetry.registered_programs()
-    m = telemetry.scope_map(STEP)
-    assert m is telemetry.scope_map(STEP)  # computed once, kept
+    return runner, batch
+
+
+def tiny_kimi_linear():
+    """Two KDA layers, a latent one, a dense and two routed feed-forwards
+    with a shared expert, every block recomputed."""
+    import dataclasses
+    cfg = dataclasses.replace(
+        lm.LMConfig.kimi_linear_48b_a3b(
+            num_layers=3, max_seq_len=32, layer_types=("kda", "mla", "kda")),
+        vocab_size=128, d_model=32, num_heads=2, mlp_dim=16, kda_num_heads=2,
+        kda_head_dim=16, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, dense_dim=64, num_experts=8,
+        experts_per_token=2, experts_held=(0, 1))
+    with small_chip():
+        return lm.make_train_setup(cfg, seq_len=16, batch_size=8)
+
+
+def tiny_deepseek_v2():
+    import dataclasses
+    cfg = dataclasses.replace(
+        lm.LMConfig.deepseek_v2_lite(num_layers=3, max_seq_len=32),
+        vocab_size=128, d_model=32, num_heads=2, mlp_dim=16, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, dense_dim=64,
+        num_experts=8, experts_per_token=2, experts_held=(0, 1))
+    return lm.make_train_setup(cfg, seq_len=16, batch_size=8)
+
+
+def tiny_keye_vl2():
+    from tests.test_keye_vl2 import tiny_config
+    with small_chip():
+        return lm.make_train_setup(tiny_config(indexer_topk=4), seq_len=16,
+                                   batch_size=8)
+
+
+def tiny_lfm2():
+    from tests.test_lfm2_moe import tiny_config
+    with small_chip():
+        return lm.make_train_setup(tiny_config(), seq_len=16, batch_size=8)
+
+
+def tiny_olmoe():
+    from tests.test_olmoe import tiny_config
+    return lm.make_train_setup(tiny_config(), seq_len=16, batch_size=8)
+
+
+def tiny_ouro():
+    from tests.test_ouro import tiny_config
+    with small_chip():
+        return lm.make_train_setup(tiny_config(), seq_len=16, batch_size=8)
+
+
+def tiny_nemotron_h():
+    from tests.test_nemotron_h import tiny_config
+    with small_chip():
+        return lm.make_train_setup(tiny_config(), seq_len=16, batch_size=8)
+
+
+FAMILIES = {"kimi_linear": tiny_kimi_linear, "deepseek_v2": tiny_deepseek_v2,
+            "keye_vl2": tiny_keye_vl2, "lfm2": tiny_lfm2, "olmoe": tiny_olmoe,
+            "ouro": tiny_ouro, "nemotron_h": tiny_nemotron_h}
+STEPS = ["lm1b", "lm1b_remat"] + sorted(FAMILIES)
+
+
+@pytest.fixture(scope="module")
+def step_of():
+    """``step_of(family)``: one step of a tiny model of the family and what
+    the program says of it afterwards: ``{"loss_fn", "counters", "map",
+    "account"}``. Built on first use and kept for the module: every case
+    that reads a family's step map shares ONE build and ONE compile of it."""
+    built = {}
+
+    def get(family):
+        if family not in built:
+            autodist_tpu.reset()
+            if family.startswith("lm1b"):
+                loss_fn = None
+                runner, batch = tiny_lm1b(remat=family == "lm1b_remat")
+            else:
+                loss_fn, params, batch, _ = FAMILIES[family]()
+                ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
+                runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
+                runner.init(params)
+            counters = runner.run(batch).get("counters", {})
+            assert STEP in telemetry.registered_programs()
+            m = telemetry.scope_map(STEP)
+            assert m is telemetry.scope_map(STEP)  # computed once, kept
+            built[family] = {"loss_fn": loss_fn, "counters": counters,
+                             "map": m,
+                             "account": telemetry.step_account(STEP)}
+            autodist_tpu.reset()
+        return built[family]
+    try:
+        yield get
+    finally:
+        autodist_tpu.reset()
+
+
+@pytest.fixture(scope="module")
+def kimi_linear_step_map(step_of):
+    return step_of("kimi_linear")["map"]
+
+
+def paths(scope_map):
+    return [o for ops in scope_map.values() for o in ops]
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_step_map_holds_every_scope_and_tells_the_passes_apart(step_of,
+                                                               remat):
+    m = step_of("lm1b_remat" if remat else "lm1b")["map"]
     # (replicated storage gathers nothing: scopes.PARAMS has its own test)
     for name in (scopes.GRAD_SYNC, scopes.OPTIMIZER, scopes.SENTINEL):
         assert under(m, name), name
@@ -115,20 +239,28 @@ def test_step_map_holds_every_scope_and_tells_the_passes_apart(remat):
     assert under(m, "jvp(loss)", in_pass="fwd")
     assert under(m, "transpose(jvp(loss))", in_pass="bwd")
     # the model's scopes, in both passes
-    for name in (scopes.EMBED, scopes.BLOCKS, scopes.ATTENTION):
+    for name in (scopes.EMBED, scopes.BLOCKS, scopes.ATTENTION,
+                 scopes.ATTN_CORE, scopes.DENSE_FFN):
         # (XLA merges a recomputed op with its forward twin and keeps one
         # of the two names: under remat the lookup shows in one pass only)
         assert under(m, name, in_pass="fwd") or (remat
                                                  and name == scopes.EMBED)
         assert under(m, name, in_pass="bwd"), name
-    # attention sits inside a block
+    # attention sits inside a block, its core inside attention, the dense
+    # feed-forward inside a block and outside attention
     assert all(o.index("/blocks/") < o.index("/attention/")
-               for ops in m.values() for o in ops if "/attention/" in o)
+               for o in paths(m) if "/attention/" in o)
+    assert all(o.index("/attention/") < o.index("/attn_core/")
+               for o in paths(m) if "/attn_core/" in o)
+    assert all(o.index("/blocks/") < o.index("/dense_ffn/")
+               and "/attention/" not in o
+               for o in paths(m) if "/dense_ffn/" in o)
     # the lean head: forward under the forward pass, and the custom_vjp
     # rule, traced at transpose time, under its own scope in the backward
     assert under(m, scopes.LEAN_HEAD, in_pass="fwd")
     head_bwd = under(m, scopes.LEAN_HEAD_BWD, in_pass="bwd")
     assert head_bwd and not under(m, scopes.LEAN_HEAD_BWD, in_pass="fwd")
+    assert not under(m, scopes.PLAIN_HEAD)  # a step has one or the other
     dots = [o for n in head_bwd for o in m[n] if "dot_general" in o
             and scopes.LEAN_HEAD_BWD in components(o)]
     assert dots, "the rule's three chunk matmuls are under its scope"
@@ -139,42 +271,86 @@ def test_step_map_holds_every_scope_and_tells_the_passes_apart(remat):
     step_scopes = {scopes.PARAMS, scopes.LOSS, scopes.GRAD_SYNC,
                    scopes.OPTIMIZER, scopes.SENTINEL, scopes.LEAN_HEAD,
                    scopes.LEAN_HEAD_BWD, scopes.EMBED, scopes.BLOCKS,
-                   scopes.ATTENTION}
+                   scopes.ATTENTION, scopes.ATTN_CORE, scopes.DENSE_FFN,
+                   scopes.PLAIN_HEAD}
     assert step_scopes <= set(scopes.SCOPES)
     with pytest.raises(KeyError):
         scopes.scope("no_such_scope")
 
 
-@pytest.fixture(scope="module")
-def kimi_linear_step_map():
-    """The step map of a tiny Kimi-Linear model (two KDA layers, a latent
-    one, a dense and two routed feed-forwards with a shared expert) whose
-    blocks are recomputed in the backward pass: the chip's memory is made
-    small enough that ``auto_remat_blocks`` says so."""
-    import dataclasses
-    cfg = dataclasses.replace(
-        lm.LMConfig.kimi_linear_48b_a3b(
-            num_layers=3, max_seq_len=32, layer_types=("kda", "mla", "kda")),
-        vocab_size=128, d_model=32, num_heads=2, mlp_dim=16, kda_num_heads=2,
-        kda_head_dim=16, kv_lora_rank=16, qk_nope_head_dim=16,
-        qk_rope_head_dim=8, v_head_dim=16, dense_dim=64, num_experts=8,
-        experts_per_token=2, experts_held=(0, 1))
-    chip = lm._chip_hbm_bytes
-    lm._chip_hbm_bytes = lambda: 1e5
-    try:
-        loss_fn, params, batch, _ = lm.make_train_setup(
-            cfg, seq_len=16, batch_size=8)
-    finally:
-        lm._chip_hbm_bytes = chip
-    autodist_tpu.reset()
-    ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
-    runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
-    runner.init(params)
-    runner.run(batch)
-    try:
-        yield telemetry.scope_map(STEP)
-    finally:
-        autodist_tpu.reset()
+MATMULS = ("dot_general", "conv_general_dilated")
+BLOCK_PARTS = (scopes.ATTENTION, scopes.MOE, scopes.DENSE_FFN)
+HEADS = (scopes.LEAN_HEAD, scopes.LEAN_HEAD_BWD, scopes.PLAIN_HEAD,
+         scopes.EXIT_GATE)
+
+
+@pytest.mark.parametrize("family", STEPS)
+def test_no_matmul_of_a_step_is_without_a_sub_layers_name(step_of, family):
+    """Every matmul and convolution under ``blocks`` lies under
+    ``attention``, ``moe`` or ``dense_ffn`` (what is left of a block, the
+    ``block_rest_ms_per_step`` of the benchmark, holds none), and every
+    one of the loss outside the blocks and the embedding under a head's
+    name or the exit gate's. None of the three names PR 49 added is a
+    flax module's name: a reader matches a whole path component."""
+    m = step_of(family)["map"]
+    matmuls = [o for o in paths(m) if o.endswith(MATMULS)]
+    in_blocks = [o for o in matmuls if scopes.BLOCKS in components(o)]
+    assert in_blocks
+    bare = [o for o in in_blocks
+            if not set(BLOCK_PARTS) & set(components(o))]
+    assert not bare, bare[:3]
+    of_the_loss = [o for o in matmuls if pass_of(o) is not None
+                   and not {scopes.BLOCKS, scopes.EMBED} & set(components(o))]
+    assert of_the_loss
+    bare = [o for o in of_the_loss if not set(HEADS) & set(components(o))]
+    assert not bare, bare[:3]
+    # each new name is on a path only where this tree's scope put it:
+    # outside its outer scope it would be a module's name
+    outer = {scopes.ATTN_CORE: scopes.ATTENTION, scopes.DENSE_FFN:
+             scopes.BLOCKS, scopes.PLAIN_HEAD: "loss"}
+    for name, outside in outer.items():
+        for o in paths(m):
+            if name in components(o):
+                upto = o[:o.index("/" + name + "/")]
+                assert outside in re.split(r"[/()]", upto), o
+    assert under(m, scopes.ATTN_CORE)
+    assert bool(under(m, scopes.LEAN_HEAD)) != bool(
+        under(m, scopes.PLAIN_HEAD))
+
+
+@pytest.mark.parametrize("family, core", [
+    ("deepseek_v2", scopes.MLA_CORE), ("kimi_linear", scopes.MLA_CORE),
+    ("keye_vl2", scopes.DSA_CORE), ("lfm2", scopes.DSA_CORE),
+    ("nemotron_h", scopes.DSA_CORE)])
+def test_the_neutral_core_scope_holds_what_the_named_core_holds(
+        step_of, family, core):
+    """``attn_core`` is a second name on the same ops: every path that
+    holds it holds the family's own core scope and the reverse, so
+    ``attn_core_ms_per_step`` reads what ``mla_core_ms_per_step`` /
+    ``dsa_core_ms_per_step`` read."""
+    m = step_of(family)["map"]
+    both = [o for o in paths(m) if scopes.ATTN_CORE in components(o)]
+    assert both and any(o.endswith("dot_general") for o in both)
+    assert all(core in components(o) for o in both)
+    assert all(scopes.ATTN_CORE in components(o) for o in paths(m)
+               if core in components(o))
+    assert all(components(o).index(scopes.ATTENTION)
+               < components(o).index(scopes.ATTN_CORE)
+               < components(o).index(core) for o in both)
+
+
+@pytest.mark.parametrize("family", ["deepseek_v2", "kimi_linear",
+                                    "nemotron_h"])
+def test_a_shared_expert_stays_the_routed_feed_forwards(step_of, family):
+    """A shared expert is a dense feed-forward module too, and its ops
+    lie under ``moe`` > ``moe_shared``, never under ``dense_ffn``."""
+    m = step_of(family)["map"]
+    shared = [o for o in paths(m) if "shared" in components(o)]
+    assert any(o.endswith("dot_general") for o in shared)
+    assert all(scopes.MOE_SHARED in components(o)
+               and scopes.DENSE_FFN not in components(o) for o in shared)
+    assert not any(scopes.MOE in components(o) for o in paths(m)
+                   if scopes.DENSE_FFN in components(o))
 
 
 @pytest.mark.parametrize("scope, outer", [
@@ -193,11 +369,10 @@ def test_a_mixers_scope_holds_its_forward_backward_and_recomputed_ops(
     assert under(m, scope, "rematted_computation", in_pass="bwd")
     assert not under(m, scope, "rematted_computation", in_pass="fwd")
     # it sits inside its outer scope, inside a block
-    paths = [o for ops in m.values() for o in ops
-             if scope in components(o)]
-    assert paths and all(
+    inside = [o for o in paths(m) if scope in components(o)]
+    assert inside and all(
         components(o).index(scopes.BLOCKS) < components(o).index(outer)
-        < components(o).index(scope) for o in paths)
+        < components(o).index(scope) for o in inside)
 
 
 def test_the_delta_rules_matmuls_are_under_its_scope(kimi_linear_step_map):
@@ -211,32 +386,17 @@ def test_the_delta_rules_matmuls_are_under_its_scope(kimi_linear_step_map):
             and scopes.KDA_SCAN not in components(o)]
 
 
-def test_a_deepseek_v2_step_names_its_attention_cores_and_counts_its_balance_loss():
+def test_a_deepseek_v2_step_names_its_attention_cores_and_counts_its_balance_loss(
+        step_of):
     """``mla_core`` holds the scores' matmuls and the softmax and NOT the
     mixer's projections, rotation or temperature; the step's metrics carry
     the device counter ``moe.aux_loss`` (the routed layers' balance losses
     summed: between 1 a layer, an even router, and E / k)."""
-    import dataclasses
-    cfg = dataclasses.replace(
-        lm.LMConfig.deepseek_v2_lite(num_layers=3, max_seq_len=32),
-        vocab_size=128, d_model=32, num_heads=2, mlp_dim=16, kv_lora_rank=16,
-        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, dense_dim=64,
-        num_experts=8, experts_per_token=2, experts_held=(0, 1))
-    loss_fn, params, batch, _ = lm.make_train_setup(
-        cfg, seq_len=16, batch_size=8)
-    assert "moe.aux_loss" in loss_fn.device_counters
-    autodist_tpu.reset()
-    try:
-        ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
-        runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
-        runner.init(params)
-        counters = runner.run(batch)["counters"]
-        m = telemetry.scope_map(STEP)
-    finally:
-        autodist_tpu.reset()
+    step = step_of("deepseek_v2")
+    assert "moe.aux_loss" in step["loss_fn"].device_counters
+    counters, m = step["counters"], step["map"]
     assert 2 * 1.0 <= float(counters["moe.aux_loss"]) <= 2 * 8 / 2
-    core = [o for ops in m.values() for o in ops
-            if scopes.MLA_CORE in components(o)]
+    core = [o for o in paths(m) if scopes.MLA_CORE in components(o)]
     assert core and all(
         components(o).index(scopes.ATTENTION) < components(o).index(scopes.MLA)
         < components(o).index(scopes.MLA_CORE) for o in core)
@@ -244,15 +404,15 @@ def test_a_deepseek_v2_step_names_its_attention_cores_and_counts_its_balance_los
     assert under(m, scopes.MLA_CORE, in_pass="fwd")
     assert under(m, scopes.MLA_CORE, in_pass="bwd")
     # the projections and the rotation are the mixer's, not the core's
-    outside = [o for ops in m.values() for o in ops
-               if scopes.MLA in components(o)
+    outside = [o for o in paths(m) if scopes.MLA in components(o)
                and scopes.MLA_CORE not in components(o)]
     assert any("dot_general" in o for o in outside)
     assert any(o.endswith(("/cos", "/sin")) for o in outside)
     assert not any(o.endswith(("/cos", "/sin")) for o in core)
 
 
-def test_a_keye_vl2_step_names_its_indexer_choice_and_core_and_counts_the_pairs():
+def test_a_keye_vl2_step_names_its_indexer_choice_and_core_and_counts_the_pairs(
+        step_of):
     """``dsa_index`` holds the indexer's projections and index scores,
     ``dsa_topk`` the choice alone (no matmul) and ``dsa_core`` the
     attention function's call (scores and softmax, NOT the q/k/v
@@ -260,31 +420,15 @@ def test_a_keye_vl2_step_names_its_indexer_choice_and_core_and_counts_the_pairs(
     block recomputed in the backward pass no recomputed op is the
     indexer's or the choice's (the selection is kept by name); the step's
     metrics carry ``dsa.selected_pairs`` and ``dsa.causal_pairs``."""
-    from tests.test_keye_vl2 import tiny_config
-    cfg = tiny_config(indexer_topk=4)
-    chip = lm._chip_hbm_bytes
-    lm._chip_hbm_bytes = lambda: 1e5
-    try:
-        loss_fn, params, batch, _ = lm.make_train_setup(
-            cfg, seq_len=16, batch_size=8)
-    finally:
-        lm._chip_hbm_bytes = chip
+    step = step_of("keye_vl2")
     assert {"dsa.selected_pairs", "dsa.causal_pairs"} <= set(
-        loss_fn.device_counters)
-    autodist_tpu.reset()
-    try:
-        ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
-        runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
-        runner.init(params)
-        counters = runner.run(batch)["counters"]
-        m = telemetry.scope_map(STEP)
-    finally:
-        autodist_tpu.reset()
+        step["loss_fn"].device_counters)
+    counters, m = step["counters"], step["map"]
     # a replica's row of 16 positions (8 rows over 8 devices), two layers:
     # 4 keys a query from position 3 on
     assert int(counters["dsa.causal_pairs"]) == 2 * 16 * 17 // 2
     assert int(counters["dsa.selected_pairs"]) == 2 * (4 * 5 // 2 + 12 * 4)
-    ops = [o for names in m.values() for o in names]
+    ops = paths(m)
     for scope in (scopes.DSA_INDEX, scopes.DSA_TOPK, scopes.DSA_CORE):
         assert scope in scopes.SCOPES
         # (a reduction's own scalar computation inside a loop's body is
@@ -309,37 +453,24 @@ def test_a_keye_vl2_step_names_its_indexer_choice_and_core_and_counts_the_pairs(
     assert not any("rematted_computation" in o or "transpose(" in o
                    for o in index + topk)
     assert any("transpose(" in o for o in core)
+    # the indexer is no part of the core under its neutral name either
+    assert not any(scopes.ATTN_CORE in components(o) for o in index + topk)
 
 
-def test_an_lfm2_step_names_its_conv_mixers_and_their_cores():
+def test_an_lfm2_step_names_its_conv_mixers_and_their_cores(step_of):
     """``conv_mix`` holds a gated short convolution whole (both
     projections' matmuls), ``conv_core`` what lies between them (the gates
     and the 3-tap convolution: no matmul), each inside ``attention``, so
     ``attn_ms_per_step`` stays the mixers' total; the grouped core of the
     one attention layer is under ``dsa_core``; with every block recomputed
     the core is forward, backward and recomputed."""
-    from tests.test_lfm2_moe import tiny_config
-    chip = lm._chip_hbm_bytes
-    lm._chip_hbm_bytes = lambda: 1e5
-    try:
-        loss_fn, params, batch, _ = lm.make_train_setup(
-            tiny_config(), seq_len=16, batch_size=8)
-    finally:
-        lm._chip_hbm_bytes = chip
-    assert loss_fn.device_counters == (
+    step = step_of("lfm2")
+    assert step["loss_fn"].device_counters == (
         "moe.max_expert_pairs", "moe.routed_pairs", "moe.chosen_pairs")
-    autodist_tpu.reset()
-    try:
-        ad = autodist_tpu.AutoDist(strategy_builder=S.AllReduce())
-        runner = ad.build(loss_fn, optax.adam(1e-3), params, batch)
-        runner.init(params)
-        counters = runner.run(batch)["counters"]
-        m = telemetry.scope_map(STEP)
-    finally:
-        autodist_tpu.reset()
+    counters, m = step["counters"], step["map"]
     # a replica's row of 16 positions, four routed layers, top-3
     assert int(counters["moe.chosen_pairs"]) == 4 * 16 * 3
-    ops = [o for names in m.values() for o in names]
+    ops = paths(m)
     for scope in (scopes.CONV_MIX, scopes.CONV_CORE):
         assert scope in scopes.SCOPES
         inside = [o for o in ops if scope in components(o)
@@ -359,6 +490,8 @@ def test_an_lfm2_step_names_its_conv_mixers_and_their_cores():
     grouped = [o for o in ops if scopes.DSA_CORE in components(o)]
     assert any("dot_general" in o for o in grouped)
     assert not any(scopes.CONV_MIX in components(o) for o in grouped)
+    # a convolution has no softmax core
+    assert not any(scopes.ATTN_CORE in components(o) for o in mix)
 
 
 def test_partitioned_storage_gathers_under_the_params_scope():
@@ -390,6 +523,53 @@ def test_map_names_are_the_running_executables_instructions():
     assert telemetry.scope_map("jit_no_such_program") is None
 
 
+def test_the_step_account_is_read_off_the_maps_one_compile():
+    """``step_account`` after ``scope_map`` and the reverse compile
+    nothing more; its memory is the compiler's analysis of the program
+    that runs, field for field; a new registration drops both; a name
+    nobody registered gives None."""
+    tel.configure("1")
+    runner, batch, _ = build_lm()
+    runner.run(batch)
+    compiles = lambda: tel.counters().get("compile.backend_compiles", 0)  # noqa: E731
+    before = compiles()
+    m = telemetry.scope_map(STEP)
+    assert compiles() == before + 1
+    account = telemetry.step_account()       # the step's module by default
+    assert compiles() == before + 1
+    assert account["module"] == STEP and account["instructions"] is m
+    stats = runner.distributed_step._step_fn.lower(
+        runner.state, {}, runner.remapper.remap_feed(batch)
+    ).compile().memory_analysis()
+    assert account["memory"] == {
+        "temp_bytes": stats.temp_size_in_bytes,
+        "argument_bytes": stats.argument_size_in_bytes,
+        "output_bytes": stats.output_size_in_bytes,
+        "alias_bytes": stats.alias_size_in_bytes,
+        "code_bytes": stats.generated_code_size_in_bytes,
+        "peak_bytes": stats.peak_memory_in_bytes}
+    assert account["memory"]["temp_bytes"] > 0
+    assert account["memory"]["argument_bytes"] > 0
+    # the reverse order, on a program nobody asked about yet
+    before = compiles()
+    ev = telemetry.step_account("jit_local_eval")
+    assert compiles() == before + 1
+    assert telemetry.scope_map("jit_local_eval") is ev["instructions"]
+    assert compiles() == before + 1
+    # a new registration of the name drops the account with the map
+    scopes.register_program(STEP, runner._lower_step)
+    assert STEP not in scopes._accounts
+    again = telemetry.step_account(STEP)
+    # (JAX answers the same lowering under the same options from memory)
+    assert again["instructions"] is not m and again["instructions"] == m
+    assert again["memory"] == account["memory"]
+    assert telemetry.step_account("jit_no_such_program") is None
+    # a backend without an analysis gives None for the memory, no guess
+    import types
+    assert scopes._memory(types.SimpleNamespace(
+        memory_analysis=lambda: None)) is None
+
+
 def test_map_survives_an_executable_from_another_trees_cache(tmp_path,
                                                              monkeypatch):
     """The hazard: metadata is no part of the persistent cache's key, so a
@@ -397,7 +577,6 @@ def test_map_survives_an_executable_from_another_trees_cache(tmp_path,
     tree's scopes; and ``lowered.compile()`` of what the jit ran is
     answered from memory with that same executable. The map must come
     from a compile of its own."""
-    import contextlib
     from jax.experimental.compilation_cache import compilation_cache as cc
     before = {k: getattr(jax.config, k) for k in (
         "jax_compilation_cache_dir",
@@ -480,17 +659,20 @@ def test_a_fit_lowers_and_compiles_nothing_extra(mode, monkeypatch):
         monkeypatch.setattr(
             Runner, name,
             lambda self, _o=orig, _n=name: lowered.append(_n) or _o(self))
-    orig_text = scopes.compiled_text
-    monkeypatch.setattr(scopes, "compiled_text",
-                        lambda n: compiled.append(n) or orig_text(n))
+    orig_compiled = scopes.compiled
+    monkeypatch.setattr(scopes, "compiled",
+                        lambda n: compiled.append(n) or orig_compiled(n))
     tel.configure(mode)
     runner, batch, _ = build_lm()
     runner.fit([batch] * 4)
     dstep = runner.distributed_step
     assert lowered == [] and compiled == []
     assert dstep._step_fn._cache_size() == 1
-    # asking is what costs: one lowering, one compile, then kept
+    assert STEP not in scopes._accounts  # nobody asked: no account
+    # asking is what costs: one lowering, one compile, then kept, for the
+    # map and the account together
     assert telemetry.scope_map(STEP) and telemetry.scope_map(STEP)
+    assert telemetry.step_account(STEP)["memory"]
     assert lowered == ["_lower_step"] and compiled == [STEP]
     assert dstep._step_fn._cache_size() == 1  # the jit's cache is untouched
     assert jax.config.jax_enable_compilation_cache  # and the flag restored
@@ -737,8 +919,7 @@ PHASES = {"setup.build": None, "setup.capture": "setup.build",
           "setup.first_step": None}
 TRACE_TIME_GAUGES = ("lean_head.chunks", "lean_head.chunk_width",
                      "lean_head.dead_cols", "attention.flash_layers",
-                     "attention.kda_kernel_layers",
-                     "attention.kda_fused_mixer_layers", "model.remat_blocks",
+                     "attention.kda_kernel_layers", "model.remat_blocks",
                      "model.kept_expert_layers", "model.kept_expert_bytes",
                      "model.kept_dense_layers", "model.kept_dense_bytes",
                      "model.kept_sublayer_out_layers",
