@@ -674,7 +674,17 @@ class Mamba2Mixer(nn.Module):
     the gate FIRST and the RMS statistic over each group's inner / G
     features, one learned weight of inner; ``W_out y``. No bias on a
     projection, no position signal. Sows ``chunk_carry`` into
-    ``counters``: what of a chunk's incoming state survives it."""
+    ``counters``: what of a chunk's incoming state survives it.
+
+    Two renderings of what lies between the projections, chosen by the
+    shapes (``ops/ssd.py:mixer_runs_fused``; no argument, flag or
+    environment variable): at the published widths everything element-wise
+    before and after the scan is one pallas pass a direction on
+    ``in_proj``'s output TOKENS LAST (``ssd.mamba_pre`` writes the scan's
+    operands in the scan's layouts, ``ssd.mamba_post`` reads its y as it
+    leaves the kernel); anything narrower takes :func:`mamba_inputs` and
+    :func:`mamba_output` below, the ``jnp`` form the passes are tested
+    against."""
     cfg: Mamba2Config
     norm_eps: float
     dtype: Dtype = jnp.float32
@@ -685,9 +695,7 @@ class Mamba2Mixer(nn.Module):
         c = self.cfg
         H, P, G, N = c.num_heads, c.head_dim, c.n_groups, c.state_size
         inner, conv_dim = H * P, H * P + 2 * G * N
-        z, xbc, dt = jnp.split(
-            linear(inner + conv_dim + H, self.dtype, "in_proj")(x),
-            [inner, inner + conv_dim], axis=-1)
+        zxbcdt = linear(inner + conv_dim + H, self.dtype, "in_proj")(x)
         w = self.param("conv", conv_filter_init, (c.conv_size, conv_dim))
         b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
         a_log = self.param("A_log", a_log_init, (H,))
@@ -696,20 +704,55 @@ class Mamba2Mixer(nn.Module):
         # (its floor, 1e-4, lies under the range)
         dt_bias = self.param("dt_bias", dt_bias_init, (H,))
         scale = self.param("norm", nn.initializers.ones, (inner,))
-        xbc = nn.silu(causal_conv(xbc, w.astype(self.dtype))
-                      + b.astype(self.dtype))
-        xs, bs, cs = jnp.split(xbc, [inner, inner + G * N], axis=-1)
-        heads = lambda t, n: t.reshape(t.shape[:-1] + (n, -1))  # noqa: E731
-        with scopes.scope(scopes.SSD_SCAN):
-            y, carry = ssd.ssd_chunked(
-                heads(xs, H), jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
-                -jnp.exp(a_log), heads(bs, G), heads(cs, G), d_skip,
+        if not self.is_initializing() and ssd.mixer_runs_fused(
+                P, N, H // G, G, c.chunk, c.conv_size):
+            # (the passes hold no parameter: an init traces no kernel)
+            xs, dt, dta, bs, cs, zx = ssd.mamba_pre(
+                jnp.moveaxis(zxbcdt, 1, 2), w, b, dt_bias, a_log, G, N,
                 c.chunk, self.dtype)
+            with scopes.scope(scopes.SSD_SCAN):
+                y, total = ssd.ssd_tokens_last(xs, dt, dta, bs, cs, d_skip,
+                                               c.chunk, self.dtype)
+                carry = jnp.mean(jnp.exp(total))
+            y = jnp.moveaxis(ssd.mamba_post(y, zx, scale, self.norm_eps,
+                                            self.dtype), 1, 2)
+        else:
+            xs, dt, a, bs, cs, z = mamba_inputs(zxbcdt, w, b, dt_bias, a_log,
+                                                c, self.dtype)
+            with scopes.scope(scopes.SSD_SCAN):
+                y, carry = ssd.ssd_chunked(xs, dt, a, bs, cs, d_skip, c.chunk,
+                                           self.dtype)
+            y = mamba_output(y, z, scale, G, self.norm_eps, self.dtype)
         self.sow("counters", "chunk_carry", carry)
-        y = heads(y.reshape(z.shape) * nn.silu(z), G)
-        y = (rms_normalize(y, self.norm_eps).reshape(z.shape)
-             * scale).astype(self.dtype)
         return linear(x.shape[-1], self.dtype, "out_proj")(y)
+
+
+def mamba_inputs(zxbcdt, w, b, dt_bias, a_log, cfg, dtype):
+    """The ``jnp`` form of what lies between a Mamba-2 mixer's ``in_proj``
+    and its recurrence: ``in_proj``'s output [B, S, inner + conv_dim + H]
+    (``[z | xBC | dt]``), the filter [K, conv_dim] and its bias, ``dt_bias``
+    and ``A_log`` [H] -> ``ssd_chunked``'s x [B, S, H, P], dt [B, S, H]
+    (float32, after its softplus), a [H] (negative), B and C [B, S, G, N],
+    and the gate z [B, S, inner]."""
+    H, P, G, N = cfg.num_heads, cfg.head_dim, cfg.n_groups, cfg.state_size
+    inner, conv_dim = H * P, H * P + 2 * G * N
+    z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
+    xbc = nn.silu(causal_conv(xbc, w.astype(dtype)) + b.astype(dtype))
+    xs, bs, cs = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+    heads = lambda t, n: t.reshape(t.shape[:-1] + (n, -1))  # noqa: E731
+    return (heads(xs, H), jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
+            -jnp.exp(a_log), heads(bs, G), heads(cs, G), z)
+
+
+def mamba_output(y, z, scale, groups, eps, dtype):
+    """The ``jnp`` form of what lies between the recurrence and
+    ``out_proj``: y [B, S, H, P], the gate z [B, S, inner] and the norm's
+    weight [inner] -> ``rms_normalize`` over each of the ``groups`` groups'
+    inner / groups features of ``y * silu(z)`` (the gate FIRST), times the
+    weight, [B, S, inner] in ``dtype``."""
+    y = y.reshape(z.shape) * nn.silu(z)
+    y = y.reshape(y.shape[:-1] + (groups, -1))
+    return (rms_normalize(y, eps).reshape(z.shape) * scale).astype(dtype)
 
 
 class LatentAttention(nn.Module):

@@ -1099,13 +1099,16 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         if runs_as_kernels(cfg.kda_head_dim, cfg.kda_head_dim):
             kda_kernel_layers = types.count("kda")
     mamba_layers = types.count("mamba2")
-    ssd_kernel_layers = 0
+    ssd_kernel_layers = mamba_fused_mixer_layers = 0
     if mamba_layers:
-        from autodist_tpu.ops.ssd import runs_as_kernels
-        if runs_as_kernels(cfg.mamba_head_dim, cfg.ssm_state_size,
-                           cfg.mamba_num_heads // cfg.mamba_n_groups,
-                           cfg.mamba_chunk):
+        from autodist_tpu.ops import ssd
+        shape = (cfg.mamba_head_dim, cfg.ssm_state_size,
+                 cfg.mamba_num_heads // cfg.mamba_n_groups)
+        if ssd.runs_as_kernels(*shape, cfg.mamba_chunk):
             ssd_kernel_layers = mamba_layers
+        if ssd.mixer_runs_fused(*shape, cfg.mamba_n_groups, cfg.mamba_chunk,
+                                cfg.mamba_conv_size):
+            mamba_fused_mixer_layers = mamba_layers
     rng = jax.random.PRNGKey(seed)
     # only the parameters leave the jit, so the forward pass the init
     # traces (XLA's attention whatever ``attn_fn`` is, and what a routed
@@ -1266,6 +1269,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         tel.gauge_set("model.kept_core_bytes", kept_core_bytes)
         tel.gauge_set("model.mamba_layers", mamba_layers)
         tel.gauge_set("model.ssd_kernel_layers", ssd_kernel_layers)
+        tel.gauge_set("model.mamba_fused_mixer_layers",
+                      mamba_fused_mixer_layers)
         tel.gauge_set("model.single_sublayer_blocks",
                       cfg.num_layers if cfg.single_sublayer else 0)
         tokens = batch["tokens"]

@@ -23,6 +23,7 @@ from benchmark.families import nemotron_h as family
 from benchmark.reference import nemotron_h as ref
 from tests.test_keye_vl2_cell import bench_json, bench_lines, dot_flops
 from tests.test_kimi_linear import close, cpu_spec, flat
+from tests.test_lm_pins import steps as pinned_steps
 from tests.test_nemotron_h import (GROUPS, HELD, PATTERN, SEQ, TOP_K, batches,
                                    tiny_config)
 
@@ -250,6 +251,10 @@ def test_the_programs_own_rules_decide_this_cells_step():
     assert ssd.runs_as_kernels(cfg.mamba_head_dim, cfg.ssm_state_size,
                                cfg.mamba_num_heads // cfg.mamba_n_groups,
                                cfg.mamba_chunk)
+    assert ssd.mixer_runs_fused(cfg.mamba_head_dim, cfg.ssm_state_size,
+                                cfg.mamba_num_heads // cfg.mamba_n_groups,
+                                cfg.mamba_n_groups, cfg.mamba_chunk,
+                                cfg.mamba_conv_size)
     core = lm.flash_kept_bytes(8192, 32, 128, 128) + 4 * lm.ssd_kept_bytes(
         1, 8192, 64, 64, 128, 128)
     assert core == 135266304 + 4 * (67108864 + 134217728)
@@ -308,6 +313,7 @@ def test_the_scans_at_the_published_head_shape_are_booked_and_counted():
     assert gauges["model.remat_blocks"] == 8
     assert gauges["model.mamba_layers"] == 4
     assert gauges["model.ssd_kernel_layers"] == 4
+    assert gauges["model.mamba_fused_mixer_layers"] == 4
     # y of the 48 tokens' one padded chunk in float32, one entering state
     a_scan = 2 * 64 * 64 * (128 * 4 + 1 * 128 * 4)
     assert lm.ssd_kept_bytes(2, 48, 64, 64, 128, 128, 4) == a_scan
@@ -324,11 +330,29 @@ def test_the_gauges_count_the_layers_by_kind():
     assert gauges["attention.flash_layers"] == 1
     assert gauges["model.mamba_layers"] == 4
     assert gauges["model.ssd_kernel_layers"] == 0       # heads of 8: jnp
+    assert gauges["model.mamba_fused_mixer_layers"] == 0
     assert gauges["model.single_sublayer_blocks"] == 8
     assert gauges["model.kept_expert_layers"] == 0      # no TPU: no recompute
     assert loss_fn.device_counters == (
         "moe.max_expert_pairs", "moe.routed_pairs", "moe.chosen_pairs",
         "mamba.chunk_carry")
+
+
+@pytest.mark.parametrize("preset", sorted(pinned_steps()))
+def test_no_other_preset_fuses_a_mamba_mixer(preset):
+    """Every preset at ``tests/test_lm_pins.py``'s tiny size (this family's
+    at its narrow heads too): no fused mixer, by the gauge the set-up
+    account reads; the cell's widths give 4
+    (``test_the_scans_at_the_published_head_shape_are_booked_and_counted``)."""
+    _, make, seq, rows, attention = pinned_steps()[preset]
+    loss_fn, params, batch, _ = lm.make_train_setup(
+        make(), seq_len=seq, batch_size=rows, seed=0, attention=attention)
+    telemetry.reset()
+    jax.eval_shape(loss_fn, params, batch)
+    gauges = telemetry.get_recorder().gauges()
+    assert gauges["model.mamba_fused_mixer_layers"] == 0
+    assert gauges["model.mamba_layers"] == (
+        4 if preset == "tiny_nemotron_h_step" else 0)
 
 
 def test_a_nemotron_h_step_names_its_mamba_mixers_and_their_scans():

@@ -4,8 +4,9 @@ interpreted on the CPU a grid step at a time, so with few groups and two or
 three chunks: against the file's own ``jnp`` form (the oracle: same
 arithmetic, other order of sums) and against the token-by-token recurrence
 of ``benchmark/reference/nemotron_h.py``; the shapes' choice of rendering,
-the name a recomputed block keeps, and both kernels through Mosaic for a
-described v5e at the cell's sizes. (``tests/test_nemotron_h.py`` holds the
+the name a recomputed block keeps, and both kernels, and the mixer's four
+passes around them (``tests/test_mamba_mixer.py`` has their values), through
+Mosaic for a described v5e at the cell's sizes. (``tests/test_nemotron_h.py`` holds the
 ``jnp`` form and the model to the reference at the tiny widths.)
 
 Tolerances, of the largest entry: float32 differs by the order of sums
@@ -241,44 +242,85 @@ def test_what_a_layer_keeps_by_closed_form():
 # ------------------------------ both kernels through Mosaic, for the cell
 
 
-def test_the_kernels_compile_for_a_v5e_at_the_cells_sizes():
-    """One sequence of 8,192 tokens, 64 heads of 64 over 8 groups of 128
-    states in bfloat16: forward and backward through XLA:TPU and Mosaic for
-    a described chip (a group step's eight decays, their gradients and the
-    state fit VMEM; the transposes, the pads and the spreads of the
-    chunk's ``jax.vjp`` lower)."""
+@pytest.fixture(scope="module")
+def chip():
+    """One chip of a described v5e:2x2 (no device attached)."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from autodist_tpu.ops import pallas_mode
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to test
         pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
-    chip = SingleDeviceSharding(topo.devices[0])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def kernels_of_the_compiled_gradient(loss, args):
+    """The names of the Mosaic calls in ``grad(loss)`` compiled by XLA:TPU
+    for the described chip, sorted."""
+    from autodist_tpu.ops import pallas_mode
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with pallas_mode.compiling_for_tpu():
+            text = jax.jit(jax.grad(
+                loss, argnums=tuple(range(len(args))))).trace(*args).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    return sorted(line.split(" = ")[0].split("%")[-1].rsplit(".", 1)[0]
+                  for line in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in line)
+
+
+def test_the_kernels_compile_for_a_v5e_at_the_cells_sizes(chip):
+    """One sequence of 8,192 tokens, 64 heads of 64 over 8 groups of 128
+    states in bfloat16: forward and backward through XLA:TPU and Mosaic for
+    a described chip (a group step's eight decays, their gradients and the
+    state fit VMEM; the transposes, the pads and the spreads of the
+    chunk's ``jax.vjp`` lower)."""
     S, H, G = 8192, 64, 8
-    x, dt, b, c, a, d = (
-        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-        for shape, dtype in (((1, S, H, P), jnp.bfloat16),
-                             ((1, S, H), jnp.float32),
-                             ((1, S, G, N), jnp.bfloat16),
-                             ((1, S, G, N), jnp.bfloat16),
-                             ((H,), jnp.float32), ((H,), jnp.float32)))
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in (((1, S, H, P), jnp.bfloat16),
+                                 ((1, S, H), jnp.float32),
+                                 ((H,), jnp.float32),
+                                 ((1, S, G, N), jnp.bfloat16),
+                                 ((1, S, G, N), jnp.bfloat16),
+                                 ((H,), jnp.float32))]
 
     def loss(x, dt, a, b, c, d):
         return jnp.sum(ssd.ssd_chunked(x, dt, a, b, c, d, CHUNK,
                                        jnp.bfloat16)[0].astype(jnp.float32))
 
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        with pallas_mode.compiling_for_tpu():
-            text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).trace(
-                x, dt, a, b, c, d).lower(
-                lowering_platforms=("tpu",)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
-    calls = [line.split(" = ")[0].split("%")[-1].rsplit(".", 1)[0]
-             for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert sorted(calls) == ["ssd_bwd", "ssd_fwd"]
+    assert kernels_of_the_compiled_gradient(loss, args) == [
+        "ssd_bwd", "ssd_fwd"]
+
+
+def test_the_mixers_passes_compile_for_a_v5e_at_the_cells_sizes(chip):
+    """``in_proj``'s output of 8,192 tokens tokens last ([1, 10304, 8192]
+    bfloat16) through ``mamba_pre``, the scan and ``mamba_post`` at the
+    tile the program uses: the lane rolls of the filter, the row loops and
+    the blocks that a step leaves as they were lower, and the forward
+    gate is made again for ``out_proj``'s weight gradient."""
+    S, H, G = 8192, 64, 8
+    inner, conv = H * P, H * P + 2 * G * N
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in (((1, inner + conv + H, S), jnp.bfloat16),
+                                 ((4, conv), jnp.float32),
+                                 ((conv,), jnp.float32), ((H,), jnp.float32),
+                                 ((H,), jnp.float32), ((H,), jnp.float32),
+                                 ((inner,), jnp.float32),
+                                 ((inner, 16), jnp.bfloat16))]
+
+    def loss(zx, w, b, dt_bias, a_log, d, scale, w_out):
+        x, dt, dta, bs, cs, zx = ssd.mamba_pre(zx, w, b, dt_bias, a_log, G,
+                                               N, CHUNK, jnp.bfloat16)
+        y, _ = ssd.ssd_tokens_last(x, dt, dta, bs, cs, d, CHUNK,
+                                   jnp.bfloat16)
+        y = ssd.mamba_post(y, zx, scale, 1e-5, jnp.bfloat16)
+        return jnp.sum(jnp.einsum("bfs,fd->bsd", y, w_out,
+                                  preferred_element_type=jnp.float32))
+
+    assert kernels_of_the_compiled_gradient(loss, args) == [
+        "mamba_post_bwd", "mamba_post_fwd", "mamba_pre_bwd", "mamba_pre_fwd",
+        "ssd_bwd", "ssd_fwd"]
