@@ -189,8 +189,11 @@ class MultiHeadAttention(nn.Module):
     ``num_kv_heads`` fewer that groups of query heads share (grouped-query
     attention: query head h reads K/V head ``h // (num_heads /
     num_kv_heads)``); optionally an RMSNorm of q and k (over all projected
-    features, OLMoE's, or per head, Qwen3's), rotary positions, and a
-    learned choice of the keys each query attends (``indexer``).
+    features, OLMoE's, or per head, Qwen3's), rotary positions, a
+    learned choice of the keys each query attends (``indexer``), and a
+    sliding ``window``: a query sees the latest ``window`` keys, its own
+    position counted (``ops/flash_attention.py`` says the same of its
+    kernels, which then walk the band's tiles alone).
 
     Three modes share one parameter set (submodules are created in the
     same order on every path, so flax resolves identical names):
@@ -221,6 +224,8 @@ class MultiHeadAttention(nn.Module):
     head_norm_eps: Optional[float] = None
     # a learned choice of the keys each query attends; None = all it sees
     indexer: Optional[IndexerConfig] = None
+    # the latest keys a query sees, itself counted; None = every earlier one
+    window: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, mask=None, cache=None, cursor=None, alive=None,
@@ -254,8 +259,14 @@ class MultiHeadAttention(nn.Module):
             q = rope(q, positions, self.rope_theta)
             k = rope(k, positions, self.rope_theta)
         new_cache = None
+        if self.window is not None and (cache is not None or return_kv):
+            raise NotImplementedError(
+                "prefill and cached decode keep every K/V row and attend all "
+                "of them: a sliding-window layer (window %d) needs a cache "
+                "that forgets, which serving does not have yet" % self.window)
         grouped_or_chosen = (self.indexer is not None
-                             or kv_heads != self.num_heads)
+                             or kv_heads != self.num_heads
+                             or self.window is not None)
         if grouped_or_chosen and (cache is not None or return_kv):
             raise NotImplementedError(
                 "prefill and cached decode keep as many K/V rows as query "
@@ -307,26 +318,36 @@ class MultiHeadAttention(nn.Module):
         return jnp.einsum("...hqk,...khd->...qhd", weights, v)
 
     def _grouped_or_chosen(self, x, q, k, v, mask, positions):
-        """The core over K/V heads that groups of query heads share and,
-        with an indexer, over the keys it chose: through ``attn_fn`` (the
-        flash kernels take both as they are) or XLA's scores with the K/V
-        heads repeated."""
-        from autodist_tpu.ops.attention import reference_attention
+        """The core over K/V heads that groups of query heads share, with
+        an indexer over the keys it chose, with a ``window`` over the band
+        it leaves (under ``swa_core``; the others under ``dsa_core``):
+        through ``attn_fn`` (the flash kernels take all three as they are)
+        or XLA's scores with the K/V heads repeated."""
+        from autodist_tpu.ops.attention import (causal_band,
+                                                reference_attention)
         chosen = None
         if self.indexer is not None:
             chosen = SparseIndexer(self.indexer, self.rope_theta,
                                    name="indexer")(x, positions)
-        with scopes.scope(scopes.ATTN_CORE), scopes.scope(scopes.DSA_CORE):
+        core = scopes.DSA_CORE if self.window is None else scopes.SWA_CORE
+        with scopes.scope(scopes.ATTN_CORE), scopes.scope(core):
             if self.attn_fn is not None:
-                # (an attention function that knows no selection still
-                # serves grouped heads)
-                return self.attn_fn(q, k, v, mask, **(
-                    {} if chosen is None else {"select": chosen}))
+                # (an attention function that knows no selection or window
+                # still serves grouped heads)
+                carried = {} if chosen is None else {"select": chosen}
+                if self.window is not None:
+                    carried["window"] = self.window
+                return self.attn_fn(q, k, v, mask, **carried)
             group = self.num_heads // k.shape[-2]
-            k, v = (jnp.repeat(t, group, axis=-2) for t in (k, v))
+            if group > 1:
+                k, v = (jnp.repeat(t, group, axis=-2) for t in (k, v))
             if chosen is not None:
                 chosen = (chosen != 0)[:, None]
                 mask = chosen if mask is None else mask & chosen
+            if self.window is not None:
+                band = causal_band(q.shape[-3], k.shape[-3],
+                                   self.window)[None, None]
+                mask = band if mask is None else mask & band
             return reference_attention(q, k, v, mask)
 
 
@@ -376,7 +397,13 @@ class RouterConfig:
     expert, routed and shared: ``down(silu(gate x) * up x)`` with three
     matrices, or ``down(relu(up x)^2)`` with two (Nemotron-H's ``relu2``);
     ``shared_width`` the shared expert's own width where it is not
-    ``shared_experts`` times a routed expert's. The defaults are OLMoE's."""
+    ``shared_experts`` times a routed expert's; ``gate_activation`` what a
+    gated ROUTED expert's gate passes ("silu", or "relu": ReGLU,
+    ``down(relu(gate x) * up x)``); ``reads_mixer_input``: the router's
+    logits are taken from what the block's FIRST norm produced, the token
+    mixer's input, and not from the feed-forward's own normed input, which
+    the experts still read (SmallThinker's "router before attention"). The
+    defaults are OLMoE's."""
     activation: str = "softmax"
     renormalize: bool = False
     scaling_factor: float = 1.0
@@ -385,6 +412,8 @@ class RouterConfig:
     seq_aux: bool = False
     gated: bool = True
     shared_width: int = 0
+    gate_activation: str = "silu"
+    reads_mixer_input: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -477,7 +506,8 @@ class MoEFeedForward(nn.Module):
     T x k). The shared experts (one SwiGLU as wide as all of them, the
     released DeepSeek and Kimi code's own form) are added in full. Experts
     without a gate (``router.gated`` False) hold no ``gate_proj``, routed
-    or shared."""
+    or shared. ``router_input`` (x's shape): what the router's logits are
+    taken from where that is not x itself."""
     num_experts: int
     experts_per_token: int
     expert_dim: int
@@ -485,7 +515,7 @@ class MoEFeedForward(nn.Module):
     router: RouterConfig = RouterConfig()
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_input=None):
         from autodist_tpu.parallel.expert import Routing, dropless_moe_ffn
         d, E, f = x.shape[-1], self.num_experts, self.expert_dim
         cfg = self.router
@@ -504,7 +534,8 @@ class MoEFeedForward(nn.Module):
             x, router, w_gate, w_up, w_down, self.experts_per_token,
             self.dtype, Routing(cfg.activation, cfg.renormalize,
                                 cfg.scaling_factor, bias),
-            held=cfg.held, seq_aux=cfg.seq_aux)
+            held=cfg.held, seq_aux=cfg.seq_aux, router_input=router_input,
+            gate_activation=cfg.gate_activation)
         if softmax:
             self.sow("losses", "router_lb", lb)
             self.sow("losses", "router_z", z)
@@ -835,7 +866,10 @@ class TransformerBlock(nn.Module):
     read (:data:`SUBLAYER_OUT_KEPT`). ``only`` leaves out the half a layer
     of single sub-layers does not have: "mixer" = ``x + Mix(N(x))`` alone,
     "ffn" = ``x + FFN(N(x))`` alone (the Nemotron-H layers, each ONE
-    sub-layer behind ONE norm)."""
+    sub-layer behind ONE norm). ``window`` is THIS layer's sliding window
+    and ``rope_theta`` THIS layer's rotation (a model may window and rotate
+    some layers and not others); a router that ``reads_mixer_input`` is
+    handed the first norm's output."""
     num_heads: int
     head_dim: int
     mlp_dim: int
@@ -861,6 +895,7 @@ class TransformerBlock(nn.Module):
     sandwich_norm: bool = False
     mamba: Optional[Mamba2Config] = None
     only: Optional[str] = None          # None (both) | "mixer" | "ffn"
+    window: Optional[int] = None
 
     def _mix(self, h, mask, cache, cursor, alive, return_kv, positions):
         """The block's token mixer on the normed input."""
@@ -872,7 +907,7 @@ class TransformerBlock(nn.Module):
                 qk_norm_eps=self.norm_eps if self.qk_norm else None,
                 rope_theta=self.rope_theta, num_kv_heads=self.num_kv_heads,
                 head_norm_eps=self.norm_eps if self.qk_head_norm else None,
-                indexer=self.indexer)(
+                indexer=self.indexer, window=self.window)(
                 h, mask, cache=cache, cursor=cursor, alive=alive,
                 return_kv=return_kv, positions=positions)
         if cache is not None or return_kv:
@@ -900,9 +935,10 @@ class TransformerBlock(nn.Module):
     def _mixer_sublayer(self, x, mask, deterministic, cache, cursor, alive,
                         return_kv, positions):
         kv = None
-        h = make_norm(self.norm, self.norm_eps, self.dtype)(x)
+        normed = make_norm(self.norm, self.norm_eps, self.dtype)(x)
         with scopes.scope(scopes.ATTENTION):
-            h = self._mix(h, mask, cache, cursor, alive, return_kv, positions)
+            h = self._mix(normed, mask, cache, cursor, alive, return_kv,
+                          positions)
         if cache is not None or return_kv:
             h, kv = h
         if self.sandwich_norm:
@@ -911,9 +947,11 @@ class TransformerBlock(nn.Module):
                 checkpoint_name(h, SUBLAYER_OUT_KEPT))
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
-        return x + h, kv
+        return x + h, kv, normed
 
-    def _ffn_sublayer(self, x, deterministic):
+    def _ffn_sublayer(self, x, deterministic, mixer_input=None):
+        """``mixer_input``: the first norm's output, for a router that
+        reads it."""
         h = make_norm(self.norm, self.norm_eps, self.dtype)(x)
         if self.dense_dim:
             with scopes.scope(scopes.DENSE_FFN):
@@ -922,7 +960,8 @@ class TransformerBlock(nn.Module):
         elif self.num_experts:
             h = MoEFeedForward(self.num_experts, self.experts_per_token,
                                self.mlp_dim, self.dtype, self.router,
-                               name="moe")(h)
+                               name="moe")(
+                h, mixer_input if self.router.reads_mixer_input else None)
         else:
             with scopes.scope(scopes.DENSE_FFN):
                 h = nn.Dense(self.mlp_dim, dtype=self.dtype)(h)
@@ -939,16 +978,17 @@ class TransformerBlock(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None, deterministic=True, cache=None,
                  cursor=None, alive=None, return_kv=False, positions=None):
-        kv = None
+        kv = mixer_input = None
         if self.only != "ffn":
-            x, kv = self._mixer_sublayer(x, mask, deterministic, cache,
-                                         cursor, alive, return_kv, positions)
+            x, kv, mixer_input = self._mixer_sublayer(
+                x, mask, deterministic, cache, cursor, alive, return_kv,
+                positions)
         elif cache is not None or return_kv:
             raise NotImplementedError(
                 "prefill and cached decode read K/V rows from every layer: "
                 "a layer that is its feed-forward alone has none")
         if self.only != "mixer":
-            x = self._ffn_sublayer(x, deterministic)
+            x = self._ffn_sublayer(x, deterministic, mixer_input)
         if cache is not None or return_kv:
             return x, kv
         return x

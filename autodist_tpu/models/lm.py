@@ -161,6 +161,24 @@ class LMConfig:
     # each sub-layer's OUTPUT is normed too before it joins the residual
     # (four norms a block)
     sandwich_norm: bool = False
+    # A model whose softmax-attention layers differ in what a query sees and
+    # in their position signal, a flag a layer as a config.json publishes
+    # them (SmallThinker's ``sliding_window_layout`` / ``rope_layout``):
+    # layer i sees the latest ``sliding_window`` keys (its own position
+    # counted) where ``window_layers[i]``, every earlier key where not; it
+    # rotates q and k by ``rope_theta`` where ``rope_layers[i]``, and has no
+    # position signal at all where not. None = no layer has a window / every
+    # layer rotates where ``rope_theta`` is set.
+    sliding_window: int = 0
+    window_layers: Optional[Tuple[int, ...]] = None
+    rope_layers: Optional[Tuple[int, ...]] = None
+    # a routed layer's router reads what the block's FIRST norm produced (the
+    # token mixer's input; "router before attention"), not the feed-forward's
+    # own normed input, which the experts still read
+    router_reads_mixer_input: bool = False
+    # what a gated routed expert's gate passes: "silu" (SwiGLU) | "relu"
+    # (ReGLU, ``down(relu(gate x) * up x)``)
+    expert_gate_activation: str = "silu"
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -280,6 +298,38 @@ class LMConfig:
                 "loop_steps > 1 scans the stack over shared weights: a "
                 "routed layer's losses and counters and an indexer's "
                 "choice, sown once a pass, have no way out of the scan yet")
+        for name, layout in (("window_layers", self.window_layers),
+                             ("rope_layers", self.rope_layers)):
+            if layout is not None and (
+                    len(layout) != self.num_layers
+                    or set(layout) - {0, 1}
+                    or set(types or ("attention",)) != {"attention"}):
+                raise ValueError(
+                    "%s is a 0 / 1 flag for each of the %d layers of a model "
+                    "of softmax attention alone, got %r with layer_types %r"
+                    % (name, self.num_layers, layout, types))
+        if bool(self.sliding_window) != bool(self.window_layers
+                                             and any(self.window_layers)) \
+                or self.sliding_window < 0:
+            raise ValueError(
+                "sliding_window (>= 1 keys, the query's own counted) is the "
+                "window of the layers window_layers flags: got %d with %r"
+                % (self.sliding_window, self.window_layers))
+        if self.rope_layers is not None and self.rope_theta is None:
+            raise ValueError("rope_layers flags the layers that rotate by "
+                             "rope_theta, which is None")
+        if self.expert_gate_activation not in ("silu", "relu"):
+            raise ValueError("expert_gate_activation must be silu|relu, got "
+                             "%r" % (self.expert_gate_activation,))
+        if (self.router_reads_mixer_input
+                or self.expert_gate_activation != "silu") and (
+                not self.num_experts or self.single_sublayer
+                or not self.expert_gated or self.num_shared_experts):
+            raise ValueError(
+                "router_reads_mixer_input and expert_gate_activation belong "
+                "to a block of a mixer AND a routed feed-forward of gated "
+                "experts without a shared one (a single sub-layer has no "
+                "first norm to read; the shared SwiGLU's gate is SiLU)")
         held = self.experts_held
         if held is not None and (
                 not held or len(set(held)) != len(held)
@@ -479,6 +529,38 @@ class LMConfig:
                    expert_gated=False, shared_expert_dim=3712, **kw)
 
     @classmethod
+    def smallthinker_21b_a3b(cls, **kw):
+        """SmallThinker-21BA3B-Instruct as its ``config.json`` publishes it
+        (huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct): 52
+        pre-norm RMSNorm (eps 1e-6) layers of hidden 2,560 without a bias,
+        28 query heads over 4 K/V heads of 128 (groups of SEVEN); by
+        ``sliding_window_layout`` and ``rope_layout``, both [0, 1, 1, 1] x
+        13, layer 4 n sees every earlier key and has NO position signal,
+        layers 4 n + 1 .. 4 n + 3 see the latest 4,096 keys and rotate q
+        and k over all 128 features at theta 1.5e6; 64 softmax-routed ReGLU
+        experts of 768 (``down(relu(gate x) * up x)``), 6 a token, gates
+        renormalised over the chosen, no shared expert, the router's logits
+        taken from the ATTENTION's normed input ("router placed before
+        attention"); an untied head over 151,936 words. ``num_layers`` cuts
+        the two layouts from their start. The window that counts the query
+        itself, the rotate-half pairing and the absence of a router loss are
+        without a key in the row: assumptions the benchmark's configuration
+        file lists."""
+        n = kw.setdefault("num_layers", 52)
+        kw.setdefault("max_seq_len", 16384)
+        layout = tuple(int(i % 4 != 0) for i in range(n))
+        kw.setdefault("window_layers", layout)
+        kw.setdefault("rope_layers", layout)
+        return cls(vocab_size=151936, d_model=2560, num_heads=28,
+                   head_dim=128, num_kv_heads=4, mlp_dim=768,
+                   norm="rmsnorm", norm_eps=1e-6, rope_theta=1.5e6,
+                   sliding_window=4096, attention_bias=False,
+                   head_bias=False, embed_scale=False, num_experts=64,
+                   experts_per_token=6, moe_renormalize=True,
+                   router_reads_mixer_input=True,
+                   expert_gate_activation="relu", **kw)
+
+    @classmethod
     def tiny(cls, **kw):
         return cls(vocab_size=128, d_model=32, num_layers=2, num_heads=2,
                    mlp_dim=64, max_seq_len=64, **kw)
@@ -544,6 +626,8 @@ class TransformerLM(nn.Module):
                 cfg.ssm_state_size, cfg.mamba_conv_size, cfg.mamba_chunk)
         if cfg.single_sublayer:
             kw["only"] = "ffn" if kind == "moe" else "mixer"
+        if cfg.window_layers is not None and cfg.window_layers[i]:
+            kw["window"] = cfg.sliding_window
         if kind == "attention" and (cfg.num_kv_heads or cfg.qk_head_norm
                                     or cfg.indexer_num_heads):
             kw.update(num_kv_heads=cfg.num_kv_heads,
@@ -560,7 +644,8 @@ class TransformerLM(nn.Module):
                 cfg.router_activation, cfg.moe_renormalize,
                 cfg.routed_scaling_factor, cfg.num_shared_experts,
                 cfg.experts_held, cfg.seq_aux, cfg.expert_gated,
-                cfg.shared_expert_dim)
+                cfg.shared_expert_dim, cfg.expert_gate_activation,
+                cfg.router_reads_mixer_input)
         # (what a core's backward kernels need of its forward kernel is
         # kept by name, or a recomputed block would run the core a second
         # time only to make it again: the delta rule's output and
@@ -614,7 +699,9 @@ class TransformerLM(nn.Module):
             cfg.mlp_dim,
             dtype=cfg.dtype, norm=cfg.norm, norm_eps=cfg.norm_eps,
             attention_bias=cfg.attention_bias, qk_norm=cfg.qk_norm,
-            rope_theta=cfg.rope_theta, num_experts=cfg.num_experts,
+            rope_theta=(cfg.rope_theta if cfg.rope_layers is None
+                        or cfg.rope_layers[i] else None),
+            num_experts=cfg.num_experts,
             experts_per_token=cfg.experts_per_token,
             sandwich_norm=cfg.sandwich_norm, name="layer_%d" % i, **kw)
 

@@ -38,6 +38,16 @@ def reference_attention(q, k, v, mask=None):
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
+def causal_band(seq_q: int, seq_k: int, window=None):
+    """[seq_q, seq_k] bool, True = attend: key j at or before query i and,
+    with a ``window``, among the ``window`` latest of those, the query's own
+    position counted (``j <= i`` and ``i - j < window``)."""
+    rows = jnp.arange(seq_q)[:, None]
+    cols = jnp.arange(seq_k)[None, :]
+    seen = rows >= cols
+    return seen if window is None else seen & (rows - cols < window)
+
+
 def _block_update(q, k_blk, v_blk, acc, m, l, blk_mask, scale):
     """One online-softmax accumulation step (the flash-attention recurrence)."""
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_blk).astype(jnp.float32) * scale

@@ -28,6 +28,25 @@ Design (standard FlashAttention-2 tiling, arXiv 2307.08691):
   last bit wherever segment ids or a selection mask the tile, and within
   a float32 rounding (1e-7) on a plain causal call's interior tiles, whose
   ``s * scale - m`` XLA:CPU contracts once no select stands between.
+- window (``window=w``, with ``causal``): query i sees key j iff
+  ``j <= i`` and ``i - j < w``: the w latest keys, THE QUERY'S OWN
+  POSITION COUNTED (the Hugging Face sliding-window convention: w = 1 is
+  a query that sees itself alone). The table then has two edges: a tile
+  is listed iff the diagonal reaches it AND the window's far edge has not
+  passed it (252 of the 528 causal tiles at seq 16,384 x 512 rows and
+  w = 4,096: 43.75 % of the causal pairs in 47.7 % of the tiles), and
+  ``CROSSED`` is on the tiles EITHER edge crosses, which alone pay the
+  two compares. Nothing else changes: the walks, ``_Q_OUT`` (a q block's
+  dq still leaves at its last tile, which is its diagonal tile, kv-major
+  the first step of its own kv pass; its first tile is no longer kv block
+  0, and ``Q_FIRST`` zeroes its slab where the table says) and the sums'
+  order are the table's. A row of a tile the far edge crosses may see no
+  key of that tile: its running maximum stays ``NEG_INF`` and what it
+  summed is wiped by ``exp(NEG_INF - m)`` = 0 at the first tile that
+  shows it a key, which its diagonal tile always does. Counters
+  ``attention.window_tiles`` / ``attention.window_tiles_causal``: the
+  steps a windowed launch's table has, and what the same launch would
+  walk without the window.
 - forward: q-major walk (a q block's kv blocks in a run); running
   (m, l, acc) live in VMEM scratch across the run; the log-sum-exp per row
   is written out for the backward pass.
@@ -145,28 +164,47 @@ def full_tiles(seq: int) -> bool:
 
 # ------------------------------------------------------------------ tiles
 
-def _tile_live(qi, ki, bq, bk, causal):
+def _tile_live(qi, ki, bq, bk, causal, window=None):
     """Does tile (q block ``qi``, kv block ``ki``) hold a visible entry by
     position? Under a causal mask: does its last row reach its first
-    column. Known from the shapes alone (ints or numpy arrays)."""
-    return qi * bq + bq - 1 >= ki * bk if causal else True
+    column; under a window besides: is its first row's distance to its last
+    column still inside it. Known from the shapes alone (ints or numpy
+    arrays)."""
+    if not causal:
+        return True
+    live = qi * bq + bq - 1 >= ki * bk
+    if window is not None:
+        live = live & (qi * bq - (ki * bk + bk - 1) < window)
+    return live
+
+
+def _tile_crossed(qi, ki, bq, bk, window=None):
+    """Does an edge of the visible band pass THROUGH the live tile, so that
+    it holds a masked entry: the diagonal (its last column lies past its
+    first row) or the window's far edge (its last row's distance to its
+    first column is outside the window)."""
+    crossed = ki * bk + bk - 1 > qi * bq
+    if window is not None:
+        crossed = crossed | (qi * bq + bq - 1 - ki * bk >= window)
+    return crossed
 
 
 # the table's columns ...
 _Q, _KV, _FLAGS, _Q_OUT = range(4)
 # ... and the bits of its flags: the first / last tile of the walk that
 # holds this q block, the same of this kv block, and a tile the diagonal
-# crosses (some column passes some row: the only ones the causal compare
-# changes)
+# or a window's far edge crosses (the only ones the positional compares
+# change)
 Q_FIRST, Q_LAST, KV_FIRST, KV_LAST, CROSSED = 1, 2, 4, 8, 16
 
 
-def _tile_table(n_q, n_kv, bq, bk, causal, kv_major):
+def _tile_table(n_q, n_kv, bq, bk, causal, kv_major, window=None):
     """The tiles a kernel walks, int32 [4, T], made from the shapes as the
     call is traced: column ``_Q`` / ``_KV`` of step t is the tile's q / kv
     block, ``_FLAGS`` its bits, ``_Q_OUT`` the q block whose rows leave
     next (below). Every tile ``_tile_live`` finds a visible entry in, once:
-    the whole rectangle, or the causal lower triangle; q-major (a q block's
+    the whole rectangle, the causal lower triangle, or of it the band a
+    ``window`` leaves; q-major (a q block's
     kv blocks in a run, rising: the forward kernel's and ``flash_dq``'s
     order of summing) or kv-major (``flash_bwd`` / ``flash_dkdv``).
 
@@ -175,13 +213,14 @@ def _tile_table(n_q, n_kv, bq, bk, causal, kv_major):
     last tile comes soonest at or after t: each q block is named over one
     run of steps that ends where it is written. q-major that is the tile's
     own q block; kv-major and causal, q block j is done at the first step
-    of kv pass j; with no mask all are done in the last kv pass. (Every q
-    block sees kv block 0, so none is left out; a kv block past the last
-    query's position has no tile: ``_bwd`` zeroes its dk / dv.)"""
+    of kv pass j, with a window or without; with no mask all are done in
+    the last kv pass. (Every q block has a tile, kv block 0 or under a
+    window its own diagonal's, so none is left out; a kv block past the
+    last query's position has no tile: ``_bwd`` zeroes its dk / dv.)"""
     grid = np.indices((n_kv, n_q) if kv_major else (n_q, n_kv))
     q, kv = (x.ravel() for x in (grid[::-1] if kv_major else grid))
     if causal:
-        live = _tile_live(q, kv, bq, bk, causal)
+        live = _tile_live(q, kv, bq, bk, causal, window)
         q, kv = q[live], kv[live]
     steps = np.arange(q.size)
 
@@ -197,19 +236,25 @@ def _tile_table(n_q, n_kv, bq, bk, causal, kv_major):
              + KV_FIRST * (kv_first[kv] == steps)
              + KV_LAST * (kv_last[kv] == steps))
     if causal:
-        flags = flags + CROSSED * (kv * bk + bk - 1 > q * bq)
+        flags = flags + CROSSED * _tile_crossed(q, kv, bq, bk, window)
     leaving = np.argsort(q_last)
     q_out = leaving[np.searchsorted(q_last[leaving], steps)]
     return np.stack([q, kv, flags, q_out]).astype(np.int32)
 
 
-def _table(Sq, Sk, bq, bk, causal, kv_major):
+def _table(Sq, Sk, bq, bk, causal, kv_major, window=None):
     """(``_tile_table`` flat, as the kernels' scalar-prefetch operand; its
     steps), counted as the launch is traced."""
-    table = _tile_table(Sq // bq, Sk // bk, bq, bk, causal, kv_major)
+    n_q, n_kv = Sq // bq, Sk // bk
+    table = _tile_table(n_q, n_kv, bq, bk, causal, kv_major, window)
     tel.counter_add("attention.flash_tiles", table.shape[1])
     tel.counter_add("attention.flash_tiles_masked",
                     int(np.count_nonzero(table[_FLAGS] & CROSSED)))
+    if window is not None:
+        q, kv = np.indices((n_q, n_kv))
+        tel.counter_add("attention.window_tiles", table.shape[1])
+        tel.counter_add("attention.window_tiles_causal", int(
+            np.count_nonzero(_tile_live(q, kv, bq, bk, causal))))
     return jnp.asarray(table.reshape(-1)), table.shape[1]
 
 
@@ -285,24 +330,29 @@ def _launch(kernel, name, table, grid, operands, in_specs, outs, scratch,
     )(table, *operands)
 
 
-def _mask_val(s, qi, ki, bq, bk, causal, qs, ks):
-    """Apply causal and/or segment masking to a score tile [bq, bk]."""
+def _mask_val(s, qi, ki, bq, bk, causal, qs, ks, window=None):
+    """Apply causal (and a window's) and/or segment masking to a score
+    tile [bq, bk]."""
     if causal:
         rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(rows >= cols, s, NEG_INF)
+        seen = rows >= cols
+        if window is not None:
+            seen = seen & (rows - cols < window)
+        s = jnp.where(seen, s, NEG_INF)
     if qs is not None:
         s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
     return s
 
 
 def _on_live_tile(qi, ki, flags, bq, bk, causal, qs_ref, ks_ref, sel_ref,
-                  body):
+                  body, window=None):
     """Run ``body(mask)`` on a tile of the table unless its segment ids
     say no entry is visible; ``mask(s)`` masks a score tile [bq, bk]. The
-    table lists no tile above the diagonal, and only one the diagonal
-    crosses (``CROSSED``) pays the causal compare: under it every row
-    passes every column. Segment ids skip a tile whose blocks' id ranges
+    table lists no tile above the diagonal or behind a window, and only
+    one an edge crosses (``CROSSED``) pays the positional compares: in the
+    others every row passes every column. Segment ids skip a tile whose
+    blocks' id ranges
     do not meet (exact for sorted segments, a safe over-approximation
     otherwise) and mask the others elementwise. A selection masks
     elementwise within the tile (its [bq, bk] int8 block, non-zero =
@@ -313,7 +363,7 @@ def _on_live_tile(qi, ki, flags, bq, bk, causal, qs_ref, ks_ref, sel_ref,
 
     def run(diagonal):
         def mask(s):
-            s = _mask_val(s, qi, ki, bq, bk, diagonal, qs, ks)
+            s = _mask_val(s, qi, ki, bq, bk, diagonal, qs, ks, window)
             if sel_ref is None:
                 return s
             return jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, NEG_INF)
@@ -352,7 +402,7 @@ def _split_refs(refs, n_fixed, has_seg, has_sel):
 
 
 def _fwd_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
-                steps):
+                steps, window):
     (q_ref, k_ref, v_ref), qs_ref, ks_ref, sel_ref, (
         o_ref, lse_ref, acc_ref, m_ref, l_ref) = _split_refs(
             refs, 3, has_seg, has_sel)
@@ -397,7 +447,7 @@ def _fwd_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
                 preferred_element_type=jnp.float32)
 
     _on_live_tile(qi, ki, flags, bq, bk, causal, qs_ref, ks_ref, sel_ref,
-                  tile)
+                  tile, window)
 
     @pl.when((flags & Q_LAST) != 0)
     def _():
@@ -411,16 +461,17 @@ def _fwd_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
             l > 0, m_ref[:, :1] + jnp.log(jnp.maximum(l, 1e-30)), 0.0)
 
 
-def _fwd(q, k, v, segs, sel, causal):
+def _fwd(q, k, v, segs, sel, causal, window=None):
     """q [B, H, S, D], k [B, H / group, S, D], v [B, H / group, S, Dv]
     (kernel-internal layout); segs is None or (q_seg [B, Sq], kv_seg
-    [B, Sk]) int32; sel None or [B, Sq, Sk] int8. Returns (out
+    [B, Sk]) int32; sel None or [B, Sq, Sk] int8; window None or the keys
+    a query sees, itself counted. Returns (out
     [B, H, Sq, Dv], lse [B, H, Sq, 1])."""
     B, H, Sq, D = q.shape
     Sk, Dv = k.shape[2], v.shape[3]
     has_seg, has_sel = segs is not None, sel is not None
     bq, bk = _tiles(Sq, Sk)
-    table, steps = _table(Sq, Sk, bq, bk, causal, kv_major=False)
+    table, steps = _table(Sq, Sk, bq, bk, causal, False, window)
 
     specs = _specs(D, Dv, bq, bk, steps, H // k.shape[1])
     in_specs = [specs.q, specs.k, specs.v]
@@ -435,7 +486,7 @@ def _fwd(q, k, v, segs, sel, causal):
     return _launch(
         functools.partial(_fwd_kernel, scale=float(1.0 / np.sqrt(D)),
                           causal=causal, has_seg=has_seg, has_sel=has_sel,
-                          bq=bq, bk=bk, steps=steps),
+                          bq=bq, bk=bk, steps=steps, window=window),
         "flash_fwd", table, (B, H, steps), operands, in_specs,
         [(specs.out, jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype)),
          (specs.row, jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32))],
@@ -456,7 +507,7 @@ def _p_and_ds(q, k, v, do, lse, delta, scale, mask):
 
 
 def _dq_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
-               steps):
+               steps, window):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), qs_ref, ks_ref, \
         sel_ref, (dq_ref, acc_ref) = _split_refs(refs, 6, has_seg, has_sel)
     qi, ki, flags = _step(tab_ref, steps)
@@ -474,7 +525,7 @@ def _dq_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
             preferred_element_type=jnp.float32)
 
     _on_live_tile(qi, ki, flags, bq, bk, causal, qs_ref, ks_ref, sel_ref,
-                  tile)
+                  tile, window)
 
     @pl.when((flags & Q_LAST) != 0)
     def _():
@@ -482,7 +533,7 @@ def _dq_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
 
 
 def _bwd_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
-                steps, fused):
+                steps, window, fused):
     """dk and dv of one kv block, summed over its q blocks in float32
     scratch. ``fused``: dq too, from the SAME ``_p_and_ds`` of each tile:
     it sums over the kv blocks in ``dq_acc``, one float32 [bq, D] slab a q
@@ -523,7 +574,7 @@ def _bwd_kernel(tab_ref, *refs, scale, causal, has_seg, has_sel, bq, bk,
                 preferred_element_type=jnp.float32)
 
     _on_live_tile(qi, ki, flags, bq, bk, causal, qs_ref, ks_ref, sel_ref,
-                  tile)
+                  tile, window)
 
     @pl.when((flags & KV_LAST) != 0)
     def _():
@@ -565,7 +616,7 @@ def _fused_vmem(Sq, D, Dv, bq, bk, dtype, has_seg, has_sel=False):
             + 2 * blocks + (9 if has_sel else 8) * _vmem_bytes(bq, bk, f32))
 
 
-def _bwd(causal, res, do):
+def _bwd(causal, res, do, window=None):
     """res tensors, do and the returned (dq, dk, dv) in [B, H, S, .] (dk
     and dv [B, H / group, S, .] as k and v are)."""
     q, k, v, out, lse, q_seg, kv_seg, sel = res
@@ -575,7 +626,8 @@ def _bwd(causal, res, do):
     group = H // k.shape[1]
     bq, bk = _tiles(Sq, Sk)
     static = dict(scale=float(1.0 / np.sqrt(D)), causal=causal,
-                  has_seg=has_seg, has_sel=has_sel, bq=bq, bk=bk)
+                  has_seg=has_seg, has_sel=has_sel, bq=bq, bk=bk,
+                  window=window)
 
     # delta_i = rowsum(dO_i * O_i): tiny elementwise reduce, XLA fuses it
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -611,7 +663,7 @@ def _bwd(causal, res, do):
     fused = vmem <= _VMEM // 2
     tel.counter_add("attention.flash_bwd_fused" if fused
                     else "attention.flash_bwd_split")
-    table, steps = _table(Sq, Sk, bq, bk, causal, kv_major=True)
+    table, steps = _table(Sq, Sk, bq, bk, causal, True, window)
     specs = _specs(D, Dv, bq, bk, steps, group)
     # K/V heads that ``group`` query heads share: each query head writes
     # the dk / dv of ITS scores ([B, H, Sk, .], a q-shaped tile spec with
@@ -643,7 +695,7 @@ def _bwd(causal, res, do):
     if fused:
         return tuple(grads)
 
-    table, steps = _table(Sq, Sk, bq, bk, causal, kv_major=False)
+    table, steps = _table(Sq, Sk, bq, bk, causal, False, window)
     specs = _specs(D, Dv, bq, bk, steps, group)
     dq, = call(_dq_kernel, "flash_dq", table, steps, specs,
                [(specs.q, q, (bq, D))])
@@ -665,16 +717,16 @@ def _heads_first(x):
     return x.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _flash(q, k, v, q_seg, kv_seg, sel, causal):
-    return _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _flash(q, k, v, q_seg, kv_seg, sel, causal, window):
+    return _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal, window)[0]
 
 
-def _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal):
+def _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal, window):
     qt, kt, vt = _heads_first(q), _heads_first(k), _heads_first(v)
     segs = None if q_seg is None else (q_seg, kv_seg)
     qt = checkpoint_name(qt, KEPT)
-    out, lse = _fwd(qt, kt, vt, segs, sel, causal)
+    out, lse = _fwd(qt, kt, vt, segs, sel, causal, window)
     # (the log-sum-exp is named as [B, H, S]: as the kernels' [B, H, S, 1]
     # a row is padded to 128 lanes in HBM, 67 MB a layer at 16 heads and
     # seq 8192 where the values are 0.5; with the name on that form
@@ -684,8 +736,8 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal):
     return _heads_first(out), (qt, kt, vt, out, lse, q_seg, kv_seg, sel)
 
 
-def _flash_bwd(causal, res, do):
-    grads = _bwd(causal, res, _heads_first(do))
+def _flash_bwd(causal, window, res, do):
+    grads = _bwd(causal, res, _heads_first(do), window)
     return tuple(_heads_first(g) for g in grads) + tuple(
         _seg_zero_cot(ids) for ids in res[5:])
 
@@ -698,7 +750,7 @@ def _tileable(q, k):
 
 
 def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
-                    select=None):
+                    select=None, window=None):
     """Exact fused attention. q, k: [B, S, H, D], v: [B, S, H, Dv] ->
     [B, S, H, Dv]; scores are scaled by 1 / sqrt(D). k and v may have
     fewer heads than q, a divisor of H: query head h reads K/V head
@@ -711,6 +763,12 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
     ``causal`` and the segment ids). It is read a [rows, rows] int8 tile
     at a time and masks within the tile; no gradient flows to it. Every
     query must keep at least one key.
+
+    ``window``: with ``causal``, the latest keys a query attends, ITS OWN
+    POSITION COUNTED: query i sees key j iff ``j <= i`` and ``i - j <
+    window`` (a sliding window of 4,096 is the query and the 4,095 before
+    it). The kernels walk only the tiles the band touches. None = every
+    earlier key.
 
     ``segment_ids``: [B, S] int32 (shared q/kv for self-attention) or a
     ``(q_seg, kv_seg)`` pair — attention is allowed iff the ids are equal.
@@ -729,8 +787,18 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
         kv_seg = jnp.asarray(segment_ids[1], jnp.int32)
     else:
         q_seg = kv_seg = jnp.asarray(segment_ids, jnp.int32)
+    if window is not None:
+        window = int(window)
+        if not causal or window < 1:
+            raise ValueError(
+                "a window counts the latest keys a causal query sees, itself "
+                "included (>= 1): got window %d with causal %s"
+                % (window, causal))
+        if window >= k.shape[1]:
+            window = None       # every earlier key: the plain causal table
     if not _tileable(q, k):
-        from autodist_tpu.ops.attention import reference_attention
+        from autodist_tpu.ops.attention import (causal_band,
+                                                reference_attention)
         from autodist_tpu.utils import logging
         logging.warning(
             "flash_attention: q %s / k %s cannot be tiled (8-row minimum) "
@@ -738,9 +806,7 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
             tuple(q.shape), tuple(k.shape))
         mask = None
         if causal:
-            rows = jnp.arange(q.shape[1])[:, None]
-            cols = jnp.arange(k.shape[1])[None, :]
-            mask = (rows >= cols)[None, None]
+            mask = causal_band(q.shape[1], k.shape[1], window)[None, None]
         if q_seg is not None:
             seg_mask = (q_seg[:, :, None] == kv_seg[:, None, :])[:, None]
             mask = seg_mask if mask is None else jnp.logical_and(mask,
@@ -754,7 +820,7 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
         return reference_attention(q, k, v, mask)
     if select is not None:
         select = jnp.asarray(select).astype(jnp.int8)
-    return _flash(q, k, v, q_seg, kv_seg, select, causal)
+    return _flash(q, k, v, q_seg, kv_seg, select, causal, window)
 
 
 def make_flash_attn_fn(causal: bool = True):
@@ -763,11 +829,12 @@ def make_flash_attn_fn(causal: bool = True):
     A key-padding mask (boolean, broadcastable [B, 1, 1, S] / [B, S])
     becomes segment ids (valid=1, pad=0) — the masked-tile block path.
     Arbitrary dense masks are not expressible as segments and raise;
-    ``select`` ([B, Sq, Sk], a sparse attention's chosen keys) goes to
-    the kernels as it is."""
-    def attn(q, k, v, mask=None, select=None):
+    ``select`` ([B, Sq, Sk], a sparse attention's chosen keys) and
+    ``window`` (a layer's sliding window) go to the kernels as they are."""
+    def attn(q, k, v, mask=None, select=None, window=None):
         if mask is None:
-            return flash_attention(q, k, v, causal, select=select)
+            return flash_attention(q, k, v, causal, select=select,
+                                   window=window)
         m = jnp.asarray(mask)
         # accept [B, S] or the layers' [B, 1, 1, S] broadcast form
         if m.ndim == 4 and m.shape[1] == 1 and m.shape[2] == 1:
@@ -777,5 +844,6 @@ def make_flash_attn_fn(causal: bool = True):
                 "flash attention supports key-padding masks ([B, S] or "
                 "[B, 1, 1, S]) via segment ids; got mask shape %s"
                 % (mask.shape,))
-        return flash_attention(q, k, v, causal, m.astype(jnp.int32), select)
+        return flash_attention(q, k, v, causal, m.astype(jnp.int32), select,
+                               window)
     return attn

@@ -298,21 +298,28 @@ class Routing(NamedTuple):
 def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
                      dtype=None, routing: Routing = Routing(),
                      held: Optional[Tuple[int, ...]] = None,
-                     seq_aux: bool = False):
+                     seq_aux: bool = False, router_input=None,
+                     gate_activation: str = "silu"):
     """Token-choice top-k MoE with no token dropped, its experts gated
-    (SwiGLU) or, with ``w_gate`` None, not (``relu(up x)^2``). Returns
+    (SwiGLU; ReGLU with ``gate_activation`` "relu") or, with ``w_gate``
+    None, not (``relu(up x)^2``). Returns
     (output with x's shape, load-balance loss, z-loss, routed pairs per
     expert of the stacks [E] int32: the layer's load).
 
     - ``x``: [..., S, d] activations; flattened to T tokens internally.
+    - ``router_input``: x's shape, what the router's logits are taken
+      from where that is not x (a router that reads the block's first
+      norm's output, the token mixer's input, while the experts read x);
+      None = x.
     - ``router_w``: [d, E_all]; logits and scores in float32 over all
       E_all; ``routing`` says how they become k gates a token (by default
       the softmax probability itself, no renormalisation over the chosen
       k: HF ``norm_topk_prob`` false).
     - ``w_gate``/``w_up``: [E, d, f], ``w_down``: [E, f, d]; computed in
       ``dtype`` with float32 accumulation of the per-token combine:
-      ``sum_j g_j * down_ej(silu(gate_ej(x)) * up_ej(x))``. ``w_gate``
-      None: two stacks an expert and no gate,
+      ``sum_j g_j * down_ej(silu(gate_ej(x)) * up_ej(x))``
+      (``gate_activation`` "relu": ``relu(gate_ej(x))`` for the SiLU).
+      ``w_gate`` None: two stacks an expert and no gate,
       ``sum_j g_j * down_ej(relu(up_ej(x))^2)`` (Nemotron-H's ``relu2``).
     - ``held``: the experts of the router's E_all whose weights the stacks
       hold, in the stacks' order (one chip's share under expert
@@ -327,21 +334,27 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
       loss per sequence of x's second-to-last axis
       (:func:`sequence_balance_loss`) and no z-loss.
     """
+    if gate_activation not in GATE_ACTIVATIONS:
+        raise ValueError("gate_activation must be one of %s, got %r"
+                         % (sorted(GATE_ACTIVATIONS), gate_activation))
     dt = dtype or x.dtype
     d = x.shape[-1]
     tokens = x.reshape(-1, d)
+    routed_from = tokens if router_input is None \
+        else router_input.reshape(-1, d)
     with scopes.scope(scopes.MOE):
         with scopes.scope(scopes.MOE_ROUTE):
             # true float32 (a TPU's default would round to bf16 passes):
             # [T, d] x [d, E] is small, and near-ties decide the routing
-            logits = jnp.dot(tokens.astype(jnp.float32),
+            logits = jnp.dot(routed_from.astype(jnp.float32),
                              router_w.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
             probs, gate, expert = routing.choose(logits, top_k)  # [T, k]
         experts = _sorted_experts if held is None else functools.partial(
             _held_experts, held=held)
         out, counts = experts(tokens.astype(dt), gate, expert,
-                              w_gate, w_up, w_down)
+                              w_gate, w_up, w_down,
+                              activation=GATE_ACTIVATIONS[gate_activation])
         if routing.activation != "softmax":
             lb = z = jnp.float32(0.0)
         elif seq_aux:
@@ -356,7 +369,12 @@ def dropless_moe_ffn(x, router_w, w_gate, w_up, w_down, top_k: int,
     return out.astype(dt).reshape(x.shape), lb, z, counts
 
 
-def _sorted_experts(tokens, gate, expert, w_gate, w_up, w_down):
+# what a gated expert's gate passes before it multiplies the up product
+GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _sorted_experts(tokens, gate, expert, w_gate, w_up, w_down,
+                    activation=jax.nn.silu):
     """All experts held: ([T, d] float32, pairs per expert [E]) by the
     sorted form of the module docstring: the T k pairs sorted by expert,
     one grouped matmul for each projection, the un-sort and the gated
@@ -373,7 +391,7 @@ def _sorted_experts(tokens, gate, expert, w_gate, w_up, w_down):
     with scopes.scope(scopes.MOE_EXPERTS):
         product = lambda w: grouped_matmul(xs, w.astype(dt), counts)  # noqa: E731
         h = (jnp.square(jax.nn.relu(product(w_up))) if w_gate is None
-             else jax.nn.silu(product(w_gate)) * product(w_up))
+             else activation(product(w_gate)) * product(w_up))
         ys = grouped_matmul(h, w_down.astype(dt), counts)    # [T*k, d]
     with scopes.scope(scopes.MOE_ROUTE):
         ys = _permute_rows(ys, inv_order, order)             # token order
@@ -397,7 +415,8 @@ def _pairs_per_expert(pair_expert, n_experts):
 KEPT = "held_experts_kept"
 
 
-def _held_experts(tokens, gate, expert, w_gate, w_up, w_down, held):
+def _held_experts(tokens, gate, expert, w_gate, w_up, w_down, held,
+                  activation=jax.nn.silu):
     """The held experts' part of the routed sum, ([T, d] float32, pairs per
     held expert [E]): EVERY held expert on EVERY token, its hidden
     activations scaled by the token's gate for it (zero where the token
@@ -437,7 +456,7 @@ def _held_experts(tokens, gate, expert, w_gate, w_up, w_down, held):
             h = jnp.square(jax.nn.relu(product(w_up)))
         else:
             g, u = product(w_gate), product(w_up)
-            h = jax.nn.silu(g) * u
+            h = activation(g) * u
         h = (h.astype(jnp.float32) * weight[:, :, None]).astype(dt)
         out = jnp.einsum("tef,efd->td", h, w_down.astype(dt),
                          preferred_element_type=jnp.float32)

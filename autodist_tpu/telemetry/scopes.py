@@ -70,6 +70,7 @@ MLA_CORE = "mla_core"
 DSA_INDEX = "dsa_index"
 DSA_TOPK = "dsa_topk"
 DSA_CORE = "dsa_core"
+SWA_CORE = "swa_core"
 CONV_MIX = "conv_mix"
 CONV_CORE = "conv_core"
 MAMBA = "mamba"
@@ -99,7 +100,8 @@ SCOPES: Dict[str, str] = {
     ATTENTION: "multi-head attention inside a block",
     ATTN_CORE: "inside attention: every call of a softmax attention core on "
                "q, k, v, whatever the mixer (plain heads, grouped or chosen "
-               "keys with dsa_core, a latent mixer's with mla_core, the "
+               "keys with dsa_core, a window's band with swa_core, a latent "
+               "mixer's with mla_core, the "
                "cached decode step's): the flash kernels on a TPU, XLA's "
                "scores elsewhere; KDA, the short convolution and Mamba-2 "
                "have no such core",
@@ -140,6 +142,11 @@ SCOPES: Dict[str, str] = {
     DSA_CORE: "inside attention: the attention core over grouped K/V heads "
               "and, with an indexer, the chosen keys alone (the flash kernels "
               "with a selection on a TPU, XLA's scores elsewhere)",
+    SWA_CORE: "inside attention: the attention core of a SLIDING-WINDOW layer "
+              "(a query sees the latest window keys, itself counted), grouped "
+              "K/V heads or not: on a TPU the flash kernels over the tiles "
+              "the band touches alone, XLA's masked scores elsewhere; a "
+              "model's global layers stay under dsa_core or bare attn_core",
     CONV_MIX: "inside attention: a gated short convolution mixer (the "
               "input projection to three times the width, the two gates, the "
               "depthwise causal convolution, the output projection)",

@@ -51,6 +51,7 @@ def steps():
     from tests.test_lfm2_moe import tiny_config as lfm2
     from tests.test_nemotron_h import tiny_config as nemotron_h
     from tests.test_ouro import tiny_config as ouro
+    from tests.test_smallthinker import tiny_config as smallthinker
     return {
         "tiny_lm_step": (("lm1b", "tiny"), lm.LMConfig.tiny, 16, 4, "auto"),
         "tiny_olmoe_step": (("olmoe_1b_7b",), tiny_olmoe, 16, 4, "auto"),
@@ -68,7 +69,13 @@ def steps():
         "tiny_lfm2_moe_step": (("lfm2_24b_a2b",), lfm2, 32, 2, "auto"),
         "tiny_ouro_step": (("ouro_2_6b",), ouro, 32, 2, "auto"),
         "tiny_nemotron_h_step": (("nemotron_twotower_30b_a3b",), nemotron_h,
-                                 32, 2, "auto")}
+                                 32, 2, "auto"),
+        # (48 positions under a window of 10: the band's mask on XLA's
+        # path; on the kernels' three 16-row tiles a side, 5 of 6 walked)
+        "tiny_smallthinker_step": (("smallthinker_21b_a3b",), smallthinker,
+                                   48, 2, "auto"),
+        "tiny_smallthinker_flash_step": (("smallthinker_21b_a3b",),
+                                         smallthinker, 48, 2, "flash")}
 
 
 def tree_digest(params):

@@ -712,6 +712,34 @@ def test_the_kernels_tiles_fit_the_v5es_vmem(one_v5e_chip, shape, dtype,
     assert "flash_fwd" in text and "flash_bwd" in text
 
 
+@pytest.mark.parametrize("window", [None, 4096],
+                         ids=["every_earlier_key", "window_4096"])
+def test_the_kernels_fit_the_v5es_vmem_at_16384_over_groups_of_7(
+        one_v5e_chip, window):
+    """``smallthinker_train_1chip``'s two launches, [1, 16384, 28, 128]
+    bfloat16 over 4 K/V heads (groups of SEVEN query heads in the K/V head
+    index), causal, with the window's tile table and without: the forward
+    kernel and the ONE backward kernel (the head's float32 dq accumulator,
+    8 MB at 16,384 x 128, stays under half of VMEM) compile for the
+    described chip."""
+    from autodist_tpu.ops import flash_attention as fa, pallas_mode
+    assert fa._tiles(16384, 16384) == (512, 512)
+    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+                              sharding=one_v5e_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, True, window=window)
+                       .astype(jnp.float32))
+    with pallas_mode.compiling_for_tpu():
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
+
+
 def test_the_cells_whole_step_compiles_with_its_kernels(v5e_2x2, monkeypatch):
     """``olmoe_train_1chip``'s step as the benchmark builds it, compiled
     for the described chip with every pallas kernel as a Mosaic call
