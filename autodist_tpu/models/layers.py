@@ -29,6 +29,23 @@ DENSE_FFN_KEPT = "dense_ffn_kept"
 # norm's backward needs its INPUT, so a recomputed block that does not keep
 # it runs both products a second time
 SUBLAYER_OUT_KEPT = "sublayer_out_kept"
+# what a sequence mixer's INPUT projections made, as its element-wise pass
+# reads it, by name (Mamba-2's ``[z | xBC | dt]``, KDA's q / k / v and the
+# narrow halves of its low-rank pairs, the gated convolution's ``[B | C |
+# u]``): that pass's backward reads it again, so a recomputed block that does
+# not keep it runs the projections a second time. The gated convolution's
+# core has no kept name of its own, as the delta rule and the scan have, so
+# its gated product rides under this one: ``[B | C | u]`` alone LOST 0.35 %
+# of the v5e's step (XLA made the product again in the prologue of
+# ``out_proj``'s weight gradient and re-planned the fusions around it), the
+# two together won 2.56 % (PERF.md section 6, PR 52)
+MIXER_IN_KEPT = "mixer_in_kept"
+
+
+def mixer_in(x):
+    """``x``, an output of a sequence mixer's input projections, under
+    :data:`MIXER_IN_KEPT` (the identity outside a policy that saves it)."""
+    return checkpoint_name(x, MIXER_IN_KEPT)
 
 
 def causal_mask(seq_len: int) -> jnp.ndarray:
@@ -592,11 +609,11 @@ class ShortConv(nn.Module):
     @nn.compact
     def __call__(self, x):
         d = x.shape[-1]
-        bcu = linear(3 * d, self.dtype, "in_proj")(x)
+        bcu = mixer_in(linear(3 * d, self.dtype, "in_proj")(x))
         w = self.param("conv", conv_filter_init, (self.kernel_size, d))
         with scopes.scope(scopes.CONV_CORE):
             b, c, u = jnp.split(bcu, 3, axis=-1)
-            y = c * causal_conv(b * u, w.astype(self.dtype))
+            y = mixer_in(c * causal_conv(b * u, w.astype(self.dtype)))
         return linear(d, self.dtype, "out_proj")(y)
 
 
@@ -628,16 +645,20 @@ class KimiDeltaAttention(nn.Module):
         # traces no kernel for them)
         fused = not self.is_initializing() and kda.runs_as_kernels(D, D)
 
+        # what a block recomputed in the backward pass keeps of the input
+        # projections where the model's rule finds the room: q, k and v as
+        # ``kda_pre`` reads them, the [T, d] halves of the two low-rank pairs
+        # (their wide halves, 8.6 GFLOP each, are made again) and b's [T, H]
         def projected(name):
             w = self.param(name + "_conv", conv_filter_init, (K, H * D))
-            return dense(H * D, name + "_proj")(x), w
+            return mixer_in(dense(H * D, name + "_proj")(x)), w
 
         (xq, wq), (xk, wk), (xv, wv) = (projected(n) for n in "qkv")
         a_log = self.param("A_log", a_log_init, (H,))
         # softplus(dt_bias) log-uniform in [1e-3, 1e-1]
         dt_bias = self.param("dt_bias", dt_bias_init, (H * D,))
-        f = dense(H * D, "f_b_proj")(dense(D, "f_a_proj")(x))
-        beta = nn.sigmoid(dense(H, "b_proj")(x).astype(jnp.float32))
+        f = dense(H * D, "f_b_proj")(mixer_in(dense(D, "f_a_proj")(x)))
+        beta = nn.sigmoid(mixer_in(dense(H, "b_proj")(x)).astype(jnp.float32))
         if fused:
             q, k, v, g = kda.kda_pre(xq, xk, xv, f, wq, wk, wv, a_log,
                                      dt_bias, self.dtype)
@@ -656,7 +677,7 @@ class KimiDeltaAttention(nn.Module):
         # core again (``models/lm.py:TransformerLM._block``)
         o = checkpoint_name(o, KDA_CORE_OUT)
         scale = self.param("o_norm", nn.initializers.ones, (D,))
-        gate = dense(H * D, "g_b_proj")(dense(D, "g_a_proj")(x))
+        gate = dense(H * D, "g_b_proj")(mixer_in(dense(D, "g_a_proj")(x)))
         gated = kda.kda_post if fused else kda_output
         return dense(x.shape[-1], "o_proj")(
             gated(o, gate, scale, self.norm_eps, self.dtype))
@@ -737,10 +758,14 @@ class Mamba2Mixer(nn.Module):
         scale = self.param("norm", nn.initializers.ones, (inner,))
         if not self.is_initializing() and ssd.mixer_runs_fused(
                 P, N, H // G, G, c.chunk, c.conv_size):
-            # (the passes hold no parameter: an init traces no kernel)
+            # (the passes hold no parameter: an init traces no kernel; a
+            # block recomputed in the backward pass keeps ``in_proj``'s output
+            # by name where the model's rule finds the room, IN THE FORM THE
+            # PASS READS: the buffer the forward writes anyway, and no
+            # transpose of it)
             xs, dt, dta, bs, cs, zx = ssd.mamba_pre(
-                jnp.moveaxis(zxbcdt, 1, 2), w, b, dt_bias, a_log, G, N,
-                c.chunk, self.dtype)
+                mixer_in(jnp.moveaxis(zxbcdt, 1, 2)), w, b, dt_bias, a_log,
+                G, N, c.chunk, self.dtype)
             with scopes.scope(scopes.SSD_SCAN):
                 y, total = ssd.ssd_tokens_last(xs, dt, dta, bs, cs, d_skip,
                                                c.chunk, self.dtype)
@@ -748,8 +773,8 @@ class Mamba2Mixer(nn.Module):
             y = jnp.moveaxis(ssd.mamba_post(y, zx, scale, self.norm_eps,
                                             self.dtype), 1, 2)
         else:
-            xs, dt, a, bs, cs, z = mamba_inputs(zxbcdt, w, b, dt_bias, a_log,
-                                                c, self.dtype)
+            xs, dt, a, bs, cs, z = mamba_inputs(mixer_in(zxbcdt), w, b,
+                                                dt_bias, a_log, c, self.dtype)
             with scopes.scope(scopes.SSD_SCAN):
                 y, carry = ssd.ssd_chunked(xs, dt, a, bs, cs, d_skip, c.chunk,
                                            self.dtype)

@@ -21,9 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from autodist_tpu.models.layers import (DENSE_FFN_KEPT, KDA_CORE_OUT,
-                                        SUBLAYER_OUT_KEPT, IndexerConfig,
-                                        KDAConfig, Mamba2Config, MLAConfig,
-                                        RouterConfig,
+                                        MIXER_IN_KEPT, SUBLAYER_OUT_KEPT,
+                                        IndexerConfig, KDAConfig,
+                                        Mamba2Config, MLAConfig, RouterConfig,
                                         SparseEmbed, TransformerBlock,
                                         YarnConfig, causal_mask, make_norm)
 from autodist_tpu.telemetry import spans as tel
@@ -576,13 +576,16 @@ class TransformerLM(nn.Module):
     # ... of whose routed layers the LAST this many keep their held
     # experts' hidden products, of whose dense layers the LAST this many
     # their feed-forward's, of whose sandwich-normed layers the LAST this
-    # many what their two output norms read, and of whose routed layers the
-    # LAST this many their shared experts' hidden products
-    # (``auto_kept_layers``'s rule for all four)
+    # many what their two output norms read, of whose routed layers the
+    # LAST this many their shared experts' hidden products, and of whose
+    # layers with a sequence mixer (Mamba-2, KDA, the gated convolution) the
+    # LAST this many what the mixer's input projections made
+    # (``auto_kept_layers``'s rule for all five)
     kept_expert_layers: int = 0
     kept_dense_layers: int = 0
     kept_sublayer_out_layers: int = 0
     kept_shared_layers: int = 0
+    kept_mixer_in_layers: int = 0
 
     def _embed(self, input_ids, positions):
         """Token embedding (scaled by sqrt(d) where the config says so)
@@ -673,7 +676,14 @@ class TransformerLM(nn.Module):
         # attention's output product and the feed-forward's down
         # projection, which the norms' backward would otherwise have the
         # recomputed forward make again: the last
-        # ``kept_sublayer_out_layers`` layers keep both)
+        # ``kept_sublayer_out_layers`` layers keep both. What a sequence
+        # mixer's input projections made, as the mixer's element-wise pass
+        # reads it and that pass's backward reads it again: 169 MB a layer
+        # at Mamba-2's 10,304 rows on 8,192 tokens against an ``in_proj`` of
+        # 0.45 TFLOP, 206 MB at KDA's q, k and v of 4,096 against three such
+        # products, 134 MB at a gated convolution's 6,144 and its gated
+        # product's 2,048: the last ``kept_mixer_in_layers`` layers that have
+        # such a mixer keep it)
         from autodist_tpu.ops.dsa import KEPT as DSA_CHOICE_KEPT
         from autodist_tpu.ops.flash_attention import KEPT as FLASH_CORE_KEPT
         from autodist_tpu.ops.ssd import KEPT as SSD_CORE_KEPT
@@ -690,6 +700,9 @@ class TransformerLM(nn.Module):
             kept += (DENSE_FFN_KEPT,)
         if i >= cfg.num_layers - self.kept_sublayer_out_layers:
             kept += (SUBLAYER_OUT_KEPT,)
+        mixers = mixer_in_layer_indices(cfg)
+        if i in mixers[max(0, len(mixers) - self.kept_mixer_in_layers):]:
+            kept += (MIXER_IN_KEPT,)
         block = nn.remat(
             TransformerBlock,
             policy=jax.checkpoint_policies.save_only_these_names(*kept)
@@ -959,6 +972,38 @@ def routed_layer_indices(cfg: LMConfig) -> Tuple[int, ...]:
                        cfg.num_layers))
 
 
+# the token mixers whose input projections' outputs carry
+# ``models/layers.py:MIXER_IN_KEPT``, by their name in ``layer_types``
+SEQUENCE_MIXERS = ("mamba2", "kda", "conv")
+
+
+def mixer_in_layer_indices(cfg: LMConfig) -> Tuple[int, ...]:
+    """The layers whose token mixer is one of :data:`SEQUENCE_MIXERS`, by
+    index."""
+    return tuple(i for i, t in enumerate(cfg.layer_types or ())
+                 if t in SEQUENCE_MIXERS)
+
+
+def mixer_in_width(cfg: LMConfig) -> int:
+    """The features a token that ONE such layer keeps under
+    :data:`models.layers.MIXER_IN_KEPT`, from the widths: Mamba-2's
+    ``in_proj`` output ``[z | xBC | dt]`` (2 H P + 2 G N + H); KDA's q, k
+    and v (H d each), the narrow halves of its two low-rank pairs (d each)
+    and b's H; the gated convolution's ``[B | C | u]`` and its gated product
+    (3 d_model + d_model). 0 without such a layer. No model of the zoo has
+    two kinds; one that had would be booked by the wider."""
+    widths = {
+        "mamba2": (2 * cfg.mamba_num_heads * cfg.mamba_head_dim
+                   + 2 * cfg.mamba_n_groups * cfg.ssm_state_size
+                   + cfg.mamba_num_heads),
+        "kda": (3 * cfg.kda_num_heads * cfg.kda_head_dim
+                + 2 * cfg.kda_head_dim + cfg.kda_num_heads),
+        "conv": 4 * cfg.d_model,
+    }
+    return max((widths[t] for t in SEQUENCE_MIXERS
+                if t in (cfg.layer_types or ())), default=0)
+
+
 class KeptLayers(NamedTuple):
     """:func:`auto_kept_layers`' counts, the LAST so many layers of each
     kind, in the order they are booked."""
@@ -966,6 +1011,7 @@ class KeptLayers(NamedTuple):
     dense: int = 0          # dense layers, their SwiGLU's products
     sublayer_outs: int = 0  # sandwich-normed layers, the output norms' inputs
     shared: int = 0         # routed layers, their shared experts' products
+    mixer_in: int = 0       # sequence-mixer layers, the input projections'
 
 
 def auto_kept_layers(remat_blocks: bool, param_count: int,
@@ -975,8 +1021,9 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
                      dense_layers: int = 0, dense_width: int = 0,
                      sandwich_layers: int = 0, d_model: int = 0,
                      shared_width: int = 0, loop_steps: int = 1,
-                     core_bytes: int = 0,
-                     expert_products: int = 2) -> KeptLayers:
+                     core_bytes: int = 0, expert_products: int = 2,
+                     mixer_layers: int = 0,
+                     mixer_width: int = 0) -> KeptLayers:
     """Of a recomputed model's layers, how many keep by name what the
     recomputed forward would otherwise make a second time only for the
     backward to read (``TransformerLM._block`` saves the names in the LAST
@@ -1002,7 +1049,11 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
       arrays an application, for the attention's output product and the
       feed-forward's down projection;
     - the shared experts' SwiGLU (the dense name in a routed block): two
-      ``[tokens, shared_width]`` arrays a routed layer.
+      ``[tokens, shared_width]`` arrays a routed layer;
+    - what a sequence mixer's input projections made
+      (``models/layers.py:MIXER_IN_KEPT``): ``[tokens, mixer_width]``
+      (:func:`mixer_in_width`) an application of each of the
+      ``mixer_layers`` layers that have such a mixer.
 
     All 0 where blocks are not recomputed (nothing is made twice) and off a
     TPU."""
@@ -1012,7 +1063,7 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
                - 12.0 * param_count)
     a_layer = kept_layer_bytes(tokens, itemsize, held_stack, dense_width,
                                d_model, shared_width, loop_steps,
-                               expert_products)
+                               expert_products, mixer_width)
 
     def book(layers, nbytes):
         nonlocal room
@@ -1024,14 +1075,15 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
     room = max(0.0, room - core_bytes)
     return KeptLayers(experts, book(dense_layers, a_layer.dense),
                       book(sandwich_layers, a_layer.sublayer_outs),
-                      book(routed_layers, a_layer.shared))
+                      book(routed_layers, a_layer.shared),
+                      book(mixer_layers, a_layer.mixer_in))
 
 
 def kept_layer_bytes(tokens: int, itemsize: int,
                      held_stack: Optional[Tuple[int, int, int]],
                      dense_width: int, d_model: int, shared_width: int,
-                     loop_steps: int = 1,
-                     expert_products: int = 2) -> KeptLayers:
+                     loop_steps: int = 1, expert_products: int = 2,
+                     mixer_width: int = 0) -> KeptLayers:
     """What ONE layer of each kind keeps by name over all its
     ``loop_steps`` applications (0 for a kind the model has none of)."""
     return KeptLayers(
@@ -1040,7 +1092,8 @@ def kept_layer_bytes(tokens: int, itemsize: int,
         loop_steps * dense_kept_bytes(tokens, dense_width, itemsize),
         loop_steps * sublayer_out_kept_bytes(tokens, d_model, itemsize),
         loop_steps * dense_kept_bytes(tokens, shared_width, itemsize,
-                                      expert_products))
+                                      expert_products),
+        loop_steps * itemsize * tokens * mixer_width)
 
 
 def flash_kept_bytes(tokens: int, num_heads: int, qk_dim: int, v_dim: int,
@@ -1229,6 +1282,7 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     shared_width = (cfg.shared_expert_dim
                     or cfg.num_shared_experts * cfg.mlp_dim) if routed else 0
     expert_products = 2 if cfg.expert_gated else 1
+    mixer_width = mixer_in_width(cfg)
     kept = auto_kept_layers(
         remat_blocks, param_count, hbm_bytes, tokens, itemsize,
         routed_layers=n_routed, held_stack=held_stack,
@@ -1236,16 +1290,19 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         sandwich_layers=cfg.num_layers if cfg.sandwich_norm else 0,
         d_model=cfg.d_model, shared_width=shared_width,
         loop_steps=cfg.loop_steps, core_bytes=kept_core_bytes,
-        expert_products=expert_products)
+        expert_products=expert_products,
+        mixer_layers=len(mixer_in_layer_indices(cfg)),
+        mixer_width=mixer_width)
     # (applications counted)
     kept_bytes = [n * nbytes for n, nbytes in zip(kept, kept_layer_bytes(
         tokens, itemsize, held_stack, cfg.dense_dim, cfg.d_model,
-        shared_width, cfg.loop_steps, expert_products))]
+        shared_width, cfg.loop_steps, expert_products, mixer_width))]
     model = TransformerLM(cfg, attn_fn=attn_fn, remat_blocks=remat_blocks,
                           kept_expert_layers=kept.experts,
                           kept_dense_layers=kept.dense,
                           kept_sublayer_out_layers=kept.sublayer_outs,
-                          kept_shared_layers=kept.shared)
+                          kept_shared_layers=kept.shared,
+                          kept_mixer_in_layers=kept.mixer_in)
     router_load = SHARE_LOAD if cfg.experts_held is not None else ROUTER_LOAD
     router_losses = cfg.router_activation == "softmax"
     indexed = bool(cfg.indexer_num_heads)
@@ -1347,7 +1404,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         tel.gauge_set("model.remat_blocks",
                       cfg.num_layers if remat_blocks else 0)
         for what, layers, nbytes in zip(("expert", "dense", "sublayer_out",
-                                         "shared"), kept, kept_bytes):
+                                         "shared", "mixer_in"), kept,
+                                        kept_bytes):
             tel.gauge_set("model.kept_%s_layers" % what, layers)
             tel.gauge_set("model.kept_%s_bytes" % what, nbytes)
         tel.gauge_set("model.loop_steps", cfg.loop_steps)

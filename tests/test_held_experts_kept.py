@@ -125,7 +125,7 @@ LFM2 = cell(558424448, 4, 8, 1536)
     ("no TPU", dict(DEEPSEEK, hbm_bytes=None), 0)])
 def test_as_many_routed_layers_keep_their_products_as_fit(
         what, inputs, layers):
-    assert lm.auto_kept_layers(**inputs) == (layers, 0, 0, 0)
+    assert lm.auto_kept_layers(**inputs) == lm.KeptLayers(layers)
     if layers:
         a_layer = lm.held_expert_kept_bytes(
             inputs["tokens"], inputs["held_stack"], inputs.get("itemsize", 2))

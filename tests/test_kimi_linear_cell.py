@@ -332,9 +332,12 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     step = out["train_step"]
     # float32 master weights and Adam's two moments: 12 B a parameter
     assert abs(step["argument_size_in_bytes"] - 12 * 602434432) < 1 << 20
-    # 5.25 GB since PR 46 keeps the four shared experts' gate and up
-    # products (0.13 GB by the closed form, 0.10 of scratch) and nothing
-    # but the state is at rest beside it;
+    # 5.79 GB since PR 52 keeps what the four KDA mixers' input projections
+    # made (q, k, v, the narrow halves of the low-rank pairs and b: 0.82 GB
+    # by the closed form, 0.54 of scratch, since the recomputed arrays stood
+    # at the peak before); 5.25 GB since PR 46 keeps the four shared
+    # experts' gate and up products (0.13 GB by the closed form, 0.10 of
+    # scratch) and nothing but the state is at rest beside it;
     # 5.16 GB since PR 45 keeps the dense layer's gate and up products
     # (0.30 GB, the closed form's 4 x 8,192 x 9,216 B to a megabyte); 4.85
     # GB since PR 42's fused passes keep no float32 intermediate of
@@ -343,8 +346,8 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
     # 4 x 268 MB; 4.65 with the flash kernel's output and q kept, PR 32;
     # 4.47, PR 30; 4.58, PR 29); the chip loaded it, cold and from the
     # cache (PERF.md section 6)
-    assert 4.8e9 < step["temp_size_in_bytes"] < 5.3e9
-    assert step["live_bytes_estimate"] < 12.8e9
+    assert 5.5e9 < step["temp_size_in_bytes"] < 6.0e9
+    assert step["live_bytes_estimate"] < 13.3e9
     # 9 expert products a routed layer, none made a second time
     products = [line for line in text.splitlines()
                 if " convolution(" in line and "moe_experts" in line]
@@ -356,3 +359,13 @@ def test_the_cells_whole_step_compiles_for_a_v5e_and_fits_it(monkeypatch):
              and ("mlp/gate_proj" in line or "mlp/up_proj" in line)]
     assert dense
     assert not [line for line in dense if "rematted_computation" in line]
+    # ... nor a KDA mixer's q, k, v, f_a, g_a and b projections (PR 52); the
+    # wide halves of the low-rank pairs read what is kept and are made again
+    made_again = {name: [line for line in text.splitlines()
+                         if " convolution(" in line
+                         and "/kda/%s/" % name in line
+                         and "rematted_computation" in line]
+                  for name in ("q_proj", "k_proj", "v_proj", "f_a_proj",
+                               "g_a_proj", "b_proj", "f_b_proj", "g_b_proj")}
+    assert {name for name, lines in made_again.items() if lines} \
+        == {"f_b_proj", "g_b_proj"}

@@ -925,7 +925,9 @@ TRACE_TIME_GAUGES = ("lean_head.chunks", "lean_head.chunk_width",
                      "model.kept_sublayer_out_layers",
                      "model.kept_sublayer_out_bytes",
                      "model.kept_shared_layers", "model.kept_shared_bytes",
-                     "model.loop_steps", "model.block_applications",
+                     "model.kept_mixer_in_layers",
+                     "model.kept_mixer_in_bytes", "model.loop_steps",
+                     "model.block_applications",
                      "model.kept_core_bytes")
 
 
