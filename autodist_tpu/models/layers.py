@@ -40,6 +40,10 @@ SUBLAYER_OUT_KEPT = "sublayer_out_kept"
 # ``out_proj``'s weight gradient and re-planned the fusions around it), the
 # two together won 2.56 % (PERF.md section 6, PR 52)
 MIXER_IN_KEPT = "mixer_in_kept"
+# a gated softmax attention's gate projection's product ``[tokens, heads,
+# head_dim]``, by name: the sigmoid's and the product's backward read it, so
+# a recomputed block that does not keep it runs the projection a second time
+ATTN_GATE_KEPT = "attn_gate_kept"
 
 
 def mixer_in(x):
@@ -207,10 +211,13 @@ class MultiHeadAttention(nn.Module):
     attention: query head h reads K/V head ``h // (num_heads /
     num_kv_heads)``); optionally an RMSNorm of q and k (over all projected
     features, OLMoE's, or per head, Qwen3's), rotary positions, a
-    learned choice of the keys each query attends (``indexer``), and a
+    learned choice of the keys each query attends (``indexer``), a
     sliding ``window``: a query sees the latest ``window`` keys, its own
     position counted (``ops/flash_attention.py`` says the same of its
-    kernels, which then walk the band's tiles alone).
+    kernels, which then walk the band's tiles alone), and an output gate
+    (``gated``: a fifth projection ``gate`` of the layer's input to every
+    head's features, whose sigmoid multiplies the core's output before the
+    output projection, ``(o * sigmoid(x W_g)) W_o``; afmoe's).
 
     Three modes share one parameter set (submodules are created in the
     same order on every path, so flax resolves identical names):
@@ -243,6 +250,8 @@ class MultiHeadAttention(nn.Module):
     indexer: Optional[IndexerConfig] = None
     # the latest keys a query sees, itself counted; None = every earlier one
     window: Optional[int] = None
+    # the core's output times the sigmoid of a ``gate`` projection of x
+    gated: bool = False
 
     @nn.compact
     def __call__(self, x, mask=None, cache=None, cursor=None, alive=None,
@@ -313,6 +322,10 @@ class MultiHeadAttention(nn.Module):
         else:
             with scopes.scope(scopes.ATTN_CORE):
                 out = self._plain_core(q, k, v, mask)
+        if self.gated:
+            with scopes.scope(scopes.ATTN_GATE):
+                gate = checkpoint_name(dense("gate")(x), ATTN_GATE_KEPT)
+                out = out * nn.sigmoid(gate)
         out = nn.DenseGeneral(features=d_model, axis=(-2, -1),
                               dtype=self.dtype, use_bias=self.use_bias,
                               name="out")(out)
@@ -894,7 +907,8 @@ class TransformerBlock(nn.Module):
     sub-layer behind ONE norm). ``window`` is THIS layer's sliding window
     and ``rope_theta`` THIS layer's rotation (a model may window and rotate
     some layers and not others); a router that ``reads_mixer_input`` is
-    handed the first norm's output."""
+    handed the first norm's output; ``gated_attention`` gives the softmax
+    attention its output gate."""
     num_heads: int
     head_dim: int
     mlp_dim: int
@@ -921,6 +935,7 @@ class TransformerBlock(nn.Module):
     mamba: Optional[Mamba2Config] = None
     only: Optional[str] = None          # None (both) | "mixer" | "ffn"
     window: Optional[int] = None
+    gated_attention: bool = False
 
     def _mix(self, h, mask, cache, cursor, alive, return_kv, positions):
         """The block's token mixer on the normed input."""
@@ -932,7 +947,8 @@ class TransformerBlock(nn.Module):
                 qk_norm_eps=self.norm_eps if self.qk_norm else None,
                 rope_theta=self.rope_theta, num_kv_heads=self.num_kv_heads,
                 head_norm_eps=self.norm_eps if self.qk_head_norm else None,
-                indexer=self.indexer, window=self.window)(
+                indexer=self.indexer, window=self.window,
+                gated=self.gated_attention)(
                 h, mask, cache=cache, cursor=cursor, alive=alive,
                 return_kv=return_kv, positions=positions)
         if cache is not None or return_kv:

@@ -20,8 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from autodist_tpu.models.layers import (DENSE_FFN_KEPT, KDA_CORE_OUT,
-                                        MIXER_IN_KEPT, SUBLAYER_OUT_KEPT,
+from autodist_tpu.models.layers import (ATTN_GATE_KEPT, DENSE_FFN_KEPT,
+                                        KDA_CORE_OUT, MIXER_IN_KEPT,
+                                        SUBLAYER_OUT_KEPT,
                                         IndexerConfig, KDAConfig,
                                         Mamba2Config, MLAConfig, RouterConfig,
                                         SparseEmbed, TransformerBlock,
@@ -74,6 +75,10 @@ class LMConfig:
     head_dim: Optional[int] = None
     num_kv_heads: Optional[int] = None
     qk_head_norm: bool = False
+    # the softmax attention's output is gated: ``(o * sigmoid(u W_g)) W_o``
+    # with a fifth projection ``W_g`` of the layer's normed input u to every
+    # head's features (afmoe's gated attention)
+    gated_attention: bool = False
     # a learned sparse attention (``sa_config``): > 0 heads of an indexer
     # that chooses ``indexer_topk`` keys for every query, its scores made
     # ``indexer_q_chunk`` queries at a time, ``indexer_rope_dim`` of its
@@ -282,6 +287,11 @@ class LMConfig:
                 "an indexer chooses keys for the softmax attention: it needs "
                 "indexer_head_dim, indexer_topk and layer_types of "
                 "'attention' alone")
+        if self.gated_attention and "attention" not in (
+                types or ("attention",)):
+            raise ValueError(
+                "gated_attention gates the softmax attention's output: "
+                "layer_types %r names no 'attention' layer" % (types,))
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm (over all features) or qk_head_norm "
                              "(per head), not both")
@@ -561,6 +571,43 @@ class LMConfig:
                    expert_gate_activation="relu", **kw)
 
     @classmethod
+    def trinity_mini_26b_a3b(cls, **kw):
+        """Trinity-Mini (26B-A3B) as its ``config.json`` publishes it
+        (huggingface.co/arcee-ai/Trinity-Mini, ``model_type: afmoe``): 32
+        layers of hidden 2,048 without a bias, FOUR RMSNorms (eps 1e-5) a
+        block (each sub-layer's input AND output), 32 query heads over 4
+        K/V heads of 128 with a per-head RMSNorm of q and k and a GATED
+        output (``(o * sigmoid(u W_g)) W_o``, ``W_g`` 2,048 -> 32 x 128 on
+        the layer's normed input); by ``layer_types``, three
+        ``sliding_attention`` layers (the latest 2,048 keys, q and k rotated
+        over all 128 features at theta 10,000) to one ``full_attention``
+        layer (every earlier key, NO position signal); a dense SwiGLU of
+        6,144 in layers 0-1 (``num_dense_layers``), then 128 sigmoid-routed
+        SwiGLU experts of 1,024, 8 a token chosen by score + bias, gates
+        renormalised over the chosen x 2.826 (``route_norm``,
+        ``route_scale``), beside one shared expert; the embedding times
+        sqrt(2,048) (``mup_enabled``); an untied head over 200,192 words.
+        ``num_layers`` cuts ``layer_types`` from its start; a cut that
+        starts elsewhere hands its own ``window_layers`` / ``rope_layers``.
+        The gate, the per-head norm, the four norms and the global layers'
+        missing rotation are the released implementation's without a key in
+        the row: assumptions the benchmark's configuration file lists."""
+        n = kw.setdefault("num_layers", 32)
+        kw.setdefault("max_seq_len", 131072)
+        layout = tuple(int(i % 4 != 3) for i in range(n))
+        kw.setdefault("window_layers", layout)
+        kw.setdefault("rope_layers", layout)
+        return cls(vocab_size=200192, d_model=2048, num_heads=32,
+                   head_dim=128, num_kv_heads=4, qk_head_norm=True,
+                   gated_attention=True, mlp_dim=1024, norm="rmsnorm",
+                   norm_eps=1e-5, rope_theta=10000.0, sliding_window=2048,
+                   attention_bias=False, head_bias=False, embed_scale=True,
+                   sandwich_norm=True, first_k_dense_replace=2,
+                   dense_dim=6144, num_experts=128, experts_per_token=8,
+                   router_activation="sigmoid", moe_renormalize=True,
+                   routed_scaling_factor=2.826, num_shared_experts=1, **kw)
+
+    @classmethod
     def tiny(cls, **kw):
         return cls(vocab_size=128, d_model=32, num_layers=2, num_heads=2,
                    mlp_dim=64, max_seq_len=64, **kw)
@@ -577,15 +624,17 @@ class TransformerLM(nn.Module):
     # experts' hidden products, of whose dense layers the LAST this many
     # their feed-forward's, of whose sandwich-normed layers the LAST this
     # many what their two output norms read, of whose routed layers the
-    # LAST this many their shared experts' hidden products, and of whose
+    # LAST this many their shared experts' hidden products, of whose
     # layers with a sequence mixer (Mamba-2, KDA, the gated convolution) the
-    # LAST this many what the mixer's input projections made
-    # (``auto_kept_layers``'s rule for all five)
+    # LAST this many what the mixer's input projections made, and of whose
+    # gated softmax-attention layers the LAST this many their gate
+    # projection's product (``auto_kept_layers``'s rule for all six)
     kept_expert_layers: int = 0
     kept_dense_layers: int = 0
     kept_sublayer_out_layers: int = 0
     kept_shared_layers: int = 0
     kept_mixer_in_layers: int = 0
+    kept_attn_gate_layers: int = 0
 
     def _embed(self, input_ids, positions):
         """Token embedding (scaled by sqrt(d) where the config says so)
@@ -683,7 +732,10 @@ class TransformerLM(nn.Module):
         # 0.45 TFLOP, 206 MB at KDA's q, k and v of 4,096 against three such
         # products, 134 MB at a gated convolution's 6,144 and its gated
         # product's 2,048: the last ``kept_mixer_in_layers`` layers that have
-        # such a mixer keep it)
+        # such a mixer keep it. A gated attention's gate projection's
+        # product, 134 MB a layer at 32 heads of 128 on 16,384 tokens
+        # against a projection of 0.27 TFLOP: the last
+        # ``kept_attn_gate_layers`` gated layers keep it)
         from autodist_tpu.ops.dsa import KEPT as DSA_CHOICE_KEPT
         from autodist_tpu.ops.flash_attention import KEPT as FLASH_CORE_KEPT
         from autodist_tpu.ops.ssd import KEPT as SSD_CORE_KEPT
@@ -703,6 +755,11 @@ class TransformerLM(nn.Module):
         mixers = mixer_in_layer_indices(cfg)
         if i in mixers[max(0, len(mixers) - self.kept_mixer_in_layers):]:
             kept += (MIXER_IN_KEPT,)
+        gated = gated_attention_layer_indices(cfg)
+        if i in gated:
+            kw["gated_attention"] = True
+        if i in gated[max(0, len(gated) - self.kept_attn_gate_layers):]:
+            kept += (ATTN_GATE_KEPT,)
         block = nn.remat(
             TransformerBlock,
             policy=jax.checkpoint_policies.save_only_these_names(*kept)
@@ -984,6 +1041,16 @@ def mixer_in_layer_indices(cfg: LMConfig) -> Tuple[int, ...]:
                  if t in SEQUENCE_MIXERS)
 
 
+def gated_attention_layer_indices(cfg: LMConfig) -> Tuple[int, ...]:
+    """The softmax-attention layers whose output is gated (their ``gate``
+    projection's product carries ``models/layers.py:ATTN_GATE_KEPT``), by
+    index."""
+    if not cfg.gated_attention:
+        return ()
+    types = cfg.layer_types or ("attention",) * cfg.num_layers
+    return tuple(i for i, t in enumerate(types) if t == "attention")
+
+
 def mixer_in_width(cfg: LMConfig) -> int:
     """The features a token that ONE such layer keeps under
     :data:`models.layers.MIXER_IN_KEPT`, from the widths: Mamba-2's
@@ -1012,6 +1079,7 @@ class KeptLayers(NamedTuple):
     sublayer_outs: int = 0  # sandwich-normed layers, the output norms' inputs
     shared: int = 0         # routed layers, their shared experts' products
     mixer_in: int = 0       # sequence-mixer layers, the input projections'
+    attn_gate: int = 0      # gated attention layers, the gate projection's
 
 
 def auto_kept_layers(remat_blocks: bool, param_count: int,
@@ -1022,8 +1090,9 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
                      sandwich_layers: int = 0, d_model: int = 0,
                      shared_width: int = 0, loop_steps: int = 1,
                      core_bytes: int = 0, expert_products: int = 2,
-                     mixer_layers: int = 0,
-                     mixer_width: int = 0) -> KeptLayers:
+                     mixer_layers: int = 0, mixer_width: int = 0,
+                     gate_layers: int = 0,
+                     gate_width: int = 0) -> KeptLayers:
     """Of a recomputed model's layers, how many keep by name what the
     recomputed forward would otherwise make a second time only for the
     backward to read (``TransformerLM._block`` saves the names in the LAST
@@ -1053,7 +1122,11 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
     - what a sequence mixer's input projections made
       (``models/layers.py:MIXER_IN_KEPT``): ``[tokens, mixer_width]``
       (:func:`mixer_in_width`) an application of each of the
-      ``mixer_layers`` layers that have such a mixer.
+      ``mixer_layers`` layers that have such a mixer;
+    - a gated attention's gate projection's product
+      (``models/layers.py:ATTN_GATE_KEPT``): ``[tokens, gate_width]`` (heads
+      x head_dim) an application of each of the ``gate_layers`` gated
+      layers.
 
     All 0 where blocks are not recomputed (nothing is made twice) and off a
     TPU."""
@@ -1063,7 +1136,7 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
                - 12.0 * param_count)
     a_layer = kept_layer_bytes(tokens, itemsize, held_stack, dense_width,
                                d_model, shared_width, loop_steps,
-                               expert_products, mixer_width)
+                               expert_products, mixer_width, gate_width)
 
     def book(layers, nbytes):
         nonlocal room
@@ -1076,14 +1149,16 @@ def auto_kept_layers(remat_blocks: bool, param_count: int,
     return KeptLayers(experts, book(dense_layers, a_layer.dense),
                       book(sandwich_layers, a_layer.sublayer_outs),
                       book(routed_layers, a_layer.shared),
-                      book(mixer_layers, a_layer.mixer_in))
+                      book(mixer_layers, a_layer.mixer_in),
+                      book(gate_layers, a_layer.attn_gate))
 
 
 def kept_layer_bytes(tokens: int, itemsize: int,
                      held_stack: Optional[Tuple[int, int, int]],
                      dense_width: int, d_model: int, shared_width: int,
                      loop_steps: int = 1, expert_products: int = 2,
-                     mixer_width: int = 0) -> KeptLayers:
+                     mixer_width: int = 0,
+                     gate_width: int = 0) -> KeptLayers:
     """What ONE layer of each kind keeps by name over all its
     ``loop_steps`` applications (0 for a kind the model has none of)."""
     return KeptLayers(
@@ -1093,7 +1168,8 @@ def kept_layer_bytes(tokens: int, itemsize: int,
         loop_steps * sublayer_out_kept_bytes(tokens, d_model, itemsize),
         loop_steps * dense_kept_bytes(tokens, shared_width, itemsize,
                                       expert_products),
-        loop_steps * itemsize * tokens * mixer_width)
+        loop_steps * itemsize * tokens * mixer_width,
+        loop_steps * itemsize * tokens * gate_width)
 
 
 def flash_kept_bytes(tokens: int, num_heads: int, qk_dim: int, v_dim: int,
@@ -1283,6 +1359,10 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
                     or cfg.num_shared_experts * cfg.mlp_dim) if routed else 0
     expert_products = 2 if cfg.expert_gated else 1
     mixer_width = mixer_in_width(cfg)
+    gated_layers = len(gated_attention_layer_indices(cfg))
+    gate_width = (cfg.num_heads * (cfg.head_dim
+                                   or cfg.d_model // cfg.num_heads)
+                  if gated_layers else 0)
     kept = auto_kept_layers(
         remat_blocks, param_count, hbm_bytes, tokens, itemsize,
         routed_layers=n_routed, held_stack=held_stack,
@@ -1292,17 +1372,20 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         loop_steps=cfg.loop_steps, core_bytes=kept_core_bytes,
         expert_products=expert_products,
         mixer_layers=len(mixer_in_layer_indices(cfg)),
-        mixer_width=mixer_width)
+        mixer_width=mixer_width, gate_layers=gated_layers,
+        gate_width=gate_width)
     # (applications counted)
     kept_bytes = [n * nbytes for n, nbytes in zip(kept, kept_layer_bytes(
         tokens, itemsize, held_stack, cfg.dense_dim, cfg.d_model,
-        shared_width, cfg.loop_steps, expert_products, mixer_width))]
+        shared_width, cfg.loop_steps, expert_products, mixer_width,
+        gate_width))]
     model = TransformerLM(cfg, attn_fn=attn_fn, remat_blocks=remat_blocks,
                           kept_expert_layers=kept.experts,
                           kept_dense_layers=kept.dense,
                           kept_sublayer_out_layers=kept.sublayer_outs,
                           kept_shared_layers=kept.shared,
-                          kept_mixer_in_layers=kept.mixer_in)
+                          kept_mixer_in_layers=kept.mixer_in,
+                          kept_attn_gate_layers=kept.attn_gate)
     router_load = SHARE_LOAD if cfg.experts_held is not None else ROUTER_LOAD
     router_losses = cfg.router_activation == "softmax"
     indexed = bool(cfg.indexer_num_heads)
@@ -1404,8 +1487,8 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         tel.gauge_set("model.remat_blocks",
                       cfg.num_layers if remat_blocks else 0)
         for what, layers, nbytes in zip(("expert", "dense", "sublayer_out",
-                                         "shared", "mixer_in"), kept,
-                                        kept_bytes):
+                                         "shared", "mixer_in", "attn_gate"),
+                                        kept, kept_bytes):
             tel.gauge_set("model.kept_%s_layers" % what, layers)
             tel.gauge_set("model.kept_%s_bytes" % what, nbytes)
         tel.gauge_set("model.loop_steps", cfg.loop_steps)

@@ -57,6 +57,7 @@ EMBED = "embed"
 BLOCKS = "blocks"
 ATTENTION = "attention"
 ATTN_CORE = "attn_core"
+ATTN_GATE = "attn_gate"
 DENSE_FFN = "dense_ffn"
 PLAIN_HEAD = "plain_head"
 MOE = "moe"
@@ -105,6 +106,11 @@ SCOPES: Dict[str, str] = {
                "cached decode step's): the flash kernels on a TPU, XLA's "
                "scores elsewhere; KDA, the short convolution and Mamba-2 "
                "have no such core",
+    ATTN_GATE: "inside attention, outside attn_core: a GATED softmax "
+               "attention's output gate (afmoe's): the gate projection of "
+               "the layer's normed input to every head's features, its "
+               "sigmoid and the product with the core's output, before the "
+               "output projection",
     DENSE_FFN: "inside blocks: a block's DENSE feed-forward alone, the "
                "SwiGLU of a leading dense layer or the two-matmul GELU one; "
                "its pre-norm, a sandwich norm, dropout and the residual add "

@@ -45,6 +45,7 @@ def steps():
     """name -> (the presets of ``LMConfig`` the row stands for, how to build
     the tiny config, seq, rows, ``attention``). ``LMConfig.lm1b`` and
     ``LMConfig.tiny`` differ in widths alone, so one row holds both."""
+    from tests.test_afmoe import tiny_config as afmoe
     from tests.test_deepseek_v2 import tiny_config as deepseek
     from tests.test_keye_vl2 import tiny_config as keye
     from tests.test_kimi_linear import tiny_config as kimi
@@ -75,7 +76,11 @@ def steps():
         "tiny_smallthinker_step": (("smallthinker_21b_a3b",), smallthinker,
                                    48, 2, "auto"),
         "tiny_smallthinker_flash_step": (("smallthinker_21b_a3b",),
-                                         smallthinker, 48, 2, "flash")}
+                                         smallthinker, 48, 2, "flash"),
+        # (the gate on both paths, W W G W W under a window of 10)
+        "tiny_afmoe_step": (("trinity_mini_26b_a3b",), afmoe, 48, 2, "auto"),
+        "tiny_afmoe_flash_step": (("trinity_mini_26b_a3b",), afmoe, 48, 2,
+                                  "flash")}
 
 
 def tree_digest(params):
