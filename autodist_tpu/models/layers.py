@@ -74,11 +74,14 @@ def rotate(x, positions, inv_freq, amplitude: float = 1.0):
             + rotated * amp(jnp.sin(ang))).astype(x.dtype)
 
 
+def rope_inv_freq(d: int, theta: float):
+    """Plain RoPE's frequencies ``theta^(-2i/D)``, [D / 2] float32."""
+    return 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+
+
 def rope(x, positions, theta: float):
-    """:func:`rotate` by plain RoPE's frequencies ``theta^(-2i/D)``."""
-    d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    return rotate(x, positions, inv_freq)
+    """:func:`rotate` by plain RoPE's frequencies."""
+    return rotate(x, positions, rope_inv_freq(x.shape[-1], theta))
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -203,6 +206,34 @@ class SparseIndexer(nn.Module):
         return chosen
 
 
+class NormScale(nn.Module):
+    """An ``nn.RMSNorm``'s learned weight alone, under the norm's own name:
+    what a pass that norms inside a kernel reads of the parameter tree the
+    ``jnp`` form's ``make_norm("rmsnorm", ...)`` initialised."""
+
+    @nn.compact
+    def __call__(self, features):
+        return self.param("scale", nn.initializers.ones, (features,))
+
+
+def attn_inputs(q, k, norms, positions, rope_theta):
+    """The ``jnp`` form of what lies between a softmax attention's
+    projections and its core: q [B, S, H, D] and k [B, S, Hkv, D] as the
+    projections made them -> q and k as the core reads them. ``norms``:
+    the (q's, k's) norms to apply, in order (OLMoE's over all projected
+    features, Qwen3's a head; each rounds to the model's dtype); then,
+    with ``rope_theta``, the rotation by ``positions`` (float32, rounded
+    once more). v has no arithmetic. ``ops/attn_pre.py`` is the same
+    values in one pass a direction, in the flash kernels' layout, and is
+    tested against this."""
+    for q_norm, k_norm in norms:
+        q, k = q_norm(q), k_norm(k)
+    if rope_theta is not None:
+        q = rope(q, positions, rope_theta)
+        k = rope(k, positions, rope_theta)
+    return q, k
+
+
 class MultiHeadAttention(nn.Module):
     """Softmax attention over heads with an injectable attention
     implementation: ``num_heads`` query heads of ``head_dim`` (its own
@@ -223,7 +254,13 @@ class MultiHeadAttention(nn.Module):
     same order on every path, so flax resolves identical names):
 
     - training/eval (default): full-sequence attention, optionally
-      through ``attn_fn``;
+      through ``attn_fn``. Where that is the flash kernels' adapter and
+      the layer norms a head or rotates at heads of whole 128-lane tiles
+      (``ops/attn_pre.py:runs_fused``, from these fields and the shapes
+      alone), everything element-wise between the projections and the core
+      is one pallas pass a direction, ``attn_pre``, on q and k in the
+      kernels' own [B, H, S, D] (v is transposed alone); every other layer
+      and mode runs :func:`attn_inputs`, the ``jnp`` form;
     - prefill (``return_kv=True``): same, but also returns the projected
       ``(k, v)`` [B, S, H, D] so the caller can seed a decode cache;
     - decode (``cache=(k_cache, v_cache)`` + ``cursor``): x is [B, 1, d],
@@ -256,6 +293,7 @@ class MultiHeadAttention(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None, cache=None, cursor=None, alive=None,
                  return_kv=False, positions=None):
+        from autodist_tpu.ops import attn_pre
         d_model = x.shape[-1]
         kv_heads = self.num_kv_heads or self.num_heads
         if self.num_heads % kv_heads:
@@ -267,23 +305,39 @@ class MultiHeadAttention(nn.Module):
         q = dense("query")(x)
         k = dense("key", kv_heads)(x)
         v = dense("value", kv_heads)(x)
-        if self.qk_norm_eps is not None:
-            def full_width_norm(t, name):
-                flat = t.reshape(t.shape[:-2] + (-1,))
-                return make_norm("rmsnorm", self.qk_norm_eps, self.dtype,
-                                 name)(flat).reshape(t.shape)
-            q = full_width_norm(q, "q_norm")
-            k = full_width_norm(k, "k_norm")
-        if self.head_norm_eps is not None:
-            q = make_norm("rmsnorm", self.head_norm_eps, self.dtype,
-                          "q_norm")(q)
-            k = make_norm("rmsnorm", self.head_norm_eps, self.dtype,
-                          "k_norm")(k)
-        if self.rope_theta is not None:
-            if positions is None:
-                raise ValueError("rotary attention needs positions")
-            q = rope(q, positions, self.rope_theta)
-            k = rope(k, positions, self.rope_theta)
+        if self.rope_theta is not None and positions is None:
+            raise ValueError("rotary attention needs positions")
+        # (the pass holds no parameter of its own: an init traces no kernel)
+        heads_first = (
+            mask is None and cache is None and not return_kv
+            and not self.is_initializing() and attn_pre.runs_fused(
+                self.attn_fn, x.shape[-2], self.head_dim,
+                self.head_norm_eps is not None, self.rope_theta is not None,
+                self.qk_norm_eps is not None))
+        if heads_first:
+            q, k = attn_pre.attn_pre(
+                q, k,
+                None if self.head_norm_eps is None else tuple(
+                    NormScale(name=name)(self.head_dim)
+                    for name in ("q_norm", "k_norm")),
+                self.head_norm_eps, positions,
+                None if self.rope_theta is None else rope_inv_freq(
+                    self.head_dim, self.rope_theta))
+            v = v.transpose(0, 2, 1, 3)
+        else:
+            norms = []
+            if self.qk_norm_eps is not None:
+                def full_width(name):
+                    norm = make_norm("rmsnorm", self.qk_norm_eps, self.dtype,
+                                     name)
+                    return lambda t: norm(
+                        t.reshape(t.shape[:-2] + (-1,))).reshape(t.shape)
+                norms.append((full_width("q_norm"), full_width("k_norm")))
+            if self.head_norm_eps is not None:
+                norms.append(tuple(
+                    make_norm("rmsnorm", self.head_norm_eps, self.dtype, name)
+                    for name in ("q_norm", "k_norm")))
+            q, k = attn_inputs(q, k, norms, positions, self.rope_theta)
         new_cache = None
         if self.window is not None and (cache is not None or return_kv):
             raise NotImplementedError(
@@ -299,7 +353,8 @@ class MultiHeadAttention(nn.Module):
                 "heads and attend all of them: grouped K/V heads and an "
                 "indexer's own key cache have no decode path yet")
         if grouped_or_chosen:
-            out = self._grouped_or_chosen(x, q, k, v, mask, positions)
+            out = self._grouped_or_chosen(x, q, k, v, mask, positions,
+                                          heads_first)
         elif cache is not None:
             from autodist_tpu.ops.attention import (cached_attention,
                                                     flash_cached_attention)
@@ -321,7 +376,7 @@ class MultiHeadAttention(nn.Module):
             new_cache = (k_cache, v_cache)
         else:
             with scopes.scope(scopes.ATTN_CORE):
-                out = self._plain_core(q, k, v, mask)
+                out = self._plain_core(q, k, v, mask, heads_first)
         if self.gated:
             with scopes.scope(scopes.ATTN_GATE):
                 gate = checkpoint_name(dense("gate")(x), ATTN_GATE_KEPT)
@@ -335,9 +390,12 @@ class MultiHeadAttention(nn.Module):
             return out, (k, v)
         return out
 
-    def _plain_core(self, q, k, v, mask):
+    def _plain_core(self, q, k, v, mask, heads_first):
         """The core over as many K/V heads as query heads: ``attn_fn`` or
-        XLA's scores."""
+        XLA's scores. ``heads_first``: q, k and v are ``attn_pre``'s,
+        [B, H, S, D] (only with the flash adapter, which takes them so)."""
+        if heads_first:
+            return self.attn_fn(q, k, v, mask, heads_first=True)
         if self.attn_fn is not None:
             return self.attn_fn(q, k, v, mask)
         scale = 1.0 / np.sqrt(self.head_dim)
@@ -347,12 +405,13 @@ class MultiHeadAttention(nn.Module):
         weights = nn.softmax(logits.astype(jnp.float32)).astype(self.dtype)
         return jnp.einsum("...hqk,...khd->...qhd", weights, v)
 
-    def _grouped_or_chosen(self, x, q, k, v, mask, positions):
+    def _grouped_or_chosen(self, x, q, k, v, mask, positions, heads_first):
         """The core over K/V heads that groups of query heads share, with
         an indexer over the keys it chose, with a ``window`` over the band
         it leaves (under ``swa_core``; the others under ``dsa_core``):
-        through ``attn_fn`` (the flash kernels take all three as they are)
-        or XLA's scores with the K/V heads repeated."""
+        through ``attn_fn`` (the flash kernels take all three as they are,
+        and ``attn_pre``'s operands ``heads_first``) or XLA's scores with
+        the K/V heads repeated."""
         from autodist_tpu.ops.attention import (causal_band,
                                                 reference_attention)
         chosen = None
@@ -367,6 +426,8 @@ class MultiHeadAttention(nn.Module):
                 carried = {} if chosen is None else {"select": chosen}
                 if self.window is not None:
                     carried["window"] = self.window
+                if heads_first:
+                    carried["heads_first"] = True
                 return self.attn_fn(q, k, v, mask, **carried)
             group = self.num_heads // k.shape[-2]
             if group > 1:

@@ -769,8 +769,7 @@ class TransformerLM(nn.Module):
             cfg.mlp_dim,
             dtype=cfg.dtype, norm=cfg.norm, norm_eps=cfg.norm_eps,
             attention_bias=cfg.attention_bias, qk_norm=cfg.qk_norm,
-            rope_theta=(cfg.rope_theta if cfg.rope_layers is None
-                        or cfg.rope_layers[i] else None),
+            rope_theta=layer_rope_theta(cfg, i),
             num_experts=cfg.num_experts,
             experts_per_token=cfg.experts_per_token,
             sandwich_norm=cfg.sandwich_norm, name="layer_%d" % i, **kw)
@@ -1009,6 +1008,13 @@ def auto_remat_blocks(param_count: int, num_layers: int,
 # its 16 B: at 12 DeepSeek-V2-Lite's cell would stop recomputing, which does
 # not fit.
 KEPT_EXPERTS_HBM_LEFT = 0.24
+
+
+def layer_rope_theta(cfg: LMConfig, i: int) -> Optional[float]:
+    """Layer i's rotation: the model's, where ``rope_layers`` names no
+    layers or names this one."""
+    return (cfg.rope_theta if cfg.rope_layers is None or cfg.rope_layers[i]
+            else None)
 
 
 def num_dense_layers(cfg: LMConfig) -> int:
@@ -1309,6 +1315,15 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
         attn_fn = make_flash_attn_fn(causal=True)
     flash_layers = (sum(t in ("attention", "mla") for t in types)
                     if attn_fn is not None else 0)
+    # block applications whose softmax attention runs ``attn_pre`` between
+    # its projections and its core: the layer's own rule, asked here
+    from autodist_tpu.ops import attn_pre
+    fused_pre_layers = cfg.loop_steps * sum(
+        kind == "attention" and attn_pre.runs_fused(
+            attn_fn, seq_len, cfg.head_dim or cfg.d_model // cfg.num_heads,
+            cfg.qk_head_norm, layer_rope_theta(cfg, i) is not None,
+            cfg.qk_norm)
+        for i, kind in enumerate(types))
     kda_kernel_layers = 0
     if "kda" in types:
         from autodist_tpu.ops.kda import runs_as_kernels
@@ -1483,6 +1498,7 @@ def make_train_setup(config: Optional[LMConfig] = None, seq_len: int = 128,
     def loss_fn(params, batch):
         # what the rule decided, once per trace, host side
         tel.gauge_set("attention.flash_layers", flash_layers)
+        tel.gauge_set("attention.fused_pre_layers", fused_pre_layers)
         tel.gauge_set("attention.kda_kernel_layers", kda_kernel_layers)
         tel.gauge_set("model.remat_blocks",
                       cfg.num_layers if remat_blocks else 0)

@@ -72,7 +72,10 @@ Design (standard FlashAttention-2 tiling, arXiv 2307.08691):
 - tiles: ``_tiles`` chooses (block_q, block_k) from the shapes; no caller
   passes a tile size (PERF.md section 6, PR 28, has the chip's readings).
 - layout: the model zoo's [batch, seq, heads, head_dim], transposed to
-  [batch, heads, seq, head_dim] around the kernels.
+  [batch, heads, seq, head_dim] around the kernels; ``heads_first`` operands
+  come in the kernels' layout already (``ops/attn_pre.py`` writes a layer's
+  q, k and v so, in the one pass that norms and rotates them) and only the
+  result and its cotangent are transposed.
 - under a recomputing checkpoint: three of the backward kernels'
   residuals carry the name ``KEPT``: the forward kernel's results ``out``
   and ``lse``, and its operand ``q`` in the kernels' layout. A
@@ -717,13 +720,15 @@ def _heads_first(x):
     return x.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _flash(q, k, v, q_seg, kv_seg, sel, causal, window):
-    return _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _flash(q, k, v, q_seg, kv_seg, sel, causal, window, heads_first):
+    return _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal, window,
+                      heads_first)[0]
 
 
-def _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal, window):
-    qt, kt, vt = _heads_first(q), _heads_first(k), _heads_first(v)
+def _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal, window, heads_first):
+    qt, kt, vt = (q, k, v) if heads_first else (
+        _heads_first(q), _heads_first(k), _heads_first(v))
     segs = None if q_seg is None else (q_seg, kv_seg)
     qt = checkpoint_name(qt, KEPT)
     out, lse = _fwd(qt, kt, vt, segs, sel, causal, window)
@@ -736,27 +741,37 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, sel, causal, window):
     return _heads_first(out), (qt, kt, vt, out, lse, q_seg, kv_seg, sel)
 
 
-def _flash_bwd(causal, window, res, do):
+def _flash_bwd(causal, window, heads_first, res, do):
     grads = _bwd(causal, res, _heads_first(do), window)
-    return tuple(_heads_first(g) for g in grads) + tuple(
-        _seg_zero_cot(ids) for ids in res[5:])
+    if not heads_first:
+        grads = tuple(_heads_first(g) for g in grads)
+    return tuple(grads) + tuple(_seg_zero_cot(ids) for ids in res[5:])
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def tileable(*seqs) -> bool:
+    """Do sequences of these lengths split into tiles the kernels take (a
+    power of two of 8 rows at least)?"""
+    return all(_pick_block(seq, _ROWS) for seq in seqs)
+
+
 def _tileable(q, k):
-    return all(_pick_block(x.shape[1], _ROWS) for x in (q, k))
+    return tileable(q.shape[1], k.shape[1])
 
 
 def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
-                    select=None, window=None):
+                    select=None, window=None, heads_first: bool = False):
     """Exact fused attention. q, k: [B, S, H, D], v: [B, S, H, Dv] ->
-    [B, S, H, Dv]; scores are scaled by 1 / sqrt(D). k and v may have
-    fewer heads than q, a divisor of H: query head h reads K/V head
-    ``h // (H / Hkv)`` (grouped-query attention; the kernels index the
-    shared head, nothing is repeated in HBM, and each query head's dk and
-    dv are summed over its group after the backward kernel).
+    [B, S, H, Dv]; scores are scaled by 1 / sqrt(D). With ``heads_first``
+    q, k and v come [B, H, S, .] (the kernels' own layout, which
+    ``ops/attn_pre.py`` writes) and take their gradients so; the result is
+    [B, S, H, Dv] either way. k and v may have fewer heads than q, a
+    divisor of H: query head h reads K/V head ``h // (H / Hkv)``
+    (grouped-query attention; the kernels index the shared head, nothing
+    is repeated in HBM, and each query head's dk and dv are summed over
+    its group after the backward kernel).
 
     ``select``: [B, Sq, Sk], non-zero = this query attends this key, every
     head alike (a learned sparse attention's choice of keys; composes with
@@ -780,6 +795,7 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
 
     Falls back to the XLA reference path (differentiable as usual) when the
     sequence can't be tiled (remainder below the 8-row minimum block)."""
+    seq_axis = 2 if heads_first else 1
     if segment_ids is None:
         q_seg = kv_seg = None
     elif isinstance(segment_ids, (tuple, list)):
@@ -794,9 +810,11 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
                 "a window counts the latest keys a causal query sees, itself "
                 "included (>= 1): got window %d with causal %s"
                 % (window, causal))
-        if window >= k.shape[1]:
+        if window >= k.shape[seq_axis]:
             window = None       # every earlier key: the plain causal table
-    if not _tileable(q, k):
+    if not tileable(q.shape[seq_axis], k.shape[seq_axis]):
+        if heads_first:
+            q, k, v = (_heads_first(x) for x in (q, k, v))
         from autodist_tpu.ops.attention import (causal_band,
                                                 reference_attention)
         from autodist_tpu.utils import logging
@@ -820,7 +838,7 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
         return reference_attention(q, k, v, mask)
     if select is not None:
         select = jnp.asarray(select).astype(jnp.int8)
-    return _flash(q, k, v, q_seg, kv_seg, select, causal, window)
+    return _flash(q, k, v, q_seg, kv_seg, select, causal, window, heads_first)
 
 
 def make_flash_attn_fn(causal: bool = True):
@@ -829,12 +847,16 @@ def make_flash_attn_fn(causal: bool = True):
     A key-padding mask (boolean, broadcastable [B, 1, 1, S] / [B, S])
     becomes segment ids (valid=1, pad=0) — the masked-tile block path.
     Arbitrary dense masks are not expressible as segments and raise;
-    ``select`` ([B, Sq, Sk], a sparse attention's chosen keys) and
-    ``window`` (a layer's sliding window) go to the kernels as they are."""
-    def attn(q, k, v, mask=None, select=None, window=None):
+    ``select`` ([B, Sq, Sk], a sparse attention's chosen keys),
+    ``window`` (a layer's sliding window) and ``heads_first`` (operands
+    [B, H, S, D]) go to the kernels as they are. The adapter says of
+    itself that it takes operands heads-first (``attn.heads_first``): what
+    ``ops/attn_pre.py:runs_fused`` asks of a layer's ``attn_fn``."""
+    def attn(q, k, v, mask=None, select=None, window=None,
+             heads_first=False):
         if mask is None:
             return flash_attention(q, k, v, causal, select=select,
-                                   window=window)
+                                   window=window, heads_first=heads_first)
         m = jnp.asarray(mask)
         # accept [B, S] or the layers' [B, 1, 1, S] broadcast form
         if m.ndim == 4 and m.shape[1] == 1 and m.shape[2] == 1:
@@ -845,5 +867,6 @@ def make_flash_attn_fn(causal: bool = True):
                 "[B, 1, 1, S]) via segment ids; got mask shape %s"
                 % (mask.shape,))
         return flash_attention(q, k, v, causal, m.astype(jnp.int32), select,
-                               window)
+                               window, heads_first)
+    attn.heads_first = True
     return attn

@@ -9,10 +9,22 @@ import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=8").strip()
 os.environ["JAX_PLATFORMS"] = "cpu"
+# The suite's time is XLA:CPU compiling thousands of tiny programs whose run
+# time is nothing: the CPU backend builds them at optimisation level 0 (no
+# LLVM passes, the fast instruction selector), which no assertion reads (a
+# CPU run gives counts and values, never a time). jaxlib reads its flags
+# once, when the CPU client is made just below; the variable is then put
+# back, so libtpu (loaded later, for the compiles for a described chip) and
+# the processes the tests start compile as they do outside the tests. The
+# bit-for-bit pins of tests/data/lm_pins.json are written under this file
+# too (``python tests/test_lm_pins.py --write`` imports it).
+_FLAGS = os.environ["XLA_FLAGS"]
+os.environ["XLA_FLAGS"] = _FLAGS + " --xla_backend_optimization_level=0"
 
 import jax  # noqa: E402
 
 assert len(jax.devices()) == 8, "virtual 8-device CPU mesh not active"
+os.environ["XLA_FLAGS"] = _FLAGS
 os.environ.setdefault("ADT_IS_TESTING", "1")
 
 import pytest  # noqa: E402

@@ -25,10 +25,9 @@ COMBOS = [("AllReduce", "flax"), ("Parallax", "sparse"),
 
 CHILD = """
 import json, sys
-import jax
-jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, %(root)r)
 sys.path.insert(0, %(tests)r)
+import conftest  # the platform, devices and backend flags of the other side
 import numpy as np
 from test_integration_matrix import run_combo
 
@@ -43,10 +42,9 @@ def test_subprocess_combo_matches_inprocess(builder_name, case_name):
     script = CHILD % {"root": os.path.dirname(HERE), "tests": HERE}
     env = dict(os.environ)
     env.pop("JAX_PLATFORMS", None)
-    # APPEND the device-count flag: ambient numerics-affecting XLA flags
-    # must apply identically to both sides of the comparison
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
+    # ambient numerics-affecting XLA flags must apply identically to both
+    # sides of the comparison: the child keeps the environment's and imports
+    # ``tests/conftest.py``, which appends this process's own
     proc = subprocess.run(
         [sys.executable, "-c", script, builder_name, case_name],
         env=env, capture_output=True, text=True, timeout=300)

@@ -673,6 +673,48 @@ def test_flash_attention_compiles_for_a_v5e_at_the_cells_shape(one_v5e_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
+@pytest.mark.parametrize("seq, heads, kv_heads, head_dim, normed, window", [
+    (16384, 32, 4, 128, True, 2048),     # trinity_mini_train_1chip
+    (8192, 32, 4, 128, True, None),      # keye_vl2_train_1chip
+    (16384, 28, 4, 128, False, 4096),    # smallthinker_train_1chip
+    (4096, 16, 16, 128, False, None),    # ouro_2_6b_train_1chip
+    (4096, 32, 32, 256, True, None)],    # heads of two lane tiles
+    ids=["trinity_mini", "keye_vl2", "smallthinker", "ouro", "heads_of_256"])
+def test_attn_pre_compiles_for_a_v5e_at_the_cells_shapes(
+        one_v5e_chip, seq, heads, kv_heads, head_dim, normed, window):
+    """``ops/attn_pre.py``'s two kernels in front of the flash kernels, the
+    operands handed over heads-first, at every shape a cell runs them at,
+    bfloat16, through Mosaic for a described chip: the lane roll, the 16-row
+    loop over every head of a 512-token tile (all heads of a step fit VMEM
+    twice over) and the ``[1, D]`` partial sums lower (``tests/
+    test_attn_pre.py`` has their values, interpreted)."""
+    from autodist_tpu.models import layers
+    from autodist_tpu.ops import attn_pre, pallas_mode
+    from autodist_tpu.ops.flash_attention import flash_attention
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+             for shape, dtype in (
+                 ((1, seq, heads, head_dim), jnp.bfloat16),
+                 ((1, seq, kv_heads, head_dim), jnp.bfloat16),
+                 ((1, seq, kv_heads, head_dim), jnp.bfloat16),
+                 ((head_dim,), jnp.float32), ((head_dim,), jnp.float32))]
+
+    def loss(q, k, v, wq, wk):
+        q, k = attn_pre.attn_pre(
+            q, k, (wq, wk) if normed else None, 1e-5, jnp.arange(seq),
+            layers.rope_inv_freq(head_dim, 1e4))
+        return jnp.sum(flash_attention(
+            q, k, v.transpose(0, 2, 1, 3), causal=True, window=window,
+            heads_first=True).astype(jnp.float32))
+    with pallas_mode.compiling_for_tpu():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *avals).compile().as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel, count in (("attn_pre_fwd", 2), ("attn_pre_bwd", 2),
+                          ("flash_fwd", 1), ("flash_bwd", 1)):
+        assert sum(kernel in call for call in calls) == count, (kernel, calls)
+
+
 @pytest.mark.parametrize("shape, dtype, segments", [
     ((1, 8192, 16, 128), jnp.bfloat16, False),
     ((16, 1024, 16, 64), jnp.bfloat16, False),   # lm1b's heads

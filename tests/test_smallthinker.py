@@ -359,8 +359,11 @@ def test_the_tile_table_is_brute_force_over_all_pairs(n, block, window,
     ``CROSSED`` exactly on the tiles that also hold an invisible pair, the
     first / last bits where the walk says, and ``_Q_OUT`` naming each q
     block over one run of steps that ends at its last tile."""
-    i, j = np.indices((n * block, n * block))
-    seen = (j <= i) & (i - j < window)
+    # every (i, j) pair, a byte each: ``i - j < window`` as ``j > i - window``
+    # makes no [S, S] array of differences (a gigabyte at 16,384 positions)
+    i = np.arange(n * block, dtype=np.int32)[:, None]
+    j = i.T
+    seen = (j <= i) & (j > i - window)
     tiles = seen.reshape(n, block, n, block)
     live = tiles.any(axis=(1, 3))
     partly = live & ~tiles.all(axis=(1, 3))

@@ -146,10 +146,12 @@ def test_lm_lean_head_matches_standard_loss():
                                            lean_head=False)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_array_equal(a, b), p1, p2)
-    np.testing.assert_allclose(float(lf_lean(p1, batch)),
-                               float(lf_std(p2, batch)), rtol=1e-5)
-    g1 = jax.grad(lf_lean)(p1, batch)
-    g2 = jax.grad(lf_std)(p2, batch)
+    # (each as ONE program: op by op the two losses and their gradients
+    # were hundreds of small compiles)
+    np.testing.assert_allclose(float(jax.jit(lf_lean)(p1, batch)),
+                               float(jax.jit(lf_std)(p2, batch)), rtol=1e-5)
+    g1 = jax.jit(jax.grad(lf_lean))(p1, batch)
+    g2 = jax.jit(jax.grad(lf_std))(p2, batch)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32),
